@@ -23,7 +23,9 @@ Probe depth:
 * ``"off"``     — trust the kernel (no probe).
 
 The default ``"auto"`` resolves to ``compile`` on a clean-trace TPU backend and
-``trace`` everywhere else.
+``trace`` everywhere else. Inside a ``jit`` trace on TPU that means the probe
+cannot see a Mosaic failure — and does not need to: the enclosing program's
+own compile then raises it, loudly, instead of degrading.
 
 Fault injection (:func:`beforeholiday_tpu.testing.faults.force_probe_failure`)
 registers op names in :data:`_FORCED_FAILURES`; the probe consults it first, so
@@ -36,6 +38,7 @@ import threading
 from typing import Any, Callable, Dict, Optional, Set, Tuple
 
 import jax
+from jax._src import core as _jax_core
 
 from beforeholiday_tpu.utils.logging import get_logger, reset_warn_once, warn_once
 
@@ -135,10 +138,11 @@ def _is_arrayish(x: Any) -> bool:
 
 
 def _trace_clean() -> bool:
-    try:
-        return jax.core.trace_state_clean()
-    except Exception:
-        return False
+    """True outside any jit/grad/shard_map trace. ``trace_state_clean`` left
+    the public ``jax.core`` namespace; the private module still carries it.
+    No fallback: if it moves again the import above fails loudly instead of
+    pinning the ``"auto"`` probe to ``"trace"`` forever."""
+    return _jax_core.trace_state_clean()
 
 
 def _probe(op_name: str, fn: Callable, args: tuple, kw: dict) -> None:

@@ -45,15 +45,6 @@ def global_norm(tree: Any) -> jax.Array:
     return jnp.sqrt(sq)
 
 
-def _axis_size(axis_name: str):
-    # jax >= 0.6 has lax.axis_size; on older jax psum-of-ones is the same
-    # value and XLA folds it to a constant
-    size = getattr(jax.lax, "axis_size", None)
-    if size is not None:
-        return size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 class TrainMonitor:
     """Config + pure functions over the ``Metrics`` pytree.
 
@@ -184,7 +175,7 @@ class TrainMonitor:
         inside a binding context for ``axis_name`` (shard_map/pmap) — the
         same place DDP's ``reduce_gradients`` runs, sharing its collectives.
         """
-        world = _axis_size(axis_name)
+        world = jax.lax.axis_size(axis_name)
         out = dict(metrics)
         for k, dt, red in self._SPEC:
             v = metrics[k]

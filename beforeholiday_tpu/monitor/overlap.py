@@ -264,13 +264,13 @@ def rank_skew(
     pack them into your metrics vector and drain as usual. Pure jnp —
     safe under jit/shard_map; must run inside a binding context for
     ``axis_name``."""
+    import jax
     import jax.numpy as jnp
 
     from beforeholiday_tpu.monitor import comms
-    from beforeholiday_tpu.monitor.metrics import _axis_size
 
     d = jnp.asarray(duration, jnp.float32)
-    world = _axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     mean = comms.psum(d, axis_name, site=site) / world
     hi = comms.pmax(d, axis_name, site=site)
     lo = comms.pmin(d, axis_name, site=site)

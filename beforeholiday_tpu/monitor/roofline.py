@@ -79,11 +79,12 @@ __all__ = [
 @dataclasses.dataclass(frozen=True)
 class ChipSpec:
     """Peak numbers utilization is measured against. ``peak_tflops`` is the
-    dense-matmul peak for the dtype you train in (the 172.6 TFLOP/s the bench
-    roofline uses is bf16); ``hbm_gbs`` is peak memory bandwidth in GB/s.
-    ``fp8_peak_tflops`` is the quantized-matmul peak FLOPs booked as
-    ``fp8_flops`` are measured against (O6 GEMMs); None means the standard
-    2x-of-dense-peak MXU ratio."""
+    dense-matmul peak for the dtype you train in (bf16 for the TPU rows);
+    ``hbm_gbs`` is peak memory bandwidth in GB/s. ``fp8_peak_tflops`` is the
+    quantized-matmul peak that FLOPs booked as ``fp8_flops`` (O6 GEMMs) are
+    measured against; None means the part publishes no fp8 rate, and an
+    entry that books fp8 FLOPs against it gets no MFU rather than an assumed
+    multiple of the dense peak."""
 
     name: str
     peak_tflops: float
@@ -96,24 +97,20 @@ class ChipSpec:
         are compute-bound, below it memory-bound."""
         return (self.peak_tflops * 1e12) / (self.hbm_gbs * 1e9)
 
-    @property
-    def fp8_peak(self) -> float:
-        """Effective fp8 peak in TFLOP/s (2x dense peak unless overridden)."""
-        return (
-            self.fp8_peak_tflops
-            if self.fp8_peak_tflops is not None
-            else 2.0 * self.peak_tflops
-        )
-
 
 _SPECS_LOCK = threading.Lock()
 _CHIP_SPECS: Dict[str, ChipSpec] = {}
 
-# The bench's historical roofline (BENCH_r0*.json measures gpt_o5_mfu against
-# it) and a CPU proxy so the 8-device host mesh produces finite, honest
-# utilization numbers instead of ~0 against a TPU peak.
-_DEFAULT_TPU = ChipSpec("tpu_roofline_r04", peak_tflops=172.6, hbm_gbs=680.0)
-_DEFAULT_CPU = ChipSpec("cpu_proxy", peak_tflops=0.2, hbm_gbs=40.0)
+# PUBLISHED peaks, keyed by the string ``jax.devices()[0].device_kind`` prints
+# on that part. Source: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s
+# bf16, 819 GB/s HBM; it lists 393 TOP/s int8 and NO fp8 rate. A self-measured
+# probe is an "achievable" number, never the utilization denominator.
+_PUBLISHED_TPU = (
+    ChipSpec("TPU v5 lite", peak_tflops=197.0, hbm_gbs=819.0),
+)
+# CPU proxy so the 8-device host mesh produces finite ratios instead of ~0
+# against a TPU peak (counts-only runs; not a device metric).
+_CPU_PROXY = ChipSpec("cpu_proxy", peak_tflops=0.2, hbm_gbs=40.0)
 
 
 def register_chip_spec(
@@ -136,7 +133,9 @@ def register_chip_spec(
             str(name), float(peak_tflops), float(hbm_gbs),
             float(fp8_peak_tflops) if fp8_peak_tflops is not None else None,
         )
-    if spec.peak_tflops <= 0 or spec.hbm_gbs <= 0 or spec.fp8_peak <= 0:
+    if spec.peak_tflops <= 0 or spec.hbm_gbs <= 0 or (
+        spec.fp8_peak_tflops is not None and spec.fp8_peak_tflops <= 0
+    ):
         raise ValueError(f"chip peaks must be positive, got {spec}")
     with _SPECS_LOCK:
         _CHIP_SPECS[spec.name] = spec
@@ -163,13 +162,16 @@ def _resolve_chip(chip: Union[ChipSpec, str, None]) -> ChipSpec:
         return chip
     if isinstance(chip, str):
         return get_chip_spec(chip)
-    # default: measure against the TPU roofline on TPU, the CPU proxy
-    # everywhere else — never silently compare a host run to a TPU peak
-    return _DEFAULT_TPU if jax.default_backend() == "tpu" else _DEFAULT_CPU
+    # default: the published peaks of the device_kind this process runs on
+    # (an unknown kind raises — no default TPU), the CPU proxy off-TPU —
+    # never silently compare a host run to a TPU peak
+    if jax.default_backend() != "tpu":
+        return _CPU_PROXY
+    return get_chip_spec(jax.devices()[0].device_kind)
 
 
-register_chip_spec(_DEFAULT_TPU)
-register_chip_spec(_DEFAULT_CPU)
+for _spec in _PUBLISHED_TPU + (_CPU_PROXY,):
+    register_chip_spec(_spec)
 
 
 # ------------------------------------------------------- XLA cost extraction
@@ -548,9 +550,11 @@ def roofline_summary(
     chip: Union[ChipSpec, str, None] = None,
 ) -> List[Dict[str, Any]]:
     """One row per entry: analytic work joined with recorded wall time
-    against ``chip`` (default: TPU roofline on TPU, CPU proxy elsewhere).
-    Entries without recorded time still classify by arithmetic intensity but
-    carry ``mfu``/``bw_util`` of None."""
+    against ``chip`` (default: the published peaks of this TPU's
+    ``device_kind``, CPU proxy elsewhere). Entries without recorded time
+    still classify by arithmetic intensity but carry ``mfu``/``bw_util`` of
+    None; so does the ``mfu`` of an entry that books fp8 FLOPs against a chip
+    with no fp8 rate."""
     spec = _resolve_chip(chip)
     ridge = spec.ridge_flops_per_byte
     rows = []
@@ -582,13 +586,14 @@ def roofline_summary(
         )
         mfu = None
         bw_util = None
-        if sec and (flops is not None or fp8_flops is not None):
+        fp8_priced = not fp8_flops or spec.fp8_peak_tflops is not None
+        if sec and fp8_priced and (flops is not None or fp8_flops is not None):
             # each precision class utilizes its own peak: bf16-class flops
             # against peak_tflops, quantized-GEMM flops against the fp8 peak
-            mfu = (
-                (flops or 0.0) / spec.peak_tflops
-                + (fp8_flops or 0.0) / spec.fp8_peak
-            ) / sec / 1e12
+            mfu = (flops or 0.0) / spec.peak_tflops
+            if fp8_flops:
+                mfu += fp8_flops / spec.fp8_peak_tflops
+            mfu = mfu / sec / 1e12
         if sec and nbytes is not None:
             bw_util = nbytes / sec / 1e9 / spec.hbm_gbs
         total_flops = (

@@ -25,63 +25,37 @@ from __future__ import annotations
 
 import contextlib
 import functools
-import threading
 from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax._src import config as _jax_config
 
 # The scope must participate in jit's cache key: `jax.jit(fused_dense)` traced
 # outside a scope and re-called inside one would otherwise hit the fp32 cache
 # entry and silently skip the policy. jax's config-state machinery exposes
-# exactly this (include_in_trace_context); fall back to a plain thread-local
-# (correct under amp's own apply wrapper, which enters the scope inside the
-# trace) if the private API moves.
-try:
-    from jax._src import config as _jax_config
-
-    _dtype_state = _jax_config.optional_enum_state(
-        name="beforeholiday_tpu_autocast_dtype",
-        enum_values=["float16", "bfloat16", "float32"],
-        default=None,
-        help="active autocast compute dtype for the per-op amp cast policy",
-        include_in_jit_key=True,
-        include_in_trace_context=True,
-    )
-    # O6's quantized-matmul routing flag must join the jit key exactly like
-    # the dtype: `jax.jit(fused_dense)` traced under O5 and re-called under O6
-    # would otherwise replay the unquantized cache entry.
-    _quantized_state = _jax_config.optional_enum_state(
-        name="beforeholiday_tpu_autocast_quantized",
-        enum_values=["on"],
-        default=None,
-        help="route fused matmuls through the fp8-style quantized path (O6)",
-        include_in_jit_key=True,
-        include_in_trace_context=True,
-    )
-    _xla_metadata = None
-except Exception:
-    # jax < 0.6: extra_jit_context is a FIXED NamedTuple — custom config
-    # states cannot join the jit key (include_in_jit_key silently no-ops for
-    # user states). The xla_metadata context manager IS part of
-    # config.trace_context() there, so riding it gives the same cache-key
-    # participation; the scope value itself lives in the thread-local below.
-    # Side effect: ops traced inside autocast carry a frontend attribute —
-    # metadata only, no semantic change.
-    _dtype_state = None
-    _quantized_state = None
-    try:
-        from jax.experimental.xla_metadata import set_xla_metadata as _xla_metadata
-    except Exception:  # pragma: no cover - future jax relocation
-        _xla_metadata = None
-
-
-class _State(threading.local):
-    dtype: Optional[str] = None
-    quantized: bool = False
-
-
-_state = _State()
+# exactly this (include_in_jit_key / include_in_trace_context). It is a
+# private API; if it moves the import fails loudly — a thread-local stand-in
+# would silently change jit-cache semantics.
+_dtype_state = _jax_config.optional_enum_state(
+    name="beforeholiday_tpu_autocast_dtype",
+    enum_values=["float16", "bfloat16", "float32"],
+    default=None,
+    help="active autocast compute dtype for the per-op amp cast policy",
+    include_in_jit_key=True,
+    include_in_trace_context=True,
+)
+# O6's quantized-matmul routing flag must join the jit key exactly like
+# the dtype: `jax.jit(fused_dense)` traced under O5 and re-called under O6
+# would otherwise replay the unquantized cache entry.
+_quantized_state = _jax_config.optional_enum_state(
+    name="beforeholiday_tpu_autocast_quantized",
+    enum_values=["on"],
+    default=None,
+    help="route fused matmuls through the fp8-style quantized path (O6)",
+    include_in_jit_key=True,
+    include_in_trace_context=True,
+)
 
 
 @contextlib.contextmanager
@@ -90,29 +64,12 @@ def autocast(dtype, *, quantized: bool = False):
     compute type (fp16 for O1, bf16 for O4). ``quantized=True`` additionally
     turns on O6's quantized-matmul routing for the scope (see
     :func:`quantized_compute`)."""
-    name = jnp.dtype(dtype).name
-    if _dtype_state is not None:
-        with _dtype_state(name):
-            if quantized:
-                with _quantized_state("on"):
-                    yield
-            else:
+    with _dtype_state(jnp.dtype(dtype).name):
+        if quantized:
+            with _quantized_state("on"):
                 yield
-    else:
-        prev = getattr(_state, "dtype", None)
-        prev_q = getattr(_state, "quantized", False)
-        _state.dtype = name
-        _state.quantized = bool(quantized) or prev_q
-        try:
-            if _xla_metadata is not None:
-                meta = name + (":q8" if _state.quantized else "")
-                with _xla_metadata(beforeholiday_tpu_autocast=meta):
-                    yield
-            else:
-                yield
-        finally:
-            _state.dtype = prev
-            _state.quantized = prev_q
+        else:
+            yield
 
 
 @contextlib.contextmanager
@@ -122,37 +79,20 @@ def quantized_compute():
     per-op cast policy — O6 keeps O5's storage-cast semantics (bf16 params,
     fp32 norms) and only swaps the GEMM arithmetic. Participates in the jit
     cache key exactly like :func:`autocast`."""
-    if _quantized_state is not None:
-        with _quantized_state("on"):
-            yield
-    else:
-        prev_q = getattr(_state, "quantized", False)
-        _state.quantized = True
-        try:
-            if _xla_metadata is not None:
-                with _xla_metadata(beforeholiday_tpu_autocast_quantized="on"):
-                    yield
-            else:
-                yield
-        finally:
-            _state.quantized = prev_q
+    with _quantized_state("on"):
+        yield
 
 
 def autocast_dtype() -> Optional[Any]:
     """The active low-precision dtype, or None outside autocast."""
-    if _dtype_state is not None:
-        name = _dtype_state.value
-    else:
-        name = getattr(_state, "dtype", None)
+    name = _dtype_state.value
     return jnp.dtype(name) if name else None
 
 
 def quantized_enabled() -> bool:
     """True inside a :func:`quantized_compute` (or ``autocast(...,
     quantized=True)``) scope — the O6 routing predicate ``ops.dense`` checks."""
-    if _quantized_state is not None:
-        return _quantized_state.value == "on"
-    return bool(getattr(_state, "quantized", False))
+    return _quantized_state.value == "on"
 
 
 def cast_floats(tree, dtype):

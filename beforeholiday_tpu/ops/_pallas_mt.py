@@ -28,10 +28,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .arena import LANES
-from ._pallas_util import (
-    CompilerParams as _CompilerParams,
-    interpret_default as _interpret_default,
-)
+from ._pallas_util import interpret_default as _interpret_default
 
 # One grid step processes BLOCK_ROWS x 128 lanes = 32768 elements per operand
 # (128 KiB fp32) — the same role as the reference's chunk_size 2048*32
@@ -63,7 +60,7 @@ def _compiler_params(interpret: bool):
     pins the correctness requirement. Interpret mode takes no TPU params."""
     if interpret:
         return {}
-    return {"compiler_params": _CompilerParams(dimension_semantics=("arbitrary",))}
+    return {"compiler_params": pltpu.CompilerParams(dimension_semantics=("arbitrary",))}
 
 
 def ew_call(

@@ -6,37 +6,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as _pltpu
-
-# jax >= 0.6 spells the TPU compiler-params struct pltpu.CompilerParams;
-# jax 0.4.x ships it as TPUCompilerParams — same fields, renamed. Resolve
-# once here (same getattr-compat idiom as static_axis_size / the shard_map
-# test shims) so kernel modules run on either.
-CompilerParams = getattr(_pltpu, "CompilerParams", None) or _pltpu.TPUCompilerParams
 
 
 def interpret_default() -> bool:
     """Pallas compiles natively on TPU; elsewhere the interpreter runs."""
     return jax.default_backend() != "tpu"
-
-
-def _manual_context_pre_vma() -> bool:
-    """jax < 0.6 fallback (no abstract-mesh/vma API): shard_map binds its
-    manual axes in the trace-time axis env, and ``check_rep=True`` traces
-    the body under a RewriteTrace — the replication checker that rejects
-    opaque pallas_calls, i.e. the role ``check_vma`` plays on newer jax.
-    Manual-and-pallas-safe is therefore: axes bound, no RewriteTrace active.
-    The repo convention shards over ALL mesh axes, so any bound frame counts
-    as fully manual (pmap frames also qualify: one device per shard there
-    too). Fail safe to jnp on any probe breakage, as above."""
-    try:
-        from jax._src import core as _core
-
-        if not _core.get_axis_env().axis_sizes:
-            return False
-        return type(_core.trace_ctx.trace).__name__ != "RewriteTrace"
-    except Exception:
-        return False
 
 
 def in_fully_manual_context() -> bool:
@@ -49,22 +23,21 @@ def in_fully_manual_context() -> bool:
     pallas_call is rejected at trace time because its out_shapes carry no
     ``vma``; the default must stay jnp there rather than regress working
     user code."""
-    if not hasattr(jax.sharding, "get_abstract_mesh"):
-        return _manual_context_pre_vma()
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
+        return False
+    if not all(t == jax.sharding.AxisType.Manual for t in mesh.axis_types):
+        return False
     try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if not mesh.axis_names:
-            return False
-        if not all(t == jax.sharding.AxisType.Manual for t in mesh.axis_types):
-            return False
         from jax._src.config import _check_vma
 
         return not _check_vma.value
     except (ImportError, AttributeError):
-        # fail safe to jnp on EVERY probe failure mode: the abstract-mesh /
-        # AxisType API absent on older jax, the _check_vma module relocated
-        # (ImportError), or the attribute moved/changed shape while the module
-        # survived (AttributeError on the name or on ``.value``)
+        # _check_vma is private jax state with no public spelling: if it
+        # moves (ImportError) or changes shape (AttributeError on the name or
+        # on ``.value``) the default stays jnp rather than tracing a
+        # pallas_call the vma checker may reject. chip_smoke.py fails on the
+        # resulting missing pallas dispatches, so this cannot go unnoticed.
         return False
 
 
@@ -76,13 +49,22 @@ def resolve_impl(impl: Optional[str]) -> str:
     ``pallas_call`` is an opaque custom call to the GSPMD partitioner: under a
     >1-device auto-sharded program it would force replication/all-gathers on
     sharded operands. Default to pallas only where the traced program owns a
-    single device per shard:
+    single device per shard — decided from the trace's ambient mesh, never
+    from how many chips the host happens to have:
 
-    * single-device TPU, or
+    * no ambient mesh (plain ``jit``/eager on the one device the inputs are
+      committed to — also on a multi-chip host), or
     * inside ``shard_map`` over ALL mesh axes (fully-manual context).
 
-    Anywhere else (GSPMD/auto axes, CPU/GPU) the jnp path partitions
-    transparently. Explicit ``impl=`` is always honored.
+    Anywhere else (a ``jax.sharding.set_mesh`` scope with auto/explicit axes,
+    which is how every GSPMD program in this repo runs; CPU/GPU) the jnp path
+    partitions transparently. Explicit ``impl=`` is always honored.
+
+    One GSPMD case is invisible at trace time: a plain ``jit`` with no
+    ``set_mesh`` whose INPUTS are sharded over several devices. It resolves
+    to pallas, and Mosaic then refuses to lower ("Mosaic kernels cannot be
+    automatically partitioned") — loud, not a quiet slow path. Run such a
+    program under ``set_mesh`` or pass ``impl="jnp"``.
 
     Note: inside shard_map the kernels require ``check_vma=False`` (the
     repo-wide convention, see parallel/distributed.py) — jax's interpret-mode
@@ -90,11 +72,11 @@ def resolve_impl(impl: Optional[str]) -> str:
     """
     if impl is None:
         on_tpu = jax.default_backend() == "tpu"
-        impl = (
-            "pallas"
-            if on_tpu and (jax.device_count() == 1 or in_fully_manual_context())
-            else "jnp"
+        one_device_per_shard = (
+            not jax.sharding.get_abstract_mesh().axis_names
+            or in_fully_manual_context()
         )
+        impl = "pallas" if on_tpu and one_device_per_shard else "jnp"
     if impl not in ("pallas", "jnp"):
         raise ValueError(f"impl must be 'pallas' or 'jnp', got {impl!r}")
     return impl

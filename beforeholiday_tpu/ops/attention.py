@@ -49,7 +49,6 @@ from beforeholiday_tpu.remat.policies import (
 )
 from beforeholiday_tpu.ops._autocast import autocast_dtype
 from beforeholiday_tpu.ops._pallas_util import (
-    CompilerParams as _CompilerParams,
     interpret_default as _interpret_default,
     resolve_impl as _resolve_impl,
 )
@@ -244,7 +243,7 @@ def _fa_fwd_pallas(q, k, v, lens, causal, scale, interpret, rate=0.0, seed=None)
     o, lse = pl.pallas_call(
         functools.partial(_fa_fwd_kernel, causal, scale, nq, nk, bq, bk, rate),
         grid_spec=grid_spec,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         out_shape=[
@@ -415,7 +414,7 @@ def _fa_bwd_pallas(q, k, v, do, o, lse, dlse, lens, causal, scale, interpret,
             scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -443,7 +442,7 @@ def _fa_bwd_pallas(q, k, v, do, o, lse, dlse, lens, causal, scale, interpret,
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -13,8 +13,8 @@ scheduler is free to hoist between the remaining backward work.
 Three guarantees every helper here keeps:
 
 * **Static geometry.** Bucket offsets/lengths and the axis size are host
-  Python ints derived at trace time (``static_axis_size`` exploits that
-  ``psum(1, axis)`` is static under ``shard_map``); nothing here branches on
+  Python ints derived at trace time (``lax.axis_size`` is static under
+  ``shard_map``); nothing here branches on
   a traced value and nothing reads back to the host
   (``tests/test_no_host_sync.py`` scans this file).
 * **fp32 accumulation under compression.** ``compress=True`` casts each
@@ -141,9 +141,6 @@ def hierarchical_compression_error_bound(
 def static_axis_size(axis_name: Any) -> int:
     """The mesh axis size as a host Python int, inside a ``shard_map`` trace.
 
-    ``lax.axis_size`` where it exists (jax >= 0.6); otherwise
-    ``psum(1, axis)`` — on the old API a psum of a Python constant folds to a
-    static int at trace time, which is exactly what bucket geometry needs.
     A tuple spec (the two-level ``(slice, intra)`` convention) returns the
     product of the per-axis sizes — the flat world size."""
     if isinstance(axis_name, (tuple, list)):
@@ -151,17 +148,7 @@ def static_axis_size(axis_name: Any) -> int:
         for ax in axis_name:
             size *= static_axis_size(ax)
         return size
-    size_fn = getattr(jax.lax, "axis_size", None)
-    size = size_fn(axis_name) if size_fn is not None else jax.lax.psum(
-        1, axis_name
-    )
-    try:
-        return int(size)
-    except Exception as exc:  # tracer leak: geometry would become dynamic
-        raise ValueError(
-            f"axis {axis_name!r} has no static size under this trace; "
-            "bucketed collectives need static bucket geometry"
-        ) from exc
+    return jax.lax.axis_size(axis_name)
 
 
 @functools.lru_cache(maxsize=4096)

@@ -36,16 +36,12 @@ from beforeholiday_tpu.tune import UNSET, resolve_trainer_knobs
 
 
 def _axis_size(axis_name: Any):
-    """``jax.lax.axis_size`` where it exists (jax >= 0.6); the psum-of-ones
-    identity on older jax — same value, and XLA folds it to a constant.
-    A two-level ``("slice", "intra")`` spec is the product of its tiers."""
+    """``jax.lax.axis_size``; a two-level ``("slice", "intra")`` spec is the
+    product of its tiers."""
     axes = hierarchical_axes(axis_name)
     if axes is not None:
         return _axis_size(axes[0]) * _axis_size(axes[1])
-    size = getattr(jax.lax, "axis_size", None)
-    if size is not None:
-        return size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    return jax.lax.axis_size(axis_name)
 
 
 def _grad_fingerprint(grads: Any) -> jax.Array:
@@ -109,8 +105,8 @@ def reduce_gradients(
     """psum a gradient pytree over ``axis_name`` with apex's scaling options.
 
     Must run inside a binding context for ``axis_name`` (shard_map / pmap)
-    **with varying-axis tracking off** (``jax.shard_map(..., check_vma=False)``,
-    legacy ``check_rep=False``): that is the mode where gradients of replicated
+    **with varying-axis tracking off** (``jax.shard_map(..., check_vma=False)``):
+    that is the mode where gradients of replicated
     params come back *local*, matching the reference's per-process grads. With
     tracking ON, shard_map's transpose already psums replicated-param
     cotangents — calling this on top would double-count; there just divide by

@@ -70,16 +70,12 @@ __all__ = [
 
 
 def _axis_size(axis_name: Any):
-    """Same compat shim as ``distributed._axis_size`` (not imported from
-    there: ``distributed`` imports this module, and the hook must reproduce
-    the sweep's op sequence byte for byte anyway)."""
+    """Same as ``distributed._axis_size`` (not imported from there:
+    ``distributed`` imports this module)."""
     axes = hierarchical_axes(axis_name)
     if axes is not None:
         return _axis_size(axes[0]) * _axis_size(axes[1])
-    size = getattr(jax.lax, "axis_size", None)
-    if size is not None:
-        return size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    return jax.lax.axis_size(axis_name)
 
 
 def _reduce_cotangent(

@@ -40,19 +40,26 @@ __all__ = ["donate_optimizer_step", "donate_step"]
 _WARN_PREFIX = "remat.donation"
 
 
-def _buffer_key(leaf: Any):
-    """A hashable identity for a leaf's device storage, or None for non-arrays."""
+def _buffer_keys(leaf: Any) -> Tuple[Any, ...]:
+    """Hashable identities of a leaf's device storage, one per addressable
+    shard; empty for non-arrays. An array replicated over a mesh by
+    ``jax.device_put`` may SHARE its source's buffer on the source's own
+    device while the other devices get copies, so aliasing has to be judged
+    shard by shard. Tracers (a donated step called under an outer jit, where
+    jax ignores the donation) and deleted arrays have no buffer to read and
+    fall back to object identity."""
     if not isinstance(leaf, jax.Array):
-        return None
-    try:
-        return leaf.unsafe_buffer_pointer()
-    except Exception:  # multi-shard / deleted / tracer — fall back to object id
-        return id(leaf)
+        return ()
+    if isinstance(leaf, jax.core.Tracer) or leaf.is_deleted():
+        return (id(leaf),)
+    return tuple(
+        s.data.unsafe_buffer_pointer() for s in leaf.addressable_shards
+    )
 
 
 def _dedupe_donated(args: Tuple[Any, ...], donated: frozenset) -> Tuple[Any, ...]:
-    """Copy any donated leaf whose buffer already appears in an earlier donated
-    slot, so XLA never sees the same buffer donated twice.
+    """Copy any donated leaf that shares a buffer with an earlier donated
+    leaf, so XLA never sees the same buffer donated twice.
 
     Aliasing across donated state trees is legal while arrays are immutable —
     e.g. fused optimizers initialize fp32 masters as the params arena itself
@@ -68,14 +75,12 @@ def _dedupe_donated(args: Tuple[Any, ...], donated: frozenset) -> Tuple[Any, ...
         leaves, treedef = jax.tree_util.tree_flatten(out[i])
         changed = False
         for j, leaf in enumerate(leaves):
-            key = _buffer_key(leaf)
-            if key is None:
-                continue
-            if key in seen:
-                leaves[j] = jax.numpy.array(leaf)  # fresh buffer breaks the alias
+            keys = _buffer_keys(leaf)
+            if seen.intersection(keys):
+                leaves[j] = jax.numpy.array(leaf)  # fresh buffers break the alias
                 changed = True
             else:
-                seen.add(key)
+                seen.update(keys)
         if changed:
             out[i] = jax.tree_util.tree_unflatten(treedef, leaves)
     return tuple(out)

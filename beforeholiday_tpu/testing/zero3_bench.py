@@ -44,18 +44,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:  # jax < 0.6 keeps shard_map in experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-else:
-    _CHECK_KW = "check_vma"
-
 
 def _shmap(f, **kw):
-    kw.setdefault(_CHECK_KW, False)
-    return _shard_map(f, **kw)
+    kw.setdefault("check_vma", False)
+    return jax.shard_map(f, **kw)
 
 
 WORLD = 8

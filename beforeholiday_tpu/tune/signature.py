@@ -134,17 +134,12 @@ def _canon_mesh(mesh: Any) -> Tuple[Tuple[str, int], ...]:
 def _canon_chip(chip: Any) -> Tuple[Any, ...]:
     from beforeholiday_tpu.monitor import roofline as _roofline
 
-    if chip is None:
-        spec = _roofline._resolve_chip(None)
-    elif isinstance(chip, str):
-        spec = _roofline.get_chip_spec(chip)
-    else:
-        spec = chip
+    spec = _roofline._resolve_chip(chip)
     return (
         spec.name,
         float(spec.peak_tflops),
         float(spec.hbm_gbs),
-        float(spec.fp8_peak),
+        spec.fp8_peak_tflops,
     )
 
 

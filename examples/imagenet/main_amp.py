@@ -37,20 +37,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax >= 0.6 spells manual mode jax.shard_map(check_vma=False); older jax has
-# the experimental module with check_rep — accept either
-if hasattr(jax, "shard_map"):
-    _shard_map = functools.partial(jax.shard_map, check_vma=False)
-else:
-    from jax.experimental.shard_map import shard_map as _esm
-
-    _shard_map = functools.partial(_esm, check_rep=False)
+_shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 from beforeholiday_tpu import amp
 from beforeholiday_tpu.models import resnet
 from beforeholiday_tpu.optimizers import FusedSGD
 from beforeholiday_tpu.parallel import DistributedDataParallel, LARC
 from beforeholiday_tpu.remat import donate_step
+from beforeholiday_tpu.utils.compile_cache import enable_compile_cache
 
 # ImageNet channel stats, in 0-255 space like the reference prefetcher
 # (main_amp.py:269-270)
@@ -378,6 +372,7 @@ def parse_args(argv=None):
 
 def main(argv=None):
     args = parse_args(argv)
+    enable_compile_cache()
     print(f"opt_level = {args.opt_level}")
     print(f"keep_batchnorm_fp32 = {args.keep_batchnorm_fp32}")
     print(f"loss_scale = {args.loss_scale}")

@@ -49,6 +49,7 @@ from beforeholiday_tpu.infer import (
 )
 from beforeholiday_tpu.monitor import FlightRecorder
 from beforeholiday_tpu.testing import gpt
+from beforeholiday_tpu.utils.compile_cache import enable_compile_cache
 
 
 def synthetic_trace(
@@ -199,6 +200,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--prefix-cache", action="store_true",
                     help="serve with radix prefix caching on")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = gpt.GPTConfig()
     params = gpt.init(jax.random.PRNGKey(args.seed), cfg)

@@ -44,12 +44,7 @@ from beforeholiday_tpu.testing.faults import preempt_after
 
 import functools
 
-if hasattr(jax, "shard_map"):
-    _shard_map = functools.partial(jax.shard_map, check_vma=False)
-else:
-    from jax.experimental.shard_map import shard_map as _esm
-
-    _shard_map = functools.partial(_esm, check_rep=False)
+_shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 D, LAYERS, ROWS = 64, 4, 16  # width, depth, global batch rows
 

@@ -32,12 +32,7 @@ import jax
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-if hasattr(jax, "shard_map"):
-    _shard_map = functools.partial(jax.shard_map, check_vma=False)
-else:
-    from jax.experimental.shard_map import shard_map as _esm
-
-    _shard_map = functools.partial(_esm, check_rep=False)
+_shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 from beforeholiday_tpu.monitor import comms_summary
 from beforeholiday_tpu.monitor.metrics import TrainMonitor

@@ -211,7 +211,7 @@ class TestSignature:
         base = tune.tuning_key(p, mesh={"data": 1})
         other_mesh = tune.tuning_key(p, mesh={"data": 8})
         other_chip = tune.tuning_key(
-            p, mesh={"data": 1}, chip="tpu_roofline_r04"
+            p, mesh={"data": 1}, chip="TPU v5 lite"
         )
         assert base.digest != other_mesh.digest
         assert base.digest != other_chip.digest

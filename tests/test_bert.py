@@ -9,10 +9,6 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-# jax >= 0.6 activates a mesh for spec-based sharding via
-# jax.sharding.set_mesh; on older jax the Mesh object IS the context manager
-_set_mesh = getattr(jax.sharding, "set_mesh", None) or (lambda m: m)
-
 from beforeholiday_tpu.optimizers import FusedLAMB
 from beforeholiday_tpu.parallel import parallel_state as ps
 from beforeholiday_tpu.testing import bert
@@ -71,7 +67,7 @@ class TestBertTensorParallel:
             lambda x, s: jax.device_put(x, NamedSharding(state.mesh, s)),
             params, bert.param_specs(cfg),
         )
-        with _set_mesh(state.mesh):
+        with jax.sharding.set_mesh(state.mesh):
             loss = float(
                 jax.jit(lambda p, *b: bert.pretrain_loss(p, *b, cfg))(sharded, *batch)
             )

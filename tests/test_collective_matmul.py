@@ -21,17 +21,10 @@ from beforeholiday_tpu.transformer.tensor_parallel import collective as cm
 
 pytestmark = pytest.mark.quantized
 
-_shard_map = getattr(jax, "shard_map", None)
-_CHECK_KW = "check_vma"
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
 
 def _smap(f, **kw):
-    kw[_CHECK_KW] = False
-    return _shard_map(f, **kw)
+    kw["check_vma"] = False
+    return jax.shard_map(f, **kw)
 
 
 WORLD = 8

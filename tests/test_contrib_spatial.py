@@ -24,20 +24,9 @@ from beforeholiday_tpu.optimizers import FusedSGD
 from beforeholiday_tpu.parallel.sync_batch_norm import init_batch_norm
 
 
-# jax >= 0.6 spells varying-axis-tracking-off jax.shard_map(check_vma=False);
-# older jax ships the experimental module with check_rep — same shim as
-# test_data_parallel.py so the suite runs on either
-_shard_map = getattr(jax, "shard_map", None)
-_CHECK_KW = "check_vma"
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
-
 def _smap(f, mesh, in_specs, out_specs):
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: False})
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                      check_vma=False)
 
 
 class TestASP:

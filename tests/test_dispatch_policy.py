@@ -2,8 +2,8 @@
 apex/transformer/functional/fused_softmax.py:164 ``is_kernel_available``).
 
 One rule for every fused op: pallas iff the traced program owns one device per
-shard (single-device TPU, or inside shard_map over all mesh axes); jnp under
-GSPMD/auto sharding and off-TPU. Verified here by (a) a decision-table unit
+shard (no ambient mesh, or inside shard_map over all mesh axes) — however many
+chips the host has; jnp under GSPMD/auto sharding and off-TPU. Verified here by (a) a decision-table unit
 test with the backend patched, and (b) actually running Pallas kernels inside
 an 8-device shard_map (interpret mode on CPU) for the multi-tensor and
 normalization families.
@@ -22,20 +22,10 @@ from beforeholiday_tpu.ops import multi_tensor as mt
 from beforeholiday_tpu.ops.normalization import fused_layer_norm
 from beforeholiday_tpu.ops.softmax import scaled_softmax
 
-# jax >= 0.6 spells varying-axis-tracking-off jax.shard_map(check_vma=False);
-# older jax ships the experimental module with check_rep — same shim as
-# test_data_parallel.py so the suite runs on either
-_shard_map = getattr(jax, "shard_map", None)
-_CHECK_KW = "check_vma"
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
 
 def _smap(f, **kw):
-    kw[_CHECK_KW] = False
-    return _shard_map(f, **kw)
+    kw["check_vma"] = False
+    return jax.shard_map(f, **kw)
 
 
 class TestResolvePolicy:
@@ -49,15 +39,33 @@ class TestResolvePolicy:
         assert jax.default_backend() != "tpu"
         assert _pallas_util.resolve_impl(None) == "jnp"
 
-    def test_tpu_multidevice_gspmd_defaults_jnp(self, monkeypatch):
+    def test_tpu_gspmd_mesh_defaults_jnp(self, monkeypatch, devices8):
+        """``set_mesh`` with auto axes is how GSPMD programs run here: the
+        partitioner owns the body -> jnp, at top level and under jit."""
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        mesh = Mesh(np.asarray(devices8).reshape(4, 2), ("data", "tensor"))
+        seen = []
+        with jax.sharding.set_mesh(mesh):
+            seen.append(_pallas_util.resolve_impl(None))
+            jax.eval_shape(
+                jax.jit(lambda x: (seen.append(_pallas_util.resolve_impl(None)), x)[1]),
+                jax.ShapeDtypeStruct((8, 4), jnp.float32),
+            )
+        assert seen == ["jnp", "jnp"]
+
+    def test_tpu_no_mesh_defaults_pallas_on_multichip_host(self, monkeypatch):
+        """No ambient mesh = the program owns the one device its inputs are
+        committed to, so a plain jit gets the kernels however many chips the
+        host has (the old ``device_count() == 1`` gate sent a four-chip
+        host's one-chip training to the unfused path)."""
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         assert jax.device_count() > 1
-        assert _pallas_util.resolve_impl(None) == "jnp"
-
-    def test_tpu_single_device_defaults_pallas(self, monkeypatch):
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        monkeypatch.setattr(jax, "device_count", lambda *a, **k: 1)
-        assert _pallas_util.resolve_impl(None) == "pallas"
+        seen = [_pallas_util.resolve_impl(None)]
+        jax.eval_shape(
+            jax.jit(lambda x: (seen.append(_pallas_util.resolve_impl(None)), x)[1]),
+            jax.ShapeDtypeStruct((8, 4), jnp.float32),
+        )
+        assert seen == ["pallas", "pallas"]
 
     def test_tpu_inside_shard_map_defaults_pallas(self, monkeypatch, devices8):
         """Fully-manual context (check_vma=False): every shard is one device
@@ -85,7 +93,7 @@ class TestResolvePolicy:
         seen = []
 
         @functools.partial(
-            _shard_map, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
+            jax.shard_map, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
         )
         def f(x):
             seen.append(_pallas_util.resolve_impl(None))
@@ -94,11 +102,6 @@ class TestResolvePolicy:
         jax.eval_shape(f, jax.ShapeDtypeStruct((8, 4), jnp.float32))
         assert seen == ["jnp"]
 
-    @pytest.mark.skipif(
-        not hasattr(jax.sharding, "AxisType"),
-        reason="partial-manual shard_map(axis_names=...) over typed mesh axes "
-               "is a jax>=0.6 API; older jax has no equivalent spelling",
-    )
     def test_partially_manual_context_defaults_jnp(self, monkeypatch, devices8):
         """shard_map over a strict subset of axes leaves Auto axes -> GSPMD
         still partitions the body -> jnp."""

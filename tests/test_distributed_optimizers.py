@@ -21,22 +21,11 @@ from beforeholiday_tpu.optimizers import (
 )
 
 
-# jax >= 0.6 spells varying-axis-tracking-off jax.shard_map(check_vma=False);
-# older jax ships the experimental module with check_rep — same shim as
-# test_data_parallel.py so the suite runs on either
-_shard_map = getattr(jax, "shard_map", None)
-_CHECK_KW = "check_vma"
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
-
 def shard_map(f=None, **kw):
-    kw.setdefault(_CHECK_KW, False)
+    kw.setdefault("check_vma", False)
     if f is None:
-        return lambda g: _shard_map(g, **kw)
-    return _shard_map(f, **kw)
+        return lambda g: jax.shard_map(g, **kw)
+    return jax.shard_map(f, **kw)
 
 
 @pytest.fixture
@@ -213,7 +202,7 @@ class TestZeroCheckpoint:
             _, state = dopt.step(params, g, state)
             return dopt.state_dict(params, state)
 
-        sd = run(params)
+        sd = jax.jit(run)(params)  # eager shard_map runs op by op on jax 0.9
         for key in ("master", "exp_avg", "exp_avg_sq"):
             assert set(sd[key]) == set(params)
             for name, leaf in sd[key].items():
@@ -244,7 +233,8 @@ class TestZeroCheckpoint:
                 p, state = dopt.step(p, {k: jnp.asarray(v) for k, v in g.items()}, state)
             return p, dopt.state_dict(params, state)
 
-        p_mid, sd = first_half(params)
+        # jitted: eager shard_map executes op by op on jax 0.9 (minutes here)
+        p_mid, sd = jax.jit(first_half)(params)
 
         @functools.partial(
             shard_map, mesh=data_mesh, in_specs=(P(), P()), out_specs=P(),
@@ -255,8 +245,8 @@ class TestZeroCheckpoint:
                 p, state = dopt.step(p, {k: jnp.asarray(v) for k, v in g.items()}, state)
             return p
 
-        p_resumed = second_half(p_mid, sd)
-        p_straight = uninterrupted(params)
+        p_resumed = jax.jit(second_half)(p_mid, sd)
+        p_straight = jax.jit(uninterrupted)(params)
         jax.tree.map(
             lambda a, b: np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), atol=1e-6, rtol=1e-6

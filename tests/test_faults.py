@@ -27,24 +27,11 @@ from beforeholiday_tpu.testing.faults import (
 pytestmark = pytest.mark.faults
 
 
-# version-compat manual-mode shard_map: jax>=0.6 spells it jax.shard_map with
-# check_vma; older jax has jax.experimental.shard_map.shard_map with check_rep.
-# Varying-axis tracking OFF either way (the repo convention, see
-# beforeholiday_tpu/parallel/distributed.py).
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is not None:
-    _CHECK_KW = "check_vma"
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
-
 def shard_map(f=None, **kw):
-    kw.setdefault(_CHECK_KW, False)
+    kw.setdefault("check_vma", False)
     if f is None:
-        return lambda g: _shard_map(g, **kw)
-    return _shard_map(f, **kw)
+        return lambda g: jax.shard_map(g, **kw)
+    return jax.shard_map(f, **kw)
 
 
 class TestPoisonGrads:

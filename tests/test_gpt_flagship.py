@@ -11,10 +11,6 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-# jax >= 0.6 activates a mesh for spec-based sharding via
-# jax.sharding.set_mesh; on older jax the Mesh object IS the context manager
-_set_mesh = getattr(jax.sharding, "set_mesh", None) or (lambda m: m)
-
 from beforeholiday_tpu.parallel import parallel_state as ps
 from beforeholiday_tpu.testing import gpt
 
@@ -73,7 +69,7 @@ class TestSequenceParallel:
             lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), params, specs
         )
         batch_sh = NamedSharding(mesh, P(ps.DATA_AXIS, None))
-        with _set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             loss, grads = jax.jit(
                 jax.value_and_grad(lambda p, t, y: gpt.loss_fn(p, t, y, cfg))
             )(sharded, jax.device_put(tokens, batch_sh), jax.device_put(targets, batch_sh))
@@ -99,7 +95,7 @@ class TestSequenceParallel:
         sharded = jax.tree.map(
             lambda x, s: jax.device_put(x, NamedSharding(state.mesh, s)), params, specs
         )
-        with _set_mesh(state.mesh):
+        with jax.sharding.set_mesh(state.mesh):
             lowered = jax.jit(
                 lambda p, t: gpt.forward(p, t, cfg)
             ).lower(sharded, tokens)

@@ -151,6 +151,15 @@ class TestCheckedImpl:
         clear_probe_cache("op_a")
         assert [k[0] for k in probe_failures()] == ["op_b"]
 
+    def test_trace_clean_sees_the_installed_jax(self):
+        """``"auto"`` picks the compile probe only on a clean trace; a probe
+        helper that always answers False (as the removed ``jax.core`` name
+        made it) would pin every TPU probe to eval_shape."""
+        assert guard_dispatch._trace_clean() is True
+        seen = []
+        jax.jit(lambda x: (seen.append(guard_dispatch._trace_clean()), x)[1])(1.0)
+        assert seen == [False]
+
     def test_probe_mode_off_trusts_kernel(self):
         def broken(x):
             raise RuntimeError("x")

@@ -37,20 +37,11 @@ from beforeholiday_tpu.parallel.parallel_state import (
 )
 from beforeholiday_tpu.testing import moe_model as mm
 
-try:  # jax >= 0.6 promotes shard_map out of experimental
-    from jax import shard_map as _shard_map  # type: ignore
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # pragma: no cover - version-dependent import
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
 
 def _smap(fn, mesh, in_specs, out_specs):
-    return _shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{_CHECK_KW: False},
+        check_vma=False,
     )
 
 

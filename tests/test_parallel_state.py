@@ -4,13 +4,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec
-
-# jax >= 0.6 exports jax.shard_map; older jax ships the experimental module —
-# same shim as test_data_parallel.py so the suite runs on either
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:
-    from jax.experimental.shard_map import shard_map
 
 from beforeholiday_tpu.parallel import parallel_state as ps
 

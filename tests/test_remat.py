@@ -224,10 +224,6 @@ class TestPipelineRemat:
                 np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6
             )
 
-    @pytest.mark.skipif(
-        not hasattr(jax.lax, "axis_size"),
-        reason="1F1B tick loop needs jax.lax.axis_size",
-    )
     @pytest.mark.parametrize("policy", REMAT_POLICIES)
     def test_1f1b_remat_parity(self, devices8, policy):
         """Per-stage remat inside the 1F1B tick loop reproduces the un-remat
@@ -237,12 +233,7 @@ class TestPipelineRemat:
 
         from beforeholiday_tpu.transformer import pipeline_parallel as pp
 
-        if hasattr(jax, "shard_map"):
-            smap = functools.partial(jax.shard_map, check_vma=False)
-        else:
-            from jax.experimental.shard_map import shard_map as _esm
-
-            smap = functools.partial(_esm, check_rep=False)
+        smap = functools.partial(jax.shard_map, check_vma=False)
 
         stacked = _toy_stack(jax.random.PRNGKey(0))
         rng = np.random.RandomState(1)
@@ -401,6 +392,28 @@ class TestDonation:
         donated = remat.donate_step(add, donate_argnums=(0, 1))
         x = jnp.arange(6.0)
         s, d = donated(x, x)  # same buffer in both donated slots
+        np.testing.assert_array_equal(np.asarray(s), np.arange(6.0) * 2)
+        np.testing.assert_array_equal(np.asarray(d), np.zeros(6))
+
+    def test_aliased_buffers_replicated_over_a_mesh_are_deduped(self, devices8):
+        """``device_put`` of two aliased leaves onto a mesh yields two arrays
+        that still share the source's buffer on the source's device (copies
+        elsewhere). The alias is per shard, and a whole-array pointer does
+        not exist for a multi-shard array — the dedupe must look shard by
+        shard or XLA rejects the step (and the other replicas hang)."""
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        mesh = Mesh(np.asarray(devices8[:4]), ("data",))
+        x = jnp.arange(6.0)
+        a, b = jax.device_put((x, x), NamedSharding(mesh, P()))
+        donated = remat.donate_step(
+            jax.shard_map(
+                lambda a, b: (a + b, a - b), mesh=mesh,
+                in_specs=(P(), P()), out_specs=(P(), P()), check_vma=False,
+            ),
+            donate_argnums=(0, 1),
+        )
+        s, d = donated(a, b)
         np.testing.assert_array_equal(np.asarray(s), np.arange(6.0) * 2)
         np.testing.assert_array_equal(np.asarray(d), np.zeros(6))
 

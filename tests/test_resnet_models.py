@@ -11,20 +11,10 @@ import torch.nn as nn
 
 from beforeholiday_tpu.models import resnet
 
-# jax >= 0.6 spells varying-axis-tracking-off jax.shard_map(check_vma=False);
-# older jax ships the experimental module with check_rep — same shim as
-# test_data_parallel.py so the suite runs on either
-_shard_map = getattr(jax, "shard_map", None)
-_CHECK_KW = "check_vma"
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
 
 def _smap(f, **kw):
-    kw[_CHECK_KW] = False
-    return _shard_map(f, **kw)
+    kw["check_vma"] = False
+    return jax.shard_map(f, **kw)
 
 
 class TorchBasicBlock(nn.Module):

@@ -55,15 +55,6 @@ from beforeholiday_tpu.monitor.flight import FlightRecorder
 from beforeholiday_tpu.monitor.trace import timeline
 from beforeholiday_tpu.parallel.parallel_state import EXPERT_AXIS
 
-try:  # jax >= 0.6 promotes shard_map out of experimental
-    from jax import shard_map as _shard_map  # type: ignore
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # pragma: no cover - version-dependent import
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
 pytestmark = pytest.mark.telemetry
 
 _REPO = pathlib.Path(__file__).resolve().parent.parent
@@ -82,9 +73,9 @@ def _fresh_ledgers():
 
 
 def _smap(fn, mesh, in_specs, out_specs):
-    return _shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{_CHECK_KW: False},
+        check_vma=False,
     )
 
 
@@ -632,30 +623,46 @@ class TestBenchDiff:
         res = bd.diff_runs({"parsed": None}, new, tol=0.10)
         assert res["missing_old"] and res["compared"] == 0
 
-    def test_smoke_identical_run_and_null_parsed(self):
-        r04 = str(_REPO / "BENCH_r04.json")
-        r05 = str(_REPO / "BENCH_r05.json")
+    @staticmethod
+    def _records(tmp_path):
+        """A parsed driver record and one that died before its metric line
+        (``parsed: null``) — the two shapes ``BENCH_r*.json`` files take."""
+        run = {
+            "n": 4, "rc": 0, "tail": "",
+            "parsed": {
+                "metric": "resnet50_amp_O5_train", "value": 2256.0,
+                "detail": {"o5_step_ms": 56.73, "gpt_o5_mfu": 0.337,
+                           "meter": {"stable": True, "pairs": [1.0, 1.02]}},
+            },
+        }
+        ok = tmp_path / "BENCH_ok.json"
+        ok.write_text(json.dumps(run))
+        null = tmp_path / "BENCH_null.json"
+        null.write_text(json.dumps(dict(run, parsed=None)))
+        return run, str(ok), str(null)
+
+    def test_smoke_identical_run_and_null_parsed(self, tmp_path):
+        _, ok, null_path = self._records(tmp_path)
         tool = str(_REPO / "tools" / "bench_diff.py")
-        same = subprocess.run([sys.executable, tool, r04, r04],
+        same = subprocess.run([sys.executable, tool, ok, ok],
                               capture_output=True, text=True)
         assert same.returncode == 0, same.stdout + same.stderr
         assert "0 past the" in same.stdout
-        # r05 died before its metric line (parsed=null): warn, exit 0
-        null = subprocess.run([sys.executable, tool, r04, r05],
+        # the new run died before its metric line (parsed=null): warn, exit 0
+        null = subprocess.run([sys.executable, tool, ok, null_path],
                               capture_output=True, text=True)
         assert null.returncode == 0, null.stdout + null.stderr
         assert "parsed=null" in null.stdout
 
     def test_perturbed_copy_exits_nonzero(self, tmp_path):
-        r04 = json.loads((_REPO / "BENCH_r04.json").read_text())
-        bad = dict(r04)
-        bad["parsed"] = _perturb(r04["parsed"], 1.5)
+        run, ok, _ = self._records(tmp_path)
+        bad = dict(run)
+        bad["parsed"] = _perturb(run["parsed"], 1.5)
         bad_path = tmp_path / "BENCH_bad.json"
         bad_path.write_text(json.dumps(bad))
         tool = str(_REPO / "tools" / "bench_diff.py")
         res = subprocess.run(
-            [sys.executable, tool, str(_REPO / "BENCH_r04.json"),
-             str(bad_path)],
+            [sys.executable, tool, ok, str(bad_path)],
             capture_output=True, text=True,
         )
         assert res.returncode == 1, res.stdout + res.stderr

@@ -18,22 +18,11 @@ from jax.sharding import Mesh, PartitionSpec as P
 from beforeholiday_tpu.transformer import tensor_parallel as tp
 
 
-# jax >= 0.6 spells varying-axis-tracking-off jax.shard_map(check_vma=False);
-# older jax ships the experimental module with check_rep — same shim as
-# test_data_parallel.py so the suite runs on either
-_shard_map = getattr(jax, "shard_map", None)
-_CHECK_KW = "check_vma"
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
-
 def shard_map(f=None, **kw):
-    kw.setdefault(_CHECK_KW, False)
+    kw.setdefault("check_vma", False)
     if f is None:
-        return lambda g: _shard_map(g, **kw)
-    return _shard_map(f, **kw)
+        return lambda g: jax.shard_map(g, **kw)
+    return jax.shard_map(f, **kw)
 
 
 @pytest.fixture

@@ -23,21 +23,12 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-# same varying-axis-tracking-off shim as test_monitor.py
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is not None:
-    _CHECK_KW = "check_vma"
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
 
 def shard_map(f=None, **kw):
-    kw.setdefault(_CHECK_KW, False)
+    kw.setdefault("check_vma", False)
     if f is None:
-        return lambda g: _shard_map(g, **kw)
-    return _shard_map(f, **kw)
+        return lambda g: jax.shard_map(g, **kw)
+    return jax.shard_map(f, **kw)
 
 
 from beforeholiday_tpu import monitor

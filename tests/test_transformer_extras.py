@@ -25,22 +25,11 @@ from beforeholiday_tpu.transformer.functional import FusedScaleMaskSoftmax
 from beforeholiday_tpu.transformer.layers import sp_fused_layer_norm
 
 
-# jax >= 0.6 spells varying-axis-tracking-off jax.shard_map(check_vma=False);
-# older jax ships the experimental module with check_rep — same shim as
-# test_data_parallel.py so the suite runs on either
-_shard_map = getattr(jax, "shard_map", None)
-_CHECK_KW = "check_vma"
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
-
 def shard_map(f=None, **kw):
-    kw.setdefault(_CHECK_KW, False)
+    kw.setdefault("check_vma", False)
     if f is None:
-        return lambda g: _shard_map(g, **kw)
-    return _shard_map(f, **kw)
+        return lambda g: jax.shard_map(g, **kw)
+    return jax.shard_map(f, **kw)
 
 
 class TestGradScaler:

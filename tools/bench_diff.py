@@ -6,7 +6,7 @@
 (the metric tree — or ``null`` when the run died before printing one). This
 tool turns the eyeballed perf trajectory into an exit code::
 
-    python tools/bench_diff.py BENCH_r04.json BENCH_r05.json
+    python tools/bench_diff.py BENCH_r01.json BENCH_r05.json
     python tools/bench_diff.py old.json new.json --tol 0.10
 
 Every NUMERIC leaf under ``parsed`` (flattened to a dotted path) present in
