@@ -22,9 +22,11 @@ reducing the gradients. It checks, and fails on:
 
 One process, no child, no ``jax_platforms``/``XLA_FLAGS`` set here. Exits
 non-zero before compiling anything unless ``jax.default_backend() == "tpu"``.
-Prints one JSON line per phase and, last, one summary object. Times and
-tokens/s are smoke observations, not benchmark numbers; no utilisation is
-computed here.
+Prints one JSON line per phase, one ``summary`` object of the run, and as the
+LAST line of stdout the verdict a driver reads: exactly
+``{"ok": bool, "device": {"platform", "kind", "count"}}`` with the device as
+JAX reports it. Times and tokens/s are smoke observations, not benchmark
+numbers; no utilisation is computed here.
 
     python chip_smoke.py                    # one chip (four-chip phase if present)
     python chip_smoke.py --require-chips 4  # fewer than four chips is an error
@@ -287,6 +289,8 @@ def main(argv=None) -> int:
         print(f"chip_smoke: needs the 'tpu' backend, JAX found {backend!r}; "
               "nothing was compiled", file=sys.stderr)
         return 1
+    # alone in a directory (no package beside it) this import raises: exit
+    # non-zero with nothing on stdout
     from beforeholiday_tpu.testing import gpt
     from beforeholiday_tpu.utils.compile_cache import enable_compile_cache
 
@@ -317,12 +321,14 @@ def main(argv=None) -> int:
     print(f"compile cache: {cache_dir}  entries after: "
           f"{_cache_entries(cache_dir)}")
 
-    ok = one["ok"] and four_ok
-    print(json.dumps({
-        "ok": ok, "device": device, "config": FLAGSHIP,
+    ok = bool(one["ok"] and four_ok)
+    print(json.dumps({"summary": {
+        "ok": ok, "config": FLAGSHIP,
         "train_1chip": _brief(one), "train_4chip": four_brief,
         "claim": None,
-    }))
+    }}))
+    # the verdict line: these keys and no others, last on stdout
+    print(json.dumps({"ok": ok, "device": device}), flush=True)
     return 0 if ok else 1
 
 

@@ -137,6 +137,39 @@ class TestMainRefusesWithoutTheChip:
         assert not (tmp_path / "cache").exists()  # nothing was compiled
 
 
+class TestVerdictLine:
+    """The driver reads the LAST stdout line: exactly ``ok`` and ``device``
+    (``platform``, ``kind``, ``count``), whatever else the run printed."""
+
+    @staticmethod
+    def _canned(name, ok):
+        return {"phase": name, "ok": ok, "compile_s": 1.0, "step_s": [0.1, 0.1],
+                "tokens_per_s": 1.0, "losses": [2.0, 1.0],
+                "errors": [] if ok else ["boom"]}
+
+    @pytest.mark.parametrize("ok", [True, False])
+    def test_last_line_is_the_contract_object(self, monkeypatch, capsys, ok):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/from/outside")
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(
+            chip_smoke, "train_1chip",
+            lambda *a, **k: self._canned("train_1chip", True))
+        monkeypatch.setattr(
+            chip_smoke, "train_4chip",
+            lambda *a, **k: self._canned("train_4chip", ok))
+        rc = chip_smoke.main([])
+        assert (rc == 0) is ok
+        lines = capsys.readouterr().out.strip().splitlines()
+        verdict = json.loads(lines[-1])
+        dev = jax.devices()
+        assert verdict == {"ok": ok, "device": {
+            "platform": dev[0].platform, "kind": dev[0].device_kind,
+            "count": len(dev)}}
+        assert type(verdict["device"]["count"]) is int
+        summary = json.loads(lines[-2])["summary"]
+        assert summary["claim"] is None and summary["ok"] is ok
+
+
 class TestCompileCacheHelper:
     @pytest.fixture
     def restore_cache_dir(self):
