@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from beforeholiday_tpu.amp.scaler import LossScaler
+from beforeholiday_tpu.monitor.spans import span
 from beforeholiday_tpu.ops._autocast import (
     autocast,
     cast_floats as _cast_floats,
@@ -487,8 +488,18 @@ def scaled_value_and_grad(
             q_scope = quantized_scope(scale_w, scale_g)
         else:
             q_scope = contextlib.nullcontext()
+        # jax.grad, split at the layer boundary the device trace is read by:
+        # the primal pass (and the residuals it saves) under ``amp_forward``,
+        # the pull-back with jax.grad's own ones cotangent under
+        # ``amp_backward`` (a remat policy's recomputed forward lands there,
+        # where its time is spent). Same program, named.
         with q_scope:
-            grads, (loss, aux) = jax.grad(scaled_loss_fn, has_aux=True)(params)
+            with span("amp_forward"):
+                scaled, pull, (loss, aux) = jax.vjp(
+                    scaled_loss_fn, params, has_aux=True
+                )
+            with span("amp_backward"):
+                (grads,) = pull(jnp.ones_like(scaled))
         if reduce_grads is not None:
             grads = reduce_grads(grads)
         amax = None

@@ -17,6 +17,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from beforeholiday_tpu.monitor.spans import span
 from beforeholiday_tpu.ops import multi_tensor as mt
 
 
@@ -85,19 +86,21 @@ class LossScaler:
         dtype), like unscale-into-master-grads.
         """
         leaves, treedef = jax.tree_util.tree_flatten(grads)
-        inv = 1.0 / state["scale"]
-        found = jnp.bool_(False)
         out = list(leaves)
         by_dtype: Dict[Any, list] = {}
         for i, g in enumerate(leaves):
             by_dtype.setdefault(g.dtype, []).append(i)
-        for dt, idx in by_dtype.items():
-            scaled, flag = mt.multi_tensor_scale(
-                [leaves[i] for i in idx], inv, out_dtype=jnp.float32, impl=impl
-            )
-            for i, s in zip(idx, scaled):
-                out[i] = s
-            found = found | flag
+        # one scope for every caller: the multiply and the non-finite check
+        with span("amp_unscale"):
+            inv = 1.0 / state["scale"]
+            found = jnp.bool_(False)
+            for dt, idx in by_dtype.items():
+                scaled, flag = mt.multi_tensor_scale(
+                    [leaves[i] for i in idx], inv, out_dtype=jnp.float32, impl=impl
+                )
+                for i, s in zip(idx, scaled):
+                    out[i] = s
+                found = found | flag
         return jax.tree_util.tree_unflatten(treedef, out), found
 
     def quantized_scales(self, state):

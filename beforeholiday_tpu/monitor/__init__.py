@@ -9,8 +9,8 @@ Split by which side of the device boundary each piece lives on:
   drain at a configurable cadence, one readback per logged step (JSONL / CSV
   / callback).
 * :mod:`beforeholiday_tpu.monitor.spans`    — trace spans and wall-clock
-  timers (the former ``utils/timers.py`` + ``utils/profiling.py``, which
-  remain as re-export shims).
+  timers; a span names the device ops it encloses and marks the
+  profiler's host line.
 * :mod:`beforeholiday_tpu.monitor.counters` — queryable guard-dispatch
   hit/degrade counters.
 * :mod:`beforeholiday_tpu.monitor.comms`    — trace-time collective-traffic

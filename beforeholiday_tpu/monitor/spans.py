@@ -1,6 +1,5 @@
 """Trace spans and wall-clock timers — the observability layer's host/trace
-annotation half (consolidates the former ``utils/timers.py`` +
-``utils/profiling.py`` stubs; both remain as back-compat re-export shims).
+annotation half (``beforeholiday_tpu.utils`` re-exports the public names).
 
 Ref: apex/transformer/pipeline_parallel/_timers.py:83 ``_Timers`` (named
 start/stop timers that ``torch.cuda.synchronize()``) and the NVTX ranges gated
@@ -9,8 +8,13 @@ by ``prof`` in DDP (apex/parallel/distributed.py:360-361). TPU equivalents:
 * ``span`` / ``annotate`` — ``jax.named_scope`` labels. They surface in
   XProf / tensorboard traces the way NVTX ranges surface in nsight, cost
   nothing at runtime (they only label the HLO), and are safe inside jit —
-  which is why the pipeline schedules, the DDP reducer, and the fused
-  optimizers carry them unconditionally.
+  which is why the amp step, the model, the kernels' entries, the pipeline
+  schedules, the DDP reducer, and the fused optimizers carry them
+  unconditionally. The same call is a ``jax.profiler.TraceAnnotation``: while
+  a profiler session is on, a span run on the host (``donate_step.call``)
+  lands on the host plane's ``python`` line, on the device planes' clock.
+  The scope names are an interface: the benchmark's per-layer metrics
+  (``benchmark/layer_metrics/*.json``) match them in the device trace.
 * ``Timers`` — host-side wall-clock timers whose device barrier is
   ``jax.block_until_ready`` on a token array (the ``cuda.synchronize``
   analogue). Between-steps tooling; never call inside a jitted step.
@@ -41,10 +45,11 @@ __all__ = [
 @contextlib.contextmanager
 def span(name: str, enabled: bool = True):
     """Named trace span (the NVTX-range idiom, gated like the reference's
-    ``prof`` flag). Zero-cost: only labels the traced HLO. When a
-    ``monitor.timeline`` recorder is active the span ALSO lands on the host
-    timeline (a ``B``/``E`` pair in the exported ``trace.json``) — same
-    label, both views."""
+    ``prof`` flag). On the device it only labels the traced HLO. On the host
+    it is a ``jax.profiler.TraceAnnotation`` (one flag test while no profiler
+    session is on), and when a ``monitor.timeline`` recorder is active the
+    span ALSO lands on that timeline (a ``B``/``E`` pair in the exported
+    ``trace.json``) — same label, all views."""
     if not enabled:
         yield
         return
@@ -57,6 +62,7 @@ def span(name: str, enabled: bool = True):
     with contextlib.ExitStack() as stack:
         if rec is not None:
             stack.enter_context(rec.span(name))
+        stack.enter_context(jax.profiler.TraceAnnotation(name))
         stack.enter_context(jax.named_scope(name))
         yield
 

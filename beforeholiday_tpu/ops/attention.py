@@ -43,6 +43,7 @@ from beforeholiday_tpu.guard.dispatch import (
     checked_impl as _checked_impl,
     count_forced as _count_forced,
 )
+from beforeholiday_tpu.monitor.spans import span as _span
 from beforeholiday_tpu.remat.policies import (
     TAG_ATTN_OUT as _TAG_ATTN_OUT,
     TAG_FLASH_LSE as _TAG_FLASH_LSE,
@@ -663,7 +664,7 @@ def flash_attention(
     q3 = q.reshape(B * H, S, D)
     k3 = k.reshape(B * H, Sk, D)
     v3 = v.reshape(B * H, Sk, D)
-    with jax.named_scope("flash_attention"):  # XProf range (NVTX idiom)
+    with _span("flash_attention"):  # XProf range (NVTX idiom); stays innermost
         if impl == "pallas":
             if dropout_rate > 0.0:
                 seed = _seed_from_key(dropout_key)

@@ -31,6 +31,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from beforeholiday_tpu.guard.dispatch import checked_impl as _checked_impl
+from beforeholiday_tpu.monitor.spans import annotate as _annotate
 from beforeholiday_tpu.remat.policies import TAG_NORM_OUT as _TAG_NORM_OUT
 from beforeholiday_tpu.ops._autocast import float_function
 from beforeholiday_tpu.ops._pallas_util import (
@@ -269,6 +270,7 @@ def _probe_ln_pallas(x2d, w, b, *, eps, rms, out_dtype):
     return y
 
 
+@_annotate("layer_norm")  # the one entry of every public norm; XProf range
 def _norm_impl(x, weight, bias, eps, rms, out_dtype, impl):
     requested = impl
     impl = _resolve_impl(impl)

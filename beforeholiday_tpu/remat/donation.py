@@ -33,6 +33,7 @@ from typing import Any, Callable, Sequence, Tuple, Union
 
 import jax
 
+from beforeholiday_tpu.monitor.spans import span
 from beforeholiday_tpu.utils.logging import warn_once
 
 __all__ = ["donate_optimizer_step", "donate_step"]
@@ -121,23 +122,29 @@ def donate_step(
     jitted = jax.jit(fn, donate_argnums=tuple(donate_argnums), **jit_kwargs)
     entry = getattr(fn, "__name__", type(fn).__name__)
 
+    def warn_undonated(args):
+        for i, arg in enumerate(args):
+            if i not in donated and _contains_arena(arg):
+                warn_once(
+                    (_WARN_PREFIX, entry, i),
+                    "donation: step %r received a PackedParams arena in "
+                    "undonated argument %d — an optimizer arena is step "
+                    "state; pass its index in donate_argnums or XLA keeps "
+                    "two copies live across the step",
+                    entry,
+                    i,
+                )
+
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):
-        if warn_undonated_arena:
-            for i, arg in enumerate(args):
-                if i in donated:
-                    continue
-                if _contains_arena(arg):
-                    warn_once(
-                        (_WARN_PREFIX, entry, i),
-                        "donation: step %r received a PackedParams arena in "
-                        "undonated argument %d — an optimizer arena is step "
-                        "state; pass its index in donate_argnums or XLA keeps "
-                        "two copies live across the step",
-                        entry,
-                        i,
-                    )
-        return jitted(*_dedupe_donated(args, donated), **kwargs)
+        # two host spans, so that a device-idle gap under the caller's
+        # dispatch is told apart: this wrapper's own Python, or the jitted call
+        with span("donate_step.prepare"):
+            if warn_undonated_arena:
+                warn_undonated(args)
+            args = _dedupe_donated(args, donated)
+        with span("donate_step.call"):  # dispatch; the first time, trace + compile
+            return jitted(*args, **kwargs)
 
     wrapper.jitted = jitted
     return wrapper

@@ -28,6 +28,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from beforeholiday_tpu.monitor.spans import annotate as _annotate, span as _span
 from beforeholiday_tpu.parallel.parallel_state import DATA_AXIS, TENSOR_AXIS
 from beforeholiday_tpu.remat import apply as _remat_apply
 from beforeholiday_tpu.remat.policies import TAG_BLOCK as _TAG_BLOCK
@@ -316,19 +317,36 @@ def forward(params: dict, tokens: jax.Array, cfg: GPTConfig,
     from beforeholiday_tpu.transformer.tensor_parallel.random import dropout
 
     B, S = tokens.shape
-    x = params["tok_embed"][tokens] + params["pos_embed"][:S]
-    x = x.astype(cfg.dtype)
-    if dropout_key is not None and cfg.dropout_rate > 0.0:
-        x = dropout(jax.random.fold_in(dropout_key, 0x7FFFFFFF), x, cfg.dropout_rate)
-    x = _constrain(x, _residual_spec(cfg))
+    # one scope per model sub-layer (embed / blocks / head): the device trace
+    # splits forward and backward time by them (jvp(..) / transpose(jvp(..)))
+    with _span("gpt_embed"):
+        x = params["tok_embed"][tokens] + params["pos_embed"][:S]
+        x = x.astype(cfg.dtype)
+        if dropout_key is not None and cfg.dropout_rate > 0.0:
+            x = dropout(
+                jax.random.fold_in(dropout_key, 0x7FFFFFFF), x, cfg.dropout_rate
+            )
+        x = _constrain(x, _residual_spec(cfg))
 
-    aux = _zero_moe_aux()
+    with _span("gpt_blocks"):
+        x, aux = _forward_blocks(params, x, cfg, dropout_key)
+    with _span("gpt_head"):
+        x = _layernorm(x, params["lnf_scale"], params["lnf_bias"])
+        logits = _vocab_head_matmul(x, params["tok_embed"])
+        logits = _constrain(logits, P(DATA_AXIS, None, TENSOR_AXIS))
+    if return_aux:
+        return logits, aux
+    return logits
+
+
+def _forward_blocks(params: dict, x, cfg: GPTConfig, dropout_key):
+    """The layer stack over the residual stream: ``(x, moe aux)``."""
     # cfg.remat_policy wraps the scanned block body: with scan-over-layers the
     # saved-residual stack is L x (per-block residuals), so the block is
     # exactly the granularity Chen/Megatron checkpointing wants
     if cfg.moe_every:
-        x, aux = _forward_moe_stack(params, x, cfg, dropout_key)
-    elif dropout_key is not None:
+        return _forward_moe_stack(params, x, cfg, dropout_key)
+    if dropout_key is not None:
         layer_keys = jax.random.split(dropout_key, cfg.n_layers)
         blk = _remat_apply(
             lambda carry, lp, lk: _block(cfg, carry, lp, dkey=lk),
@@ -349,12 +367,7 @@ def forward(params: dict, tokens: jax.Array, cfg: GPTConfig,
             return blk(carry, lp), None
 
         x, _ = jax.lax.scan(body, x, params["blocks"])
-    x = _layernorm(x, params["lnf_scale"], params["lnf_bias"])
-    logits = _vocab_head_matmul(x, params["tok_embed"])
-    logits = _constrain(logits, P(DATA_AXIS, None, TENSOR_AXIS))
-    if return_aux:
-        return logits, aux
-    return logits
+    return x, _zero_moe_aux()
 
 
 def _forward_moe_stack(params: dict, x, cfg: GPTConfig, dropout_key):
@@ -403,6 +416,7 @@ def _forward_moe_stack(params: dict, x, cfg: GPTConfig, dropout_key):
     return x, {k: aux[k] / G for k in _MOE_AUX_KEYS}
 
 
+@_annotate("gpt_loss")
 def _cross_entropy(logits, targets):
     logz = jax.nn.logsumexp(logits, axis=-1)
     tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]

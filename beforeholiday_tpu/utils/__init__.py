@@ -1,9 +1,8 @@
 """Shared utilities: logging, pytree helpers; timers/profiling live in
-``beforeholiday_tpu.monitor`` now (re-exported here for back-compat)."""
+``beforeholiday_tpu.monitor.spans`` (re-exported here)."""
 
 from beforeholiday_tpu.utils.logging import get_logger, reset_warn_once, warn_once
-from beforeholiday_tpu.utils.profiling import annotate, nvtx_range, trace
-from beforeholiday_tpu.utils.timers import Timers
+from beforeholiday_tpu.monitor.spans import Timers, annotate, nvtx_range, trace
 
 __all__ = [
     "get_logger",

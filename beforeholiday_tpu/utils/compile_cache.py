@@ -27,9 +27,15 @@ def enable_compile_cache() -> str:
     return it.
 
     With ``JAX_COMPILATION_CACHE_DIR`` set, JAX reads the variable itself and
-    nothing is set in code — whoever placed the cache from outside keeps
+    no directory is set in code — whoever placed the cache from outside keeps
     control of it. Otherwise the cache is ``<checkout>/.jax_cache`` (ignored
     by git), the checkout found from this file's own location."""
+    # The key covers the scope names: by default JAX strips them before it
+    # hashes a program, so a program that differs from a cached one only in
+    # its ``monitor.spans`` scopes would be served the cached executable, and
+    # a device trace of it would carry the OLD names (seen on the CPU cache,
+    # PR 24). The per-layer metrics read those names.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get(_ENV)
     if placed:
         return placed

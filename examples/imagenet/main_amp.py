@@ -387,7 +387,7 @@ def main(argv=None):
         overlap_backward=args.overlap_backward,
     )
     print(f"devices: {jax.device_count()}  distributed: {trainer.distributed}")
-    from beforeholiday_tpu.utils.profiling import trace as profile_trace
+    from beforeholiday_tpu.monitor.spans import trace as profile_trace
 
     flight = None
     if args.flight_recorder:

@@ -174,8 +174,10 @@ class TestCompileCacheHelper:
     @pytest.fixture
     def restore_cache_dir(self):
         prev = jax.config.jax_compilation_cache_dir
+        prev_key = jax.config.jax_compilation_cache_include_metadata_in_key
         yield
         jax.config.update("jax_compilation_cache_dir", prev)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key", prev_key)
 
     def test_env_placement_is_left_alone(self, monkeypatch, restore_cache_dir):
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/from/outside")

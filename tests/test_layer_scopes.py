@@ -1,0 +1,276 @@
+"""Layer scopes inside the training step (PR 24).
+
+The device trace is read by the ``monitor.spans`` scope names: the benchmark's
+per-layer metrics (``benchmark/layer_metrics/*.json``) match them in the
+framework name (``tf_op``) of every device op. These tests pin the names on
+the CPU, from the step wired exactly as ``benchmark/families/gpt.py`` wires it
+(one chip, and data parallel over the CPU mesh): every scope is there, forward
+and backward never share a name, every matmul and kernel lies under one of the
+two, ``flash_attention`` stays the innermost scope of the attention calls —
+and the split of ``jax.grad`` that made room for the scopes is the same
+program (bitwise against the ``jax.grad`` oracle).
+
+The names are read from the *compiled* module's ``op_name`` metadata: XLA
+composes the call-site's name with the names inside a called function (the
+scanned block is a ``closed_call``), which is what the profiler shows.
+"""
+
+import contextlib
+import os
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if _REPO not in sys.path:
+    sys.path.insert(0, _REPO)
+
+from beforeholiday_tpu import amp, monitor  # noqa: E402
+from beforeholiday_tpu.amp.scaler import LossScaler  # noqa: E402
+from beforeholiday_tpu.optimizers import FusedAdam  # noqa: E402
+from beforeholiday_tpu.remat import donate_step  # noqa: E402
+from beforeholiday_tpu.testing import gpt  # noqa: E402
+
+_LAYOUTS = {"single": "tiny-gpt.train", "dp": "tiny-gpt.train-dp4"}
+# scope -> the layouts whose step must carry it
+_SCOPES = {
+    "amp_forward": ("single", "dp"), "amp_backward": ("single", "dp"),
+    "amp_unscale": ("single", "dp"),
+    "gpt_embed": ("single", "dp"), "gpt_blocks": ("single", "dp"),
+    "gpt_head": ("single", "dp"), "gpt_loss": ("single", "dp"),
+    "layer_norm": ("single", "dp"), "flash_attention": ("single", "dp"),
+    "fused_adam_step_flat": ("single", "dp"),
+    "ddp_reduce_gradients": ("dp",),
+}
+
+
+def _scopes_of(name):
+    return name.split("/")
+
+
+@pytest.fixture(scope="module")
+def op_names():
+    """``layout -> the distinct op_name of every op of the compiled step``."""
+    from benchmark import run as bench_run
+
+    cache = {}
+
+    def get(layout):
+        if layout not in cache:
+            cell = bench_run.load("workloads", _LAYOUTS[layout])
+            cfg = bench_run.load("configs", cell["config"])
+            if len(jax.devices()) < cell["chips"]:
+                pytest.skip(f"needs {cell['chips']} virtual devices")
+            run = bench_run.Cell(cell, cfg, jax.devices()[:cell["chips"]])
+            run.start(7)
+            run.build()
+            compiled = run.program.step.jitted.lower(run.state, run.pool[0]).compile()
+            # (names outside ``jit(..)`` belong to reducers' sub-computations)
+            cache[layout] = sorted(set(re.findall(r'op_name="(jit\([^"]+)"', compiled.as_text())))
+        return cache[layout]
+
+    return get
+
+
+@pytest.mark.parametrize("layout,scope", [
+    (layout, scope) for scope, layouts in _SCOPES.items() for layout in layouts])
+def test_scope_is_in_the_compiled_step(op_names, layout, scope):
+    assert any(scope in _scopes_of(n) or f"jvp({scope})" in n for n in op_names(layout)), scope
+
+
+def _pass_of(name):
+    """The pass an op runs in, as the metrics decide it: ``amp_backward`` is
+    the ambient scope of the whole pull-back, so it wins. (The ``custom_vjp``
+    backward rule of the un-scanned final LayerNorm is named
+    ``amp_backward/transpose(amp_forward)/jvp(gpt_head)/layer_norm/...``: the
+    forward scope survives, wrapped, in a backward op's name.)"""
+    for scope in ("amp_backward", "amp_forward"):
+        if scope in name:
+            return scope
+    return None
+
+
+@pytest.mark.parametrize("layout", sorted(_LAYOUTS))
+def test_forward_and_backward_never_share_a_name(op_names, layout):
+    both = [n for n in op_names(layout) if "amp_forward" in n and "amp_backward" in n]
+    # only as the wrapped form, and never the other way round
+    assert all("amp_backward/transpose(amp_forward)" in n for n in both), both
+    # the model scopes survive inside both passes
+    for scope in ("gpt_embed", "gpt_blocks", "gpt_head", "gpt_loss"):
+        assert any(f"amp_forward/jvp({scope})" in n for n in op_names(layout)), scope
+        assert any(_pass_of(n) == "amp_backward" and f"jvp({scope})" in n
+                   for n in op_names(layout)), scope
+
+
+@pytest.mark.parametrize("layout", sorted(_LAYOUTS))
+def test_matmuls_and_kernels_lie_under_forward_or_backward(op_names, layout):
+    heavy = [n for n in op_names(layout)
+             if {"dot_general", "pallas_call", "layer_norm"} & set(_scopes_of(n))]
+    assert len(heavy) > 10
+    assert not [n for n in heavy if _pass_of(n) is None]
+    # ... also inside the scanned block, a closed_call under the while body
+    assert any("amp_forward/jvp(gpt_blocks)/while/body/closed_call" in n
+               and n.endswith("dot_general") for n in heavy)
+    assert any("amp_backward/transpose(jvp(gpt_blocks))/while/body/closed_call" in n
+               and n.endswith("dot_general") for n in heavy)
+
+
+@pytest.mark.parametrize("layout", sorted(_LAYOUTS))
+def test_first_level_scopes_partition_the_step(op_names, layout):
+    """No op carries two of the first-level scopes the benchmark's partition
+    (forward / backward / unscale / reduce / optimizer) is read by, but for
+    the wrapped forward scope inside a backward name."""
+    first = ("amp_backward", "amp_unscale", "ddp_reduce_gradients",
+             "ddp_overlap_hook", "fused_adam_step_flat")
+    twice = [n for n in op_names(layout)
+             if sum(s in n for s in first) + (_pass_of(n) == "amp_forward") > 1]
+    assert not twice
+
+
+@pytest.mark.parametrize("impl", ["pallas", "jnp"])
+def test_flash_attention_stays_the_innermost_scope(impl):
+    """The chip's compiler names the flash custom calls after the innermost
+    scope (``%flash_attention.N``, which ``flash_attn_ms`` matches): no new
+    scope may open inside it, in the forward or in the backward pass."""
+    cfg = gpt.GPTConfig(vocab_size=64, seq_len=128, d_model=32, n_heads=2, n_layers=2,
+                        dtype=jnp.bfloat16, attention_impl=impl)
+    params = gpt.init(jax.random.PRNGKey(0), cfg)
+    batch = gpt.synthetic_batch(jax.random.PRNGKey(1), cfg, 2)
+    svag = amp.scaled_value_and_grad(
+        lambda p, tok, tgt: gpt.loss_fn(p, tok, tgt, cfg), LossScaler(loss_scale=1.0))
+    text = jax.jit(svag).lower(params, LossScaler(loss_scale=1.0).init(), *batch).as_text(
+        debug_info=True)
+    inside = [n for n in set(re.findall(r'loc\("([^"]+)"', text)) if "flash_attention/" in n]
+    assert inside
+    ours = set(_SCOPES) - {"flash_attention"}
+    for n in inside:
+        tail = n.split("flash_attention/", 1)[1]
+        assert not ours & set(_scopes_of(tail)), n
+    if impl == "pallas":
+        kernels = [n for n in inside if "pallas_call" in _scopes_of(n)]
+        assert kernels
+        assert all("flash_attention/pallas_call" in n for n in kernels)
+
+
+# ---------------------------------------------------------------------------
+# the split of jax.grad is the same program
+# ---------------------------------------------------------------------------
+
+def _oracle(loss_fn, scaler, *, has_aux, reduce_grads):
+    """``scaled_value_and_grad`` as the parent commit wrote it: one ``jax.grad``."""
+    def wrapped(params, scaler_state, *args):
+        def scaled_loss_fn(p):
+            res = loss_fn(p, *args)
+            loss, aux = res if has_aux else (res, None)
+            return scaler.scale_loss(loss, scaler_state), (loss, aux)
+
+        scale_w, scale_g = scaler.quantized_scales(scaler_state)
+        q_scope, amax = contextlib.nullcontext(), None
+        if scale_w is not None:
+            from beforeholiday_tpu.ops.quantized import quantized_scope
+
+            q_scope = quantized_scope(scale_w, scale_g)
+        with q_scope:
+            grads, (loss, aux) = jax.grad(scaled_loss_fn, has_aux=True)(params)
+        if reduce_grads is not None:
+            grads = reduce_grads(grads)
+        if scale_w is not None:
+            from beforeholiday_tpu.ops.quantized import amax_of_tree
+
+            amax = (amax_of_tree(params), amax_of_tree(grads))
+        grads, found_inf = scaler.unscale(grads, scaler_state)
+        new_state = scaler.update(scaler_state, found_inf, amax=amax)
+        if has_aux:
+            return loss, aux, grads, found_inf, new_state
+        return loss, grads, found_inf, new_state
+
+    return wrapped
+
+
+@pytest.mark.parametrize("level", ["O5", "O6"])
+@pytest.mark.parametrize("reduce", [False, True])
+@pytest.mark.parametrize("has_aux", [False, True])
+def test_scaled_value_and_grad_is_bitwise_the_grad_oracle(level, reduce, has_aux):
+    from beforeholiday_tpu.ops import dense
+
+    rng = np.random.default_rng(3)
+    params = {"w1": jnp.asarray(rng.normal(size=(16, 32)) * 0.3, jnp.float32),
+              "w2": jnp.asarray(rng.normal(size=(32, 8)) * 0.3, jnp.float32)}
+    x = jnp.asarray(rng.normal(size=(12, 16)), jnp.float32)
+    y = jnp.asarray(rng.normal(size=(12, 8)), jnp.float32)
+    m = amp.initialize(
+        lambda p, a: dense.fused_dense(jnp.tanh(dense.fused_dense(a, p["w1"])), p["w2"]),
+        params, FusedAdam(lr=1e-3), level)
+
+    def loss_fn(p, a, t):
+        out = m.apply(p, a)
+        loss = jnp.mean(jnp.square(out - t))
+        return (loss, {"out_norm": jnp.linalg.norm(out)}) if has_aux else loss
+
+    # a reducer that is not the identity, so that its place in the order shows
+    reducer = (lambda g: jax.tree.map(lambda a: a * 0.5, g)) if reduce else None
+    state = m.scaler.init()
+    got = jax.jit(amp.scaled_value_and_grad(
+        loss_fn, m.scaler, has_aux=has_aux, reduce_grads=reducer))(m.params, state, x, y)
+    want = jax.jit(_oracle(
+        loss_fn, m.scaler, has_aux=has_aux, reduce_grads=reducer))(m.params, state, x, y)
+    got_leaves, got_tree = jax.tree.flatten(got)
+    want_leaves, want_tree = jax.tree.flatten(want)
+    assert got_tree == want_tree
+    for a, b in zip(got_leaves, want_leaves):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert np.isfinite(np.asarray(got[0]))
+
+
+# ---------------------------------------------------------------------------
+# host spans
+# ---------------------------------------------------------------------------
+
+def test_donate_step_records_nested_prepare_and_call_spans():
+    step = donate_step(lambda s, x: (s + x, jnp.sum(x)), donate_argnums=(0,))
+    state = jnp.zeros((4,))
+    with monitor.timeline() as rec:
+        with monitor.span("dispatch"):
+            state, _ = step(state, jnp.ones((4,)))
+            state, _ = step(state, jnp.ones((4,)))
+    stack, closed = [], []
+    for ev in rec.events():
+        if ev["ph"] == "B":
+            stack.append(ev["name"])
+        elif ev["ph"] == "E":
+            closed.append((stack.pop(), tuple(stack)))
+    assert not stack                        # every B has its E
+    ours = [c for c in closed if c[0].startswith("donate_step.")]
+    # per call: prepare, then call, both directly inside the caller's span
+    assert ours == [("donate_step.prepare", ("dispatch",)),
+                    ("donate_step.call", ("dispatch",))] * 2
+    np.testing.assert_array_equal(np.asarray(state), 2.0)
+
+
+def test_host_span_does_not_leak_into_the_jitted_step_names():
+    step = donate_step(lambda s, x: (s * 2.0 + x,), donate_argnums=(0,))
+    with monitor.span("donate_step.call"):
+        text = step.jitted.lower(jnp.zeros((4,)), jnp.ones((4,))).as_text(debug_info=True)
+    assert "donate_step" not in text
+
+
+def test_compile_cache_key_covers_the_scope_names():
+    """A cached executable carries the scope names it was compiled with; with
+    the names left out of the key (JAX's default) a re-scoped program would be
+    served the old names, and the per-layer metrics would read those."""
+    from beforeholiday_tpu.utils import compile_cache
+
+    prev_dir = jax.config.jax_compilation_cache_dir
+    prev = jax.config.jax_compilation_cache_include_metadata_in_key
+    try:
+        jax.config.update("jax_compilation_cache_include_metadata_in_key", False)
+        compile_cache.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_include_metadata_in_key is True
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev_dir)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key", prev)

@@ -473,16 +473,14 @@ class TestDispatchCounters:
 
 class TestSpans:
     def test_utils_shims_are_the_same_objects(self):
+        from beforeholiday_tpu import utils
         from beforeholiday_tpu.monitor import spans
-        from beforeholiday_tpu.utils import profiling, timers
 
-        assert timers.Timers is spans.Timers
-        assert timers._Timer is spans._Timer
-        assert profiling.annotate is spans.annotate
-        assert profiling.nvtx_range is spans.nvtx_range
-        assert profiling.trace is spans.trace
-        # package-level back-compat surface
-        from beforeholiday_tpu.utils import Timers, annotate, nvtx_range, trace  # noqa: F401
+        # the package-level surface IS monitor.spans (the shim modules are gone)
+        assert utils.Timers is spans.Timers
+        assert utils.annotate is spans.annotate
+        assert utils.nvtx_range is spans.nvtx_range
+        assert utils.trace is spans.trace
 
     def test_span_and_annotate_work_under_jit(self):
         @jax.jit
