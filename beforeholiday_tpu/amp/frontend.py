@@ -301,8 +301,10 @@ def initialize(
     ``arena_native=True`` (implies ``arena_masters``) stores the cast params
     as :class:`PackedParams` — per-dtype flat HBM arenas. ``AmpModel.apply``
     unpacks transparently (static slices XLA fuses into consumers), so
-    ``jax.grad`` taken at the packed argument returns gradient ARENAS and the
-    master-weight optimizer step runs with ZERO per-step packing — the TPU
+    ``jax.grad`` taken at the packed argument returns gradient ARENAS, packed
+    once per step by ``unpack``'s ``custom_vjp`` (one ``concatenate`` per
+    dtype bucket: what "born flat" costs), and the master-weight optimizer
+    step streams them with no packing of its own — the TPU
     equivalent of the reference's pointer-aliased tensor lists
     (csrc/multi_tensor_apply.cuh never repacks either). Single-device /
     manual-shard_map fast path, like ``arena_masters``.
@@ -416,7 +418,11 @@ def make_apply(
 
     def amp_apply(p, *inputs, **kwinputs):
         if isinstance(p, PackedParams):
-            p = p.unpack()  # static slices — fused into consumers under jit
+            # static slices, fused into consumers under jit; transposed as
+            # ONE pack per arena, not as the slices' own add_any(pad(g0), …),
+            # which one chip recomputed in every consumer of the gradients
+            # (PR 25; PackedParams.unpack)
+            p = p.unpack()
         if has_state:
             model_state, *inputs = inputs
         if policy.patch_torch_functions:
