@@ -1,0 +1,365 @@
+"""Qwen3-Next: gated-DeltaNet linear attention among gated softmax attention,
+every MLP a mixture of experts with a shared expert.
+
+Written from the published configuration and layer equations
+(huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct, ``config.json``; Gated
+DeltaNet: Yang et al. 2024). Bias-free throughout. With
+``rms0(x, w) = x / sqrt(mean(x^2) + eps) * (1 + w)`` (the zero-centred RMSNorm):
+
+* layer ``l``: ``h = x + mixer_l(rms0(x))``; ``y = h + moe(rms0(h))``. The
+  mixer is gated softmax attention where ``(l + 1) % period == 0`` and a gated
+  DeltaNet otherwise; after the last layer ``rms0`` and an untied head.
+* gated DeltaNet: ``[q, k, v, z] = x W_qkvz``, ``[b, a] = x W_ba``; ``q, k, v``
+  through a causal depthwise convolution of width 4 and SiLU; ``q, k``
+  L2-normalised per head, ``q`` scaled by ``d_k^-1/2``, each key head serving
+  ``value_heads / key_heads`` value heads; ``beta = sigmoid(b)``,
+  ``log alpha = -exp(A_log) * softplus(a + dt_bias)``; the gated delta rule
+  (``ops.gated_delta``); ``(w_n * o / rms(o)) * silu(z)`` per head; ``W_out``.
+* gated attention: ``[q, gate] = x W_q`` per head, ``k``, ``v`` on fewer heads
+  (GQA); ``q, k`` through ``rms0`` over the head; rotary embedding on the first
+  ``rotary_dim`` dims of each head (``rotate_half`` layout); causal softmax
+  attention; ``(attn * sigmoid(gate)) W_o``.
+* MoE: ``moe.dropless`` (softmax over all experts, top-k renormalised, the
+  experts this chip holds, a sigmoid-gated shared expert).
+
+Parameters are stacked so that the arena sees a few large leaves: what every
+layer has under ``layers`` ``(L, ...)`` (the held experts ``(L, E_held, ...)``),
+the DeltaNet mixers under ``linear`` ``(L - L/period, ...)``, the attention
+mixers under ``attn`` ``(L/period, ...)``. The layer stack is a ``lax.scan``
+over periods whose body unrolls one period.
+
+GQA goes through ``ops.flash_attention``, which takes equal head counts, by
+repeating each KV head over its query heads (the backward pass sums them).
+
+Not here: the multi-token-prediction module and an auxiliary balancing loss
+(the published ``config.json`` has a key for neither).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+from beforeholiday_tpu.monitor.spans import annotate as _annotate, span as _span
+from beforeholiday_tpu.remat import apply as _remat_apply
+
+_F32 = jnp.float32
+
+
+@dataclasses.dataclass(frozen=True)
+class Qwen3NextConfig:
+    vocab_size: int = 512               # ids held here (a slice of the vocabulary)
+    hidden_size: int = 128
+    num_hidden_layers: int = 4
+    full_attention_interval: int = 4    # the period: its last layer is attention
+    # gated attention
+    num_attention_heads: int = 4
+    num_key_value_heads: int = 2
+    head_dim: int = 64
+    partial_rotary_factor: float = 0.25
+    rope_theta: float = 1e7
+    # gated DeltaNet
+    linear_num_key_heads: int = 2
+    linear_num_value_heads: int = 4
+    linear_key_head_dim: int = 32
+    linear_value_head_dim: int = 32
+    linear_conv_kernel_dim: int = 4
+    # mixture of experts
+    num_experts: int = 16               # the router's width
+    num_experts_held: int = 16          # experts first_expert .. + held live here
+    first_expert: int = 0
+    num_experts_per_tok: int = 4
+    moe_intermediate_size: int = 64
+    shared_expert_intermediate_size: int = 64
+    norm_topk_prob: bool = True
+    moe_rows_bound: Optional[int] = None   # None: the worst case, never overflows
+    rms_norm_eps: float = 1e-6
+    dtype: jnp.dtype = jnp.float32      # activation dtype
+    remat_policy: Optional[str] = None  # over one layer; None = no remat
+    gated_delta_chunk: int = 128
+    attention_impl: Optional[str] = None   # forces the flash dispatch in tests
+
+    @property
+    def periods(self) -> int:
+        if self.num_hidden_layers % self.full_attention_interval:
+            raise ValueError(
+                f"num_hidden_layers {self.num_hidden_layers} is not whole periods "
+                f"of {self.full_attention_interval}")
+        return self.num_hidden_layers // self.full_attention_interval
+
+    @property
+    def rotary_dim(self) -> int:
+        return int(self.head_dim * self.partial_rotary_factor)
+
+
+def param_shapes(cfg: Qwen3NextConfig) -> dict:
+    """``{group: {name: (shape, init)}}``; init is ``std`` (N(0, 0.02)), ``zero``,
+    ``one``, ``conv`` or ``a_log`` (see :func:`init`)."""
+    D, L, P = cfg.hidden_size, cfg.num_hidden_layers, cfg.periods
+    Ll = L - P
+    E, Eh = cfg.num_experts, cfg.num_experts_held
+    F, Fs = cfg.moe_intermediate_size, cfg.shared_expert_intermediate_size
+    H, Hkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    Hk, Hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    conv_channels = 2 * Hk * dk + Hv * dv
+    return {
+        "top": {
+            "embed": ((cfg.vocab_size, D), "std"),
+            "head": ((cfg.vocab_size, D), "std"),
+            "final_norm": ((D,), "zero"),
+        },
+        "layers": {
+            "input_norm": ((L, D), "zero"),
+            "post_norm": ((L, D), "zero"),
+            "router": ((L, D, E), "std"),
+            "w_gate": ((L, Eh, D, F), "std"),
+            "w_up": ((L, Eh, D, F), "std"),
+            "w_down": ((L, Eh, F, D), "std"),
+            "shared_w_gate": ((L, D, Fs), "std"),
+            "shared_w_up": ((L, D, Fs), "std"),
+            "shared_w_down": ((L, Fs, D), "std"),
+            "shared_score": ((L, D, 1), "std"),
+        },
+        "linear": {
+            "w_qkvz": ((Ll, D, conv_channels + Hv * dv), "std"),
+            "w_ba": ((Ll, D, 2 * Hv), "std"),
+            "conv": ((Ll, conv_channels, cfg.linear_conv_kernel_dim), "conv"),
+            "a_log": ((Ll, Hv), "a_log"),
+            "dt_bias": ((Ll, Hv), "one"),
+            "out_norm": ((Ll, dv), "one"),
+            "w_out": ((Ll, Hv * dv, D), "std"),
+        },
+        "attn": {
+            "w_q": ((P, D, H * 2 * hd), "std"),
+            "w_k": ((P, D, Hkv * hd), "std"),
+            "w_v": ((P, D, Hkv * hd), "std"),
+            "q_norm": ((P, hd), "zero"),
+            "k_norm": ((P, hd), "zero"),
+            "w_o": ((P, H * hd, D), "std"),
+        },
+    }
+
+
+def init(key: jax.Array, cfg: Qwen3NextConfig) -> dict:
+    """Seeded float32 parameters: matmul weights N(0, 0.02); the convolution
+    uniform in +-1/sqrt(width) (torch's Conv1d default); ``A_log = log U(0, 16)``
+    and ``dt_bias = 1`` (the published modelling code); norm weights at their
+    identity."""
+    shapes = param_shapes(cfg)
+    out, i = {}, 0
+    for group in sorted(shapes):
+        dst = out if group == "top" else out.setdefault(group, {})
+        for name in sorted(shapes[group]):
+            shape, kind = shapes[group][name]
+            k = jax.random.fold_in(key, i)
+            i += 1
+            if kind == "std":
+                w = jax.random.normal(k, shape, _F32) * 0.02
+            elif kind == "conv":
+                bound = 1.0 / math.sqrt(shape[-1])
+                w = jax.random.uniform(k, shape, _F32, -bound, bound)
+            elif kind == "a_log":
+                w = jnp.log(jax.random.uniform(k, shape, _F32, 1e-3, 16.0))
+            else:
+                w = jnp.full(shape, 0.0 if kind == "zero" else 1.0, _F32)
+            dst[name] = w
+    return out
+
+
+def keep_fp32(path) -> bool:
+    """``amp.initialize(keep_fp32_mask=...)``: the norm weights, and the two
+    per-head scalars of the decay (``A_log`` enters through two exponentials)."""
+    names = [str(getattr(p, "key", getattr(p, "name", p))).lower() for p in path]
+    return any("norm" in n or n in ("a_log", "dt_bias") for n in names)
+
+
+# ---------------------------------------------------------------------------------
+# pieces
+# ---------------------------------------------------------------------------------
+
+
+def rms_norm0(x, w, eps):
+    """Zero-centred RMSNorm: the weight is stored as its offset from one."""
+    from beforeholiday_tpu.ops import fused_rms_norm
+
+    return fused_rms_norm(x, 1.0 + w.astype(_F32), eps=eps)
+
+
+def rope_partial(x, rotary_dim: int, theta: float):
+    """Rotary position embedding on the first ``rotary_dim`` dims of each head
+    (``rotate_half`` layout: dim ``i`` pairs with ``i + rotary_dim / 2``), the
+    rest passed through. ``x``: ``(B, S, H, hd)``; positions ``0 .. S-1``."""
+    half = rotary_dim // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=_F32) * 2.0 / rotary_dim)
+    angle = jnp.arange(x.shape[1], dtype=_F32)[:, None] * inv_freq[None, :]
+    cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
+    x1, x2 = x[..., :half].astype(_F32), x[..., half:rotary_dim].astype(_F32)
+    rotated = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return jnp.concatenate([rotated.astype(x.dtype), x[..., rotary_dim:]], axis=-1)
+
+
+def causal_depthwise_conv(x, w):
+    """``y[t, c] = sum_j w[c, j] * x[t - (K-1) + j, c]``, zeros before the
+    start. ``x``: ``(B, S, C)``, ``w``: ``(C, K)``."""
+    K, S = w.shape[-1], x.shape[1]
+    xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+    w = w.astype(_F32)
+    y = sum(xp[:, j:j + S].astype(_F32) * w[:, j] for j in range(K))
+    return y.astype(x.dtype)
+
+
+def _l2_normalize(x, eps=1e-6):
+    x = x.astype(_F32)
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+@_annotate("linear_mixer")
+def gated_delta_net(cfg: Qwen3NextConfig, x, p):
+    from beforeholiday_tpu.ops import fused_rms_norm
+    from beforeholiday_tpu.ops.gated_delta import gated_delta_rule
+
+    B, S, _ = x.shape
+    Hk, Hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    dt = x.dtype
+    qkvz = x @ p["w_qkvz"].astype(dt)
+    ba = jnp.dot(x, p["w_ba"].astype(dt), preferred_element_type=_F32)
+    n_conv = 2 * Hk * dk + Hv * dv
+    qkv = jax.nn.silu(causal_depthwise_conv(qkvz[..., :n_conv], p["conv"]))
+    z = qkvz[..., n_conv:].reshape(B, S, Hv, dv)
+    q = qkv[..., :Hk * dk].reshape(B, S, Hk, dk)
+    k = qkv[..., Hk * dk:2 * Hk * dk].reshape(B, S, Hk, dk)
+    v = qkv[..., 2 * Hk * dk:].reshape(B, S, Hv, dv)
+    q = (_l2_normalize(q) * dk ** -0.5).astype(dt)
+    k = _l2_normalize(k).astype(dt)
+    if Hv != Hk:                       # each key head serves Hv / Hk value heads
+        q, k = (jnp.repeat(t, Hv // Hk, axis=2) for t in (q, k))
+    beta = jax.nn.sigmoid(ba[..., :Hv])
+    g = -jnp.exp(p["a_log"].astype(_F32)) * jax.nn.softplus(
+        ba[..., Hv:] + p["dt_bias"].astype(_F32))
+    o = gated_delta_rule(q, k, v, g, beta, chunk=cfg.gated_delta_chunk)
+    o = fused_rms_norm(o, p["out_norm"].astype(_F32), eps=cfg.rms_norm_eps)
+    o = (o * jax.nn.silu(z)).reshape(B, S, Hv * dv)
+    return o @ p["w_out"].astype(dt)
+
+
+@_annotate("attn_mixer")
+def gated_attention(cfg: Qwen3NextConfig, x, p):
+    from beforeholiday_tpu.ops import flash_attention
+
+    B, S, _ = x.shape
+    H, Hkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    dt = x.dtype
+    qg = (x @ p["w_q"].astype(dt)).reshape(B, S, H, 2 * hd)
+    q, gate = qg[..., :hd], qg[..., hd:]
+    k = (x @ p["w_k"].astype(dt)).reshape(B, S, Hkv, hd)
+    v = (x @ p["w_v"].astype(dt)).reshape(B, S, Hkv, hd)
+    q = rms_norm0(q, p["q_norm"], cfg.rms_norm_eps)
+    k = rms_norm0(k, p["k_norm"], cfg.rms_norm_eps)
+    q = rope_partial(q, cfg.rotary_dim, cfg.rope_theta)
+    k = rope_partial(k, cfg.rotary_dim, cfg.rope_theta)
+    if H != Hkv:                       # GQA by repetition (module docstring)
+        k, v = (jnp.repeat(t, H // Hkv, axis=2) for t in (k, v))
+    heads_first = lambda t: t.transpose(0, 2, 1, 3)
+    ctx = flash_attention(
+        heads_first(q), heads_first(k), heads_first(v), causal=True,
+        scale=hd ** -0.5, impl=cfg.attention_impl)
+    ctx = heads_first(ctx) * jax.nn.sigmoid(gate.astype(_F32)).astype(dt)
+    return ctx.reshape(B, S, H * hd) @ p["w_o"].astype(dt)
+
+
+def _layer(cfg: Qwen3NextConfig, x, lp, mixer, mp):
+    """One decoder layer: ``(x, counters)``."""
+    from beforeholiday_tpu.moe.dropless import dropless_moe
+
+    B, S, D = x.shape
+    x = x + mixer(cfg, rms_norm0(x, lp["input_norm"], cfg.rms_norm_eps), mp)
+    h = rms_norm0(x, lp["post_norm"], cfg.rms_norm_eps)
+    y, counters = dropless_moe(
+        h.reshape(B * S, D), lp, top_k=cfg.num_experts_per_tok,
+        first_expert=cfg.first_expert, rows_bound=cfg.moe_rows_bound,
+        renormalize=cfg.norm_topk_prob)
+    return x + y.reshape(B, S, D), counters
+
+
+COUNTERS = ("expert_rows", "expert_load_max_over_mean", "dropped_rows")
+
+
+def forward(params: dict, tokens: jax.Array, cfg: Qwen3NextConfig):
+    """``tokens (B, S) int32 -> (logits (B, S, V) float32, counters)``.
+    ``counters``: per step, over the layers: ``expert_rows`` (sum),
+    ``expert_load_max_over_mean`` (max), ``dropped_rows`` (sum)."""
+    P, per = cfg.periods, cfg.full_attention_interval
+    with _span("qwen3n_embed"):
+        x = params["embed"][tokens].astype(cfg.dtype)
+
+    by_period = lambda tree, n: jax.tree.map(
+        lambda a: a.reshape(P, n, *a.shape[1:]), tree)
+    linear = _remat_apply(
+        lambda x, lp, mp: _layer(cfg, x, lp, gated_delta_net, mp), cfg.remat_policy)
+    attn = _remat_apply(
+        lambda x, lp, mp: _layer(cfg, x, lp, gated_attention, mp), cfg.remat_policy)
+
+    def unstack(tree, n):
+        # lax.split: its gradient is one concatenation; that of ``a[i]`` is a
+        # zero-padded copy of the whole stack for every ``i``
+        parts = jax.tree.map(lambda a: jax.lax.split(a, [1] * n, axis=0), tree)
+        return [jax.tree.map(lambda p: p[i][0], parts,
+                             is_leaf=lambda p: isinstance(p, (list, tuple)))
+                for i in range(n)]
+
+    def period(x, xs):
+        layers, lin, att = xs
+        layers, lin = unstack(layers, per), unstack(lin, per - 1)
+        seen = []
+        for i in range(per - 1):
+            x, c = linear(x, layers[i], lin[i])
+            seen.append(c)
+        x, c = attn(x, layers[per - 1], att)
+        seen.append(c)
+        return x, jax.tree.map(lambda *v: jnp.stack(v), *seen)
+
+    with _span("qwen3n_layers"):
+        x, seen = jax.lax.scan(period, x, (
+            by_period(params["layers"], per), by_period(params["linear"], per - 1),
+            params["attn"]))
+    counters = {
+        "expert_rows": jnp.sum(seen["expert_rows"]),
+        "expert_load_max_over_mean": jnp.max(seen["expert_load_max_over_mean"]),
+        "dropped_rows": jnp.sum(seen["dropped_rows"]),
+    }
+    with _span("qwen3n_head"):
+        x = rms_norm0(x, params["final_norm"], cfg.rms_norm_eps)
+        logits = jax.lax.dot_general(
+            x, params["head"].astype(x.dtype), (((2,), (1,)), ((), ())),
+            preferred_element_type=_F32)
+    return logits, counters
+
+
+@_annotate("qwen3n_loss")
+def cross_entropy(logits, targets):
+    logz = jax.nn.logsumexp(logits.astype(_F32), axis=-1)
+    picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    return jnp.mean(logz - picked.astype(_F32))
+
+
+def loss_fn(params: dict, tokens: jax.Array, targets: jax.Array,
+            cfg: Qwen3NextConfig, forward_fn=None):
+    """``(mean next-token cross entropy over the vocabulary held, counters)``.
+    ``forward_fn(params, tokens)`` overrides the plain forward (an amp-wrapped
+    apply), as in ``testing/gpt.loss_fn``."""
+    if forward_fn is None:
+        logits, counters = forward(params, tokens, cfg)
+    else:
+        logits, counters = forward_fn(params, tokens)
+    return cross_entropy(logits, targets), counters
+
+
+def param_count(cfg: Qwen3NextConfig) -> int:
+    return sum(math.prod(shape) for group in param_shapes(cfg).values()
+               for shape, _ in group.values())

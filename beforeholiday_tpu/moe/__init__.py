@@ -8,13 +8,20 @@ expert-parallel dispatch/combine over the ledgered ``all_to_all`` on the
 ``make_moe_mesh(data, tensor, pipeline, expert)`` carve, and with the
 ``("slice", "intra")`` hierarchy for multi-slice routing. See PAPERS.md
 (GShard, Switch Transformer) and the README's **Mixture-of-Experts**
-section.
+section. ``moe.dropless`` is the other recipe: top-k of many narrow experts,
+no capacity and no dropped token, rows sorted by expert into a grouped
+matmul, the layer told which experts it holds.
 """
 
 from beforeholiday_tpu.moe.dispatch import (
     dense_oracle,
     expert_all_to_all,
     moe_layer,
+)
+from beforeholiday_tpu.moe.dropless import (
+    dropless_experts,
+    dropless_moe,
+    route_topk,
 )
 from beforeholiday_tpu.moe.experts import (
     expert_ffn,
@@ -34,11 +41,14 @@ __all__ = [
     "RouterDecision",
     "dense_gates",
     "dense_oracle",
+    "dropless_experts",
+    "dropless_moe",
     "expert_all_to_all",
     "expert_ffn",
     "expert_param_specs",
     "init_experts",
     "moe_layer",
     "route",
+    "route_topk",
     "router_logits",
 ]

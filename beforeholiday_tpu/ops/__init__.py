@@ -10,6 +10,8 @@
 * ``dense`` — fused dense / GELU-epilogue dense / whole-MLP chains
   (``fused_dense_cuda``, ``mlp_cuda``) — XLA-epilogue-fused by construction.
 * ``attention`` — Pallas flash attention (``fmhalib``, ``fast_multihead_attn``).
+* ``gated_delta`` — the gated delta rule of Gated DeltaNet linear attention,
+  chunk-wise, with a Pallas chunk scan (no reference equivalent).
 * ``quantized`` — fp8-style quantized matmul with per-tensor delayed scaling
   (the O6 tier; no reference equivalent — Transformer-Engine-shaped departure).
 """
@@ -58,6 +60,7 @@ from .attention import (  # noqa: F401
     is_flash_available,
     self_attention,
 )
+from .gated_delta import gated_delta_rule  # noqa: F401
 from .quantized import (  # noqa: F401
     quantized_matmul,
     quantized_matmul_error_bound,

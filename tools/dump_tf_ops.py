@@ -1,0 +1,66 @@
+#!/usr/bin/env python
+"""Run one benchmark cell traced and write what its per-layer metrics are read
+from: every distinct framework name (``tf_op``: the ``monitor.spans`` scope
+path) of chip 0's device ops with its self time, and every distinct HLO name
+stem (``%flash_attention``, ``%fusion``, ...) with its self time.
+
+    python tools/dump_tf_ops.py --workload <cell> --seed <n> --out chiprun_out/<cell>.json
+
+The file has the form of ``benchmark/tests/fixtures/tf_ops/*.json`` (PR 24);
+``benchmark/tests`` check the metric patterns against such files. Chip only:
+it goes through ``benchmark/run.py``, which refuses any other backend for a
+real cell."""
+
+import argparse
+import collections
+import json
+import os
+import re
+import sys
+
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--out", required=True)
+    args = ap.parse_args(argv)
+
+    import jax
+
+    from benchmark import run, trace_reduce
+
+    kept, load = {}, trace_reduce.load
+
+    def keeping(*a, **kw):
+        kept["trace"] = load(*a, **kw)
+        return kept["trace"]
+
+    trace_reduce.load = keeping
+    rc = run.main(["--workload", args.workload, "--seed", str(args.seed),
+                   "--seconds", "1", "--trace", "1"])
+    if rc or "trace" not in kept:
+        return rc or 1
+    t = kept["trace"]
+    by_scope, by_name = collections.Counter(), collections.Counter()
+    for op in t.chips[0]["ops"]:
+        by_scope[str(op.stats.get("tf_op", ""))] += op.self_ps
+        by_name[re.sub(r"[.\d]+$", "", op.name)] += op.self_ps
+    start, end = t.window(0)
+    cell = run.load("workloads", args.workload)
+    out = {"cell": args.workload, "seed": args.seed, "steps": 2 * cell["pool"],
+           "device_kind": jax.devices()[0].device_kind,
+           "from": "tools/dump_tf_ops.py on the chip: chip 0, every distinct tf_op of the "
+                   "XLA Ops line with its self time in ps; hlo_names: the same by HLO name stem",
+           "busy_ps": trace_reduce.length(t.busy(0)), "window_ps": end - start,
+           "ops": sorted(by_scope.items()), "hlo_names": sorted(by_name.items())}
+    os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+    with open(args.out, "w") as f:
+        json.dump(out, f)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
