@@ -63,6 +63,14 @@ _COUNTERS: Dict[Tuple, Dict[str, int]] = {}
 _WARNED_KEYS: Set[Tuple] = set()
 
 
+# (op, kernel, statics) -> {"traces", "total", "live", "masked"}: the tile plan
+# a kernel was built with, booked when the kernel is traced (the plan is
+# static per call). Beside the dispatch counters: those say WHICH
+# implementation a key took, these how much of the score square that
+# implementation computes for it. Guarded by _VERDICTS_LOCK.
+_TILES: Dict[Tuple, Dict[str, int]] = {}
+
+
 def _count(key: Tuple, outcome: str, probed: bool = False) -> None:
     # caller holds _VERDICTS_LOCK
     c = _COUNTERS.setdefault(key, {"pallas": 0, "jnp": 0, "probes": 0})
@@ -81,6 +89,27 @@ def dispatch_counters() -> Dict[Tuple, Dict[str, int]]:
 def reset_dispatch_counters() -> None:
     with _VERDICTS_LOCK:
         _COUNTERS.clear()
+        _TILES.clear()
+
+
+def count_tiles(op_name: str, kernel: str, statics: Tuple, *, total: int,
+                live: int, masked: int) -> None:
+    """Book one trace of ``op_name``'s ``kernel`` for the static key
+    ``statics``: tiles in the score square, tiles the kernel computes, tiles
+    it computes through a mask. ``live < total`` is a causal plan skipping the
+    tiles above the diagonal; ``masked < live`` is tiles taking the mask-free
+    path. Telemetry only, like :func:`count_forced`."""
+    with _VERDICTS_LOCK:
+        row = _TILES.setdefault((op_name, kernel, tuple(statics)), {"traces": 0})
+        row["traces"] += 1
+        row.update(total=total, live=live, masked=masked)
+
+
+def tile_counters() -> Dict[Tuple, Dict[str, int]]:
+    """Snapshot per ``(op, kernel, statics)``: ``{"traces", "total", "live",
+    "masked"}`` — tile counts of one head of one call."""
+    with _VERDICTS_LOCK:
+        return {k: dict(v) for k, v in _TILES.items()}
 
 
 class InjectedProbeFailure(RuntimeError):

@@ -12,7 +12,8 @@ Split by which side of the device boundary each piece lives on:
   timers; a span names the device ops it encloses and marks the
   profiler's host line.
 * :mod:`beforeholiday_tpu.monitor.counters` — queryable guard-dispatch
-  hit/degrade counters.
+  hit/degrade counters, and the tile plan each traced flash kernel was
+  built with (``tile_records``).
 * :mod:`beforeholiday_tpu.monitor.comms`    — trace-time collective-traffic
   ledger (op kind / axis / dtype / bytes / call-site, subsystem rollup).
 * :mod:`beforeholiday_tpu.monitor.trace`    — host timeline recorder +
@@ -62,6 +63,7 @@ from beforeholiday_tpu.monitor.counters import (  # noqa: F401
     dispatch_summary,
     reset_counters,
     reset_dispatch_counters,
+    tile_records,
 )
 from beforeholiday_tpu.monitor.comms import (  # noqa: F401
     comms_records,
@@ -165,6 +167,7 @@ __all__ = [
     "start_trace",
     "stop_trace",
     "straggler_report",
+    "tile_records",
     "timeline",
     "trace",
     "track_compiles",

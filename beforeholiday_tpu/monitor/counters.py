@@ -8,6 +8,9 @@ to the jnp oracle, and was a probe actually built? The raw counts live in
 operators — per-key rows plus an op-level rollup suitable for a bench JSON
 line or a health dashboard.
 
+``tile_records`` shows, per traced flash kernel, how much of the score square
+its tile plan computes and how much of that goes through a mask.
+
 Imports of ``guard.dispatch`` are deferred into the functions: the package
 import chain (utils → monitor.spans → monitor/__init__ → here) must not
 re-enter ``guard`` mid-import.
@@ -23,6 +26,7 @@ __all__ = [
     "dispatch_summary",
     "reset_counters",
     "reset_dispatch_counters",
+    "tile_records",
 ]
 
 
@@ -80,6 +84,24 @@ def dispatch_records() -> List[Dict[str, object]]:
             for key, c in _dispatch.dispatch_counters().items()
         ),
         key=lambda r: (r["op"], r["key"]),
+    )
+
+
+def tile_records() -> List[Dict[str, object]]:
+    """Per traced kernel JSON-ready rows, beside :func:`dispatch_records`:
+    ``{"op", "kernel", "key", "traces", "total", "live", "masked"}`` — the
+    tile plan the kernel was built with (``guard.dispatch.count_tiles``).
+    ``live < total``: tiles above the causal diagonal are not computed;
+    ``masked < live``: tiles wholly below it take the mask-free path. A call
+    whose ``live == total == masked`` did not engage either."""
+    from beforeholiday_tpu.guard import dispatch as _dispatch
+
+    return sorted(
+        (
+            {"op": op, "kernel": kernel, "key": repr(statics), **counts}
+            for (op, kernel, statics), counts in _dispatch.tile_counters().items()
+        ),
+        key=lambda r: (r["op"], r["key"], r["kernel"]),
     )
 
 

@@ -16,6 +16,15 @@ from the saved (q, k, v, lse) — the same rematerialization trade the
 reference's backward kernels make, shaped for the MXU: every inner op is a
 (BQ, D) x (D, BK)-style matmul, fp32 accumulation.
 
+A call is scheduled on two levels (:class:`TilePlan`), both a function of
+``(Sq, Sk, D, causal)`` alone. The grid hands the kernels large *blocks*
+(:func:`_block_size`: few grid steps, few copies); inside a block that the
+causal diagonal crosses the kernels walk *tiles*, so that the triangle above
+the diagonal is not computed and only the tiles on it build a mask — at
+S = 1024 a head is ONE block, and all of the skipping happens inside it. A
+non-causal call is one tile a block. ``guard.dispatch.count_tiles`` books what
+each traced kernel's plan computes (``monitor.tile_records()``).
+
 Variable-length batches are expressed as per-sequence key lengths
 (``kv_lens``) rather than the reference's packed cu_seqlens: on TPU the
 padded-dense layout keeps shapes static for XLA while the kernel masks
@@ -31,10 +40,11 @@ GSPMD-partitionable) elsewhere; plus a shape gate like the reference's
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -42,6 +52,7 @@ from jax.experimental.pallas import tpu as pltpu
 from beforeholiday_tpu.guard.dispatch import (
     checked_impl as _checked_impl,
     count_forced as _count_forced,
+    count_tiles as _count_tiles,
 )
 from beforeholiday_tpu.monitor.spans import span as _span
 from beforeholiday_tpu.remat.policies import (
@@ -57,22 +68,130 @@ from beforeholiday_tpu.ops._pallas_util import (
 _NEG = -1e30  # mask fill; large-negative (not -inf) keeps exp/max NaN-free
 
 _MIN_BLOCK = 128
+# Strips a block on the causal diagonal is cut into (_tile_plan). Measured on a
+# v5e at the GPT cells' call (64 heads, S=1024, D=64, bf16; fwd + dq + dkv device
+# ms, and the seconds the chip's host took to trace the three kernels once;
+# PR 28): one masked block 1.022 ms / 0.139 s; 2 strips 0.721 / 0.082; 4 strips
+# 0.623 / 0.147; 8 strips 0.571 / 0.267. A kernel's body is traced three to
+# seven times at every start of a program and inside the data-parallel step's
+# backward each traced operation cost ~6x what it costs alone, so the body's
+# size is set-up time: 4 keeps it what one masked block cost.
+_DIAG_STRIPS = 4
 
 
 def _block_size(seq_len: int, head_dim: int = 64) -> int:
-    """Largest block (query rows == key cols) that tiles the sequence.
+    """Largest GRID block (query rows == key cols) that tiles the sequence —
+    the outer of the two levels of :class:`TilePlan`.
 
     Bigger blocks amortize per-grid-step overhead and give the MXU larger
-    matmuls: at S=8192/D=64 the causal forward measured 30.0 ms with
-    1024-blocks vs 31.4 (512) vs 43.8 (256) on a v5e. 1024 is allowed only
-    for head_dim <= 128 — the dkv backward holds ~6 operand blocks plus two
-    (bk, D) fp32 scratch accumulators and (bq, bk) fp32 intermediates, which
-    at D > 128 would push past the ~16 MB VMEM budget."""
+    matmuls: at S=8192/D=64 (32 heads, where a head is 8 x 8 blocks of 1024)
+    the causal forward measured 30.0 ms with 1024-blocks vs 31.4 (512) vs
+    43.8 (256) on a v5e. 1024 is allowed only for head_dim <= 128 — the dkv
+    backward holds ~6 operand blocks plus two (bk, D) fp32 scratch
+    accumulators and (bq, bk) fp32 intermediates, which at D > 128 would push
+    past the ~16 MB VMEM budget. The block is NOT what decides how much of the
+    causal triangle is skipped: at S=1024 one head is a single block, and the
+    skipping happens inside it, tile by tile (:func:`_tile_plan`)."""
     ladder = (1024, 512, 256) if head_dim <= 128 else (512, 256)
     for cand in ladder:
         if seq_len % cand == 0:
             return cand
     return _MIN_BLOCK
+
+
+class TilePlan(NamedTuple):
+    """The two-level schedule of one flash call, shared by the forward, dq and
+    dkv kernels so that all three agree on which tile is which.
+
+    The *block* (``bq`` x ``bk``) is what the grid hands a kernel: large, so
+    that a head costs few grid steps and few copies. Inside a block the
+    kernels walk *tiles* (``tq`` x ``tk``). Off the causal path a block is
+    one tile — the kernel body of a non-causal call. On it (blocks are square
+    there) a block above the diagonal is skipped, a block below it is one
+    unmasked piece, and a block the diagonal crosses is walked strip by strip
+    (:meth:`walk`): the run of tiles wholly below the diagonal is one piece
+    with no mask arithmetic, the tile on it is the one piece that builds the
+    iota mask, the tiles above it are not computed. What is left out is
+    exactly what the mask zeroes, so the results are those of the whole
+    masked block, bit for bit. The walk is static and short (``_DIAG_STRIPS``
+    strips, two score pieces each, every other product once per strip), so
+    the kernel body stays small: it is traced and lowered at every start of a
+    program (PR 28: set-up time is a budget). Tile ids (dropout re-seeds the
+    PRNG per tile) count the tiles of the whole score square row-major."""
+
+    sq: int
+    sk: int
+    bq: int
+    bk: int
+    tq: int
+    tk: int
+    causal: bool
+
+    @property
+    def nq(self) -> int:
+        return self.sq // self.bq
+
+    @property
+    def nk(self) -> int:
+        return self.sk // self.bk
+
+    @property
+    def one_pass(self) -> bool:
+        """A causal call whose block holds a whole row of the square (one
+        block a head): nothing carries over between grid steps, so a strip
+        goes from its scores to its result in one pass and the kernels leave
+        the VMEM accumulators alone — no init, no rescale, no final copy.
+        (A non-causal call keeps the body it had.)"""
+        return self.causal and self.nq == 1
+
+    def walk(self, by_cols: bool, diag: bool):
+        """Static walk of one block: ``[(fixed, [(moving, on_diag), ...])]``,
+        spans as block-local slices. ``fixed`` runs along the kernel's own
+        axis (query rows for fwd/dq, key columns for dkv: ``by_cols``) and
+        ``moving`` along the other; ``on_diag`` marks the tile the diagonal
+        crosses. ``diag=False`` is the whole block in one piece."""
+        outer, inner = (self.bk, self.bq) if by_cols else (self.bq, self.bk)
+        if not diag:
+            return [(slice(0, outer), [(slice(0, inner), False)])]
+        t = self.tq
+        strips = []
+        for at in range(0, outer, t):
+            on = (slice(at, at + t), True)
+            if by_cols:  # the tile on the diagonal, then every row below it
+                pieces = [on, (slice(at + t, inner), False)]
+            else:        # every key left of the diagonal, then the tile on it
+                pieces = [(slice(0, at), False), on]
+            strips.append((slice(at, at + t),
+                           [p for p in pieces if p[0].stop > p[0].start]))
+        return strips
+
+    def counts(self, has_lens: bool) -> Dict[str, int]:
+        """Tiles of the score square: ``total``, ``live`` (computed) and
+        ``masked`` (computed through a mask). The same for all three kernels.
+        With ``kv_lens`` a key length can fall inside any tile, so every
+        computed tile takes the length test."""
+        total = (self.sq // self.tq) * (self.sk // self.tk)
+        if not self.causal:
+            return {"total": total, "live": total, "masked": total}
+        n = self.sq // self.tq
+        live = n * (n + 1) // 2
+        return {"total": total, "live": live, "masked": live if has_lens else n}
+
+
+@functools.lru_cache(maxsize=None)
+def _tile_plan(sq: int, sk: int, head_dim: int, causal: bool) -> TilePlan:
+    """The schedule of a call from what it can observe; no knob.
+
+    Blocks are :func:`_block_size`'s. A causal call's blocks are square
+    (``sq == sk``) and the ones on the diagonal are walked in ``_DIAG_STRIPS``
+    strips of square tiles, never smaller than the 128-lane minimum."""
+    bq, bk = _block_size(sq, head_dim), _block_size(sk, head_dim)
+    if not causal:
+        return TilePlan(sq, sk, bq, bk, bq, bk, False)
+    if sq != sk:
+        raise ValueError(f"causal attention needs matching q/k lengths, got {sq} vs {sk}")
+    t = max(_MIN_BLOCK, bq // _DIAG_STRIPS)
+    return TilePlan(sq, sk, bq, bk, t, t, True)
 
 
 # Above this many bytes of materialized (BH, S, Sk) fp32 scores the jnp
@@ -116,27 +235,65 @@ def is_flash_available(seq_len: int, head_dim: int) -> bool:
 # ---------------------------------------------------------------------------------
 
 
-def _mask(causal, i, j, lens, shape, bq, bk):
-    """Additive-mask predicate for score block (i, j). True = masked out.
-    ``lens`` is a scalar int32 (this sequence's key length)."""
-    kj = j * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    masked = kj >= lens
-    if causal:
-        qi = i * bq + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-        masked |= kj > qi
+# The walk's pieces are written with ``jax.lax`` primitives, not ``jnp``: every
+# ``jnp`` function and array operator is a nested ``jit`` whose trace costs
+# several primitive binds, a kernel body is traced again at every start of a
+# program (three to seven times a step, PR 28), and a walk repeats its piece's
+# operations once per piece. ``lax`` broadcasts a size-1 dimension as ``jnp``
+# does; the helpers below are what ``keepdims`` and ``x[:, 0:1]`` expand to.
+
+
+def _col(x):
+    """(rows, 128) lane-replicated per-row values -> their first lane, (rows, 1)."""
+    return lax.slice_in_dim(x, 0, 1, axis=1)
+
+
+def _row_reduce(reduce, x):
+    """``reduce`` over the columns of ``x``, kept as (rows, 1)."""
+    return lax.broadcast_in_dim(reduce(x, (1,)), (x.shape[0], 1), (0,))
+
+
+def _dot(a, b, contract):
+    """MXU product in the operands' dtype (bf16 on the native path) with fp32
+    accumulation — casting up first would force the slow multi-pass fp32 mode.
+    ``contract`` = (dimension of a, dimension of b)."""
+    return lax.dot_general(a, b, (((contract[0],), (contract[1],)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _at(block, size, offset):
+    """Index ``offset`` into block ``block`` of ``size`` (a static 0 adds no op)."""
+    at = lax.mul(block, size)
+    return lax.add(at, offset) if offset else at
+
+
+def _mask(plan, on_diag, i, j, rows, cols, lens):
+    """Mask predicate of the score piece ``rows`` x ``cols`` of block (i, j).
+    True = masked out; None where nothing in the piece can be. ``lens`` is a
+    scalar int32 (this sequence's key length), or None when the call has no
+    ``kv_lens``: every key is then in range, statically."""
+    if lens is None and not on_diag:
+        return None
+    shape = (rows.stop - rows.start, cols.stop - cols.start)
+    kj = lax.add(lax.broadcasted_iota(jnp.int32, shape, 1), _at(j, plan.bk, cols.start))
+    masked = None if lens is None else lax.ge(kj, lens)
+    if on_diag:
+        qi = lax.add(lax.broadcasted_iota(jnp.int32, shape, 0), _at(i, plan.bq, rows.start))
+        over = lax.gt(kj, qi)
+        masked = over if masked is None else lax.bitwise_or(masked, over)
     return masked
 
 
 def _keep_mask(seed_ref, b, i, j, nq, nk, shape, keep_prob):
-    """In-kernel dropout keep-mask for score block (b, i, j) — the TPU
+    """In-kernel dropout keep-mask for score tile (b, i, j) — the TPU
     counterpart of the reference's curand path in its fused kernels
     (ref: apex/contrib/csrc/multihead_attn/dropout.cuh:1-272, consumed by
     every *_func variant, self_multihead_attn_func.py:148-186).
 
-    The PRNG is RE-SEEDED per (batch*head, q-block, k-block) from the caller's
-    seed plus a mixed block id, then one (BQ, BK) draw is taken — so the
+    The PRNG is RE-SEEDED per (batch*head, q-tile, k-tile) from the caller's
+    seed plus a mixed tile id, then one (TQ, TK) draw is taken — so the
     forward and BOTH backward kernels regenerate the exact same mask for a
-    block regardless of their different grid orders, the same
+    tile regardless of their different grid orders and walks, the same
     counter-per-block contract as Philox offsets in the reference."""
     block_id = (b * nq + i) * nk + j
     # Knuth multiplicative mix: adjacent block ids land far apart in seed
@@ -149,87 +306,214 @@ def _keep_mask(seed_ref, b, i, j, nq, nk, shape, keep_prob):
     return u < keep_prob
 
 
-def _fa_fwd_kernel(causal, scale, nq, nk, bq, bk, rate, *refs):
-    if rate > 0.0:
-        (lens_ref, seed_ref, q_ref, k_ref, v_ref,
-         o_ref, lse_ref, acc_ref, m_ref, l_ref) = refs
-    else:
-        (lens_ref, q_ref, k_ref, v_ref,
-         o_ref, lse_ref, acc_ref, m_ref, l_ref) = refs
-        seed_ref = None
-    b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    seq_len = lens_ref[b]
+def _keep_tiles(plan, seed_ref, b, ti0, tj0, shape, keep_prob):
+    """Keep-mask of a panel of ``shape`` whose first tile is (ti0, tj0) of the
+    square: the panel's tiles drawn one by one under their own ids, so a
+    tile's mask does not depend on which panel a kernel computes it in."""
+    tq, tk = plan.tq, plan.tk
+    rows = [
+        [_keep_mask(seed_ref, b, ti0 + r, tj0 + c, plan.sq // tq, plan.sk // tk,
+                    (tq, tk), keep_prob) for c in range(shape[1] // tk)]
+        for r in range(shape[0] // tq)
+    ]
+    if len(rows) == 1 and len(rows[0]) == 1:
+        return rows[0][0]
+    return jnp.concatenate([jnp.concatenate(r, axis=1) for r in rows], axis=0)
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    live = (j * bk <= i * bq + (bq - 1)) if causal else (j >= 0)
+class _Panel(NamedTuple):
+    """One strip of a block's walk as a kernel sees it: ``rows`` x ``cols`` of
+    the block, one of them the strip itself and the other the run of its
+    pieces. The scores are computed piece by piece (``pieces``: row slice,
+    column slice, mask or None), so that only the piece on the diagonal pays
+    for a mask, and joined along ``axis``; from there on the panel is one
+    array and takes one matmul per product. ``masked`` is the panel's whole
+    mask where a row of it may have no live key (a call with ``kv_lens``) and
+    its probabilities need the explicit zero, else None: causality alone
+    leaves every row its diagonal, so exp underflows to exactly 0 there.
+    ``keep`` is the dropout keep-mask or None."""
 
-    @pl.when(live)
-    def _compute():
-        # matmuls keep the input dtype (bf16 on the MXU's native path) with
-        # fp32 accumulation via preferred_element_type — casting up first
-        # would force the slow multi-pass fp32 MXU mode
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        masked = _mask(causal, i, j, seq_len, s.shape, bq, bk)
-        s = jnp.where(masked, _NEG, s)
-        m_prev = m_ref[...]                      # (BQ, 128) lane-replicated
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        # explicit zero on masked slots: when a whole row is masked s == m_new
-        # == _NEG and exp(s - m) would be 1, not 0
-        p = jnp.where(masked, 0.0, jnp.exp(s - m_new[:, 0:1]))
-        # the softmax normalizer l accumulates the UNDROPPED p: out_i =
-        # (1/l_i) sum_j mask_ij/keep * p_ij v_j == softmax->dropout->matmul
-        # (torch's order, self_multihead_attn_func.py:148-186) — dropping
-        # after normalization, expressed online
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+    rows: slice
+    cols: slice
+    pieces: list
+    axis: int
+    masked: Optional[jax.Array]
+    keep: Optional[jax.Array]
+
+
+def _join(parts, axis):
+    """The pieces of a panel side by side (or stacked) as one array."""
+    return parts[0] if len(parts) == 1 else lax.concatenate(parts, axis)
+
+
+def _panels(plan, by_cols, walk, b, i, j, lens, seed_ref, rate):
+    """The walk of block (i, j) as ``_Panel``s, every mask built."""
+    out = []
+    for fixed, moving in walk:
+        run = slice(moving[0][0].start, moving[-1][0].stop)
+        pieces = []
+        for span, on_diag in moving:
+            rows, cols = (span, fixed) if by_cols else (fixed, span)
+            pieces.append((rows, cols, _mask(plan, on_diag, i, j, rows, cols, lens)))
+        rows, cols = (run, fixed) if by_cols else (fixed, run)
+        axis = 0 if by_cols else 1
+        keep = None
         if rate > 0.0:
-            keep = _keep_mask(seed_ref, b, i, j, nq, nk, p.shape, 1.0 - rate)
-            pd = jnp.where(keep, p * (1.0 / (1.0 - rate)), 0.0)
-        else:
-            pd = p
-        acc_ref[...] = acc_ref[...] * alpha[:, 0:1] + jax.lax.dot_general(
-            pd.astype(v_ref.dtype), v_ref[0],
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-        )
-        m_ref[...] = m_new
+            keep = _keep_tiles(
+                plan, seed_ref, b,
+                _at(i, plan.bq // plan.tq, rows.start // plan.tq),
+                _at(j, plan.bk // plan.tk, cols.start // plan.tk),
+                (rows.stop - rows.start, cols.stop - cols.start), 1.0 - rate)
+        masked = None if lens is None else _join([m for *_, m in pieces], axis)
+        out.append(_Panel(rows, cols, pieces, axis, masked, keep))
+    return out
 
-    @pl.when(j == nk - 1)
-    def _final():
-        l = l_ref[:, 0:1]
-        nonempty = l > 0.0
-        o = jnp.where(nonempty, acc_ref[...] / jnp.where(nonempty, l, 1.0), 0.0)
-        o_ref[0] = o.astype(o_ref.dtype)
-        # lane-replicated (BQ, 128) — the TPU-native layout for per-row
-        # scalars (a (1, BQ) block fails Mosaic's (8, 128) tiling rule)
-        lse_ref[0] = jnp.where(
-            nonempty, m_ref[...] + jnp.log(jnp.where(nonempty, l_ref[...], 1.0)), _NEG
-        )
+
+def _panel_scores(panel, q_ref, k_ref, scale):
+    """Scaled, masked scores of a panel, piece by piece and joined."""
+    parts = []
+    for rows, cols, masked in panel.pieces:
+        s = lax.mul(_dot(q_ref[0, rows, :], k_ref[0, cols, :], (1, 1)), scale)
+        parts.append(s if masked is None else lax.select(masked, lax.full_like(s, _NEG), s))
+    return _join(parts, panel.axis)
+
+
+def _walk_block(plan, by_cols, i, j, block):
+    """Run ``block(walk)`` for grid step (i, j): the diagonal's walk where
+    the causal diagonal crosses the block, the one-piece walk for a block
+    wholly below it and for every block of a non-causal call; nothing for a
+    block above it."""
+    if plan.causal:
+        pl.when(i == j)(lambda: block(plan.walk(by_cols, True)))
+    if not plan.causal or plan.nq > 1:
+        below = (j < i) if plan.causal else (j >= 0)
+        pl.when(below)(lambda: block(plan.walk(by_cols, False)))
+
+
+def _kernel_scalars(refs, has_lens, rate):
+    """(lens ref or None, seed ref or None, the other refs)."""
+    refs = list(refs)
+    lens_ref = refs.pop(0) if has_lens else None
+    seed_ref = refs.pop(0) if rate > 0.0 else None
+    return lens_ref, seed_ref, refs
+
+
+def _fa_fwd_kernel(plan, scale, has_lens, rate, *refs):
+    lens_ref, seed_ref, refs = _kernel_scalars(refs, has_lens, rate)
+    q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
+    b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    lens = lens_ref[b] if has_lens else None
+    one_pass = plan.one_pass
+
+    if not one_pass:
+        @pl.when(j == 0)
+        def _init():
+            m_ref[...] = jnp.full_like(m_ref, _NEG)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def finish(pn, m, l, acc):
+        """A strip's rows of the output and of lse from their final (rows, 1)
+        statistics, as ``_final`` makes them from the accumulators."""
+        if pn.masked is None:        # every row has its diagonal: l > 0
+            o, lse = lax.div(acc, l), lax.add(m, lax.log(l))
+        else:
+            nonempty = lax.gt(l, 0.0)
+            safe = lax.select(nonempty, l, lax.full_like(l, 1.0))
+            o = lax.select(lax.broadcast_in_dim(nonempty, acc.shape, (0, 1)),
+                           lax.div(acc, safe), lax.full_like(acc, 0.0))
+            lse = lax.select(nonempty, lax.add(m, lax.log(safe)), lax.full_like(m, _NEG))
+        o_ref[0, pn.rows, :] = o.astype(o_ref.dtype)
+        lse_ref[0, pn.rows, :] = lax.broadcast_in_dim(lse, (lse.shape[0], 128), (0, 1))
+
+    # A walk is emitted phase by phase, not strip by strip: the strips are
+    # independent, and with each phase's panels side by side the scheduler
+    # overlaps one panel's matmul with another's vector work instead of
+    # waiting out every strip's matmul -> max -> exp -> matmul chain
+    def block(walk):
+        panels = _panels(plan, False, walk, b, i, j, lens, seed_ref, rate)
+        # phase 1 — the scores of every panel
+        scores = [_panel_scores(pn, q_ref, k_ref, scale) for pn in panels]
+        # phase 2 — the (running) max: one cross-lane reduction per strip
+        stats = []
+        for pn, s in zip(panels, scores):
+            m_new = _row_reduce(lax.reduce_max, s)
+            if one_pass:
+                stats.append((m_new, m_new, None))
+            else:
+                m_prev = m_ref[pn.rows, :]           # (rows, 128) lane-replicated
+                m_new = lax.max(m_prev, m_new)
+                stats.append((m_new, _col(m_new), lax.exp(lax.sub(m_prev, m_new))))
+        # phase 3 — probabilities, normalizer and output
+        for pn, s, (m_new, m_col, alpha) in zip(panels, scores, stats):
+            p = lax.exp(lax.sub(s, m_col))
+            if pn.masked is not None:
+                # explicit zero on masked slots: when a whole row is masked
+                # s == m_new == _NEG and exp(s - m) would be 1, not 0
+                p = lax.select(pn.masked, lax.full_like(p, 0.0), p)
+            # the softmax normalizer l sums the UNDROPPED p: out_i = (1/l_i)
+            # sum_j mask_ij/keep * p_ij v_j == softmax->dropout->matmul
+            # (torch's order, self_multihead_attn_func.py:148-186) — dropping
+            # after normalization, expressed online
+            l = _row_reduce(lax.reduce_sum, p)
+            if rate > 0.0:
+                p = lax.select(pn.keep, lax.mul(p, 1.0 / (1.0 - rate)),
+                               lax.full_like(p, 0.0))
+            pv = _dot(p.astype(v_ref.dtype), v_ref[0, pn.cols, :], (1, 0))
+            if one_pass:
+                finish(pn, m_new, l, pv)
+            else:
+                l_ref[pn.rows, :] = lax.add(lax.mul(alpha, l_ref[pn.rows, :]), l)
+                acc_ref[pn.rows, :] = lax.add(lax.mul(acc_ref[pn.rows, :], _col(alpha)), pv)
+                m_ref[pn.rows, :] = m_new
+
+    _walk_block(plan, False, i, j, block)
+
+    if not one_pass:
+        @pl.when(j == plan.nk - 1)
+        def _final():
+            l = l_ref[:, 0:1]
+            nonempty = l > 0.0
+            o = jnp.where(nonempty, acc_ref[...] / jnp.where(nonempty, l, 1.0), 0.0)
+            o_ref[0] = o.astype(o_ref.dtype)
+            # lane-replicated (BQ, 128) — the TPU-native layout for per-row
+            # scalars (a (1, BQ) block fails Mosaic's (8, 128) tiling rule)
+            lse_ref[0] = jnp.where(
+                nonempty, m_ref[...] + jnp.log(jnp.where(nonempty, l_ref[...], 1.0)), _NEG
+            )
+
+
+def _book_tiles(plan, head_dim, has_lens, *kernels):
+    """Book the plan's tile counts once per kernel traced with it."""
+    for kernel in kernels:
+        _count_tiles("flash_attention", kernel,
+                     (plan.sq, plan.sk, head_dim, plan.causal, has_lens),
+                     **plan.counts(has_lens))
+
+
+def _scalar_operands(lens, seed, rate):
+    """Scalar-prefetch operands: the key lengths (when the call has any) and
+    the dropout seed (when active). They ride SMEM (a (1,1)-blocked SMEM
+    operand fails Mosaic's tiling check); index maps receive the scalar refs
+    last — ``*_`` absorbs however many there are."""
+    scalars = [] if lens is None else [lens.astype(jnp.int32)]
+    if rate > 0.0:
+        scalars.append(seed.astype(jnp.int32))
+    return scalars
 
 
 def _fa_fwd_pallas(q, k, v, lens, causal, scale, interpret, rate=0.0, seed=None):
+    """``lens=None``: the call has no ``kv_lens`` — no length test anywhere."""
     BH, Sq, D = q.shape
-    Sk = k.shape[1]
-    bq, bk = _block_size(Sq, D), _block_size(Sk, D)
-    nq, nk = Sq // bq, Sk // bk
-    # lens (and the dropout seed when active) ride scalar-prefetch SMEM (a
-    # (1,1)-blocked SMEM operand fails Mosaic's tiling check); index maps
-    # receive the scalar refs last — *_ absorbs however many there are
+    plan = _tile_plan(Sq, k.shape[1], D, causal)
+    bq, bk = plan.bq, plan.bk
+    _book_tiles(plan, D, lens is not None, "fwd")
     qspec = pl.BlockSpec((1, bq, D), lambda b, i, j, *_: (b, i, 0))
     kspec = pl.BlockSpec((1, bk, D), lambda b, i, j, *_: (b, j, 0))
-    scalars = [lens.astype(jnp.int32)]
-    if rate > 0.0:
-        scalars.append(seed.astype(jnp.int32))
+    scalars = _scalar_operands(lens, seed, rate)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(BH, nq, nk),
+        grid=(BH, plan.nq, plan.nk),
         in_specs=[qspec, kspec, kspec],
         out_specs=[
             qspec,
@@ -242,7 +526,7 @@ def _fa_fwd_pallas(q, k, v, lens, causal, scale, interpret, rate=0.0, seed=None)
         ],
     )
     o, lse = pl.pallas_call(
-        functools.partial(_fa_fwd_kernel, causal, scale, nq, nk, bq, bk, rate),
+        functools.partial(_fa_fwd_kernel, plan, scale, lens is not None, rate),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
@@ -262,149 +546,162 @@ def _fa_fwd_pallas(q, k, v, lens, causal, scale, interpret, rate=0.0, seed=None)
 # ---------------------------------------------------------------------------------
 
 
-def _block_p_ds(causal, scale, b, i, j, lens, q, k, v, do, o, lse, dlse,
-                bq, bk, rate, nq, nk, seed_ref):
-    """Shared recompute: dv-side probabilities z and score-grad ds for block
-    (b, i, j). ``lse``/``dlse``: (BQ, 128) lane-replicated; delta_i =
-    rowsum(dO_i * O_i) is recomputed here from the o/do blocks (cheap VPU
-    work vs another HBM residual). ``dlse`` is the cotangent of the EXPOSED
-    lse output (zero for plain attention; nonzero when the caller merges
-    chunk outputs by lse, as ring attention does — d lse_i/d s_ij = p_ij
-    adds dlse_i inside the parens). Matmuls run in the input dtype with fp32
-    accumulation.
+def _row_delta(do, o):
+    """delta_i = rowsum(dO_i * O_i) as (rows, 1), recomputed from the o/do
+    blocks (cheap VPU work vs another HBM residual)."""
+    return _row_reduce(lax.reduce_sum,
+                       lax.mul(do.astype(jnp.float32), o.astype(jnp.float32)))
+
+
+def _panel_p_ds(scale, s, dp, lse, delta, dlse, panel, rate):
+    """Shared recompute: dv-side probabilities z and score-grad ds of a panel
+    from its scores ``s`` (:func:`_panel_scores`) and ``dp = do . v``.
+    ``lse`` and ``dlse`` are (rows, 128) lane-replicated, ``delta``
+    (:func:`_row_delta`) is (rows, 1). ``dlse`` (or None) is the cotangent of
+    the EXPOSED lse output (zero for plain attention; nonzero when the caller
+    merges chunk outputs by lse, as ring attention does — d lse_i/d s_ij =
+    p_ij adds dlse_i inside the parens).
 
     With dropout (``rate > 0``) the forward computed out_i = sum_j z_ij v_j
     with z = keep/(1-rate) * softmax(s); the same mask regenerates here
-    (:func:`_keep_mask` is deterministic per block). The chain rule gives
+    (:func:`_keep_mask` is deterministic per tile). The chain rule gives
     dp~_ij = (do_i . v_j) * keep_ij/(1-rate), and the softmax-backward
     rowsum term STAYS delta_i = do_i . o_i because
     sum_k dp~_ik p_ik = sum_k (do.v_k) z_ik = do_i . o_i — the undropped
     p carries the Jacobian, the dropped z carries dv."""
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale
-    masked = _mask(causal, i, j, lens, s.shape, bq, bk)
-    p = jnp.where(masked, 0.0, jnp.exp(jnp.where(masked, _NEG, s) - lse[:, 0:1]))
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    p = lax.exp(lax.sub(s, _col(lse)))
+    if panel.masked is not None:  # as in the forward: a row with no live key
+        p = lax.select(panel.masked, lax.full_like(p, 0.0), p)
     if rate > 0.0:
-        keep = _keep_mask(seed_ref, b, i, j, nq, nk, p.shape, 1.0 - rate)
         inv = 1.0 / (1.0 - rate)
-        z = jnp.where(keep, p * inv, 0.0)
-        dp = jnp.where(keep, dp * inv, 0.0)
+        zero = lax.full_like(p, 0.0)
+        z = lax.select(panel.keep, lax.mul(p, inv), zero)
+        dp = lax.select(panel.keep, lax.mul(dp, inv), zero)
     else:
         z = p
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
-                    keepdims=True)
-    extra = dlse[:, 0:1] if dlse is not None else 0.0
-    ds = p * (dp - delta + extra) * scale
-    return z, ds
+    inner = lax.add(lax.sub(dp, delta), _col(dlse) if dlse is not None else 0.0)
+    return z, lax.mul(lax.mul(p, inner), scale)
 
 
-def _fa_dq_kernel(causal, scale, nq, nk, bq, bk, has_dlse, rate, *refs):
-    if rate > 0.0:
-        lens_ref, seed_ref, *refs = refs
-    else:
-        lens_ref, *refs = refs
-        seed_ref = None
+def _bwd_refs(refs, has_dlse):
+    """Split a backward kernel's refs after the scalars: the six operands
+    and dlse (or None), then the outputs and accumulators."""
     q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, *rest = refs
-    if has_dlse:
-        dlse_ref, dq_ref, dq_acc = rest
-    else:
-        dq_ref, dq_acc = rest
-        dlse_ref = None
+    dlse_ref = rest.pop(0) if has_dlse else None
+    return (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref), rest
+
+
+def _fa_dq_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
+    lens_ref, seed_ref, refs = _kernel_scalars(refs, has_lens, rate)
+    (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref), rest = _bwd_refs(
+        refs, has_dlse)
+    dq_ref, dq_acc = rest
     b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    lens = lens_ref[b] if has_lens else None
+    one_pass = plan.one_pass
 
-    @pl.when(j == 0)
-    def _init():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
+    if not one_pass:
+        @pl.when(j == 0)
+        def _init():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    live = (j * bk <= i * bq + (bq - 1)) if causal else (j >= 0)
+    def block(walk):  # phase by phase, as the forward
+        panels = _panels(plan, False, walk, b, i, j, lens, seed_ref, rate)
+        scores = []
+        for pn in panels:
+            do = do_ref[0, pn.rows, :]
+            scores.append((_panel_scores(pn, q_ref, k_ref, scale),
+                           _dot(do, v_ref[0, pn.cols, :], (1, 1)),
+                           _row_delta(do, o_ref[0, pn.rows, :])))
+        grads = [
+            _panel_p_ds(scale, s, dp, lse_ref[0, pn.rows, :], delta,
+                        dlse_ref[0, pn.rows, :] if has_dlse else None, pn, rate)[1]
+            for pn, (s, dp, delta) in zip(panels, scores)]
+        for pn, ds in zip(panels, grads):
+            dq = _dot(ds.astype(k_ref.dtype), k_ref[0, pn.cols, :], (1, 0))
+            if one_pass:
+                dq_ref[0, pn.rows, :] = dq.astype(dq_ref.dtype)
+            else:
+                dq_acc[pn.rows, :] = lax.add(dq_acc[pn.rows, :], dq)
 
-    @pl.when(live)
-    def _compute():
-        _, ds = _block_p_ds(
-            causal, scale, b, i, j, lens_ref[b],
-            q_ref[0], k_ref[0], v_ref[0], do_ref[0], o_ref[0], lse_ref[0],
-            dlse_ref[0] if has_dlse else None, bq, bk, rate, nq, nk, seed_ref,
-        )
-        dq_acc[...] += jax.lax.dot_general(
-            ds.astype(k_ref.dtype), k_ref[0],
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-        )
+    _walk_block(plan, False, i, j, block)
 
-    @pl.when(j == nk - 1)
-    def _final():
-        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+    if not one_pass:
+        @pl.when(j == plan.nk - 1)
+        def _final():
+            dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
-def _fa_dkv_kernel(causal, scale, nq, nk, bq, bk, has_dlse, rate, *refs):
-    if rate > 0.0:
-        lens_ref, seed_ref, *refs = refs
-    else:
-        lens_ref, *refs = refs
-        seed_ref = None
-    q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, *rest = refs
-    if has_dlse:
-        dlse_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
-    else:
-        dk_ref, dv_ref, dk_acc, dv_acc = rest
-        dlse_ref = None
+def _fa_dkv_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
+    lens_ref, seed_ref, refs = _kernel_scalars(refs, has_lens, rate)
+    (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref), rest = _bwd_refs(
+        refs, has_dlse)
+    dk_ref, dv_ref, dk_acc, dv_acc = rest
     # k block outer, q block inner
     b, j, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    lens = lens_ref[b] if has_lens else None
+    one_pass = plan.one_pass
 
-    @pl.when(i == 0)
-    def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
+    if not one_pass:
+        @pl.when(i == 0)
+        def _init():
+            dk_acc[...] = jnp.zeros_like(dk_acc)
+            dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    live = (i * bq + (bq - 1) >= j * bk) if causal else (i >= 0)
+    def block(walk):  # as the dq kernel's; strips are key columns
+        panels = _panels(plan, True, walk, b, i, j, lens, seed_ref, rate)
+        # once for the block: a row's delta serves every strip that reaches it
+        delta = _row_delta(do_ref[0], o_ref[0])
+        scores = []
+        for pn in panels:
+            do = do_ref[0, pn.rows, :]
+            scores.append((_panel_scores(pn, q_ref, k_ref, scale),
+                           _dot(do, v_ref[0, pn.cols, :], (1, 1)), do))
+        grads = [
+            _panel_p_ds(scale, s, dp, lse_ref[0, pn.rows, :],
+                        delta[pn.rows, :],
+                        dlse_ref[0, pn.rows, :] if has_dlse else None, pn, rate)
+            for pn, (s, dp, _) in zip(panels, scores)]
+        for pn, (z, ds), (_, _, do) in zip(panels, grads, scores):
+            q = q_ref[0, pn.rows, :]
+            # dv sees the DROPPED probabilities z (dropout sits between
+            # softmax and the @v matmul); dk/dq flow through ds, whose
+            # rowsum term keeps the undropped p Jacobian — _panel_p_ds
+            dv = _dot(z.astype(do.dtype), do, (0, 0))
+            dk = _dot(ds.astype(q.dtype), q, (0, 0))
+            if one_pass:
+                dv_ref[0, pn.cols, :] = dv.astype(dv_ref.dtype)
+                dk_ref[0, pn.cols, :] = dk.astype(dk_ref.dtype)
+            else:
+                dv_acc[pn.cols, :] = lax.add(dv_acc[pn.cols, :], dv)
+                dk_acc[pn.cols, :] = lax.add(dk_acc[pn.cols, :], dk)
 
-    @pl.when(live)
-    def _compute():
-        z, ds = _block_p_ds(
-            causal, scale, b, i, j, lens_ref[b],
-            q_ref[0], k_ref[0], v_ref[0], do_ref[0], o_ref[0], lse_ref[0],
-            dlse_ref[0] if has_dlse else None, bq, bk, rate, nq, nk, seed_ref,
-        )
-        # dv sees the DROPPED probabilities z (dropout sits between softmax
-        # and the @v matmul); dk/dq flow through ds, whose rowsum term keeps
-        # the undropped p Jacobian — see _block_p_ds
-        dv_acc[...] += jax.lax.dot_general(
-            z.astype(do_ref.dtype), do_ref[0],
-            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-        )
-        dk_acc[...] += jax.lax.dot_general(
-            ds.astype(q_ref.dtype), q_ref[0],
-            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-        )
+    _walk_block(plan, True, i, j, block)
 
-    @pl.when(i == nq - 1)
-    def _final():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+    if not one_pass:
+        @pl.when(i == plan.nq - 1)
+        def _final():
+            dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+            dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _fa_bwd_pallas(q, k, v, do, o, lse, dlse, lens, causal, scale, interpret,
                    rate=0.0, seed=None):
     """``dlse=None`` (the plain-attention path) omits the operand entirely —
     an all-zero lane-replicated dlse would otherwise add an arena-sized HBM
-    read to BOTH backward kernels for nothing."""
+    read to BOTH backward kernels for nothing. ``lens=None``: no ``kv_lens``."""
     BH, Sq, D = q.shape
-    Sk = k.shape[1]
-    bq, bk = _block_size(Sq, D), _block_size(Sk, D)
-    nq, nk = Sq // bq, Sk // bk
+    plan = _tile_plan(Sq, k.shape[1], D, causal)
+    bq, bk, nq, nk = plan.bq, plan.bk, plan.nq, plan.nk
     has_dlse = dlse is not None
     dlse_ops = (dlse,) if has_dlse else ()
-    scalars = [lens.astype(jnp.int32)]
-    if rate > 0.0:
-        scalars.append(seed.astype(jnp.int32))
+    scalars = _scalar_operands(lens, seed, rate)
+    _book_tiles(plan, D, lens is not None, "dq", "dkv")
     qspec_i = pl.BlockSpec((1, bq, D), lambda b, i, j, *_: (b, i, 0))
     kspec_j = pl.BlockSpec((1, bk, D), lambda b, i, j, *_: (b, j, 0))
     lse_i = pl.BlockSpec((1, bq, 128), lambda b, i, j, *_: (b, i, 0))
     dq = pl.pallas_call(
-        functools.partial(_fa_dq_kernel, causal, scale, nq, nk, bq, bk,
+        functools.partial(_fa_dq_kernel, plan, scale, lens is not None,
                           has_dlse, rate),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
@@ -426,7 +723,7 @@ def _fa_bwd_pallas(q, k, v, do, o, lse, dlse, lens, causal, scale, interpret,
     kspec_out = pl.BlockSpec((1, bk, D), lambda b, j, i, *_: (b, j, 0))
     lse_in = pl.BlockSpec((1, bq, 128), lambda b, j, i, *_: (b, i, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_fa_dkv_kernel, causal, scale, nq, nk, bq, bk,
+        functools.partial(_fa_dkv_kernel, plan, scale, lens is not None,
                           has_dlse, rate),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
@@ -456,6 +753,11 @@ def _fa_bwd_pallas(q, k, v, do, o, lse, dlse, lens, causal, scale, interpret,
 # ---------------------------------------------------------------------------------
 
 
+def _zeros_like(lens):
+    """Cotangent of the key lengths, which may be absent (``None``)."""
+    return None if lens is None else jnp.zeros_like(lens)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def _flash3(q, k, v, lens, seed, causal, scale, rate):
     o, _ = _fa_fwd_pallas(q, k, v, lens, causal, scale, _interpret_default(),
@@ -479,7 +781,7 @@ def _flash3_bwd(causal, scale, rate, res, do):
         q, k, v, do, o, lse, None, lens, causal, scale, _interpret_default(),
         rate, seed,
     )
-    return dq, dk, dv, jnp.zeros_like(lens), jnp.zeros_like(seed)
+    return dq, dk, dv, _zeros_like(lens), jnp.zeros_like(seed)
 
 
 _flash3.defvjp(_flash3_fwd, _flash3_bwd)
@@ -507,7 +809,7 @@ def _flash3_lse_bwd(causal, scale, res, cts):
     dq, dk, dv = _fa_bwd_pallas(
         q, k, v, do, o, lse, dlse, lens, causal, scale, _interpret_default()
     )
-    return dq, dk, dv, jnp.zeros_like(lens)
+    return dq, dk, dv, _zeros_like(lens)
 
 
 _flash3_lse.defvjp(_flash3_lse_fwd, _flash3_lse_bwd)
@@ -539,10 +841,11 @@ def flash_attention_with_lse(q3, k3, v3, *, causal, scale, kv_lens=None):
     fully-masked rows carry lse = -1e30 so their merge weight underflows to
     exactly zero). Differentiable in q/k/v AND through lse (the backward
     kernels take the dlse cotangent)."""
-    BH, S, D = q3.shape
-    if kv_lens is None:
-        kv_lens = jnp.full((BH,), float(k3.shape[1]), jnp.float32)
-    return _flash3_lse(q3, k3, v3, kv_lens.astype(jnp.float32), causal, scale)
+    if kv_lens is not None:
+        kv_lens = kv_lens.astype(jnp.float32)
+    elif not causal:
+        kv_lens = jnp.full((q3.shape[0],), float(k3.shape[1]), jnp.float32)
+    return _flash3_lse(q3, k3, v3, kv_lens, causal, scale)
 
 
 # ---------------------------------------------------------------------------------
@@ -660,6 +963,10 @@ def flash_attention(
     else:
         lens = kv_lens.astype(jnp.float32)
     lens_bh = jnp.repeat(lens, H)  # (B*H,): per-head copy of each seq length
+    # a causal call without kv_lens hands its kernels no lengths at all: every
+    # key is in range, statically, and only the diagonal's tiles build a mask
+    # (a non-causal call keeps the length test, and the kernel body it had)
+    lens_pallas = None if causal and kv_lens is None else lens_bh
 
     q3 = q.reshape(B * H, S, D)
     k3 = k.reshape(B * H, Sk, D)
@@ -677,7 +984,7 @@ def flash_attention(
                     # Flash is the only path — book it, skip probe/downgrade.
                     _count_forced(
                         "flash_attention", impl,
-                        q3, k3, v3, lens_bh, seed,
+                        q3, k3, v3, lens_pallas, seed,
                         causal=causal, scale=scale, rate=float(dropout_rate),
                     )
                 else:
@@ -685,11 +992,11 @@ def flash_attention(
                     # keeps the honor-or-raise contract above
                     impl = _checked_impl(
                         "flash_attention", impl, _probe_flash_pallas,
-                        q3, k3, v3, lens_bh, seed,
+                        q3, k3, v3, lens_pallas, seed,
                         causal=causal, scale=scale, rate=float(dropout_rate),
                     )
         if impl == "pallas":
-            o = _flash3(q3, k3, v3, lens_bh, seed, causal, scale,
+            o = _flash3(q3, k3, v3, lens_pallas, seed, causal, scale,
                         float(dropout_rate))
         else:
             o = _attn_jnp(q3, k3, v3, lens_bh, causal, scale,

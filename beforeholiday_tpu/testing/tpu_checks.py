@@ -136,6 +136,114 @@ def check_flash_dropout(results: list) -> None:
           and bool(jnp.all(jnp.isfinite(gq.astype(jnp.float32)))))
 
 
+def check_flash_tiles(results: list) -> None:
+    """The causal tile plan (``ops.attention.TilePlan``) compiled, at the
+    benchmark cells' own sequence lengths and head sizes: forward and the
+    three gradients against the jnp oracle, dropout across several tiles
+    regenerating ONE mask in all three kernels, and the engagement counter
+    (``monitor.tile_records``) printed. Interpret mode cannot see a Mosaic
+    lowering or a tiling fault of the walk's slices and joins."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from beforeholiday_tpu import monitor
+    from beforeholiday_tpu.guard import dispatch
+    from beforeholiday_tpu.ops import attention as A
+
+    def check(name, cond, info=""):
+        results.append((f"flash_tiles/{name}", bool(cond), str(info)))
+
+    def rel(a, b):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return float(jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(b)) + 1e-30))
+
+    def inputs(seed, BH, S, D):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+        return tuple(jax.random.normal(kk, (BH, S, D), jnp.float32).astype(jnp.bfloat16)
+                     for kk in ks)
+
+    dispatch.reset_dispatch_counters()
+    # (name, BH, S, D, kv_lens): the GPT cells' call; the Qwen cell's at fewer
+    # heads (the oracle holds the (BH, S, S) scores); lengths cutting a tile
+    cases = [("gpt_s1024_d64", 64, 1024, 64, None),
+             ("qwen_s8192_d256", 2, 8192, 256, None),
+             ("gpt_s1024_d64_lens", 8, 1024, 64, (1024, 700, 512, 0, 1, 255, 256, 1023))]
+    for name, BH, S, D, kv in cases:
+        q, k, v, w = inputs(1, BH, S, D)
+        sc = 1.0 / np.sqrt(D)
+        lens = None if kv is None else jnp.asarray(kv, jnp.float32)
+        full = jnp.full((BH,), float(S), jnp.float32) if lens is None else lens
+        seed = jnp.zeros((1,), jnp.int32)
+
+        def loss(fn, q, k, v):
+            return jnp.sum(fn(q, k, v).astype(jnp.float32) * w.astype(jnp.float32))
+
+        pal = lambda q, k, v: A._flash3(q, k, v, lens, seed, True, sc, 0.0)
+        ora = lambda q, k, v: A._attn_jnp(q, k, v, full, True, sc)
+        o_p = jax.jit(pal)(q, k, v)
+        o_j = jax.jit(ora)(q, k, v)
+        check(f"{name}/fwd", rel(o_p, o_j) < 2e-2, f"rel={rel(o_p, o_j):.1e}")
+        gp = jax.jit(jax.grad(functools.partial(loss, pal), argnums=(0, 1, 2)))(q, k, v)
+        gj = jax.jit(jax.grad(functools.partial(loss, ora), argnums=(0, 1, 2)))(q, k, v)
+        for gname, a, b in zip(("dq", "dk", "dv"), gp, gj):
+            ok = bool(jnp.all(jnp.isfinite(a.astype(jnp.float32))))
+            check(f"{name}/{gname}", ok and rel(a, b) < 3e-2, f"rel={rel(a, b):.1e}")
+
+    # dropout: the mask of every tile, drawn by a mini kernel under the tile
+    # ids the plan gives, fed to a jnp reference — forward, dq, dk and dv all
+    # have to have regenerated exactly that mask, each in its own walk
+    BH, S, D, rate = 4, 1024, 64, 0.2
+    plan = A._tile_plan(S, S, D, True)
+    n, t = S // plan.tq, plan.tq
+    q, k, v, w = (x.astype(jnp.float32) for x in inputs(2, BH, S, D))
+    seed = A._seed_from_key(jax.random.PRNGKey(5))
+    sc = 1.0 / np.sqrt(D)
+
+    def mask_kernel(seed_ref, o_ref):
+        b, ti, tj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+        keep = A._keep_mask(seed_ref, b, ti, tj, n, n, (t, t), 1.0 - rate)
+        o_ref[0] = keep.astype(jnp.float32)
+
+    mask = pl.pallas_call(
+        mask_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(BH, n, n), in_specs=[],
+            out_specs=pl.BlockSpec((1, t, t), lambda b, ti, tj, *_: (b, ti, tj)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((BH, S, S), jnp.float32),
+    )(seed)
+    causal = jnp.arange(S)[None, :] <= jnp.arange(S)[:, None]
+
+    def ref(q, k, v):
+        s = jnp.where(causal, jnp.einsum("bqd,bkd->bqk", q, k) * sc, -jnp.inf)
+        return jnp.einsum("bqk,bkd->bqd", mask * jax.nn.softmax(s, axis=-1) / (1.0 - rate), v)
+
+    pal = lambda q, k, v: A._flash3(q, k, v, None, seed, True, sc, rate)
+    d = float(jnp.max(jnp.abs(jax.jit(pal)(q, k, v) - jax.jit(ref)(q, k, v))))
+    check("dropout/fwd_same_mask", d < 1e-2, f"maxdiff={d:.1e} tiles={n}x{n}")
+    gp = jax.jit(jax.grad(lambda *a: jnp.sum(pal(*a) * w), argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(lambda *a: jnp.sum(ref(*a) * w), argnums=(0, 1, 2)))(q, k, v)
+    for gname, a, b in zip(("dq", "dk", "dv"), gp, gr):
+        r = float(jnp.max(jnp.abs(a - b)) / jnp.linalg.norm(b.ravel()))
+        check(f"dropout/{gname}_same_mask", r < 1e-3, f"relmax={r:.2e}")
+
+    # a non-causal call is one tile a block: every tile live, the plan idle
+    jax.jit(lambda q, k, v: A._flash3(
+        q, k, v, jnp.full((BH,), float(S)), jnp.zeros((1,), jnp.int32), False, sc, 0.0))(q, k, v)
+    # key: (Sq, Sk, D, causal, has kv_lens) -> live, total, masked
+    want = {"(1024, 1024, 64, True, False)": (10, 16, 4),
+            "(8192, 8192, 256, True, False)": (2080, 4096, 64),
+            "(1024, 1024, 64, True, True)": (10, 16, 10),    # every tile tests the length
+            "(1024, 1024, 64, False, True)": (1, 1, 1)}      # the body a non-causal call had
+    rows = [r for r in monitor.tile_records() if r["op"] == "flash_attention"]
+    for r in rows:
+        got = (r["live"], r["total"], r["masked"])
+        check(f"counter/{r['kernel']}{r['key']}", got == want.get(r["key"]),
+              f"{got[0]}/{got[1]} masked {got[2]} ({r['traces']} traces)")
+    check("counter/all_kernels_booked",
+          {r["kernel"] for r in rows} == {"fwd", "dq", "dkv"}, len(rows))
+
+
 def check_aliased_mt_kernels(results: list) -> None:
     """The Pallas multi-tensor kernels run with input_output_aliases on the
     compiled path (in-place updates, ~1.8x streaming win) — aliasing bugs
@@ -557,7 +665,7 @@ def main() -> int:
 
     enable_compile_cache()
     results: list = []
-    for group in (check_flash_dropout, check_aliased_mt_kernels,
+    for group in (check_flash_dropout, check_flash_tiles, check_aliased_mt_kernels,
                   check_compiled_kernel_parity):
         try:
             group(results)

@@ -252,3 +252,275 @@ class TestFlashOnlyDispatch:
         booked = self._booked()
         assert booked["pallas"] >= 1
         assert booked["probes"] >= 1  # the guard probed as usual
+
+
+# ---------------------------------------------------------------------------------
+# the two-level tile plan (PR 28): grid blocks as before, tiles inside the
+# blocks the causal diagonal crosses
+# ---------------------------------------------------------------------------------
+
+
+def _walk_tiles(plan, by_cols):
+    """Every (tile row, tile col, through a causal mask) the kernel whose
+    strips run along ``by_cols`` computes, from the plan's own walk over the
+    live blocks: what the kernels unroll, flattened."""
+    out = []
+    for i in range(plan.nq):
+        for j in range(plan.nk):
+            if plan.causal and j > i:
+                continue
+            for fixed, pieces in plan.walk(by_cols, plan.causal and i == j):
+                for moving, on_diag in pieces:
+                    rows, cols = (moving, fixed) if by_cols else (fixed, moving)
+                    out += [((i * plan.bq + r) // plan.tq, (j * plan.bk + c) // plan.tk, on_diag)
+                            for r in range(rows.start, rows.stop, plan.tq)
+                            for c in range(cols.start, cols.stop, plan.tk)]
+    return out
+
+
+class TestTilePlan:
+    # (Sq, Sk, D, causal) -> grid block, tile, live / total tiles, masked
+    TABLE = [
+        ((1024, 1024, 64, True), (1024, 1024), (256, 256), (10, 16, 4)),   # the GPT cells
+        ((8192, 8192, 64, True), (1024, 1024), (256, 256), (528, 1024, 32)),
+        ((8192, 8192, 256, True), (512, 512), (128, 128), (2080, 4096, 64)),  # the Qwen cell
+        ((1536, 1536, 64, True), (512, 512), (128, 128), (78, 144, 12)),
+        ((384, 384, 64, True), (128, 128), (128, 128), (6, 9, 3)),
+        ((128, 128, 64, True), (128, 128), (128, 128), (1, 1, 1)),
+        ((1024, 1024, 64, False), (1024, 1024), (1024, 1024), (1, 1, 1)),  # one tile a block
+        ((256, 384, 64, False), (256, 128), (256, 128), (3, 3, 3)),
+    ]
+
+    @pytest.mark.parametrize("key,block,tile,counts", TABLE,
+                             ids=[f"S{k[0]}x{k[1]}-D{k[2]}-{'causal' if k[3] else 'full'}"
+                                  for k, *_ in TABLE])
+    def test_plan_table(self, key, block, tile, counts):
+        plan = A._tile_plan(*key)
+        assert (plan.bq, plan.bk) == block
+        assert (plan.bq, plan.bk) == (A._block_size(key[0], key[2]), A._block_size(key[1], key[2]))
+        assert (plan.tq, plan.tk) == tile
+        live, total, masked = counts
+        assert plan.counts(False) == {"total": total, "live": live, "masked": masked}
+        # with kv_lens every computed tile takes the length test
+        assert plan.counts(True)["masked"] == live
+
+    @pytest.mark.parametrize("key", [k for k, *_ in TABLE if k[0] <= 1536],
+                             ids=lambda k: f"S{k[0]}x{k[1]}-D{k[2]}-{'causal' if k[3] else 'full'}")
+    @pytest.mark.parametrize("by_cols", [False, True], ids=["rows", "cols"])
+    def test_walk_covers_exactly_the_live_tiles(self, key, by_cols):
+        """Brute force against the mask: a tile is computed iff some score in
+        it is live, exactly once, and only a tile the diagonal crosses goes
+        through the causal mask — for the row walk (fwd, dq) and the column
+        walk (dkv) alike."""
+        plan = A._tile_plan(*key)
+        tiles = _walk_tiles(plan, by_cols)
+        assert len({t[:2] for t in tiles}) == len(tiles)
+        want = {}
+        for r in range(plan.sq // plan.tq):
+            for c in range(plan.sk // plan.tk):
+                first_col, last_col = c * plan.tk, (c + 1) * plan.tk - 1
+                first_row, last_row = r * plan.tq, (r + 1) * plan.tq - 1
+                if not plan.causal:
+                    want[(r, c)] = False
+                elif first_col <= last_row:                # some key <= some query
+                    want[(r, c)] = last_col > first_row    # some key > some query
+        assert {t[:2]: t[2] for t in tiles} == want
+        counts = plan.counts(False)
+        assert counts["live"] == len(tiles)
+        if plan.causal:
+            assert counts["masked"] == sum(t[2] for t in tiles)
+
+    def test_counter_books_each_traced_kernel(self):
+        from beforeholiday_tpu import monitor
+        from beforeholiday_tpu.guard import dispatch
+
+        dispatch.reset_dispatch_counters()
+        q, k, v = _qkv(jax.random.PRNGKey(7), B=1, H=1, S=512)
+        jax.grad(lambda q: jnp.sum(A.flash_attention(q, k, v, causal=True, impl="pallas")))(q)
+        rows = {r["kernel"]: r for r in monitor.tile_records() if r["op"] == "flash_attention"}
+        assert sorted(rows) == ["dkv", "dq", "fwd"]
+        for r in rows.values():   # block 512 in four strips of 128
+            assert (r["live"], r["total"], r["masked"]) == (10, 16, 4)
+            assert r["traces"] >= 1
+        dispatch.reset_dispatch_counters()
+        assert monitor.tile_records() == []
+
+
+def _kernel_primitives(fn, *args):
+    """Per pallas_call of ``fn``'s jaxpr, the sorted primitives of its kernel
+    body (nested bodies included, jit wrappers not)."""
+    found = []
+
+    def body(jaxpr, out):
+        for e in jaxpr.eqns:
+            if e.primitive.name not in ("pjit", "jit", "closed_call"):
+                out.append(e.primitive.name)
+            for v in e.params.values():
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    if hasattr(sub, "eqns") or hasattr(sub, "jaxpr"):
+                        body(getattr(sub, "jaxpr", sub), out)
+        return out
+
+    def find(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                found.append(sorted(body(e.params["jaxpr"], [])))
+                continue
+            for v in e.params.values():
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    if hasattr(sub, "eqns") or hasattr(sub, "jaxpr"):
+                        find(getattr(sub, "jaxpr", sub))
+
+    find(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+class TestNonCausalBodyUnchanged:
+    """A non-causal call is one tile a block: the kernel bodies PR 28 found,
+    operation for operation. The counts were read off the three kernels of
+    the commit before the tile plan (352c098) at Sq=256, Sk=384, without and
+    with the ``dlse`` operand (one more read and one more lane slice) — less
+    two ``convert_element_type`` a kernel: the casts of the Python scalars
+    ``_NEG`` and ``0.0`` that ``jnp.where`` made and ``lax.select`` does not
+    (scalar constants, no vector work)."""
+
+    FWD = {"add": 4, "broadcast_in_dim": 14, "cond": 3, "convert_element_type": 9,
+           "div": 1, "dot_general": 2, "eq": 2, "exp": 2, "ge": 2, "get": 11, "gt": 1,
+           "iota": 1, "log": 1, "max": 1, "mul": 4, "program_id": 3, "reduce_max": 1,
+           "reduce_sum": 1, "select_n": 6, "slice": 2, "sub": 2, "swap": 8}
+    DQ = {"add": 3, "broadcast_in_dim": 4, "cond": 3, "convert_element_type": 7,
+          "dot_general": 3, "eq": 2, "exp": 1, "ge": 2, "get": 10, "iota": 1, "mul": 5,
+          "program_id": 3, "reduce_sum": 1, "select_n": 2, "slice": 1, "sub": 2, "swap": 3}
+    DKV = {"add": 4, "broadcast_in_dim": 5, "cond": 3, "convert_element_type": 9,
+           "dot_general": 4, "eq": 2, "exp": 1, "ge": 2, "get": 13, "iota": 1, "mul": 5,
+           "program_id": 3, "reduce_sum": 1, "select_n": 2, "slice": 1, "sub": 2, "swap": 6}
+
+    @staticmethod
+    def _grads(with_lse):
+        BH, Sq, Sk, D = 2, 256, 384, 64
+        q = jnp.zeros((BH, Sq, D), jnp.bfloat16)
+        k = jnp.zeros((BH, Sk, D), jnp.bfloat16)
+        lens = jnp.full((BH,), float(Sk))
+        seed = jnp.zeros((1,), jnp.int32)
+
+        def loss(q, k, v):
+            if with_lse:
+                o, lse = A._flash3_lse(q, k, v, lens, False, 0.125)
+                return jnp.sum(o.astype(jnp.float32)) + jnp.sum(lse)
+            return jnp.sum(A._flash3(q, k, v, lens, seed, False, 0.125, 0.0).astype(jnp.float32))
+
+        return _kernel_primitives(jax.grad(loss, argnums=(0, 1, 2)), q, k, k)
+
+    @pytest.mark.parametrize("with_lse", [False, True], ids=["plain", "lse"])
+    def test_same_operations(self, with_lse):
+        import collections
+
+        fwd, dq, dkv = (collections.Counter(p) for p in self._grads(with_lse))
+        dlse = {"get": 1, "slice": 1} if with_lse else {}
+        assert fwd == self.FWD
+        assert dq == collections.Counter(self.DQ) + collections.Counter(dlse)
+        assert dkv == collections.Counter(self.DKV) + collections.Counter(dlse)
+
+
+def _oracle_grads(q, k, v, lens, causal, scale, w, wl=None):
+    """(o, dq, dk, dv) of the jnp oracle for the loss sum(o * w) [+ sum(lse * wl)]."""
+    def loss(q, k, v):
+        o = A._attn_jnp(q, k, v, lens, causal, scale)
+        out = jnp.sum(o * w)
+        if wl is not None:
+            s = jnp.einsum("bqd,bkd->bqk", q, k) * scale
+            kj = jnp.arange(k.shape[1])
+            masked = kj[None, None, :] >= lens[:, None, None]
+            if causal:
+                masked |= kj[None, :] > jnp.arange(q.shape[1])[:, None]
+            lse = jax.nn.logsumexp(jnp.where(masked, -jnp.inf, s), axis=-1)
+            out = out + jnp.sum(jnp.where(jnp.isfinite(lse), lse, 0.0) * wl)
+        return out
+    o = A._attn_jnp(q, k, v, lens, causal, scale)
+    return (o,) + jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+class TestTiledParity:
+    """Interpret-mode parity of forward, dq, dk, dv against ``_attn_jnp`` where
+    the diagonal walk has all four strips (S=512: block 512, tiles of 128)."""
+
+    S, D, BH = 512, 64, 2
+
+    def _inputs(self, seed, sk=None):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+        sk = sk or self.S
+        q = jax.random.normal(ks[0], (self.BH, self.S, self.D), jnp.float32)
+        k = jax.random.normal(ks[1], (self.BH, sk, self.D), jnp.float32)
+        v = jax.random.normal(ks[2], (self.BH, sk, self.D), jnp.float32)
+        w = jax.random.normal(ks[3], (self.BH, self.S, self.D), jnp.float32)
+        wl = jax.random.normal(ks[4], (self.BH, self.S), jnp.float32)
+        return q, k, v, w, wl
+
+    @pytest.mark.parametrize("lens", [None, (300, 512), (256, 384), (0, 130)],
+                             ids=["no-lens", "inside-a-tile", "on-a-tile-edge", "zero"])
+    def test_causal_matches_oracle(self, lens):
+        q, k, v, w, _ = self._inputs(11)
+        scale = 1.0 / np.sqrt(self.D)
+        kv = None if lens is None else jnp.asarray(lens, jnp.float32)
+        seed = jnp.zeros((1,), jnp.int32)
+
+        def loss(q, k, v):
+            return jnp.sum(A._flash3(q, k, v, kv, seed, True, scale, 0.0) * w)
+
+        got = (A._flash3(q, k, v, kv, seed, True, scale, 0.0),) + jax.grad(
+            loss, argnums=(0, 1, 2))(q, k, v)
+        full = jnp.full((self.BH,), float(self.S)) if kv is None else kv
+        want = _oracle_grads(q, k, v, full, True, scale, w)
+        for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+            assert not np.any(np.isnan(np.asarray(a))), name
+            np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5, err_msg=name)
+
+    @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+    def test_lse_variant_with_dlse(self, causal):
+        q, k, v, w, wl = self._inputs(12)
+        scale = 1.0 / np.sqrt(self.D)
+        lens = jnp.asarray((300.0, 512.0))
+
+        def loss(q, k, v):
+            o, lse = A.flash_attention_with_lse(q, k, v, causal=causal, scale=scale,
+                                                kv_lens=lens)
+            return jnp.sum(o * w) + jnp.sum(lse * wl)
+
+        got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        want = _oracle_grads(q, k, v, lens, causal, scale, w, wl)[1:]
+        for name, a, b in zip(("dq", "dk", "dv"), got, want):
+            np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4, err_msg=name)
+
+    def test_unequal_lengths_non_causal(self):
+        q, k, v, w, _ = self._inputs(13, sk=384)
+        scale = 1.0 / np.sqrt(self.D)
+        lens = jnp.asarray((384.0, 200.0))
+        seed = jnp.zeros((1,), jnp.int32)
+
+        def loss(q, k, v):
+            return jnp.sum(A._flash3(q, k, v, lens, seed, False, scale, 0.0) * w)
+
+        got = (A._flash3(q, k, v, lens, seed, False, scale, 0.0),) + jax.grad(
+            loss, argnums=(0, 1, 2))(q, k, v)
+        want = _oracle_grads(q, k, v, lens, False, scale, w)
+        for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+            np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5, err_msg=name)
+
+    def test_multi_block_causal_bf16(self):
+        """Blocks above the diagonal skipped, below it unmasked, on it walked:
+        S=1024 at D=256 is 2 x 2 blocks of 512."""
+        ks = jax.random.split(jax.random.PRNGKey(14), 4)
+        q, k, v, w = (jax.random.normal(kk, (1, 1024, 256), jnp.float32) for kk in ks)
+        scale = 1.0 / 16.0
+        seed = jnp.zeros((1,), jnp.int32)
+        assert A._tile_plan(1024, 1024, 256, True).nq == 2
+        qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, k, v))
+
+        def loss(q, k, v):
+            return jnp.sum(A._flash3(q, k, v, None, seed, True, scale, 0.0).astype(jnp.float32) * w)
+
+        got = jax.grad(loss, argnums=(0, 1, 2))(qb, kb, vb)
+        full = jnp.full((1,), 1024.0)
+        want = _oracle_grads(*(x.astype(jnp.float32) for x in (qb, kb, vb)), full, True, scale, w)[1:]
+        for name, a, b in zip(("dq", "dk", "dv"), got, want):
+            np.testing.assert_allclose(a.astype(np.float32), b, atol=3e-2, rtol=3e-2, err_msg=name)
