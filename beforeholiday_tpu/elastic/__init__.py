@@ -28,10 +28,10 @@ the replication tripwire, and the flight recorder — into a survivable loop:
   thread, and :class:`~beforeholiday_tpu.elastic.watchdog.RankHangError`
   raised into the loop's poll.
 
-Drills live in ``testing/elastic_bench.py`` (SIGKILL a training subprocess
-mid-run, assert bitwise-correct resume), ``testing/chaos_bench.py``
-(randomized multi-fault schedules, each bitwise vs an uninterrupted
-reference), and ``tests/test_elastic.py`` / ``tests/test_chaos.py``.
+Drills live in ``testing/drills.py`` (SIGKILL a training subprocess
+mid-run, assert bitwise-correct resume; randomized multi-fault schedules,
+each bitwise vs an uninterrupted reference) and are driven by
+``tests/test_elastic.py`` / ``tests/test_chaos.py``.
 """
 
 from beforeholiday_tpu.elastic.checkpoint import (

@@ -104,7 +104,7 @@ def _phase(name: str):
 
 
 def reset_ckpt_ledger() -> None:
-    """Zero the process-global ckpt ledger (tests/bench rungs)."""
+    """Zero the process-global ckpt ledger (tests, drills)."""
     with _LOCK:
         _LEDGER.clear()
         _COUNTS["generations"] = 0

@@ -1,7 +1,7 @@
 """Real preemption bridge — OS signals routed into the elastic run loop.
 
-PR 12's drills injected :class:`~beforeholiday_tpu.testing.faults.
-SimulatedPreemption` from a host-side tick; a REAL preemption arrives as a
+PR 12's drills injected :class:`SimulatedPreemption` from a host-side tick
+(``testing.faults.preempt_after``); a REAL preemption arrives as a
 signal (cloud TPU preemption notices are a SIGTERM to the worker; operators
 use SIGUSR1 for a manual drain). A signal handler cannot safely touch JAX,
 threads, or files mid-step — so the bridge is two halves joined by one
@@ -39,14 +39,35 @@ from __future__ import annotations
 import signal as _signal
 from typing import Optional, Sequence, Tuple
 
-from beforeholiday_tpu.testing.faults import SimulatedPreemption
 from beforeholiday_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
 
-__all__ = ["PreemptionNotice"]
+__all__ = ["PreemptionNotice", "SimulatedPreemption"]
 
 DEFAULT_SIGNALS = (_signal.SIGTERM, _signal.SIGUSR1)
+
+
+class SimulatedPreemption(RuntimeError):
+    """A preemption notice / lost rank, as the elastic run loop sees it.
+
+    ``surviving_world`` optionally names the world size that remains after
+    the event (e.g. a host carrying 4 of 8 ranks died); ``None`` defers to
+    the elastic trainer's ``survivor_policy``. ``drain=True`` marks a
+    GRACEFUL notice (the shape of a real SIGTERM from the scheduler: this
+    process itself is going away) — the elastic trainer responds by making
+    its state durable and returning cleanly instead of resizing in place.
+    Raised by :meth:`PreemptionNotice.tick` when a real signal arrived and by
+    the ``testing.faults.preempt_after`` injector (which re-exports this
+    class); catchable anywhere a real preemption callback would fire.
+    """
+
+    def __init__(self, message: str = "simulated preemption", *,
+                 surviving_world: Optional[int] = None,
+                 drain: bool = False):
+        super().__init__(message)
+        self.surviving_world = surviving_world
+        self.drain = bool(drain)
 
 
 def _signame(signum: int) -> str:

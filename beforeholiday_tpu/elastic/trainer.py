@@ -9,8 +9,8 @@ Composition, not new physics — every piece already exists in the repo:
   (``guard.StepGuard.apply_sharded_update``);
 * the replication tripwire (``parallel.check_replicated_consistency``) —
   a traced ``mismatch`` flag in the step's metrics row;
-* fault injectors (``testing.faults.preempt_after`` raising
-  :class:`~beforeholiday_tpu.testing.faults.SimulatedPreemption``);
+* the preemption exception, :class:`~beforeholiday_tpu.elastic.signals.
+  SimulatedPreemption` (raised by a real signal's notice or by an injector);
 * the async :class:`~beforeholiday_tpu.elastic.checkpoint.CheckpointManager`.
 
 A RESIZE EVENT (tripwire mismatch, ``SimulatedPreemption``, or a real
@@ -27,7 +27,7 @@ preemption notice routed to the same exception) is handled as:
 5. continue — ``global_step`` rolls back to the checkpointed step and the
    loop replays forward. The continued loss trajectory is bitwise identical
    to an uninterrupted run at the new world size from the same checkpoint
-   (``testing/elastic_bench.py`` and ``tests/test_elastic.py`` pin this).
+   (``tests/test_elastic.py::TestElasticTrainerDrills`` pins this).
 
 The user supplies ``make_step(mesh, world) -> step`` where
 ``step(state, gstate, batch) -> (state, gstate, row)``; ``row`` is a dict of
@@ -57,6 +57,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 _shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 from beforeholiday_tpu.elastic import checkpoint as ckpt
+from beforeholiday_tpu.elastic.signals import SimulatedPreemption
 from beforeholiday_tpu.elastic.watchdog import RankHangError
 from beforeholiday_tpu.monitor.trace import active_recorder
 from beforeholiday_tpu.optimizers import zero3
@@ -64,7 +65,6 @@ from beforeholiday_tpu.parallel.parallel_state import (
     DATA_AXIS,
     carve_data_mesh,
 )
-from beforeholiday_tpu.testing.faults import SimulatedPreemption
 from beforeholiday_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -415,8 +415,8 @@ class ElasticTrainer:
 
     def checkpoint_now(self, *, wait: bool = False) -> str:
         """Submit a generation for the current state immediately; with
-        ``wait=True`` block until it is durable (the synchronous-baseline
-        mode the bench compares against)."""
+        ``wait=True`` block until it is durable (what the drills' reference
+        runs do at every lineage boundary)."""
         path = self._submit_checkpoint()
         if wait:
             self._manager.wait()

@@ -49,13 +49,13 @@ __all__ = [
 ]
 
 # process-global watchdog ledger: one row per flagged hang, mirroring the
-# ckpt ledger's reset/records surface so bench rungs can rollup drills
+# ckpt ledger's reset/records surface so tests and drills can roll them up
 _LOCK = threading.Lock()
 _LEDGER: List[Dict[str, Any]] = []
 
 
 def reset_watchdog_ledger() -> None:
-    """Zero the process-global watchdog ledger (tests/bench rungs)."""
+    """Zero the process-global watchdog ledger (tests, drills)."""
     with _LOCK:
         _LEDGER.clear()
 

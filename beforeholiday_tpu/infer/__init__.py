@@ -12,7 +12,7 @@ Built entirely on machinery the training stack already ships:
   cast via the amp stack).
 * :mod:`beforeholiday_tpu.infer.batching` — Orca-style continuous batching:
   admit/evict at decode-step granularity against the page budget, preempt
-  by recompute on famine, plus the static-batching baseline the bench pairs
+  by recompute on famine, plus the static-batching baseline the tests pair
   it with.
 * :mod:`beforeholiday_tpu.infer.radix`    — host-side radix tree over
   page-aligned token prefixes: shared prompt prefixes alias shared KV pages
@@ -26,8 +26,9 @@ Built entirely on machinery the training stack already ships:
   recorder.
 
 The async open-loop request driver (with the crash flight recorder wired
-in) lives in ``examples/serve/``; the bench rungs in
-``testing/infer_bench.py`` surface through ``bench.py``.
+in) lives in ``examples/serve/``. Serving has no cell in the benchmark yet
+(ROADMAP B2); ``tests/test_infer.py`` and ``tests/test_serving.py`` hold the
+decode oracles and the closed signature set.
 """
 
 from beforeholiday_tpu.infer.batching import (  # noqa: F401

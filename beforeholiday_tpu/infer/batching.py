@@ -18,7 +18,7 @@ scheduler's sanctioned host entry point (it reads back one token per active
 request per iteration — serving cannot emit tokens without that readback,
 and it piggybacks on the step boundary exactly like the metrics drain).
 
-``static_batched_generate`` is the paired baseline for the bench: same
+``static_batched_generate`` is the paired baseline: same
 engine, same allocator budget, same bucket set — but the classic static
 policy (a batch admits only when the PREVIOUS batch fully drains, and holds
 worst-case pages for every member up front).
@@ -347,7 +347,7 @@ def static_batched_generate(
     slots stay occupied until the longest member finishes — the two wastes
     continuous batching removes. Decode steps run only the unfinished rows
     (bucket padding absorbs the rest), which flatters the baseline slightly;
-    the gap the bench measures is therefore the SCHEDULING win alone."""
+    a gap measured against it is therefore the SCHEDULING win alone."""
     allocator = PageAllocator(engine.cfg.num_pages)
     queue = deque(sorted(requests, key=lambda r: (r.arrival, r.rid)))
     finished: List[Request] = []

@@ -28,12 +28,12 @@ cache:
   regime has room to absorb — prefilling past decode capacity would just
   park pages in the handoff queue).
 
-The bench (``testing/serving_bench.py``) runs the same mixed workload
+``tests/test_serving.py::TestDisaggregation`` runs the same mixed workload
 through a unified ``ContinuousBatcher`` and this scheduler at equal page
-budget and checks: byte-identical token streams (greedy; rows are
-independent under bucket padding), goodput no worse, and the roofline
-ledger showing prefill compute-bound / decode memory-bound — the regime
-split this module exists to exploit.
+budget and checks byte-identical token streams (greedy; rows are
+independent under bucket padding) and a closed signature set. Whether the
+regime split — prefill compute-bound, decode memory-bound — pays in goodput
+has not been measured on the chip (ROADMAP B2).
 """
 
 from __future__ import annotations

@@ -159,7 +159,7 @@ class EngineConfig:
 
     @property
     def declared_signatures(self) -> int:
-        """Total compiled-signature budget — the bench's acceptance bound."""
+        """Total compiled-signature budget — the bound the tests hold."""
         return (
             self.declared_prefill_signatures
             + self.declared_decode_signatures
@@ -438,12 +438,12 @@ class InferenceEngine:
 
     @property
     def compiled_signatures(self) -> int:
-        """Executables resident in the AOT cache — the bench compares this
-        against ``cfg.declared_signatures``."""
+        """Executables resident in the AOT cache — never more than
+        ``cfg.declared_signatures`` (``tests/test_infer.py``)."""
         return len(self._exec)
 
     def reset_cache(self) -> None:
-        """Fresh zeroed pools (tests/bench isolation; reused pages don't need
+        """Fresh zeroed pools (test isolation; reused pages don't need
         this — prefill rewrites every slot it claims and kv_lens masks the
         rest)."""
         self._cache = kvcache.alloc_cache(self.layout)

@@ -391,7 +391,7 @@ def kv_logit_error_bound(
     geometrically, ``logit_ceiling`` (the fp32 run's max |logit|) converts
     relative to absolute, and ``growth`` majorizes the per-step accumulation
     as more quantized history enters each read. Worst-case-over-everything,
-    hence loose; the bench also reports the measured deviation."""
+    hence loose (``tests/test_serving.py`` holds a run inside it)."""
     if n_layers < 1:
         raise ValueError(f"n_layers must be >= 1, got {n_layers}")
     eps = E4M3_REL + margin * E4M3_TINY / E4M3_MAX

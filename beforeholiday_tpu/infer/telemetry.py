@@ -17,8 +17,8 @@ each lifecycle transition (enqueue → admit → first token → decode tick →
   ``req:queued`` / ``req:active`` span chain (re-queued on preemption) plus
   a ``first_token`` instant, and the scheduler books counter tracks
   (``pages_free``, ``batch_fill``, ``queue_depth``) every step. With no
-  recorder active every span call is a no-op — the telemetry-on rung of the
-  bench holds a ≤5% overhead gate over the plain batcher;
+  recorder active every span call is a no-op (what the observer costs a
+  serving loop has not been measured on the chip);
 * **SLO burn rate** (:class:`SLOPolicy`) — declared latency targets judged
   with the multi-window burn-rate rule: breach only when the error budget
   burns faster than ``burn_threshold`` over BOTH the short and the long

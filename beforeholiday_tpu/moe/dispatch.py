@@ -20,13 +20,15 @@ collective (it is pure data movement; ``tests/test_moe.py`` pins it), and
 the per-tier ledger split shows how much of the dispatch payload actually
 crosses the slow tier.
 
-Bitwise-parity contract (the subsystem's keystone, asserted by tests and by
-``testing/moe_bench.py`` before any timing): at sufficient capacity —
+Bitwise-parity contract (the subsystem's keystone, held by
+``tests/test_moe.py``): at sufficient capacity —
 ``route(...).drop_fraction == 0`` — the FORWARD pass of :func:`moe_layer` on
 an expert-parallel mesh equals :func:`dense_oracle` bitwise. The chain:
 routing is per-group and mesh-independent; the all_to_all pair is a pure
-permutation; the grouped FFN is row-stable (batch-shape-independent per
-row); the dispatch scatter and combine gather are 0/1 contractions with at
+permutation; the grouped FFN is row-stable among slabs the backend gives
+the same GEMM kernel (on XLA:CPU the reduction order of a GEMM depends on
+its row count: compare at the distributed slab's ``ep * capacity`` rows,
+``tests/test_moe.py::test_expert_parallel_bitwise``); the dispatch scatter and combine gather are 0/1 contractions with at
 most one nonzero term per output element (exact copies under IEEE, any
 grouping); and the final gate-weighted sum is spelled as the SAME
 ``(T, E) x (E, T, D)`` einsum in both paths, so XLA lowers one kernel shape

@@ -273,7 +273,7 @@ def comms_summary() -> List[Dict[str, object]]:
     """Subsystem rollup, one row per site-tag prefix (the segment before the
     first ``.``): ``{"subsystem", "sites", "calls", "bytes", "logical_bytes",
     "compression_ratio", "by_kind", "by_tier"}`` — the shape
-    ``bench.py``/MULTICHIP embed, mirroring ``dispatch_summary``. ``bytes``
+    ``__graft_entry__`` prints, mirroring ``dispatch_summary``. ``bytes``
     totals are WIRE traffic (actual interconnect cost);
     ``compression_ratio = logical_bytes / bytes`` is 1.0 for uncompressed
     subsystems and ~2.0 for bf16-on-the-wire over fp32. ``by_tier`` splits

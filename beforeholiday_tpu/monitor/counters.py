@@ -5,8 +5,8 @@ Every ``checked_impl`` call is trace-time dispatch telemetry: did this
 (op, backend, shapes/dtypes, statics) key take the pallas kernel or degrade
 to the jnp oracle, and was a probe actually built? The raw counts live in
 ``guard.dispatch`` (under its verdict lock); this module shapes them for
-operators — per-key rows plus an op-level rollup suitable for a bench JSON
-line or a health dashboard.
+operators — per-key rows plus an op-level rollup suitable for a JSON line
+or a health dashboard.
 
 ``tile_records`` shows, per traced flash kernel, how much of the score square
 its tile plan computes and how much of that goes through a mask.
@@ -108,7 +108,7 @@ def tile_records() -> List[Dict[str, object]]:
 def dispatch_summary() -> List[Dict[str, object]]:
     """Op-level rollup, one JSON-ready row per op name:
     ``{"op", "keys", "pallas", "jnp", "probes", "pallas_ratio",
-    "degraded_keys"}`` — the shape ``bench.py`` embeds in its emitted line
+    "degraded_keys"}`` — the shape ``monitor.perf_report`` embeds
     (``pallas_ratio`` = fraction of the op's dispatches that took the
     kernel; 1.0 is a fully-healthy op, 0.0 a fully-degraded one)."""
     from beforeholiday_tpu.guard import dispatch as _dispatch

@@ -16,7 +16,7 @@ input, elementwise ops one flop per output element.
 FLOPs alone are not attribution — they need wall time. Timing is the
 CALLER's job (this module must stay free of device syncs; the no-host-sync
 scan covers it with zero sanctions): measure a step however you already do
-(bench fences, span wall-times) and hand the seconds to
+(fenced steps, span wall-times) and hand the seconds to
 :func:`record_wall_time`, or let :func:`join_spans` pull durations for spans
 named after tracked entries off a trace recorder. ``roofline_summary`` then
 joins analytic work with measured time against a registrable
@@ -27,7 +27,7 @@ joins analytic work with measured time against a registrable
 * ``bound``     — compute / memory (arithmetic intensity vs the ridge
   point) or comms (recorded comms time dominates the step).
 
-:func:`perf_report` is the one-call rollup the bench and the dryrun embed:
+:func:`perf_report` is the one-call rollup ``__graft_entry__`` prints:
 per-entry ``<entry>_mfu`` / ``<entry>_bw_util`` keys plus the overlap and
 straggler numbers from :mod:`beforeholiday_tpu.monitor.overlap` and the
 dispatch/comms/compile summaries.
@@ -469,12 +469,12 @@ def record_wall_time(
     comms_seconds: float = 0.0,
 ) -> None:
     """Attribute measured wall time to an entry — the join point between the
-    caller's timing (bench fences, span durations) and the analytic costs.
+    caller's timing (fenced steps, span durations) and the analytic costs.
 
     ``seconds`` covers ``steps`` executions. ``flops``/``bytes_accessed``
     are optional PER-STEP overrides for callers that know the analytic count
-    in closed form (the bench's 6·N·tokens); they take precedence over the
-    tracked costs so the headline MFU matches the bench's own arithmetic.
+    in closed form (6·N·tokens); they take precedence over the tracked
+    costs so the headline MFU matches the caller's own arithmetic.
     ``fp8_flops`` is the per-step share of ``flops``-class work executed as
     quantized (fp8) matmuls — it is measured against the chip's fp8 peak in
     the MFU, so pass the SPLIT (``flops`` excluding the fp8 share), not the
@@ -648,8 +648,7 @@ def perf_report(
     ``<entry>_mfu`` / ``<entry>_bw_util`` keys, the measured
     ``overlap_fraction`` and ``rank_skew_*`` from the timeline (``events``
     defaults to the active trace recorder's), and the dispatch/comms/compile
-    summaries — the shape ``bench.py`` embeds under its stability gate and
-    the MULTICHIP dryrun prints."""
+    summaries (``tests/test_perf_attr.py`` pins the shape)."""
     from beforeholiday_tpu.monitor import overlap as _overlap
     from beforeholiday_tpu.monitor.comms import comms_summary
     from beforeholiday_tpu.monitor.compile import compile_summary

@@ -381,8 +381,8 @@ def loss_parity_bound(
     initial loss ≈ ln V is a ceiling on the sensitivity), and ``growth``
     majorizes the per-step divergence rate of two SGD/Adam trajectories under
     persistent relative perturbation (1 + lr·curvature, with generous slack).
-    Worst-case-over-everything, hence loose; the bench also reports the
-    measured deviation, which is typically orders of magnitude smaller."""
+    Worst-case-over-everything, hence loose; a run's measured deviation
+    (tests/test_quantized.py) is typically orders of magnitude smaller."""
     if n_matmuls < 1:
         raise ValueError(f"n_matmuls must be >= 1, got {n_matmuls}")
     eps_fwd = (1.0 + 2.0 * _E4M3_REL) ** n_matmuls - 1.0

@@ -23,7 +23,7 @@ step (``params, state = step(params, grads, state)``); reusing a donated input
 afterwards is a crash, not a slowdown — which is why the examples' trainers
 rebind. Donation requested on a jit nested inside another jit is ignored by
 jax (the outer trace owns the buffers), so donated steps remain safe to call
-from wrapper jits like the bench chains.
+from wrapper jits such as a multi-step loop.
 """
 
 from __future__ import annotations

@@ -7,7 +7,7 @@ Activation Recomputation in Large Transformer Models"): trade backward-pass
 recompute for peak activation memory. JAX already ships the machinery
 (``jax.checkpoint`` + ``jax.checkpoint_policies``); what this module adds is
 the *naming layer* so a policy travels as a plain string through configs,
-pipeline schedules, and bench JSON — no callables smuggled through
+pipeline schedules and result files — no callables smuggled through
 dataclasses, no jit-cache misses from anonymous lambdas.
 
 Built-in policies:

@@ -1,11 +1,43 @@
-"""Chaos soak: randomized multi-fault schedules against the elastic stack.
+"""Kill/resume, chaos and goodput drills for the elastic stack — the harness
+``tests/test_elastic.py``, ``tests/test_chaos.py`` and
+``tests/test_telemetry.py`` drive, on the 8-device virtual CPU mesh.
 
-PR 12 proved SINGLE-fault recovery bitwise; production preemptible slices
-deliver fault SEQUENCES — a SIGTERM notice while a generation is in
-flight, a host lost right after capacity grew back, a hung rank discovered
-mid-shrink. This harness composes the whole fault menagerie into seeded
-random schedules and holds every one to the same oracle: the run must end
-with the master arena BITWISE-EQUAL to an uninterrupted reference.
+These are safety drills, not measurements: every one ends in a BITWISE
+comparison against an independent, uninterrupted reference (or, for the
+goodput run, an exact integer sum), and nothing here reads a clock for a
+result.
+
+* **Fixtures** — a tiny ZeRO-3 engine (``_engine``), a batch keyed on the
+  global step (``_batch_fn``: a replay after reload sees identical data,
+  which is what makes a continued trajectory bitwise) and its parameters.
+* **The train child** — ``python -m beforeholiday_tpu.testing.drills --role
+  train`` trains with async generation checkpoints and, by flag, SIGKILLs
+  itself mid-run (rank loss the hard way: no atexit, no flush — the writer
+  thread dies wherever it stands), self-delivers a REAL SIGTERM with the
+  flight recorder armed and a ``PreemptionNotice`` installed (the graceful
+  drain), or resumes from the last durable generation. Not for direct use:
+  ``_spawn_train_child`` starts it under ``JAX_PLATFORMS=cpu``.
+* **The preemption drill** (``_run_drill``) — the child dies by SIGKILL at
+  world 8; the parent finds the last DURABLE generation (a torn one scans as
+  manifest-less and is skipped), resumes at world 4 and runs to the target.
+  The oracle is an INDEPENDENT reference: a fresh world-8 run recomputes the
+  checkpointed step from scratch, checkpoints synchronously, reshards to 4
+  and runs the same steps — loss trajectory and final master arena must
+  match the resumed run BITWISE. That proves both halves at once: the async
+  snapshot captured the true state, and resharding + resume replay the
+  exact trajectory.
+* **The chaos soak** — PR 12 proved SINGLE-fault recovery bitwise;
+  production preemptible slices deliver fault SEQUENCES — a SIGTERM notice
+  while a generation is in flight, a host lost right after capacity grew
+  back, a hung rank discovered mid-shrink. ``generate_schedule`` composes
+  the whole fault menagerie into seeded random schedules and
+  ``run_schedule`` holds every one to the same oracle: the run must end with
+  the master arena BITWISE-EQUAL to an uninterrupted reference.
+  ``growback_drill`` is the deterministic 4→8 grow-back; ``run_soak`` is all
+  of ``SCHEDULE_SEEDS`` plus the grow drill.
+* **The goodput run** (``_goodput_run``) — a seeded preempt 8→4 / grow-back
+  4→8 schedule under a live timeline; ``goodput_report`` must sum its
+  integer-microsecond breakdown EXACTLY to wall time.
 
 Fault kinds (all injectors live in :mod:`beforeholiday_tpu.testing.faults`
 or ride the elastic subsystem's own hooks):
@@ -34,14 +66,6 @@ master arena, per-step loss, and per-step world must all match bitwise.
 Detection timing (watchdog wall clocks) may vary run to run; the oracle
 keys on OBSERVED events, so a hang that fires late (or not at all) still
 yields a consistent comparison.
-
-Gated keys: ``chaos_schedules_survived`` (all-of-N bitwise) and
-``growback_resume_bitwise`` (the dedicated 4→8 grow drill); the grow-back
-stall meter (``growback_stall_s``) is wall-clock and reported ungated.
-
-Run as ``python -m beforeholiday_tpu.testing.chaos_bench`` (``--quick``
-shrinks sizes) under ``JAX_PLATFORMS=cpu
-XLA_FLAGS=--xla_force_host_platform_device_count=8``; prints one JSON line.
 """
 
 from __future__ import annotations
@@ -53,19 +77,374 @@ import json
 import os
 import random
 import signal
-import tempfile
+import subprocess
+import sys
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from beforeholiday_tpu.testing import elastic_bench as eb
-
 WORLD = 8
+RESUME_WORLD = 4
 CKPT_EVERY = 2
 SCHEDULE_SEEDS = (0, 1, 2, 3, 4, 5)
 
 _IN_PROCESS_KINDS = ("shrink", "signal", "grow", "torn", "hang")
+
+
+def _geometry(quick: bool):
+    """(dim, layers, rows) for the drill model — rows divisible by both the
+    full and the surviving world so the same global batch shards either way."""
+    return (32, 4, 16) if quick else (64, 8, 16)
+
+
+def _params(dim: int, layers: int):
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(0)
+    return {
+        f"w{i:02d}": jnp.asarray(
+            (rng.randn(dim, dim) / np.sqrt(dim)).astype(np.float32)
+        )
+        for i in range(layers)
+    }
+
+
+def _batch_fn(rows: int, dim: int):
+    """Global batch keyed on the global step — a replay after reload sees
+    identical data, which is what makes the continued trajectory bitwise."""
+    import jax.numpy as jnp
+
+    def batch(step: int):
+        rng = np.random.RandomState(10_000 + int(step))
+        return jnp.asarray(rng.randn(rows, dim).astype(np.float32))
+
+    return batch
+
+
+def _engine(dim: int, layers: int):
+    """(params, layout, opt, make_step) — the pieces ElasticTrainer wants."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from beforeholiday_tpu.elastic import zero3_state_specs
+    from beforeholiday_tpu.monitor import comms as mon_comms
+    from beforeholiday_tpu.optimizers import ZeRO3FusedAdam, zero3
+
+    import functools
+
+    _shmap = functools.partial(jax.shard_map, check_vma=False)
+
+    params = _params(dim, layers)
+    layout = zero3.layout_of(params)
+    opt = ZeRO3FusedAdam(
+        lr=1e-2, weight_decay=0.02, impl="jnp",
+        prefetch=1, param_residency="keep",
+    )
+    specs = zero3_state_specs()
+
+    def make_step(mesh, world):
+        def body(state, batch):
+            def loss_fn(master):
+                p = opt.gather_params(master, layout)
+                y = batch
+                for k in sorted(p):
+                    y = jnp.tanh(y @ p[k])
+                return jnp.sum(y)
+
+            local_loss, g = jax.value_and_grad(loss_fn)(state["master"])
+            new_state = opt.step(g, state)
+            loss = mon_comms.psum(local_loss, "data", site="elastic.loss")
+            return new_state, loss
+
+        inner = jax.jit(_shmap(
+            body, mesh=mesh, in_specs=(specs, P("data")),
+            out_specs=(specs, P()),
+        ))
+
+        def step(state, gstate, batch):
+            new_state, loss = inner(state, batch)
+            return new_state, gstate, {"loss": loss}
+
+        return step
+
+    return params, layout, opt, make_step
+
+
+def _require_mesh():
+    import jax
+
+    if len(jax.devices()) < WORLD or jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"the drills need a >= {WORLD}-device CPU platform, "
+            f"got {len(jax.devices())} x {jax.default_backend()}"
+        )
+
+
+# --------------------------------------------------------------- drill child
+def _train_role(args) -> None:
+    """The drill child. Three shapes, picked by flags:
+
+    * ``--kill-at N`` (default drill): train with async checkpoints, then
+      SIGKILL the whole process right after committing N steps — whatever
+      generation is in flight stays torn on disk.
+    * ``--term-at N [--arm-notice --dump PATH]``: self-deliver a REAL
+      SIGTERM after committing N steps with the flight recorder's
+      preemption dump armed and a ``PreemptionNotice`` installed — the
+      handler dumps the black box, hands off to the notice (no signal
+      re-delivery), the run loop drains, and the child exits 0 printing a
+      JSON line (``_spawn_leg``'s graceful-drain drill).
+    * ``--resume``: restore from the last durable generation in ``--dir``
+      at ``--world`` ranks instead of ``init`` (the post-fault child).
+    """
+    _require_mesh()
+    import contextlib
+
+    from beforeholiday_tpu.elastic import ElasticTrainer, PreemptionNotice
+    from beforeholiday_tpu.monitor.flight import FlightRecorder
+
+    dim, layers, rows = _geometry(args.quick)
+    params, layout, opt, make_step = _engine(dim, layers)
+    batch = _batch_fn(rows, dim)
+    world = args.world or WORLD
+    notice = None
+    if args.arm_notice:
+        notice = PreemptionNotice((signal.SIGTERM,)).install()
+    trainer = ElasticTrainer(
+        opt, layout, make_step, directory=args.dir,
+        checkpoint_every=args.ckpt_every, queue_depth=2, keep=2,
+        hosts=args.hosts, notice=notice,
+    )
+    rec = FlightRecorder(path=args.dump) if args.dump else None
+    drained = False
+    with rec if rec is not None else contextlib.nullcontext():
+        if rec is not None:
+            # armed AFTER the notice installed: the recorder's handler owns
+            # the signal, dumps first, then finds the notice registered as
+            # the graceful consumer — drain instead of re-delivery
+            rec.arm_preemption_dump(signal.SIGTERM)
+        if args.resume:
+            trainer.restore(world=world)
+        else:
+            trainer.init(params, world=world)
+        while trainer.global_step < args.total:
+            trainer.run(1, batch)
+            if trainer.events and trainer.events[-1].reason == (
+                "preemption_drain"
+            ):
+                # leave the recorder context BEFORE exiting: a sys.exit
+                # inside it would dump again (exception:SystemExit) over
+                # the preemption dump we are about to report
+                drained = True
+                break
+            if args.kill_at and trainer.global_step == args.kill_at:
+                os.kill(os.getpid(), signal.SIGKILL)
+            if args.term_at and trainer.global_step == args.term_at:
+                os.kill(os.getpid(), signal.SIGTERM)
+        if args.kill_at and not drained:
+            raise RuntimeError(
+                f"train child survived to step {trainer.global_step} "
+                f"without being killed (kill_at={args.kill_at})"
+            )
+    trainer.close()
+    if drained:
+        print(json.dumps({
+            "drained_at": trainer.global_step,
+            "world": trainer.world,
+            "dumps": list(rec.dumps) if rec is not None else [],
+        }))
+        sys.exit(0)
+    print(json.dumps({
+        "finished_at": trainer.global_step, "world": trainer.world,
+    }))
+
+
+def _child_env() -> dict:
+    """Env for a drill child: CPU platform, 8 virtual devices,
+    repo root importable."""
+    repo_root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = (
+        f"--xla_force_host_platform_device_count={WORLD}"
+    )
+    env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
+    return env
+
+
+def _spawn_train_child(ckpt_dir: str, *, quick: bool,
+                       extra_args: list = (), timeout: float = 300.0):
+    """Run a ``--role train`` child with ``extra_args`` appended; returns
+    the ``CompletedProcess`` (callers assert on rc/stdout — ``_spawn_leg``
+    reuses this for its SIGTERM/SIGKILL legs)."""
+    cmd = [
+        sys.executable, "-m", "beforeholiday_tpu.testing.drills",
+        "--role", "train", "--dir", ckpt_dir,
+    ] + list(extra_args)
+    if quick:
+        cmd.append("--quick")
+    return subprocess.run(
+        cmd, capture_output=True, text=True, timeout=timeout,
+        env=_child_env(),
+    )
+
+
+def _spawn_killed_child(ckpt_dir: str, *, quick: bool, total: int,
+                        kill_at: int, ckpt_every: int) -> int:
+    """Run the drill child to its SIGKILL; returns the (negative) rc."""
+    proc = _spawn_train_child(
+        ckpt_dir, quick=quick, extra_args=[
+            "--total", str(total), "--kill-at", str(kill_at),
+            "--ckpt-every", str(ckpt_every),
+        ],
+    )
+    if proc.returncode != -signal.SIGKILL:
+        raise AssertionError(
+            f"drill child was supposed to die by SIGKILL, got rc="
+            f"{proc.returncode}\nstdout: {proc.stdout[-2000:]}\n"
+            f"stderr: {proc.stderr[-2000:]}"
+        )
+    return proc.returncode
+
+
+# ------------------------------------------------------- preemption drill
+def _run_drill(tmp: str, quick: bool):
+    from beforeholiday_tpu import elastic
+    from beforeholiday_tpu.elastic import ElasticTrainer
+
+    dim, layers, rows = _geometry(quick)
+    params, layout, opt, make_step = _engine(dim, layers)
+    batch = _batch_fn(rows, dim)
+    # with queue_depth=2, submit N returning means generation N-6 finished
+    # (the bounded queue is the proof): killing after the step-10 submit
+    # guarantees at least gens 2 and 4 are durable, whatever the writer's
+    # fsync pace — the kill still usually tears whatever is in flight
+    total, kill_at, ckpt_every = 16, 11, 2
+
+    child_dir = os.path.join(tmp, "drill")
+    killed_rc = _spawn_killed_child(
+        child_dir, quick=quick, total=total, kill_at=kill_at,
+        ckpt_every=ckpt_every,
+    )
+
+    gen = elastic.latest_generation(child_dir)
+    if gen is None:
+        gens = elastic.list_generations(child_dir)
+        raise AssertionError(
+            f"no durable generation survived the SIGKILL; saw {gens}"
+        )
+    resumed_from, _ = gen
+    replay = total - resumed_from
+    if not 0 < replay < total:
+        raise AssertionError(
+            f"drill resumed from step {resumed_from} (kill at {kill_at}) — "
+            "the checkpoint cadence is broken"
+        )
+
+    # resume the survivors at the smaller world
+    with ElasticTrainer(
+        opt, layout, make_step, directory=child_dir, checkpoint_every=0,
+    ) as resumed:
+        got = resumed.restore(world=RESUME_WORLD)
+        if got != resumed_from:
+            raise AssertionError(
+                f"restore landed on step {got}, latest durable is "
+                f"{resumed_from}"
+            )
+        resumed_hist = resumed.run(replay, batch)
+        resumed_master = np.asarray(resumed.state["master"])
+
+    # independent reference: recompute the checkpointed step from scratch,
+    # checkpoint synchronously, reshard, run the same steps
+    ref_dir = os.path.join(tmp, "reference")
+    with ElasticTrainer(
+        opt, layout, make_step, directory=ref_dir, checkpoint_every=0,
+    ) as ref:
+        ref.init(params, world=WORLD)
+        ref.run(resumed_from, batch)
+        ref.checkpoint_now(wait=True)
+        ref.restore(world=RESUME_WORLD)
+        ref_hist = ref.run(replay, batch)
+        ref_master = np.asarray(ref.state["master"])
+
+    if [r["step"] for r in resumed_hist] != [r["step"] for r in ref_hist]:
+        raise AssertionError("resumed and reference step ids diverged")
+    for a, b in zip(resumed_hist, ref_hist):
+        if a["loss"] != b["loss"]:
+            raise AssertionError(
+                f"loss trajectory diverged at step {a['step']}: resumed "
+                f"{a['loss']!r} vs reference {b['loss']!r}"
+            )
+    if resumed_master.dtype != ref_master.dtype or not np.array_equal(
+        resumed_master, ref_master
+    ):
+        raise AssertionError(
+            "final master arena of the resumed run is not bitwise equal to "
+            "the uninterrupted reference at the same world size"
+        )
+    return {
+        "killed_rc": killed_rc,
+        "resumed_from_step": resumed_from,
+        "drill_steps_replayed": replay,
+    }
+
+
+# ------------------------------------------------------------ goodput run
+def _goodput_run(tmpdir: str):
+    """One seeded fault-schedule run (preempt 8->4, grow back 4->8) under a
+    live timeline; returns the exact-sum goodput report."""
+    from beforeholiday_tpu import elastic
+    from beforeholiday_tpu.elastic import ElasticTrainer
+    from beforeholiday_tpu.monitor import compile_counts, goodput_report
+    from beforeholiday_tpu.monitor.trace import timeline
+    from beforeholiday_tpu.testing.faults import preempt_after
+
+    dim, layers, rows = _geometry(True)
+    params, layout, opt, make_step = _engine(dim, layers)
+    elastic.reset_ckpt_ledger()
+    trainer = ElasticTrainer(
+        opt, layout, make_step, directory=tmpdir,
+        checkpoint_every=2, queue_depth=2, keep=3,
+        capacity_probe=lambda: WORLD, grow_when_available=True,
+    )
+    with timeline() as rec:
+        trainer.init(params, world=WORLD)
+        # preempt on the 5th tick -> resize to the survivor world; the
+        # capacity probe reports the full world at every checkpoint
+        # boundary after that, so the next boundary grows back to 8
+        trainer.run(
+            10, _batch_fn(rows, dim),
+            preemption=preempt_after(5, surviving_world=RESUME_WORLD),
+        )
+        trainer.close()
+    events = rec.events()
+    report = goodput_report(
+        events,
+        resize_events=trainer.events,
+        ckpt=elastic.ckpt_summary(),
+        compile_counts=compile_counts(),
+    )
+    # the classifier's contract: the integer breakdown sums to wall EXACTLY
+    parts = sum(report[k] for k in (
+        "productive_us", "checkpoint_us", "drain_us", "restore_us",
+        "hang_us", "reshard_us", "compile_us", "other_us",
+    ))
+    assert parts == report["wall_us"], (parts, report["wall_us"])
+    # both resizes really happened and their machinery was booked
+    reasons = [e.reason for e in trainer.events]
+    assert reasons == ["preemption", "grow"], reasons
+    assert report["restore_us"] > 0 and report["reshard_us"] > 0, report
+    assert report["productive_us"] > 0
+    # checkpoint badput is the ledger's exposed time as seen from the run
+    # loop: never more than what the ckpt ledger itself booked (writer
+    # thread excluded on both sides), and present once generations exist
+    assert report["checkpoint_s"] <= report["ckpt_exposed_s"] + 0.05, report
+    return report, trainer.events
+
+
+# ------------------------------------------------------------- chaos soak
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,7 +493,7 @@ def generate_schedule(seed: int, *, spawn: Optional[str] = None
     w = 4 if spawn == "sigkill" else WORLD   # sigkill leg resumes at 4
     # sigkill must land AFTER the bounded queue has proven earlier
     # generations durable (submit N returning means N-6 finished with
-    # queue_depth=2) — same timing argument as elastic_bench's drill; a
+    # queue_depth=2) — same timing argument as ``_run_drill``'s; a
     # graceful drain needs no such margin, it waits the writer itself
     spawn_at = 11 if spawn == "sigkill" else 5
     step = (spawn_at + 5 if spawn else 0) + rng.randint(3, 5)
@@ -229,7 +608,7 @@ def _spawn_leg(sched: FaultSchedule, ckpt_dir: str, tmp: str,
     from beforeholiday_tpu import elastic
 
     if sched.spawn == "sigkill":
-        proc = eb._spawn_train_child(
+        proc = _spawn_train_child(
             ckpt_dir, quick=quick, extra_args=[
                 "--total", str(sched.spawn_at + 6),
                 "--kill-at", str(sched.spawn_at),
@@ -244,7 +623,7 @@ def _spawn_leg(sched: FaultSchedule, ckpt_dir: str, tmp: str,
             )
         return {"rc": proc.returncode, "resume_world": 4, "dump": None}
     dump = os.path.join(tmp, f"dump_{sched.seed}.json")
-    proc = eb._spawn_train_child(
+    proc = _spawn_train_child(
         ckpt_dir, quick=quick, extra_args=[
             "--total", str(sched.spawn_at + 10),
             "--term-at", str(sched.spawn_at),
@@ -290,10 +669,10 @@ def run_schedule(sched: FaultSchedule, tmp: str, quick: bool
     )
     from beforeholiday_tpu.testing import faults as flt
 
-    dim, layers, rows = eb._geometry(quick)
-    engine = eb._engine(dim, layers)
+    dim, layers, rows = _geometry(quick)
+    engine = _engine(dim, layers)
     params, layout, opt, make_step = engine
-    base_bf = eb._batch_fn(rows, dim)
+    base_bf = _batch_fn(rows, dim)
     needs_pace = any(f.kind == "hang" for f in sched.faults)
 
     def bf(step):
@@ -444,9 +823,9 @@ def growback_drill(tmp: str, quick: bool) -> Dict[str, Any]:
     that same generation."""
     from beforeholiday_tpu.elastic import ElasticTrainer
 
-    dim, layers, rows = eb._geometry(quick)
-    params, layout, opt, make_step = eb._engine(dim, layers)
-    bf = eb._batch_fn(rows, dim)
+    dim, layers, rows = _geometry(quick)
+    params, layout, opt, make_step = _engine(dim, layers)
+    bf = _batch_fn(rows, dim)
     cap = {"n": 4}
     # capacity returns right after step 6 commits — step 6's boundary
     # already probed cap=4, so the grow lands at the NEXT boundary, step 8
@@ -481,7 +860,7 @@ def growback_drill(tmp: str, quick: bool) -> Dict[str, Any]:
     ref_master, ref_history = replay_reference(
         [(0, 4), (grow_boundary, WORLD)], total,
         os.path.join(tmp, "grow_ref"),
-        engine=eb._engine(dim, layers), batch_fn=bf,
+        engine=_engine(dim, layers), batch_fn=bf,
     )
     final_rows = {}
     for row in history:
@@ -497,11 +876,14 @@ def growback_drill(tmp: str, quick: bool) -> Dict[str, Any]:
     return {"growback_resume_bitwise": 1.0, "growback_stall_s": stall}
 
 
-# ---------------------------------------------------------------------- rungs
+# ------------------------------------------------------------------ the soak
 
 
-def main(quick: bool = False):
-    eb._require_mesh()
+def run_soak(tmp: str, quick: bool) -> Dict[str, Any]:
+    """Every seeded schedule plus the grow drill; each asserts its bitwise
+    oracle, so returning at all means all survived. Returns the grow drill's
+    facts and one ``run_schedule`` summary per schedule."""
+    _require_mesh()
 
     schedules = [
         generate_schedule(s, spawn=(
@@ -523,68 +905,32 @@ def main(quick: bool = False):
     if not any("grow" in s.kinds for s in schedules):
         raise AssertionError("no schedule includes grow-back")
 
-    results = []
-    with tempfile.TemporaryDirectory(prefix="chaos_bench_") as tmp:
-        grow = growback_drill(tmp, quick)
-        for sched in schedules:
-            results.append(run_schedule(sched, tmp, quick))
+    grow = growback_drill(tmp, quick)
+    results = [run_schedule(sched, tmp, quick) for sched in schedules]
 
     survived = sum(1 for r in results if r["bitwise"] == 1.0)
     if survived != len(schedules):
         raise AssertionError(
             f"only {survived}/{len(schedules)} schedules survived"
         )
-    grow_stalls = [s for r in results for s in r["grow_stalls_s"]]
-    grow_stalls.append(grow["growback_stall_s"])
-    sigkill = [r for r in results if "sigkill" in r["kinds"]]
-    sigterm = [r for r in results if "sigterm" in r["kinds"]]
-    out = {
-        "chaos_schedules_survived": survived,
-        "chaos_schedules_total": len(schedules),
-        "chaos_fault_kinds": sorted(
-            set(k for r in results for k in r["kinds"])
-        ),
-        "chaos_total_events": sum(r["n_events"] for r in results),
-        "chaos_sigkill_rc": sigkill[0]["spawn_rc"] if sigkill else None,
-        "chaos_sigterm_drain_rc": (
-            sigterm[0]["spawn_rc"] if sigterm else None
-        ),
-        "chaos_sigterm_dump_written": (
-            1 if (sigterm and sigterm[0]["spawn_dump"]) else 0
-        ),
-        "growback_resume_bitwise": grow["growback_resume_bitwise"],
-        "growback_stall_s": round(float(np.max(grow_stalls)), 4),
-        "growback_stall_mean_s": round(float(np.mean(grow_stalls)), 4),
-        "schedules": [
-            {
-                "seed": r["seed"], "kinds": r["kinds"],
-                "events": r["event_reasons"],
-                "lineage": [list(e) for e in r["lineage"]],
-            }
-            for r in results
-        ],
-        # the survived count and the grow drill's bitwise verdict repeat by
-        # construction (same seeds, same oracle); a full second soak would
-        # double the stage's runtime for no extra information — mirror the
-        # elastic stage's pattern and re-assert the verified values
-        "pass2": {
-            "chaos_schedules_survived": survived,
-            "growback_resume_bitwise": grow["growback_resume_bitwise"],
-        },
-        "config": (
-            f"world={WORLD} ckpt_every={CKPT_EVERY} "
-            f"seeds={list(SCHEDULE_SEEDS)} geom={eb._geometry(quick)}"
-        ),
-    }
-    print(json.dumps(out))
-    return out
+    return {"growback": grow, "schedules": results}
 
 
 def _cli():
     ap = argparse.ArgumentParser()
+    ap.add_argument("--role", choices=("train",), required=True)
     ap.add_argument("--quick", action="store_true")
-    args = ap.parse_args()
-    main(quick=args.quick)
+    ap.add_argument("--dir", required=True)
+    ap.add_argument("--total", type=int, default=16)
+    ap.add_argument("--kill-at", dest="kill_at", type=int, default=0)
+    ap.add_argument("--term-at", dest="term_at", type=int, default=0)
+    ap.add_argument("--ckpt-every", dest="ckpt_every", type=int, default=2)
+    ap.add_argument("--world", type=int, default=0)
+    ap.add_argument("--hosts", type=int, default=1)
+    ap.add_argument("--resume", action="store_true")
+    ap.add_argument("--arm-notice", dest="arm_notice", action="store_true")
+    ap.add_argument("--dump", default=None)
+    _train_role(ap.parse_args())
 
 
 if __name__ == "__main__":

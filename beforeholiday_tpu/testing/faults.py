@@ -37,27 +37,7 @@ from typing import Any, Callable, Iterator, Optional
 import jax
 import jax.numpy as jnp
 
-
-class SimulatedPreemption(RuntimeError):
-    """In-process stand-in for a preemption notice / lost rank.
-
-    ``surviving_world`` optionally names the world size that remains after
-    the event (e.g. a host carrying 4 of 8 ranks died); ``None`` defers to
-    the elastic trainer's ``survivor_policy``. ``drain=True`` marks a
-    GRACEFUL notice (the shape of a real SIGTERM from the scheduler: this
-    process itself is going away) — the elastic trainer responds by making
-    its state durable and returning cleanly instead of resizing in place.
-    Raised by :func:`preempt_after` and by
-    :meth:`~beforeholiday_tpu.elastic.signals.PreemptionNotice.tick`;
-    catchable anywhere a real preemption callback would fire.
-    """
-
-    def __init__(self, message: str = "simulated preemption", *,
-                 surviving_world: Optional[int] = None,
-                 drain: bool = False):
-        super().__init__(message)
-        self.surviving_world = surviving_world
-        self.drain = bool(drain)
+from beforeholiday_tpu.elastic.signals import SimulatedPreemption  # noqa: F401 — re-exported
 
 
 def poison_grads(

@@ -22,8 +22,8 @@ mesh coordinate routes its own token group, GShard's "group = local batch".
 split as ``emulate_tensor`` column/row chunks accumulated in rank order
 (CPU psum order), the groups as a Python loop in mesh order. At sufficient
 capacity the distributed forward equals the reference BITWISE for any
-(data, tensor, pipe, expert) carve — the parity the tests and
-``testing/moe_bench.py``'s ``moe_4d_mesh_parity`` rung assert.
+(data, tensor, pipe, expert) carve — the parity
+``tests/test_moe.py::test_4d_mesh_parity`` asserts.
 """
 
 from __future__ import annotations

@@ -447,7 +447,8 @@ def _min_step_seconds(run, state, steps: int = 8, iters: int = 3) -> float:
 
 
 def _gpt_train_step(opt_level: str, cfg, batch: int):
-    """The bench.py GPT rung pattern: amp + FusedAdam + scaled_value_and_grad,
+    """The GPT cells' step (``benchmark/families/gpt.py``) at check size:
+    amp + FusedAdam + scaled_value_and_grad,
     arena-native PackedParams (O5/O6 are master-weight levels). Returns
     ``(run, state, n_params, n_dense, tokens_per_step)``."""
     from beforeholiday_tpu import amp
@@ -599,8 +600,8 @@ def collective_matmul_overlap() -> dict:
     """Ring collective matmul vs monolithic all-gather-then-matmul under
     real ICI: the ppermute ring must hide the SP all-gather behind partial
     GEMMs (bitwise parity is pinned on the CPU mesh by
-    collective_matmul_bench; THIS measures whether the overlap pays on
-    hardware)."""
+    tests/test_collective_matmul.py; THIS measures whether the overlap pays
+    on hardware)."""
     skip = _skip_off_tpu()
     if skip:
         return skip

@@ -21,8 +21,8 @@ configs, and never push a strict bucket-gated entry over its budget.
 
 Budgeting: ``max_trials`` bounds trial_fn invocations; ``steps_per_trial``
 is the rung-0 horizon, doubled (``eta``) each promotion rung;
-``iters`` timings per trial with min-of-iters (the bench meter's
-convention — the minimum is the least-noise estimator on a shared host).
+``iters`` timings per trial with min-of-iters (the minimum is the
+least-noise estimator on a shared host).
 """
 
 from __future__ import annotations

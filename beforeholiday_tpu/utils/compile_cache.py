@@ -2,8 +2,8 @@
 
 A chip call may start on a machine with nothing compiled, and the flagship
 step takes tens of seconds to build. Every entry point (``chip_smoke.py``,
-``bench.py``, ``testing.tpu_checks``, the example trainers and the serve
-driver) calls :func:`enable_compile_cache` before its first compile.
+``benchmark/run.py``, ``testing.tpu_checks``, the example trainers and the
+serve driver) calls :func:`enable_compile_cache` before its first compile.
 
 The directory is part of the cache key's lookup path, so it must not move
 between runs: never a ``tempfile`` name, a pid or a timestamp.

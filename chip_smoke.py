@@ -114,7 +114,7 @@ def _reset_dispatch():
 
 
 def build_step(cfg, reduce_grads=None):
-    """The flagship step exactly as ``bench.py:make_gpt_rung`` builds it.
+    """The flagship step, wired as ``benchmark/families/gpt.py`` wires the cells'.
     Returns ``(step, state)``: ``step(params, opt_state, scaler_state, tokens,
     targets) -> (params, opt_state, scaler_state, loss, found_inf)``."""
     from beforeholiday_tpu import amp

@@ -63,7 +63,7 @@ def synthetic_trace(
     shared_prefix_tokens: int = 0,
     prefix_families: int = 4,
 ) -> List[Request]:
-    """Poisson arrivals with uniform prompt/generation lengths — the bench's
+    """Poisson arrivals with uniform prompt/generation lengths — a
     synthetic open-loop load (arrival times are offsets from trace start).
 
     ``shared_prefix_tokens > 0`` switches to a PREFIX-HEAVY workload: the

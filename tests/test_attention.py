@@ -206,7 +206,7 @@ class TestFlashOnlyDispatch:
     """Above the oracle-score budget the jnp fallback is not a viable
     degradation target (it materializes O(S^2) fp32 scores through autodiff),
     so dispatch must become flash-ONLY: no probe, no downgrade, the dispatch
-    booked via ``count_forced`` — the S=8192 backward bench rung's contract,
+    booked via ``count_forced`` — the contract of an S=8192 backward,
     pinned here at unit size by shrinking the budget instead of the shape."""
 
     def _booked(self):

@@ -13,9 +13,6 @@ The contracts this file pins (see beforeholiday_tpu/parallel/bucketing.py):
   fp32) and per-site call counts equal to the bucket count.
 """
 
-import subprocess
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -425,6 +422,12 @@ def _zero2_setup(seed):
     return params, grads
 
 
+_Z2_STATE_SPECS = {
+    "master": P("data"), "exp_avg": P("data"), "exp_avg_sq": P("data"),
+    "step": P(),
+}
+
+
 class TestZero2Bucketed:
     def _step(self, mesh, params, grads, **opt_kw):
         from beforeholiday_tpu.optimizers import DistributedFusedAdam
@@ -443,12 +446,75 @@ class TestZero2Bucketed:
             out_specs=P(),
         )
 
+    def _programs(self, mesh, **opt_kw):
+        """(init, step), each its own jitted program — a training loop's
+        form: one compiled step, driven from the host, state through HBM."""
+        from beforeholiday_tpu.optimizers import DistributedFusedAdam
+
+        opt = DistributedFusedAdam(axis_name="data", **opt_kw)
+        init = jax.jit(shard_map(
+            opt.init, mesh=mesh, in_specs=(P(),), out_specs=_Z2_STATE_SPECS))
+
+        def body(p, g, st):
+            return opt.step(p, jax.tree.map(lambda v: v[0], g), st)
+
+        step = jax.jit(shard_map(
+            body, mesh=mesh, in_specs=(P(), P("data"), _Z2_STATE_SPECS),
+            out_specs=(P(), _Z2_STATE_SPECS)))
+        return init, step
+
     def test_bucketed_step_matches_unbucketed_bitwise(self, mesh):
+        """Bucketing the ZeRO-2 collectives changes no bit of the params or
+        of the sharded state, step after step.
+
+        Red from the seed to PR 28 in another form: both optimizers unrolled
+        TWO steps inside one jit. No reduction order differs there — each
+        element of the reduce-scatter is the same 8-term sum in rank order
+        whatever bucket carries it (asserted bitwise below). What differed
+        is where XLA:CPU (jax 0.9.0) rounds the elementwise Adam chain: the
+        unbucketed unrolled program becomes ONE loop fusion holding both
+        updates, whose a*b+c pairs LLVM contracts to FMAs, while the
+        per-bucket slices of the bucketed program stop that fusion and the
+        bias-corrected moments are written to memory as float32 between the
+        two updates. One step agreed bitwise, two differed in the last place
+        (1 of 333 and 14 of 7800 elements, 1 ulp). A step per jit call is
+        both the shape a training loop runs and the same elementwise program
+        over the same (shard,) arenas on both sides, so that is what is
+        compared — and it is compared on everything the step returns."""
         params, grads = _zero2_setup(20)
-        a = self._step(mesh, params, grads)
-        b = self._step(mesh, params, grads, bucket_bytes=16 * 1024)
-        for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
-            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+        bb = 16 * 1024
+
+        (init_a, step_a), (init_b, step_b) = (
+            self._programs(mesh), self._programs(mesh, bucket_bytes=bb))
+        pa, sa = params, init_a(params)
+        pb, sb = params, init_b(params)
+
+        # order-independent: the bucket geometry tiles the shard exactly, in
+        # more than one bucket, and the reduced gradient shard is bitwise
+        padded = sa["master"].size
+        shard = padded // WORLD
+        assert shard * WORLD == padded
+        assert padded >= sum(int(np.prod(v.shape)) for v in params.values())
+        slices = bucket_slices(shard, 4 * WORLD, bb)
+        assert len(slices) > 1
+        assert slices[0][0] == 0
+        assert all(a[0] + a[1] == b[0] for a, b in zip(slices, slices[1:]))
+        assert slices[-1][0] + slices[-1][1] == shard
+        gflat = _rand((WORLD, padded), 22)
+        mono = _run(
+            mesh, lambda g: jax.lax.psum_scatter(
+                g[0], "data", scatter_dimension=0, tiled=True), gflat)
+        buck = _run(
+            mesh, lambda g: bucketed_psum_scatter(
+                g[0], "data", site="t.z2", bucket_bytes=bb), gflat)
+        np.testing.assert_array_equal(np.asarray(mono), np.asarray(buck))
+
+        for _ in range(3):
+            pa, sa = step_a(pa, grads, sa)
+            pb, sb = step_b(pb, grads, sb)
+            for x, y in zip(jax.tree.leaves((pa, sa)),
+                            jax.tree.leaves((pb, sb))):
+                np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
     def test_compressed_step_close(self, mesh):
         params, grads = _zero2_setup(21)
@@ -631,32 +697,3 @@ class TestSpecMemoization:
         xs = [jnp.zeros((64, 3)), jnp.zeros((17,))]
         ys = [jnp.ones((64, 3)), jnp.ones((17,))]
         assert make_spec(xs) is make_spec(ys)
-
-
-# ----------------------------------------------------------- perf proxies
-
-
-@pytest.mark.comms_perf
-@pytest.mark.slow
-def test_comms_bench_subprocess():
-    """The bench entry point emits a sane JSON line (quick sizes)."""
-    import json
-    import os
-
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    out = subprocess.run(
-        [sys.executable, "-m", "beforeholiday_tpu.testing.comms_bench",
-         "--quick"],
-        env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert out.returncode == 0, out.stderr[-500:]
-    res = json.loads(out.stdout.strip().splitlines()[-1])
-    for key in ("ddp_bucketed_vs_monolithic", "zero2_compressed_vs_fp32",
-                "bucket_bytes", "n_buckets"):
-        assert key in res
-    assert res["ddp_bucketed_vs_monolithic"] > 0
-    assert res["zero2_compressed_max_err"] < 0.1
-    # the jitted entries must not have recompiled mid-bench
-    assert all(not row["recompiled"] for row in res["compile_counters"])

@@ -13,8 +13,6 @@ from __future__ import annotations
 
 import os
 import signal
-import subprocess
-import sys
 import time
 
 import pytest
@@ -27,12 +25,11 @@ from beforeholiday_tpu.elastic import (
     watchdog_records,
 )
 from beforeholiday_tpu.elastic.signals import _signame
-from beforeholiday_tpu.testing import chaos_bench as cb
+from beforeholiday_tpu.testing import drills
 from beforeholiday_tpu.testing.faults import SimulatedPreemption, hang_rank
 
 pytestmark = pytest.mark.chaos
 
-_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ---------------------------------------------------------------------------
@@ -193,36 +190,36 @@ class TestHangWatchdog:
 
 class TestScheduleGenerator:
     def test_deterministic(self):
-        assert cb.generate_schedule(3) == cb.generate_schedule(3)
-        assert (cb.generate_schedule(0, spawn="sigkill")
-                == cb.generate_schedule(0, spawn="sigkill"))
+        assert drills.generate_schedule(3) == drills.generate_schedule(3)
+        assert (drills.generate_schedule(0, spawn="sigkill")
+                == drills.generate_schedule(0, spawn="sigkill"))
 
     def test_acceptance_shape_of_the_soak_set(self):
-        """The exact composition the bench gates: >= 6 schedules, each
+        """The exact composition ``run_soak`` requires: >= 6 schedules, each
         composing >= 2 distinct fault kinds, >= 1 with SIGKILL, >= 1 with
         grow-back — pinned here so a generator edit that silently weakens
-        the soak fails a fast unit, not a 10-minute bench."""
+        the soak fails a fast unit, not a slow soak."""
         schedules = [
-            cb.generate_schedule(s, spawn=(
+            drills.generate_schedule(s, spawn=(
                 "sigkill" if s == 0 else "sigterm" if s == 1 else None
             ))
-            for s in cb.SCHEDULE_SEEDS
+            for s in drills.SCHEDULE_SEEDS
         ]
         assert len(schedules) >= 6
         for sch in schedules:
             assert len(set(sch.kinds)) >= 2, sch
             for f in sch.faults:
-                assert f.kind in cb._IN_PROCESS_KINDS
+                assert f.kind in drills._IN_PROCESS_KINDS
                 # every fault lands after the first durable generation can
                 # exist and before the run's tail
-                assert cb.CKPT_EVERY < f.at_step < sch.total
+                assert drills.CKPT_EVERY < f.at_step < sch.total
         assert any(s.spawn == "sigkill" for s in schedules)
         assert any(s.spawn == "sigterm" for s in schedules)
         assert any("grow" in s.kinds for s in schedules)
 
     def test_torn_is_always_paired_with_a_shrink(self):
         for seed in range(20):
-            sch = cb.generate_schedule(seed)
+            sch = drills.generate_schedule(seed)
             faults = sorted(sch.faults, key=lambda f: f.at_step)
             for i, f in enumerate(faults):
                 if f.kind == "torn":
@@ -239,37 +236,37 @@ class _Ev:
 
 class TestFinalLineage:
     def test_empty(self):
-        assert cb.final_lineage([(0, 8)], []) == [(0, 8)]
+        assert drills.final_lineage([(0, 8)], []) == [(0, 8)]
 
     def test_simple_shrink_chain(self):
         evs = [_Ev("preemption", 4, 4), _Ev("hang", 10, 2)]
-        assert cb.final_lineage([(0, 8)], evs) == [(0, 8), (4, 4), (10, 2)]
+        assert drills.final_lineage([(0, 8)], evs) == [(0, 8), (4, 4), (10, 2)]
 
     def test_rollback_replays_over_earlier_segments(self):
         """A resize that resumes from an OLDER generation than a previous
         event's boundary erases that segment from the final trajectory."""
         evs = [_Ev("preemption", 8, 4), _Ev("tripwire", 6, 2)]
-        assert cb.final_lineage([(0, 8)], evs) == [(0, 8), (6, 2)]
+        assert drills.final_lineage([(0, 8)], evs) == [(0, 8), (6, 2)]
 
     def test_drain_rolls_nothing_back(self):
         evs = [_Ev("preemption_drain", 5, 8), _Ev("grow", 6, 8)]
-        assert cb.final_lineage([(0, 4)], evs) == [(0, 4), (6, 8)]
+        assert drills.final_lineage([(0, 4)], evs) == [(0, 4), (6, 8)]
 
     def test_spawn_leg_initial_lineage(self):
         evs = [_Ev("grow", 12, 8)]
-        assert cb.final_lineage([(0, 8), (10, 4)], evs) == [
+        assert drills.final_lineage([(0, 8), (10, 4)], evs) == [
             (0, 8), (10, 4), (12, 8),
         ]
 
     def test_starts_strictly_increase(self):
         evs = [_Ev("preemption", 4, 4), _Ev("preemption", 4, 2)]
-        lin = cb.final_lineage([(0, 8)], evs)
+        lin = drills.final_lineage([(0, 8)], evs)
         assert lin == [(0, 8), (4, 2)]
         assert all(a[0] < b[0] for a, b in zip(lin, lin[1:]))
 
 
 # ---------------------------------------------------------------------------
-# soak legs (slow): one live schedule in-process, the full set via the bench
+# soak legs (slow): one live schedule, then the full set (drills.run_soak)
 # ---------------------------------------------------------------------------
 
 
@@ -284,7 +281,7 @@ def _mesh_or_skip():
 class TestChaosSoak:
     def test_growback_drill_bitwise(self, tmp_path):
         _mesh_or_skip()
-        out = cb.growback_drill(str(tmp_path), quick=True)
+        out = drills.growback_drill(str(tmp_path), quick=True)
         assert out["growback_resume_bitwise"] == 1.0
         assert out["growback_stall_s"] > 0.0
 
@@ -293,34 +290,27 @@ class TestChaosSoak:
         lineage collapsed, reference replayed, bitwise asserted inside
         run_schedule."""
         _mesh_or_skip()
-        sched = cb.generate_schedule(3)
+        sched = drills.generate_schedule(3)
         assert {"shrink", "grow"} <= set(sched.kinds)
-        out = cb.run_schedule(sched, str(tmp_path), quick=True)
+        out = drills.run_schedule(sched, str(tmp_path), quick=True)
         assert out["bitwise"] == 1.0
         assert "grow" in out["event_reasons"]
 
-    def test_full_soak_subprocess(self):
-        """The whole bench gate in one subprocess: six seeded schedules +
-        the grow drill, every one bitwise or the child exits nonzero."""
-        import json
-
-        env = dict(os.environ)
-        env["JAX_PLATFORMS"] = "cpu"
-        env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        env["PYTHONPATH"] = _REPO_ROOT
-        proc = subprocess.run(
-            [sys.executable, "-m", "beforeholiday_tpu.testing.chaos_bench",
-             "--quick"],
-            env=env, capture_output=True, text=True, timeout=560,
-        )
-        assert proc.returncode == 0, proc.stderr[-3000:]
-        out = json.loads(proc.stdout.strip().splitlines()[-1])
-        assert out["chaos_schedules_survived"] == out["chaos_schedules_total"]
-        assert out["chaos_schedules_total"] >= 6
-        assert out["chaos_sigkill_rc"] == -signal.SIGKILL
-        assert out["chaos_sigterm_drain_rc"] == 0
-        assert out["chaos_sigterm_dump_written"] == 1
-        assert out["growback_resume_bitwise"] == 1.0
+    def test_full_soak(self, tmp_path):
+        """The whole soak: six seeded schedules (one behind a SIGKILLed
+        child, one behind a SIGTERM-drained child) + the grow drill, every
+        one bitwise or ``run_soak`` raises."""
+        _mesh_or_skip()
+        out = drills.run_soak(str(tmp_path), quick=True)
+        runs = out["schedules"]
+        assert len(runs) == len(drills.SCHEDULE_SEEDS) >= 6
+        assert all(r["bitwise"] == 1.0 for r in runs)
+        (sigkill,) = [r for r in runs if "sigkill" in r["kinds"]]
+        (sigterm,) = [r for r in runs if "sigterm" in r["kinds"]]
+        assert sigkill["spawn_rc"] == -signal.SIGKILL
+        assert sigterm["spawn_rc"] == 0
+        assert sigterm["spawn_dump"] and os.path.isfile(sigterm["spawn_dump"])
+        assert out["growback"]["growback_resume_bitwise"] == 1.0
 
 
 # ---------------------------------------------------------------------------
@@ -348,10 +338,8 @@ class TestLivenessSurfaces:
         if len(jax.devices()) < 8 or jax.default_backend() != "cpu":
             pytest.skip("needs the 8-device CPU mesh")
         from beforeholiday_tpu.elastic import ElasticTrainer
-        from beforeholiday_tpu.testing import elastic_bench as eb
-
-        params, layout, opt, make_step = eb._engine(32, 2)
-        bf = eb._batch_fn(8, 32)
+        params, layout, opt, make_step = drills._engine(32, 2)
+        bf = drills._batch_fn(8, 32)
         d = str(tmp_path)
         wd = HangWatchdog(4, hang_timeout_s=30.0)
         with ElasticTrainer(

@@ -4,7 +4,7 @@ The decomposition changes the schedule, never the numbers — so the contract
 tests are bitwise: forward AND all three grads of the sequence-parallel
 ColumnParallel layer must match the monolithic gather-then-matmul exactly.
 Plus the knob semantics (default OFF, module-wide + per-call override) and
-the per-hop comms-ledger sites the replay bench keys on.
+the per-hop comms-ledger sites.
 """
 
 import functools

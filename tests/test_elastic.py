@@ -48,7 +48,7 @@ from beforeholiday_tpu.parallel import (
     carve_data_mesh,
     check_replicated_consistency,
 )
-from beforeholiday_tpu.testing import elastic_bench as eb
+from beforeholiday_tpu.testing import drills
 from beforeholiday_tpu.testing import faults
 
 pytestmark = pytest.mark.elastic
@@ -448,7 +448,7 @@ class TestCarveAndConsistency:
 def _sharded_fixture(devices, world, guard, *, dim=16, layers=2):
     """(mesh, opt, layout, state, gstate, grads_of) on a world-sized mesh."""
     mesh = carve_data_mesh(world, devices=devices)
-    params = eb._params(dim, layers)
+    params = drills._params(dim, layers)
     layout = zero3.layout_of(params)
     opt = ZeRO3FusedAdam(lr=1e-2, impl="jnp", param_residency="keep")
     specs = zero3_state_specs()
@@ -553,7 +553,7 @@ class TestElasticTrainerDrills:
     DIM, LAYERS, ROWS = 32, 2, 8
 
     def _pieces(self):
-        return eb._engine(self.DIM, self.LAYERS)
+        return drills._engine(self.DIM, self.LAYERS)
 
     def test_preemption_resize_is_bitwise(self, tmp_path):
         """In-process preemption drill: a SimulatedPreemption on the 8th
@@ -562,7 +562,7 @@ class TestElasticTrainerDrills:
         to the same generation, checkpointed synchronously, and resharded
         to 4."""
         params, layout, opt, make_step = self._pieces()
-        batch = eb._batch_fn(self.ROWS, self.DIM)
+        batch = drills._batch_fn(self.ROWS, self.DIM)
 
         d1 = str(tmp_path / "drill")
         with ElasticTrainer(
@@ -653,7 +653,7 @@ class TestElasticTrainerDrills:
 
             return step
 
-        batch = eb._batch_fn(self.ROWS, self.DIM)
+        batch = drills._batch_fn(self.ROWS, self.DIM)
         with ElasticTrainer(
             opt, layout, make_step, directory=str(tmp_path),
             checkpoint_every=2,
@@ -675,7 +675,7 @@ class TestElasticTrainerDrills:
 
     def test_resize_below_min_world_refuses(self, tmp_path):
         params, layout, opt, make_step = self._pieces()
-        batch = eb._batch_fn(self.ROWS, self.DIM)
+        batch = drills._batch_fn(self.ROWS, self.DIM)
         with ElasticTrainer(
             opt, layout, make_step, directory=str(tmp_path),
             checkpoint_every=1, min_world=4,
@@ -692,7 +692,7 @@ class TestElasticTrainerDrills:
             opt, layout, make_step, directory=str(tmp_path),
         ) as tr:
             with pytest.raises(RuntimeError, match="init\\(\\) or restore"):
-                tr.run(1, eb._batch_fn(self.ROWS, self.DIM))
+                tr.run(1, drills._batch_fn(self.ROWS, self.DIM))
 
 
 # ---------------------------------------------------------------------------
@@ -708,7 +708,7 @@ class TestGuardStateAcrossReshard:
         the O6 amax observations threaded into the guarded update — the
         full in-flight scaler surface (scale, consecutive_overflows, amax
         history) rides the gstate."""
-        params = eb._params(self.DIM, self.LAYERS)
+        params = drills._params(self.DIM, self.LAYERS)
         layout = zero3.layout_of(params)
         opt = ZeRO3FusedAdam(lr=1e-2, impl="jnp", param_residency="keep")
         specs = zero3_state_specs()
@@ -764,7 +764,7 @@ class TestGuardStateAcrossReshard:
             check_params=True,
         )
         params, layout, opt, make_step = self._guard_engine(guard)
-        raw_batch = eb._batch_fn(self.ROWS, self.DIM)
+        raw_batch = drills._batch_fn(self.ROWS, self.DIM)
 
         def batch(step):
             poison = np.float32(1.0 if step in (4, 5) else 0.0)
@@ -816,7 +816,7 @@ class TestGuardStateAcrossReshard:
             check_params=True,
         )
         params, layout, opt, make_step = self._guard_engine(guard)
-        raw_batch = eb._batch_fn(self.ROWS, self.DIM)
+        raw_batch = drills._batch_fn(self.ROWS, self.DIM)
 
         def batch(step):
             return raw_batch(step), np.float32(0.0)
@@ -985,7 +985,7 @@ class TestResizeValidationAndGrowback:
     DIM, LAYERS, ROWS = 32, 2, 8
 
     def _trainer(self, tmp_path, **kw):
-        params, layout, opt, make_step = eb._engine(self.DIM, self.LAYERS)
+        params, layout, opt, make_step = drills._engine(self.DIM, self.LAYERS)
         tr = ElasticTrainer(
             opt, layout, make_step, directory=str(tmp_path),
             checkpoint_every=2, **kw,
@@ -996,7 +996,7 @@ class TestResizeValidationAndGrowback:
         params, tr = self._trainer(tmp_path)
         with tr:
             tr.init(params, world=4)
-            tr.run(2, eb._batch_fn(self.ROWS, self.DIM))
+            tr.run(2, drills._batch_fn(self.ROWS, self.DIM))
             with pytest.raises(ValueError, match=">= 1"):
                 tr._resize(0, reason="manual")
             with pytest.raises(ValueError, match="divide"):
@@ -1008,7 +1008,7 @@ class TestResizeValidationAndGrowback:
             assert tr.world == 4   # nothing moved
 
     def test_hosts_validation(self, tmp_path):
-        params, layout, opt, make_step = eb._engine(self.DIM, self.LAYERS)
+        params, layout, opt, make_step = drills._engine(self.DIM, self.LAYERS)
         with pytest.raises(ValueError, match="hosts"):
             ElasticTrainer(
                 opt, layout, make_step, directory=str(tmp_path), hosts=0,
@@ -1018,9 +1018,7 @@ class TestResizeValidationAndGrowback:
         """Capacity returns mid-run; the trainer grows 4 -> 8 at the next
         checkpoint boundary and the continued run matches a reference that
         resharded the same generation."""
-        from beforeholiday_tpu.testing import chaos_bench as cb
-
-        out = cb.growback_drill(str(tmp_path), quick=True)
+        out = drills.growback_drill(str(tmp_path), quick=True)
         assert out["growback_resume_bitwise"] == 1.0
         assert out["growback_stall_s"] > 0.0
 
@@ -1037,8 +1035,21 @@ class TestResizeValidationAndGrowback:
 
 
 # ---------------------------------------------------------------------------
-# the real-signal drain drill (subprocess; slow)
+# the kill/resume drill and the real-signal drain drill (subprocess; slow)
 # ---------------------------------------------------------------------------
+
+
+class TestKillResumeDrill:
+    def test_sigkill_at_8_resumes_at_4_bitwise(self, tmp_path):
+        """The preemption drill: a child training at world 8 with async
+        checkpoints SIGKILLs itself mid-run; the last durable generation
+        resumes at world 4 and must match, loss by loss and arena for arena,
+        an independent uninterrupted reference resharded from the same step
+        (asserted inside ``_run_drill``)."""
+        out = drills._run_drill(str(tmp_path), quick=True)
+        assert out["killed_rc"] == -signal.SIGKILL
+        assert 0 < out["resumed_from_step"] < 11    # the kill came after 11
+        assert out["drill_steps_replayed"] == 16 - out["resumed_from_step"]
 
 
 @pytest.mark.slow
@@ -1050,7 +1061,7 @@ class TestGracefulDrainDrill:
         signal, no torn tail."""
         ckpt = str(tmp_path / "ck")
         dump = str(tmp_path / "dump.json")
-        proc = eb._spawn_train_child(ckpt, quick=True, extra_args=[
+        proc = drills._spawn_train_child(ckpt, quick=True, extra_args=[
             "--total", "15", "--term-at", "5", "--ckpt-every", "2",
             "--hosts", "2", "--arm-notice", "--dump", dump,
         ])

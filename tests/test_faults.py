@@ -184,6 +184,16 @@ class TestChaosInjectors:
     (their end-to-end drills live in test_chaos.py / test_elastic.py —
     here just the injector contracts)."""
 
+    def test_preemption_exception_is_the_elastic_one(self):
+        """``preempt_after`` raises the exception the trainer's real SIGTERM
+        path raises: one class, defined beside the handler, re-exported here."""
+        from beforeholiday_tpu.elastic import signals
+        from beforeholiday_tpu.testing import faults
+
+        assert faults.SimulatedPreemption is signals.SimulatedPreemption
+        with pytest.raises(signals.SimulatedPreemption):
+            faults.preempt_after(1)()
+
     def test_hang_rank_targets_one_rank_after_step(self):
         from beforeholiday_tpu.elastic import HangWatchdog
         from beforeholiday_tpu.testing.faults import hang_rank

@@ -455,6 +455,14 @@ class TestDispatchCounters:
         assert by_op["op_b"]["jnp"] == 1
         assert by_op["op_b"]["degraded_keys"] == 1
 
+    def test_summary_row_shape_is_json_ready(self):
+        checked_impl("op_shape", "pallas", lambda x: x, jnp.ones((2,)))
+        rows = dispatch_summary()
+        assert rows and set(rows[0]) == {
+            "op", "keys", "pallas", "jnp", "probes", "degraded_keys",
+            "pallas_ratio"}
+        json.dumps(rows)
+
     def test_reset_clears_counters_but_cache_clear_does_not(self):
         def fine(x):
             return x

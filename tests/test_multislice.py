@@ -39,7 +39,6 @@ from beforeholiday_tpu.parallel.parallel_state import (
     hierarchical_axes,
     make_two_level_mesh,
 )
-from beforeholiday_tpu.testing._replay import COLLECTIVES
 
 pytestmark = pytest.mark.multislice
 
@@ -50,6 +49,11 @@ def shard_map(f=None, **kw):
         return lambda g: jax.shard_map(g, **kw)
     return jax.shard_map(f, **kw)
 
+
+# the collective primitives a reduce engine can emit (psum_scatter traces to
+# reduce_scatter on some jax versions; monitor/comms.py wraps one of each)
+COLLECTIVES = frozenset(
+    {"psum", "psum_scatter", "reduce_scatter", "all_gather", "all_to_all"})
 
 AX = HIERARCHICAL_AXES  # ("slice", "intra")
 N_SLICES, SLICE_SIZE = 2, 4

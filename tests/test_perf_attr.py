@@ -5,7 +5,7 @@
   (forced via monkeypatch) exactly for a matmul and within 1% for a
   flash-attention block;
 * ``perf_report`` joins recorded wall time into per-entry MFU that agrees
-  with the directly-computed number (the bench's ``gpt_o5_mfu`` arithmetic)
+  with the directly-computed number (6·N·tokens over the peak)
   within 5% on a GPT proxy step;
 * ``overlap_report`` reproduces constructed-timeline oracles (full / none /
   partial overlap, per-step weighting, cross-rank pid filtering) and

@@ -339,3 +339,51 @@ class TestLossParityBound:
         assert Q.loss_parity_bound(0, n_matmuls=8, loss_ceiling=12.0) > b0
         with pytest.raises(ValueError, match="n_matmuls"):
             Q.loss_parity_bound(0, n_matmuls=0, loss_ceiling=6.0)
+
+    def test_o6_train_run_stays_inside_the_bound(self):
+        """An O6 GPT train run beside O5 from identical init and batch: at
+        EVERY step the loss deviation sits inside ``loss_parity_bound`` (the
+        per-matmul e4m3 envelope composed across the quantized GEMMs,
+        compounded per step), no step is skipped on either side, and the
+        delayed-scaling state really ran: both amax-history rows populated."""
+        from beforeholiday_tpu.testing import gpt
+
+        cfg = gpt.GPTConfig(vocab_size=512, seq_len=64, d_model=64, n_heads=4,
+                            n_layers=2, dtype=jnp.bfloat16)
+        steps = 12
+
+        def losses_of(opt_level):
+            params = gpt.init(jax.random.PRNGKey(0), cfg)
+            batch = gpt.synthetic_batch(jax.random.PRNGKey(1), cfg, 4)
+            m = amp.initialize(lambda p, t: gpt.forward(p, t, cfg), params,
+                               FusedAdam(lr=1e-3), opt_level)
+            svag = amp.scaled_value_and_grad(
+                lambda p, tok, tgt: gpt.loss_fn(p, tok, tgt, cfg,
+                                                forward_fn=m.apply), m.scaler)
+
+            @jax.jit
+            def step(p, o, sc):
+                loss, g, fi, sc = svag(p, sc, *batch)
+                p, o = m.optimizer.step(p, g, o, found_inf=fi)
+                return p, o, sc, loss, fi
+
+            p, o, sc = m.params, m.optimizer.init(m.params), m.scaler.init()
+            losses, skipped = [], 0
+            for _ in range(steps):
+                p, o, sc, loss, fi = step(p, o, sc)
+                losses.append(float(loss))
+                skipped += int(float(fi) > 0)
+            return losses, sc, skipped
+
+        l5, _, skip5 = losses_of("O5")
+        l6, sc6, skip6 = losses_of("O6")
+        assert (skip5, skip6) == (0, 0)
+        ceiling = max(abs(v) for v in l5)
+        for t, (a, b) in enumerate(zip(l5, l6)):
+            # every quantized GEMM on the loss path: 4 fused_dense per block
+            bound = Q.loss_parity_bound(
+                t, n_matmuls=4 * cfg.n_layers, loss_ceiling=ceiling)
+            assert abs(a - b) <= bound, (t, a, b, bound)
+        hist = np.asarray(sc6["amax_history"])
+        assert hist.shape[0] == len(Q.HISTORY_ROLES)
+        assert all((hist[i] > 0).any() for i in range(hist.shape[0]))

@@ -21,19 +21,13 @@
   request records attached;
 * the hierarchical MoE dispatch splits its comms payload per interconnect
   tier in ``comms_summary()["by_tier"]`` (slice stage on DCN, intra stage
-  on ICI, exact bytes each) while the flat dispatch books a single tier;
-* ``tools/bench_diff.py`` gates drift between two BENCH_r*.json runs:
-  byte-identical runs and a parsed=null side exit 0, a perturbed copy
-  exits nonzero with DRIFT lines.
+  on ICI, exact bytes each) while the flat dispatch books a single tier.
 """
 
 from __future__ import annotations
 
-import importlib.util
 import json
 import pathlib
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -57,7 +51,6 @@ from beforeholiday_tpu.parallel.parallel_state import EXPERT_AXIS
 
 pytestmark = pytest.mark.telemetry
 
-_REPO = pathlib.Path(__file__).resolve().parent.parent
 
 
 @pytest.fixture(autouse=True)
@@ -309,11 +302,11 @@ class TestGoodputLedger:
         assert rep["compile_signatures"] == 2
 
     def test_real_fault_schedule_run(self, devices8, tmp_path):
-        """The bench's seeded drill as a test: preempt 8→4 mid-run, grow
+        """The seeded goodput drill: preempt 8→4 mid-run, grow
         back 4→8 at the next checkpoint boundary, under a live timeline.
         ``_goodput_run`` asserts the exact sum, the resize reasons, the
         restore/reshard booking, and ckpt-ledger consistency internally."""
-        from beforeholiday_tpu.testing.telemetry_bench import _goodput_run
+        from beforeholiday_tpu.testing.drills import _goodput_run
 
         report, events = _goodput_run(str(tmp_path))
         assert 0.0 < report["goodput_fraction"] < 1.0
@@ -545,12 +538,13 @@ class TestCommsByTier:
         return cfg.n_experts * C * D * 4   # one a2a payload, fp32 bytes
 
     def test_flat_dispatch_books_single_ici_tier(self, devices8):
-        self._run_moe(devices8, (EXPERT_AXIS,), EXPERT_AXIS, False)
+        payload = self._run_moe(devices8, (EXPERT_AXIS,), EXPERT_AXIS, False)
         (row,) = [r for r in comms.comms_summary()
                   if r["subsystem"] == "moe"]
         assert set(row["by_tier"]) == {"ici"}
         tier = row["by_tier"]["ici"]
-        assert tier["bytes"] == row["bytes"] > 0
+        # dispatch out + combine back, the analytic (E, C, D) payload each
+        assert tier["bytes"] == row["bytes"] == 2 * payload
         assert tier["calls"] == row["calls"]
         assert tier["compression_ratio"] == 1.0
         sites = {r["site"] for r in comms.comms_records()
@@ -577,93 +571,3 @@ class TestCommsByTier:
         ]:
             assert by_site[site]["tier"] == tier
             assert by_site[site]["bytes"] == payload
-
-
-# ------------------------------------------------------------- bench_diff
-
-
-def _load_bench_diff():
-    spec = importlib.util.spec_from_file_location(
-        "bench_diff", _REPO / "tools" / "bench_diff.py"
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _perturb(tree, factor):
-    """Multiply every numeric leaf (bool excluded) by ``factor``."""
-    if isinstance(tree, dict):
-        return {k: _perturb(v, factor) for k, v in tree.items()}
-    if isinstance(tree, list):
-        return [_perturb(v, factor) for v in tree]
-    if isinstance(tree, (int, float)) and not isinstance(tree, bool):
-        return tree * factor
-    return tree
-
-
-class TestBenchDiff:
-    def test_flatten_numeric(self):
-        bd = _load_bench_diff()
-        flat = bd.flatten_numeric({
-            "a": 1, "b": {"c": 2.5, "d": True}, "e": [3, {"f": 4}], "g": "s",
-        })
-        assert flat == {"a": 1.0, "b.c": 2.5, "e[0]": 3.0, "e[1].f": 4.0}
-
-    def test_diff_runs_gates_and_zero_baseline(self):
-        bd = _load_bench_diff()
-        old = {"parsed": {"x": 100.0, "zero": 0.0, "gone": 1.0}}
-        new = {"parsed": {"x": 109.0, "zero": 0.05, "fresh": 2.0}}
-        res = bd.diff_runs(old, new, tol=0.10)
-        assert res["compared"] == 2
-        assert res["regressions"] == []         # 9% and |0.05| both inside
-        assert res["added"] == ["fresh"] and res["removed"] == ["gone"]
-        res = bd.diff_runs(old, new, tol=0.04)
-        assert {r["key"] for r in res["regressions"]} == {"x", "zero"}
-        res = bd.diff_runs({"parsed": None}, new, tol=0.10)
-        assert res["missing_old"] and res["compared"] == 0
-
-    @staticmethod
-    def _records(tmp_path):
-        """A parsed driver record and one that died before its metric line
-        (``parsed: null``) — the two shapes ``BENCH_r*.json`` files take."""
-        run = {
-            "n": 4, "rc": 0, "tail": "",
-            "parsed": {
-                "metric": "resnet50_amp_O5_train", "value": 2256.0,
-                "detail": {"o5_step_ms": 56.73, "gpt_o5_mfu": 0.337,
-                           "meter": {"stable": True, "pairs": [1.0, 1.02]}},
-            },
-        }
-        ok = tmp_path / "BENCH_ok.json"
-        ok.write_text(json.dumps(run))
-        null = tmp_path / "BENCH_null.json"
-        null.write_text(json.dumps(dict(run, parsed=None)))
-        return run, str(ok), str(null)
-
-    def test_smoke_identical_run_and_null_parsed(self, tmp_path):
-        _, ok, null_path = self._records(tmp_path)
-        tool = str(_REPO / "tools" / "bench_diff.py")
-        same = subprocess.run([sys.executable, tool, ok, ok],
-                              capture_output=True, text=True)
-        assert same.returncode == 0, same.stdout + same.stderr
-        assert "0 past the" in same.stdout
-        # the new run died before its metric line (parsed=null): warn, exit 0
-        null = subprocess.run([sys.executable, tool, ok, null_path],
-                              capture_output=True, text=True)
-        assert null.returncode == 0, null.stdout + null.stderr
-        assert "parsed=null" in null.stdout
-
-    def test_perturbed_copy_exits_nonzero(self, tmp_path):
-        run, ok, _ = self._records(tmp_path)
-        bad = dict(run)
-        bad["parsed"] = _perturb(run["parsed"], 1.5)
-        bad_path = tmp_path / "BENCH_bad.json"
-        bad_path.write_text(json.dumps(bad))
-        tool = str(_REPO / "tools" / "bench_diff.py")
-        res = subprocess.run(
-            [sys.executable, tool, ok, str(bad_path)],
-            capture_output=True, text=True,
-        )
-        assert res.returncode == 1, res.stdout + res.stderr
-        assert "DRIFT" in res.stdout
