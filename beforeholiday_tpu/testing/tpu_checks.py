@@ -244,6 +244,62 @@ def check_flash_tiles(results: list) -> None:
           {r["kernel"] for r in rows} == {"fwd", "dq", "dkv"}, len(rows))
 
 
+def check_wy_prepare(results: list) -> None:
+    """The gated delta rule's chunk-local algebra in its two kernels
+    (``ops.gated_delta``: ``wy_prepare_fwd`` / ``wy_prepare_bwd``), compiled, at
+    the Qwen cell's shape (32 heads x 64 chunks, C = d = 128, bfloat16): every
+    output and every input cotangent against the jnp path (``wy_prepare`` and
+    XLA's transpose of it), and the time a layer of both. Interpret mode cannot
+    see what Mosaic makes of the hand-split three-pass products."""
+    from beforeholiday_tpu.ops import gated_delta as gd
+
+    def check(name, cond, info=""):
+        results.append((f"wy_prepare/{name}", bool(cond), str(info)))
+
+    BH, N, C, d = 32, 64, 128, 128
+    ks = jax.random.split(jax.random.PRNGKey(3), 11)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    bf = lambda x: x.astype(jnp.bfloat16)
+    q = bf(unit(jax.random.normal(ks[0], (BH, N, C, d))) * d ** -0.5)
+    k = bf(unit(jax.random.normal(ks[1], (BH, N, C, d))))
+    v = bf(jax.random.normal(ks[2], (BH, N, C, d)))
+    g = -0.5 * jax.random.uniform(ks[3], (BH, N, C))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (BH, N, C)))
+    args = (q, k, v, g, beta)
+    shapes = jax.eval_shape(gd.wy_prepare, *args)
+    cts = tuple(jax.random.normal(kk, s.shape, jnp.float32).astype(s.dtype)
+                for kk, s in zip(ks[5:], shapes))
+    # the jnp path as the cell ran it: recomputed in the backward pass
+    paths = {"jnp": jax.checkpoint(gd.wy_prepare), "pallas": gd._wy_pallas}
+    fwd = {name: jax.jit(fn) for name, fn in paths.items()}
+
+    def both(fn):       # the outputs too, or XLA drops the forward kernel as dead
+        def run(cts, *a):
+            o, pull = jax.vjp(fn, *a)
+            return o, pull(cts)
+        return jax.jit(run)
+
+    vjp = {name: both(fn) for name, fn in paths.items()}
+    out = {name: fn(*args) for name, fn in fwd.items()}
+    grads = {name: fn(cts, *args)[1] for name, fn in vjp.items()}
+    # bfloat16 tensors a rounding or two apart; dg and dbeta are float32 sums of
+    # bfloat16-grade terms (XLA rounds d(kb) to bfloat16, the kernel does not)
+    for what, names, got, want in (
+            ("out", ("w", "u", "qg", "kd", "p", "gl"), out["pallas"], out["jnp"]),
+            ("grad", ("dq", "dk", "dv", "dg", "dbeta"), grads["pallas"], grads["jnp"])):
+        for name, a, b in zip(names, got, want):
+            a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+            gap, scale = float(jnp.max(jnp.abs(a - b))), float(jnp.max(jnp.abs(b)))
+            ok = bool(jnp.all(jnp.isfinite(a))) and gap <= 2e-2 * scale
+            check(f"{what}/{name}", ok, f"max|d|={gap:.3e} of {scale:.3e}")
+    ms = {}
+    for name in paths:
+        ms[f"{name}_fwd"] = 1e3 * _min_step_seconds(lambda _: fwd[name](*args), None)
+        ms[f"{name}_fwd_bwd"] = 1e3 * _min_step_seconds(lambda _: vjp[name](cts, *args), None)
+    check("ms_a_layer", ms["pallas_fwd"] < ms["jnp_fwd"] and ms["pallas_fwd_bwd"] < ms["jnp_fwd_bwd"],
+          json.dumps({n: round(t, 3) for n, t in ms.items()}))
+
+
 def check_aliased_mt_kernels(results: list) -> None:
     """The Pallas multi-tensor kernels run with input_output_aliases on the
     compiled path (in-place updates, ~1.8x streaming win) — aliasing bugs
@@ -666,8 +722,8 @@ def main() -> int:
 
     enable_compile_cache()
     results: list = []
-    for group in (check_flash_dropout, check_flash_tiles, check_aliased_mt_kernels,
-                  check_compiled_kernel_parity):
+    for group in (check_flash_dropout, check_flash_tiles, check_wy_prepare,
+                  check_aliased_mt_kernels, check_compiled_kernel_parity):
         try:
             group(results)
         except Exception as e:  # a crashed group must not mask the others

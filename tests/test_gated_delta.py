@@ -1,11 +1,16 @@
 """The gated delta rule (``ops/gated_delta.py``) against the recurrence itself.
 
-The op is chunk-wise (WY factors in ``jax.numpy``, the chunk scan as a Pallas
-kernel with a hand-written backward, or as a ``lax.scan``); the oracle here is
-the token-by-token recurrence, written from the equations and differentiated by
-``jax.grad``. Float32 at ``highest`` matmul precision, so the tolerance is that
-of reassociation: 2e-5 of each tensor's largest value (the chunk-wise form adds
-its products in another order; measured 2.5e-6)."""
+The op is chunk-wise (the WY factors in ``jax.numpy`` or in two Pallas kernels,
+the chunk scan as a Pallas kernel with a hand-written backward, or as a
+``lax.scan``); the oracle here is the token-by-token recurrence, written from
+the equations and differentiated by ``jax.grad``. Float32 at ``highest`` matmul
+precision, so the tolerance is that of reassociation: 2e-5 of each tensor's
+largest value (the chunk-wise form adds its products in another order; measured
+2.5e-6). The WY kernels are held to ``wy_prepare`` itself, output by output and
+cotangent by cotangent (``_WY_TOL``)."""
+
+import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -177,3 +182,120 @@ def test_a_chunk_that_is_not_a_power_of_two_is_refused():
     args, _ = inputs(1, 1, 96, 1, 128, 128)
     with pytest.raises(ValueError, match="power of two"):
         gd.gated_delta_rule(*args, chunk=96)
+
+
+# ---------------------------------------------------------------------------------
+# the chunk-local algebra in its two kernels (the interpreter here) against wy_prepare
+# ---------------------------------------------------------------------------------
+
+# the kernels write Precision.HIGH by hand, three bfloat16 passes a product (2^-16
+# of its operands), where wy_prepare on the CPU multiplies in full float32: a
+# chain of six such products in the inverse and one in the solve
+_WY_TOL = 1e-4
+_WY_SHAPES = ((128, 128, 128), (64, 128, 128))          # (C, d_k, d_v)
+_WY_OUT = ("w", "u", "qg", "kd", "p", "gl")
+
+
+def chunked(seed, BH, N, C, dk, dv, decay):
+    """``inputs`` as the chunk scan takes them: (BH, N, C, .)."""
+    args, _ = inputs(seed, BH, N * C, 1, dk, dv, decay)
+    return tuple(t[:, :, 0].reshape(BH, N, C, *t.shape[3:]) for t in args)
+
+
+@functools.lru_cache(maxsize=None)
+def wy_both(C, dk, dv, decay=0.05):
+    """Outputs and input cotangents (on random output cotangents, ``gl``'s
+    too) of the kernels and of ``wy_prepare``."""
+    args = chunked(C, 2, 3, C, dk, dv, decay)
+    with jax.default_matmul_precision("highest"):
+        want, pull_want = jax.vjp(gd.wy_prepare, *args)
+        got, pull_got = jax.vjp(jax.jit(gd._wy_pallas), *args)
+        keys = jax.random.split(jax.random.PRNGKey(C), len(want))
+        cts = tuple(jax.random.normal(kk, t.shape) for kk, t in zip(keys, want))
+        return (dict(zip(_WY_OUT, zip(got, want))),
+                dict(zip(_NAMES, zip(pull_got(cts), pull_want(cts)))))
+
+
+def _wy_close(got, want, what):
+    assert got.shape == want.shape and got.dtype == want.dtype, what
+    scale = float(jnp.max(jnp.abs(want)))
+    assert float(jnp.max(jnp.abs(got - want))) <= _WY_TOL * scale, what
+
+
+@pytest.mark.parametrize("name", _WY_OUT)
+@pytest.mark.parametrize("C,dk,dv", _WY_SHAPES)
+def test_wy_kernel_outputs_match_wy_prepare(C, dk, dv, name):
+    _wy_close(*wy_both(C, dk, dv)[0][name], f"{name}, C={C}")
+
+
+@pytest.mark.parametrize("name", _NAMES)
+@pytest.mark.parametrize("C,dk,dv", _WY_SHAPES)
+def test_wy_kernel_cotangents_match_the_transpose_of_wy_prepare(C, dk, dv, name):
+    _wy_close(*wy_both(C, dk, dv)[1][name], f"d{name}, C={C}")
+
+
+def test_wy_kernels_under_a_strong_decay():
+    # log decay down to -20 a token: the kernel may only exponentiate differences <= 0
+    out, grads = wy_both(128, 128, 128, decay=20.0)
+    for name, (got, want) in {**out, **grads}.items():
+        assert bool(jnp.all(jnp.isfinite(got))), name
+        _wy_close(got, want, f"{name} under strong decay")
+
+
+def test_wy_kernels_with_repeated_keys():
+    """The same key at every position, beta = 1, no decay: L is all ones below
+    the diagonal, (I + L)^-1 = I - shift, so u_i = v_i - v_(i-1) and w_i = 0
+    past the first row; a plain Neumann series would cancel terms of C(127, 63)."""
+    C, d = 128, 128
+    key = jnp.zeros((d,)).at[0].set(1.0)
+    k = jnp.broadcast_to(key, (1, 2, C, d))
+    v = jax.random.normal(jax.random.PRNGKey(0), (1, 2, C, d))
+    g, beta = jnp.zeros((1, 2, C)), jnp.ones((1, 2, C))
+    w, u, *_ = gd._wy_pallas(k, k, v, g, beta)
+    np.testing.assert_allclose(u, v - jnp.pad(v, ((0, 0), (0, 0), (1, 0), (0, 0)))[:, :, :-1],
+                               atol=1e-3 * float(jnp.max(jnp.abs(v))))
+    np.testing.assert_allclose(w, k.at[:, :, 1:].set(0.0), atol=1e-3)
+
+
+def test_the_wy_backward_kernel_recomputes_the_inverse():
+    """The custom_vjp keeps the chunked operands (the per-row scalars as one
+    (8, C) tile a chunk) and no (C, C) float32 tensor."""
+    q, k, v, g, beta = (t.reshape(-1, *t.shape[2:]) for t in chunked(2, 1, 2, 128, 128, 128, 1.5))
+    rows, _ = gd._wy_rows(g, beta)
+    _, res = gd._wy_kernels_fwd(q, k, v, rows)
+    assert len(res) == 4 and all(r is o for r, o in zip(res, (q, k, v, rows)))
+
+
+def test_a_shape_the_gate_refuses_falls_to_jnp_and_is_counted(monkeypatch):
+    from beforeholiday_tpu.guard import dispatch
+
+    dispatch.reset_dispatch_counters()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # resolve_impl -> pallas
+    monkeypatch.setattr(gd, "_interpret_default", lambda: True)
+    args, _ = inputs(6, 1, 128, 1, 64, 128)
+    _close(gd.gated_delta_rule(*args), recurrence(*args), "o, d_k=64")
+    counted = {k[0]: v for k, v in dispatch.dispatch_counters().items()}
+    assert counted["gated_delta_rule"]["jnp"] == 1 and counted["gated_delta_rule"]["pallas"] == 0
+
+
+def test_the_wy_kernels_lie_under_the_scope_and_are_not_named_after_it():
+    """``gated_delta_ms`` reads the scope path, ``gated_delta_roofline`` the
+    kernels named ``gated_delta*``: the scan's two, not these."""
+    from jax._src import core
+
+    def kernels(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["name"]
+            for sub in core.jaxprs_in_params(eqn.params):
+                yield from kernels(sub)
+
+    args, ct = inputs(4, 1, 128, 1, 128, 128)
+    grad = jax.grad(lambda *a: jnp.sum(gd.gated_delta_rule(*a, impl="pallas") * ct),
+                    argnums=range(5))
+    names = set(kernels(jax.make_jaxpr(grad)(*args).jaxpr))
+    assert names == {"wy_prepare_fwd", "wy_prepare_bwd", "gated_delta_fwd", "gated_delta_bwd"}
+    text = jax.jit(grad).lower(*args).as_text(debug_info=True)
+    for name in ("wy_prepare_fwd", "wy_prepare_bwd"):
+        assert re.search(r"gated_delta\)*/" + name, text), name
+        assert not re.search(r"gated_delta_scan\)*/" + name, text), name
