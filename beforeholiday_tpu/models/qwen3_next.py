@@ -44,6 +44,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from beforeholiday_tpu.models import layers as _layers
+from beforeholiday_tpu.models.layers import COUNTERS  # noqa: F401  (the step's counters)
 from beforeholiday_tpu.monitor.spans import annotate as _annotate, span as _span
 from beforeholiday_tpu.remat import apply as _remat_apply
 
@@ -194,13 +196,7 @@ def rope_partial(x, rotary_dim: int, theta: float):
     """Rotary position embedding on the first ``rotary_dim`` dims of each head
     (``rotate_half`` layout: dim ``i`` pairs with ``i + rotary_dim / 2``), the
     rest passed through. ``x``: ``(B, S, H, hd)``; positions ``0 .. S-1``."""
-    half = rotary_dim // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=_F32) * 2.0 / rotary_dim)
-    angle = jnp.arange(x.shape[1], dtype=_F32)[:, None] * inv_freq[None, :]
-    cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
-    x1, x2 = x[..., :half].astype(_F32), x[..., half:rotary_dim].astype(_F32)
-    rotated = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return jnp.concatenate([rotated.astype(x.dtype), x[..., rotary_dim:]], axis=-1)
+    return _layers.apply_rotary(x, *_layers.rotary_table(x.shape[1], rotary_dim, theta))
 
 
 def causal_depthwise_conv(x, w):
@@ -287,9 +283,6 @@ def _layer(cfg: Qwen3NextConfig, x, lp, mixer, mp):
     return x + y.reshape(B, S, D), counters
 
 
-COUNTERS = ("expert_rows", "expert_load_max_over_mean", "dropped_rows")
-
-
 def forward(params: dict, tokens: jax.Array, cfg: Qwen3NextConfig):
     """``tokens (B, S) int32 -> (logits (B, S, V) float32, counters)``.
     ``counters``: per step, over the layers: ``expert_rows`` (sum),
@@ -298,20 +291,12 @@ def forward(params: dict, tokens: jax.Array, cfg: Qwen3NextConfig):
     with _span("qwen3n_embed"):
         x = params["embed"][tokens].astype(cfg.dtype)
 
-    by_period = lambda tree, n: jax.tree.map(
-        lambda a: a.reshape(P, n, *a.shape[1:]), tree)
+    by_period = lambda tree, n: _layers.by_period(tree, P, n)
+    unstack = _layers.unstack
     linear = _remat_apply(
         lambda x, lp, mp: _layer(cfg, x, lp, gated_delta_net, mp), cfg.remat_policy)
     attn = _remat_apply(
         lambda x, lp, mp: _layer(cfg, x, lp, gated_attention, mp), cfg.remat_policy)
-
-    def unstack(tree, n):
-        # lax.split: its gradient is one concatenation; that of ``a[i]`` is a
-        # zero-padded copy of the whole stack for every ``i``
-        parts = jax.tree.map(lambda a: jax.lax.split(a, [1] * n, axis=0), tree)
-        return [jax.tree.map(lambda p: p[i][0], parts,
-                             is_leaf=lambda p: isinstance(p, (list, tuple)))
-                for i in range(n)]
 
     def period(x, xs):
         layers, lin, att = xs
@@ -328,11 +313,7 @@ def forward(params: dict, tokens: jax.Array, cfg: Qwen3NextConfig):
         x, seen = jax.lax.scan(period, x, (
             by_period(params["layers"], per), by_period(params["linear"], per - 1),
             params["attn"]))
-    counters = {
-        "expert_rows": jnp.sum(seen["expert_rows"]),
-        "expert_load_max_over_mean": jnp.max(seen["expert_load_max_over_mean"]),
-        "dropped_rows": jnp.sum(seen["dropped_rows"]),
-    }
+    counters = _layers.reduce_counters(seen)
     with _span("qwen3n_head"):
         x = rms_norm0(x, params["final_norm"], cfg.rms_norm_eps)
         logits = jax.lax.dot_general(
@@ -341,11 +322,7 @@ def forward(params: dict, tokens: jax.Array, cfg: Qwen3NextConfig):
     return logits, counters
 
 
-@_annotate("qwen3n_loss")
-def cross_entropy(logits, targets):
-    logz = jax.nn.logsumexp(logits.astype(_F32), axis=-1)
-    picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    return jnp.mean(logz - picked.astype(_F32))
+cross_entropy = _annotate("qwen3n_loss")(_layers.cross_entropy)
 
 
 def loss_fn(params: dict, tokens: jax.Array, targets: jax.Array,

@@ -145,11 +145,14 @@ def dropless_moe(
     rows_bound: Optional[int] = None,
     renormalize: bool = True,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Routed experts (the held ones' part) plus the gated shared expert.
+    """Routed experts (the held ones' part) plus, where the model has one, the
+    gated shared expert.
 
     ``x``: ``(T, D)``. ``p``: ``router (D, E)``, ``w_gate`` / ``w_up`` /
-    ``w_down`` (stacked over the held experts), ``shared_w_gate`` /
-    ``shared_w_up`` / ``shared_w_down`` and ``shared_score (D, 1)``. Returns
+    ``w_down`` (stacked over the held experts) and, for a model with a shared
+    expert, ``shared_w_gate`` / ``shared_w_up`` / ``shared_w_down`` and
+    ``shared_score (D, 1)``: without them the layer is its routed part alone,
+    and the ``moe_shared`` span does not open. Returns
     ``(y (T, D) in x's dtype, counters)``."""
     with _span("moe"):
         with _span("moe_route"):
@@ -157,9 +160,12 @@ def dropless_moe(
         routed, counters = dropless_experts(
             x, weights, idx, {n: p[n] for n in ("w_gate", "w_up", "w_down")},
             first_expert=first_expert, rows_bound=rows_bound)
-        with _span("moe_shared"):
-            shared = shared_expert(x, p["shared_w_gate"], p["shared_w_up"],
-                                   p["shared_w_down"], p["shared_score"])
+        if "shared_w_gate" in p:
+            with _span("moe_shared"):
+                shared = shared_expert(x, p["shared_w_gate"], p["shared_w_up"],
+                                       p["shared_w_down"], p["shared_score"])
+            with _span("moe_combine"):
+                routed = routed + shared.astype(_F32)
         with _span("moe_combine"):
-            y = (routed + shared.astype(_F32)).astype(x.dtype)
+            y = routed.astype(x.dtype)
     return y, counters

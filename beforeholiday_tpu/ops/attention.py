@@ -17,13 +17,18 @@ reference's backward kernels make, shaped for the MXU: every inner op is a
 (BQ, D) x (D, BK)-style matmul, fp32 accumulation.
 
 A call is scheduled on two levels (:class:`TilePlan`), both a function of
-``(Sq, Sk, D, causal)`` alone. The grid hands the kernels large *blocks*
+``(Sq, Sk, D, causal, window)`` alone. The grid hands the kernels large *blocks*
 (:func:`_block_size`: few grid steps, few copies); inside a block that the
 causal diagonal crosses the kernels walk *tiles*, so that the triangle above
 the diagonal is not computed and only the tiles on it build a mask — at
 S = 1024 a head is ONE block, and all of the skipping happens inside it. A
-non-causal call is one tile a block. ``guard.dispatch.count_tiles`` books what
-each traced kernel's plan computes (``monitor.tile_records()``).
+non-causal call is one tile a block. A causal call with a ``window`` (sliding-
+window attention: the last W keys) goes further: its GRID is the band of
+blocks the window reaches, through index maps offset from the outer block, so
+that a block outside the band costs no grid step and no copy, and the blocks
+the window's lower edge crosses are walked as the diagonal's are.
+``guard.dispatch.count_tiles`` books what each traced kernel's plan computes
+(``monitor.tile_records()``).
 
 Variable-length batches are expressed as per-sequence key lengths
 (``kv_lens``) rather than the reference's packed cu_seqlens: on TPU the
@@ -99,6 +104,28 @@ def _block_size(seq_len: int, head_dim: int = 64) -> int:
     return _MIN_BLOCK
 
 
+def _window_block(seq_len: int, head_dim: int, window: int) -> int:
+    """Grid block of a windowed call: :func:`_block_size`'s, halved only while
+    the half still holds the whole window (a block of twice the window or more
+    would be mostly outside the band).
+
+    Large blocks win with a window as they do without one. Measured on a v5e
+    at the Mellum cell's call (32 heads, S=8192, D=128, W=1024, bf16; fwd / dq +
+    dkv device ms of one layer; PR 31), where the plain causal call takes
+    4.98 / 14.17: blocks of 1024 in 4 strips (a band of 2 blocks, 150 of 1024
+    tiles) 1.75 / 3.52; 1024 in 8 strips (540 of 4096) 1.58 / 3.25; 512 in 4
+    strips (a band of 3) 2.54 / 4.51; 512 in 2 strips 2.79 / 4.66; 256 in 2
+    strips (a band of 5) 4.01 / 7.64; 256 whole 5.09 / 8.00. A block of the
+    window's size walks 20 tiles for the 16 the mask needs, and a smaller one
+    walks fewer only to pay more grid steps and more copies. Eight strips are
+    8 % faster at twice the kernel body, which ``_DIAG_STRIPS`` weighs the
+    same way."""
+    block = _block_size(seq_len, head_dim)
+    while block > _MIN_BLOCK and block // 2 >= window and seq_len % (block // 2) == 0:
+        block //= 2
+    return block
+
+
 class TilePlan(NamedTuple):
     """The two-level schedule of one flash call, shared by the forward, dq and
     dkv kernels so that all three agree on which tile is which.
@@ -126,6 +153,7 @@ class TilePlan(NamedTuple):
     tq: int
     tk: int
     causal: bool
+    window: Optional[int] = None    # keys a query sees, itself among them
 
     @property
     def nq(self) -> int:
@@ -165,12 +193,76 @@ class TilePlan(NamedTuple):
                            [p for p in pieces if p[0].stop > p[0].start]))
         return strips
 
+    # -- a windowed plan: the grid is the band ---------------------------------
+
+    @property
+    def band(self) -> int:
+        """Key blocks a query block's grid steps visit in a windowed plan: the
+        block the window's lower edge falls in for the block's first row, up
+        to the diagonal. The last grid axis has this many steps, not ``nk``:
+        step ``s`` of query block ``i`` is key block ``i - (band - 1) + s``
+        (fwd, dq), step ``s`` of key block ``j`` is query block ``j + s``
+        (dkv), through index maps offset from the outer block. The
+        ``band - 1`` steps that fall before the sequence's start (or, for
+        dkv, past its end) are clamped onto the first (last) block, so they
+        copy nothing new, and compute nothing."""
+        return min(self.nq, -(-(self.window - 1) // self.bk) + 1)
+
+    def band_walk(self, by_cols: bool, d: int):
+        """Static walk of the block ``d`` blocks below the diagonal of a
+        windowed plan, in :meth:`walk`'s form; the second member of a piece is
+        0 for tiles wholly inside the band (no mask arithmetic), else the
+        edges that cross it: 1 the causal diagonal, 2 the window's lower edge,
+        3 both. Tiles outside the band are in no piece, and a strip with none
+        is left out. ``q - k`` of a block-local ``(r, c)`` is ``d * bq + r - c``,
+        kept where ``0 <= q - k < window``."""
+        b, t, strips = self.bq, self.tq, []
+        for at in range(0, b, t):
+            pieces = []
+            for mv in range(0, b, t):
+                r0, c0 = (mv, at) if by_cols else (at, mv)
+                lo, hi = d * b + r0 - c0 - (t - 1), d * b + r0 - c0 + (t - 1)
+                if hi < 0 or lo >= self.window:
+                    continue
+                edge = (1 if lo < 0 else 0) | (2 if hi >= self.window else 0)
+                if pieces and pieces[-1][1] == edge:
+                    pieces[-1] = (slice(pieces[-1][0].start, mv + t), edge)
+                else:
+                    pieces.append((slice(mv, mv + t), edge))
+            if pieces:
+                strips.append((slice(at, at + t), pieces))
+        return strips
+
+    def band_walks(self, by_cols: bool):
+        """``[(walk, [d, ...])]``: the distinct walks of a windowed plan's
+        band and the block distances each serves (the blocks wholly inside the
+        band share one)."""
+        out = []
+        for d in range(self.band):
+            walk = self.band_walk(by_cols, d)
+            for known, ds in out:
+                if known == walk:
+                    ds.append(d)
+                    break
+            else:
+                out.append((walk, [d]))
+        return out
+
     def counts(self, has_lens: bool) -> Dict[str, int]:
         """Tiles of the score square: ``total``, ``live`` (computed) and
         ``masked`` (computed through a mask). The same for all three kernels.
         With ``kv_lens`` a key length can fall inside any tile, so every
         computed tile takes the length test."""
         total = (self.sq // self.tq) * (self.sk // self.tk)
+        if self.window is not None:
+            live = masked = 0
+            for d in range(self.band):      # nq - d blocks lie d below the diagonal
+                for _, pieces in self.band_walk(False, d):
+                    for span, edge in pieces:
+                        n = (span.stop - span.start) // self.tk * (self.nq - d)
+                        live += n
+                        masked += n if edge or has_lens else 0
+            return {"total": total, "live": live, "masked": masked}
         if not self.causal:
             return {"total": total, "live": total, "masked": total}
         n = self.sq // self.tq
@@ -179,19 +271,24 @@ class TilePlan(NamedTuple):
 
 
 @functools.lru_cache(maxsize=None)
-def _tile_plan(sq: int, sk: int, head_dim: int, causal: bool) -> TilePlan:
+def _tile_plan(sq: int, sk: int, head_dim: int, causal: bool,
+               window: Optional[int] = None) -> TilePlan:
     """The schedule of a call from what it can observe; no knob.
 
     Blocks are :func:`_block_size`'s. A causal call's blocks are square
     (``sq == sk``) and the ones on the diagonal are walked in ``_DIAG_STRIPS``
-    strips of square tiles, never smaller than the 128-lane minimum."""
+    strips of square tiles, never smaller than the 128-lane minimum. With a
+    ``window`` (fewer keys than the sequence has) the blocks are
+    :func:`_window_block`'s and the grid is the band (:attr:`TilePlan.band`)."""
     bq, bk = _block_size(sq, head_dim), _block_size(sk, head_dim)
     if not causal:
         return TilePlan(sq, sk, bq, bk, bq, bk, False)
     if sq != sk:
         raise ValueError(f"causal attention needs matching q/k lengths, got {sq} vs {sk}")
+    if window is not None:
+        bq = bk = _window_block(sq, head_dim, window)
     t = max(_MIN_BLOCK, bq // _DIAG_STRIPS)
-    return TilePlan(sq, sk, bq, bk, t, t, True)
+    return TilePlan(sq, sk, bq, bk, t, t, True, window)
 
 
 # Above this many bytes of materialized (BH, S, Sk) fp32 scores the jnp
@@ -271,7 +368,9 @@ def _mask(plan, on_diag, i, j, rows, cols, lens):
     """Mask predicate of the score piece ``rows`` x ``cols`` of block (i, j).
     True = masked out; None where nothing in the piece can be. ``lens`` is a
     scalar int32 (this sequence's key length), or None when the call has no
-    ``kv_lens``: every key is then in range, statically."""
+    ``kv_lens``: every key is then in range, statically. ``on_diag`` says
+    which edges cross the piece (:meth:`TilePlan.band_walk`): 1 (or True) the
+    causal diagonal, 2 a window's lower edge, 3 both."""
     if lens is None and not on_diag:
         return None
     shape = (rows.stop - rows.start, cols.stop - cols.start)
@@ -279,8 +378,11 @@ def _mask(plan, on_diag, i, j, rows, cols, lens):
     masked = None if lens is None else lax.ge(kj, lens)
     if on_diag:
         qi = lax.add(lax.broadcasted_iota(jnp.int32, shape, 0), _at(i, plan.bq, rows.start))
-        over = lax.gt(kj, qi)
-        masked = over if masked is None else lax.bitwise_or(masked, over)
+        for bit, outside in ((1, lambda: lax.gt(kj, qi)),
+                             (2, lambda: lax.le(lax.add(kj, plan.window), qi))):
+            if on_diag & bit:
+                over = outside()
+                masked = over if masked is None else lax.bitwise_or(masked, over)
     return masked
 
 
@@ -369,20 +471,66 @@ def _panels(plan, by_cols, walk, b, i, j, lens, seed_ref, rate):
     return out
 
 
-def _panel_scores(panel, q_ref, k_ref, scale):
+def _panel_scores(panel, q_ref, k_ref, scale, fill=_NEG):
     """Scaled, masked scores of a panel, piece by piece and joined."""
     parts = []
     for rows, cols, masked in panel.pieces:
         s = lax.mul(_dot(q_ref[0, rows, :], k_ref[0, cols, :], (1, 1)), scale)
-        parts.append(s if masked is None else lax.select(masked, lax.full_like(s, _NEG), s))
+        parts.append(s if masked is None else lax.select(masked, lax.full_like(s, fill), s))
     return _join(parts, panel.axis)
 
 
-def _walk_block(plan, by_cols, i, j, block):
+def _fill(plan):
+    """Mask fill of a plan's scores. A windowed plan's lies BELOW the running
+    max's initial value: in the first block of a band the window's lower edge
+    can mask a whole row of a panel while the row's max still stands at its
+    initial ``_NEG``, and ``exp(_NEG - _NEG)`` would count every masked key as
+    one; ``exp(2 * _NEG - _NEG)`` is exactly 0. (On the diagonal alone every
+    row has its own key, which is why the plain causal plan needs none of it.)"""
+    return _NEG if plan.window is None else 2 * _NEG
+
+
+def _grid_ids(plan, by_cols):
+    """``(b, i, j, step)`` of a grid step: batch x head, query block, key
+    block, and the position on the last grid axis (the accumulators start at
+    step 0 and are written out at ``_steps(plan) - 1``). Off a window the last
+    axis IS the other side's block; with one it is the place in the band."""
+    b, outer, step = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    if plan.window is None:
+        return (b, step, outer, step) if by_cols else (b, outer, step, step)
+    if by_cols:                                  # diagonal first, then downwards
+        return b, lax.add(outer, step), outer, step
+    return b, outer, lax.add(outer, lax.sub(step, plan.band - 1)), step
+
+
+def _steps(plan, by_cols=False):
+    """Length of the last grid axis: of the fwd and dq kernels, or of dkv."""
+    if plan.window is not None:
+        return plan.band
+    return plan.nq if by_cols else plan.nk
+
+
+def _walk_band(plan, by_cols, i, j, step, block):
+    """:func:`_walk_block` of a windowed plan: the block's distance below the
+    diagonal is a function of the grid step alone, so each distinct walk of
+    the band is emitted once, under the steps it serves, and only where the
+    step is inside the sequence."""
+    inside = lax.lt(i, plan.nq) if by_cols else lax.ge(j, 0)
+    for walk, ds in plan.band_walks(by_cols):
+        steps = [d if by_cols else plan.band - 1 - d for d in ds]
+        lo, hi = min(steps), max(steps)          # a walk's distances are a run
+        at = lax.eq(step, lo) if lo == hi else lax.bitwise_and(
+            lax.ge(step, lo), lax.le(step, hi))
+        pl.when(lax.bitwise_and(at, inside))(lambda walk=walk: block(walk))
+
+
+def _walk_block(plan, by_cols, i, j, block, step=None):
     """Run ``block(walk)`` for grid step (i, j): the diagonal's walk where
     the causal diagonal crosses the block, the one-piece walk for a block
     wholly below it and for every block of a non-causal call; nothing for a
     block above it."""
+    if plan.window is not None:
+        return _walk_band(plan, by_cols, i, j, step, block)
     if plan.causal:
         pl.when(i == j)(lambda: block(plan.walk(by_cols, True)))
     if not plan.causal or plan.nq > 1:
@@ -401,12 +549,13 @@ def _kernel_scalars(refs, has_lens, rate):
 def _fa_fwd_kernel(plan, scale, has_lens, rate, *refs):
     lens_ref, seed_ref, refs = _kernel_scalars(refs, has_lens, rate)
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
-    b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    b, i, j, step = _grid_ids(plan, False)
     lens = lens_ref[b] if has_lens else None
     one_pass = plan.one_pass
+    fill = _fill(plan)
 
     if not one_pass:
-        @pl.when(j == 0)
+        @pl.when(step == 0)
         def _init():
             m_ref[...] = jnp.full_like(m_ref, _NEG)
             l_ref[...] = jnp.zeros_like(l_ref)
@@ -433,7 +582,7 @@ def _fa_fwd_kernel(plan, scale, has_lens, rate, *refs):
     def block(walk):
         panels = _panels(plan, False, walk, b, i, j, lens, seed_ref, rate)
         # phase 1 — the scores of every panel
-        scores = [_panel_scores(pn, q_ref, k_ref, scale) for pn in panels]
+        scores = [_panel_scores(pn, q_ref, k_ref, scale, fill) for pn in panels]
         # phase 2 — the (running) max: one cross-lane reduction per strip
         stats = []
         for pn, s in zip(panels, scores):
@@ -467,10 +616,10 @@ def _fa_fwd_kernel(plan, scale, has_lens, rate, *refs):
                 acc_ref[pn.rows, :] = lax.add(lax.mul(acc_ref[pn.rows, :], _col(alpha)), pv)
                 m_ref[pn.rows, :] = m_new
 
-    _walk_block(plan, False, i, j, block)
+    _walk_block(plan, False, i, j, block, step)
 
     if not one_pass:
-        @pl.when(j == plan.nk - 1)
+        @pl.when(step == _steps(plan) - 1)
         def _final():
             l = l_ref[:, 0:1]
             nonempty = l > 0.0
@@ -484,11 +633,35 @@ def _fa_fwd_kernel(plan, scale, has_lens, rate, *refs):
 
 
 def _book_tiles(plan, head_dim, has_lens, *kernels):
-    """Book the plan's tile counts once per kernel traced with it."""
+    """Book the plan's tile counts once per kernel traced with it (a windowed
+    plan's key ends in its window; the others' keys are what they were)."""
+    key = (plan.sq, plan.sk, head_dim, plan.causal, has_lens)
+    if plan.window is not None:
+        key += (plan.window,)
     for kernel in kernels:
-        _count_tiles("flash_attention", kernel,
-                     (plan.sq, plan.sk, head_dim, plan.causal, has_lens),
-                     **plan.counts(has_lens))
+        _count_tiles("flash_attention", kernel, key, **plan.counts(has_lens))
+
+
+def _block_maps(plan):
+    """Index maps ``(own, keys, queries)`` of the (1, block, D) operands:
+    ``own`` follows a kernel's outer block; ``keys`` (fwd, dq: query block
+    outer) and ``queries`` (dkv: key block outer) follow the last grid axis.
+    In a windowed plan that axis walks the band, offset from the outer block
+    and clamped into the sequence (:attr:`TilePlan.band`)."""
+    own = lambda b, o, s, *_: (b, o, 0)
+    if plan.window is None:
+        other = lambda b, o, s, *_: (b, s, 0)
+        return own, other, other
+    back, last = plan.band - 1, plan.nq - 1
+    keys = lambda b, i, s, *_: (b, jnp.maximum(i + s - back, 0), 0)
+    queries = lambda b, j, s, *_: (b, jnp.minimum(j + s, last), 0)
+    return own, keys, queries
+
+
+def _kernel_name(plan, kernel):
+    """A windowed plan's kernels carry their own names in the device trace,
+    under the op's prefix; the others keep the scope's (``%flash_attention.N``)."""
+    return None if plan.window is None else f"flash_attention_window_{kernel}"
 
 
 def _scalar_operands(lens, seed, rate):
@@ -502,22 +675,24 @@ def _scalar_operands(lens, seed, rate):
     return scalars
 
 
-def _fa_fwd_pallas(q, k, v, lens, causal, scale, interpret, rate=0.0, seed=None):
+def _fa_fwd_pallas(q, k, v, lens, causal, scale, interpret, rate=0.0, seed=None,
+                   window=None):
     """``lens=None``: the call has no ``kv_lens`` — no length test anywhere."""
     BH, Sq, D = q.shape
-    plan = _tile_plan(Sq, k.shape[1], D, causal)
+    plan = _tile_plan(Sq, k.shape[1], D, causal, window)
     bq, bk = plan.bq, plan.bk
     _book_tiles(plan, D, lens is not None, "fwd")
-    qspec = pl.BlockSpec((1, bq, D), lambda b, i, j, *_: (b, i, 0))
-    kspec = pl.BlockSpec((1, bk, D), lambda b, i, j, *_: (b, j, 0))
+    own, keys, _ = _block_maps(plan)
+    qspec = pl.BlockSpec((1, bq, D), own)
+    kspec = pl.BlockSpec((1, bk, D), keys)
     scalars = _scalar_operands(lens, seed, rate)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(BH, plan.nq, plan.nk),
+        grid=(BH, plan.nq, _steps(plan)),
         in_specs=[qspec, kspec, kspec],
         out_specs=[
             qspec,
-            pl.BlockSpec((1, bq, 128), lambda b, i, j, *_: (b, i, 0)),
+            pl.BlockSpec((1, bq, 128), own),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, D), jnp.float32),
@@ -536,6 +711,7 @@ def _fa_fwd_pallas(q, k, v, lens, causal, scale, interpret, rate=0.0, seed=None)
             jax.ShapeDtypeStruct((BH, Sq, 128), jnp.float32),
         ],
         interpret=interpret,
+        name=_kernel_name(plan, "fwd"),
     )(*scalars, q, k, v)
     return o, lse
 
@@ -596,12 +772,13 @@ def _fa_dq_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
     (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref), rest = _bwd_refs(
         refs, has_dlse)
     dq_ref, dq_acc = rest
-    b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    b, i, j, step = _grid_ids(plan, False)
     lens = lens_ref[b] if has_lens else None
     one_pass = plan.one_pass
+    fill = _fill(plan)
 
     if not one_pass:
-        @pl.when(j == 0)
+        @pl.when(step == 0)
         def _init():
             dq_acc[...] = jnp.zeros_like(dq_acc)
 
@@ -610,7 +787,7 @@ def _fa_dq_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
         scores = []
         for pn in panels:
             do = do_ref[0, pn.rows, :]
-            scores.append((_panel_scores(pn, q_ref, k_ref, scale),
+            scores.append((_panel_scores(pn, q_ref, k_ref, scale, fill),
                            _dot(do, v_ref[0, pn.cols, :], (1, 1)),
                            _row_delta(do, o_ref[0, pn.rows, :])))
         grads = [
@@ -624,10 +801,10 @@ def _fa_dq_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
             else:
                 dq_acc[pn.rows, :] = lax.add(dq_acc[pn.rows, :], dq)
 
-    _walk_block(plan, False, i, j, block)
+    _walk_block(plan, False, i, j, block, step)
 
     if not one_pass:
-        @pl.when(j == plan.nk - 1)
+        @pl.when(step == _steps(plan) - 1)
         def _final():
             dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
@@ -638,12 +815,13 @@ def _fa_dkv_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
         refs, has_dlse)
     dk_ref, dv_ref, dk_acc, dv_acc = rest
     # k block outer, q block inner
-    b, j, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    b, i, j, step = _grid_ids(plan, True)
     lens = lens_ref[b] if has_lens else None
     one_pass = plan.one_pass
+    fill = _fill(plan)
 
     if not one_pass:
-        @pl.when(i == 0)
+        @pl.when(step == 0)
         def _init():
             dk_acc[...] = jnp.zeros_like(dk_acc)
             dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -655,7 +833,7 @@ def _fa_dkv_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
         scores = []
         for pn in panels:
             do = do_ref[0, pn.rows, :]
-            scores.append((_panel_scores(pn, q_ref, k_ref, scale),
+            scores.append((_panel_scores(pn, q_ref, k_ref, scale, fill),
                            _dot(do, v_ref[0, pn.cols, :], (1, 1)), do))
         grads = [
             _panel_p_ds(scale, s, dp, lse_ref[0, pn.rows, :],
@@ -676,36 +854,37 @@ def _fa_dkv_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
                 dv_acc[pn.cols, :] = lax.add(dv_acc[pn.cols, :], dv)
                 dk_acc[pn.cols, :] = lax.add(dk_acc[pn.cols, :], dk)
 
-    _walk_block(plan, True, i, j, block)
+    _walk_block(plan, True, i, j, block, step)
 
     if not one_pass:
-        @pl.when(i == plan.nq - 1)
+        @pl.when(step == _steps(plan, True) - 1)
         def _final():
             dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
             dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _fa_bwd_pallas(q, k, v, do, o, lse, dlse, lens, causal, scale, interpret,
-                   rate=0.0, seed=None):
+                   rate=0.0, seed=None, window=None):
     """``dlse=None`` (the plain-attention path) omits the operand entirely —
     an all-zero lane-replicated dlse would otherwise add an arena-sized HBM
     read to BOTH backward kernels for nothing. ``lens=None``: no ``kv_lens``."""
     BH, Sq, D = q.shape
-    plan = _tile_plan(Sq, k.shape[1], D, causal)
+    plan = _tile_plan(Sq, k.shape[1], D, causal, window)
     bq, bk, nq, nk = plan.bq, plan.bk, plan.nq, plan.nk
     has_dlse = dlse is not None
     dlse_ops = (dlse,) if has_dlse else ()
     scalars = _scalar_operands(lens, seed, rate)
     _book_tiles(plan, D, lens is not None, "dq", "dkv")
-    qspec_i = pl.BlockSpec((1, bq, D), lambda b, i, j, *_: (b, i, 0))
-    kspec_j = pl.BlockSpec((1, bk, D), lambda b, i, j, *_: (b, j, 0))
-    lse_i = pl.BlockSpec((1, bq, 128), lambda b, i, j, *_: (b, i, 0))
+    own, keys, queries = _block_maps(plan)
+    qspec_i = pl.BlockSpec((1, bq, D), own)
+    kspec_j = pl.BlockSpec((1, bk, D), keys)
+    lse_i = pl.BlockSpec((1, bq, 128), own)
     dq = pl.pallas_call(
         functools.partial(_fa_dq_kernel, plan, scale, lens is not None,
                           has_dlse, rate),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
-            grid=(BH, nq, nk),
+            grid=(BH, nq, _steps(plan)),
             in_specs=[qspec_i, kspec_j, kspec_j, qspec_i, qspec_i, lse_i]
                      + ([lse_i] if has_dlse else []),
             out_specs=qspec_i,
@@ -716,18 +895,19 @@ def _fa_bwd_pallas(q, k, v, do, o, lse, dlse, lens, causal, scale, interpret,
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name=_kernel_name(plan, "dq"),
     )(*scalars, q, k, v, do, o, lse, *dlse_ops)
 
     # dkv grid: (BH, k-block, q-block) — q-side operands indexed by the INNER id
-    qspec_in = pl.BlockSpec((1, bq, D), lambda b, j, i, *_: (b, i, 0))
-    kspec_out = pl.BlockSpec((1, bk, D), lambda b, j, i, *_: (b, j, 0))
-    lse_in = pl.BlockSpec((1, bq, 128), lambda b, j, i, *_: (b, i, 0))
+    qspec_in = pl.BlockSpec((1, bq, D), queries)
+    kspec_out = pl.BlockSpec((1, bk, D), own)
+    lse_in = pl.BlockSpec((1, bq, 128), queries)
     dk, dv = pl.pallas_call(
         functools.partial(_fa_dkv_kernel, plan, scale, lens is not None,
                           has_dlse, rate),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
-            grid=(BH, nk, nq),
+            grid=(BH, nk, _steps(plan, True)),
             in_specs=[qspec_in, kspec_out, kspec_out, qspec_in, qspec_in, lse_in]
                      + ([lse_in] if has_dlse else []),
             out_specs=[kspec_out, kspec_out],
@@ -744,6 +924,7 @@ def _fa_bwd_pallas(q, k, v, do, o, lse, dlse, lens, causal, scale, interpret,
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name=_kernel_name(plan, "dkv"),
     )(*scalars, q, k, v, do, o, lse, *dlse_ops)
     return dq, dk, dv
 
@@ -758,16 +939,16 @@ def _zeros_like(lens):
     return None if lens is None else jnp.zeros_like(lens)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _flash3(q, k, v, lens, seed, causal, scale, rate):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash3(q, k, v, lens, seed, causal, scale, rate, window=None):
     o, _ = _fa_fwd_pallas(q, k, v, lens, causal, scale, _interpret_default(),
-                          rate, seed)
+                          rate, seed, window)
     return o
 
 
-def _flash3_fwd(q, k, v, lens, seed, causal, scale, rate):
+def _flash3_fwd(q, k, v, lens, seed, causal, scale, rate, window):
     o, lse = _fa_fwd_pallas(q, k, v, lens, causal, scale, _interpret_default(),
-                            rate, seed)
+                            rate, seed, window)
     # remat boundary tag: under a save_only_these_names policy the (BH, S)
     # lse rows survive checkpointing so the flash backward can rebuild the
     # probabilities without a full forward re-run (identity otherwise)
@@ -775,11 +956,11 @@ def _flash3_fwd(q, k, v, lens, seed, causal, scale, rate):
     return o, (q, k, v, lens, seed, o, lse)
 
 
-def _flash3_bwd(causal, scale, rate, res, do):
+def _flash3_bwd(causal, scale, rate, window, res, do):
     q, k, v, lens, seed, o, lse = res
     dq, dk, dv = _fa_bwd_pallas(
         q, k, v, do, o, lse, None, lens, causal, scale, _interpret_default(),
-        rate, seed,
+        rate, seed, window,
     )
     return dq, dk, dv, _zeros_like(lens), jnp.zeros_like(seed)
 
@@ -790,24 +971,27 @@ _flash3.defvjp(_flash3_fwd, _flash3_bwd)
 # --- (o, lse) variant for chunk-merging callers (ring attention) ----------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _flash3_lse(q, k, v, lens, causal, scale):
-    o, lse = _fa_fwd_pallas(q, k, v, lens, causal, scale, _interpret_default())
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash3_lse(q, k, v, lens, causal, scale, window=None):
+    o, lse = _fa_fwd_pallas(q, k, v, lens, causal, scale, _interpret_default(),
+                            window=window)
     return o, lse[..., 0]
 
 
-def _flash3_lse_fwd(q, k, v, lens, causal, scale):
-    o, lse = _fa_fwd_pallas(q, k, v, lens, causal, scale, _interpret_default())
+def _flash3_lse_fwd(q, k, v, lens, causal, scale, window):
+    o, lse = _fa_fwd_pallas(q, k, v, lens, causal, scale, _interpret_default(),
+                            window=window)
     lse = _checkpoint_name(lse, _TAG_FLASH_LSE)
     return (o, lse[..., 0]), (q, k, v, lens, o, lse)
 
 
-def _flash3_lse_bwd(causal, scale, res, cts):
+def _flash3_lse_bwd(causal, scale, window, res, cts):
     do, dlse_row = cts
     q, k, v, lens, o, lse = res
     dlse = jnp.broadcast_to(dlse_row[..., None], lse.shape)
     dq, dk, dv = _fa_bwd_pallas(
-        q, k, v, do, o, lse, dlse, lens, causal, scale, _interpret_default()
+        q, k, v, do, o, lse, dlse, lens, causal, scale, _interpret_default(),
+        window=window,
     )
     return dq, dk, dv, _zeros_like(lens)
 
@@ -815,12 +999,13 @@ def _flash3_lse_bwd(causal, scale, res, cts):
 _flash3_lse.defvjp(_flash3_lse_fwd, _flash3_lse_bwd)
 
 
-def _probe_flash_pallas(q3, k3, v3, lens_bh, seed, *, causal, scale, rate):
+def _probe_flash_pallas(q3, k3, v3, lens_bh, seed, *, causal, scale, rate,
+                        window=None):
     """Guard probe: forward AND backward flash kernels must build for the key
     (the bwd pass launches two extra pallas_calls with their own specs)."""
 
     def f(q, k, v):
-        return _flash3(q, k, v, lens_bh, seed, causal, scale, rate)
+        return _flash3(q, k, v, lens_bh, seed, causal, scale, rate, window)
 
     o, vjp = jax.vjp(f, q3, k3, v3)
     vjp(jnp.zeros_like(o))
@@ -835,17 +1020,20 @@ def _seed_from_key(key: jax.Array) -> jax.Array:
     return jax.lax.bitcast_convert_type(bits, jnp.int32)
 
 
-def flash_attention_with_lse(q3, k3, v3, *, causal, scale, kv_lens=None):
+def flash_attention_with_lse(q3, k3, v3, *, causal, scale, kv_lens=None,
+                             window=None):
     """(BH, S, D) flash attention returning (o, lse (BH, S)) — the merge
     interface for blockwise/ring composition (lse = m + log l per row;
     fully-masked rows carry lse = -1e30 so their merge weight underflows to
     exactly zero). Differentiable in q/k/v AND through lse (the backward
-    kernels take the dlse cotangent)."""
+    kernels take the dlse cotangent). ``window``: as :func:`flash_attention`'s,
+    within this one chunk (positions count from the chunk's start)."""
     if kv_lens is not None:
         kv_lens = kv_lens.astype(jnp.float32)
     elif not causal:
         kv_lens = jnp.full((q3.shape[0],), float(k3.shape[1]), jnp.float32)
-    return _flash3_lse(q3, k3, v3, kv_lens, causal, scale)
+    window = _checked_window(window, causal, q3.shape[1])
+    return _flash3_lse(q3, k3, v3, kv_lens, causal, scale, window)
 
 
 # ---------------------------------------------------------------------------------
@@ -853,7 +1041,8 @@ def flash_attention_with_lse(q3, k3, v3, *, causal, scale, kv_lens=None):
 # ---------------------------------------------------------------------------------
 
 
-def _attn_jnp(q, k, v, lens, causal, scale, dropout_rate=0.0, dropout_key=None):
+def _attn_jnp(q, k, v, lens, causal, scale, dropout_rate=0.0, dropout_key=None,
+              window=None):
     BH, S, D = q.shape
     Sk = k.shape[1]
     s = jnp.einsum(
@@ -863,6 +1052,8 @@ def _attn_jnp(q, k, v, lens, causal, scale, dropout_rate=0.0, dropout_key=None):
     masked = kj[None, None, :].astype(jnp.float32) >= lens[:, None, None]
     if causal:
         masked |= kj[None, :] > jnp.arange(S)[:, None]
+    if window is not None:
+        masked |= kj[None, :] <= jnp.arange(S)[:, None] - window
     s = jnp.where(masked, _NEG, s)
     m = jnp.max(s, axis=-1, keepdims=True)
     # zero masked slots explicitly: for a fully-masked row s == m == _NEG and
@@ -884,6 +1075,21 @@ def _attn_jnp(q, k, v, lens, causal, scale, dropout_rate=0.0, dropout_key=None):
 # ---------------------------------------------------------------------------------
 
 
+def _checked_window(window, causal, seq_len):
+    """The window a call's kernels are planned with: ``None`` where it masks
+    nothing (no window, or one that holds the whole sequence), so that such a
+    call IS the plain causal call."""
+    if window is None:
+        return None
+    if not causal:
+        raise ValueError("window= needs causal=True (a window looks back from the query)")
+    window = int(window)
+    if window < 1:
+        raise ValueError(f"window must hold at least the query's own key, got {window}")
+    return None if window >= seq_len else window
+
+
+
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -895,6 +1101,7 @@ def flash_attention(
     dropout_rate: float = 0.0,
     dropout_key: Optional[jax.Array] = None,
     impl: Optional[str] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Fused scaled-dot-product attention.
 
@@ -902,6 +1109,12 @@ def flash_attention(
     at index >= len are masked out (the reference fmha's variable-seqlen
     support, ref: apex/contrib/fmha/fmha.py:33-60, expressed padded-dense).
     Returns (B, H, S, D) in q's dtype. fp32 accumulation throughout.
+
+    ``window`` (with ``causal=True``): sliding-window attention — query ``i``
+    sees the ``window`` keys ``i - window < j <= i``, itself among them. The
+    kernels' grid is then the band of blocks the window reaches and not the
+    square (:attr:`TilePlan.band`); a window that holds the whole sequence is
+    the plain causal call.
 
     ``dropout_rate``/``dropout_key``: attention-probability dropout in
     torch's softmax->dropout->matmul order (ref:
@@ -930,6 +1143,10 @@ def flash_attention(
             f"causal attention needs matching q/k lengths, got {S} vs {Sk}"
         )
     scale = float(scale) if scale is not None else 1.0 / (D ** 0.5)
+    window = _checked_window(window, causal, S)
+    # a windowed call's probe key and kernels carry the window; a call without
+    # one passes nothing, and its key and kernels are what they were
+    windowed = {} if window is None else {"window": window}
     if dropout_rate > 0.0 and dropout_key is None:
         raise ValueError("dropout_rate > 0 requires a dropout_key")
     forced = impl is not None
@@ -986,6 +1203,7 @@ def flash_attention(
                         "flash_attention", impl,
                         q3, k3, v3, lens_pallas, seed,
                         causal=causal, scale=scale, rate=float(dropout_rate),
+                        **windowed,
                     )
                 else:
                     # default-on dispatch is guarded; a forced impl='pallas'
@@ -994,13 +1212,14 @@ def flash_attention(
                         "flash_attention", impl, _probe_flash_pallas,
                         q3, k3, v3, lens_pallas, seed,
                         causal=causal, scale=scale, rate=float(dropout_rate),
+                        **windowed,
                     )
         if impl == "pallas":
             o = _flash3(q3, k3, v3, lens_pallas, seed, causal, scale,
-                        float(dropout_rate))
+                        float(dropout_rate), window)
         else:
             o = _attn_jnp(q3, k3, v3, lens_bh, causal, scale,
-                          dropout_rate, dropout_key)
+                          dropout_rate, dropout_key, window)
     # remat boundary tag: the attention context is a cheap (B, H, S, D)
     # save point vs the O(S^2) score/prob intermediates behind it
     return _checkpoint_name(o.reshape(B, H, S, D), _TAG_ATTN_OUT)
@@ -1019,6 +1238,7 @@ def self_attention(
     dropout_rate: float = 0.0,
     dropout_key: Optional[jax.Array] = None,
     impl: Optional[str] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Fused self-attention block: QKV projection → flash attention → output
     projection (ref: apex/contrib/multihead_attn/self_multihead_attn.py:22,
@@ -1044,6 +1264,7 @@ def self_attention(
     ctx = flash_attention(
         heads(q), heads(k), heads(v), causal=causal, kv_lens=kv_lens,
         dropout_rate=dropout_rate, dropout_key=dropout_key, impl=impl,
+        window=window,
     )
     ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, D)
     out = ctx @ w_out.astype(x.dtype)

@@ -524,3 +524,245 @@ class TestTiledParity:
         want = _oracle_grads(*(x.astype(jnp.float32) for x in (qb, kb, vb)), full, True, scale, w)[1:]
         for name, a, b in zip(("dq", "dk", "dv"), got, want):
             np.testing.assert_allclose(a.astype(np.float32), b, atol=3e-2, rtol=3e-2, err_msg=name)
+
+
+# ---------------------------------------------------------------------------------
+# the window (PR 31): causal attention over the last W keys, the grid the band
+# ---------------------------------------------------------------------------------
+
+
+def _window_oracle(q, k, v, window, scale, kv_lens=None):
+    """Materialised mask, straight from the definition: key j of query i is
+    kept where ``i - window < j <= i`` (and ``j < len``)."""
+    S = q.shape[1]
+    i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+    keep = (j <= i) & (j > i - window)
+    keep = keep[None] if kv_lens is None else keep[None] & (j[None] < kv_lens[:, None, None])
+    s = jnp.where(keep, jnp.einsum("bqd,bkd->bqk", q, k) * scale, -jnp.inf)
+    p = jnp.where(keep, jnp.exp(s - jnp.max(jnp.where(keep, s, -1e30), -1, keepdims=True)), 0.0)
+    l = jnp.sum(p, -1, keepdims=True)
+    return jnp.einsum("bqk,bkd->bqd", jnp.where(l > 0, p / jnp.where(l > 0, l, 1.0), 0.0), v)
+
+
+# (S, W, D): where the window's lower edge falls against the plan's blocks
+WINDOWED = {
+    "edge-inside-a-block": (512, 200, 64),          # block 256 > W, tiles of 128
+    "edge-inside-a-tile-D128": (1024, 300, 128),    # block 512, the edge crosses two tiles
+    "edge-on-a-block-boundary": (1024, 512, 64),    # W == block
+    "window-is-one-tile": (512, 128, 64),           # W == block == tile
+    "across-several-blocks": (2560, 2 * 512 + 128, 64),   # W = 2 blocks + 128: band of 4
+    "one-block-a-head": (128, 50, 64),              # the one-pass body, both edges in it
+    "own-key-only": (256, 1, 64),
+}
+
+
+class TestWindow:
+    @staticmethod
+    def _inputs(S, D, seed, BH=2):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+        return tuple(jax.random.normal(kk, (BH, S, D), jnp.float32) for kk in ks)
+
+    def test_plans_put_the_edge_where_the_cases_say(self):
+        plans = {name: A._tile_plan(S, S, D, True, W) for name, (S, W, D) in WINDOWED.items()}
+        got = {name: (p.bq, p.tq, p.band) for name, p in plans.items()}
+        assert got == {
+            "edge-inside-a-block": (256, 128, 2), "edge-inside-a-tile-D128": (512, 128, 2),
+            "edge-on-a-block-boundary": (512, 128, 2), "window-is-one-tile": (128, 128, 2),
+            "across-several-blocks": (512, 128, 4), "one-block-a-head": (128, 128, 1),
+            "own-key-only": (128, 128, 1)}
+        assert plans["one-block-a-head"].one_pass
+
+    @pytest.mark.parametrize("lens", [None, (0.62, 1.0)], ids=["no-lens", "kv-lens"])
+    @pytest.mark.parametrize("case", WINDOWED)
+    def test_matches_the_materialised_mask(self, case, lens):
+        """Forward and the three gradients, Pallas in interpret mode, against
+        the mask written out (tolerances: the causal path's, TestTiledParity)."""
+        S, W, D = WINDOWED[case]
+        q, k, v, w = self._inputs(S, D, 31)
+        scale = 1.0 / np.sqrt(D)
+        kv = None if lens is None else jnp.asarray([int(f * S) for f in lens], jnp.float32)
+        seed = jnp.zeros((1,), jnp.int32)
+
+        def flash(q, k, v):
+            return A._flash3(q, k, v, kv, seed, True, scale, 0.0, W)
+
+        got = (flash(q, k, v),) + jax.grad(
+            lambda q, k, v: jnp.sum(flash(q, k, v) * w), argnums=(0, 1, 2))(q, k, v)
+        want = (_window_oracle(q, k, v, W, scale, kv),) + jax.grad(
+            lambda q, k, v: jnp.sum(_window_oracle(q, k, v, W, scale, kv) * w),
+            argnums=(0, 1, 2))(q, k, v)
+        for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+            assert not np.any(np.isnan(np.asarray(a))), name
+            np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5, err_msg=name)
+
+    @pytest.mark.parametrize("impl", ["pallas", "jnp"])
+    def test_public_call_and_jnp_path(self, impl):
+        """(B, H, S, D) through ``flash_attention`` on both paths, gradients
+        through the custom VJP / autodiff, bf16 on the Pallas path's terms."""
+        S, W, D = 512, 200, 64
+        q, k, v, w = (x.reshape(1, 2, S, D) for x in self._inputs(S, D, 32))
+        lens = jnp.asarray([400])
+        f = lambda q, k, v: jnp.sum(A.flash_attention(
+            q, k, v, causal=True, window=W, kv_lens=lens, impl=impl) * w)
+        g = lambda q, k, v: jnp.sum(_window_oracle(
+            q[0], k[0], v[0], W, D ** -0.5, jnp.asarray([400.0, 400.0])) * w[0])
+        for a, b in zip(jax.grad(f, (0, 1, 2))(q, k, v), jax.grad(g, (0, 1, 2))(q, k, v)):
+            np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5)
+
+    def test_lse_variant_and_self_attention_pass_the_window_on(self):
+        S, W, D = 512, 200, 64
+        q, k, v, w = self._inputs(S, D, 33)
+        o, lse = A.flash_attention_with_lse(q, k, v, causal=True, scale=0.125, window=W)
+        np.testing.assert_allclose(o, _window_oracle(q, k, v, W, 0.125), atol=5e-5, rtol=5e-5)
+        i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+        s = jnp.where((j <= i) & (j > i - W), jnp.einsum("bqd,bkd->bqk", q, k) * 0.125, -jnp.inf)
+        np.testing.assert_allclose(lse, jax.nn.logsumexp(s, -1), atol=5e-5, rtol=5e-5)
+        x = jax.random.normal(jax.random.PRNGKey(3), (1, S, 128), jnp.float32)
+        w_qkv = jax.random.normal(jax.random.PRNGKey(4), (128, 384), jnp.float32) * 0.05
+        w_out = jnp.eye(128)
+        a = A.self_attention(x, w_qkv, None, w_out, None, 2, causal=True, window=W, impl="pallas")
+        b = A.self_attention(x, w_qkv, None, w_out, None, 2, causal=True, window=W, impl="jnp")
+        c = A.self_attention(x, w_qkv, None, w_out, None, 2, causal=True, impl="jnp")
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5)
+        assert float(jnp.max(jnp.abs(b - c))) > 1e-3          # the window cuts
+
+    @pytest.mark.parametrize("by_cols", [False, True], ids=["rows", "cols"])
+    @pytest.mark.parametrize("case", [c for c in WINDOWED if WINDOWED[c][0] <= 2560])
+    def test_band_walk_covers_exactly_the_live_tiles(self, case, by_cols):
+        """Brute force against the mask: over the band's blocks a tile is
+        computed iff some score in it is live, once; it goes through the causal
+        test iff the diagonal crosses it and through the window test iff the
+        lower edge does — for the row walk (fwd, dq) and the column walk (dkv)."""
+        S, W, D = WINDOWED[case]
+        plan = A._tile_plan(S, S, D, True, W)
+        b, t, seen = plan.bq, plan.tq, {}
+        for i in range(plan.nq):
+            for d in range(min(plan.band, i + 1)):
+                for fixed, pieces in plan.band_walk(by_cols, d):
+                    for moving, edge in pieces:
+                        rows, cols = (moving, fixed) if by_cols else (fixed, moving)
+                        for r in range(rows.start, rows.stop, t):
+                            for c in range(cols.start, cols.stop, t):
+                                key = ((i * b + r) // t, ((i - d) * b + c) // t)
+                                assert key not in seen
+                                seen[key] = edge
+        want = {}
+        for r in range(S // t):
+            for c in range(S // t):
+                lo, hi = (r - c) * t - (t - 1), (r - c) * t + (t - 1)     # q - k over the tile
+                if hi >= 0 and lo < W:
+                    want[(r, c)] = (1 if lo < 0 else 0) | (2 if hi >= W else 0)
+        assert seen == want
+        counts = plan.counts(False)
+        assert counts["live"] == len(want) and counts["masked"] == sum(map(bool, want.values()))
+        assert plan.counts(True)["masked"] == len(want)
+
+    def test_the_mellum_cells_plan(self):
+        """(S 8192, W 1024, D 128): blocks of 1024 in four strips, a band of two
+        blocks a query block, and no grid step on a block outside the band."""
+        plan = A._tile_plan(8192, 8192, 128, True, 1024)
+        assert (plan.bq, plan.bk, plan.tq, plan.tk, plan.band) == (1024, 1024, 256, 256, 2)
+        # 15 live blocks of the 64: the diagonal's 10 tiles + 10 of the block before it
+        assert plan.counts(False) == {"total": 1024, "live": 150, "masked": 60}
+        own, keys, queries = A._block_maps(plan)
+        visited, clamped = set(), 0
+        for i in range(plan.nq):
+            steps = [int(keys(0, i, s)[1]) for s in range(plan.band)]
+            assert len(steps) <= 2 and own(0, i, 0) == (0, i, 0)
+            for s, j in enumerate(steps):
+                if i + s - (plan.band - 1) < 0:      # before the sequence's start:
+                    clamped += 1                     # clamped onto the next step's block,
+                    assert j == steps[s + 1]         # so nothing new is copied
+                else:
+                    assert 0 <= i - j < plan.band    # inside the band
+                    visited.add((i, j))
+        assert len(visited) == 15 and clamped == 1
+        assert {(int(queries(0, j, s)[1]), j) for j in range(plan.nk)
+                for s in range(plan.band) if j + s < plan.nq} == visited
+        assert int(queries(0, plan.nk - 1, 1)[1]) == plan.nq - 1      # dkv's one clamped step
+
+    def test_tile_counter_and_kernel_names(self):
+        from beforeholiday_tpu import monitor
+        from beforeholiday_tpu.guard import dispatch
+
+        dispatch.reset_dispatch_counters()
+        q, k, v = _qkv(jax.random.PRNGKey(7), B=1, H=1, S=512)
+        f = lambda q: jnp.sum(A.flash_attention(q, k, v, causal=True, window=200, impl="pallas"))
+        jax.grad(f)(q)
+        rows = {r["kernel"]: r for r in monitor.tile_records() if r["op"] == "flash_attention"}
+        assert sorted(rows) == ["dkv", "dq", "fwd"]
+        for r in rows.values():
+            assert r["key"] == repr((512, 512, 64, True, False, 200))
+            assert (r["live"], r["total"], r["masked"]) == (9, 16, 9)
+        dispatch.reset_dispatch_counters()
+        names = [e.params["name"] for e in _pallas_eqns(jax.make_jaxpr(jax.grad(f))(q).jaxpr)]
+        assert sorted(names) == ["flash_attention_window_dkv", "flash_attention_window_dq",
+                                 "flash_attention_window_fwd"]
+
+    def test_errors(self):
+        q, k, v = _qkv(jax.random.PRNGKey(1), B=1, H=1, S=128)
+        with pytest.raises(ValueError, match="causal=True"):
+            A.flash_attention(q, k, v, window=64)
+        with pytest.raises(ValueError, match="at least"):
+            A.flash_attention(q, k, v, causal=True, window=0)
+
+
+def _pallas_eqns(jaxpr):
+    out = []
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            out.append(e)
+            continue
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                if hasattr(sub, "eqns") or hasattr(sub, "jaxpr"):
+                    out += _pallas_eqns(getattr(sub, "jaxpr", sub))
+    return out
+
+
+class TestNoWindowIsTheParentsCall:
+    """``window=None`` (and a window that holds the whole sequence) plans,
+    counts, names and keys a call as the commit before the window did."""
+
+    @pytest.mark.parametrize("key,block,tile,counts", TestTilePlan.TABLE,
+                             ids=[f"S{k[0]}x{k[1]}-D{k[2]}-{'causal' if k[3] else 'full'}"
+                                  for k, *_ in TestTilePlan.TABLE])
+    def test_plan_and_counts(self, key, block, tile, counts):
+        plan = A._tile_plan(*key)
+        assert plan == A._tile_plan(*key, None) and plan.window is None
+        assert tuple(plan)[:7] == (key[0], key[1], *block, *tile, key[3])
+        live, total, masked = counts
+        assert plan.counts(False) == {"total": total, "live": live, "masked": masked}
+
+    @pytest.mark.parametrize("window", [None, 512, 4096], ids=["none", "W=S", "W>S"])
+    def test_probe_key_tile_key_grid_and_names(self, window, monkeypatch):
+        from beforeholiday_tpu import monitor
+        from beforeholiday_tpu.guard import dispatch as gd
+
+        monkeypatch.setattr(A, "_resolve_impl", lambda impl: "pallas")
+        q, k, v = _qkv(jax.random.PRNGKey(5), B=1, H=2, S=512, D=64)
+        gd.clear_probe_cache("flash_attention")
+        gd.reset_dispatch_counters()
+        f = lambda q: jnp.sum(A.flash_attention(q, k, v, causal=True, window=window))
+        jaxpr = jax.make_jaxpr(jax.grad(f))(q).jaxpr
+        keys = [key for key in gd.dispatch_counters() if key[0] == "flash_attention"]
+        sig = ((2, 512, 64), "float32")
+        assert keys == [("flash_attention", "cpu", (sig, sig, sig, "None", ((1,), "int32")),
+                         (("causal", "True"), ("rate", "0.0"), ("scale", "0.125")), ())]
+        assert {r["key"] for r in monitor.tile_records()} == {repr((512, 512, 64, True, False))}
+        calls = _pallas_eqns(jaxpr)
+        assert [e.params["grid_mapping"].grid for e in calls] == [(2, 1, 1)] * 3
+        assert all(e.params["name"] is None for e in calls)    # the scope names them
+        gd.reset_dispatch_counters()
+
+    def test_a_windowed_call_has_its_own_probe_key(self, monkeypatch):
+        from beforeholiday_tpu.guard import dispatch as gd
+
+        monkeypatch.setattr(A, "_resolve_impl", lambda impl: "pallas")
+        q, k, v = _qkv(jax.random.PRNGKey(5), B=1, H=1, S=512, D=64)
+        gd.clear_probe_cache("flash_attention")
+        gd.reset_dispatch_counters()
+        A.flash_attention(q, k, v, causal=True, window=200)
+        (key,) = [key for key in gd.dispatch_counters() if key[0] == "flash_attention"]
+        assert key[3] == (("causal", "True"), ("rate", "0.0"), ("scale", "0.125"), ("window", "200"))
+        gd.reset_dispatch_counters()

@@ -274,3 +274,84 @@ def test_compile_cache_key_covers_the_scope_names():
     finally:
         jax.config.update("jax_compilation_cache_dir", prev_dir)
         jax.config.update("jax_compilation_cache_include_metadata_in_key", prev)
+
+
+# ---------------------------------------------------------------------------
+# family mellum (PR 31): window_mixer / full_mixer and the model's four scopes
+# ---------------------------------------------------------------------------
+
+_MELLUM_MODEL = ("mellum_embed", "mellum_layers", "mellum_head", "mellum_loss")
+_MELLUM_SCOPES = _MELLUM_MODEL + (
+    "window_mixer", "full_mixer", "amp_forward", "amp_backward", "amp_unscale",
+    "fused_adam_step_flat", "layer_norm", "flash_attention", "moe_route", "moe_dispatch",
+    "moe_experts", "moe_combine")
+
+
+@pytest.fixture(scope="module")
+def mellum_names():
+    """The distinct ``op_name`` of every op of the compiled tiny-mellum step."""
+    from benchmark import run as bench_run
+
+    cell = bench_run.load("workloads", "tiny-mellum.train")
+    run = bench_run.Cell(cell, bench_run.load("configs", cell["config"]), jax.devices()[:1])
+    run.start(7)
+    run.build()
+    compiled = run.program.step.jitted.lower(run.state, run.pool[0]).compile()
+    return sorted(set(re.findall(r'op_name="(jit\([^"]+)"', compiled.as_text())))
+
+
+@pytest.mark.parametrize("scope", _MELLUM_SCOPES)
+def test_mellum_scope_is_in_the_compiled_step(mellum_names, scope):
+    assert any(scope in _scopes_of(n) or f"jvp({scope})" in n for n in mellum_names), scope
+
+
+def test_mellum_first_level_scopes_partition_the_step(mellum_names):
+    first = ("amp_backward", "amp_unscale", "ddp_reduce_gradients",
+             "ddp_overlap_hook", "fused_adam_step_flat")
+    twice = [n for n in mellum_names
+             if sum(s in n for s in first) + (_pass_of(n) == "amp_forward") > 1]
+    assert not twice
+    both = [n for n in mellum_names if "amp_forward" in n and "amp_backward" in n]
+    assert all("amp_backward/transpose(amp_forward)" in n for n in both), both
+    for scope in _MELLUM_MODEL:      # the model's scopes survive inside both passes
+        assert any(f"amp_forward/jvp({scope})" in n for n in mellum_names), scope
+        assert any(_pass_of(n) == "amp_backward" and f"jvp({scope})" in n
+                   for n in mellum_names), scope
+
+
+def test_mellum_second_level_scopes_do_not_overlap(mellum_names):
+    """An op is under one model scope at most, and under one of the two mixers
+    or the MoE at most; the mixers and the MoE lie inside ``mellum_layers``."""
+    for n in mellum_names:
+        assert sum(f"({s})" in n or s in _scopes_of(n) for s in _MELLUM_MODEL) <= 1, n
+        parts = [s for s in ("window_mixer", "full_mixer", "moe") if s in _scopes_of(n)]
+        assert len(parts) <= 1, n
+        if parts and _pass_of(n):
+            assert "mellum_layers" in n, n
+    heavy = [n for n in mellum_names if n.endswith("dot_general")]
+    assert heavy and not [n for n in heavy if _pass_of(n) is None]
+
+
+@pytest.mark.parametrize("kind", ["sliding_attention", "full_attention"])
+def test_flash_attention_stays_innermost_in_both_mellum_mixers(kind):
+    from beforeholiday_tpu.models import mellum
+
+    cfg = mellum.MellumConfig(attention_impl="pallas", sliding_window=100, dtype=jnp.bfloat16)
+    params = mellum.init(jax.random.PRNGKey(0), cfg)
+    lp = {k: v[0] for k, v in params["layers"].items()}
+    x = jnp.zeros((1, 256, cfg.hidden_size), jnp.bfloat16)
+    table = mellum.rotary_tables(cfg, 256)[kind]
+    f = lambda x, lp: jnp.sum(mellum.attention(cfg, x, lp, kind, table).astype(jnp.float32))
+    text = jax.jit(jax.grad(f)).lower(x, lp).as_text(debug_info=True)
+    mixer = "window_mixer" if kind == "sliding_attention" else "full_mixer"
+    inside = [n for n in set(re.findall(r'loc\("([^"]+)"', text)) if "flash_attention/" in n]
+    assert inside and all(mixer in n for n in inside)
+    kernels = [n for n in inside if "pallas_call" in _scopes_of(n)]
+    # the windowed kernels alone carry their own names (a ``pallas_call``'s name
+    # is one more scope around it), under the op's scope and prefix
+    named = r"flash_attention/flash_attention_window_(fwd|dq|dkv)/pallas_call$"
+    if kind == "sliding_attention":
+        assert len(kernels) == 3 and all(re.search(named, n) for n in kernels), kernels
+    else:
+        assert kernels and all(n.endswith("flash_attention/pallas_call") for n in kernels)
+        assert "flash_attention_window" not in text

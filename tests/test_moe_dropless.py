@@ -216,3 +216,43 @@ def test_rows_of_no_group_never_reach_the_result_or_its_gradients(monkeypatch):
     _close(got[0], want[0], "dx")
     for name in ("router", "w_gate", "w_up", "w_down"):
         _close(got[1][name], want[1][name], f"d{name}")
+
+
+# -- a model without a shared expert (PR 31) ---------------------------------------
+
+_SHARED = ("shared_w_gate", "shared_w_up", "shared_w_down", "shared_score")
+
+
+@pytest.mark.parametrize("first,held", ((0, E), (4, 4), (12, 4)))
+def test_without_a_shared_expert_the_layer_is_its_routed_part(first, held):
+    p, x = layer_params(8), tokens(8)
+    mine = {k: v for k, v in dict(p, **held_slice(p, first, held)).items() if k not in _SHARED}
+    got, counters = dropless.dropless_moe(x, mine, top_k=K, first_expert=first)
+    w, idx = dropless.route_topk(x, p["router"], K)
+    routed, want_counters = dropless.dropless_experts(
+        x, w, idx, held_slice(p, first, held), first_expert=first)
+    assert got.dtype == x.dtype and bool(jnp.array_equal(got, routed.astype(x.dtype)))
+    _close(got, dense_routed(x, p, first, held), "routed alone")
+    assert {k: float(v) for k, v in counters.items()} == \
+        {k: float(v) for k, v in want_counters.items()}
+
+
+def test_with_a_shared_expert_the_layer_is_what_it_was():
+    """Bit for bit: the routed part plus the gated shared expert, summed in
+    float32 and cast once, as before the branch."""
+    p, x = layer_params(9), tokens(9)
+    got, _ = dropless.dropless_moe(x, p, top_k=K)
+    w, idx = dropless.route_topk(x, p["router"], K)
+    routed, _ = dropless.dropless_experts(x, w, idx, held_slice(p, 0, E))
+    shared = dropless.shared_expert(*(p[k] if k != "x" else x for k in ("x",) + _SHARED))
+    assert bool(jnp.array_equal(got, (routed + shared.astype(jnp.float32)).astype(x.dtype)))
+
+
+def test_the_shared_span_opens_only_where_there_is_a_shared_expert():
+    p, x = layer_params(7), tokens(7)
+    bare = {k: v for k, v in p.items() if k not in _SHARED}
+    hlo = jax.jit(lambda x, p: dropless.dropless_moe(x, p, top_k=K)[0]).lower(
+        x, bare).compile().as_text()
+    for scope in ("moe/moe_route", "moe/moe_dispatch", "moe/moe_experts", "moe/moe_combine"):
+        assert scope in hlo, scope
+    assert "moe_shared" not in hlo

@@ -231,3 +231,26 @@ def test_required_operations_at_the_published_widths():
     assert total == 6 * (3 * linear + attn + 4 * moe + 18992 * 2048) + 6 * 8192 * 4096 \
         + 18 * 128 * 128 * 32 * 3
     assert 1.2e9 < total < 1.5e9                           # ISSUE 26: 1.38 GFLOP a token
+
+
+def test_rope_partial_on_the_shared_table_is_bit_for_bit_what_it_was():
+    """``rope_partial`` is now a call of ``models.layers``; the parent's own
+    lines (a0dd9e5), kept here, give the same bits."""
+    def parents(x, rotary_dim, theta):
+        half = rotary_dim // 2
+        inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rotary_dim)
+        angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv_freq[None, :]
+        cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
+        x1 = x[..., :half].astype(jnp.float32)
+        x2 = x[..., half:rotary_dim].astype(jnp.float32)
+        rotated = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+        return jnp.concatenate([rotated.astype(x.dtype), x[..., rotary_dim:]], axis=-1)
+
+    for dtype, rotary_dim, theta in ((jnp.float32, 64, 1e7), (jnp.bfloat16, 64, 1e7),
+                                     (jnp.float32, 8, 1e4)):
+        x = jax.random.normal(jax.random.PRNGKey(9), (2, 300, 3, 256), dtype)
+        np.testing.assert_array_equal(np.asarray(model.rope_partial(x, rotary_dim, theta)),
+                                      np.asarray(parents(x, rotary_dim, theta)))
+        np.testing.assert_array_equal(
+            np.asarray(jax.jit(model.rope_partial, static_argnums=(1, 2))(x, rotary_dim, theta)),
+            np.asarray(jax.jit(parents, static_argnums=(1, 2))(x, rotary_dim, theta)))
