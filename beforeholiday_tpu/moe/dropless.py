@@ -7,10 +7,12 @@ route each token to ten of several hundred narrow experts (``top_k = 10`` of
 ``T x E x C`` floats, and they may not drop a token. This module is the other
 recipe (MegaBlocks, Gale et al. 2022; see PAPERS.md): sort the ``(token,
 choice)`` assignments by expert, run each expert's SwiGLU as one group of a
-grouped matmul over the sorted rows (``jax.lax.ragged_dot``, which the TPU
-compiler runs as a native grouped kernel: rows outside every group cost
-nothing, and are left uninitialised, forward and backward, so both sides of
-the experts are masked), and scatter the weighted results back onto the tokens.
+grouped matmul over the sorted rows (``ops/grouped_matmul.py``: Pallas kernels
+that hold an expert's weight panel in VMEM while its row tiles go by, and
+``jax.lax.ragged_dot`` off their shapes and off the TPU; either way rows
+outside every group cost nothing and are left unspecified, forward and
+backward, so both sides of the experts are masked), and scatter the weighted
+results back onto the tokens.
 
 **The layer is told which experts it holds** (``first_expert`` and the leading
 axis of the stacked expert weights), as expert parallelism asks: the router
@@ -39,6 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from beforeholiday_tpu.monitor.spans import span as _span
+from beforeholiday_tpu.ops.grouped_matmul import grouped_matmul as _grouped_matmul
 
 __all__ = [
     "dropless_experts",
@@ -87,12 +90,14 @@ def dropless_experts(
     *,
     first_expert: int = 0,
     rows_bound: Optional[int] = None,
+    impl: Optional[str] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """The held experts' part of ``sum_e w_e * swiglu_e(x)``.
 
     ``x``: ``(T, D)``; ``weights`` / ``idx``: ``(T, k)`` from :func:`route_topk`;
     ``experts``: ``w_gate`` / ``w_up`` ``(E_held, D, F)`` and ``w_down``
     ``(E_held, F, D)`` for expert ids ``first_expert .. first_expert + E_held``.
+    ``impl`` is :func:`~beforeholiday_tpu.ops.grouped_matmul.grouped_matmul`'s.
     Returns ``(y (T, D) float32, counters)``."""
     T, D = x.shape
     k = idx.shape[1]
@@ -119,10 +124,10 @@ def dropless_experts(
         xs = jnp.where(valid[:, None], x[token], 0)
     with _span("moe_experts"):
         dt = x.dtype
-        ragged = lambda a, w, out: jax.lax.ragged_dot(
-            a, w.astype(dt), group_sizes, preferred_element_type=out)
-        h = jax.nn.silu(ragged(xs, experts["w_gate"], _F32)) * ragged(xs, experts["w_up"], _F32)
-        y = ragged(h.astype(dt), experts["w_down"], dt)      # as a dense layer hands it on
+        grouped = lambda a, w, out: _grouped_matmul(
+            a, w.astype(dt), group_sizes, preferred_element_type=out, impl=impl)
+        h = jax.nn.silu(grouped(xs, experts["w_gate"], _F32)) * grouped(xs, experts["w_up"], _F32)
+        y = grouped(h.astype(dt), experts["w_down"], dt)      # as a dense layer hands it on
     with _span("moe_combine"):
         # rows of no group are whatever the grouped kernel left there: cut them
         y = jnp.where(valid[:, None], y, 0).astype(_F32) * w_sorted[:, None]
@@ -144,6 +149,7 @@ def dropless_moe(
     first_expert: int = 0,
     rows_bound: Optional[int] = None,
     renormalize: bool = True,
+    impl: Optional[str] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Routed experts (the held ones' part) plus, where the model has one, the
     gated shared expert.
@@ -159,7 +165,7 @@ def dropless_moe(
             weights, idx = route_topk(x, p["router"], top_k, renormalize=renormalize)
         routed, counters = dropless_experts(
             x, weights, idx, {n: p[n] for n in ("w_gate", "w_up", "w_down")},
-            first_expert=first_expert, rows_bound=rows_bound)
+            first_expert=first_expert, rows_bound=rows_bound, impl=impl)
         if "shared_w_gate" in p:
             with _span("moe_shared"):
                 shared = shared_expert(x, p["shared_w_gate"], p["shared_w_up"],

@@ -12,6 +12,9 @@
 * ``attention`` — Pallas flash attention (``fmhalib``, ``fast_multihead_attn``).
 * ``gated_delta`` — the gated delta rule of Gated DeltaNet linear attention,
   chunk-wise, with a Pallas chunk scan (no reference equivalent).
+* ``grouped_matmul`` — rows sorted by expert times each expert's weight panel,
+  the panel held in VMEM while its row tiles go by (Pallas; ``jax.lax.ragged_dot``
+  off the kernels' shapes): the experts of ``moe/dropless.py``.
 * ``quantized`` — fp8-style quantized matmul with per-tensor delayed scaling
   (the O6 tier; no reference equivalent — Transformer-Engine-shaped departure).
 """

@@ -300,6 +300,66 @@ def check_wy_prepare(results: list) -> None:
           json.dumps({n: round(t, 3) for n, t in ms.items()}))
 
 
+# (tag, buffer rows, groups, K, N, rows in a group, the product's dtype)
+_GROUPED_SHAPES = (
+    ("mellum_up", 24576, 16, 2304, 896, 16400, jnp.float32),
+    ("mellum_down", 24576, 16, 896, 2304, 16400, jnp.bfloat16),
+    ("qwen_up", 16384, 32, 2048, 512, 5240, jnp.float32),
+    ("qwen_down", 16384, 32, 512, 2048, 5240, jnp.bfloat16),
+)
+
+
+def check_grouped_matmul(results: list) -> None:
+    """The experts' grouped matmuls in their three kernels (``ops.grouped_matmul``:
+    ``grouped_matmul_fwd`` / ``_dlhs`` / ``_drhs``), compiled, at both 8k cells'
+    shapes (the Mellum cell's 24,576-row buffer with ~16,400 rows in 16 groups
+    against 2304 x 896 panels, the Qwen cell's 16,384 with ~5,240 in 32 against
+    2048 x 512; both ways round, as the up and the down projection run them):
+    the output and both cotangents against ``lax.ragged_dot`` on the rows of a
+    group, and the ms a layer's product takes in each kernel beside XLA's.
+    Interpret mode cannot see what Mosaic makes of a 4 MB panel held across grid
+    steps or of the transposed left operand of ``drhs``."""
+    from beforeholiday_tpu.ops import grouped_matmul as gm
+
+    def check(name, cond, info=""):
+        results.append((f"grouped_matmul/{name}", bool(cond), str(info)))
+
+    bf = jnp.bfloat16
+    for tag, R, E, K, N, rows, out_dtype in _GROUPED_SHAPES:
+        ks = jax.random.split(jax.random.PRNGKey(R + K), 3)
+        share = np.random.default_rng(E).dirichlet(np.full(E, 8.0))   # fullest ~1.5-2 x the mean
+        sizes = jnp.asarray(np.floor(share * rows), jnp.int32)
+        valid = (jnp.arange(R) < jnp.sum(sizes))[:, None]
+        lhs = jax.random.normal(ks[0], (R, K), jnp.float32).astype(bf)
+        rhs = (jax.random.normal(ks[1], (E, K, N), jnp.float32) * 0.05).astype(bf)
+        ct = jax.random.normal(ks[2], (R, N), jnp.float32).astype(out_dtype)
+
+        def runs(impl):
+            op = lambda a, b: gm.grouped_matmul(a, b, sizes, preferred_element_type=out_dtype,
+                                                impl=impl)
+            pull = lambda which: jax.jit(lambda a, b, c: jax.vjp(op, a, b)[1](c)[which])
+            return {"fwd": jax.jit(op), "dlhs": pull(0), "drhs": pull(1)}
+
+        fns = {"pallas": runs("pallas"), "ragged_dot": runs("jnp")}
+        args = {"fwd": (lhs, rhs), "dlhs": (lhs, rhs, ct), "drhs": (lhs, rhs, ct)}
+        ms = {}
+        for kernel in ("fwd", "dlhs", "drhs"):
+            got, want = (fns[impl][kernel](*args[kernel]).astype(jnp.float32)
+                         for impl in ("pallas", "ragged_dot"))
+            if kernel != "drhs":        # rows of no group are unspecified on both sides
+                got, want = jnp.where(valid, got, 0.0), jnp.where(valid, want, 0.0)
+            gap, scale = float(jnp.max(jnp.abs(got - want))), float(jnp.max(jnp.abs(want)))
+            # bfloat16 results a rounding apart; a float32 one differs by the order of its sums
+            ok = bool(jnp.all(jnp.isfinite(got))) and gap <= 2e-2 * scale
+            check(f"{tag}/{kernel}", ok, f"max|d|={gap:.3e} of {scale:.3e}")
+            for impl in fns:
+                ms[f"{impl}_{kernel}"] = 1e3 * _min_step_seconds(
+                    lambda _: fns[impl][kernel](*args[kernel]), None)
+        check(f"{tag}/ms_a_product",
+              all(ms[f"pallas_{k}"] < ms[f"ragged_dot_{k}"] for k in ("fwd", "dlhs", "drhs")),
+              json.dumps({n: round(t, 3) for n, t in ms.items()}))
+
+
 def check_aliased_mt_kernels(results: list) -> None:
     """The Pallas multi-tensor kernels run with input_output_aliases on the
     compiled path (in-place updates, ~1.8x streaming win) — aliasing bugs
@@ -723,7 +783,8 @@ def main() -> int:
     enable_compile_cache()
     results: list = []
     for group in (check_flash_dropout, check_flash_tiles, check_wy_prepare,
-                  check_aliased_mt_kernels, check_compiled_kernel_parity):
+                  check_grouped_matmul, check_aliased_mt_kernels,
+                  check_compiled_kernel_parity):
         try:
             group(results)
         except Exception as e:  # a crashed group must not mask the others

@@ -1,0 +1,64 @@
+"""Kernels of the main path compiled for a described v5e, at the cells' widths.
+
+Nothing runs and no chip is needed: the TPU's compiler is installed here and
+compiles for a chip that is described, not attached. It refuses what the
+Pallas interpreter lets through (a block Mosaic cannot tile, more VMEM than a
+kernel may take), so each later PR is held to it at no chip time. The topology
+is described inside a fixture: only the worker that is given this file loads
+the TPU's library (see the ``on-chip-measurement`` guide, section 2). Keep every
+such compile in this one file."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:      # noqa: BLE001 — no compiler here, nothing to hold to
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def compiled_not_interpreted(monkeypatch):
+    from beforeholiday_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_interpret_default", lambda: False)
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)   # unreadable without a chip
+    yield gm
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.mark.parametrize("R,E,K,N,out", (
+    (24576, 16, 2304, 896, jnp.float32),        # the Mellum cell, gate / up
+    (24576, 16, 896, 2304, jnp.bfloat16),       # ... down
+    (16384, 32, 2048, 512, jnp.float32),        # the Qwen cell, gate / up
+    (16384, 32, 512, 2048, jnp.bfloat16),       # ... down
+    (1000, 4, 128, 256, jnp.float32),           # a buffer that ends inside a row tile
+), ids=("mellum_up", "mellum_down", "qwen_up", "qwen_down", "ragged_buffer"))
+def test_the_grouped_matmul_kernels_compile_for_the_chip(one_chip, compiled_not_interpreted,
+                                                         R, E, K, N, out):
+    gm = compiled_not_interpreted
+    bf = jnp.bfloat16
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def all_three(lhs, rhs, sizes, ct):
+        y, pull = jax.vjp(lambda a, b: gm.grouped_matmul(
+            a, b, sizes, preferred_element_type=out, impl="pallas"), lhs, rhs)
+        return y, pull(ct)
+
+    text = jax.jit(all_three).lower(
+        shape((R, K), bf), shape((E, K, N), bf), shape((E,), jnp.int32), shape((R, N), out)
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    for kernel in ("fwd", "dlhs", "drhs"):
+        assert f"grouped_matmul_{kernel}" in text

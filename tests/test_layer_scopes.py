@@ -355,3 +355,42 @@ def test_flash_attention_stays_innermost_in_both_mellum_mixers(kind):
     else:
         assert kernels and all(n.endswith("flash_attention/pallas_call") for n in kernels)
         assert "flash_attention_window" not in text
+
+
+# ---------------------------------------------------------------------------
+# the grouped-matmul kernels of the dropless experts (PR 32)
+# ---------------------------------------------------------------------------
+
+def test_the_grouped_matmul_kernels_lie_under_the_experts_scope_in_both_passes():
+    """``moe_ms[.mellum]`` reads ``/moe/``, ``grouped_matmul_ms[.mellum]`` the
+    kernels' own ``name=`` (the chip prints ``%grouped_matmul_fwd.N``); XLA's
+    ``ragged_dot`` carried no scope and read under ``unattributed_ms``. Now each
+    kernel lies under ``moe/moe_experts`` and under exactly one pass, so the
+    first-level partition holds once they leave ``unattributed_ms``."""
+    from beforeholiday_tpu.moe import dropless
+
+    T, D, E, F = 64, 128, 4, 128
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    n = lambda k, *shape: (jax.random.normal(k, shape) * 0.2).astype(jnp.bfloat16)
+    p = {"router": n(ks[0], D, E), "w_gate": n(ks[1], E, D, F), "w_up": n(ks[2], E, D, F),
+         "w_down": n(ks[3], E, F, D)}
+    x = n(ks[4], T, D)
+    svag = amp.scaled_value_and_grad(
+        lambda p, x: jnp.sum(dropless.dropless_moe(x, p, top_k=2, impl="pallas")[0]
+                             .astype(jnp.float32)),
+        LossScaler(loss_scale=1.0))
+    # the compiled program's names: a kernel call is a ``jax.jit`` function of its
+    # own (lowered once a shape), inlined under the scopes of each call site
+    text = jax.jit(svag).lower(p, LossScaler(loss_scale=1.0).init(), x).compile().as_text()
+    kernels = [n for n in set(re.findall(r'op_name="(jit\([^"]+)"', text)) if "grouped_matmul_" in n]
+    by_name = {k: [n for n in kernels if f"/grouped_matmul_{k}" in n]
+               for k in ("fwd", "dlhs", "drhs")}
+    assert all(by_name.values()), by_name
+    for n in kernels:
+        assert re.search(r"moe\)*/moe_experts\)*/jit\(_t?gmm\)/grouped_matmul_", n), n
+    first = ("amp_backward", "amp_unscale", "ddp_reduce_gradients", "fused_adam_step_flat")
+    for k, names in by_name.items():
+        want = "amp_forward" if k == "fwd" else "amp_backward"
+        assert all(_pass_of(n) == want for n in names), (k, names)
+        assert not [n for n in names
+                    if sum(s in n for s in first) + (_pass_of(n) == "amp_forward") > 1]

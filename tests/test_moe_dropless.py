@@ -5,8 +5,14 @@ the uncut layer of the benchmark's plain reference.
 Float32 at ``highest`` matmul precision: the program sorts rows by expert and
 runs grouped matmuls, the oracle runs every expert on every token and masks, so
 the two differ by the order of a ten-term weighted sum: 1e-5 of the largest
-output (measured 1e-6)."""
+output (measured 1e-6).
 
+Every case runs on both implementations of the grouped matmul (``impl``):
+``jnp`` is ``jax.lax.ragged_dot``, ``pallas`` the kernels of
+``ops/grouped_matmul.py`` in the Pallas interpreter, which is why the widths
+are whole lane tiles."""
+
+import functools
 import os
 import sys
 
@@ -22,13 +28,18 @@ from beforeholiday_tpu.moe import dropless  # noqa: E402
 from benchmark.reference import qwen3_next as reference  # noqa: E402
 
 _TOL = 1e-5
-T, D, E, F, K = 96, 32, 16, 24, 4
+T, D, E, F, K = 96, 128, 16, 128, 4
 
 
 @pytest.fixture(autouse=True)
 def _highest():
     with jax.default_matmul_precision("highest"):
         yield
+
+
+@pytest.fixture(params=("jnp", "pallas"))
+def impl(request):
+    return request.param
 
 
 def layer_params(seed, router_skew=0.0):
@@ -82,37 +93,38 @@ def test_route_topk_renormalises_over_all_the_chosen():
 
 
 @pytest.mark.parametrize("first,held", ((0, E), (0, 4), (4, 4), (12, 4), (5, 1)))
-def test_held_experts_match_the_dense_masked_sum(first, held):
+def test_held_experts_match_the_dense_masked_sum(first, held, impl):
     p, x = layer_params(1), tokens(1)
     w, idx = dropless.route_topk(x, p["router"], K)
     got, counters = jax.jit(lambda x, w, idx, ex: dropless.dropless_experts(
-        x, w, idx, ex, first_expert=first))(x, w, idx, held_slice(p, first, held))
+        x, w, idx, ex, first_expert=first, impl=impl))(x, w, idx, held_slice(p, first, held))
     _close(got, dense_routed(x, p, first, held), f"experts {first}..{first + held}")
     rows = int(jnp.sum((idx >= first) & (idx < first + held)))
     assert int(counters["expert_rows"]) == rows and int(counters["dropped_rows"]) == 0
 
 
 @pytest.mark.parametrize("first,held", ((0, E), (4, 4)))
-def test_a_skewed_router_drops_nothing(first, held):
+def test_a_skewed_router_drops_nothing(first, held, impl):
     """One expert takes a row of nearly every token; none is dropped."""
     p, x = layer_params(2, router_skew=0.3), tokens(2, skewed=True)
     w, idx = dropless.route_topk(x, p["router"], K)
     share = float(jnp.mean(jnp.any(idx == 5, axis=-1)))
     assert share > 0.9, share
     got, counters = dropless.dropless_experts(
-        x, w, idx, held_slice(p, first, held), first_expert=first)
+        x, w, idx, held_slice(p, first, held), first_expert=first, impl=impl)
     _close(got, dense_routed(x, p, first, held), "skewed")
     assert int(counters["dropped_rows"]) == 0
     assert float(counters["expert_load_max_over_mean"]) > 1.5   # of at most 4 held experts
 
 
-def test_gradients_match_the_dense_masked_sum():
+def test_gradients_match_the_dense_masked_sum(impl):
     p, x = layer_params(3), tokens(3)
     ct = jax.random.normal(jax.random.PRNGKey(9), (T, D))
 
     def program(x, p):
         w, idx = dropless.route_topk(x, p["router"], K)
-        y, _ = dropless.dropless_experts(x, w, idx, held_slice(p, 4, 8), first_expert=4)
+        y, _ = dropless.dropless_experts(x, w, idx, held_slice(p, 4, 8), first_expert=4,
+                                         impl=impl)
         return jnp.sum(y * ct)
 
     got = jax.grad(program, argnums=(0, 1))(x, p)
@@ -122,12 +134,14 @@ def test_gradients_match_the_dense_masked_sum():
         _close(got[1][name], want[1][name], f"d{name}")
 
 
-def test_a_tight_rows_bound_counts_what_it_cuts():
+def test_a_tight_rows_bound_counts_what_it_cuts(impl):
     p, x = layer_params(4), tokens(4)
     w, idx = dropless.route_topk(x, p["router"], K)
     rows = int(jnp.sum(idx < 8))
-    _, loose = dropless.dropless_experts(x, w, idx, held_slice(p, 0, 8), rows_bound=rows)
-    _, tight = dropless.dropless_experts(x, w, idx, held_slice(p, 0, 8), rows_bound=rows - 7)
+    _, loose = dropless.dropless_experts(x, w, idx, held_slice(p, 0, 8), rows_bound=rows,
+                                         impl=impl)
+    _, tight = dropless.dropless_experts(x, w, idx, held_slice(p, 0, 8), rows_bound=rows - 7,
+                                         impl=impl)
     assert int(loose["dropped_rows"]) == 0 and int(tight["dropped_rows"]) == 7
     assert int(tight["expert_rows"]) == rows
 
@@ -138,7 +152,7 @@ def _reference_cfg(held, first):
 
 
 @pytest.mark.parametrize("shares", (16, 4, 2))
-def test_the_shares_add_up_to_the_uncut_reference_layer(shares):
+def test_the_shares_add_up_to_the_uncut_reference_layer(shares, impl):
     """Expert parallelism over ``shares`` chips: each holds E / shares experts,
     routes over all E and computes its own part; the shared expert is computed
     alike on every chip and counted once. The sum is the whole layer, as the
@@ -150,7 +164,7 @@ def test_the_shares_add_up_to_the_uncut_reference_layer(shares):
     for rank in range(shares):
         w, idx = dropless.route_topk(x, p["router"], K)
         part, _ = dropless.dropless_experts(
-            x, w, idx, held_slice(p, rank * held, held), first_expert=rank * held)
+            x, w, idx, held_slice(p, rank * held, held), first_expert=rank * held, impl=impl)
         total = total + part
     total = total + dropless.shared_expert(
         x, p["shared_w_gate"], p["shared_w_up"], p["shared_w_down"], p["shared_score"])
@@ -158,27 +172,28 @@ def test_the_shares_add_up_to_the_uncut_reference_layer(shares):
 
 
 @pytest.mark.parametrize("first,held", ((0, 4), (8, 8)))
-def test_one_share_matches_the_reference_given_the_same_share(first, held):
+def test_one_share_matches_the_reference_given_the_same_share(first, held, impl):
     p, x = layer_params(6), tokens(6)
     mine = dict(p, **held_slice(p, first, held))
-    got, _ = dropless.dropless_moe(x, mine, top_k=K, first_expert=first)
+    got, _ = dropless.dropless_moe(x, mine, top_k=K, first_expert=first, impl=impl)
     _close(got, reference.moe(x, mine, _reference_cfg(held, first), "float32"), "one share")
 
 
-def test_scopes():
+def test_scopes(impl):
     p, x = layer_params(7), tokens(7)
-    hlo = jax.jit(lambda x, p: dropless.dropless_moe(x, p, top_k=K)[0]).lower(
+    hlo = jax.jit(lambda x, p: dropless.dropless_moe(x, p, top_k=K, impl=impl)[0]).lower(
         x, p).compile().as_text()
     for scope in ("moe/moe_route", "moe/moe_dispatch", "moe/moe_experts",
                   "moe/moe_shared", "moe/moe_combine"):
         assert scope in hlo, scope
 
 
-def _poisoned_ragged_dot(real):
-    """``ragged_dot`` as the chip runs it: rows that belong to no group are
-    left uninitialised, in the result and in the cotangent of the rows operand
-    (on the CPU they come out zero, which hid a wrong dx until the chip run of
-    PR 26). Here they are set to 1e30."""
+def _poisoned(real):
+    """The grouped matmul as the chip runs it: rows that belong to no group are
+    left unspecified, in the result and in the cotangent of the rows operand
+    (``ragged_dot`` on the CPU leaves them zero, which hid a wrong dx until the
+    chip run of PR 26; the kernels leave whatever the buffer held). Here they are
+    set to 1e30."""
     def outside(group_sizes, n):
         return (jnp.arange(n) >= jnp.sum(group_sizes))[:, None]
 
@@ -201,8 +216,9 @@ def _poisoned_ragged_dot(real):
     return lambda a, w, group_sizes, **kw: poisoned(a, w, group_sizes)
 
 
-def test_rows_of_no_group_never_reach_the_result_or_its_gradients(monkeypatch):
-    monkeypatch.setattr(jax.lax, "ragged_dot", _poisoned_ragged_dot(jax.lax.ragged_dot))
+def test_rows_of_no_group_never_reach_the_result_or_its_gradients(monkeypatch, impl):
+    monkeypatch.setattr(dropless, "_grouped_matmul", _poisoned(
+        functools.partial(dropless._grouped_matmul, impl=impl)))
     p, x = layer_params(8), tokens(8)
     ct = jax.random.normal(jax.random.PRNGKey(10), (T, D))
 
@@ -224,34 +240,34 @@ _SHARED = ("shared_w_gate", "shared_w_up", "shared_w_down", "shared_score")
 
 
 @pytest.mark.parametrize("first,held", ((0, E), (4, 4), (12, 4)))
-def test_without_a_shared_expert_the_layer_is_its_routed_part(first, held):
+def test_without_a_shared_expert_the_layer_is_its_routed_part(first, held, impl):
     p, x = layer_params(8), tokens(8)
     mine = {k: v for k, v in dict(p, **held_slice(p, first, held)).items() if k not in _SHARED}
-    got, counters = dropless.dropless_moe(x, mine, top_k=K, first_expert=first)
+    got, counters = dropless.dropless_moe(x, mine, top_k=K, first_expert=first, impl=impl)
     w, idx = dropless.route_topk(x, p["router"], K)
     routed, want_counters = dropless.dropless_experts(
-        x, w, idx, held_slice(p, first, held), first_expert=first)
+        x, w, idx, held_slice(p, first, held), first_expert=first, impl=impl)
     assert got.dtype == x.dtype and bool(jnp.array_equal(got, routed.astype(x.dtype)))
     _close(got, dense_routed(x, p, first, held), "routed alone")
     assert {k: float(v) for k, v in counters.items()} == \
         {k: float(v) for k, v in want_counters.items()}
 
 
-def test_with_a_shared_expert_the_layer_is_what_it_was():
+def test_with_a_shared_expert_the_layer_is_what_it_was(impl):
     """Bit for bit: the routed part plus the gated shared expert, summed in
     float32 and cast once, as before the branch."""
     p, x = layer_params(9), tokens(9)
-    got, _ = dropless.dropless_moe(x, p, top_k=K)
+    got, _ = dropless.dropless_moe(x, p, top_k=K, impl=impl)
     w, idx = dropless.route_topk(x, p["router"], K)
-    routed, _ = dropless.dropless_experts(x, w, idx, held_slice(p, 0, E))
+    routed, _ = dropless.dropless_experts(x, w, idx, held_slice(p, 0, E), impl=impl)
     shared = dropless.shared_expert(*(p[k] if k != "x" else x for k in ("x",) + _SHARED))
     assert bool(jnp.array_equal(got, (routed + shared.astype(jnp.float32)).astype(x.dtype)))
 
 
-def test_the_shared_span_opens_only_where_there_is_a_shared_expert():
+def test_the_shared_span_opens_only_where_there_is_a_shared_expert(impl):
     p, x = layer_params(7), tokens(7)
     bare = {k: v for k, v in p.items() if k not in _SHARED}
-    hlo = jax.jit(lambda x, p: dropless.dropless_moe(x, p, top_k=K)[0]).lower(
+    hlo = jax.jit(lambda x, p: dropless.dropless_moe(x, p, top_k=K, impl=impl)[0]).lower(
         x, bare).compile().as_text()
     for scope in ("moe/moe_route", "moe/moe_dispatch", "moe/moe_experts", "moe/moe_combine"):
         assert scope in hlo, scope

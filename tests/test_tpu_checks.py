@@ -52,3 +52,19 @@ def test_rung_decorator_registers():
         assert tpu_checks.RUNGS["_probe_rung"] is _probe_rung
     finally:
         del tpu_checks.RUNGS["_probe_rung"]
+
+
+def test_the_grouped_matmul_check_runs_its_comparison(monkeypatch):
+    """The chip check at a toy shape on the interpreter: every output and
+    cotangent is compared (the timings mean nothing here and are not judged)."""
+    import jax.numpy as jnp
+
+    monkeypatch.setattr(tpu_checks, "_GROUPED_SHAPES",
+                        (("toy", 512, 4, 128, 256, 300, jnp.float32),))
+    results = []
+    tpu_checks.check_grouped_matmul(results)
+    by_name = {name: (ok, info) for name, ok, info in results}
+    assert set(by_name) == {f"grouped_matmul/toy/{k}" for k in
+                            ("fwd", "dlhs", "drhs", "ms_a_product")}
+    for k in ("fwd", "dlhs", "drhs"):
+        assert by_name[f"grouped_matmul/toy/{k}"][0], by_name

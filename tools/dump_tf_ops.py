@@ -2,7 +2,9 @@
 """Run one benchmark cell traced and write what its per-layer metrics are read
 from: every distinct framework name (``tf_op``: the ``monitor.spans`` scope
 path) of chip 0's device ops with its self time, and every distinct HLO name
-stem (``%flash_attention``, ``%fusion``, ...) with its self time.
+stem (``%flash_attention``, ``%fusion``, ...) with its self time; beside them
+what the run dispatched (``monitor.dispatch_summary()``: ``pallas`` / ``jnp`` by
+guarded op) and the tile plan of every traced kernel (``monitor.tile_records()``).
 
     python tools/dump_tf_ops.py --workload <cell> --seed <n> --out chiprun_out/<cell>.json
 
@@ -30,6 +32,7 @@ def main(argv=None):
 
     import jax
 
+    from beforeholiday_tpu import monitor
     from benchmark import run, trace_reduce
 
     kept, load = {}, trace_reduce.load
@@ -55,7 +58,8 @@ def main(argv=None):
            "from": "tools/dump_tf_ops.py on the chip: chip 0, every distinct tf_op of the "
                    "XLA Ops line with its self time in ps; hlo_names: the same by HLO name stem",
            "busy_ps": trace_reduce.length(t.busy(0)), "window_ps": end - start,
-           "ops": sorted(by_scope.items()), "hlo_names": sorted(by_name.items())}
+           "ops": sorted(by_scope.items()), "hlo_names": sorted(by_name.items()),
+           "dispatch": monitor.dispatch_summary(), "tiles": monitor.tile_records()}
     os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
     with open(args.out, "w") as f:
         json.dump(out, f)
