@@ -1,5 +1,6 @@
-"""What the decoder models share: rotary tables, cross entropy, the layer
-stack's plumbing and the reduction of the MoE counters over layers."""
+"""What the decoder models share: rotary tables, the causal depthwise
+convolution of the recurrent mixers, cross entropy, the layer stack's plumbing
+and the reduction of the MoE counters over layers."""
 
 from __future__ import annotations
 
@@ -71,6 +72,16 @@ def apply_rotary(x, cos, sin):
     if rotary_dim == x.shape[-1]:
         return rotated.astype(x.dtype)
     return jnp.concatenate([rotated.astype(x.dtype), x[..., rotary_dim:]], axis=-1)
+
+
+def causal_depthwise_conv(x, w):
+    """``y[t, c] = sum_j w[c, j] * x[t - (K-1) + j, c]``, zeros before the
+    start. ``x``: ``(B, S, C)``, ``w``: ``(C, K)``."""
+    K, S = w.shape[-1], x.shape[1]
+    xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+    w = w.astype(_F32)
+    y = sum(xp[:, j:j + S].astype(_F32) * w[:, j] for j in range(K))
+    return y.astype(x.dtype)
 
 
 def cross_entropy(logits, targets):

@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 
 from beforeholiday_tpu.models import layers as _layers
+from beforeholiday_tpu.models.layers import causal_depthwise_conv
 from beforeholiday_tpu.models.layers import COUNTERS  # noqa: F401  (the step's counters)
 from beforeholiday_tpu.monitor.spans import annotate as _annotate, span as _span
 from beforeholiday_tpu.remat import apply as _remat_apply
@@ -197,16 +198,6 @@ def rope_partial(x, rotary_dim: int, theta: float):
     (``rotate_half`` layout: dim ``i`` pairs with ``i + rotary_dim / 2``), the
     rest passed through. ``x``: ``(B, S, H, hd)``; positions ``0 .. S-1``."""
     return _layers.apply_rotary(x, *_layers.rotary_table(x.shape[1], rotary_dim, theta))
-
-
-def causal_depthwise_conv(x, w):
-    """``y[t, c] = sum_j w[c, j] * x[t - (K-1) + j, c]``, zeros before the
-    start. ``x``: ``(B, S, C)``, ``w``: ``(C, K)``."""
-    K, S = w.shape[-1], x.shape[1]
-    xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
-    w = w.astype(_F32)
-    y = sum(xp[:, j:j + S].astype(_F32) * w[:, j] for j in range(K))
-    return y.astype(x.dtype)
 
 
 def _l2_normalize(x, eps=1e-6):

@@ -12,6 +12,10 @@
 * ``attention`` — Pallas flash attention (``fmhalib``, ``fast_multihead_attn``).
 * ``gated_delta`` — the gated delta rule of Gated DeltaNet linear attention,
   chunk-wise, with a Pallas chunk scan (no reference equivalent).
+* ``ssd`` (``ops.state_space_dual`` is ``ops.ssd.ssd``) — the state-space dual
+  of a Mamba-2 mixer, chunk-wise: ``B`` and ``C`` shared by a group's heads, a
+  decay a head, the state carried across chunks in VMEM by Pallas kernels
+  forward and backward (no reference equivalent).
 * ``grouped_matmul`` — rows sorted by expert times each expert's weight panel,
   the panel held in VMEM while its row tiles go by (Pallas; ``jax.lax.ragged_dot``
   off the kernels' shapes): the experts of ``moe/dropless.py``.
@@ -64,6 +68,7 @@ from .attention import (  # noqa: F401
     self_attention,
 )
 from .gated_delta import gated_delta_rule  # noqa: F401
+from .ssd import ssd as state_space_dual  # noqa: F401  (``ops.ssd`` stays the module)
 from .quantized import (  # noqa: F401
     quantized_matmul,
     quantized_matmul_error_bound,

@@ -360,6 +360,58 @@ def check_grouped_matmul(results: list) -> None:
               json.dumps({n: round(t, 3) for n, t in ms.items()}))
 
 
+# batch, tokens, heads, head dim, groups, state: one Mamba-2 block of the Nemotron cell
+_SSD_SHAPE = (1, 8192, 16, 64, 1, 128)
+
+
+def check_ssd(results: list) -> None:
+    """The state-space recurrence in its three kernels (``ops.ssd``: ``ssd_fwd``,
+    ``ssd_bwd_states``, ``ssd_bwd``), compiled, at the Nemotron cell's shape (one
+    block's 16 heads of 64 in one group, state 128, 8192 tokens in chunks of 128):
+    the output and every cotangent against the ``jnp`` chunk scan on the same
+    bfloat16 operands, and the ms a block takes forward and backward beside it.
+    Interpret mode cannot see what Mosaic makes of the lane selects between the
+    two heads of a unit or of the accumulators held across the units of a chunk."""
+    from beforeholiday_tpu.ops.ssd import ssd
+
+    def check(name, cond, info=""):
+        results.append((f"ssd/{name}", bool(cond), str(info)))
+
+    B, S, H, P, G, N = _SSD_SHAPE
+    ks = jax.random.split(jax.random.PRNGKey(33), 7)
+    bf = jnp.bfloat16
+    x = jax.random.normal(ks[0], (B, S, H, P), jnp.float32).astype(bf)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H), jnp.float32) - 3.0)
+    A = -jax.random.uniform(ks[2], (H,), jnp.float32, 1.0, 16.0)
+    Bm = (jax.random.normal(ks[3], (B, S, G, N), jnp.float32) * 0.3).astype(bf)
+    Cm = (jax.random.normal(ks[4], (B, S, G, N), jnp.float32) * 0.3).astype(bf)
+    D = jax.random.normal(ks[5], (H,), jnp.float32)
+    ct = jax.random.normal(ks[6], (B, S, H, P), jnp.float32).astype(bf)
+    args = (x, dt, A, Bm, Cm, D)
+
+    def runs(impl):
+        op = lambda *a: ssd(*a, impl=impl)
+        return {"fwd": jax.jit(op),
+                "bwd": jax.jit(lambda *a: jax.vjp(op, *a[:-1])[1](a[-1]))}
+
+    fns = {"pallas": runs("pallas"), "jnp": runs("jnp")}
+    got, want = (fns[impl]["fwd"](*args).astype(jnp.float32) for impl in fns)
+    gap, scale = float(jnp.max(jnp.abs(got - want))), float(jnp.max(jnp.abs(want)))
+    check("fwd", bool(jnp.all(jnp.isfinite(got))) and gap <= 2e-2 * scale,
+          f"max|d|={gap:.3e} of {scale:.3e}")
+    cts = {impl: fns[impl]["bwd"](*args, ct) for impl in fns}
+    for name, g, w in zip(("dx", "ddt", "dA", "dB", "dC", "dD"), cts["pallas"], cts["jnp"]):
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
+        gap, scale = float(jnp.max(jnp.abs(g - w))), float(jnp.max(jnp.abs(w)))
+        check(name, bool(jnp.all(jnp.isfinite(g))) and gap <= 3e-2 * scale,
+              f"max|d|={gap:.3e} of {scale:.3e}")
+    ms = {f"{impl}_{k}": 1e3 * _min_step_seconds(
+        lambda _: fns[impl][k](*(args + ((ct,) if k == "bwd" else ()))), None)
+        for impl in fns for k in ("fwd", "bwd")}
+    check("ms_a_block", all(ms[f"pallas_{k}"] < ms[f"jnp_{k}"] for k in ("fwd", "bwd")),
+          json.dumps({n: round(t, 3) for n, t in ms.items()}))
+
+
 def check_aliased_mt_kernels(results: list) -> None:
     """The Pallas multi-tensor kernels run with input_output_aliases on the
     compiled path (in-place updates, ~1.8x streaming win) — aliasing bugs
@@ -783,7 +835,7 @@ def main() -> int:
     enable_compile_cache()
     results: list = []
     for group in (check_flash_dropout, check_flash_tiles, check_wy_prepare,
-                  check_grouped_matmul, check_aliased_mt_kernels,
+                  check_grouped_matmul, check_ssd, check_aliased_mt_kernels,
                   check_compiled_kernel_parity):
         try:
             group(results)

@@ -44,7 +44,10 @@ def compiled_not_interpreted(monkeypatch):
     (16384, 32, 2048, 512, jnp.float32),        # the Qwen cell, gate / up
     (16384, 32, 512, 2048, jnp.bfloat16),       # ... down
     (1000, 4, 128, 256, jnp.float32),           # a buffer that ends inside a row tile
-), ids=("mellum_up", "mellum_down", "qwen_up", "qwen_down", "ragged_buffer"))
+    (8192, 8, 1024, 2688, jnp.float32),         # the Nemotron cell, up (in the latent)
+    (8192, 8, 2688, 1024, jnp.bfloat16),        # ... down
+), ids=("mellum_up", "mellum_down", "qwen_up", "qwen_down", "ragged_buffer", "nemotron_up",
+        "nemotron_down"))
 def test_the_grouped_matmul_kernels_compile_for_the_chip(one_chip, compiled_not_interpreted,
                                                          R, E, K, N, out):
     gm = compiled_not_interpreted
@@ -62,3 +65,33 @@ def test_the_grouped_matmul_kernels_compile_for_the_chip(one_chip, compiled_not_
     assert text.count("tpu_custom_call") == 3
     for kernel in ("fwd", "dlhs", "drhs"):
         assert f"grouped_matmul_{kernel}" in text
+
+
+@pytest.mark.parametrize("batch,S,H,P,G,N", (
+    (1, 8192, 16, 64, 1, 128),                  # the Nemotron cell: a rank's 16 heads, one group
+    (2, 1024, 8, 64, 2, 128),                   # two groups, two sequences
+    (1, 1000, 2, 128, 1, 128),                  # a head a unit, a sequence that is padded
+), ids=("nemotron_block", "two_groups", "wide_heads_ragged"))
+def test_the_ssd_kernels_compile_for_the_chip(one_chip, monkeypatch, batch, S, H, P, G, N):
+    from beforeholiday_tpu.ops import ssd as ssd_mod
+
+    monkeypatch.setattr(ssd_mod, "_interpret_default", lambda: False)
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    bf, f32 = jnp.bfloat16, jnp.float32
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def both(x, dt, A, B, C, D, ct):
+        y, pull = jax.vjp(lambda *a: ssd_mod.ssd(*a, impl="pallas"), x, dt, A, B, C, D)
+        return y, pull(ct)
+
+    try:
+        text = jax.jit(both).lower(
+            shape((batch, S, H, P), bf), shape((batch, S, H), f32), shape((H,), f32),
+            shape((batch, S, G, N), bf), shape((batch, S, G, N), bf), shape((H,), f32),
+            shape((batch, S, H, P), bf)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+    assert text.count("tpu_custom_call") == 3
+    for kernel in ("ssd_fwd", "ssd_bwd_states", "ssd_bwd"):
+        assert kernel in text

@@ -394,3 +394,121 @@ def test_the_grouped_matmul_kernels_lie_under_the_experts_scope_in_both_passes()
         assert all(_pass_of(n) == want for n in names), (k, names)
         assert not [n for n in names
                     if sum(s in n for s in first) + (_pass_of(n) == "amp_forward") > 1]
+
+
+# ---------------------------------------------------------------------------
+# family nemotron_h (PR 33): ssm_mixer / attn_mixer, ssd, moe_latent and the
+# model's four scopes
+# ---------------------------------------------------------------------------
+
+_NEMOTRON_MODEL = ("nemotron_h_embed", "nemotron_h_layers", "nemotron_h_head", "nemotron_h_loss")
+_NEMOTRON_SCOPES = _NEMOTRON_MODEL + (
+    "ssm_mixer", "attn_mixer", "ssd", "amp_forward", "amp_backward", "amp_unscale",
+    "fused_adam_step_flat", "layer_norm", "flash_attention", "moe_route", "moe_latent",
+    "moe_dispatch", "moe_experts", "moe_shared", "moe_combine")
+
+
+@pytest.fixture(scope="module")
+def nemotron_run():
+    from benchmark import run as bench_run
+
+    cell = bench_run.load("workloads", "tiny-nemotron-h.train")
+    run = bench_run.Cell(cell, bench_run.load("configs", cell["config"]), jax.devices()[:1])
+    run.start(7)
+    run.build()
+    return run
+
+
+@pytest.fixture(scope="module")
+def nemotron_names(nemotron_run):
+    """The distinct ``op_name`` of every op of the compiled tiny-nemotron-h step."""
+    run = nemotron_run
+    compiled = run.program.step.jitted.lower(run.state, run.pool[0]).compile()
+    return sorted(set(re.findall(r'op_name="(jit\([^"]+)"', compiled.as_text())))
+
+
+@pytest.mark.parametrize("scope", _NEMOTRON_SCOPES)
+def test_nemotron_scope_is_in_the_compiled_step(nemotron_names, scope):
+    assert any(scope in _scopes_of(n) or f"jvp({scope})" in n for n in nemotron_names), scope
+
+
+def test_nemotron_first_level_scopes_partition_the_step(nemotron_names):
+    first = ("amp_backward", "amp_unscale", "ddp_reduce_gradients",
+             "ddp_overlap_hook", "fused_adam_step_flat")
+    twice = [n for n in nemotron_names
+             if sum(s in n for s in first) + (_pass_of(n) == "amp_forward") > 1]
+    assert not twice
+    both = [n for n in nemotron_names if "amp_forward" in n and "amp_backward" in n]
+    assert all("amp_backward/transpose(amp_forward)" in n for n in both), both
+    for scope in _NEMOTRON_MODEL:    # the model's scopes survive inside both passes
+        assert any(f"amp_forward/jvp({scope})" in n for n in nemotron_names), scope
+        assert any(_pass_of(n) == "amp_backward" and f"jvp({scope})" in n
+                   for n in nemotron_names), scope
+
+
+def test_nemotron_second_level_scopes_do_not_overlap(nemotron_names):
+    """An op is under one model scope at most, and under one of the two mixers
+    or the MoE at most (a block is one of the three); ``ssd`` lies inside
+    ``ssm_mixer``, ``moe_latent`` inside ``moe``, all inside ``nemotron_h_layers``."""
+    for n in nemotron_names:
+        assert sum(f"({s})" in n or s in _scopes_of(n) for s in _NEMOTRON_MODEL) <= 1, n
+        parts = [s for s in ("ssm_mixer", "attn_mixer", "moe") if s in _scopes_of(n)]
+        assert len(parts) <= 1, n
+        if parts and _pass_of(n):
+            assert "nemotron_h_layers" in n, n
+        if "ssd" in _scopes_of(n):
+            assert "ssm_mixer" in _scopes_of(n), n
+        if "moe_latent" in _scopes_of(n):
+            assert "moe" in _scopes_of(n), n
+        if "flash_attention" in _scopes_of(n):
+            assert "attn_mixer" in _scopes_of(n), n
+    heavy = [n for n in nemotron_names if n.endswith("dot_general")]
+    assert heavy and not [n for n in heavy if _pass_of(n) is None]
+
+
+def test_nemotron_step_counts_its_routed_rows(nemotron_run):
+    """``expert_rows_per_step.nemotron_h`` and ``expert_load_max_over_mean.nemotron_h``
+    read the family's counters; a step of the fixture routes 2 MoE blocks x 96
+    tokens x 4 choices, a quarter of the experts held (nothing dropped: the
+    bound is the worst case)."""
+    from benchmark.families import nemotron_h as family
+
+    run = nemotron_run
+    before = family.counters()
+    run.run_step(0)
+    run.run_step(1)
+    seen = family.counters()
+    assert set(seen) == {"expert_rows", "expert_load_max_over_mean", "dropped_rows", "steps"}
+    assert seen["steps"] - before.get("steps", 0.0) == 2.0 and seen["dropped_rows"] == 0.0
+    assert 0 < seen["expert_rows"] / seen["steps"] <= 2 * 96 * 4
+    assert 1.0 <= seen["expert_load_max_over_mean"] <= 4.0
+
+
+def test_the_ssd_kernels_carry_their_names_under_the_mixer_in_both_passes():
+    """``ssd_ms`` reads the ``/ssd/`` scope, ``ssd_roofline`` the kernels' own
+    ``name=`` (the chip prints ``%ssd_fwd.N``, ``%ssd_bwd_states.N``, ``%ssd_bwd.N``):
+    the forward kernel lies under ``amp_forward``, the sweep for the chunk-start
+    states and the reverse walk under ``amp_backward``."""
+    from beforeholiday_tpu.models import nemotron_h
+
+    cfg = nemotron_h.NemotronHConfig(
+        mamba_num_heads=2, mamba_head_dim=64, n_groups=1, ssm_state_size=128, ssd_impl="pallas",
+        dtype=jnp.bfloat16)
+    params = nemotron_h.init(jax.random.PRNGKey(0), cfg)
+    p = {k: v[0].astype(jnp.bfloat16) for k, v in params["mamba"].items()}
+    x = jnp.zeros((1, 256, cfg.hidden_size), jnp.bfloat16)
+    svag = amp.scaled_value_and_grad(
+        lambda p, x: jnp.sum(nemotron_h.mamba2_mixer(cfg, x, p).astype(jnp.float32)),
+        LossScaler(loss_scale=1.0))
+    # the compiled program's names: a kernel call is a ``jax.jit`` function of its own
+    # (lowered once a shape), inlined under the scopes of each call site; the
+    # ``pallas_call``'s ``name=`` is one more scope around what the interpreter makes of it
+    text = jax.jit(svag).lower(p, LossScaler(loss_scale=1.0).init(), x).compile().as_text()
+    names = set(re.findall(r'op_name="(jit\([^"]+)"', text))
+    kernels = {k: [n for n in names if f"/{k}/" in n] for k in ("ssd_fwd", "ssd_bwd_states", "ssd_bwd")}
+    assert all(kernels.values()), {k: len(v) for k, v in kernels.items()}
+    for k, found in kernels.items():
+        want = "amp_forward" if k == "ssd_fwd" else "amp_backward"
+        for n in found:
+            assert _pass_of(n) == want, (k, n)
+            assert re.search(rf"ssm_mixer\)*/ssd\)*/jit\(_(?:fwd|bwd)_pallas\)/{k}/", n), n

@@ -272,3 +272,164 @@ def test_the_shared_span_opens_only_where_there_is_a_shared_expert(impl):
     for scope in ("moe/moe_route", "moe/moe_dispatch", "moe/moe_experts", "moe/moe_combine"):
         assert scope in hlo, scope
     assert "moe_shared" not in hlo
+
+
+# -- the second router, the second expert form, the latent (PR 33) -----------------
+
+DL = 128                 # the latent's width
+K_MANY = 6               # more choices than a narrow share holds experts
+
+
+def latent_params(seed):
+    """A LatentMoE part: two-matrix experts in a ``DL``-wide latent, an ungated
+    shared expert on the full width."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+    n = lambda k, *shape: jax.random.normal(k, shape) * 0.2
+    return {"router": n(ks[0], D, E), "fc1_latent": n(ks[1], D, DL), "fc2_latent": n(ks[2], DL, D),
+            "w_up": n(ks[3], E, DL, F), "w_down": n(ks[4], E, F, DL),
+            "shared_w_up": n(ks[5], D, F), "shared_w_down": n(ks[6], F, D)}
+
+
+def dense_sigmoid_weights(x, router, k, bias, scale, renormalize=True):
+    """``(T, E)``: each expert's weight by the formula, zero where it was not
+    chosen; the choice by a full argsort of score + bias."""
+    scores = jax.nn.sigmoid(x @ router)
+    ranked = jnp.argsort(-(scores + (0.0 if bias is None else bias)), axis=-1)[:, :k]
+    chosen = jnp.sum(jax.nn.one_hot(ranked, router.shape[1]), axis=1) > 0
+    picked = jnp.where(chosen, scores, 0.0)
+    if renormalize:
+        picked = picked / (jnp.sum(picked, -1, keepdims=True) + 1e-20)
+    return picked * scale
+
+
+def dense_relu2_routed(x, gates, p, first, held):
+    out = jnp.zeros((x.shape[0], p["w_down"].shape[-1]))
+    for e in range(first, first + held):
+        out = out + gates[:, e:e + 1] * dropless.relu2_mlp(x, p["w_up"][e], p["w_down"][e])
+    return out
+
+
+def scattered(w, idx):
+    """``(T, k)`` weights and ids as ``(T, E)``."""
+    return jnp.sum(jax.nn.one_hot(idx, E) * w[..., None], axis=1)
+
+
+@pytest.mark.parametrize("bias_seed,scale,renormalize", ((None, 1.0, True), (3, 5.0, True),
+                                                         (3, 2.5, False)))
+def test_route_sigmoid_is_the_dense_formula(bias_seed, scale, renormalize):
+    p, x = layer_params(10), tokens(10)
+    bias = None if bias_seed is None else jax.random.normal(jax.random.PRNGKey(bias_seed), (E,))
+    w, idx = dropless.route_sigmoid(x, p["router"], K, bias=bias, scale=scale,
+                                    renormalize=renormalize)
+    assert w.shape == idx.shape == (T, K) and idx.dtype == jnp.int32 and w.dtype == jnp.float32
+    _close(scattered(w, idx), dense_sigmoid_weights(x, p["router"], K, bias, scale, renormalize),
+           "weights")
+    if renormalize:
+        _close(jnp.sum(w, -1), jnp.full((T,), scale), "the chosen sum to the scale")
+
+
+def test_the_bias_moves_the_choice_and_never_the_weights_or_the_gradient():
+    p, x = layer_params(11), tokens(11)
+    bias = jnp.zeros((E,)).at[3].set(10.0)          # expert 3 is now everyone's first choice
+    w, idx = dropless.route_sigmoid(x, p["router"], K, bias=bias, renormalize=False)
+    assert bool(jnp.all(jnp.any(idx == 3, axis=-1)))
+    scores = jax.nn.sigmoid(x @ p["router"])
+    _close(w, jnp.take_along_axis(scores, idx, axis=-1), "the weights are plain scores")
+    assert float(jnp.max(w)) <= 1.0
+    grad = jax.grad(lambda b: jnp.sum(dropless.route_sigmoid(x, p["router"], K, bias=b)[0]))(bias)
+    assert float(jnp.max(jnp.abs(grad))) == 0.0
+    got = jax.grad(lambda r: jnp.sum(dropless.route_sigmoid(x, r, K, bias=bias, scale=5.0)[0] ** 2))(
+        p["router"])
+    want = jax.grad(lambda r: jnp.sum(dense_sigmoid_weights(x, r, K, bias, 5.0) ** 2))(p["router"])
+    _close(got, want, "d router")
+
+
+@pytest.mark.parametrize("first,held,k", ((0, E, K), (4, 4, K), (12, 4, K_MANY), (5, 1, K_MANY),
+                                          (0, 2, K_MANY)))
+def test_two_matrix_relu2_experts_match_the_dense_masked_sum(first, held, k, impl):
+    """Also where a token has more choices than the share holds experts
+    (``k > held``): the held choices are compacted a token before the sort."""
+    p, x = latent_params(12), tokens(12)[:, :DL]
+    w, idx = dropless.route_sigmoid(tokens(12), p["router"], k, scale=5.0)
+    experts = {n: p[n][first:first + held] for n in ("w_up", "w_down")}
+    got, counters = jax.jit(lambda x, w, idx, ex: dropless.dropless_experts(
+        x, w, idx, ex, first_expert=first, impl=impl))(x, w, idx, experts)
+    _close(got, dense_relu2_routed(x, scattered(w, idx), p, first, held), "relu2 experts")
+    rows = int(jnp.sum((idx >= first) & (idx < first + held)))
+    assert int(counters["expert_rows"]) == rows and int(counters["dropped_rows"]) == 0
+
+
+def test_relu2_experts_gradients_where_choices_outnumber_the_held(impl):
+    p, x = latent_params(13), tokens(13)[:, :DL]
+    ct = jax.random.normal(jax.random.PRNGKey(14), (T, DL))
+    first, held = 8, 4
+
+    def program(x, p):
+        w, idx = dropless.route_sigmoid(tokens(13), p["router"], K_MANY, scale=5.0)
+        y, _ = dropless.dropless_experts(
+            x, w, idx, {n: p[n][first:first + held] for n in ("w_up", "w_down")},
+            first_expert=first, impl=impl)
+        return jnp.sum(y * ct)
+
+    def dense(x, p):
+        gates = dense_sigmoid_weights(tokens(13), p["router"], K_MANY, None, 5.0)
+        return jnp.sum(dense_relu2_routed(x, gates, p, first, held) * ct)
+
+    got, want = (jax.grad(f, argnums=(0, 1))(x, p) for f in (program, dense))
+    _close(got[0], want[0], "dx")
+    for name in ("router", "w_up", "w_down"):
+        _close(got[1][name], want[1][name], f"d{name}")
+
+
+def test_a_tight_bound_counts_what_it_cuts_where_choices_outnumber_the_held(impl):
+    p, x = latent_params(15), tokens(15)[:, :DL]
+    w, idx = dropless.route_sigmoid(tokens(15), p["router"], K_MANY)
+    experts = {n: p[n][:4] for n in ("w_up", "w_down")}
+    rows = int(jnp.sum(idx < 4))
+    _, counters = dropless.dropless_experts(x, w, idx, experts, rows_bound=rows - 7, impl=impl)
+    assert int(counters["expert_rows"]) == rows and int(counters["dropped_rows"]) == 7
+
+
+@pytest.mark.parametrize("renormalize", (True, False))
+@pytest.mark.parametrize("first,held", ((0, E), (4, 4), (0, 2)))
+def test_the_latent_layer_is_its_formula(first, held, renormalize, impl):
+    """``fc2(sum_k w_k relu2_k(fc1 x)) + relu2_shared(x)``, the router and the
+    shared expert on the full width; ``renormalize=`` reaches whichever router
+    the layer is given."""
+    p, x = latent_params(16), tokens(16)
+    route = functools.partial(dropless.route_sigmoid, scale=5.0)
+    mine = dict(p, w_up=p["w_up"][first:first + held], w_down=p["w_down"][first:first + held])
+    got, counters = dropless.dropless_moe(x, mine, top_k=K_MANY, first_expert=first,
+                                          renormalize=renormalize, route=route, impl=impl)
+    gates = dense_sigmoid_weights(x, p["router"], K_MANY, None, 5.0, renormalize)
+    want = dense_relu2_routed(x @ p["fc1_latent"], gates, p, first, held) @ p["fc2_latent"] \
+        + dropless.relu2_mlp(x, p["shared_w_up"], p["shared_w_down"])
+    _close(got, want, "latent layer")
+    assert got.shape == x.shape and int(counters["dropped_rows"]) == 0
+
+
+def test_the_latent_span_opens_only_where_there_is_a_latent(impl):
+    p, x = latent_params(17), tokens(17)
+    lowered = lambda p: jax.jit(lambda x, p: dropless.dropless_moe(
+        x, p, top_k=K, route=dropless.route_sigmoid, impl=impl)[0]).lower(x, p).compile().as_text()
+    hlo = lowered(p)
+    for scope in ("moe/moe_route", "moe/moe_latent", "moe/moe_dispatch", "moe/moe_experts",
+                  "moe/moe_shared", "moe/moe_combine"):
+        assert scope in hlo, scope
+    q = layer_params(17)
+    assert "moe_latent" not in jax.jit(lambda x, p: dropless.dropless_moe(
+        x, p, top_k=K, impl=impl)[0]).lower(x, q).compile().as_text()
+
+
+def test_swiglu_softmax_layers_trace_what_they_traced(impl):
+    """No op of the new forms enters the jaxpr of a SwiGLU / softmax layer: one
+    ``top_k`` (the router's: the compaction of ``k > held`` would be a second),
+    no square of a relu, no sigmoid but two silus and the shared score (PR 33)."""
+    p, x = layer_params(18), tokens(18)
+    trace = lambda p: str(jax.make_jaxpr(
+        lambda x, p: dropless.dropless_moe(x, p, top_k=K, impl=impl))(x, p))
+    text = trace(p)
+    assert text.count("top_k") == 1 and "square" not in text and "integer_pow" not in text
+    assert text.count("logistic") == 3        # silu of the experts and the shared one, the score
+    bare = trace({k: v for k, v in p.items() if k not in _SHARED})
+    assert bare.count("top_k") == 1 and bare.count("logistic") == 1 and "square" not in bare

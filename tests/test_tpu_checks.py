@@ -68,3 +68,22 @@ def test_the_grouped_matmul_check_runs_its_comparison(monkeypatch):
                             ("fwd", "dlhs", "drhs", "ms_a_product")}
     for k in ("fwd", "dlhs", "drhs"):
         assert by_name[f"grouped_matmul/toy/{k}"][0], by_name
+
+
+def test_the_ssd_check_runs_its_comparison(monkeypatch):
+    """The chip check of ``ops.ssd`` at a toy shape on the interpreter: the
+    output and every cotangent are compared (the timings are not judged here)."""
+    monkeypatch.setattr(tpu_checks, "_SSD_SHAPE", (1, 256, 2, 64, 1, 128))
+    results = []
+    tpu_checks.check_ssd(results)
+    by_name = {name: (ok, info) for name, ok, info in results}
+    compared = ("fwd", "dx", "ddt", "dA", "dB", "dC", "dD")
+    assert set(by_name) == {f"ssd/{k}" for k in compared + ("ms_a_block",)}
+    for k in compared:
+        assert by_name[f"ssd/{k}"][0], by_name
+
+
+def test_the_ssd_check_is_one_of_the_groups_main_runs():
+    import inspect
+
+    assert "check_ssd" in inspect.getsource(tpu_checks.main)
