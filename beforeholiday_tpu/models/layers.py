@@ -97,6 +97,21 @@ def by_period(tree, periods: int, n: int):
     return jax.tree.map(lambda a: a.reshape(periods, n, *a.shape[1:]), tree)
 
 
+def scan_periods(period, x, stacked):
+    """``lax.scan(period, x, stacked)`` over leaves stacked ``(periods, ...)``;
+    a stack of ONE period is the body called once. A loop of one trip is still
+    a loop to the compiler until late: its residuals cross the boundary stacked
+    ``(1, ...)`` and are re-laid out on the other side, and what is nested in
+    it is compiled as a loop's body. With the MoE layer's own loops
+    (``moe.dropless.gather_rows``) inside, the one-period Qwen3-Next step held
+    0.96 GiB more temporaries by the chip compiler's count than without the
+    scan (PERF.md, PR 34)."""
+    if jax.tree.leaves(stacked)[0].shape[0] != 1:
+        return jax.lax.scan(period, x, stacked)
+    x, seen = period(x, jax.tree.map(lambda a: a[0], stacked))
+    return x, jax.tree.map(lambda a: a[None], seen)
+
+
 def unstack(tree, n: int):
     """The ``n`` members of leaves stacked on their leading axis. ``lax.split``:
     its gradient is one concatenation; that of ``a[i]`` is a zero-padded copy

@@ -235,7 +235,7 @@ def forward(params: dict, tokens: jax.Array, cfg: MellumConfig):
         return x, jax.tree.map(lambda *v: jnp.stack(v), *seen)
 
     with _span("mellum_layers"):
-        x, seen = jax.lax.scan(
+        x, seen = _layers.scan_periods(
             period, x, _layers.by_period(params["layers"], cfg.periods, len(kinds)))
     counters = _layers.reduce_counters(seen)
     with _span("mellum_head"):

@@ -322,7 +322,7 @@ def forward(params: dict, tokens: jax.Array, cfg: NemotronHConfig):
         return x, jax.tree.map(lambda *v: jnp.stack(v), *seen) if seen else None
 
     with _span("nemotron_h_layers"):
-        x, seen = jax.lax.scan(period, x, {
+        x, seen = _layers.scan_periods(period, x, {
             g: _layers.by_period(params[g], periods, n) for g, n in per.items()})
     counters = (_layers.reduce_counters(seen) if seen is not None
                 else {k: jnp.zeros((), _F32) for k in COUNTERS})

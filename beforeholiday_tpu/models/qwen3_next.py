@@ -301,7 +301,7 @@ def forward(params: dict, tokens: jax.Array, cfg: Qwen3NextConfig):
         return x, jax.tree.map(lambda *v: jnp.stack(v), *seen)
 
     with _span("qwen3n_layers"):
-        x, seen = jax.lax.scan(period, x, (
+        x, seen = _layers.scan_periods(period, x, (
             by_period(params["layers"], per), by_period(params["linear"], per - 1),
             params["attn"]))
     counters = _layers.reduce_counters(seen)

@@ -24,9 +24,17 @@ here stands in for it.
 
 **Static shapes.** The sorted-rows buffer has ``rows_bound`` rows. ``None``
 sizes it for the worst case (every token sending ``min(top_k, held)`` choices
-here), which can never overflow. A tighter bound saves memory in proportion;
-assignments beyond it are *counted* (``dropped_rows``) so that the caller can
-fail the step: they are never silently lost.
+here), which can never overflow; assignments beyond a tighter bound are
+*counted* (``dropped_rows``) so that the caller can fail the step: they are
+never silently lost. **The buffer's empty tail is not walked**: rows reach the
+buffer and leave it through :func:`gather_rows` and :func:`scatter_add_rows`,
+loops over row tiles whose trip count is the rows that landed (a device scalar),
+where XLA's static gather and scatter-add move every row of the buffer. What a
+tighter bound still saves is memory, in proportion, and the experts' elementwise
+work (``silu``, the products, the converts) over the empty rows. A buffer that
+is full (every expert held and no bound: every choice lands) walks every tile
+and pays the loop on top: 25 % more than the static ops at 16,384 x 2,048, 40 %
+at 8,192 x 1,024, nothing at 24,576 x 2,304 (:func:`_row_tile`; PERF.md, PR 34).
 
 **Two expert forms, two routers.** The form is a property of the parameters:
 with a ``w_gate`` an expert is SwiGLU's three matrices, ``(silu(x W_g) * x W_u)
@@ -45,20 +53,25 @@ expert's rows over the mean), ``dropped_rows``.
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
+from beforeholiday_tpu.monitor.counters import book_tiles as _book_tiles
 from beforeholiday_tpu.monitor.spans import span as _span
 from beforeholiday_tpu.ops.grouped_matmul import grouped_matmul as _grouped_matmul
 
 __all__ = [
     "dropless_experts",
     "dropless_moe",
+    "gather_rows",
     "relu2_mlp",
     "route_sigmoid",
     "route_topk",
+    "scatter_add_rows",
     "shared_expert",
     "swiglu",
 ]
@@ -122,6 +135,210 @@ def shared_expert(x, w_gate, w_up, w_down, w_score):
     return (jax.nn.sigmoid(score) * swiglu(x, w_gate, w_up, w_down)).astype(x.dtype)
 
 
+# -- the sort's two sides: rows moved tile by tile, as far as rows landed ------------
+
+def _row_tile(D: int) -> int:
+    """Rows one trip of the loops below moves, from what the code sees.
+
+    A trip is a handful of op launches whatever it moves, and the last trip
+    walks up to a tile of rows nobody needs: small tiles pay the first, large
+    ones the second. On a v5e, ms a layer for the four movements at the three
+    8k cells' buffers (``testing/tpu_checks.py`` ``moe_rows/*``, PR 34; the
+    one-shot form walks the whole buffer):
+
+    ====================  ========  =====  =====  ======
+    ``R x D``, rows in    one-shot  512    1024   2048
+    ====================  ========  =====  =====  ======
+    16384 x 2048,  5126   4.47      2.52   2.61   4.02
+    24576 x 2304, 16216   9.10      6.51   6.29   16.00
+    8192 x 1024,   2607   1.57      1.35   1.37   1.48
+    ====================  ========  =====  =====  ======
+
+    With every row landed (``n_valid = R``) the same three buffers take 4.45 /
+    9.07 / 1.54 ms one-shot and 5.57 / 8.97 / 2.17 at 1024: the loop wins below
+    about three quarters full and loses above. (All timed while the dispatch's
+    transpose still summed in bfloat16; it sums in float32 since.) 512 and 1024
+    are within 4 % of each other everywhere; at 2048 rows a float32 tile of 2304
+    columns no longer stays where the smaller ones do and a row costs 2.5 x. So:
+    1024 rows up to these widths (a wider row would want fewer: hold ``tile x D``
+    near 2.4 M elements); the callers cap the tile at the buffer."""
+    return max(256, min(1024, 2_400_000 // D // 256 * 256))
+
+
+def _trips(n_valid, tile: int):
+    return (n_valid + (tile - 1)) // tile
+
+
+# The two loops are ``jax.jit`` functions: a layer's four movements are traced
+# once a shape and served to every layer and program after it from jit's cache
+# (untraced, 32 loop bodies a step cost the chip machine's host 3.5 s of set-up).
+
+@functools.partial(jax.jit, static_argnames=("tile", "out_dtype"))
+def _gather_loop(src, token, n_valid, scale, dot_with, tile: int, out_dtype):
+    """``(rows (R, D) out_dtype, dots (R,) float32)``: for the tiles that reach
+    below ``n_valid``, ``rows[r] = src[token[r]] * scale[r]`` (the product in
+    float32, rounded once) and ``dots[r] = sum(src[token[r]] * dot_with[r])``
+    in float32, from the same fetch; rows at or past ``n_valid`` are zero in
+    both. Either is ``None`` where it was not asked for (``out_dtype`` /
+    ``dot_with`` ``None``). Where ``R`` is no multiple of the tile, the last
+    tile is moved back to end at ``R`` and writes some rows a second time."""
+    R, D = token.shape[0], src.shape[1]
+
+    def body(i, carry):
+        rows, dots = carry
+        start = jnp.minimum(i * tile, R - tile)
+        live = start + jnp.arange(tile, dtype=jnp.int32) < n_valid
+        got = src[lax.dynamic_slice(token, (start,), (tile,))]
+        if dot_with is not None:
+            other = lax.dynamic_slice(dot_with, (start, 0), (tile, D))
+            dot = jnp.sum(got.astype(_F32) * other.astype(_F32), axis=-1)
+            dots = lax.dynamic_update_slice(dots, jnp.where(live, dot, 0.0), (start,))
+        if rows is not None:
+            if scale is not None:
+                got = got.astype(_F32) \
+                    * lax.dynamic_slice(scale, (start,), (tile,)).astype(_F32)[:, None]
+            got = jnp.where(live[:, None], got, 0).astype(out_dtype)
+            rows = lax.dynamic_update_slice(rows, got, (start, 0))
+        return rows, dots
+
+    init = (None if out_dtype is None else jnp.zeros((R, D), out_dtype),
+            None if dot_with is None else jnp.zeros((R,), _F32))
+    return lax.fori_loop(0, _trips(n_valid, tile), body, init)
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "out_rows", "out_dtype"))
+def _scatter_add_loop(rows, token, n_valid, scale, tile: int, out_rows: int, out_dtype):
+    """``out[token[r]] += rows[r] * scale[r]`` for ``r < n_valid``, tile by tile
+    in the buffer's order, on one accumulator ``(out_rows, D)`` of
+    ``out_dtype``; the product in float32. Rows at or past ``n_valid`` (and the
+    rows a moved-back last tile would add twice) are selected to zero *before*
+    the product: they hold whatever the grouped kernel left there."""
+    R, D = rows.shape
+
+    def body(i, acc):
+        start = jnp.minimum(i * tile, R - tile)
+        at = start + jnp.arange(tile, dtype=jnp.int32)
+        live = (at >= i * tile) & (at < n_valid)
+        add = jnp.where(live[:, None], lax.dynamic_slice(rows, (start, 0), (tile, D)), 0)
+        if scale is not None:
+            add = add.astype(_F32) \
+                * lax.dynamic_slice(scale, (start,), (tile,)).astype(_F32)[:, None]
+        return acc.at[lax.dynamic_slice(token, (start,), (tile,))].add(add.astype(out_dtype))
+
+    return lax.fori_loop(0, _trips(n_valid, tile), body, jnp.zeros((out_rows, D), out_dtype))
+
+
+# Each is the other's transpose, and a loop with a trip count the device knows
+# has no transpose of its own. ``static`` is the tile and what the backward
+# needs of an operand it does not keep (its rows, its dtype): no residual is
+# held for a shape.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _gather(src, token, n_valid, scale, static):
+    tile, _, dtype = static
+    return _gather_loop(src, token, n_valid, scale, None, tile, dtype)[0]
+
+
+def _gather_fwd(src, token, n_valid, scale, static):
+    return (_gather(src, token, n_valid, scale, static),
+            (None if scale is None else src, token, n_valid, scale))
+
+
+def _gather_bwd(static, res, ct):
+    tile, src_rows, dtype = static
+    src, token, n_valid, scale = res
+    # a token's rows lie in different tiles: the sum stays float32 across the
+    # trips and is rounded once, as XLA's one-shot scatter-add of bfloat16 is
+    d_src = _scatter_add_loop(ct, token, n_valid, scale, tile, src_rows, _F32).astype(dtype)
+    d_scale = None
+    if scale is not None:
+        d_scale = _gather_loop(src, token, n_valid, None, ct, tile, None)[1].astype(scale.dtype)
+    return d_src, None, None, d_scale
+
+
+_gather.defvjp(_gather_fwd, _gather_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _scatter_add(rows, token, n_valid, scale, static):
+    tile, out_rows, out_dtype, _ = static
+    return _scatter_add_loop(rows, token, n_valid, scale, tile, out_rows, out_dtype)
+
+
+def _scatter_add_fwd(rows, token, n_valid, scale, static):
+    return (_scatter_add(rows, token, n_valid, scale, static),
+            (None if scale is None else rows, token, n_valid, scale))
+
+
+def _scatter_add_bwd(static, res, ct):
+    tile, _, _, rows_dtype = static
+    rows, token, n_valid, scale = res
+    d_rows, d_scale = _gather_loop(ct, token, n_valid, scale, rows, tile, rows_dtype)
+    return d_rows, None, None, None if scale is None else d_scale.astype(scale.dtype)
+
+
+_scatter_add.defvjp(_scatter_add_fwd, _scatter_add_bwd)
+
+
+def _n_valid(n_valid, R: int):
+    return jnp.clip(jnp.asarray(n_valid, jnp.int32), 0, R)
+
+
+def gather_rows(src: jax.Array, token: jax.Array, n_valid, *,
+                scale: Optional[jax.Array] = None) -> jax.Array:
+    """``(R, D)`` in ``src``'s dtype: row ``r`` is ``src[token[r]]`` (times
+    ``scale[r]``, the product in float32 and rounded once) for ``r < n_valid``
+    and zero from there on.
+
+    ``src``: ``(T, D)``; ``token``: ``(R,)`` row numbers; ``n_valid``: a device
+    scalar. A loop walks the tiles (:func:`_row_tile` rows each) that reach
+    below ``n_valid`` and no others: the tail of the buffer costs its zero-fill.
+    Its transpose is :func:`scatter_add_rows`, with the sum taken in float32
+    and rounded once to ``src``'s dtype."""
+    R = token.shape[0]
+    tile = min(R, _row_tile(src.shape[1]))
+    _book("gather", R, src.shape[1], src.dtype, tile)
+    return _gather(src, token, _n_valid(n_valid, R), scale, (tile, src.shape[0], src.dtype))
+
+
+def scatter_add_rows(rows: jax.Array, token: jax.Array, n_valid, *, out_rows: int,
+                     scale: Optional[jax.Array] = None, out_dtype=None) -> jax.Array:
+    """``(out_rows, D)`` of ``out_dtype`` (``rows``' own by default):
+    ``out[token[r]] += rows[r] * scale[r]`` over ``r < n_valid``, a token's rows
+    in the order the buffer holds them, the product in float32.
+
+    What ``rows`` holds at or past ``n_valid`` is never read into a sum (it may
+    be NaN). The same loop as :func:`gather_rows`, whose transpose this is; with
+    a ``scale`` its backward takes the scale's cotangent from the same fetch."""
+    R = token.shape[0]
+    out_dtype = jnp.dtype(rows.dtype if out_dtype is None else out_dtype)
+    tile = min(R, _row_tile(rows.shape[1]))
+    _book("scatter_add", R, rows.shape[1], rows.dtype, tile)
+    return _scatter_add(rows, token, _n_valid(n_valid, R), scale,
+                        (tile, out_rows, out_dtype, rows.dtype))
+
+
+@jax.custom_vjp
+def _settled(rows, live):
+    """``rows`` through one masking pass that changes no value (the loop left
+    zeros where ``live`` is false). ``xs`` lives until the backward pass (the
+    weights' cotangents read it), and the chip's compiler holds a loop's own
+    result that long at twice its size: + ``R x D`` a layer, 0.42 GiB in the
+    Mellum cell's step (PR 34). The cotangent passes as it is: the loop that
+    takes it masks the tail itself."""
+    return jnp.where(live[:, None], rows, 0)
+
+
+_settled.defvjp(lambda rows, live: (_settled(rows, live), None), lambda _, ct: (ct, None))
+
+
+def _book(kernel: str, R: int, D: int, dtype, tile: int):
+    """``monitor.tile_records()``: the buffer's tiles (the most trips a loop
+    makes; how many it makes is ``expert_rows`` over the tile, on the device)."""
+    _book_tiles("moe_rows", kernel, (R, D, str(jnp.dtype(dtype)), tile),
+                 total=-(-R // tile), live=-(-R // tile), masked=1)
+
+
 def dropless_experts(
     x: jax.Array,
     weights: jax.Array,
@@ -165,12 +382,13 @@ def dropless_experts(
         ends = jnp.minimum(jnp.cumsum(counts), R)
         group_sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
         rows = jnp.sum(counts)
-        valid = jnp.arange(R) < ends[-1]
-        w_sorted = jnp.where(valid, weights.reshape(-1)[order], 0.0)
+        n_valid = ends[-1]                      # the rows that landed: the loops' bound
+        w_sorted = weights.reshape(-1)[order]
         # the rows of no group hold whatever the grouped kernel leaves there,
-        # in its outputs AND in the cotangent it hands back for ``xs``: this
-        # select keeps that out of dx (its transpose is the same select)
-        xs = jnp.where(valid[:, None], x[token], 0)
+        # in its outputs AND in the cotangent it hands back for ``xs``: both
+        # loops and their transposes select them out (and never walk the tiles
+        # past the last row that landed)
+        xs = _settled(gather_rows(x, token, n_valid), jnp.arange(R) < n_valid)
     with _span("moe_experts"):
         dt = x.dtype
         grouped = lambda a, w, out: _grouped_matmul(
@@ -182,9 +400,8 @@ def dropless_experts(
             h = jnp.square(jax.nn.relu(grouped(xs, experts["w_up"], _F32)))
         y = grouped(h.astype(dt), experts["w_down"], dt)      # as a dense layer hands it on
     with _span("moe_combine"):
-        # rows of no group are whatever the grouped kernel left there: cut them
-        y = jnp.where(valid[:, None], y, 0).astype(_F32) * w_sorted[:, None]
-        out = jnp.zeros((T, D), _F32).at[token].add(y)
+        # ``w * y`` a tile at a time in float32, summed onto the tokens in float32
+        out = scatter_add_rows(y, token, n_valid, scale=w_sorted, out_rows=T, out_dtype=_F32)
     counters = {
         "expert_rows": rows.astype(_F32),
         "expert_load_max_over_mean": jnp.max(counts).astype(_F32) * held

@@ -58,6 +58,7 @@ from beforeholiday_tpu.monitor.metrics import (  # noqa: F401
 )
 from beforeholiday_tpu.monitor.export import MetricsLogger  # noqa: F401
 from beforeholiday_tpu.monitor.counters import (  # noqa: F401
+    book_tiles,
     dispatch_counters,
     dispatch_records,
     dispatch_summary,
@@ -129,6 +130,7 @@ __all__ = [
     "active_flight_recorder",
     "active_recorder",
     "annotate",
+    "book_tiles",
     "chip_specs",
     "classify_span",
     "comms_records",

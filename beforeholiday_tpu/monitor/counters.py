@@ -21,6 +21,7 @@ from __future__ import annotations
 from typing import Dict, List, Tuple
 
 __all__ = [
+    "book_tiles",
     "dispatch_counters",
     "dispatch_records",
     "dispatch_summary",
@@ -103,6 +104,15 @@ def tile_records() -> List[Dict[str, object]]:
         ),
         key=lambda r: (r["op"], r["key"], r["kernel"]),
     )
+
+
+def book_tiles(op: str, kernel: str, statics: Tuple, *, total: int, live: int,
+               masked: int) -> None:
+    """Book one trace of a tiled op for :func:`tile_records`, for callers
+    that may not import ``guard`` (``moe``): ``guard.dispatch.count_tiles``."""
+    from beforeholiday_tpu.guard import dispatch as _dispatch
+
+    _dispatch.count_tiles(op, kernel, statics, total=total, live=live, masked=masked)
 
 
 def dispatch_summary() -> List[Dict[str, object]]:

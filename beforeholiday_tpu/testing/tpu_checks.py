@@ -360,6 +360,114 @@ def check_grouped_matmul(results: list) -> None:
               json.dumps({n: round(t, 3) for n, t in ms.items()}))
 
 
+# (tag, tokens, buffer rows, width, rows that land a layer in the cell: ledger, PR 33)
+_MOE_ROWS_SHAPES = (
+    ("qwen", 8192, 16384, 2048, 5126),
+    ("mellum", 8192, 24576, 2304, 16216),
+    ("nemotron", 8192, 8192, 1024, 2607),
+)
+_MOE_ROWS_TILES = (512, 1024, 2048)
+
+
+def check_moe_rows(results: list) -> None:
+    """The sort's two sides (``moe.dropless.gather_rows`` / ``scatter_add_rows``:
+    loops that stop at the last row that landed), compiled, at the three 8k
+    cells' ``(T, R, D)``: the four row movements of a layer — dispatch forward
+    and backward in bfloat16, combine forward (``w * y`` summed in float32) and
+    backward (``dy`` and ``dw`` from one fetch) — against plain indexing over the
+    whole buffer, with the tail of ``y`` and of the cotangents NaN, at ``n_valid``
+    = the cell's, ``R / 8`` and ``R``; and the ms a layer's four movements take
+    as loops, at three tiles, beside the one-shot form (which walks ``R`` rows
+    whatever landed). Only the chip says what a trip of the loop costs."""
+    from beforeholiday_tpu.moe import dropless
+
+    def check(name, cond, info=""):
+        results.append((f"moe_rows/{name}", bool(cond), str(info)))
+
+    def at_tile(tile, fn):
+        """``fn`` traced with the loops' tile at ``tile`` (``_row_tile`` is read
+        when a movement is traced)."""
+        def traced(*args):
+            kept = dropless._row_tile
+            dropless._row_tile = lambda D: tile
+            try:
+                return fn(*args)
+            finally:
+                dropless._row_tile = kept
+        return traced
+
+    bf, f32 = jnp.bfloat16, jnp.float32
+    for tag, T, R, D, landed in _MOE_ROWS_SHAPES:
+        ks = jax.random.split(jax.random.PRNGKey(R + D), 6)
+        # sixteen groups of ascending tokens, as a stable sort by expert leaves them
+        token = jnp.sort(jax.random.randint(ks[0], (16, R // 16), 0, T), axis=1).reshape(-1)
+        x = jax.random.normal(ks[1], (T, D), f32).astype(bf)
+        w = jax.random.uniform(ks[2], (R,), f32)
+        y = jax.random.normal(ks[3], (R, D), f32).astype(bf)
+        dxs = jax.random.normal(ks[4], (R, D), f32).astype(bf)
+        dout = jax.random.normal(ks[5], (T, D), f32)
+
+        def movements(tile):
+            """The four as jitted functions of ``n_valid``; ``tile`` ``None`` is
+            the one-shot form. A fresh function each call: nothing is served
+            from another setting's cache."""
+            if tile is None:
+                cut = lambda n, a: jnp.where((jnp.arange(R) < n)[:, None], a, 0)
+                gather = lambda n, x: cut(n, x[token])
+                combine = lambda n, y, w: jnp.zeros((T, D), f32).at[token].add(
+                    cut(n, y).astype(f32) * w[:, None])
+            else:
+                gather = at_tile(tile, lambda n, x: dropless.gather_rows(x, token, n))
+                combine = at_tile(tile, lambda n, y, w: dropless.scatter_add_rows(
+                    y, token, n, scale=w, out_rows=T, out_dtype=f32))
+            return {
+                "dispatch_fwd": jax.jit(lambda n: gather(n, x)),
+                "dispatch_bwd": jax.jit(lambda n, ct: jax.vjp(
+                    lambda x: gather(n, x), x)[1](ct)[0]),
+                "combine_fwd": jax.jit(lambda n, y: combine(n, y, w)),
+                "combine_bwd": jax.jit(lambda n, y: jax.vjp(
+                    lambda y, w: combine(n, y, w), y, w)[1](dout)),
+            }
+
+        def poisoned(a, n):
+            return jnp.where((jnp.arange(R) >= n)[:, None], jnp.nan, a)
+
+        def args(name, n):
+            return {"dispatch_fwd": (n,), "dispatch_bwd": (n, poisoned(dxs, n)),
+                    "combine_fwd": (n, poisoned(y, n)), "combine_bwd": (n, poisoned(y, n))}[name]
+
+        def timed(fn, a):
+            return 1e3 * _min_step_seconds(lambda _: fn(*a), None)
+
+        picked = dropless._row_tile(D)                 # what the layer runs with
+        loop, plain = movements(picked), movements(None)
+        for label, n in (("cell", landed), ("eighth", R // 8), ("full", R)):
+            n = jnp.int32(n)
+            for name in loop:
+                got = jax.tree.leaves(loop[name](*args(name, n)))
+                want = jax.tree.leaves(plain[name](*args(name, n)))
+                gap = max(float(jnp.max(jnp.abs(g.astype(f32) - v.astype(f32))))
+                          for g, v in zip(got, want))
+                scale = max(float(jnp.max(jnp.abs(v.astype(f32)))) for v in want)
+                finite = all(bool(jnp.all(jnp.isfinite(g.astype(f32)))) for g in got)
+                # gathers move bits; the bfloat16 transpose is a float32 sum rounded
+                # once here: one bfloat16 ulp of the largest value from a form
+                # that rounds as often as it adds (the one-shot one off the TPU)
+                limit = 0.0 if name == "dispatch_fwd" else \
+                    8e-3 if name == "dispatch_bwd" else 1e-5
+                check(f"{tag}/{label}/{name}", finite and gap <= limit * scale,
+                      f"max|d|={gap:.3e} of {scale:.3e}")
+        ms = {}
+        for tile in (None,) + _MOE_ROWS_TILES:
+            fns = movements(tile)
+            for label, n in (("cell", landed), ("full", R)):
+                n = jnp.int32(n)
+                ms[f"{'one_shot' if tile is None else tile}_{label}"] = sum(
+                    timed(fns[name], args(name, n)) for name in fns)
+        check(f"{tag}/ms_a_layer", ms[f"{picked}_cell"] < ms["one_shot_cell"],
+              json.dumps({n: round(t, 3) for n, t in ms.items()}))
+
+
 # batch, tokens, heads, head dim, groups, state: one Mamba-2 block of the Nemotron cell
 _SSD_SHAPE = (1, 8192, 16, 64, 1, 128)
 
@@ -835,7 +943,7 @@ def main() -> int:
     enable_compile_cache()
     results: list = []
     for group in (check_flash_dropout, check_flash_tiles, check_wy_prepare,
-                  check_grouped_matmul, check_ssd, check_aliased_mt_kernels,
+                  check_grouped_matmul, check_moe_rows, check_ssd, check_aliased_mt_kernels,
                   check_compiled_kernel_parity):
         try:
             group(results)

@@ -9,6 +9,7 @@ the TPU's library (see the ``on-chip-measurement`` guide, section 2). Keep every
 such compile in this one file."""
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -95,3 +96,73 @@ def test_the_ssd_kernels_compile_for_the_chip(one_chip, monkeypatch, batch, S, H
     assert text.count("tpu_custom_call") == 3
     for kernel in ("ssd_fwd", "ssd_bwd_states", "ssd_bwd"):
         assert kernel in text
+
+
+def _one_shot_rows(monkeypatch, dropless):
+    """The sort's two sides as the static ops they were before PR 34: one
+    gather and one scatter-add over the whole buffer, cut by a ``where``."""
+    def gather(src, token, n_valid, *, scale=None):
+        return jnp.where((jnp.arange(token.shape[0]) < n_valid)[:, None], src[token], 0)
+
+    def scatter_add(rows, token, n_valid, *, out_rows, scale=None, out_dtype=None):
+        valid = jnp.arange(token.shape[0]) < n_valid
+        rows = jnp.where(valid[:, None], rows, 0).astype(jnp.float32) \
+            * jnp.where(valid, scale, 0.0)[:, None]
+        return jnp.zeros((out_rows, rows.shape[1]), out_dtype).at[token].add(rows)
+
+    monkeypatch.setattr(dropless, "gather_rows", gather)
+    monkeypatch.setattr(dropless, "scatter_add_rows", scatter_add)
+
+
+@pytest.mark.parametrize("T,R,D,k,held,F,gated", (
+    (8192, 16384, 2048, 10, 32, 512, True),     # the Qwen cell
+    (8192, 24576, 2304, 8, 16, 896, True),      # the Mellum cell
+    (8192, 8192, 1024, 22, 8, 2688, False),     # the Nemotron cell (the latent; k > held)
+), ids=("qwen", "mellum", "nemotron"))
+def test_the_dropless_layer_compiles_for_the_chip_with_its_rows_moved_in_loops(
+        one_chip, compiled_not_interpreted, monkeypatch, T, R, D, k, held, F, gated):
+    """Forward + backward of ``dropless_experts`` at a cell's shapes: the four
+    row movements are ``while`` loops whose bodies update the buffers in place
+    (no ``copy`` of a buffer inside one), and the program's temporaries are not
+    above the one-shot form's (1 MiB of slack: the loops carry a few ``(R,)``
+    vectors; what they save is the ``R x D`` float32 product). At the Mellum
+    cell's shapes the masking pass over ``xs`` (``dropless._settled``) is what
+    keeps the compiler from holding the gather loop's result at twice its size:
+    without it the temporaries are ``R x D`` bfloat16 larger. If this compiler
+    stops doing that, the pass can go."""
+    from beforeholiday_tpu.moe import dropless
+
+    bf, f32 = jnp.bfloat16, jnp.float32
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    experts = {"w_up": shape((held, D, F), bf), "w_down": shape((held, F, D), bf)}
+    if gated:
+        experts["w_gate"] = shape((held, D, F), bf)
+
+    def compiled():
+        def both(x, w, idx, experts, ct):       # a fresh function: nothing from jit's cache
+            (y, counters), pull = jax.vjp(lambda x, w, experts: dropless.dropless_experts(
+                x, w, idx, experts, rows_bound=R, impl="pallas"), x, w, experts)
+            return y, pull((ct, jax.tree.map(jnp.zeros_like, counters)))
+
+        return jax.jit(both).lower(shape((T, D), bf), shape((T, k), f32),
+                                   shape((T, k), jnp.int32), experts, shape((T, D), f32)).compile()
+
+    loops = compiled()
+    text = loops.as_text()
+    bodies = [c for c in text.split("\n\n") if "/while/body/" in c
+              and ("moe_dispatch" in c or "moe_combine" in c)]
+    movers = [b for b in bodies if "scatter-add" in b or "/while/body/gather" in b]
+    assert len(movers) >= 4, len(movers)
+    for body in movers:
+        big = [l for l in body.splitlines() if re.search(r" copy\(", l)
+               and re.search(rf"\[(?:{R}|{T}),{D}\]", l)]
+        assert not big, big
+    if R == 24576:
+        monkeypatch.setattr(dropless, "_settled", lambda rows, live: rows)
+        bare = compiled().memory_analysis().temp_size_in_bytes
+        assert bare - loops.memory_analysis().temp_size_in_bytes >= 0.9 * R * D * 2
+    _one_shot_rows(monkeypatch, dropless)
+    one_shot = compiled()
+    assert "moe_dispatch)/while/body" not in one_shot.as_text()
+    got, was = (c.memory_analysis().temp_size_in_bytes for c in (loops, one_shot))
+    assert got <= was + 2 ** 20, (got / 2 ** 20, was / 2 ** 20)

@@ -438,7 +438,11 @@ def test_nemotron_first_level_scopes_partition_the_step(nemotron_names):
     twice = [n for n in nemotron_names
              if sum(s in n for s in first) + (_pass_of(n) == "amp_forward") > 1]
     assert not twice
-    both = [n for n in nemotron_names if "amp_forward" in n and "amp_backward" in n]
+    # (off the TPU ``ssd`` is a checkpointed chunk scan: with the one-period
+    # stack no longer a loop, PR 34, what the backward pass recomputes or transposes of it
+    # carries its forward names behind the backward's)
+    both = [n for n in nemotron_names if "amp_forward" in n and "amp_backward" in n
+            and "/ssd/checkpoint/" not in n]
     assert all("amp_backward/transpose(amp_forward)" in n for n in both), both
     for scope in _NEMOTRON_MODEL:    # the model's scopes survive inside both passes
         assert any(f"amp_forward/jvp({scope})" in n for n in nemotron_names), scope
@@ -512,3 +516,56 @@ def test_the_ssd_kernels_carry_their_names_under_the_mixer_in_both_passes():
         for n in found:
             assert _pass_of(n) == want, (k, n)
             assert re.search(rf"ssm_mixer\)*/ssd\)*/jit\(_(?:fwd|bwd)_pallas\)/{k}/", n), n
+
+
+# ---------------------------------------------------------------------------
+# the sort's two sides as loops (PR 34): every op of a loop carries its span
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def qwen_names():
+    """The distinct ``op_name`` of every op of the compiled tiny-qwen3-next step."""
+    from benchmark import run as bench_run
+
+    cell = bench_run.load("workloads", "tiny-qwen3-next.train")
+    run = bench_run.Cell(cell, bench_run.load("configs", cell["config"]), jax.devices()[:1])
+    run.start(7)
+    run.build()
+    compiled = run.program.step.jitted.lower(run.state, run.pool[0]).compile()
+    return sorted(set(re.findall(r'op_name="(jit\([^"]+)"', compiled.as_text())))
+
+
+@pytest.mark.parametrize("names_of,suffix", (
+    ("qwen_names", ""), ("mellum_names", ".mellum"), ("nemotron_names", ".nemotron_h")))
+def test_every_op_of_the_row_loops_lies_under_its_span(request, names_of, suffix):
+    """``gather_rows`` / ``scatter_add_rows`` run as ``while`` loops inside the
+    spans ``moe/moe_dispatch`` and ``moe/moe_combine``, the backward loops (a
+    ``custom_vjp``'s) too: ``moe_ms*`` and ``moe_sort_ms*`` read them by those
+    names, so a loop op without one would fall to ``unattributed_ms``."""
+    from benchmark import run as bench_run
+
+    names = request.getfixturevalue(names_of)
+    patterns = [re.compile(bench_run.load("layer_metrics", m + suffix)["pattern"])
+                for m in ("moe_ms", "moe_sort_ms")]
+    moved = [n for n in names if re.search(r"/(gather|scatter-add|dynamic_update_slice)$", n)
+             and "/while/body" in n.split("moe_")[-1] and "/moe" in n]
+    for side in ("moe_dispatch", "moe_combine"):
+        for mover in ("gather", "scatter-add"):
+            for want in ("amp_forward", "amp_backward"):
+                # dispatch gathers forward and scatter-adds backward; combine the reverse
+                forward = (side == "moe_dispatch") == (mover == "gather")
+                if forward != (want == "amp_forward"):
+                    continue
+                assert [n for n in moved if side in _scopes_of(n) and n.endswith(mover)
+                        and _pass_of(n) == want], (side, mover, want)
+    # every op inside a loop of the two spans, whatever it is
+    in_loops = [n for n in names
+                if re.search(r"moe_(dispatch|combine)\)*/jit\(_\w+_loop\)/while/body/", n)]
+    assert len(in_loops) >= 8
+    for n in in_loops:
+        assert all(p.search(n) for p in patterns), n
+        assert _pass_of(n) is not None, n
+    # and no row mover of the two spans outside a loop
+    loose = [n for n in names if re.search(r"moe_(dispatch|combine)", n)
+             and n.endswith("scatter-add") and "/while/body/" not in n.split("moe_")[-1]]
+    assert not [n for n in loose if "moe_combine" in n], loose

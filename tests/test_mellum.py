@@ -249,6 +249,35 @@ def test_layer_types_must_be_whole_periods():
             model.MellumConfig(layer_types=bad).period
 
 
+@pytest.mark.parametrize("periods", (1, 3))
+def test_a_stack_of_one_period_is_not_a_loop(periods):
+    """``layers.scan_periods`` is ``lax.scan`` in values and gradients; one
+    period is the body called once, with no ``while`` for the compiler to find
+    (PR 34: nested in one, the MoE layer's loops cost the Qwen cell 0.96 GiB)."""
+    ws = jax.random.normal(jax.random.PRNGKey(periods), (periods, 2, 8, 8))
+
+    def period(x, w):
+        for i in range(2):
+            x = jnp.tanh(x @ w[i])
+        return x, {"seen": jnp.sum(x)}
+
+    def loss(scan, x, ws):
+        y, seen = scan(period, x, ws)
+        return jnp.sum(y) + jnp.sum(seen["seen"]), seen
+
+    x = jnp.ones((4, 8))
+    (got, seen), grads = jax.value_and_grad(
+        lambda x, ws: loss(layers.scan_periods, x, ws), (0, 1), has_aux=True)(x, ws)
+    (want, seen_w), grads_w = jax.value_and_grad(
+        lambda x, ws: loss(jax.lax.scan, x, ws), (0, 1), has_aux=True)(x, ws)
+    assert seen["seen"].shape == seen_w["seen"].shape == (periods,)
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    for g, w in zip(grads, grads_w):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)
+    text = jax.jit(jax.grad(lambda x: loss(layers.scan_periods, x, ws)[0])).lower(x).as_text()
+    assert ("stablehlo.while" in text) == (periods > 1)
+
+
 def test_keep_fp32_mask():
     tree = family._to_tree(family.weights(CFG, jax.random.PRNGKey(0)))
     flat, _ = jax.tree_util.tree_flatten_with_path(tree)

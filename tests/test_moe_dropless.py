@@ -433,3 +433,309 @@ def test_swiglu_softmax_layers_trace_what_they_traced(impl):
     assert text.count("logistic") == 3        # silu of the experts and the shared one, the score
     bare = trace({k: v for k, v in p.items() if k not in _SHARED})
     assert bare.count("top_k") == 1 and bare.count("logistic") == 1 and "square" not in bare
+
+
+# -- the sort's two sides walk only the rows that land (PR 34) ----------------------
+
+ROWS = 64                # a sorted-rows buffer of the two functions' own tests
+
+
+def _rows_case(seed=20, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    token = jax.random.randint(ks[0], (ROWS,), 0, T)
+    n = lambda k, *shape: jax.random.normal(k, shape).astype(dtype)
+    return {"token": token, "src": n(ks[1], T, D), "rows": n(ks[2], ROWS, D),
+            "scale": jax.random.normal(ks[3], (ROWS,)), "ct_rows": n(ks[4], ROWS, D),
+            "ct_out": n(ks[5], T, D)}
+
+
+def _plain_gather(src, token, n_valid, scale=None):
+    got = src[token] if scale is None else src[token] * scale[:, None]
+    return jnp.where((jnp.arange(token.shape[0]) < n_valid)[:, None], got, 0)
+
+
+def _plain_scatter_add(rows, token, n_valid, scale=None):
+    rows = jnp.where((jnp.arange(token.shape[0]) < n_valid)[:, None], rows, 0)
+    return jnp.zeros((T, rows.shape[1]), rows.dtype).at[token].add(
+        rows if scale is None else rows * scale[:, None])
+
+
+# n_valid: none, one row, on a tile's edge, off it, inside the last tile, the whole buffer;
+# tile: divides the buffer, does not (the last tile is moved back), is larger than it
+_FILLS = (0, 1, 16, 17, 50, ROWS)
+_TILES = (16, 24, 100)
+
+
+@pytest.mark.parametrize("scaled", (False, True), ids=("plain", "scaled"))
+@pytest.mark.parametrize("tile", _TILES)
+@pytest.mark.parametrize("n_valid", _FILLS)
+def test_gather_rows_is_plain_indexing_up_to_the_rows_that_landed(
+        monkeypatch, n_valid, tile, scaled):
+    monkeypatch.setattr(dropless, "_row_tile", lambda D: tile)
+    c = _rows_case()
+    scale = c["scale"] if scaled else None
+    got = jax.jit(lambda src, n, scale: dropless.gather_rows(
+        src, c["token"], n, scale=scale))(c["src"], n_valid, scale)
+    want = _plain_gather(c["src"], c["token"], n_valid, scale)
+    assert got.dtype == want.dtype and got.shape == (ROWS, D)
+    if n_valid:                                                 # (a zero oracle has no scale)
+        _close(got, want, "rows")
+    assert not bool(jnp.any(got[n_valid:]))                     # the tail is zero, not small
+    args = (c["src"],) + ((scale,) if scaled else ())
+    grads = lambda f: jax.grad(lambda *a: jnp.sum(f(*a) * c["ct_rows"]),
+                               argnums=tuple(range(len(args))))(*args)
+    got = grads(lambda src, scale=None: dropless.gather_rows(
+        src, c["token"], n_valid, scale=scale))
+    want = grads(lambda src, scale=None: _plain_gather(src, c["token"], n_valid, scale))
+    for g, w, what in zip(got, want, ("d src", "d scale")):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        assert float(jnp.max(jnp.abs(g - w))) <= _TOL * max(float(jnp.max(jnp.abs(w))), 1.0), what
+
+
+@pytest.mark.parametrize("scaled", (False, True), ids=("plain", "scaled"))
+@pytest.mark.parametrize("tile", _TILES)
+@pytest.mark.parametrize("n_valid", _FILLS)
+def test_scatter_add_rows_is_plain_indexing_up_to_the_rows_that_landed(
+        monkeypatch, n_valid, tile, scaled):
+    monkeypatch.setattr(dropless, "_row_tile", lambda D: tile)
+    c = _rows_case(21)
+    scale = c["scale"] if scaled else None
+    got = jax.jit(lambda rows, n, scale: dropless.scatter_add_rows(
+        rows, c["token"], n, scale=scale, out_rows=T))(c["rows"], n_valid, scale)
+    want = _plain_scatter_add(c["rows"], c["token"], n_valid, scale)
+    assert got.dtype == want.dtype and got.shape == (T, D)
+    assert float(jnp.max(jnp.abs(got - want))) <= _TOL * max(float(jnp.max(jnp.abs(want))), 1.0)
+    args = (c["rows"],) + ((scale,) if scaled else ())
+    grads = lambda f: jax.grad(lambda *a: jnp.sum(f(*a) * c["ct_out"]),
+                               argnums=tuple(range(len(args))))(*args)
+    got = grads(lambda rows, scale=None: dropless.scatter_add_rows(
+        rows, c["token"], n_valid, scale=scale, out_rows=T))
+    want = grads(lambda rows, scale=None: _plain_scatter_add(rows, c["token"], n_valid, scale))
+    for g, w, what in zip(got, want, ("d rows", "d scale")):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        assert float(jnp.max(jnp.abs(g - w))) <= _TOL * max(float(jnp.max(jnp.abs(w))), 1.0), what
+        assert not bool(jnp.any(g[n_valid:])), what             # nothing flows into the tail
+
+
+@pytest.mark.parametrize("tile", _TILES)
+def test_what_the_tail_holds_is_never_read_into_a_sum(monkeypatch, tile):
+    """NaN in ``rows`` and in the cotangent of the gathered rows from ``n_valid``
+    on (the scale is the router's weights: finite everywhere): values and
+    cotangents are those of a clean tail."""
+    monkeypatch.setattr(dropless, "_row_tile", lambda D: tile)
+    c, n_valid = _rows_case(22), 37
+    tail = (jnp.arange(ROWS) >= n_valid)
+    nan = lambda a: jnp.where(tail.reshape((-1,) + (1,) * (a.ndim - 1)), jnp.nan, a)
+    add = lambda rows, scale: dropless.scatter_add_rows(
+        rows, c["token"], n_valid, scale=scale, out_rows=T)
+    got, pull = jax.vjp(add, nan(c["rows"]), c["scale"])
+    want, pull_clean = jax.vjp(add, c["rows"], c["scale"])
+    assert bool(jnp.array_equal(got, want))
+    for g, w in zip(pull(c["ct_out"]), pull_clean(c["ct_out"])):
+        assert bool(jnp.array_equal(g, w))
+    _, pull = jax.vjp(lambda src: dropless.gather_rows(src, c["token"], n_valid),
+                      c["src"])
+    assert bool(jnp.array_equal(pull(nan(c["ct_rows"]))[0], pull(jnp.where(
+        tail[:, None], 0, c["ct_rows"]))[0]))
+
+
+def test_the_sums_are_taken_where_they_were(monkeypatch):
+    """bfloat16 rows: the gather rounds ``src * scale`` once from float32; the
+    combine's accumulator is float32 and its product ``w * y`` is float32; the
+    dispatch's transpose sums a token's rows in float32 across the tiles they
+    lie in and rounds once, as XLA's one-shot scatter-add of bfloat16 does."""
+    monkeypatch.setattr(dropless, "_row_tile", lambda D: 16)
+    c, n_valid, bf = _rows_case(23, jnp.bfloat16), 50, jnp.bfloat16
+    got = dropless.gather_rows(c["src"], c["token"], n_valid, scale=c["scale"])
+    want = _plain_gather(c["src"].astype(jnp.float32), c["token"], n_valid, c["scale"]).astype(bf)
+    assert got.dtype == bf and bool(jnp.array_equal(got, want))
+    got = dropless.scatter_add_rows(c["rows"], c["token"], n_valid, scale=c["scale"],
+                                    out_rows=T, out_dtype=jnp.float32)
+    want = _plain_scatter_add(c["rows"].astype(jnp.float32), c["token"], n_valid, c["scale"])
+    assert got.dtype == jnp.float32
+    assert float(jnp.max(jnp.abs(got - want))) <= 1e-6 * float(jnp.max(jnp.abs(want)))
+    d_src = jax.grad(lambda s: jnp.sum(dropless.gather_rows(s, c["token"], n_valid)
+                                       .astype(jnp.float32)))(c["src"])
+    assert d_src.dtype == bf
+    _, pull = jax.vjp(lambda s: dropless.gather_rows(s, c["token"], n_valid), c["src"])
+    once = _plain_scatter_add(c["ct_rows"].astype(jnp.float32), c["token"], n_valid).astype(bf)
+    assert bool(jnp.array_equal(pull(c["ct_rows"])[0], once))
+    d_rows, d_scale = jax.grad(lambda r, s: jnp.sum(dropless.scatter_add_rows(
+        r, c["token"], n_valid, scale=s, out_rows=T, out_dtype=jnp.float32)),
+        argnums=(0, 1))(c["rows"], c["scale"])
+    assert d_rows.dtype == bf and d_scale.dtype == jnp.float32
+    assert bool(jnp.array_equal(d_rows, jnp.where((jnp.arange(ROWS) < n_valid)[:, None],
+                                                  c["scale"][:, None], 0).astype(bf)
+                                * jnp.ones((ROWS, D), bf)))
+
+
+def test_the_loops_are_booked_once_a_traced_layer():
+    from beforeholiday_tpu import monitor
+
+    before = {(r["kernel"], r["key"]): r["traces"] for r in monitor.tile_records()
+              if r["op"] == "moe_rows"}
+    p, x = layer_params(24), tokens(24)
+    w, idx = dropless.route_topk(x, p["router"], K)
+    jax.jit(jax.grad(lambda x: jnp.sum(dropless.dropless_experts(
+        x, w, idx, held_slice(p, 0, 8), rows_bound=200)[0]))).lower(x)
+    after = {(r["kernel"], r["key"]): r for r in monitor.tile_records() if r["op"] == "moe_rows"}
+    tile = min(200, dropless._row_tile(D))
+    for kernel in ("gather", "scatter_add"):
+        key = (kernel, str((200, D, "float32", tile)))
+        assert after[key]["traces"] == before.get(key, 0) + 1, after
+        assert after[key]["total"] == -(-200 // tile)
+
+
+# ... and the layer end to end, against every held expert on every token
+
+def _dense_layer(x, p, first, held, k, relu2):
+    """Softmax top-k weights scattered to ``(T, E)``, every held expert on every
+    token."""
+    w, idx = dropless.route_topk(x, p["router"], k)
+    gates = scattered(w, idx)
+    out = jnp.zeros_like(x)
+    for e in range(first, first + held):
+        y = dropless.relu2_mlp(x, p["w_up"][e], p["w_down"][e]) if relu2 else \
+            dropless.swiglu(x, p["w_gate"][e], p["w_up"][e], p["w_down"][e])
+        out = out + gates[:, e:e + 1] * y
+    return out
+
+
+# (case, first, held, k, rows_bound, tile, relu2 experts)
+_LAYER_CASES = (
+    ("k_under_held", 4, 8, K, None, 64, False),
+    ("k_over_held_compacted", 12, 4, K_MANY, None, 64, True),
+    ("one_held_expert_tile_over_buffer", 5, 1, K, None, 1024, False),
+    ("bound_on_a_tile_edge", 0, 8, K, 256, 64, False),
+    ("bound_off_a_tile_edge", 0, 8, K, 250, 48, True),
+    ("tile_larger_than_the_buffer", 4, 4, K, 120, 4096, False),
+)
+
+
+@pytest.mark.parametrize("case,first,held,k,rows_bound,tile,relu2", _LAYER_CASES,
+                         ids=[c[0] for c in _LAYER_CASES])
+def test_the_layer_matches_the_dense_sum_in_values_and_cotangents(
+        monkeypatch, impl, case, first, held, k, rows_bound, tile, relu2):
+    """``y``, ``dx``, the router's cotangent (through the weights) and the
+    experts' matrices, with the grouped matmul leaving 1e30 in the rows of no
+    group, forward and backward (``_poisoned``)."""
+    monkeypatch.setattr(dropless, "_row_tile", lambda D: tile)
+    monkeypatch.setattr(dropless, "_grouped_matmul", _poisoned(
+        functools.partial(dropless._grouped_matmul, impl=impl)))
+    p, x = layer_params(25), tokens(25)
+    ct = jax.random.normal(jax.random.PRNGKey(26), (T, D))
+    names = ("w_up", "w_down") if relu2 else ("w_gate", "w_up", "w_down")
+
+    def program(x, p):
+        w, idx = dropless.route_topk(x, p["router"], k)
+        y, counters = dropless.dropless_experts(
+            x, w, idx, {n: p[n][first:first + held] for n in names}, first_expert=first,
+            rows_bound=rows_bound)
+        return jnp.sum(y * ct), (y, counters)
+
+    (_, (y, counters)), got = jax.jit(jax.value_and_grad(program, argnums=(0, 1), has_aux=True))(x, p)
+    assert int(counters["dropped_rows"]) == 0, case
+    want_y = _dense_layer(x, p, first, held, k, relu2)
+    want = jax.grad(lambda x, p: jnp.sum(_dense_layer(x, p, first, held, k, relu2) * ct),
+                    argnums=(0, 1))(x, p)
+    _close(y, want_y, "y")
+    _close(got[0], want[0], "dx")
+    for name in ("router",) + names:
+        _close(got[1][name], want[1][name], f"d{name}")
+
+
+@pytest.mark.parametrize("tile", (32, 64, 4096))
+def test_an_expert_with_no_rows_and_a_token_with_no_landed_choice(monkeypatch, impl, tile):
+    """The router never chooses expert 6 (its column is far below the rest), and
+    the tokens whose every choice is absent from the share get a zero row."""
+    monkeypatch.setattr(dropless, "_row_tile", lambda D: tile)
+    p, x = layer_params(27), tokens(27).at[:, 0].set(1.0)
+    p = dict(p, router=p["router"].at[:, 6].set(0.0).at[0, 6].set(-50.0))   # its logit is -50
+    w, idx = dropless.route_topk(x, p["router"], K)
+    first, held = 4, 4
+    assert not bool(jnp.any(idx == 6))
+    unreached = ~jnp.any((idx >= first) & (idx < first + held), axis=-1)
+    assert int(jnp.sum(unreached)) > 0
+    y, counters = dropless.dropless_experts(x, w, idx, held_slice(p, first, held),
+                                            first_expert=first, impl=impl)
+    _close(y, dense_routed(x, p, first, held), "y")
+    assert not bool(jnp.any(jnp.where(unreached[:, None], y, 0)))
+    assert int(counters["dropped_rows"]) == 0
+
+
+@pytest.mark.parametrize("tile", (32, 4096))
+def test_no_choice_lands_at_all(monkeypatch, impl, tile):
+    """``n_valid = 0``: no trip of either loop; the part is zero and so is every
+    cotangent it hands back."""
+    monkeypatch.setattr(dropless, "_row_tile", lambda D: tile)
+    p, x = layer_params(28), tokens(28)
+    w, idx = dropless.route_topk(x, p["router"], K)
+    ex = held_slice(p, 0, 2)
+    program = lambda x, w, ex: dropless.dropless_experts(
+        x, w, idx, ex, first_expert=E + 3, impl=impl)         # ids the router never gives
+    (y, counters), pull = jax.vjp(program, x, w, ex)
+    assert int(counters["expert_rows"]) == 0 and not bool(jnp.any(y))
+    for g in jax.tree.leaves(pull((jnp.ones_like(y), jax.tree.map(jnp.zeros_like, counters)))):
+        assert not bool(jnp.any(g))
+
+
+@pytest.mark.parametrize("tile", (32, 48, 4096))
+def test_a_buffer_that_overflows_is_full_and_counts_the_rest(monkeypatch, impl, tile):
+    """``n_valid = R``: every tile is walked; the assignments beyond the buffer
+    are the last of the sort (the highest experts' last tokens), counted, and
+    what landed is summed as the dense sum over exactly those."""
+    monkeypatch.setattr(dropless, "_row_tile", lambda D: tile)
+    p, x = layer_params(29), tokens(29)
+    w, idx = dropless.route_topk(x, p["router"], K)
+    first, held = 0, 8
+    landed = (idx >= first) & (idx < first + held)
+    rows = int(jnp.sum(landed))
+    R = rows - 19
+    y, counters = dropless.dropless_experts(x, w, idx, held_slice(p, first, held),
+                                            first_expert=first, rows_bound=R, impl=impl)
+    assert int(counters["dropped_rows"]) == 19 and int(counters["expert_rows"]) == rows
+    # the kept assignments: the first R in (expert, token) order
+    order = jnp.argsort(jnp.where(landed, idx, E).reshape(-1), stable=True)[:R]
+    kept = jnp.zeros((T * K,), bool).at[order].set(True).reshape(T, K)
+    gates = scattered(jnp.where(kept, w, 0.0), idx)
+    want = jnp.zeros_like(x)
+    for e in range(first, first + held):
+        want = want + gates[:, e:e + 1] * dropless.swiglu(
+            x, p["w_gate"][e], p["w_up"][e], p["w_down"][e])
+    _close(y, want, "what landed")
+
+
+def _row_movers(jaxpr, inside=False, found=None):
+    """``[(primitive, inside a while?, shapes)]`` of every gather and scatter of
+    two-dimensional row blocks in ``jaxpr`` and what it calls."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name in ("gather", "scatter-add", "scatter_add", "scatter"):
+            moved = eqn.outvars[0].aval if name == "gather" else eqn.invars[2].aval
+            if moved.ndim == 2:
+                found.append((name, inside, moved.shape))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _row_movers(sub, inside or name == "while", found)
+    return found
+
+
+def test_the_gradient_of_a_layer_moves_rows_inside_loops_only(monkeypatch):
+    """Four movements a layer, each a loop: no gather or scatter of ``D``-wide
+    rows outside a ``while`` — the buffer's ``R`` rows are never moved by one
+    static op — and inside them a tile at a time."""
+    R, tile = 256, 64
+    monkeypatch.setattr(dropless, "_row_tile", lambda D: tile)
+    p, x = layer_params(30), tokens(30)
+
+    def program(x, p):
+        y, _ = dropless.dropless_moe(x, p, top_k=K, rows_bound=R)
+        return jnp.sum(y)
+
+    jaxpr = jax.make_jaxpr(jax.grad(program, argnums=(0, 1)))(x, p).jaxpr
+    moved = [m for m in _row_movers(jaxpr) if m[2][1] == D]
+    assert not [m for m in moved if not m[1]], moved
+    assert sorted(m[0] for m in moved) == ["gather", "gather", "scatter-add", "scatter-add"], moved
+    assert all(m[2] == (tile, D) for m in moved), moved
+    # the lowered module holds them as ``while`` ops (a body may be shared)
+    assert "stablehlo.while" in jax.jit(jax.grad(program, argnums=(0, 1))).lower(x, p).as_text()

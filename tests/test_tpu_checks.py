@@ -83,7 +83,27 @@ def test_the_ssd_check_runs_its_comparison(monkeypatch):
         assert by_name[f"ssd/{k}"][0], by_name
 
 
-def test_the_ssd_check_is_one_of_the_groups_main_runs():
+def test_the_moe_rows_check_runs_its_comparison(monkeypatch):
+    """The chip check of the sort's two sides at a toy shape on the CPU: the four
+    row movements of a layer against plain indexing at three fills, the tail
+    NaN (the timings are not judged here)."""
+    from beforeholiday_tpu.moe import dropless
+
+    monkeypatch.setattr(tpu_checks, "_MOE_ROWS_SHAPES", (("toy", 64, 256, 128, 100),))
+    monkeypatch.setattr(tpu_checks, "_MOE_ROWS_TILES", (32, 64))
+    monkeypatch.setattr(dropless, "_row_tile", lambda D: 64)
+    results = []
+    tpu_checks.check_moe_rows(results)
+    by_name = {name: (ok, info) for name, ok, info in results}
+    moved = ("dispatch_fwd", "dispatch_bwd", "combine_fwd", "combine_bwd")
+    assert set(by_name) == {f"moe_rows/toy/{fill}/{m}" for fill in ("cell", "eighth", "full")
+                            for m in moved} | {"moe_rows/toy/ms_a_layer"}
+    for name, (ok, info) in by_name.items():
+        assert ok or name.endswith("ms_a_layer"), (name, info)
+
+
+@pytest.mark.parametrize("check", ("check_ssd", "check_moe_rows"))
+def test_the_check_is_one_of_the_groups_main_runs(check):
     import inspect
 
-    assert "check_ssd" in inspect.getsource(tpu_checks.main)
+    assert check in inspect.getsource(tpu_checks.main)
