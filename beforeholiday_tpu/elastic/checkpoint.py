@@ -19,7 +19,7 @@ generation. This module hides it:
   memory growth.
 
 Every stall is booked to the module's ``ckpt`` ledger so hidden-vs-exposed
-time is measurable with the existing overlap machinery:
+time is measurable:
 
 * training-thread phases (``submit``, ``backpressure``, ``wait``) are
   EXPOSED — the step loop was blocked for that long;
@@ -30,8 +30,8 @@ time is measurable with the existing overlap machinery:
   microsecond was spent waiting on the writer) — and ``hidden_fraction =
   hidden_s / background_s``. For the interval-exact view, run under
   ``monitor.timeline()``: each phase lands as a ``ckpt:<phase>`` span
-  (writer phases on their own thread row) and ``overlap_report`` classifies
-  ``ckpt:*`` as wire/stall time against the step's compute spans.
+  (writer phases on their own thread row) and ``monitor.goodput_report``
+  books the exposed ones as checkpoint stall against the step spans.
 
 The D2H payload is additionally booked to the comms ledger (site
 ``ckpt.snapshot``, tier ``host``), so ``comms_summary()`` shows checkpoint
@@ -84,7 +84,7 @@ _COUNTS = {"generations": 0, "bytes": 0}
 def _phase(name: str):
     """Time one ledger phase; mirror it as a ``ckpt:<name>`` span on the
     active timeline recorder (writer phases land on their own thread row, so
-    ``overlap_report`` sees checkpoint stall vs step compute exactly)."""
+    ``goodput_report`` tells checkpoint stall from step compute exactly)."""
     from beforeholiday_tpu.monitor.trace import active_recorder
 
     rec = active_recorder()

@@ -9,24 +9,30 @@ Split by which side of the device boundary each piece lives on:
   drain at a configurable cadence, one readback per logged step (JSONL / CSV
   / callback).
 * :mod:`beforeholiday_tpu.monitor.spans`    — trace spans and wall-clock
-  timers; a span names the device ops it encloses and marks the
-  profiler's host line.
+  timers; a span names the device ops it encloses, marks the profiler's
+  host line and books the host time under it; the collector's pauses.
 * :mod:`beforeholiday_tpu.monitor.counters` — queryable guard-dispatch
   hit/degrade counters, and the tile plan each traced flash kernel was
   built with (``tile_records``).
 * :mod:`beforeholiday_tpu.monitor.comms`    — trace-time collective-traffic
   ledger (op kind / axis / dtype / bytes / call-site, subsystem rollup).
 * :mod:`beforeholiday_tpu.monitor.trace`    — host timeline recorder +
-  Chrome-trace/Perfetto ``trace.json`` exporter (``timeline``).
+  Chrome-trace/Perfetto ``trace.json`` exporter (``timeline``), and the
+  always-on **host ledger** on the same clock (``host_records``): compile
+  phases and compile-cache traffic by jitted function, host time under
+  every span, collector pauses. For the operator: ``compile_summary()``
+  after set-up says which entry's trace, lowering or compile took the
+  seconds and whether the cache was warm; after a stall,
+  ``host_records()`` (or ``trace.json``, which carries the same events
+  while a timeline is on) says whether a collection or a compile lay in it.
 * :mod:`beforeholiday_tpu.monitor.compile`  — recompile sentinel
-  (``track_compiles``: count signatures per jitted entry, warn on storms).
+  (``track_compiles``: count signatures per jitted entry, warn on storms)
+  and the ``jax.monitoring`` listeners that feed the ledger its seconds.
 * :mod:`beforeholiday_tpu.monitor.memory`   — per-jit memory ledger
   (``track_memory``: AOT ``memory_analysis()`` bytes per entry/signature).
 * :mod:`beforeholiday_tpu.monitor.roofline` — roofline/MFU ledger
   (``track_costs``: AOT ``cost_analysis()`` FLOPs/bytes per entry joined
   with measured wall time; ``perf_report`` is the one-call rollup).
-* :mod:`beforeholiday_tpu.monitor.overlap`  — measured compute/comms
-  overlap fraction and cross-rank straggler skew over the timeline.
 * :mod:`beforeholiday_tpu.monitor.flight`   — crash flight recorder
   (ring buffer of drained steps, dumped on StepGuard rollback / crash).
 """
@@ -40,6 +46,9 @@ Split by which side of the device boundary each piece lives on:
 from beforeholiday_tpu.monitor.trace import (  # noqa: F401
     TraceRecorder,
     active_recorder,
+    host_records,
+    reset_host_ledger,
+    span_intervals,
     timeline,
 )
 from beforeholiday_tpu.monitor.spans import (  # noqa: F401
@@ -101,12 +110,6 @@ from beforeholiday_tpu.monitor.roofline import (  # noqa: F401
     roofline_summary,
     track_costs,
 )
-from beforeholiday_tpu.monitor.overlap import (  # noqa: F401
-    overlap_report,
-    rank_skew,
-    span_intervals,
-    straggler_report,
-)
 from beforeholiday_tpu.monitor.flight import (  # noqa: F401
     FlightRecorder,
     active_flight_recorder,
@@ -144,6 +147,7 @@ __all__ = [
     "get_chip_spec",
     "global_norm",
     "goodput_report",
+    "host_records",
     "join_spans",
     "ledger_scope",
     "measure_costs",
@@ -151,15 +155,14 @@ __all__ = [
     "memory_records",
     "memory_summary",
     "nvtx_range",
-    "overlap_report",
     "perf_report",
-    "rank_skew",
     "record_wall_time",
     "register_chip_spec",
     "reset_comms_ledger",
     "reset_compile_counts",
     "reset_counters",
     "reset_dispatch_counters",
+    "reset_host_ledger",
     "reset_memory_ledger",
     "reset_roofline_ledger",
     "roofline_records",
@@ -168,7 +171,6 @@ __all__ = [
     "span_intervals",
     "start_trace",
     "stop_trace",
-    "straggler_report",
     "tile_records",
     "timeline",
     "trace",

@@ -27,18 +27,30 @@ Wrap ABOVE ``jax.jit`` (the sentinel must see the concrete arguments, not
 tracers). Like the comms ledger, state is process-global and host-only;
 ``reset_compile_counts`` clears it (and re-arms the warning) between
 benchmark configurations.
+
+**The seconds.** The sentinel counts signatures; what a compilation COSTS the
+host comes from JAX itself. Importing this module registers, once a process,
+two ``jax.monitoring`` listeners that book every trace, lowering and backend
+compile (an executable loaded from the persistent cache, or compiled) and
+every compile-cache hit, miss and load into the host ledger
+(``monitor.host_records()``), under the jitted function's name and on the
+timeline's clock; ``compile_summary`` rolls them up by entry. The events fire
+only when JAX traces, lowers or compiles: a step served from the jit cache
+costs nothing here.
 """
 
 from __future__ import annotations
 
 import functools
 import threading
+import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from beforeholiday_tpu.monitor.trace import book, host_records, outermost
 from beforeholiday_tpu.utils.logging import reset_warn_once, warn_once
 
 __all__ = [
@@ -162,18 +174,38 @@ def compile_counts() -> Dict[str, Dict[str, int]]:
 
 
 def compile_summary() -> List[Dict[str, object]]:
-    """`dispatch_summary`-style rollup: one sorted row per tracked entry,
-    ``{"entry", "signatures", "calls", "recompiled"}``."""
-    counts = compile_counts()
-    return [
-        {
-            "entry": name,
-            "signatures": c["signatures"],
-            "calls": c["calls"],
-            "recompiled": c["signatures"] > 1,
-        }
-        for name, c in sorted(counts.items())
-    ]
+    """`dispatch_summary`-style rollup: one sorted row per entry, ``{"entry",
+    "signatures", "calls", "recompiled"}`` from the sentinel and, from the host
+    ledger, the seconds JAX spent on the jitted function of that name:
+    ``trace_s`` (of which ``trace_outer_s`` was not inside another function's
+    trace: sum THAT over entries), ``lower_s``, ``backend_s``, ``compiles``
+    (backend compiles or cache loads) and ``cache_hits`` / ``cache_misses``
+    (JAX raises a miss when it WRITES an entry: a program under the cache's
+    size or compile-time threshold is neither, so ``compiles - cache_hits`` is
+    what was compiled here). An entry only one side knows has zeros for the
+    other."""
+    rows: Dict[str, Dict[str, object]] = {}
+
+    def row(name):
+        return rows.setdefault(name, {
+            "entry": name, "signatures": 0, "calls": 0, "recompiled": False,
+            "trace_s": 0.0, "trace_outer_s": 0.0, "lower_s": 0.0,
+            "backend_s": 0.0, "compiles": 0, "cache_hits": 0, "cache_misses": 0})
+
+    for name, c in compile_counts().items():
+        row(name).update(signatures=c["signatures"], calls=c["calls"],
+                         recompiled=c["signatures"] > 1)
+    traces = []
+    for r in host_records():
+        if r["kind"] in _SECONDS:
+            row(r["name"])[_SECONDS[r["kind"]]] += (r["end"] - r["start"]) / 1e9
+        if r["kind"] in _COUNTS:
+            row(r["name"])[_COUNTS[r["kind"]]] += 1
+        if r["kind"] == "compile.trace":
+            traces.append(r)
+    for r in outermost(traces):
+        row(r["name"])["trace_outer_s"] += (r["end"] - r["start"]) / 1e9
+    return [rows[name] for name in sorted(rows)]
 
 
 def reset_compile_counts(entry: Optional[str] = None) -> None:
@@ -195,3 +227,81 @@ def reset_compile_counts(entry: Optional[str] = None) -> None:
             _ENTRIES.clear()
     for name in names:
         reset_warn_once((_WARN_PREFIX, name))
+
+
+# ------------------------------------------------- the host ledger's feeders
+_PHASES = {   # jax/_src/dispatch.py: each fires with ``fun_name=``
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+    "/jax/core/compile/backend_compile_duration": "compile.backend",
+}
+_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_EVENTS = {   # jax/_src/compiler.py, compilation_cache.py: no name
+    "/jax/compilation_cache/cache_hits": "cache.hit",
+    "/jax/compilation_cache/cache_misses": "cache.miss",
+}
+_SECONDS = {"compile.trace": "trace_s", "compile.lower": "lower_s",
+            "compile.backend": "backend_s"}
+_COUNTS = {"compile.backend": "compiles", "cache.hit": "cache_hits",
+           "cache.miss": "cache_misses"}
+
+
+class _Pending(threading.local):
+    """This thread's cache events that wait for their entry's name."""
+
+    def __init__(self):
+        self.events: list = []
+
+
+_PENDING = _Pending()
+
+
+def _on_event(event: str, **kw: Any) -> None:
+    kind = _CACHE_EVENTS.get(event)
+    if kind is not None:
+        now = time.perf_counter_ns()
+        _PENDING.events.append((kind, now, now))
+
+
+def _on_duration(event: str, duration: float, **kw: Any) -> None:
+    """End = receipt, start = end - duration. JAX names the cache's events
+    after nothing, but raises them inside the backend compile they belong to,
+    on its thread: they are booked under that entry's name when it ends."""
+    kind = _PHASES.get(event)
+    if kind is None and event != _CACHE_LOAD:
+        return
+    end = time.perf_counter_ns()
+    start = end - int(duration * 1e9)
+    if kind is None:
+        _PENDING.events.append(("cache.load", start, end))
+        return
+    name = str(kw.get("fun_name", ""))
+    if name.startswith("jit(") and name.endswith(")"):
+        name = name[4:-1]          # lowering and compiling say ``jit(step)``
+    if kind == "compile.backend":
+        waiting = _PENDING.events
+        for cache_kind, s, e in waiting:
+            book(cache_kind, name if s >= start else "", s, e)
+        del waiting[:]
+    book(kind, name, start, end)
+
+
+_on_event._host_ledger = _on_duration._host_ledger = True
+
+
+def _listen_once() -> None:
+    """Register the two listeners unless this process already has them (the
+    module imported under a second name, or reloaded)."""
+    from jax._src import monitoring
+
+    def missing(getter):
+        have = getattr(monitoring, getter, lambda: ())()
+        return not any(getattr(cb, "_host_ledger", False) for cb in have)
+
+    if missing("get_event_listeners"):
+        jax.monitoring.register_event_listener(_on_event)
+    if missing("get_event_duration_listeners"):
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+_listen_once()

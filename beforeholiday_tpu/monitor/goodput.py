@@ -9,7 +9,7 @@ those up into the number long runs are judged by: the fraction of wall time
 spent stepping vs everything that isn't a step.
 
 ``goodput_report`` is a pure host-side classifier over an explicit event
-list (mirror of ``overlap_report``): no recorder coupling, trivially
+list: no recorder coupling, trivially
 oracle-testable against a hand-constructed timeline. Classification is by
 *priority claiming* over integer-microsecond intervals — each category in
 turn claims the part of the wall not already claimed by a higher-priority
@@ -29,7 +29,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
-from .overlap import span_intervals
+from .trace import span_intervals
 
 __all__ = ["goodput_report", "classify_span"]
 

@@ -28,8 +28,7 @@ joins analytic work with measured time against a registrable
   point) or comms (recorded comms time dominates the step).
 
 :func:`perf_report` is the one-call rollup ``__graft_entry__`` prints:
-per-entry ``<entry>_mfu`` / ``<entry>_bw_util`` keys plus the overlap and
-straggler numbers from :mod:`beforeholiday_tpu.monitor.overlap` and the
+per-entry ``<entry>_mfu`` / ``<entry>_bw_util`` keys plus the
 dispatch/comms/compile summaries.
 
 Usage::
@@ -512,7 +511,7 @@ def join_spans(events: Optional[List[Dict[str, Any]]] = None) -> int:
         events = rec.events()
     with _LOCK:
         tracked = set(_ENTRIES)
-    from beforeholiday_tpu.monitor.overlap import span_intervals
+    from beforeholiday_tpu.monitor.trace import span_intervals
 
     joined = 0
     for iv in span_intervals(events):
@@ -638,18 +637,10 @@ def reset_roofline_ledger() -> None:
 
 
 # ---------------------------------------------------------------- the report
-def perf_report(
-    *,
-    chip: Union[ChipSpec, str, None] = None,
-    events: Optional[List[Dict[str, Any]]] = None,
-    step_span: str = "step",
-) -> Dict[str, Any]:
+def perf_report(*, chip: Union[ChipSpec, str, None] = None) -> Dict[str, Any]:
     """The one-call attribution rollup: roofline rows flattened into
-    ``<entry>_mfu`` / ``<entry>_bw_util`` keys, the measured
-    ``overlap_fraction`` and ``rank_skew_*`` from the timeline (``events``
-    defaults to the active trace recorder's), and the dispatch/comms/compile
+    ``<entry>_mfu`` / ``<entry>_bw_util`` keys, and the dispatch/comms/compile
     summaries (``tests/test_perf_attr.py`` pins the shape)."""
-    from beforeholiday_tpu.monitor import overlap as _overlap
     from beforeholiday_tpu.monitor.comms import comms_summary
     from beforeholiday_tpu.monitor.compile import compile_summary
     from beforeholiday_tpu.monitor.counters import dispatch_summary
@@ -665,29 +656,6 @@ def perf_report(
             report[f"{r['entry']}_mfu"] = round(r["mfu"], 6)
         if r["bw_util"] is not None:
             report[f"{r['entry']}_bw_util"] = round(r["bw_util"], 6)
-
-    if events is None:
-        from beforeholiday_tpu.monitor.trace import active_recorder
-
-        rec = active_recorder()
-        events = rec.events() if rec is not None else None
-    if events:
-        ov = _overlap.overlap_report(events, step_span=step_span)
-        report["overlap"] = {
-            "steps": len(ov["steps"]),
-            "comms_us": ov["comms_us"],
-            "hidden_us": ov["hidden_us"],
-            "exposed_us": ov["exposed_us"],
-        }
-        if ov["overlap_fraction"] is not None:
-            report["overlap_fraction"] = ov["overlap_fraction"]
-        stragglers = _overlap.straggler_report(events)
-        if stragglers:
-            worst = stragglers[0]
-            report["rank_skew_span"] = worst["name"]
-            report["rank_skew_us"] = worst["skew_us"]
-            report["rank_skew_rel"] = worst["skew_rel"]
-            report["stragglers"] = stragglers
 
     report["dispatch"] = dispatch_summary()
     report["comms"] = comms_summary()

@@ -15,6 +15,15 @@ by ``prof`` in DDP (apex/parallel/distributed.py:360-361). TPU equivalents:
   lands on the host plane's ``python`` line, on the device planes' clock.
   The scope names are an interface: the benchmark's per-layer metrics
   (``benchmark/layer_metrics/*.json``) match them in the device trace.
+  Every span also books the host time under it into the host ledger
+  (``monitor.host_records()``, kind ``span``): inside ``jit`` the body runs
+  when the function is TRACED, so that is what the scope and everything under
+  it cost set-up; on the host (``donate_step.call``) it is a step's dispatch.
+* the collector's pauses — a ``gc.callbacks`` entry, registered once when
+  this module is imported, books every collection of a millisecond or more
+  into the same ledger (kind ``gc``, name ``gc.gen<n>``) and counts the
+  shorter ones; while a profiler session is on each is a ``TraceAnnotation``
+  too, so that a pause lies on the host line beside the step it delayed.
 * ``Timers`` — host-side wall-clock timers whose device barrier is
   ``jax.block_until_ready`` on a token array (the ``cuda.synchronize``
   analogue). Between-steps tooling; never call inside a jitted step.
@@ -26,10 +35,15 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import gc
 import time
 from typing import Dict, Optional
 
 import jax
+
+# the full dotted path: the package attribute ``trace`` is rebound to THIS
+# module's profiler function, so only this form reliably reaches the submodule
+from beforeholiday_tpu.monitor.trace import GC_MIN_NS, active_recorder, book, tally
 
 __all__ = [
     "Timers",
@@ -53,18 +67,17 @@ def span(name: str, enabled: bool = True):
     if not enabled:
         yield
         return
-    # deferred, full-dotted-path import: the package attribute ``trace`` is
-    # rebound to THIS module's profiler function, so only the dotted form
-    # reliably reaches the submodule
-    from beforeholiday_tpu.monitor.trace import active_recorder
-
     rec = active_recorder()
-    with contextlib.ExitStack() as stack:
-        if rec is not None:
-            stack.enter_context(rec.span(name))
-        stack.enter_context(jax.profiler.TraceAnnotation(name))
-        stack.enter_context(jax.named_scope(name))
-        yield
+    start = time.perf_counter_ns()
+    try:
+        if rec is None:
+            with jax.profiler.TraceAnnotation(name), jax.named_scope(name):
+                yield
+        else:
+            with rec.span(name), jax.profiler.TraceAnnotation(name), jax.named_scope(name):
+                yield
+    finally:
+        book("span", name, start, time.perf_counter_ns())
 
 
 # the pre-monitor name; same contract, kept importable forever
@@ -84,6 +97,38 @@ def annotate(name: str):
         return wrapped
 
     return deco
+
+
+_GC_NAMES = ("gc.gen0", "gc.gen1", "gc.gen2")
+_gc_open = [0, None]       # the collection under way: its start, its annotation
+
+
+def _on_collection(phase, info):
+    """The ``gc.callbacks`` entry: ``start`` / ``stop`` of every collection,
+    on the host ledger's clock. It runs inside whatever allocation started
+    the collection, so it takes no lock and must not raise."""
+    if phase == "start":
+        if jax.profiler.TraceAnnotation.is_enabled():      # a profiler session is on
+            _gc_open[1] = jax.profiler.TraceAnnotation(_GC_NAMES[info["generation"]])
+            _gc_open[1].__enter__()
+        _gc_open[0] = time.perf_counter_ns()
+        return
+    end = time.perf_counter_ns()
+    start, annotation = _gc_open
+    _gc_open[:] = 0, None
+    if annotation is not None:
+        annotation.__exit__(None, None, None)
+    if not start:              # registered while a collection was under way
+        return
+    if end - start >= GC_MIN_NS:
+        book("gc", _GC_NAMES[info["generation"]], start, end, collected=info["collected"])
+    else:
+        tally("gc.short", _GC_NAMES[info["generation"]], start, end)
+
+
+_on_collection._host_ledger = True
+if not any(getattr(cb, "_host_ledger", False) for cb in gc.callbacks):
+    gc.callbacks.append(_on_collection)     # once a process, however imported
 
 
 def start_trace(log_dir: str, **kw) -> None:

@@ -7,9 +7,8 @@
 * ``perf_report`` joins recorded wall time into per-entry MFU that agrees
   with the directly-computed number (6·N·tokens over the peak)
   within 5% on a GPT proxy step;
-* ``overlap_report`` reproduces constructed-timeline oracles (full / none /
-  partial overlap, per-step weighting, cross-rank pid filtering) and
-  ``rank_skew`` on the 8-device CPU mesh matches numpy;
+* ``span_intervals`` rebuilds nested and per-rank spans from a constructed
+  timeline;
 * a forced StepGuard rollback trip, drained through TrainMonitor ->
   MetricsLogger -> FlightRecorder, dumps a structured JSON black box with
   the last-N snapshots and the loss-scale trajectory;
@@ -29,15 +28,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, PartitionSpec as P
-
-
-def shard_map(f=None, **kw):
-    kw.setdefault("check_vma", False)
-    if f is None:
-        return lambda g: jax.shard_map(g, **kw)
-    return jax.shard_map(f, **kw)
-
 
 from beforeholiday_tpu import monitor
 from beforeholiday_tpu.amp.scaler import LossScaler
@@ -66,11 +56,6 @@ def _fresh_perf_state():
     _reset()
     yield
     _reset()
-
-
-@pytest.fixture
-def data_mesh(devices8):
-    return Mesh(np.asarray(devices8).reshape(8), ("data",))
 
 
 class _Capture(logging.Handler):
@@ -352,7 +337,7 @@ class TestGPTProxyMFU:
 
 
 # -------------------------------------------------------------------------------
-# overlap: constructed-timeline oracles
+# span intervals: constructed-timeline oracles
 # -------------------------------------------------------------------------------
 
 
@@ -393,158 +378,6 @@ class TestSpanIntervals:
         ivs = monitor.span_intervals(events)
         assert len(ivs) == 2
         assert {iv["pid"] for iv in ivs} == {0, 1}
-
-
-class TestOverlapReport:
-    def test_full_overlap_is_one(self):
-        events = (
-            _span("step", 0.0, 100.0)
-            + _span("compute", 0.0, 100.0)
-            + _span("psum:ddp.grads", 20.0, 60.0)
-        )
-        rep = monitor.overlap_report(events)
-        assert rep["overlap_fraction"] == 1.0
-        assert rep["hidden_us"] == 40.0
-        assert rep["exposed_us"] == 0.0
-
-    def test_no_overlap_is_zero(self):
-        events = (
-            _span("step", 0.0, 100.0)
-            + _span("compute", 0.0, 50.0)
-            + _span("all_gather:tp.fwd", 50.0, 100.0)
-        )
-        rep = monitor.overlap_report(events)
-        assert rep["overlap_fraction"] == 0.0
-        assert rep["exposed_us"] == 50.0
-
-    def test_partial_overlap_oracle(self):
-        # comms [40, 100]: hidden under compute [0, 60] for 20us of 60
-        events = (
-            _span("step", 0.0, 100.0)
-            + _span("compute", 0.0, 60.0)
-            + _span("psum:grads", 40.0, 100.0)
-        )
-        rep = monitor.overlap_report(events)
-        np.testing.assert_allclose(rep["overlap_fraction"], 20.0 / 60.0)
-        (row,) = rep["steps"]
-        assert row["comms_us"] == 60.0
-        assert row["hidden_us"] == 20.0
-
-    def test_no_comms_reports_none(self):
-        events = _span("step", 0.0, 100.0) + _span("compute", 0.0, 100.0)
-        rep = monitor.overlap_report(events)
-        assert rep["overlap_fraction"] is None
-        assert rep["comms_us"] == 0.0
-
-    def test_multi_step_weighting(self):
-        # step 0: 10us comms fully hidden; step 1: 30us comms fully exposed
-        # -> weighted fraction 10/40, NOT the per-step mean 0.5
-        events = (
-            _span("step", 0.0, 100.0)
-            + _span("compute", 0.0, 100.0)
-            + _span("psum:a", 0.0, 10.0)
-            + _span("step", 200.0, 300.0)
-            + _span("psum:b", 200.0, 230.0)
-        )
-        rep = monitor.overlap_report(events)
-        np.testing.assert_allclose(rep["overlap_fraction"], 10.0 / 40.0)
-        assert len(rep["steps"]) == 2
-        assert rep["steps"][0]["overlap_fraction"] == 1.0
-        assert rep["steps"][1]["overlap_fraction"] == 0.0
-
-    def test_cross_rank_spans_filtered_by_step_pid(self):
-        # rank 1's comms must not leak into rank 0's step accounting
-        events = (
-            _span("step", 0.0, 100.0, pid=0)
-            + _span("compute", 0.0, 100.0, pid=0)
-            + _span("psum:mine", 0.0, 10.0, pid=0)
-            + _span("psum:other_rank", 0.0, 80.0, pid=1)
-        )
-        rep = monitor.overlap_report(events)
-        (row,) = rep["steps"]
-        assert row["comms_us"] == 10.0
-
-    def test_whole_trace_as_one_step_when_unnamed(self):
-        events = (
-            _span("compute", 0.0, 50.0) + _span("psum:x", 25.0, 50.0)
-        )
-        rep = monitor.overlap_report(events)
-        assert len(rep["steps"]) == 1
-        np.testing.assert_allclose(rep["overlap_fraction"], 1.0)
-
-    def test_custom_is_comms_predicate(self):
-        events = (
-            _span("step", 0.0, 100.0)
-            + _span("wire_time", 0.0, 40.0)
-            + _span("math", 0.0, 100.0)
-        )
-        rep = monitor.overlap_report(
-            events, is_comms=lambda n: n == "wire_time"
-        )
-        assert rep["comms_us"] == 40.0
-        assert rep["overlap_fraction"] == 1.0
-
-
-class TestStragglerReport:
-    def test_skew_oracle_and_ordering(self):
-        events = (
-            _span("fwd", 0.0, 100.0, pid=0)
-            + _span("fwd", 0.0, 130.0, pid=1)
-            + _span("fwd", 0.0, 110.0, pid=2)
-            + _span("bwd", 0.0, 200.0, pid=0)
-            + _span("bwd", 0.0, 205.0, pid=1)
-        )
-        rows = monitor.straggler_report(events)
-        assert [r["name"] for r in rows] == ["fwd", "bwd"]  # worst first
-        fwd = rows[0]
-        assert fwd["ranks"] == 3
-        assert fwd["max_rank"] == 1
-        np.testing.assert_allclose(fwd["skew_us"], 30.0)
-        mean = (100.0 + 130.0 + 110.0) / 3
-        np.testing.assert_allclose(fwd["skew_rel"], 30.0 / mean)
-
-    def test_single_rank_spans_excluded(self):
-        events = _span("solo", 0.0, 10.0, pid=0)
-        assert monitor.straggler_report(events) == []
-
-    def test_repeated_spans_sum_per_rank(self):
-        events = (
-            _span("fwd", 0.0, 10.0, pid=0) + _span("fwd", 20.0, 30.0, pid=0)
-            + _span("fwd", 0.0, 15.0, pid=1)
-        )
-        (row,) = monitor.straggler_report(events)
-        np.testing.assert_allclose(row["max_us"], 20.0)  # 10 + 10
-        np.testing.assert_allclose(row["skew_us"], 5.0)
-
-
-class TestRankSkewDevice:
-    def test_matches_numpy_oracle_on_mesh(self, data_mesh):
-        durs = np.full((8,), 10.0, np.float32)
-        durs[3] = 13.0
-
-        @jax.jit
-        @shard_map(mesh=data_mesh, in_specs=(P("data"),), out_specs=P())
-        def skew(d):
-            return monitor.rank_skew(jnp.squeeze(d), "data")
-
-        out = {k: float(np.asarray(v))
-               for k, v in jax.device_get(skew(jnp.asarray(durs))).items()}
-        np.testing.assert_allclose(out["mean"], durs.mean(), rtol=1e-6)
-        np.testing.assert_allclose(out["max"], 13.0)
-        np.testing.assert_allclose(out["min"], 10.0)
-        np.testing.assert_allclose(out["skew"], 3.0)
-        np.testing.assert_allclose(
-            out["skew_rel"], 3.0 / durs.mean(), rtol=1e-6)
-
-    def test_traffic_lands_in_comms_ledger(self, data_mesh):
-        @jax.jit
-        @shard_map(mesh=data_mesh, in_specs=(P("data"),), out_specs=P())
-        def skew(d):
-            return monitor.rank_skew(jnp.squeeze(d), "data")
-
-        jax.block_until_ready(skew(jnp.ones((8,), jnp.float32)))
-        sites = {r["site"] for r in monitor.comms_records()}
-        assert "monitor.rank_skew" in sites
 
 
 # -------------------------------------------------------------------------------

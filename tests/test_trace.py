@@ -382,8 +382,9 @@ class TestPerfettoMetadata:
         assert ivs_with == ivs_without
 
     def test_exported_cross_rank_trace_round_trips_to_analyzers(self, tmp_path):
-        """The exported JSON is the overlap/straggler engines' input format:
-        nesting stays valid per rank and the spans reconstruct exactly."""
+        """The exported JSON is the analyzers' input format (``span_intervals``,
+        goodput, ``join_spans``): nesting stays valid per rank and the spans
+        reconstruct exactly."""
         rec = self._cross_rank_trace()
         path = tmp_path / "trace.json"
         rec.export(str(path))
@@ -401,9 +402,6 @@ class TestPerfettoMetadata:
         assert depths[(0, "step")] == 0
         assert depths[(1, "step")] == 0
         assert depths[(1, "psum:ddp.grads")] == 1
-        rows = monitor.straggler_report(events)
-        assert [r["name"] for r in rows] == ["step"]
-        assert rows[0]["ranks"] == 2
 
 
 # -------------------------------------------------------------------------------
