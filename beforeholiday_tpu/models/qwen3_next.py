@@ -45,7 +45,6 @@ import jax
 import jax.numpy as jnp
 
 from beforeholiday_tpu.models import layers as _layers
-from beforeholiday_tpu.models.layers import causal_depthwise_conv
 from beforeholiday_tpu.models.layers import COUNTERS  # noqa: F401  (the step's counters)
 from beforeholiday_tpu.monitor.spans import annotate as _annotate, span as _span
 from beforeholiday_tpu.remat import apply as _remat_apply
@@ -200,38 +199,33 @@ def rope_partial(x, rotary_dim: int, theta: float):
     return _layers.apply_rotary(x, *_layers.rotary_table(x.shape[1], rotary_dim, theta))
 
 
-def _l2_normalize(x, eps=1e-6):
-    x = x.astype(_F32)
-    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
-
-
 @_annotate("linear_mixer")
 def gated_delta_net(cfg: Qwen3NextConfig, x, p):
-    from beforeholiday_tpu.ops import fused_rms_norm
+    """Everything between the two projections and the delta rule is
+    ``ops.deltanet``'s two passes (Pallas kernels on the TPU at head dims of 128,
+    the ``jnp`` chain elsewhere). The convolved columns and ``z`` are two
+    products over column ranges of ``w_qkvz`` — a weight slice is 50 MB where an
+    activation slice, and the pad that is its transpose, were 201 — the former
+    by key head, the order ``deltanet_qkv`` reads."""
+    from beforeholiday_tpu.ops.deltanet import by_key_head, deltanet_gate, deltanet_qkv
     from beforeholiday_tpu.ops.gated_delta import gated_delta_rule
 
-    B, S, _ = x.shape
     Hk, Hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
     dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    heads = dict(key_heads=Hk, value_heads=Hv, d_k=dk, d_v=dv)
     dt = x.dtype
-    qkvz = x @ p["w_qkvz"].astype(dt)
-    ba = jnp.dot(x, p["w_ba"].astype(dt), preferred_element_type=_F32)
+    w = p["w_qkvz"].astype(dt)
     n_conv = 2 * Hk * dk + Hv * dv
-    qkv = jax.nn.silu(causal_depthwise_conv(qkvz[..., :n_conv], p["conv"]))
-    z = qkvz[..., n_conv:].reshape(B, S, Hv, dv)
-    q = qkv[..., :Hk * dk].reshape(B, S, Hk, dk)
-    k = qkv[..., Hk * dk:2 * Hk * dk].reshape(B, S, Hk, dk)
-    v = qkv[..., 2 * Hk * dk:].reshape(B, S, Hv, dv)
-    q = (_l2_normalize(q) * dk ** -0.5).astype(dt)
-    k = _l2_normalize(k).astype(dt)
-    if Hv != Hk:                       # each key head serves Hv / Hk value heads
-        q, k = (jnp.repeat(t, Hv // Hk, axis=2) for t in (q, k))
+    cols = x @ by_key_head(w[:, :n_conv], axis=1, **heads)
+    z = x @ w[:, n_conv:]
+    ba = jnp.dot(x, p["w_ba"].astype(dt), preferred_element_type=_F32)
+    q, k, v = deltanet_qkv(cols, by_key_head(p["conv"], axis=0, **heads), **heads)
     beta = jax.nn.sigmoid(ba[..., :Hv])
     g = -jnp.exp(p["a_log"].astype(_F32)) * jax.nn.softplus(
         ba[..., Hv:] + p["dt_bias"].astype(_F32))
-    o = gated_delta_rule(q, k, v, g, beta, chunk=cfg.gated_delta_chunk)
-    o = fused_rms_norm(o, p["out_norm"].astype(_F32), eps=cfg.rms_norm_eps)
-    o = (o * jax.nn.silu(z)).reshape(B, S, Hv * dv)
+    o = gated_delta_rule(q, k, v, jnp.moveaxis(g, 2, 1), jnp.moveaxis(beta, 2, 1),
+                         chunk=cfg.gated_delta_chunk, heads_first=True)
+    o = deltanet_gate(o, z, p["out_norm"].astype(_F32), eps=cfg.rms_norm_eps)
     return o @ p["w_out"].astype(dt)
 
 

@@ -12,6 +12,9 @@
 * ``attention`` — Pallas flash attention (``fmhalib``, ``fast_multihead_attn``).
 * ``gated_delta`` — the gated delta rule of Gated DeltaNet linear attention,
   chunk-wise, with a Pallas chunk scan (no reference equivalent).
+* ``deltanet`` — a gated-DeltaNet layer outside its recurrence as two fused
+  Pallas passes, each with its backward kernel: convolution, SiLU, L2 norms,
+  the key heads' repetition and the heads-first layout; the gated output norm.
 * ``ssd`` (``ops.state_space_dual`` is ``ops.ssd.ssd``) — the state-space dual
   of a Mamba-2 mixer, chunk-wise: ``B`` and ``C`` shared by a group's heads, a
   decay a head, the state carried across chunks in VMEM by Pallas kernels
@@ -68,6 +71,7 @@ from .attention import (  # noqa: F401
     self_attention,
 )
 from .gated_delta import gated_delta_rule  # noqa: F401
+from .deltanet import deltanet_gate, deltanet_qkv  # noqa: F401
 from .ssd import ssd as state_space_dual  # noqa: F401  (``ops.ssd`` stays the module)
 from .quantized import (  # noqa: F401
     quantized_matmul,

@@ -594,19 +594,28 @@ def gated_delta_rule(
     *,
     chunk: int = DEFAULT_CHUNK,
     impl: Optional[str] = None,
+    heads_first: bool = False,
 ) -> jax.Array:
     """The gated delta rule over whole sequences, state zero at the start.
 
     ``q, k``: ``(B, S, H, d_k)`` (scaled and normalised by the caller; a key
-    head that serves several value heads is repeated by the caller), ``v``:
-    ``(B, S, H, d_v)``, ``g`` (the log of the decay, <= 0) and ``beta``:
+    head that serves several value heads arrives once for each of them: the
+    caller repeats it, or writes it so, as ``ops.deltanet.deltanet_qkv`` does),
+    ``v``: ``(B, S, H, d_v)``, ``g`` (the log of the decay, <= 0) and ``beta``:
     ``(B, S, H)``. Returns ``o (B, S, H, d_v)`` in ``v``'s dtype. Matmul
     operands keep the input dtype, accumulation and the state are float32.
+
+    ``heads_first``: the operands are ``(B, H, S, d)`` and ``(B, H, S)`` and so is
+    ``o``, which is how the kernels read and write them: a caller that holds them
+    so spares the two re-layouts.
 
     A sequence that is not a multiple of ``chunk`` is padded at its end with
     steps that leave the state alone (``beta = 0``, ``g = 0``) and whose
     outputs are cut off."""
-    B, S, H, dk = q.shape
+    if heads_first:
+        B, H, S, dk = q.shape
+    else:
+        B, S, H, dk = q.shape
     dv = v.shape[-1]
     if k.shape != q.shape or v.shape[:3] != q.shape[:3] or g.shape != q.shape[:3] \
             or beta.shape != g.shape:
@@ -629,8 +638,10 @@ def gated_delta_rule(
     N = (S + pad) // chunk
 
     def chunks(t):
-        """(B, S, H, ...) -> (B*H, N, C, ...), the tail padded with zeros."""
-        t = jnp.moveaxis(t, 2, 1)
+        """(B, S, H, ...) or (B, H, S, ...) -> (B*H, N, C, ...), the tail padded
+        with zeros."""
+        if not heads_first:
+            t = jnp.moveaxis(t, 2, 1)
         if pad:
             t = jnp.pad(t, ((0, 0), (0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 3))
         return t.reshape(B * H, N, chunk, *t.shape[3:])
@@ -649,4 +660,4 @@ def gated_delta_rule(
         with _span("gated_delta_scan"):     # innermost: names the scan's kernels
             o = scan(*operands)
     o = o.reshape(B, H, N * chunk, dv)[:, :, :S]
-    return jnp.moveaxis(o, 1, 2)
+    return o if heads_first else jnp.moveaxis(o, 1, 2)

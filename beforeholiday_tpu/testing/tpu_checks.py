@@ -300,6 +300,85 @@ def check_wy_prepare(results: list) -> None:
           json.dumps({n: round(t, 3) for n, t in ms.items()}))
 
 
+def check_deltanet(results: list, S: int = 8192, Hk: int = 16) -> None:
+    """The DeltaNet layer's two fused passes (``ops.deltanet``), compiled, at the
+    Qwen cell's shape (8,192 rows, 16 key and 32 value heads of 128, a filter of
+    4, bfloat16): every output and cotangent against the jnp chain, and the time
+    a layer of each kernel at a few row tiles, the GB/s of the bytes it has to
+    move against 819. A fresh function each timing."""
+    from beforeholiday_tpu.ops import deltanet as dn
+
+    def check(name, cond, info=""):
+        results.append((f"deltanet/{name}", bool(cond), str(info)))
+
+    B, Hv, d, K = 1, 2 * Hk, 128, 4      # a smaller S / Hk is the CPU rehearsal
+    C = 2 * Hk * d + Hv * d
+    heads = dict(key_heads=Hk, value_heads=Hv, d_k=d, d_v=d)
+    bf = jnp.bfloat16
+    ks = jax.random.split(jax.random.PRNGKey(5), 9)
+    cols = jax.random.normal(ks[0], (B, S, C)).astype(bf)
+    filt = jax.random.uniform(ks[1], (C, K), jnp.float32, -0.5, 0.5)
+    cts = tuple(jax.random.normal(k, (B, Hv, S, d)).astype(bf) for k in ks[2:5])
+    o = jax.random.normal(ks[5], (B, Hv, S, d)).astype(bf)
+    z = jax.random.normal(ks[6], (B, S, Hv * d)).astype(bf)
+    w = 1.0 + 0.1 * jax.random.normal(ks[7], (d,))
+    dy = jax.random.normal(ks[8], (B, S, Hv * d)).astype(bf)
+
+    def qkv(impl):
+        def run(cols, filt, cts):
+            out, pull = jax.vjp(lambda c, f: dn.deltanet_qkv(c, f, impl=impl, **heads), cols, filt)
+            return out + pull(cts)
+        return functools.partial(jax.jit(run), cols, filt, cts)
+
+    def gate(impl):
+        def run(o, z, w, dy):
+            y, pull = jax.vjp(lambda *a: dn.deltanet_gate(*a, eps=1e-6, impl=impl), o, z, w)
+            return (y,) + pull(dy)
+        return functools.partial(jax.jit(run), o, z, w, dy)
+
+    both = {(op.__name__, impl): op(impl) for op in (qkv, gate) for impl in ("pallas", "jnp")}
+
+    # bfloat16 tensors a rounding or two apart (the chain rounds on its way); the
+    # two weight gradients are float32 sums over 8,192 rows of such terms
+    for op, names in (("qkv", ("q", "k", "v", "dcols", "dfilt")), ("gate", ("y", "do", "dz", "dw"))):
+        for name, a, b in zip(names, both[op, "pallas"](), both[op, "jnp"]()):
+            a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+            gap, scale = float(jnp.max(jnp.abs(a - b))), float(jnp.max(jnp.abs(b)))
+            ok = bool(jnp.all(jnp.isfinite(a))) and gap <= 2e-2 * scale
+            check(f"parity/{name}", ok, f"max|d|={gap:.3e} of {scale:.3e}")
+
+    filt8 = dn._filter_rows(filt)
+    w1 = w.reshape(1, d)
+    unit = S * Hv * d * 2                       # one (S, 4096) bfloat16 activation: 67 MB
+    # what each kernel must move, in such units: cols = 2, q / k / v / o / z / y = 1
+    must = {"qkv_fwd": 5, "qkv_bwd": 7, "gate_fwd": 3, "gate_bwd": 5}
+    ms = {}
+    for tile in (128, 256, 512, 1024, 2048):
+        if S % tile:
+            continue
+        p = dn._Plan(Hk, Hv // Hk, d, d, K, tile)
+        gate = dict(group=min(4, Hv), tile=tile, eps=1e-6)
+        calls = {      # a fresh function each: nothing is served from another plan's trace
+            "qkv_fwd": (lambda *a: dn._qkv_fwd(*a, p=p), (cols, filt8)),
+            "qkv_bwd": (lambda *a: dn._qkv_bwd(*a, p=p), (cols, filt8) + cts),
+            "gate_fwd": (lambda *a: dn._gate_fwd(*a, **gate), (o, z, w1)),
+            "gate_bwd": (lambda *a: dn._gate_bwd(*a, **gate), (o, z, w1, dy)),
+        }
+        for name, (fn, args) in calls.items():
+            fn = jax.jit(fn)
+            try:
+                t = _min_step_seconds(lambda _: fn(*args), None)
+            except Exception as e:  # noqa: BLE001 — a plan Mosaic refuses is a reading too
+                ms[f"{name}@{tile}"] = f"{type(e).__name__}: {str(e)[:80]}"
+                continue
+            ms[f"{name}@{tile}"] = [round(1e3 * t, 3), round(must[name] * unit / t / 1e9)]
+    check("ms_and_gbps_a_layer", True, json.dumps(ms))
+    chain = {f"{op}_{impl}": round(1e3 * _min_step_seconds(lambda _: fn(), None, steps=4), 3)
+             for (op, impl), fn in both.items()}
+    check("fwd_bwd_ms_a_layer", chain["qkv_pallas"] < chain["qkv_jnp"]
+          and chain["gate_pallas"] < chain["gate_jnp"], json.dumps(chain))
+
+
 # (tag, buffer rows, groups, K, N, rows in a group, the product's dtype)
 _GROUPED_SHAPES = (
     ("mellum_up", 24576, 16, 2304, 896, 16400, jnp.float32),
@@ -942,7 +1021,7 @@ def main() -> int:
 
     enable_compile_cache()
     results: list = []
-    for group in (check_flash_dropout, check_flash_tiles, check_wy_prepare,
+    for group in (check_flash_dropout, check_flash_tiles, check_wy_prepare, check_deltanet,
                   check_grouped_matmul, check_moe_rows, check_ssd, check_aliased_mt_kernels,
                   check_compiled_kernel_parity):
         try:

@@ -18,13 +18,17 @@ from jax.sharding import SingleDeviceSharding
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:      # noqa: BLE001 — no compiler here, nothing to hold to
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -166,3 +170,75 @@ def test_the_dropless_layer_compiles_for_the_chip_with_its_rows_moved_in_loops(
     assert "moe_dispatch)/while/body" not in one_shot.as_text()
     got, was = (c.memory_analysis().temp_size_in_bytes for c in (loops, one_shot))
     assert got <= was + 2 ** 20, (got / 2 ** 20, was / 2 ** 20)
+
+
+def test_the_deltanet_kernels_compile_for_the_chip(one_chip, monkeypatch):
+    """Both passes of ``ops/deltanet.py``, forward and backward, at the Qwen
+    cell's shapes: 8,192 rows, 16 key and 32 value heads of 128, a filter of 4."""
+    from beforeholiday_tpu.ops import deltanet as dn
+
+    monkeypatch.setattr(dn, "_interpret_default", lambda: False)
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    bf, f32 = jnp.bfloat16, jnp.float32
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    B, S, Hk, Hv, d, K = 1, 8192, 16, 32, 128, 4
+    C = 2 * Hk * d + Hv * d
+    heads = dict(key_heads=Hk, value_heads=Hv, d_k=d, d_v=d)
+
+    def both(cols, filt, cq, ck, cv, o, z, w, dy):
+        qkv, pull = jax.vjp(lambda c, f: dn.deltanet_qkv(c, f, impl="pallas", **heads), cols, filt)
+        y, pull_y = jax.vjp(lambda *a: dn.deltanet_gate(*a, eps=1e-6, impl="pallas"), o, z, w)
+        return qkv, pull((cq, ck, cv)), y, pull_y(dy)
+
+    heads_first, columns = shape((B, Hv, S, d), bf), shape((B, S, Hv * d), bf)
+    try:
+        text = jax.jit(both).lower(
+            shape((B, S, C), bf), shape((C, K), f32), heads_first, heads_first, heads_first,
+            heads_first, columns, shape((d,), f32), columns).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+    assert text.count("tpu_custom_call") == 4
+    for kernel in ("deltanet_qkv_fwd", "deltanet_qkv_bwd", "deltanet_gate_fwd", "deltanet_gate_bwd"):
+        assert kernel in text
+
+
+def test_the_qwen_step_compiled_for_the_chip_keeps_what_the_deltanet_kernels_gave(
+        topo, monkeypatch):
+    """The whole step of ``qwen3-next-80b-a3b.train-s8k`` compiled for a described
+    v5e (``tools/offline_step.py``; ~50 s, nothing runs). The cell stands at the
+    compiler's memory limit, and what a change asks for beyond it is paid in
+    rematerialisation, not in an error (PERF.md, PR 34). With the DeltaNet
+    layer's chain in two kernels (PR 37) the program is held to: the float32
+    logits are not computed twice; of the parent's 84 rematerialised ops 9 are
+    left (the three ``x @ w_cols`` products and the attention layer's q
+    projection); no pad, sum or copy of a ``[8192,12288]`` activation (the
+    parent cut z and the convolved columns out of one product and padded their
+    cotangents back into one) nor of the 134 MB ``[8192,8192]`` columns; and the
+    temporaries stay at the limit the compiler fills to (6.211 GiB; the parent's
+    program read 6.158 after rematerialising from 8.19, this one needs 6.59
+    with nothing rematerialised: both compiled for a v5p to see it)."""
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "tools"))
+    import offline_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")     # resolve_impl -> pallas
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled, _ = offline_step.compile_cell("qwen3-next-80b-a3b.train-s8k", topo)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+    text = compiled.as_text()
+    for kernel in ("deltanet_qkv_fwd", "deltanet_qkv_bwd", "deltanet_gate_fwd", "deltanet_gate_bwd"):
+        assert len(set(re.findall(rf"%{kernel}[.\d]* = ", text))) == 3, kernel
+    made = [l for l in text.splitlines() if re.match(r"\s*(ROOT )?%\S+ = ", l)]
+    remat = [l for l in made if re.match(r"\s*(ROOT )?%\S*\.remat\S* = ", l)]
+    assert not [l for l in remat if "f32[8192,18992]" in l or "f32[1,8192,18992]" in l]
+    assert len(remat) <= 12, len(remat)
+    mixer = [l for l in made if "linear_mixer" in l
+             and re.search(r" (pad|add|copy|concatenate|transpose)\(", l)]
+    wide = re.compile(r"= \S*\[(1,)?8192,(12288|8192)\]|= \S*\[1,8192,32,128\]")
+    assert not [l[:160] for l in mixer if wide.search(l)]
+    assert compiled.memory_analysis().temp_size_in_bytes <= 6.25 * 2 ** 30

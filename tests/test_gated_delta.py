@@ -84,6 +84,30 @@ def test_gradients_match_the_recurrence(impl, S, chunk):
         _close(a, b, f"d{name}, S={S}")
 
 
+@pytest.mark.parametrize("impl", ("pallas", "jnp"))
+@pytest.mark.parametrize("S,chunk", ((256, 128), (72, 64)), ids=("whole_chunks", "padded_tail"))
+def test_the_heads_first_entry_is_the_public_one_without_its_two_re_layouts(impl, S, chunk):
+    """``heads_first=True`` takes ``(B, H, S, d)`` and ``(B, H, S)`` and returns
+    ``(B, H, S, d_v)``: what ``ops.deltanet.deltanet_qkv`` writes and
+    ``deltanet_gate`` reads. Outputs and cotangents are bit-equal to the public
+    layout's: the same kernels on the same operands."""
+    args, ct = inputs(S, 2, S, 3, 128, 128)
+    first = lambda t: jnp.moveaxis(t, 2, 1)
+
+    def run(heads_first, args, ct):
+        o, pull = jax.vjp(lambda *a: gd.gated_delta_rule(
+            *a, chunk=chunk, impl=impl, heads_first=heads_first), *args)
+        return (o,) + pull(ct)
+
+    want = run(False, args, ct)
+    got = run(True, tuple(first(t) for t in args), first(ct))
+    assert got[0].shape == (2, 3, S, 128)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, first(w))
+    with pytest.raises(ValueError, match="shapes mismatch"):
+        gd.gated_delta_rule(first(args[0]), *args[1:], heads_first=True)
+
+
 def test_a_strong_decay_neither_overflows_nor_loses_the_answer():
     # log decay down to -20 a token: exp(-20 * 128) underflows to 0, and no
     # quotient of decays may be formed on the way

@@ -519,6 +519,102 @@ def test_the_ssd_kernels_carry_their_names_under_the_mixer_in_both_passes():
 
 
 # ---------------------------------------------------------------------------
+# the DeltaNet layer's two fused passes (PR 37): under the mixer, outside the
+# delta rule's scope, and named after neither it nor the norm
+# ---------------------------------------------------------------------------
+
+def test_the_deltanet_kernels_lie_under_the_mixer_and_outside_the_delta_rule(monkeypatch):
+    """``linear_mixer_ms`` reads ``linear_mixer``; ``gated_delta_ms`` every op whose
+    path holds ``gated_delta`` and ``gated_delta_roofline`` the kernels named
+    ``%gated_delta*`` against the recurrence's products alone; ``layer_norm_ms``
+    reads ``layer_norm``. The four new kernels lie under the first and under none
+    of the others, the forward ones under ``amp_forward`` and the backward ones
+    under ``amp_backward``; the delta rule's own four stay where they were."""
+    from beforeholiday_tpu.models import qwen3_next
+    from beforeholiday_tpu.ops import deltanet, gated_delta
+
+    monkeypatch.setattr(deltanet, "_resolve_impl", lambda impl: "pallas")
+    monkeypatch.setattr(gated_delta, "_resolve_impl", lambda impl: "pallas")
+    cfg = qwen3_next.Qwen3NextConfig(
+        hidden_size=64, linear_num_key_heads=1, linear_num_value_heads=2,
+        linear_key_head_dim=128, linear_value_head_dim=128, gated_delta_chunk=64,
+        dtype=jnp.bfloat16)
+    params = qwen3_next.init(jax.random.PRNGKey(0), cfg)
+    p = {k: v[0].astype(jnp.float32 if "norm" in k or k in ("a_log", "dt_bias")
+                        else jnp.bfloat16) for k, v in params["linear"].items()}
+    x = jnp.zeros((1, 128, cfg.hidden_size), jnp.bfloat16)
+    svag = amp.scaled_value_and_grad(
+        lambda p, x: jnp.sum(qwen3_next.gated_delta_net(cfg, x, p).astype(jnp.float32)),
+        LossScaler(loss_scale=1.0))
+    text = jax.jit(svag).lower(p, LossScaler(loss_scale=1.0).init(), x).compile().as_text()
+    names = set(re.findall(r'op_name="(jit\([^"]+)"', text))
+    new = {k: [n for n in names if f"/{k}/" in n or n.endswith(f"/{k}")]
+           for k in ("deltanet_qkv_fwd", "deltanet_qkv_bwd", "deltanet_gate_fwd",
+                     "deltanet_gate_bwd")}
+    assert all(new.values()), {k: len(v) for k, v in new.items()}
+    for k, found in new.items():
+        span = k.rsplit("_", 1)[0]
+        for n in found:
+            assert _pass_of(n) == ("amp_forward" if k.endswith("fwd") else "amp_backward"), (k, n)
+            assert re.search(rf"linear_mixer\)*/{span}\)*/jit\(_(?:qkv|gate)_(?:fwd|bwd)\)/{k}", n), n
+            assert "gated_delta" not in n and "layer_norm" not in n, n
+    under_mixer = [n for n in names if "linear_mixer" in n]
+    assert not [n for n in under_mixer if "layer_norm" in n]    # the norm is in the epilogue
+    for k in ("wy_prepare_fwd", "wy_prepare_bwd", "gated_delta_fwd", "gated_delta_bwd"):
+        found = [n for n in under_mixer if f"/{k}" in n]
+        assert found and all(re.search(r"linear_mixer\)*/gated_delta", n) for n in found), k
+    assert not [n for n in names if "deltanet" in n and "gated_delta" in n]
+    _, pats = _linear_split()       # and the ledger's split reads each op under the mixer once
+    for n in under_mixer:
+        assert sum(bool(pats[k].search(n)) for k in (
+            "gated_delta_ms", "linear_proj_ms", "linear_elementwise_ms")) == 1, n
+    for k in new:
+        assert all(pats["linear_elementwise_ms"].search(n) for n in new[k]), k
+
+
+def _linear_split():
+    """The patterns of ``gated_delta_ms``, ``linear_proj_ms``, ``linear_elementwise_ms``
+    and ``linear_mixer_ms`` (``benchmark/layer_metrics``: data files)."""
+    import json
+    import os
+
+    base = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "benchmark")
+
+    def pattern(name):
+        with open(os.path.join(base, "layer_metrics", f"{name}.json")) as f:
+            return re.compile(json.load(f)["pattern"])
+
+    return base, {n: pattern(n) for n in ("gated_delta_ms", "linear_proj_ms",
+                                          "linear_elementwise_ms", "linear_mixer_ms")}
+
+
+@pytest.mark.parametrize("fixture", ("qwen3-next-80b-a3b.train-s8k",
+                                     "qwen3-next-80b-a3b.train-s8k.pr37"))
+def test_the_two_new_metrics_and_the_delta_rule_partition_the_mixer_on_the_chips_names(fixture):
+    """On the names the chip printed before PR 37 (PR 26's fixture: the chain's
+    fusions) and since (the four kernels): every op under ``linear_mixer`` is
+    read by exactly one of ``gated_delta_ms``, ``linear_proj_ms`` and
+    ``linear_elementwise_ms``, so the three add up to ``linear_mixer_ms``; both
+    new ones read something on both generations of names; an op outside the
+    mixer is read by neither."""
+    import json
+    import os
+
+    base, pats = _linear_split()
+    with open(os.path.join(base, "tests", "fixtures", "tf_ops_qwen3_next", f"{fixture}.json")) as f:
+        ops = json.load(f)["ops"]
+    parts = ("gated_delta_ms", "linear_proj_ms", "linear_elementwise_ms")
+    total = {n: 0 for n in pats}
+    for name, ps in ops:
+        hit = [n for n in parts if pats[n].search(name)]
+        assert len(hit) == (1 if pats["linear_mixer_ms"].search(name) else 0), (name, hit)
+        for n in hit + (["linear_mixer_ms"] if hit else []):
+            total[n] += ps
+    assert all(total[n] > 0 for n in parts), total
+    assert sum(total[n] for n in parts) == total["linear_mixer_ms"]
+
+
+# ---------------------------------------------------------------------------
 # the sort's two sides as loops (PR 34): every op of a loop carries its span
 # ---------------------------------------------------------------------------
 

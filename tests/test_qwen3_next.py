@@ -148,7 +148,7 @@ def test_zero_centred_norm():
 def test_causal_depthwise_conv():
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 12, 6))
     w = jax.random.normal(jax.random.PRNGKey(4), (6, 4))
-    got = model.causal_depthwise_conv(x, w)
+    got = model._layers.causal_depthwise_conv(x, w)
     np.testing.assert_allclose(got, reference.causal_conv(x, w), rtol=1e-6, atol=1e-6)
     # against lax.conv: torch's Conv1d(groups=C, padding=K-1) cut to the length
     want = jax.lax.conv_general_dilated(
@@ -156,7 +156,7 @@ def test_causal_depthwise_conv():
         precision="highest").transpose(0, 2, 1)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
     later = x.at[:, 7:].set(0.0)          # causal: the past does not see the future
-    np.testing.assert_array_equal(model.causal_depthwise_conv(later, w)[:, :7], got[:, :7])
+    np.testing.assert_array_equal(model._layers.causal_depthwise_conv(later, w)[:, :7], got[:, :7])
 
 
 @pytest.mark.parametrize("impl", ("pallas", "jnp"))
@@ -180,6 +180,68 @@ def test_gated_delta_net_layer():
     mp = reference._group(w, "linear.1")
     x = jax.random.normal(jax.random.PRNGKey(8), (2, 40, 64))
     got = model.gated_delta_net(family.model_config(cfg), x, mp)
+    want = reference.gated_delta_net(x, mp, cfg, "float32")
+    assert float(jnp.max(jnp.abs(got - want))) <= 2e-5 * float(jnp.max(jnp.abs(want)))
+
+
+# the kernels of ``ops/deltanet.py`` take head dims of 128 and whole tiles of 16
+# rows: the small model at those, three tiles a sequence
+_KERNEL_CFG = dict(CFG, linear_key_head_dim=128, linear_value_head_dim=128, seq_len=48)
+_KERNEL_GRADS = {}
+
+
+def _with_the_deltanet_kernels(fn, layers=3):
+    """``fn()`` with ``ops.deltanet`` dispatching its kernels (under the
+    interpreter here) where the CPU would take the chain; ``layers`` DeltaNet
+    layers in what ``fn`` traces, and ``guard.dispatch`` counts each."""
+    from beforeholiday_tpu.guard import dispatch
+    from beforeholiday_tpu.ops import deltanet
+
+    resolve = deltanet._resolve_impl
+    deltanet._resolve_impl = lambda impl: "pallas"
+    dispatch.reset_dispatch_counters()
+    try:
+        out = fn()
+    finally:
+        deltanet._resolve_impl = resolve
+    counted = {k[0]: v for k, v in dispatch.dispatch_counters().items()}
+    for op in ("deltanet_qkv", "deltanet_gate"):
+        assert (counted[op]["pallas"], counted[op]["jnp"]) == (layers, 0), (op, counted[op])
+    return out
+
+
+def _kernel_and_chain():
+    if not _KERNEL_GRADS:
+        w, batch = _weights(_KERNEL_CFG), _batch(_KERNEL_CFG)
+        both = lambda: jax.jit(jax.value_and_grad(       # a fresh function each: its own trace
+            lambda w: _program_loss(w, batch, _KERNEL_CFG)))(w)
+        _KERNEL_GRADS["chain"] = both()
+        _KERNEL_GRADS["kernel"] = _with_the_deltanet_kernels(both)
+    return _KERNEL_GRADS["kernel"], _KERNEL_GRADS["chain"]
+
+
+def test_the_loss_is_the_same_with_the_deltanet_kernels_and_with_the_chain():
+    (got, _), (want, _) = _kernel_and_chain()
+    assert abs(float(got) - float(want)) <= 2e-6 * abs(float(want)), (got, want)
+
+
+@pytest.mark.parametrize("leaf", sorted(family.weight_shapes(CFG)))
+def test_every_gradient_leaf_is_the_same_with_the_deltanet_kernels_and_with_the_chain(leaf):
+    (_, got), (_, want) = _kernel_and_chain()
+    got, want = got[leaf], want[leaf]
+    scale = float(jnp.max(jnp.abs(want)))
+    assert scale > 0, f"{leaf}: the chain's gradient is all zero"
+    assert float(jnp.max(jnp.abs(got - want))) <= 1e-3 * scale, leaf
+
+
+def test_the_layer_with_the_deltanet_kernels_matches_the_reference():
+    cfg = dict(_KERNEL_CFG)
+    w = _weights(cfg, seed=7)
+    mp = reference._group(w, "linear.1")
+    x = jax.random.normal(jax.random.PRNGKey(8), (2, 48, 64))
+    got = _with_the_deltanet_kernels(
+        lambda: jax.jit(lambda x: model.gated_delta_net(family.model_config(cfg), x, mp))(x),
+        layers=1)
     want = reference.gated_delta_net(x, mp, cfg, "float32")
     assert float(jnp.max(jnp.abs(got - want))) <= 2e-5 * float(jnp.max(jnp.abs(want)))
 
