@@ -95,12 +95,12 @@ def route_topk(
 
 def route_sigmoid(
     x: jax.Array, w_router: jax.Array, top_k: int, *, bias: Optional[jax.Array] = None,
-    scale: float = 1.0, renormalize: bool = True
+    scale: float = 1.0, renormalize: bool = True, eps: float = 1e-20
 ) -> Tuple[jax.Array, jax.Array]:
     """``(weights (T, k) float32, expert ids (T, k) int32)``: each of the
     router's outputs through a sigmoid in float32; the ``top_k`` largest of
     ``score + bias`` (``bias (E,)``: it enters the choice only, so it has no
-    gradient); the chosen *scores* divided by their sum (plus 1e-20) where
+    gradient); the chosen *scores* divided by their sum (plus ``eps``) where
     ``renormalize``, times ``scale``."""
     logits = jnp.dot(x, w_router.astype(x.dtype), preferred_element_type=_F32)
     scores = jax.nn.sigmoid(logits)
@@ -111,7 +111,7 @@ def route_sigmoid(
     chosen = idx[..., None] == jnp.arange(scores.shape[-1], dtype=idx.dtype)
     weights = jnp.sum(jnp.where(chosen, scores[..., None, :], 0.0), axis=-1)
     if renormalize:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + eps)
     return weights * scale, idx.astype(jnp.int32)
 
 

@@ -15,6 +15,9 @@
 * ``deltanet`` — a gated-DeltaNet layer outside its recurrence as two fused
   Pallas passes, each with its backward kernel: convolution, SiLU, L2 norms,
   the key heads' repetition and the heads-first layout; the gated output norm.
+* ``short_conv`` — the double-gated short convolution of an LFM2 mixer
+  (``C * conv_K(B * x~)``, no activation) as one fused Pallas pass forward and one
+  backward, the filter's gradient accumulated over the grid.
 * ``ssd`` (``ops.state_space_dual`` is ``ops.ssd.ssd``) — the state-space dual
   of a Mamba-2 mixer, chunk-wise: ``B`` and ``C`` shared by a group's heads, a
   decay a head, the state carried across chunks in VMEM by Pallas kernels
@@ -72,6 +75,7 @@ from .attention import (  # noqa: F401
 )
 from .gated_delta import gated_delta_rule  # noqa: F401
 from .deltanet import deltanet_gate, deltanet_qkv  # noqa: F401
+from .short_conv import gated_short_conv  # noqa: F401
 from .ssd import ssd as state_space_dual  # noqa: F401  (``ops.ssd`` stays the module)
 from .quantized import (  # noqa: F401
     quantized_matmul,

@@ -379,6 +379,63 @@ def check_deltanet(results: list, S: int = 8192, Hk: int = 16) -> None:
           and chain["gate_pallas"] < chain["gate_jnp"], json.dumps(chain))
 
 
+def check_short_conv(results: list, S: int = 8192, D: int = 2048) -> None:
+    """The double-gated short convolution (``ops.short_conv``), compiled, at the
+    LFM2 cell's shape (8,192 rows, 2,048 channels, three taps, bfloat16): the
+    output and both cotangents against the jnp chain, the time a layer of each
+    kernel at a few row tiles with the GB/s of the bytes it has to move against
+    819, and forward + backward a layer beside the chain's. A fresh function
+    each timing."""
+    from beforeholiday_tpu.ops import short_conv as sc
+
+    def check(name, cond, info=""):
+        results.append((f"short_conv/{name}", bool(cond), str(info)))
+
+    B, K, bf = 1, 3, jnp.bfloat16                 # a smaller S / D is the CPU rehearsal
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    bcx = jax.random.normal(ks[0], (B, S, 3 * D)).astype(bf)
+    w = jax.random.uniform(ks[1], (D, K), jnp.float32, -0.577, 0.577).astype(bf)
+    dy = jax.random.normal(ks[2], (B, S, D)).astype(bf)
+
+    def both(impl):
+        def run(bcx, w, dy):
+            y, pull = jax.vjp(lambda a, f: sc.gated_short_conv(a, f, impl=impl), bcx, w)
+            return (y,) + pull(dy)
+        return functools.partial(jax.jit(run), bcx, w, dy)
+
+    runs = {impl: both(impl) for impl in ("pallas", "jnp")}
+    # bfloat16 tensors a rounding or two apart (the chain rounds z and the
+    # convolution); dw is a sum over 8,192 rows of such terms, rounded to bfloat16
+    for name, a, b in zip(("y", "dbcx", "dw"), runs["pallas"](), runs["jnp"]()):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        gap, scale = float(jnp.max(jnp.abs(a - b))), float(jnp.max(jnp.abs(b)))
+        ok = bool(jnp.all(jnp.isfinite(a))) and gap <= 3e-2 * scale
+        check(f"parity/{name}", ok, f"max|d|={gap:.3e} of {scale:.3e}")
+
+    w8 = sc._filter_rows(w)
+    unit = B * S * D * 2                            # one (S, D) bfloat16 activation
+    must = {"fwd": 4, "bwd": 7}                     # bcx = 3, y / dy = 1, dbcx = 3
+    ms = {}
+    for tile in (64, 128, 256, 512):
+        if S % tile:
+            continue
+        p = sc._Plan(D, K, tile)
+        calls = {"fwd": (lambda *a: sc._fwd(*a, p=p), (bcx, w8)),
+                 "bwd": (lambda *a: sc._bwd(*a, p=p), (bcx, w8, dy))}
+        for name, (fn, args) in calls.items():
+            fn = jax.jit(fn)                        # a fresh function each
+            try:
+                t = _min_step_seconds(lambda _: fn(*args), None)
+            except Exception as e:  # noqa: BLE001 — a plan Mosaic refuses is a reading too
+                ms[f"{name}@{tile}"] = f"{type(e).__name__}: {str(e)[:80]}"
+                continue
+            ms[f"{name}@{tile}"] = [round(1e3 * t, 3), round(must[name] * unit / t / 1e9)]
+    check("ms_and_gbps_a_layer", True, json.dumps(ms))
+    chain = {impl: round(1e3 * _min_step_seconds(lambda _: fn(), None, steps=4), 3)
+             for impl, fn in runs.items()}
+    check("fwd_bwd_ms_a_layer", chain["pallas"] < chain["jnp"], json.dumps(chain))
+
+
 # (tag, buffer rows, groups, K, N, rows in a group, the product's dtype)
 _GROUPED_SHAPES = (
     ("mellum_up", 24576, 16, 2304, 896, 16400, jnp.float32),
@@ -1022,7 +1079,7 @@ def main() -> int:
     enable_compile_cache()
     results: list = []
     for group in (check_flash_dropout, check_flash_tiles, check_wy_prepare, check_deltanet,
-                  check_grouped_matmul, check_moe_rows, check_ssd, check_aliased_mt_kernels,
+                  check_short_conv, check_grouped_matmul, check_moe_rows, check_ssd, check_aliased_mt_kernels,
                   check_compiled_kernel_parity):
         try:
             group(results)

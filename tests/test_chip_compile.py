@@ -51,8 +51,10 @@ def compiled_not_interpreted(monkeypatch):
     (1000, 4, 128, 256, jnp.float32),           # a buffer that ends inside a row tile
     (8192, 8, 1024, 2688, jnp.float32),         # the Nemotron cell, up (in the latent)
     (8192, 8, 2688, 1024, jnp.bfloat16),        # ... down
+    (12288, 8, 2048, 1792, jnp.float32),        # the LFM2 cell, gate / up: 14 lane tiles
+    (12288, 8, 1792, 2048, jnp.bfloat16),       # ... down
 ), ids=("mellum_up", "mellum_down", "qwen_up", "qwen_down", "ragged_buffer", "nemotron_up",
-        "nemotron_down"))
+        "nemotron_down", "lfm2_up", "lfm2_down"))
 def test_the_grouped_matmul_kernels_compile_for_the_chip(one_chip, compiled_not_interpreted,
                                                          R, E, K, N, out):
     gm = compiled_not_interpreted
@@ -242,3 +244,32 @@ def test_the_qwen_step_compiled_for_the_chip_keeps_what_the_deltanet_kernels_gav
     wide = re.compile(r"= \S*\[(1,)?8192,(12288|8192)\]|= \S*\[1,8192,32,128\]")
     assert not [l[:160] for l in mixer if wide.search(l)]
     assert compiled.memory_analysis().temp_size_in_bytes <= 6.25 * 2 ** 30
+
+
+@pytest.mark.parametrize("batch,S,D,K", ((1, 8192, 2048, 3), (2, 1024, 256, 4), (1, 48, 128, 8)),
+                         ids=("lfm2_mixer", "two_sequences_four_taps", "tiles_of_16_rows"))
+def test_the_short_conv_kernels_compile_for_the_chip(one_chip, monkeypatch, batch, S, D, K):
+    """Both passes of ``ops/short_conv.py`` at the LFM2 cell's shape (8,192 rows,
+    2,048 channels, three taps: 128-row tiles at the full width of 6,144 columns)
+    and at two others."""
+    from beforeholiday_tpu.ops import short_conv as sc
+
+    monkeypatch.setattr(sc, "_interpret_default", lambda: False)
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    bf = jnp.bfloat16
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def both(bcx, w, dy):
+        y, pull = jax.vjp(lambda a, f: sc.gated_short_conv(a, f, impl="pallas"), bcx, w)
+        return y, pull(dy)
+
+    try:
+        text = jax.jit(both).lower(
+            shape((batch, S, 3 * D), bf), shape((D, K), bf), shape((batch, S, D), bf)
+        ).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+    assert text.count("tpu_custom_call") == 2
+    for kernel in ("short_conv_fwd", "short_conv_bwd"):
+        assert kernel in text

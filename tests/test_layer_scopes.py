@@ -665,3 +665,97 @@ def test_every_op_of_the_row_loops_lies_under_its_span(request, names_of, suffix
     loose = [n for n in names if re.search(r"moe_(dispatch|combine)", n)
              and n.endswith("scatter-add") and "/while/body/" not in n.split("moe_")[-1]]
     assert not [n for n in loose if "moe_combine" in n], loose
+
+
+# ---------------------------------------------------------------------------
+# family lfm2_moe (PR 39): conv_mixer / short_conv, attn_mixer, dense_ffn and
+# the model's four scopes
+# ---------------------------------------------------------------------------
+
+_LFM2_MODEL = ("lfm2_embed", "lfm2_layers", "lfm2_head", "lfm2_loss")
+_LFM2_SCOPES = _LFM2_MODEL + (
+    "conv_mixer", "short_conv", "attn_mixer", "dense_ffn", "amp_forward", "amp_backward",
+    "amp_unscale", "fused_adam_step_flat", "layer_norm", "flash_attention", "moe_route",
+    "moe_dispatch", "moe_experts", "moe_combine")
+
+
+@pytest.fixture(scope="module")
+def lfm2_names():
+    """The distinct ``op_name`` of every op of the compiled tiny-lfm2-moe step."""
+    from benchmark import run as bench_run
+
+    cell = bench_run.load("workloads", "tiny-lfm2-moe.train")
+    run = bench_run.Cell(cell, bench_run.load("configs", cell["config"]), jax.devices()[:1])
+    run.start(7)
+    run.build()
+    compiled = run.program.step.jitted.lower(run.state, run.pool[0]).compile()
+    return sorted(set(re.findall(r'op_name="(jit\([^"]+)"', compiled.as_text())))
+
+
+@pytest.mark.parametrize("scope", _LFM2_SCOPES)
+def test_lfm2_scope_is_in_the_compiled_step(lfm2_names, scope):
+    assert any(scope in _scopes_of(n) or f"jvp({scope})" in n for n in lfm2_names), scope
+
+
+def test_lfm2_first_level_scopes_partition_the_step(lfm2_names):
+    first = ("amp_backward", "amp_unscale", "ddp_reduce_gradients",
+             "ddp_overlap_hook", "fused_adam_step_flat")
+    twice = [n for n in lfm2_names
+             if sum(s in n for s in first) + (_pass_of(n) == "amp_forward") > 1]
+    assert not twice
+    both = [n for n in lfm2_names if "amp_forward" in n and "amp_backward" in n]
+    assert all("amp_backward/transpose(amp_forward)" in n for n in both), both
+    for scope in _LFM2_MODEL:        # the model's scopes survive inside both passes
+        assert any(f"amp_forward/jvp({scope})" in n for n in lfm2_names), scope
+        assert any(_pass_of(n) == "amp_backward" and f"jvp({scope})" in n
+                   for n in lfm2_names), scope
+
+
+def test_lfm2_second_level_scopes_do_not_overlap(lfm2_names):
+    """An op is under one model scope at most, and under one of the two mixers,
+    the dense feed-forward part or the MoE at most; ``short_conv`` lies inside
+    ``conv_mixer``, ``flash_attention`` inside ``attn_mixer``, all inside
+    ``lfm2_layers``; no name of the family holds another family's metric
+    pattern."""
+    in_path = lambda s, n: any(s == part.strip("()").split("(")[-1] for part in _scopes_of(n))
+    for n in lfm2_names:
+        assert sum(f"({s})" in n or s in _scopes_of(n) for s in _LFM2_MODEL) <= 1, n
+        parts = [s for s in ("conv_mixer", "attn_mixer", "dense_ffn", "moe") if in_path(s, n)]
+        assert len(parts) <= 1, n
+        if parts and _pass_of(n):
+            assert "lfm2_layers" in n, n
+        if in_path("short_conv", n):
+            assert parts == ["conv_mixer"], n
+        if in_path("flash_attention", n):
+            assert parts == ["attn_mixer"], n
+        assert not re.search(r"gated_delta|ssd|window_mixer|full_mixer|ssm_mixer|moe_latent|"
+                             r"moe_shared", n), n
+    heavy = [n for n in lfm2_names if n.endswith("dot_general")]
+    assert heavy and not [n for n in heavy if _pass_of(n) is None]
+
+
+def test_the_short_conv_kernels_carry_their_names_under_the_mixer_in_both_passes():
+    """``short_conv_ms`` reads the ``short_conv`` scope, ``short_conv_roofline``
+    the kernels' own ``name=`` (the chip prints ``%short_conv_fwd.N``,
+    ``%short_conv_bwd.N``): the forward kernel lies under ``amp_forward``, the
+    backward one under ``amp_backward``, both under ``conv_mixer/short_conv``."""
+    from beforeholiday_tpu.models import lfm2_moe
+
+    cfg = lfm2_moe.Lfm2MoeConfig(hidden_size=128, num_hidden_layers=1, first_layer=22,
+                                 short_conv_impl="pallas", dtype=jnp.bfloat16)
+    params = lfm2_moe.init(jax.random.PRNGKey(0), cfg)
+    p = {k: params["layers"][0][k].astype(jnp.bfloat16) for k in ("w_in", "conv", "w_out")}
+    x = jnp.zeros((1, 96, cfg.hidden_size), jnp.bfloat16)
+    svag = amp.scaled_value_and_grad(
+        lambda p, x: jnp.sum(lfm2_moe.short_conv_mixer(cfg, x, p).astype(jnp.float32)),
+        LossScaler(loss_scale=1.0))
+    text = jax.jit(svag).lower(p, LossScaler(loss_scale=1.0).init(), x).compile().as_text()
+    names = set(re.findall(r'op_name="(jit\([^"]+)"', text))
+    kernels = {k: [n for n in names if f"/{k}/" in n] for k in ("short_conv_fwd", "short_conv_bwd")}
+    assert all(kernels.values()), {k: len(v) for k, v in kernels.items()}
+    for k, found in kernels.items():
+        want = "amp_forward" if k == "short_conv_fwd" else "amp_backward"
+        for n in found:
+            assert _pass_of(n) == want, (k, n)
+            assert re.search(rf"conv_mixer\)*/short_conv\)*/jit\(_(?:fwd|bwd)\)/{k}/", n), n
+            assert not re.search(r"layer_norm|flash_attention|grouped_matmul|/moe/", n), n

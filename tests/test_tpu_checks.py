@@ -107,3 +107,20 @@ def test_the_check_is_one_of_the_groups_main_runs(check):
     import inspect
 
     assert check in inspect.getsource(tpu_checks.main)
+
+
+def test_the_short_conv_check_runs_its_comparison():
+    """The chip check of ``ops.short_conv`` at a toy shape on the interpreter:
+    the output and both cotangents are compared with the chain's (the timings
+    are not judged here), and ``main`` runs the group."""
+    import inspect
+
+    results = []
+    tpu_checks.check_short_conv(results, S=128, D=128)
+    by_name = {name: (ok, info) for name, ok, info in results}
+    compared = ("parity/y", "parity/dbcx", "parity/dw")
+    assert set(by_name) == {f"short_conv/{k}" for k in
+                            compared + ("ms_and_gbps_a_layer", "fwd_bwd_ms_a_layer")}
+    for k in compared:
+        assert by_name[f"short_conv/{k}"][0], by_name
+    assert "check_short_conv" in inspect.getsource(tpu_checks.main)
