@@ -30,6 +30,14 @@ the window's lower edge crosses are walked as the diagonal's are.
 ``guard.dispatch.count_tiles`` books what each traced kernel's plan computes
 (``monitor.tile_records()``).
 
+The backward is one kernel or two by what the plan says carries over between
+grid steps (:func:`_fa_bwd_pallas`). Where a head is one block
+(``TilePlan.one_pass``: causal, S <= 1024 at D <= 128, S <= 512 above) nothing
+does, and ONE call recomputes the scores once and returns dq, dk and dv
+(booked as ``dqkv``). Everywhere else dq sums over key blocks and dk / dv over
+query blocks, two grid orders, so a dq call and a dkv call each recompute the
+scores (booked as ``dq`` and ``dkv``).
+
 Variable-length batches are expressed as per-sequence key lengths
 (``kv_lens``) rather than the reference's packed cu_seqlens: on TPU the
 padded-dense layout keeps shapes static for XLA while the kernel masks
@@ -45,6 +53,7 @@ GSPMD-partitionable) elsewhere; plus a shape gate like the reference's
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import Dict, NamedTuple, Optional
 
 import jax
@@ -127,8 +136,8 @@ def _window_block(seq_len: int, head_dim: int, window: int) -> int:
 
 
 class TilePlan(NamedTuple):
-    """The two-level schedule of one flash call, shared by the forward, dq and
-    dkv kernels so that all three agree on which tile is which.
+    """The two-level schedule of one flash call, shared by the forward and the
+    backward kernels so that all agree on which tile is which.
 
     The *block* (``bq`` x ``bk``) is what the grid hands a kernel: large, so
     that a head costs few grid steps and few copies. Inside a block the
@@ -166,10 +175,11 @@ class TilePlan(NamedTuple):
     @property
     def one_pass(self) -> bool:
         """A causal call whose block holds a whole row of the square (one
-        block a head): nothing carries over between grid steps, so a strip
-        goes from its scores to its result in one pass and the kernels leave
-        the VMEM accumulators alone — no init, no rescale, no final copy.
-        (A non-causal call keeps the body it had.)"""
+        block a head): nothing carries over between grid steps. The forward's
+        strips go from their scores to their result in one pass, the VMEM
+        accumulators left alone — no init, no rescale, no final copy — and the
+        backward is one kernel, not two (:func:`_fa_bwd_pallas`).
+        (A non-causal call keeps the bodies it had.)"""
         return self.causal and self.nq == 1
 
     def walk(self, by_cols: bool, diag: bool):
@@ -394,7 +404,7 @@ def _keep_mask(seed_ref, b, i, j, nq, nk, shape, keep_prob):
 
     The PRNG is RE-SEEDED per (batch*head, q-tile, k-tile) from the caller's
     seed plus a mixed tile id, then one (TQ, TK) draw is taken — so the
-    forward and BOTH backward kernels regenerate the exact same mask for a
+    forward and every backward kernel regenerate the exact same mask for a
     tile regardless of their different grid orders and walks, the same
     counter-per-block contract as Philox offsets in the reference."""
     block_id = (b * nq + i) * nk + j
@@ -717,8 +727,9 @@ def _fa_fwd_pallas(q, k, v, lens, causal, scale, interpret, rate=0.0, seed=None,
 
 
 # ---------------------------------------------------------------------------------
-# backward: dq kernel (grid BH, nq, nk) + dkv kernel (grid BH, nk, nq); both
-# recompute block scores from (q, k, lse) — flash-attention rematerialization
+# backward: dq kernel (grid BH, nq, nk) + dkv kernel (grid BH, nk, nq), or where
+# a head is one block the two in one (grid BH, 1, 1); all recompute block scores
+# from (q, k, lse) — flash-attention rematerialization
 # ---------------------------------------------------------------------------------
 
 
@@ -774,13 +785,11 @@ def _fa_dq_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
     dq_ref, dq_acc = rest
     b, i, j, step = _grid_ids(plan, False)
     lens = lens_ref[b] if has_lens else None
-    one_pass = plan.one_pass
     fill = _fill(plan)
 
-    if not one_pass:
-        @pl.when(step == 0)
-        def _init():
-            dq_acc[...] = jnp.zeros_like(dq_acc)
+    @pl.when(step == 0)
+    def _init():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
     def block(walk):  # phase by phase, as the forward
         panels = _panels(plan, False, walk, b, i, j, lens, seed_ref, rate)
@@ -796,17 +805,13 @@ def _fa_dq_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
             for pn, (s, dp, delta) in zip(panels, scores)]
         for pn, ds in zip(panels, grads):
             dq = _dot(ds.astype(k_ref.dtype), k_ref[0, pn.cols, :], (1, 0))
-            if one_pass:
-                dq_ref[0, pn.rows, :] = dq.astype(dq_ref.dtype)
-            else:
-                dq_acc[pn.rows, :] = lax.add(dq_acc[pn.rows, :], dq)
+            dq_acc[pn.rows, :] = lax.add(dq_acc[pn.rows, :], dq)
 
     _walk_block(plan, False, i, j, block, step)
 
-    if not one_pass:
-        @pl.when(step == _steps(plan) - 1)
-        def _final():
-            dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+    @pl.when(step == _steps(plan) - 1)
+    def _final():
+        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
 def _fa_dkv_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
@@ -817,14 +822,12 @@ def _fa_dkv_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
     # k block outer, q block inner
     b, i, j, step = _grid_ids(plan, True)
     lens = lens_ref[b] if has_lens else None
-    one_pass = plan.one_pass
     fill = _fill(plan)
 
-    if not one_pass:
-        @pl.when(step == 0)
-        def _init():
-            dk_acc[...] = jnp.zeros_like(dk_acc)
-            dv_acc[...] = jnp.zeros_like(dv_acc)
+    @pl.when(step == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
 
     def block(walk):  # as the dq kernel's; strips are key columns
         panels = _panels(plan, True, walk, b, i, j, lens, seed_ref, rate)
@@ -847,86 +850,167 @@ def _fa_dkv_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
             # rowsum term keeps the undropped p Jacobian — _panel_p_ds
             dv = _dot(z.astype(do.dtype), do, (0, 0))
             dk = _dot(ds.astype(q.dtype), q, (0, 0))
-            if one_pass:
-                dv_ref[0, pn.cols, :] = dv.astype(dv_ref.dtype)
-                dk_ref[0, pn.cols, :] = dk.astype(dk_ref.dtype)
-            else:
-                dv_acc[pn.cols, :] = lax.add(dv_acc[pn.cols, :], dv)
-                dk_acc[pn.cols, :] = lax.add(dk_acc[pn.cols, :], dk)
+            dv_acc[pn.cols, :] = lax.add(dv_acc[pn.cols, :], dv)
+            dk_acc[pn.cols, :] = lax.add(dk_acc[pn.cols, :], dk)
 
     _walk_block(plan, True, i, j, block, step)
 
-    if not one_pass:
-        @pl.when(step == _steps(plan, True) - 1)
-        def _final():
-            dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
-            dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+    @pl.when(step == _steps(plan, True) - 1)
+    def _final():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _fa_dqkv_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
+    """The whole backward of a head that is one block (``plan.one_pass``): the
+    dq kernel's row walk, with dk and dv taken from the same ``z`` and ``ds``
+    instead of from a second recompute of them. A strip's dq rows are final
+    when the strip is done. A key tile's dk and dv sum over every strip that
+    reaches it, in float32 and rounded once: the first of those strips stores
+    its share in VMEM, the ones between add theirs, the last adds and writes
+    the result out — the walk is static, so which strip is which is known
+    here, and nothing is zeroed first or copied out afterwards."""
+    lens_ref, seed_ref, refs = _kernel_scalars(refs, has_lens, rate)
+    (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref), rest = _bwd_refs(
+        refs, has_dlse)
+    dq_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
+    b, i, j, _ = _grid_ids(plan, False)
+    lens = lens_ref[b] if has_lens else None
+    fill = _fill(plan)
+    # the one block is on the diagonal, and at the window's lower edge if any
+    walk = plan.walk(False, True) if plan.window is None else plan.band_walk(False, 0)
+
+    panels = _panels(plan, False, walk, b, i, j, lens, seed_ref, rate)
+    delta = _row_delta(do_ref[0], o_ref[0])      # once for the block
+    # the key tiles each strip reaches, and the first and last strip to reach each
+    tiles = [range(pn.cols.start // plan.tk, pn.cols.stop // plan.tk) for pn in panels]
+    first = {c: n for n in reversed(range(len(panels))) for c in tiles[n]}
+    last = {c: n for n in range(len(panels)) for c in tiles[n]}
+
+    def recompute(pn):
+        do = do_ref[0, pn.rows, :]
+        return (_panel_scores(pn, q_ref, k_ref, scale, fill),
+                _dot(do, v_ref[0, pn.cols, :], (1, 1)), do)
+
+    # A strip's two score products are emitted one strip ahead of its three
+    # result products, so the MXU, which bounds this kernel (at D = 64 half of
+    # each pass is idle), has the next strip's scores to make while the vector
+    # units turn this strip's into p and ds. On a v5e at the GPT cells' call
+    # (S=1024, D=64; ms for 64 heads, the operands' copies included; PR 41):
+    # every phase for all strips first, as the forward has it, 0.628; strip
+    # after strip 0.619; this 0.611.
+    ahead = recompute(panels[0])
+    for n, pn in enumerate(panels):
+        s, dp, do = ahead
+        if n + 1 < len(panels):
+            ahead = recompute(panels[n + 1])
+        z, ds = _panel_p_ds(scale, s, dp, lse_ref[0, pn.rows, :], delta[pn.rows, :],
+                            dlse_ref[0, pn.rows, :] if has_dlse else None, pn, rate)
+        ds = ds.astype(k_ref.dtype)
+        dq = _dot(ds, k_ref[0, pn.cols, :], (1, 0))
+        dq_ref[0, pn.rows, :] = dq.astype(dq_ref.dtype)
+        # dv sees the DROPPED probabilities z, dk the score-grad ds, as in the
+        # dkv kernel
+        sums = ((dv_acc, dv_ref, _dot(z.astype(do.dtype), do, (0, 0))),
+                (dk_acc, dk_ref, _dot(ds, q_ref[0, pn.rows, :], (0, 0))))
+        for (new, done), run in itertools.groupby(
+                tiles[n], lambda c: (first[c] == n, last[c] == n)):
+            run = list(run)
+            cols = slice(run[0] * plan.tk, (run[-1] + 1) * plan.tk)
+            for acc, out, part in sums:
+                part = lax.slice_in_dim(part, cols.start - pn.cols.start,
+                                        cols.stop - pn.cols.start)
+                if not new:
+                    part = lax.add(acc[cols, :], part)
+                if done:
+                    out[0, cols, :] = part.astype(out.dtype)
+                else:
+                    acc[cols, :] = part
+
+
+def _bwd_call(body, kernel, plan, args, *, grid, in_specs, out_specs, out_like, scratch):
+    """One backward ``pallas_call`` of ``args`` (:func:`_fa_bwd_pallas`'s, from
+    ``q`` on): ``body`` over the scalars, the six operands — ``in_specs``, whose
+    last serves ``dlse`` too — the outputs shaped like ``out_like`` and float32
+    ``scratch``; books the plan's tiles under ``kernel``."""
+    *operands, dlse, lens, scale, interpret, rate, seed = args
+    has_dlse = dlse is not None
+    scalars = _scalar_operands(lens, seed, rate)
+    _book_tiles(plan, operands[0].shape[2], lens is not None, kernel)
+    return pl.pallas_call(
+        functools.partial(body, plan, scale, lens is not None, has_dlse, rate),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=grid,
+            in_specs=in_specs + ([in_specs[-1]] if has_dlse else []),
+            out_specs=out_specs,
+            scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in scratch],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in out_like],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
+        interpret=interpret,
+        name=_kernel_name(plan, kernel),
+    )(*scalars, *operands, *((dlse,) if has_dlse else ()))
+
+
+def _fa_bwd_fused(plan, *args):
+    """(dq, dk, dv) of a ``one_pass`` plan from one call on a (BH, 1, 1) grid."""
+    q, k, v = args[:3]
+    BH, _, D = q.shape
+    own, _, _ = _block_maps(plan)
+    blk = pl.BlockSpec((1, plan.bq, D), own)
+    return _bwd_call(
+        _fa_dqkv_kernel, "dqkv", plan, args, grid=(BH, 1, 1),
+        in_specs=[blk] * 5 + [pl.BlockSpec((1, plan.bq, 128), own)],
+        out_specs=[blk] * 3, out_like=(q, k, v), scratch=[(plan.bk, D)] * 2)
+
+
+def _fa_bwd_two_calls(plan, *args):
+    """(dq, dk, dv) from the dq kernel (grid BH, nq, nk: dq carries over key
+    blocks) and the dkv kernel (grid BH, nk, nq: dk and dv over query blocks)."""
+    q, k, v = args[:3]
+    BH, _, D = q.shape
+    bq, bk = plan.bq, plan.bk
+    own, keys, queries = _block_maps(plan)
+    qspec_i = pl.BlockSpec((1, bq, D), own)
+    kspec_j = pl.BlockSpec((1, bk, D), keys)
+    (dq,) = _bwd_call(
+        _fa_dq_kernel, "dq", plan, args, grid=(BH, plan.nq, _steps(plan)),
+        in_specs=[qspec_i, kspec_j, kspec_j, qspec_i, qspec_i,
+                  pl.BlockSpec((1, bq, 128), own)],
+        out_specs=[qspec_i], out_like=(q,), scratch=[(bq, D)])
+    # dkv grid: (BH, k-block, q-block) — q-side operands indexed by the INNER id
+    qspec_in = pl.BlockSpec((1, bq, D), queries)
+    kspec_out = pl.BlockSpec((1, bk, D), own)
+    dk, dv = _bwd_call(
+        _fa_dkv_kernel, "dkv", plan, args, grid=(BH, plan.nk, _steps(plan, True)),
+        in_specs=[qspec_in, kspec_out, kspec_out, qspec_in, qspec_in,
+                  pl.BlockSpec((1, bq, 128), queries)],
+        out_specs=[kspec_out, kspec_out], out_like=(k, v), scratch=[(bk, D)] * 2)
+    return dq, dk, dv
 
 
 def _fa_bwd_pallas(q, k, v, do, o, lse, dlse, lens, causal, scale, interpret,
                    rate=0.0, seed=None, window=None):
-    """``dlse=None`` (the plain-attention path) omits the operand entirely —
-    an all-zero lane-replicated dlse would otherwise add an arena-sized HBM
-    read to BOTH backward kernels for nothing. ``lens=None``: no ``kv_lens``."""
-    BH, Sq, D = q.shape
-    plan = _tile_plan(Sq, k.shape[1], D, causal, window)
-    bq, bk, nq, nk = plan.bq, plan.bk, plan.nq, plan.nk
-    has_dlse = dlse is not None
-    dlse_ops = (dlse,) if has_dlse else ()
-    scalars = _scalar_operands(lens, seed, rate)
-    _book_tiles(plan, D, lens is not None, "dq", "dkv")
-    own, keys, queries = _block_maps(plan)
-    qspec_i = pl.BlockSpec((1, bq, D), own)
-    kspec_j = pl.BlockSpec((1, bk, D), keys)
-    lse_i = pl.BlockSpec((1, bq, 128), own)
-    dq = pl.pallas_call(
-        functools.partial(_fa_dq_kernel, plan, scale, lens is not None,
-                          has_dlse, rate),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(scalars),
-            grid=(BH, nq, _steps(plan)),
-            in_specs=[qspec_i, kspec_j, kspec_j, qspec_i, qspec_i, lse_i]
-                     + ([lse_i] if has_dlse else []),
-            out_specs=qspec_i,
-            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
-        interpret=interpret,
-        name=_kernel_name(plan, "dq"),
-    )(*scalars, q, k, v, do, o, lse, *dlse_ops)
+    """The backward of a flash call, by what its plan says carries over between
+    grid steps. Where a head is ONE block (``plan.one_pass``: causal, S <= 1024
+    at D <= 128, S <= 512 above) nothing does, and one call
+    (:func:`_fa_bwd_fused`) recomputes the scores, ``do . v``, the ``exp``, the
+    diagonal's mask and ``ds`` once for dq, dk and dv: five products and one
+    vector pass over the live tiles, every operand read once. Everywhere else
+    dq sums over a query block's key blocks and dk / dv over a key block's
+    query blocks, which one grid order cannot both keep in VMEM, so two calls
+    (:func:`_fa_bwd_two_calls`) each walk the square their own way and each
+    recompute: seven products and two vector passes.
 
-    # dkv grid: (BH, k-block, q-block) — q-side operands indexed by the INNER id
-    qspec_in = pl.BlockSpec((1, bq, D), queries)
-    kspec_out = pl.BlockSpec((1, bk, D), own)
-    lse_in = pl.BlockSpec((1, bq, 128), queries)
-    dk, dv = pl.pallas_call(
-        functools.partial(_fa_dkv_kernel, plan, scale, lens is not None,
-                          has_dlse, rate),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(scalars),
-            grid=(BH, nk, _steps(plan, True)),
-            in_specs=[qspec_in, kspec_out, kspec_out, qspec_in, qspec_in, lse_in]
-                     + ([lse_in] if has_dlse else []),
-            out_specs=[kspec_out, kspec_out],
-            scratch_shapes=[
-                pltpu.VMEM((bk, D), jnp.float32),
-                pltpu.VMEM((bk, D), jnp.float32),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
-        interpret=interpret,
-        name=_kernel_name(plan, "dkv"),
-    )(*scalars, q, k, v, do, o, lse, *dlse_ops)
-    return dq, dk, dv
+    ``dlse=None`` (the plain-attention path) omits the operand entirely —
+    an all-zero lane-replicated dlse would otherwise add an arena-sized HBM
+    read to every backward kernel for nothing. ``lens=None``: no ``kv_lens``."""
+    plan = _tile_plan(q.shape[1], k.shape[1], q.shape[2], causal, window)
+    calls = _fa_bwd_fused if plan.one_pass else _fa_bwd_two_calls
+    return calls(plan, q, k, v, do, o, lse, dlse, lens, scale, interpret, rate, seed)
 
 
 # ---------------------------------------------------------------------------------
@@ -1002,7 +1086,7 @@ _flash3_lse.defvjp(_flash3_lse_fwd, _flash3_lse_bwd)
 def _probe_flash_pallas(q3, k3, v3, lens_bh, seed, *, causal, scale, rate,
                         window=None):
     """Guard probe: forward AND backward flash kernels must build for the key
-    (the bwd pass launches two extra pallas_calls with their own specs)."""
+    (the bwd pass launches one or two more pallas_calls with their own specs)."""
 
     def f(q, k, v):
         return _flash3(q, k, v, lens_bh, seed, causal, scale, rate, window)

@@ -140,8 +140,9 @@ def check_flash_tiles(results: list) -> None:
     """The causal tile plan (``ops.attention.TilePlan``) compiled, at the
     benchmark cells' own sequence lengths and head sizes: forward and the
     three gradients against the jnp oracle, dropout across several tiles
-    regenerating ONE mask in all three kernels, and the engagement counter
-    (``monitor.tile_records``) printed. Interpret mode cannot see a Mosaic
+    regenerating ONE mask in every kernel, the engagement counter
+    (``monitor.tile_records``) printed, and the fused backward of a one-block
+    head against the two kernels it replaces. Interpret mode cannot see a Mosaic
     lowering or a tiling fault of the walk's slices and joins."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -240,8 +241,51 @@ def check_flash_tiles(results: list) -> None:
         got = (r["live"], r["total"], r["masked"])
         check(f"counter/{r['kernel']}{r['key']}", got == want.get(r["key"]),
               f"{got[0]}/{got[1]} masked {got[2]} ({r['traces']} traces)")
-    check("counter/all_kernels_booked",
-          {r["kernel"] for r in rows} == {"fwd", "dq", "dkv"}, len(rows))
+    # a head of one block books the fused backward, several blocks the two kernels
+    by_key = {}
+    for r in rows:
+        by_key.setdefault(r["key"], set()).add(r["kernel"])
+    one_block, blocks = {"fwd", "dqkv"}, {"fwd", "dq", "dkv"}
+    check("counter/kernels_by_plan", by_key == {
+        "(1024, 1024, 64, True, False)": one_block, "(1024, 1024, 64, True, True)": one_block,
+        "(8192, 8192, 256, True, False)": blocks, "(1024, 1024, 64, False, True)": {"fwd"}},
+        json.dumps({k: sorted(v) for k, v in by_key.items()}))
+
+    # the fused backward (PR 41) against the two kernels every other plan takes,
+    # from the same residuals: the GPT cells' call, the two largest one-block
+    # shapes (VMEM), key lengths cutting a tile, dropout. dq is the dq kernel's
+    # product; dk and dv sum the same float32 terms strip by strip and round once
+    # (the timed cases hold enough heads that the device's time, not the
+    # host's dispatch of ~0.5 ms a call, is what the clock reads)
+    fused = [("fused_s1024_d64", 256, 1024, 64, None, 0.0),
+             ("fused_s1024_d128", 128, 1024, 128, None, 0.0),
+             ("fused_s512_d256", 128, 512, 256, None, 0.0),
+             ("fused_s1024_d64_lens", 8, 1024, 64, (1024, 700, 512, 0, 1, 255, 256, 1023), 0.0),
+             ("fused_s1024_d64_dropout", 8, 1024, 64, None, 0.2)]
+    for name, BH, S, D, kv, rate in fused:
+        q, k, v, do = inputs(3, BH, S, D)
+        sc = 1.0 / np.sqrt(D)
+        lens = None if kv is None else jnp.asarray(kv, jnp.float32)
+        seed = A._seed_from_key(jax.random.PRNGKey(6))
+        plan = A._tile_plan(S, S, D, True)
+        o, lse = jax.jit(lambda q, k, v: A._fa_fwd_pallas(
+            q, k, v, lens, True, sc, False, rate, seed))(q, k, v)
+        runs = {tag: functools.partial(
+                    jax.jit(lambda *a, fn=fn: fn(plan, *a, None, lens, sc, False, rate, seed)),
+                    q, k, v, do, o, lse)
+                for tag, fn in (("fused", A._fa_bwd_fused), ("two_calls", A._fa_bwd_two_calls))}
+        one, two = runs["fused"](), runs["two_calls"]()
+        check(f"{name}/dq_bit_for_bit", jnp.array_equal(one[0], two[0]))
+        for gname, a, b in zip(("dk", "dv"), one[1:], two[1:]):
+            a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+            ok = bool(jnp.all(jnp.isfinite(a))) and rel(a, b) < 1e-2
+            check(f"{name}/{gname}", ok,
+                  f"max|d|={float(jnp.max(jnp.abs(a - b))):.3e} of {float(jnp.max(jnp.abs(b))):.3e}, "
+                  f"{int(jnp.sum(a != b))} of {a.size} differ")
+        if rate == 0.0 and kv is None:
+            ms = {tag: round(1e3 * _min_step_seconds(lambda _: fn(), None), 4)
+                  for tag, fn in runs.items()}
+            check(f"{name}/ms_{BH}_heads", ms["fused"] < ms["two_calls"], json.dumps(ms))
 
 
 def check_wy_prepare(results: list) -> None:

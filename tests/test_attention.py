@@ -338,7 +338,7 @@ class TestTilePlan:
         q, k, v = _qkv(jax.random.PRNGKey(7), B=1, H=1, S=512)
         jax.grad(lambda q: jnp.sum(A.flash_attention(q, k, v, causal=True, impl="pallas")))(q)
         rows = {r["kernel"]: r for r in monitor.tile_records() if r["op"] == "flash_attention"}
-        assert sorted(rows) == ["dkv", "dq", "fwd"]
+        assert sorted(rows) == ["dqkv", "fwd"]      # one block a head: one backward kernel
         for r in rows.values():   # block 512 in four strips of 128
             assert (r["live"], r["total"], r["masked"]) == (10, 16, 4)
             assert r["traces"] >= 1
@@ -751,7 +751,7 @@ class TestNoWindowIsTheParentsCall:
                          (("causal", "True"), ("rate", "0.0"), ("scale", "0.125")), ())]
         assert {r["key"] for r in monitor.tile_records()} == {repr((512, 512, 64, True, False))}
         calls = _pallas_eqns(jaxpr)
-        assert [e.params["grid_mapping"].grid for e in calls] == [(2, 1, 1)] * 3
+        assert [e.params["grid_mapping"].grid for e in calls] == [(2, 1, 1)] * 2   # fwd, dqkv
         assert all(e.params["name"] is None for e in calls)    # the scope names them
         gd.reset_dispatch_counters()
 
@@ -766,3 +766,198 @@ class TestNoWindowIsTheParentsCall:
         (key,) = [key for key in gd.dispatch_counters() if key[0] == "flash_attention"]
         assert key[3] == (("causal", "True"), ("rate", "0.0"), ("scale", "0.125"), ("window", "200"))
         gd.reset_dispatch_counters()
+
+
+# ---------------------------------------------------------------------------------
+# one backward kernel where a head is one block (PR 41)
+# ---------------------------------------------------------------------------------
+
+
+def _hashed_keep(seed_ref, b, i, j, nq, nk, shape, keep_prob):
+    """``A._keep_mask`` for the interpreter, which has no PRNG: the same
+    contract (a tile's mask is a function of the seed and the tile's id in the
+    whole square, whichever kernel and panel draws it) from integer hashing."""
+    tile = (b * nq + i) * nk + j
+    r = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    h = (r * 7919 + c * 104729 + tile * 31337 + seed_ref[0]) % 1009
+    return h < int(keep_prob * 1009)
+
+
+# (S, D): the GPT cells' call; one tile a block; the widest head at its largest
+# one-block S; D = 128 in two strips
+FUSED_SHAPES = [(1024, 64), (256, 128), (512, 256), (128, 64)]
+
+
+class TestFusedBackward:
+    """``plan.one_pass``: dq, dk and dv from ONE kernel (``_fa_bwd_fused``),
+    against the oracle at ``TestTiledParity``'s tolerances and against the two
+    kernels every other plan takes (``_fa_bwd_two_calls``, called here on the
+    same one-block plan)."""
+
+    BH = 2
+
+    def _inputs(self, S, D, seed=21):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+        q, k, v, w = (jax.random.normal(kk, (self.BH, S, D), jnp.float32) for kk in ks[:4])
+        return q, k, v, w, jax.random.normal(ks[4], (self.BH, S), jnp.float32)
+
+    @staticmethod
+    def _lens(case, S, D):
+        t = A._tile_plan(S, S, D, True).tk
+        return {"no-lens": None,
+                "inside-a-tile": (S - t // 2 - 5, S),
+                "on-a-tile-edge": (t * max(1, S // t // 2), S - t if S > t else S),
+                "zero": (0, t // 2 + 2)}[case]
+
+    @pytest.mark.parametrize("case", ["no-lens", "inside-a-tile", "on-a-tile-edge", "zero"])
+    @pytest.mark.parametrize("S,D", FUSED_SHAPES, ids=lambda x: str(x))
+    def test_matches_oracle(self, S, D, case):
+        q, k, v, w, _ = self._inputs(S, D)
+        scale = 1.0 / np.sqrt(D)
+        lens = self._lens(case, S, D)
+        kv = None if lens is None else jnp.asarray(lens, jnp.float32)
+        seed = jnp.zeros((1,), jnp.int32)
+        assert A._tile_plan(S, S, D, True).one_pass
+
+        def loss(q, k, v):
+            return jnp.sum(A._flash3(q, k, v, kv, seed, True, scale, 0.0) * w)
+
+        got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        full = jnp.full((self.BH,), float(S)) if kv is None else kv
+        want = _oracle_grads(q, k, v, full, True, scale, w)[1:]
+        for name, a, b in zip(("dq", "dk", "dv"), got, want):
+            assert not np.any(np.isnan(np.asarray(a))), name
+            np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5, err_msg=name)
+
+    @pytest.mark.parametrize("S,D", FUSED_SHAPES, ids=lambda x: str(x))
+    def test_lse_variant_with_dlse(self, S, D):
+        q, k, v, w, wl = self._inputs(S, D, 22)
+        scale = 1.0 / np.sqrt(D)
+        lens = jnp.asarray(self._lens("inside-a-tile", S, D), jnp.float32)
+
+        def loss(q, k, v):
+            o, lse = A.flash_attention_with_lse(q, k, v, causal=True, scale=scale, kv_lens=lens)
+            return jnp.sum(o * w) + jnp.sum(lse * wl)
+
+        got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        want = _oracle_grads(q, k, v, lens, True, scale, w, wl)[1:]
+        for name, a, b in zip(("dq", "dk", "dv"), got, want):
+            np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4, err_msg=name)
+
+    @pytest.mark.parametrize("S,D", FUSED_SHAPES, ids=lambda x: str(x))
+    def test_dropout_is_the_two_call_backwards(self, S, D, monkeypatch):
+        """Fixed seed, the forward's draw: dq is the dq kernel's bit for bit
+        (same panels, same product), dk and dv are the same float32 terms
+        summed strip by strip instead of inside one product."""
+        monkeypatch.setattr(A, "_keep_mask", _hashed_keep)
+        q, k, v, w, _ = self._inputs(S, D, 23)
+        scale, rate = 1.0 / np.sqrt(D), 0.25
+        seed = jnp.asarray([12345], jnp.int32)
+        plan = A._tile_plan(S, S, D, True)
+        o, lse = A._fa_fwd_pallas(q, k, v, None, True, scale, True, rate, seed)
+        plain, _ = A._fa_fwd_pallas(q, k, v, None, True, scale, True)
+        assert float(jnp.max(jnp.abs(o - plain))) > 1e-2          # the mask bites
+        args = (plan, q, k, v, w, o, lse, None, None, scale, True, rate, seed)
+        one, two = A._fa_bwd_fused(*args), A._fa_bwd_two_calls(*args)
+        np.testing.assert_array_equal(one[0], two[0])
+        for name, a, b in zip(("dk", "dv"), one[1:], two[1:]):
+            np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5, err_msg=name)
+
+    @staticmethod
+    def _grad_fn(with_lse):
+        seed = jnp.zeros((1,), jnp.int32)
+
+        def loss(q, k, v):
+            if with_lse:
+                o, lse = A._flash3_lse(q, k, v, None, True, 0.125)
+                return jnp.sum(o.astype(jnp.float32)) + jnp.sum(lse)
+            return jnp.sum(A._flash3(q, k, v, None, seed, True, 0.125, 0.0).astype(jnp.float32))
+
+        return jax.grad(loss, argnums=(0, 1, 2))
+
+    @pytest.mark.parametrize("with_lse", [False, True], ids=["plain", "lse"])
+    @pytest.mark.parametrize("S,D", FUSED_SHAPES + [(512, 64), (1024, 128)], ids=lambda x: str(x))
+    def test_one_block_a_head_is_one_backward_call(self, S, D, with_lse):
+        x = jax.ShapeDtypeStruct((2, S, D), jnp.bfloat16)
+        calls = _pallas_eqns(jax.make_jaxpr(self._grad_fn(with_lse))(x, x, x).jaxpr)
+        assert [e.params["grid_mapping"].grid for e in calls] == [(2, 1, 1)] * 2
+        assert [len(e.params["out_avals"]) for e in calls] == [2, 3]     # (o, lse); (dq, dk, dv)
+        assert all(e.params["name"] is None for e in calls)    # ``%flash_attention.N`` on the chip
+
+    # a 2 x 2-block causal call (S=1024 at D=256): the three kernels of the
+    # commit before the fused backward (6707be1), operation for operation
+    FWD = {"add": 25, "broadcast_in_dim": 24, "concatenate": 3, "cond": 4,
+           "convert_element_type": 14, "div": 1, "dot_general": 13, "eq": 3, "exp": 10,
+           "get": 40, "gt": 5, "iota": 8, "log": 1, "lt": 1, "max": 5, "mul": 26,
+           "program_id": 3, "reduce_max": 5, "reduce_sum": 5, "select_n": 8, "slice": 10,
+           "sub": 10, "swap": 20}
+    DQ = {"add": 24, "broadcast_in_dim": 10, "concatenate": 3, "cond": 4,
+          "convert_element_type": 20, "dot_general": 18, "eq": 3, "exp": 5, "get": 47, "gt": 4,
+          "iota": 8, "lt": 1, "mul": 31, "program_id": 3, "reduce_sum": 5, "select_n": 4,
+          "slice": 5, "sub": 10, "swap": 7}
+    DKV = {"add": 29, "broadcast_in_dim": 8, "concatenate": 3, "cond": 4,
+           "convert_element_type": 20, "dot_general": 23, "eq": 3, "exp": 5, "get": 52, "gt": 4,
+           "iota": 8, "lt": 1, "mul": 28, "program_id": 3, "reduce_sum": 2, "select_n": 4,
+           "slice": 8, "sub": 10, "swap": 14}
+
+    @pytest.mark.parametrize("with_lse", [False, True], ids=["plain", "lse"])
+    def test_several_blocks_a_head_keep_their_two_calls_and_bodies(self, with_lse):
+        import collections
+
+        x = jax.ShapeDtypeStruct((1, 1024, 256), jnp.bfloat16)
+        assert A._tile_plan(1024, 1024, 256, True).nq == 2
+        calls = _pallas_eqns(jax.make_jaxpr(self._grad_fn(with_lse))(x, x, x).jaxpr)
+        assert [e.params["grid_mapping"].grid for e in calls] == [(1, 2, 2)] * 3
+        fwd, dq, dkv = (collections.Counter(p)
+                        for p in _kernel_primitives(self._grad_fn(with_lse), x, x, x))
+        dlse = collections.Counter({"get": 5, "slice": 5} if with_lse else {})   # a read a strip
+        assert fwd == self.FWD
+        assert dq == collections.Counter(self.DQ) + dlse
+        assert dkv == collections.Counter(self.DKV) + dlse
+
+    def test_the_fused_body_is_smaller_than_the_two_it_replaces(self):
+        """The body is traced at every start of a program (set-up time): at the
+        GPT cells' call the two bodies were 167 + 169 operations (6707be1)."""
+        x = jax.ShapeDtypeStruct((2, 1024, 64), jnp.bfloat16)
+        _, dqkv = _kernel_primitives(self._grad_fn(False), x, x, x)
+        assert len(dqkv) <= 250, len(dqkv)
+        # s a piece (the run left of the diagonal, the tile on it), then a strip: dp, dq, dk, dv
+        assert dqkv.count("dot_general") == 7 + 4 * 4
+        assert dqkv.count("exp") == 4 and "cond" not in dqkv
+
+    @pytest.mark.parametrize("key,kernels,counts", [
+        ((1024, 1024, 64), ["dqkv", "fwd"], (10, 16, 4)),             # the GPT cells
+        ((8192, 8192, 256), ["dkv", "dq", "fwd"], (2080, 4096, 64)),  # the Qwen cell
+    ], ids=["gpt_cells", "qwen_cell"])
+    def test_tile_records_say_which_backward_was_traced(self, key, kernels, counts):
+        from beforeholiday_tpu import monitor
+        from beforeholiday_tpu.guard import dispatch
+
+        dispatch.reset_dispatch_counters()
+        x = jax.ShapeDtypeStruct((1, key[0], key[2]), jnp.bfloat16)
+        jax.make_jaxpr(self._grad_fn(False))(x, x, x)
+        rows = {r["kernel"]: r for r in monitor.tile_records() if r["op"] == "flash_attention"}
+        assert sorted(rows) == kernels
+        for r in rows.values():
+            assert r["key"] == repr((*key, True, False))
+            assert (r["live"], r["total"], r["masked"]) == counts
+        dispatch.reset_dispatch_counters()
+
+    def test_a_windowed_block_takes_the_fused_call_under_its_own_name(self):
+        """One block a head with a window inside it: the band's walk of that
+        block (both edges), the kernel named as the windowed ones are."""
+        S, W, D = 512, 300, 64
+        plan = A._tile_plan(S, S, D, True, W)
+        assert plan.one_pass and plan.band == 1
+        q, k, v, w, _ = self._inputs(S, D, 24)
+        kv = jnp.asarray((200.0, 512.0))
+        seed = jnp.zeros((1,), jnp.int32)
+        flash = lambda q, k, v: jnp.sum(A._flash3(q, k, v, kv, seed, True, 0.125, 0.0, W) * w)
+        want = jax.grad(lambda q, k, v: jnp.sum(_window_oracle(q, k, v, W, 0.125, kv) * w),
+                        argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(jax.grad(flash, argnums=(0, 1, 2))(q, k, v), want):
+            np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5)
+        names = [e.params["name"] for e in _pallas_eqns(
+            jax.make_jaxpr(jax.grad(flash, argnums=(0, 1, 2)))(q, k, v).jaxpr)]
+        assert names == ["flash_attention_window_fwd", "flash_attention_window_dqkv"]

@@ -273,3 +273,76 @@ def test_the_short_conv_kernels_compile_for_the_chip(one_chip, monkeypatch, batc
     assert text.count("tpu_custom_call") == 2
     for kernel in ("short_conv_fwd", "short_conv_bwd"):
         assert kernel in text
+
+
+@pytest.fixture
+def flash_compiled(monkeypatch):
+    """``ops.attention`` with its kernels compiled, not interpreted, and the
+    compilation cache off (a compile for a described chip cannot be read back)."""
+    from beforeholiday_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "_interpret_default", lambda: False)
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield A
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.mark.parametrize("variant", ("plain", "lens_dlse", "dropout"))
+@pytest.mark.parametrize("S,D", ((1024, 64), (1024, 128), (512, 256)),
+                         ids=("gpt_cells", "widest_at_1024", "widest_at_512"))
+def test_the_fused_flash_backward_compiles_for_the_chip(one_chip, flash_compiled, S, D, variant):
+    """Where a head is one block the backward is ONE kernel beside the forward
+    (``ops/attention.py:_fa_bwd_fused``): at the GPT cells' shape and at the two
+    largest one-block shapes, where its VMEM is what Mosaic could refuse —
+    plain, with ``kv_lens`` and the ``dlse`` operand, and with dropout."""
+    A = flash_compiled
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    BH, x = 8, shape((8, S, D), jnp.bfloat16)
+    assert A._tile_plan(S, S, D, True).one_pass
+
+    def both(q, k, v, lens, seed, do, dlse):
+        if variant == "lens_dlse":
+            (o, lse), pull = jax.vjp(
+                lambda *a: A._flash3_lse(*a, lens, True, 0.125), q, k, v)
+            return o, pull((do, dlse))
+        rate = 0.1 if variant == "dropout" else 0.0
+        o, pull = jax.vjp(lambda *a: A._flash3(*a, None, seed, True, 0.125, rate), q, k, v)
+        return o, pull(do)
+
+    text = jax.jit(both).lower(
+        x, x, x, shape((BH,), jnp.float32), shape((1,), jnp.int32), x,
+        shape((BH, S), jnp.float32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+
+
+def test_same_step_reads_two_texts_as_one_program_when_only_source_lines_moved(
+        one_chip, flash_compiled, tmp_path):
+    """``tools/same_step.py`` on a compiled flash forward + backward: a frame
+    table with other line numbers is the same program, a kernel's body prints
+    without its locations, and a changed operand is not the same program."""
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
+    import same_step
+
+    A = flash_compiled
+    x = jax.ShapeDtypeStruct((2, 256, 64), jnp.bfloat16, sharding=one_chip)
+    seed = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+
+    def both(q, k, v, seed, do):
+        o, pull = jax.vjp(lambda *a: A._flash3(*a, None, seed, True, 0.125, 0.0), q, k, v)
+        return o, pull(do)
+
+    text = jax.jit(both).lower(x, x, x, seed, x).compile().as_text()
+    frames = [l for l in text.splitlines() if same_step._FRAME.match(l)]
+    assert frames and text.count('"body":"') == 2
+    moved = re.sub(r"(function_name_id=\d+ line=)(\d+)", lambda m: m[1] + str(int(m[2]) + 7), text)
+    other = text.replace("bf16[2,256,64]", "bf16[2,256,65]", 1)
+    assert moved != text and other != text
+    for name, content in (("a", text), ("b", moved), ("c", other)):
+        (tmp_path / name).write_text(content)
+    assert same_step.differing(tmp_path / "a", tmp_path / "b") == []
+    assert len(same_step.differing(tmp_path / "a", tmp_path / "c")) == 1
+    kernel = same_step._kernel_text(same_step._BODY.search(text).group(1))
+    assert "func.func" in kernel and "loc(" not in kernel
