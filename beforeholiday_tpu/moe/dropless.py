@@ -429,9 +429,12 @@ def dropless_moe(
     SwiGLU experts ``w_gate`` (stacked over the held experts); for a model
     whose routed experts work in a latent, ``fc1_latent (D, Dl)`` /
     ``fc2_latent (Dl, D)`` (the experts are then ``Dl`` wide); for a model with
-    a shared expert ``shared_w_up`` / ``shared_w_down`` and, if it is gated,
-    ``shared_w_gate`` and ``shared_score (D, 1)``: without them the layer is its
-    routed part alone, and the ``moe_shared`` span does not open. ``route`` is
+    a shared expert ``shared_w_up`` / ``shared_w_down``, which select its form
+    with the keys beside them: ``shared_w_gate`` and ``shared_score (D, 1)`` a
+    SwiGLU gated per token by ``sigmoid(x shared_score)``; ``shared_w_gate``
+    alone an ungated SwiGLU; neither the ungated ``relu^2`` pair. Without
+    ``shared_w_up`` the layer is its routed part alone, and the ``moe_shared``
+    span does not open. ``route`` is
     the router, called ``route(x, router, top_k, renormalize=renormalize)``: a
     partial of :func:`route_sigmoid`, say. Returns ``(y (T, D) in x's dtype, counters)``."""
     dt = x.dtype
@@ -451,9 +454,12 @@ def dropless_moe(
                                  preferred_element_type=_F32)
         if "shared_w_up" in p:
             with _span("moe_shared"):
-                if "shared_w_gate" in p:
+                if "shared_score" in p:
                     shared = shared_expert(x, p["shared_w_gate"], p["shared_w_up"],
                                            p["shared_w_down"], p["shared_score"])
+                elif "shared_w_gate" in p:
+                    shared = swiglu(x, p["shared_w_gate"], p["shared_w_up"],
+                                    p["shared_w_down"])
                 else:
                     shared = relu2_mlp(x, p["shared_w_up"], p["shared_w_down"])
             with _span("moe_combine"):

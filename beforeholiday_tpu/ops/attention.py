@@ -16,8 +16,13 @@ from the saved (q, k, v, lse) — the same rematerialization trade the
 reference's backward kernels make, shaped for the MXU: every inner op is a
 (BQ, D) x (D, BK)-style matmul, fp32 accumulation.
 
+Queries and keys share one width ``Dk`` and values have their own ``Dv`` (latent
+attention: 192-wide scores over 128-wide values); every kernel computes its
+scores at ``Dk`` and its values at ``Dv``, nothing is padded to a common width,
+and a call with ``Dv == Dk`` is the one-width call it always was.
+
 A call is scheduled on two levels (:class:`TilePlan`), both a function of
-``(Sq, Sk, D, causal, window)`` alone. The grid hands the kernels large *blocks*
+``(Sq, Sk, Dk, Dv, causal, window)`` alone. The grid hands the kernels large *blocks*
 (:func:`_block_size`: few grid steps, few copies); inside a block that the
 causal diagonal crosses the kernels walk *tiles*, so that the triangle above
 the diagonal is not computed and only the tiles on it build a mask — at
@@ -93,27 +98,35 @@ _MIN_BLOCK = 128
 _DIAG_STRIPS = 4
 
 
-def _block_size(seq_len: int, head_dim: int = 64) -> int:
+def _block_size(seq_len: int, head_dim: int = 64, v_head_dim: Optional[int] = None) -> int:
     """Largest GRID block (query rows == key cols) that tiles the sequence —
     the outer of the two levels of :class:`TilePlan`.
 
     Bigger blocks amortize per-grid-step overhead and give the MXU larger
     matmuls: at S=8192/D=64 (32 heads, where a head is 8 x 8 blocks of 1024)
     the causal forward measured 30.0 ms with 1024-blocks vs 31.4 (512) vs
-    43.8 (256) on a v5e. 1024 is allowed only for head_dim <= 128 — the dkv
-    backward holds ~6 operand blocks plus two (bk, D) fp32 scratch
-    accumulators and (bq, bk) fp32 intermediates, which at D > 128 would push
-    past the ~16 MB VMEM budget. The block is NOT what decides how much of the
+    43.8 (256) on a v5e. 1024 is allowed only where the values are one lane tile
+    wide (``v_head_dim <= 128``) and the queries and keys at most two
+    (``head_dim <= 256``) — the dkv backward holds ~6 operand blocks plus two
+    fp32 scratch accumulators and (bq, bk) fp32 intermediates, which with wider
+    values would push past the ~16 MB VMEM budget. For a call of one width that
+    is D <= 128, as it always was. Of two widths, 192 / 128 (latent attention:
+    three of the five operands and both outputs of the forward are 128 wide)
+    takes 1024 and is faster for it: one layer at (32 heads, S=8192), fwd / dq +
+    dkv device ms on a v5e, 11.98 / 27.25 with blocks of 512 and 7.52 / 22.25
+    with blocks of 1024 (PR 42). The block is NOT what decides how much of the
     causal triangle is skipped: at S=1024 one head is a single block, and the
     skipping happens inside it, tile by tile (:func:`_tile_plan`)."""
-    ladder = (1024, 512, 256) if head_dim <= 128 else (512, 256)
+    v_head_dim = head_dim if v_head_dim is None else v_head_dim
+    ladder = (1024, 512, 256) if head_dim <= 256 and v_head_dim <= 128 else (512, 256)
     for cand in ladder:
         if seq_len % cand == 0:
             return cand
     return _MIN_BLOCK
 
 
-def _window_block(seq_len: int, head_dim: int, window: int) -> int:
+def _window_block(seq_len: int, head_dim: int, window: int,
+                  v_head_dim: Optional[int] = None) -> int:
     """Grid block of a windowed call: :func:`_block_size`'s, halved only while
     the half still holds the whole window (a block of twice the window or more
     would be mostly outside the band).
@@ -129,7 +142,7 @@ def _window_block(seq_len: int, head_dim: int, window: int) -> int:
     walks fewer only to pay more grid steps and more copies. Eight strips are
     8 % faster at twice the kernel body, which ``_DIAG_STRIPS`` weighs the
     same way."""
-    block = _block_size(seq_len, head_dim)
+    block = _block_size(seq_len, head_dim, v_head_dim)
     while block > _MIN_BLOCK and block // 2 >= window and seq_len % (block // 2) == 0:
         block //= 2
     return block
@@ -282,21 +295,23 @@ class TilePlan(NamedTuple):
 
 @functools.lru_cache(maxsize=None)
 def _tile_plan(sq: int, sk: int, head_dim: int, causal: bool,
-               window: Optional[int] = None) -> TilePlan:
-    """The schedule of a call from what it can observe; no knob.
+               window: Optional[int] = None, v_head_dim: Optional[int] = None) -> TilePlan:
+    """The schedule of a call from what it can observe; no knob. ``head_dim``
+    is the queries' and keys', ``v_head_dim`` the values' (the same where not
+    given).
 
     Blocks are :func:`_block_size`'s. A causal call's blocks are square
     (``sq == sk``) and the ones on the diagonal are walked in ``_DIAG_STRIPS``
     strips of square tiles, never smaller than the 128-lane minimum. With a
     ``window`` (fewer keys than the sequence has) the blocks are
     :func:`_window_block`'s and the grid is the band (:attr:`TilePlan.band`)."""
-    bq, bk = _block_size(sq, head_dim), _block_size(sk, head_dim)
+    bq, bk = _block_size(sq, head_dim, v_head_dim), _block_size(sk, head_dim, v_head_dim)
     if not causal:
         return TilePlan(sq, sk, bq, bk, bq, bk, False)
     if sq != sk:
         raise ValueError(f"causal attention needs matching q/k lengths, got {sq} vs {sk}")
     if window is not None:
-        bq = bk = _window_block(sq, head_dim, window)
+        bq = bk = _window_block(sq, head_dim, window, v_head_dim)
     t = max(_MIN_BLOCK, bq // _DIAG_STRIPS)
     return TilePlan(sq, sk, bq, bk, t, t, True, window)
 
@@ -326,14 +341,16 @@ def oracle_score_budget() -> int:
     return _ORACLE_SCORE_BYTES_CAP
 
 
-def is_flash_available(seq_len: int, head_dim: int) -> bool:
+def is_flash_available(seq_len: int, head_dim: int, v_head_dim: Optional[int] = None) -> bool:
     """Shape gate for the Pallas kernel (ref: fused_softmax.py:164
     ``is_kernel_available`` plays the same role for the softmax kernels).
 
-    Requires the sequence to tile exactly into (BQ, BK) blocks and a head
-    dim that fits VMEM comfortably alongside the accumulators.
+    Requires the sequence to tile exactly into (BQ, BK) blocks and head dims
+    (``head_dim`` of queries and keys, ``v_head_dim`` of values: the same where
+    not given) that fit VMEM comfortably alongside the accumulators.
     """
-    return seq_len % _MIN_BLOCK == 0 and 8 <= head_dim <= 512
+    dims = (head_dim, head_dim if v_head_dim is None else v_head_dim)
+    return seq_len % _MIN_BLOCK == 0 and all(8 <= d <= 512 for d in dims)
 
 
 # ---------------------------------------------------------------------------------
@@ -642,10 +659,18 @@ def _fa_fwd_kernel(plan, scale, has_lens, rate, *refs):
             )
 
 
-def _book_tiles(plan, head_dim, has_lens, *kernels):
+def _widths(q, v):
+    """``(Dk, Dv)`` of a call's ``(BH, S, D)`` operands: the width of queries
+    and keys (the scores' depth) and the width of values and of the result."""
+    return q.shape[2], v.shape[2]
+
+
+def _book_tiles(plan, widths, has_lens, *kernels):
     """Book the plan's tile counts once per kernel traced with it (a windowed
-    plan's key ends in its window; the others' keys are what they were)."""
-    key = (plan.sq, plan.sk, head_dim, plan.causal, has_lens)
+    plan's key ends in its window; a call whose values are as wide as its keys
+    books the one width, as it always did, the others ``(Dk, Dv)``)."""
+    dk, dv = widths
+    key = (plan.sq, plan.sk, dk if dk == dv else widths, plan.causal, has_lens)
     if plan.window is not None:
         key += (plan.window,)
     for kernel in kernels:
@@ -653,7 +678,7 @@ def _book_tiles(plan, head_dim, has_lens, *kernels):
 
 
 def _block_maps(plan):
-    """Index maps ``(own, keys, queries)`` of the (1, block, D) operands:
+    """Index maps ``(own, keys, queries)`` of the (1, block, Dk or Dv) operands:
     ``own`` follows a kernel's outer block; ``keys`` (fwd, dq: query block
     outer) and ``queries`` (dkv: key block outer) follow the last grid axis.
     In a windowed plan that axis walks the band, offset from the outer block
@@ -688,24 +713,24 @@ def _scalar_operands(lens, seed, rate):
 def _fa_fwd_pallas(q, k, v, lens, causal, scale, interpret, rate=0.0, seed=None,
                    window=None):
     """``lens=None``: the call has no ``kv_lens`` — no length test anywhere."""
-    BH, Sq, D = q.shape
-    plan = _tile_plan(Sq, k.shape[1], D, causal, window)
+    BH, Sq, _ = q.shape
+    Dk, Dv = _widths(q, v)
+    plan = _tile_plan(Sq, k.shape[1], Dk, causal, window, Dv)
     bq, bk = plan.bq, plan.bk
-    _book_tiles(plan, D, lens is not None, "fwd")
+    _book_tiles(plan, (Dk, Dv), lens is not None, "fwd")
     own, keys, _ = _block_maps(plan)
-    qspec = pl.BlockSpec((1, bq, D), own)
-    kspec = pl.BlockSpec((1, bk, D), keys)
     scalars = _scalar_operands(lens, seed, rate)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(BH, plan.nq, _steps(plan)),
-        in_specs=[qspec, kspec, kspec],
+        in_specs=[pl.BlockSpec((1, bq, Dk), own), pl.BlockSpec((1, bk, Dk), keys),
+                  pl.BlockSpec((1, bk, Dv), keys)],
         out_specs=[
-            qspec,
+            pl.BlockSpec((1, bq, Dv), own),
             pl.BlockSpec((1, bq, 128), own),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
@@ -717,7 +742,7 @@ def _fa_fwd_pallas(q, k, v, lens, causal, scale, interpret, rate=0.0, seed=None,
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((BH, Sq, Dv), q.dtype),
             jax.ShapeDtypeStruct((BH, Sq, 128), jnp.float32),
         ],
         interpret=interpret,
@@ -936,7 +961,7 @@ def _bwd_call(body, kernel, plan, args, *, grid, in_specs, out_specs, out_like, 
     *operands, dlse, lens, scale, interpret, rate, seed = args
     has_dlse = dlse is not None
     scalars = _scalar_operands(lens, seed, rate)
-    _book_tiles(plan, operands[0].shape[2], lens is not None, kernel)
+    _book_tiles(plan, _widths(operands[0], operands[2]), lens is not None, kernel)
     return pl.pallas_call(
         functools.partial(body, plan, scale, lens is not None, has_dlse, rate),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -958,37 +983,37 @@ def _bwd_call(body, kernel, plan, args, *, grid, in_specs, out_specs, out_like, 
 def _fa_bwd_fused(plan, *args):
     """(dq, dk, dv) of a ``one_pass`` plan from one call on a (BH, 1, 1) grid."""
     q, k, v = args[:3]
-    BH, _, D = q.shape
+    BH, (Dk, Dv) = q.shape[0], _widths(q, v)
     own, _, _ = _block_maps(plan)
-    blk = pl.BlockSpec((1, plan.bq, D), own)
+    at_k, at_v = (pl.BlockSpec((1, plan.bq, D), own) for D in (Dk, Dv))
     return _bwd_call(
         _fa_dqkv_kernel, "dqkv", plan, args, grid=(BH, 1, 1),
-        in_specs=[blk] * 5 + [pl.BlockSpec((1, plan.bq, 128), own)],
-        out_specs=[blk] * 3, out_like=(q, k, v), scratch=[(plan.bk, D)] * 2)
+        in_specs=[at_k, at_k, at_v, at_v, at_v, pl.BlockSpec((1, plan.bq, 128), own)],
+        out_specs=[at_k, at_k, at_v], out_like=(q, k, v),
+        scratch=[(plan.bk, Dk), (plan.bk, Dv)])
 
 
 def _fa_bwd_two_calls(plan, *args):
     """(dq, dk, dv) from the dq kernel (grid BH, nq, nk: dq carries over key
     blocks) and the dkv kernel (grid BH, nk, nq: dk and dv over query blocks)."""
     q, k, v = args[:3]
-    BH, _, D = q.shape
+    BH, (Dk, Dv) = q.shape[0], _widths(q, v)
     bq, bk = plan.bq, plan.bk
     own, keys, queries = _block_maps(plan)
-    qspec_i = pl.BlockSpec((1, bq, D), own)
-    kspec_j = pl.BlockSpec((1, bk, D), keys)
+    # q, k (and dq, dk) are Dk wide; v, do, o (and dv) Dv wide
+    spec = lambda rows, D, at: pl.BlockSpec((1, rows, D), at)
     (dq,) = _bwd_call(
         _fa_dq_kernel, "dq", plan, args, grid=(BH, plan.nq, _steps(plan)),
-        in_specs=[qspec_i, kspec_j, kspec_j, qspec_i, qspec_i,
-                  pl.BlockSpec((1, bq, 128), own)],
-        out_specs=[qspec_i], out_like=(q,), scratch=[(bq, D)])
+        in_specs=[spec(bq, Dk, own), spec(bk, Dk, keys), spec(bk, Dv, keys),
+                  spec(bq, Dv, own), spec(bq, Dv, own), spec(bq, 128, own)],
+        out_specs=[spec(bq, Dk, own)], out_like=(q,), scratch=[(bq, Dk)])
     # dkv grid: (BH, k-block, q-block) — q-side operands indexed by the INNER id
-    qspec_in = pl.BlockSpec((1, bq, D), queries)
-    kspec_out = pl.BlockSpec((1, bk, D), own)
     dk, dv = _bwd_call(
         _fa_dkv_kernel, "dkv", plan, args, grid=(BH, plan.nk, _steps(plan, True)),
-        in_specs=[qspec_in, kspec_out, kspec_out, qspec_in, qspec_in,
-                  pl.BlockSpec((1, bq, 128), queries)],
-        out_specs=[kspec_out, kspec_out], out_like=(k, v), scratch=[(bk, D)] * 2)
+        in_specs=[spec(bq, Dk, queries), spec(bk, Dk, own), spec(bk, Dv, own),
+                  spec(bq, Dv, queries), spec(bq, Dv, queries), spec(bq, 128, queries)],
+        out_specs=[spec(bk, Dk, own), spec(bk, Dv, own)], out_like=(k, v),
+        scratch=[(bk, Dk), (bk, Dv)])
     return dq, dk, dv
 
 
@@ -1008,7 +1033,8 @@ def _fa_bwd_pallas(q, k, v, do, o, lse, dlse, lens, causal, scale, interpret,
     ``dlse=None`` (the plain-attention path) omits the operand entirely —
     an all-zero lane-replicated dlse would otherwise add an arena-sized HBM
     read to every backward kernel for nothing. ``lens=None``: no ``kv_lens``."""
-    plan = _tile_plan(q.shape[1], k.shape[1], q.shape[2], causal, window)
+    Dk, Dv = _widths(q, v)
+    plan = _tile_plan(q.shape[1], k.shape[1], Dk, causal, window, Dv)
     calls = _fa_bwd_fused if plan.one_pass else _fa_bwd_two_calls
     return calls(plan, q, k, v, do, o, lse, dlse, lens, scale, interpret, rate, seed)
 
@@ -1106,7 +1132,8 @@ def _seed_from_key(key: jax.Array) -> jax.Array:
 
 def flash_attention_with_lse(q3, k3, v3, *, causal, scale, kv_lens=None,
                              window=None):
-    """(BH, S, D) flash attention returning (o, lse (BH, S)) — the merge
+    """``q3, k3 (BH, S, Dk)``, ``v3 (BH, Sk, Dv)`` flash attention returning
+    ``(o (BH, S, Dv), lse (BH, S))`` — the merge
     interface for blockwise/ring composition (lse = m + log l per row;
     fully-masked rows carry lse = -1e30 so their merge weight underflows to
     exactly zero). Differentiable in q/k/v AND through lse (the backward
@@ -1189,10 +1216,14 @@ def flash_attention(
 ) -> jax.Array:
     """Fused scaled-dot-product attention.
 
-    q, k, v: (B, H, S, D). ``kv_lens``: optional (B,) int key lengths — keys
-    at index >= len are masked out (the reference fmha's variable-seqlen
-    support, ref: apex/contrib/fmha/fmha.py:33-60, expressed padded-dense).
-    Returns (B, H, S, D) in q's dtype. fp32 accumulation throughout.
+    q: (B, H, S, Dk), k: (B, H, Sk, Dk), v: (B, H, Sk, Dv) — values may be
+    narrower or wider than queries and keys (latent attention: ``Dk`` 192 over
+    ``Dv`` 128); the kernels compute scores at ``Dk`` and values at ``Dv``, with
+    no copy padded to a common width. ``kv_lens``: optional (B,) int key
+    lengths — keys at index >= len are masked out (the reference fmha's
+    variable-seqlen support, ref: apex/contrib/fmha/fmha.py:33-60, expressed
+    padded-dense). Returns (B, H, S, Dv) in q's dtype; ``scale`` defaults to
+    ``Dk^-1/2``. fp32 accumulation throughout.
 
     ``window`` (with ``causal=True``): sliding-window attention — query ``i``
     sees the ``window`` keys ``i - window < j <= i``, itself among them. The
@@ -1220,8 +1251,12 @@ def flash_attention(
         q, k, v = q.astype(act), k.astype(act), v.astype(act)
     B, H, S, D = q.shape
     Sk = k.shape[2]
-    if k.shape != v.shape or k.shape[:2] != q.shape[:2] or k.shape[3] != D:
-        raise ValueError(f"q/k/v shapes mismatch, got {q.shape}/{k.shape}/{v.shape}")
+    if v.ndim != 4 or k.shape[:3] != v.shape[:3] or k.shape[:2] != q.shape[:2] \
+            or k.shape[3] != D:
+        raise ValueError(
+            f"expected q (B, H, S, Dk), k (B, H, Sk, Dk), v (B, H, Sk, Dv), got "
+            f"{q.shape}/{k.shape}/{v.shape}")
+    Dv = v.shape[3]
     if causal and Sk != S:
         raise ValueError(
             f"causal attention needs matching q/k lengths, got {S} vs {Sk}"
@@ -1246,7 +1281,7 @@ def flash_attention(
             )
         impl = "jnp"
     if impl == "pallas" and not (
-        is_flash_available(S, D) and is_flash_available(Sk, D)
+        is_flash_available(S, D, Dv) and is_flash_available(Sk, D, Dv)
     ):
         if forced:
             # resolve_impl's contract: an explicit impl= is always honored —
@@ -1254,7 +1289,7 @@ def flash_attention(
             raise ValueError(
                 f"impl='pallas' forced but shapes don't tile the kernel: "
                 f"q len {S} / kv len {Sk} (both need % {_MIN_BLOCK} == 0), "
-                f"head_dim={D} (needs 8..512); pass impl=None for automatic "
+                f"head dims {D} (q, k) / {Dv} (v) (both need 8..512); pass impl=None for automatic "
                 f"fallback"
             )
         impl = "jnp"
@@ -1271,7 +1306,7 @@ def flash_attention(
 
     q3 = q.reshape(B * H, S, D)
     k3 = k.reshape(B * H, Sk, D)
-    v3 = v.reshape(B * H, Sk, D)
+    v3 = v.reshape(B * H, Sk, Dv)
     with _span("flash_attention"):  # XProf range (NVTX idiom); stays innermost
         if impl == "pallas":
             if dropout_rate > 0.0:
@@ -1304,9 +1339,9 @@ def flash_attention(
         else:
             o = _attn_jnp(q3, k3, v3, lens_bh, causal, scale,
                           dropout_rate, dropout_key, window)
-    # remat boundary tag: the attention context is a cheap (B, H, S, D)
+    # remat boundary tag: the attention context is a cheap (B, H, S, Dv)
     # save point vs the O(S^2) score/prob intermediates behind it
-    return _checkpoint_name(o.reshape(B, H, S, D), _TAG_ATTN_OUT)
+    return _checkpoint_name(o.reshape(B, H, S, Dv), _TAG_ATTN_OUT)
 
 
 def self_attention(

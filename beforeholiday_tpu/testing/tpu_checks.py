@@ -480,6 +480,77 @@ def check_short_conv(results: list, S: int = 8192, D: int = 2048) -> None:
     check("fwd_bwd_ms_a_layer", chain["pallas"] < chain["jnp"], json.dumps(chain))
 
 
+def check_flash_mla(results: list, H: int = 32, S: int = 8192, Dk: int = 192, Dv: int = 128,
+                    blocks=(512, 1024)) -> None:
+    """Flash attention at two widths (``ops.attention``: latent attention's
+    ``Dk``-wide queries and keys on ``Dv``-wide values), compiled, at the Kanana
+    cell's call ``(1, H, S, Dk / Dv)`` bfloat16, causal: the output and the three
+    cotangents against the jnp path at a length whose scores the oracle can hold
+    (S <= 2048: the two-call backward at Dk 192, blocks of 512), then ms a layer of
+    the forward and of the dq + dkv pair at the cell's length under each grid
+    block of ``blocks`` (``_block_size`` gives 1024 at 192 / 128 since these
+    readings: each is read by putting a ladder in its place for one fresh
+    function), with the share of the bf16 peak the required operations reach."""
+    from beforeholiday_tpu.monitor.roofline import _resolve_chip
+    from beforeholiday_tpu.ops import attention as A
+
+    def check(name, cond, info=""):
+        results.append((f"flash_mla/{name}", bool(cond), str(info)))
+
+    bf, scale = jnp.bfloat16, Dk ** -0.5
+
+    def inputs(S):
+        ks = jax.random.split(jax.random.PRNGKey(42), 4)
+        shape = lambda D: (1, H, S, D)
+        return tuple(jax.random.normal(k, shape(D)).astype(bf)
+                     for k, D in zip(ks, (Dk, Dk, Dv, Dv)))
+
+    def both(impl):
+        def run(q, k, v, do):
+            o, pull = jax.vjp(
+                lambda *a: A.flash_attention(*a, causal=True, scale=scale, impl=impl), q, k, v)
+            return (o,) + pull(do)
+        return jax.jit(run)
+
+    args = inputs(min(S, 2048))
+    got, want = both("pallas")(*args), both("jnp")(*args)
+    # bfloat16 results of float32 sums taken in another order: the tolerance the
+    # other compiled kernels are held to (``check_flash_tiles``)
+    for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        gap, size = float(jnp.max(jnp.abs(a - b))), float(jnp.max(jnp.abs(b)))
+        ok = bool(jnp.all(jnp.isfinite(a))) and gap <= 3e-2 * size
+        check(f"parity/{name}", ok, f"shape {a.shape} max|d|={gap:.3e} of {size:.3e}")
+    check("widths", [t.shape[-1] for t in got] == [Dv, Dk, Dk, Dv], [t.shape for t in got])
+
+    q, k, v, do = (t[0] for t in inputs(S))
+    seed = jnp.zeros((1,), jnp.int32)
+    flops = 2 * H * (Dk + Dv) * S * (S + 1) / 2            # forward; backward twice that
+    peak, ms = _resolve_chip(None).peak_tflops * 1e12, {}
+    ladder = A._block_size
+    for block in blocks:
+        A._block_size = lambda s, *widths, b=block: b if s % b == 0 else ladder(s, *widths)
+        A._tile_plan.cache_clear()
+        try:
+            fwd = jax.jit(lambda q, k, v: A._flash3(q, k, v, None, seed, True, scale, 0.0))
+            interpret = A._interpret_default()          # False on the chip
+            o, lse = jax.jit(lambda q, k, v: A._fa_fwd_pallas(
+                q, k, v, None, True, scale, interpret))(q, k, v)
+            bwd = jax.jit(lambda q, k, v, do, o, lse: A._fa_bwd_pallas(
+                q, k, v, do, o, lse, None, None, True, scale, interpret))
+            t_f = _min_step_seconds(lambda _: fwd(q, k, v), None)
+            t_b = _min_step_seconds(lambda _: bwd(q, k, v, do, o, lse), None)
+            ms[str(block)] = {"fwd_ms": round(1e3 * t_f, 3), "bwd_ms": round(1e3 * t_b, 3),
+                              "fwd_pct_of_peak": round(100 * flops / peak / t_f, 1),
+                              "bwd_pct_of_peak": round(100 * 2 * flops / peak / t_b, 1)}
+        except Exception as e:  # noqa: BLE001 — a block Mosaic refuses is a reading too
+            ms[str(block)] = f"{type(e).__name__}: {str(e)[:120]}"
+        finally:
+            A._block_size = ladder
+            A._tile_plan.cache_clear()
+    check("ms_a_layer_by_block", any(isinstance(v, dict) for v in ms.values()), json.dumps(ms))
+
+
 # (tag, buffer rows, groups, K, N, rows in a group, the product's dtype)
 _GROUPED_SHAPES = (
     ("mellum_up", 24576, 16, 2304, 896, 16400, jnp.float32),
@@ -1123,7 +1194,7 @@ def main() -> int:
     enable_compile_cache()
     results: list = []
     for group in (check_flash_dropout, check_flash_tiles, check_wy_prepare, check_deltanet,
-                  check_short_conv, check_grouped_matmul, check_moe_rows, check_ssd, check_aliased_mt_kernels,
+                  check_short_conv, check_flash_mla, check_grouped_matmul, check_moe_rows, check_ssd, check_aliased_mt_kernels,
                   check_compiled_kernel_parity):
         try:
             group(results)

@@ -961,3 +961,156 @@ class TestFusedBackward:
         names = [e.params["name"] for e in _pallas_eqns(
             jax.make_jaxpr(jax.grad(flash, argnums=(0, 1, 2)))(q, k, v).jaxpr)]
         assert names == ["flash_attention_window_fwd", "flash_attention_window_dqkv"]
+
+
+# -- queries and keys of one width, values of another (latent attention; PR 42) ------
+
+# (Dk, Dv, S): a head that is one block (the fused backward) and one of several
+# (the dq + dkv pair: 5 x 5 blocks of 256) at each pair of widths
+TWO_WIDTH_SHAPES = [(192, 128, 256), (192, 128, 1280), (64, 128, 256), (64, 128, 1280)]
+
+
+class TestTwoWidths:
+    """``q, k (.., Dk)`` on ``v (.., Dv)``: every output and cotangent of the
+    kernels (interpret mode) against the jnp oracle, which takes the two widths
+    by itself; scores are ``Dk`` deep and default to ``Dk^-1/2``, values and the
+    result are ``Dv`` wide, ``dq`` / ``dk`` come back ``Dk`` wide and ``dv``
+    ``Dv`` wide, and nothing is padded to a common width."""
+
+    BH = 2
+
+    def _inputs(self, Dk, Dv, S, seed=31):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+        q, k = (jax.random.normal(kk, (self.BH, S, Dk), jnp.float32) for kk in ks[:2])
+        v, w = (jax.random.normal(kk, (self.BH, S, Dv), jnp.float32) for kk in ks[2:4])
+        return q, k, v, w, jax.random.normal(ks[4], (self.BH, S), jnp.float32)
+
+    @pytest.mark.parametrize("lens", [None, "inside-a-tile"])
+    @pytest.mark.parametrize("Dk,Dv,S", TWO_WIDTH_SHAPES, ids=lambda x: str(x))
+    def test_causal_matches_oracle(self, Dk, Dv, S, lens):
+        q, k, v, w, _ = self._inputs(Dk, Dv, S)
+        scale = 1.0 / np.sqrt(Dk)
+        plan = A._tile_plan(S, S, Dk, True, None, Dv)
+        assert plan.one_pass == (S == 256) and plan.bq == 256
+        kv = None if lens is None else jnp.asarray((S - 70, S), jnp.float32)
+        seed = jnp.zeros((1,), jnp.int32)
+        flash = lambda q, k, v: A._flash3(q, k, v, kv, seed, True, scale, 0.0)
+        got = (flash(q, k, v),) + jax.grad(
+            lambda *a: jnp.sum(flash(*a) * w), argnums=(0, 1, 2))(q, k, v)
+        full = jnp.full((self.BH,), float(S)) if kv is None else kv
+        want = _oracle_grads(q, k, v, full, True, scale, w)
+        assert [a.shape[-1] for a in got] == [Dv, Dk, Dk, Dv]
+        for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+            assert not np.any(np.isnan(np.asarray(a))), name
+            np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5, err_msg=name)
+
+    @pytest.mark.parametrize("Dk,Dv,S", TWO_WIDTH_SHAPES[:3], ids=lambda x: str(x))
+    def test_lse_variant_with_dlse(self, Dk, Dv, S):
+        q, k, v, w, wl = self._inputs(Dk, Dv, S, 32)
+        scale = 1.0 / np.sqrt(Dk)
+        lens = jnp.asarray((S - 70, S), jnp.float32)
+
+        def loss(q, k, v):
+            o, lse = A.flash_attention_with_lse(q, k, v, causal=True, scale=scale, kv_lens=lens)
+            assert o.shape == (self.BH, S, Dv) and lse.shape == (self.BH, S)
+            return jnp.sum(o * w) + jnp.sum(lse * wl)
+
+        got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        want = _oracle_grads(q, k, v, lens, True, scale, w, wl)[1:]
+        for name, a, b in zip(("dq", "dk", "dv"), got, want):
+            np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4, err_msg=name)
+
+    @pytest.mark.parametrize("Dk,Dv", [(192, 128), (64, 128)])
+    def test_non_causal_with_kv_lens_through_the_public_call(self, Dk, Dv):
+        """(B, H, S, D) operands, more keys than queries, a key length inside a
+        block; the default scale is ``Dk^-1/2``."""
+        ks = jax.random.split(jax.random.PRNGKey(33), 4)
+        q = jax.random.normal(ks[0], (2, 2, 256, Dk), jnp.float32)
+        k = jax.random.normal(ks[1], (2, 2, 384, Dk), jnp.float32)
+        v = jax.random.normal(ks[2], (2, 2, 384, Dv), jnp.float32)
+        w = jax.random.normal(ks[3], (2, 2, 256, Dv), jnp.float32)
+        lens = jnp.asarray([300, 384])
+
+        def grads(impl, **kw):
+            f = lambda *a: A.flash_attention(*a, kv_lens=lens, impl=impl, **kw)
+            return (f(q, k, v),) + jax.grad(lambda *a: jnp.sum(f(*a) * w), (0, 1, 2))(q, k, v)
+
+        got, want = grads("pallas"), grads("jnp", scale=Dk ** -0.5)
+        assert got[0].shape == (2, 2, 256, Dv)
+        for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+            np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5, err_msg=name)
+
+    def test_bfloat16_at_the_latent_attention_widths(self):
+        q, k, v, w, _ = (t.astype(jnp.bfloat16) if t.ndim == 3 else t
+                         for t in self._inputs(192, 128, 512, 34))
+        got = A.flash_attention(q[None], k[None], v[None], causal=True, impl="pallas")
+        want = A.flash_attention(q[None], k[None], v[None], causal=True, impl="jnp")
+        assert got.dtype == jnp.bfloat16 and got.shape == (1, self.BH, 512, 128)
+        np.testing.assert_allclose(got.astype(np.float32), want.astype(np.float32),
+                                   atol=2e-2, rtol=2e-2)
+
+    def test_the_plan_takes_both_widths(self):
+        """Blocks of 1024 where the values are one lane tile wide and the keys at
+        most two: 192 / 128 (measured on the chip, PR 42) as every one-width call
+        at D <= 128; a one-width call at 192 or 256 keeps 512. A head of 192 /
+        128 at S = 8192 is 8 x 8 blocks and its backward the dq + dkv pair; both
+        widths are in the tiles' key."""
+        from beforeholiday_tpu import monitor
+        from beforeholiday_tpu.guard import dispatch
+
+        plan = A._tile_plan(8192, 8192, 192, True, None, 128)
+        assert (plan.bq, plan.nq, plan.tq, plan.one_pass) == (1024, 8, 256, False)
+        assert A._tile_plan(8192, 8192, 64, True, None, 128) == A._tile_plan(8192, 8192, 128, True)
+        for one_width in (192, 256):
+            assert A._tile_plan(8192, 8192, one_width, True).bq == 512
+        assert A._tile_plan(8192, 8192, 128, True, None, 192).bq == 512      # wide values
+        assert A._tile_plan(8192, 8192, 128, True, None, 128) == A._tile_plan(8192, 8192, 128, True)
+        key = (2048, 2048, (192, 128), True, False)
+        for kernel in ("fwd", "dq", "dkv"):
+            dispatch._TILES.pop(("flash_attention", kernel, key), None)
+        q, k, v, w, _ = self._inputs(192, 128, 2048)
+        jax.grad(lambda *a: jnp.sum(A._flash3(
+            *a, None, jnp.zeros((1,), jnp.int32), True, 0.1, 0.0)))(q, k, v)
+        rows = {r["kernel"]: r for r in monitor.tile_records() if r["key"] == repr(key)}
+        assert sorted(rows) == ["dkv", "dq", "fwd"]
+        assert all((r["total"], r["live"], r["masked"]) == (64, 36, 8) for r in rows.values())
+
+    def test_one_width_books_the_tiles_it_booked(self):
+        """A ``Dv == Dk`` call's key holds the one width as an int, as before
+        the kernels knew two (``monitor.tile_records()`` rows keep their keys)."""
+        from beforeholiday_tpu.guard import dispatch
+
+        before = set(dispatch.tile_counters())
+        q, k, v = (t[0] for t in _qkv(jax.random.PRNGKey(35), B=1, H=2, S=384, D=64))
+        jax.grad(lambda *a: jnp.sum(A._flash3(
+            *a, None, jnp.zeros((1,), jnp.int32), True, 0.1, 0.0)))(q, k, v)
+        new = set(dispatch.tile_counters()) - before
+        assert new == {("flash_attention", kernel, (384, 384, 64, True, False))
+                       for kernel in ("fwd", "dq", "dkv")}
+
+    def test_no_operand_is_padded_to_a_common_width(self):
+        """The kernels' operands are the caller's arrays at their own widths:
+        no ``pad`` / ``concatenate`` before a ``pallas_call``, whose operands are
+        192 and 128 wide and whose results are 192 (dq, dk) and 128 (o, dv)."""
+        q, k, v, w, _ = self._inputs(192, 128, 2048)
+        flash = lambda *a: A.flash_attention(*(t[None] for t in a), causal=True, impl="pallas")
+        jaxpr = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2)))(q, k, v)
+        calls = _pallas_eqns(jaxpr.jaxpr)
+        assert len(calls) == 3          # fwd, dq, dkv
+        widths = lambda vs: sorted({x.aval.shape[-1] for x in vs} - {1})
+        for eqn in calls:
+            assert widths(eqn.invars) == [128, 192]
+        assert [widths(e.outvars) for e in calls[-2:]] == [[192], [128, 192]]
+        names = {e.primitive.name for e in jaxpr.jaxpr.eqns}
+        assert not names & {"pad", "concatenate"}
+
+    def test_shape_errors_name_both_widths(self):
+        q, k, v = _qkv(jax.random.PRNGKey(36), S=128, D=64)
+        with pytest.raises(ValueError, match=r"q \(B, H, S, Dk\), k \(B, H, Sk, Dk\), v \(B, H, Sk, Dv\)"):
+            A.flash_attention(q, k[..., :32], v)
+        with pytest.raises(ValueError, match=r"q \(B, H, S, Dk\)"):
+            A.flash_attention(q, k, v[:, :, :64])
+        with pytest.raises(ValueError, match=r"head dims 64 \(q, k\) / 4 \(v\)"):
+            A.flash_attention(q, k, v[..., :4], impl="pallas")
+        assert A.is_flash_available(128, 192, 128) and not A.is_flash_available(128, 192, 4)
+        assert A.is_flash_available(128, 64) and not A.is_flash_available(100, 64, 64)

@@ -53,8 +53,10 @@ def compiled_not_interpreted(monkeypatch):
     (8192, 8, 2688, 1024, jnp.bfloat16),        # ... down
     (12288, 8, 2048, 1792, jnp.float32),        # the LFM2 cell, gate / up: 14 lane tiles
     (12288, 8, 1792, 2048, jnp.bfloat16),       # ... down
+    (9216, 16, 2048, 768, jnp.float32),         # the Kanana cell, gate / up: 6 lane tiles
+    (9216, 16, 768, 2048, jnp.bfloat16),        # ... down
 ), ids=("mellum_up", "mellum_down", "qwen_up", "qwen_down", "ragged_buffer", "nemotron_up",
-        "nemotron_down", "lfm2_up", "lfm2_down"))
+        "nemotron_down", "lfm2_up", "lfm2_down", "kanana_up", "kanana_down"))
 def test_the_grouped_matmul_kernels_compile_for_the_chip(one_chip, compiled_not_interpreted,
                                                          R, E, K, N, out):
     gm = compiled_not_interpreted
@@ -124,7 +126,8 @@ def _one_shot_rows(monkeypatch, dropless):
     (8192, 16384, 2048, 10, 32, 512, True),     # the Qwen cell
     (8192, 24576, 2304, 8, 16, 896, True),      # the Mellum cell
     (8192, 8192, 1024, 22, 8, 2688, False),     # the Nemotron cell (the latent; k > held)
-), ids=("qwen", "mellum", "nemotron"))
+    (8192, 9216, 2048, 6, 16, 768, True),       # the Kanana cell
+), ids=("qwen", "mellum", "nemotron", "kanana"))
 def test_the_dropless_layer_compiles_for_the_chip_with_its_rows_moved_in_loops(
         one_chip, compiled_not_interpreted, monkeypatch, T, R, D, k, held, F, gated):
     """Forward + backward of ``dropless_experts`` at a cell's shapes: the four
@@ -314,6 +317,41 @@ def test_the_fused_flash_backward_compiles_for_the_chip(one_chip, flash_compiled
         x, x, x, shape((BH,), jnp.float32), shape((1,), jnp.int32), x,
         shape((BH, S), jnp.float32)).compile().as_text()
     assert text.count("tpu_custom_call") == 2
+
+
+@pytest.mark.parametrize("BH,S,Dk,Dv,calls", (
+    (32, 8192, 192, 128, 3),                    # the Kanana cell: 8 x 8 blocks of 1,024 a head
+    (8, 1024, 192, 128, 2),                     # a head that is one block: the fused backward
+    (8, 2048, 64, 128, 3),                      # narrower keys than values: blocks of 1,024
+), ids=("kanana_cell", "one_block", "narrow_keys"))
+@pytest.mark.parametrize("variant", ("plain", "lens_dlse"))
+def test_the_two_width_flash_kernels_compile_for_the_chip(one_chip, flash_compiled, BH, S, Dk, Dv,
+                                                          calls, variant):
+    """``q, k`` at ``Dk`` on ``v`` at ``Dv`` (latent attention: 192 on 128, one
+    and a half lane tiles of scores): the forward and both backward plans at the
+    cell's call, where 192-wide blocks and a 192-deep product are what Mosaic
+    could refuse; results come at their own widths, with no ``pad`` in the
+    program."""
+    A = flash_compiled
+    bf = jnp.bfloat16
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    scale = Dk ** -0.5
+
+    def both(q, k, v, lens, seed, do, dlse):
+        if variant == "lens_dlse":
+            (o, lse), pull = jax.vjp(lambda *a: A._flash3_lse(*a, lens, True, scale), q, k, v)
+            return o, pull((do, dlse))
+        o, pull = jax.vjp(lambda *a: A._flash3(*a, None, seed, True, scale, 0.0), q, k, v)
+        return o, pull(do)
+
+    args = (shape((BH, S, Dk), bf), shape((BH, S, Dk), bf), shape((BH, S, Dv), bf),
+            shape((BH,), jnp.float32), shape((1,), jnp.int32), shape((BH, S, Dv), bf),
+            shape((BH, S), jnp.float32))
+    text = jax.jit(both).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == calls
+    o, (dq, dk, dv) = jax.eval_shape(both, *args)
+    assert [t.shape[-1] for t in (o, dq, dk, dv)] == [Dv, Dk, Dk, Dv]
+    assert not re.search(r"= [a-z0-9]+\[[\d,]*\]\S* pad\(", text)
 
 
 def test_same_step_reads_two_texts_as_one_program_when_only_source_lines_moved(

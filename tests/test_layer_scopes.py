@@ -16,6 +16,7 @@ scanned block is a ``closed_call``), which is what the profiler shows.
 """
 
 import contextlib
+import json
 import os
 import re
 import sys
@@ -759,3 +760,94 @@ def test_the_short_conv_kernels_carry_their_names_under_the_mixer_in_both_passes
             assert _pass_of(n) == want, (k, n)
             assert re.search(rf"conv_mixer\)*/short_conv\)*/jit\(_(?:fwd|bwd)\)/{k}/", n), n
             assert not re.search(r"layer_norm|flash_attention|grouped_matmul|/moe/", n), n
+
+
+# ---------------------------------------------------------------------------
+# family deepseek_v3 (PR 42): mla_mixer / mla_latent, dense_ffn, the MoE with
+# its shared expert and the model's four scopes
+# ---------------------------------------------------------------------------
+
+_DSV3_MODEL = ("deepseek_v3_embed", "deepseek_v3_layers", "deepseek_v3_head", "deepseek_v3_loss")
+_DSV3_SCOPES = _DSV3_MODEL + (
+    "mla_mixer", "mla_latent", "dense_ffn", "amp_forward", "amp_backward", "amp_unscale",
+    "fused_adam_step_flat", "layer_norm", "flash_attention", "moe_route", "moe_dispatch",
+    "moe_experts", "moe_shared", "moe_combine")
+
+
+@pytest.fixture(scope="module")
+def dsv3_names():
+    """The distinct ``op_name`` of every op of the compiled tiny-deepseek-v3 step."""
+    from benchmark import run as bench_run
+
+    cell = bench_run.load("workloads", "tiny-deepseek-v3.train")
+    run = bench_run.Cell(cell, bench_run.load("configs", cell["config"]), jax.devices()[:1])
+    run.start(7)
+    run.build()
+    compiled = run.program.step.jitted.lower(run.state, run.pool[0]).compile()
+    return sorted(set(re.findall(r'op_name="(jit\([^"]+)"', compiled.as_text())))
+
+
+@pytest.mark.parametrize("scope", _DSV3_SCOPES)
+def test_deepseek_v3_scope_is_in_the_compiled_step(dsv3_names, scope):
+    assert any(scope in _scopes_of(n) or f"jvp({scope})" in n for n in dsv3_names), scope
+
+
+def test_deepseek_v3_first_level_scopes_partition_the_step(dsv3_names):
+    first = ("amp_backward", "amp_unscale", "ddp_reduce_gradients",
+             "ddp_overlap_hook", "fused_adam_step_flat")
+    twice = [n for n in dsv3_names
+             if sum(s in n for s in first) + (_pass_of(n) == "amp_forward") > 1]
+    assert not twice
+    both = [n for n in dsv3_names if "amp_forward" in n and "amp_backward" in n]
+    assert all("amp_backward/transpose(amp_forward)" in n for n in both), both
+    for scope in _DSV3_MODEL:        # the model's scopes survive inside both passes
+        assert any(f"amp_forward/jvp({scope})" in n for n in dsv3_names), scope
+        assert any(_pass_of(n) == "amp_backward" and f"jvp({scope})" in n
+                   for n in dsv3_names), scope
+
+
+def test_deepseek_v3_second_level_scopes_do_not_overlap(dsv3_names):
+    """An op is under one model scope at most, and under the mixer, the dense
+    feed-forward part or the MoE at most; ``mla_latent`` and ``flash_attention``
+    lie inside ``mla_mixer`` and not inside each other, ``moe_shared`` inside
+    ``moe``, all inside ``deepseek_v3_layers``; no name of the family holds
+    another family's metric pattern."""
+    in_path = lambda s, n: any(s == part.strip("()").split("(")[-1] for part in _scopes_of(n))
+    for n in dsv3_names:
+        assert sum(f"({s})" in n or s in _scopes_of(n) for s in _DSV3_MODEL) <= 1, n
+        parts = [s for s in ("mla_mixer", "dense_ffn", "moe") if in_path(s, n)]
+        assert len(parts) <= 1, n
+        if parts and _pass_of(n):
+            assert "deepseek_v3_layers" in n, n
+        inner = [s for s in ("mla_latent", "flash_attention") if in_path(s, n)]
+        assert len(inner) <= 1, n
+        if inner:
+            assert parts == ["mla_mixer"], n
+        if in_path("moe_shared", n):
+            assert parts == ["moe"], n
+        assert not re.search(r"gated_delta|ssd|window_mixer|full_mixer|ssm_mixer|attn_mixer|"
+                             r"conv_mixer|short_conv|moe_latent|linear_mixer", n), n
+    heavy = [n for n in dsv3_names if n.endswith("dot_general")]
+    assert heavy and not [n for n in heavy if _pass_of(n) is None]
+    # the latent's two products and its norm are under mla_latent in both passes
+    latent = [n for n in dsv3_names if in_path("mla_latent", n)]
+    assert {_pass_of(n) for n in latent} >= {"amp_forward", "amp_backward"}
+    assert any(n.endswith("dot_general") for n in latent)
+    assert any(in_path("layer_norm", n) for n in latent)
+
+
+def test_the_mixers_glue_is_what_its_metric_reads(dsv3_names):
+    """``mla_glue_ms`` reads what lies under ``mla_mixer`` outside
+    ``flash_attention`` and is not a ``dot_general``: the rotary embedding, the
+    splits, ``k_rot``'s broadcast, the concatenations and ``dk_rot``'s sum are
+    there, and no product is."""
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark", "layer_metrics",
+                           "mla_glue_ms.deepseek_v3.json")) as f:
+        pattern = re.compile(json.load(f)["pattern"])
+    glue = [n for n in dsv3_names if pattern.search(n)]
+    assert glue and all("mla_mixer" in n for n in glue)
+    assert not [n for n in glue if "flash_attention" in n or "dot_general" in n]
+    kinds = {n.rsplit("/", 1)[-1] for n in glue}
+    assert kinds & {"concatenate", "mul", "broadcast_in_dim", "reduce_sum", "slice", "transpose"}
+    under = [n for n in dsv3_names if "mla_mixer" in n]
+    assert any(n.endswith("dot_general") for n in under) and len(glue) < len(under)

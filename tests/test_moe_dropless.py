@@ -739,3 +739,38 @@ def test_the_gradient_of_a_layer_moves_rows_inside_loops_only(monkeypatch):
     assert all(m[2] == (tile, D) for m in moved), moved
     # the lowered module holds them as ``while`` ops (a body may be shared)
     assert "stablehlo.while" in jax.jit(jax.grad(program, argnums=(0, 1))).lower(x, p).as_text()
+
+
+# -- the third shared expert: an ungated SwiGLU (PR 42) -----------------------------
+
+def test_an_ungated_swiglu_shared_expert_is_selected_by_its_keys(impl):
+    """``shared_w_gate`` without ``shared_score``: the routed part plus
+    ``swiglu(x)``, summed in float32 and cast once, under the ``moe_shared``
+    span; not the gated form (which reads ``shared_score``) and not the
+    ``relu^2`` pair (which has no ``shared_w_gate``)."""
+    p, x = layer_params(11), tokens(11)
+    ungated = {k: v for k, v in p.items() if k != "shared_score"}
+    route = functools.partial(dropless.route_sigmoid, bias=jnp.zeros((E,)), scale=2.448)
+    got, counters = dropless.dropless_moe(x, ungated, top_k=K, route=route, impl=impl)
+    w, idx = route(x, p["router"], K, renormalize=True)
+    routed, want_counters = dropless.dropless_experts(x, w, idx, held_slice(p, 0, E), impl=impl)
+    shared = dropless.swiglu(x, p["shared_w_gate"], p["shared_w_up"], p["shared_w_down"])
+    assert got.dtype == x.dtype
+    assert bool(jnp.array_equal(got, (routed + shared.astype(jnp.float32)).astype(x.dtype)))
+    assert {k: float(v) for k, v in counters.items()} == \
+        {k: float(v) for k, v in want_counters.items()}
+    size = float(jnp.max(jnp.abs(got)))
+    gated, _ = dropless.dropless_moe(x, p, top_k=K, route=route, impl=impl)
+    squared, _ = dropless.dropless_moe(
+        x, {k: v for k, v in ungated.items() if k != "shared_w_gate"}, top_k=K, route=route,
+        impl=impl)
+    for other in (gated, squared):
+        assert float(jnp.max(jnp.abs(other - got))) > 1e-2 * size
+    hlo = jax.jit(lambda x, p: dropless.dropless_moe(
+        x, p, top_k=K, route=route, impl=impl)[0]).lower(x, ungated).compile().as_text()
+    assert "moe/moe_shared" in hlo
+    # a gradient reaches each of its three matrices and no ``shared_score`` is asked for
+    grads = jax.grad(lambda p: jnp.sum(jnp.square(dropless.dropless_moe(
+        x, p, top_k=K, route=route, impl=impl)[0])))(ungated)
+    for name in ("shared_w_gate", "shared_w_up", "shared_w_down"):
+        assert float(jnp.max(jnp.abs(grads[name]))) > 0, name

@@ -124,3 +124,26 @@ def test_the_short_conv_check_runs_its_comparison():
     for k in compared:
         assert by_name[f"short_conv/{k}"][0], by_name
     assert "check_short_conv" in inspect.getsource(tpu_checks.main)
+
+
+def test_the_flash_mla_check_runs_its_comparison():
+    """The chip check of the two-width flash kernels at a toy length on the
+    interpreter: the output and the three cotangents at 192 / 128 are compared
+    with the jnp path's (the timings are not judged here), each grid block is
+    read through a ladder that is put back, and ``main`` runs the group."""
+    import inspect
+    import json
+
+    from beforeholiday_tpu.ops import attention as A
+
+    ladder, results = A._block_size, []
+    tpu_checks.check_flash_mla(results, H=2, S=256, blocks=(128, 256))
+    by_name = {name: (ok, info) for name, ok, info in results}
+    compared = ("parity/o", "parity/dq", "parity/dk", "parity/dv", "widths")
+    assert set(by_name) == {f"flash_mla/{k}" for k in compared + ("ms_a_layer_by_block",)}
+    for k in compared:
+        assert by_name[f"flash_mla/{k}"][0], by_name
+    read = json.loads(by_name["flash_mla/ms_a_layer_by_block"][1])
+    assert sorted(read) == ["128", "256"] and all(isinstance(v, dict) for v in read.values()), read
+    assert A._block_size is ladder and A._tile_plan(8192, 8192, 192, True, None, 128).bq == 1024
+    assert "check_flash_mla" in inspect.getsource(tpu_checks.main)
