@@ -39,9 +39,14 @@ The backward is one kernel or two by what the plan says carries over between
 grid steps (:func:`_fa_bwd_pallas`). Where a head is one block
 (``TilePlan.one_pass``: causal, S <= 1024 at D <= 128, S <= 512 above) nothing
 does, and ONE call recomputes the scores once and returns dq, dk and dv
-(booked as ``dqkv``). Everywhere else dq sums over key blocks and dk / dv over
-query blocks, two grid orders, so a dq call and a dkv call each recompute the
-scores (booked as ``dq`` and ``dkv``).
+(booked as ``dqkv``). Where a causal head without a window is several blocks
+(S = 8192: 8 x 8 or 16 x 16) dk / dv sum over query blocks in a block-sized
+accumulator and dq over key blocks in a float32 scratch that holds the whole
+head in VMEM (``Sq * Dk * 4 <= _HEAD_DQ_BYTES``), so again ONE call on the dkv
+kernel's grid recomputes once (booked as ``dqkv_blocks``). Everywhere else — a
+non-causal call, a windowed plan, a head too long for VMEM — two grid orders, so
+a dq call and a dkv call each recompute the scores (booked as ``dq`` and
+``dkv``).
 
 Variable-length batches are expressed as per-sequence key lengths
 (``kv_lens``) rather than the reference's packed cu_seqlens: on TPU the
@@ -109,7 +114,9 @@ def _block_size(seq_len: int, head_dim: int = 64, v_head_dim: Optional[int] = No
     wide (``v_head_dim <= 128``) and the queries and keys at most two
     (``head_dim <= 256``) — the dkv backward holds ~6 operand blocks plus two
     fp32 scratch accumulators and (bq, bk) fp32 intermediates, which with wider
-    values would push past the ~16 MB VMEM budget. For a call of one width that
+    values would push past the ~16 MB VMEM budget Mosaic gives a kernel that
+    asks for none (the fused backward of several blocks runs the same body and
+    asks for its own: :func:`_blocks_vmem_bytes`). For a call of one width that
     is D <= 128, as it always was. Of two widths, 192 / 128 (latent attention:
     three of the five operands and both outputs of the forward are 128 wide)
     takes 1024 and is faster for it: one layer at (32 heads, S=8192), fwd / dq +
@@ -752,9 +759,10 @@ def _fa_fwd_pallas(q, k, v, lens, causal, scale, interpret, rate=0.0, seed=None,
 
 
 # ---------------------------------------------------------------------------------
-# backward: dq kernel (grid BH, nq, nk) + dkv kernel (grid BH, nk, nq), or where
-# a head is one block the two in one (grid BH, 1, 1); all recompute block scores
-# from (q, k, lse) — flash-attention rematerialization
+# backward: dq kernel (grid BH, nq, nk) + dkv kernel (grid BH, nk, nq), or the two
+# in one: where a head is one block (grid BH, 1, 1), and where a causal head of
+# several blocks keeps its dq in VMEM (grid BH, nk, nq); all recompute block
+# scores from (q, k, lse) — flash-attention rematerialization
 # ---------------------------------------------------------------------------------
 
 
@@ -839,15 +847,49 @@ def _fa_dq_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
+def _dkv_block(plan, scale, rate, walk, b, i, j, lens, seed_ref, operands,
+               dk_acc, dv_acc, dq_acc=None):
+    """One block of the key-block-outer walk: dk and dv of its panels (strips
+    of key columns) added to the key block's accumulators and, where the kernel
+    sums dq too (``dq_acc``: the head's float32 dq, ``(nq, bq, Dk)``), query
+    block ``i``'s share of dq from the same ``ds``. Phase by phase, as the
+    forward."""
+    q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref = operands
+    panels = _panels(plan, True, walk, b, i, j, lens, seed_ref, rate)
+    # once for the block: a row's delta serves every strip that reaches it
+    delta = _row_delta(do_ref[0], o_ref[0])
+    scores = []
+    for pn in panels:
+        do = do_ref[0, pn.rows, :]
+        scores.append((_panel_scores(pn, q_ref, k_ref, scale, _fill(plan)),
+                       _dot(do, v_ref[0, pn.cols, :], (1, 1)), do))
+    grads = [
+        _panel_p_ds(scale, s, dp, lse_ref[0, pn.rows, :],
+                    delta[pn.rows, :],
+                    dlse_ref[0, pn.rows, :] if dlse_ref is not None else None, pn, rate)
+        for pn, (s, dp, _) in zip(panels, scores)]
+    for pn, (z, ds), (_, _, do) in zip(panels, grads, scores):
+        q = q_ref[0, pn.rows, :]
+        # dv sees the DROPPED probabilities z (dropout sits between
+        # softmax and the @v matmul); dk/dq flow through ds, whose
+        # rowsum term keeps the undropped p Jacobian — _panel_p_ds
+        dv = _dot(z.astype(do.dtype), do, (0, 0))
+        ds = ds.astype(q.dtype)
+        dk = _dot(ds, q, (0, 0))
+        dv_acc[pn.cols, :] = lax.add(dv_acc[pn.cols, :], dv)
+        dk_acc[pn.cols, :] = lax.add(dk_acc[pn.cols, :], dk)
+        if dq_acc is not None:
+            dq = _dot(ds, k_ref[0, pn.cols, :], (1, 0))
+            dq_acc[i, pn.rows, :] = lax.add(dq_acc[i, pn.rows, :], dq)
+
+
 def _fa_dkv_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
     lens_ref, seed_ref, refs = _kernel_scalars(refs, has_lens, rate)
-    (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref), rest = _bwd_refs(
-        refs, has_dlse)
+    operands, rest = _bwd_refs(refs, has_dlse)
     dk_ref, dv_ref, dk_acc, dv_acc = rest
     # k block outer, q block inner
     b, i, j, step = _grid_ids(plan, True)
     lens = lens_ref[b] if has_lens else None
-    fill = _fill(plan)
 
     @pl.when(step == 0)
     def _init():
@@ -855,28 +897,8 @@ def _fa_dkv_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     def block(walk):  # as the dq kernel's; strips are key columns
-        panels = _panels(plan, True, walk, b, i, j, lens, seed_ref, rate)
-        # once for the block: a row's delta serves every strip that reaches it
-        delta = _row_delta(do_ref[0], o_ref[0])
-        scores = []
-        for pn in panels:
-            do = do_ref[0, pn.rows, :]
-            scores.append((_panel_scores(pn, q_ref, k_ref, scale, fill),
-                           _dot(do, v_ref[0, pn.cols, :], (1, 1)), do))
-        grads = [
-            _panel_p_ds(scale, s, dp, lse_ref[0, pn.rows, :],
-                        delta[pn.rows, :],
-                        dlse_ref[0, pn.rows, :] if has_dlse else None, pn, rate)
-            for pn, (s, dp, _) in zip(panels, scores)]
-        for pn, (z, ds), (_, _, do) in zip(panels, grads, scores):
-            q = q_ref[0, pn.rows, :]
-            # dv sees the DROPPED probabilities z (dropout sits between
-            # softmax and the @v matmul); dk/dq flow through ds, whose
-            # rowsum term keeps the undropped p Jacobian — _panel_p_ds
-            dv = _dot(z.astype(do.dtype), do, (0, 0))
-            dk = _dot(ds.astype(q.dtype), q, (0, 0))
-            dv_acc[pn.cols, :] = lax.add(dv_acc[pn.cols, :], dv)
-            dk_acc[pn.cols, :] = lax.add(dk_acc[pn.cols, :], dk)
+        _dkv_block(plan, scale, rate, walk, b, i, j, lens, seed_ref, operands,
+                   dk_acc, dv_acc)
 
     _walk_block(plan, True, i, j, block, step)
 
@@ -953,15 +975,62 @@ def _fa_dqkv_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
                     acc[cols, :] = part
 
 
-def _bwd_call(body, kernel, plan, args, *, grid, in_specs, out_specs, out_like, scratch):
+def _fa_dqkv_blocks_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
+    """The whole backward of a causal head of several blocks: the dkv kernel's
+    walk (key block ``j`` outer, query block ``i`` inner, from the diagonal
+    downwards), with dq taken from the same ``ds`` instead of from a second
+    recompute of it. dk and dv sum over ``i`` in their ``(bk, D)`` scratch as in
+    the dkv kernel. dq sums over ``j``, the OUTER axis, so the float32 dq of the
+    whole head stays in VMEM (``dq_acc``: one block a query block): step
+    ``(j, i)`` adds ``ds @ k_j`` to block ``i``. With ``j`` ascending, query
+    block ``j`` has every key block before ``j`` in it when the diagonal step
+    ``(j, j)`` — the first live step of ``j``'s walk — adds the last, so it is
+    rounded and written to the ``(1, bq, Dk)`` output block there and leaves
+    when ``j`` moves on: dq never sits in HBM in float32, and is summed in the
+    order the dq kernel sums it."""
+    lens_ref, seed_ref, refs = _kernel_scalars(refs, has_lens, rate)
+    operands, rest = _bwd_refs(refs, has_dlse)
+    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = rest
+    b, i, j, step = _grid_ids(plan, True)
+    lens = lens_ref[b] if has_lens else None
+
+    @pl.when(step == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(j == 0)            # every query block's first term is key block 0's
+    def _init_dq():
+        dq_acc[i] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
+
+    def block(walk):
+        _dkv_block(plan, scale, rate, walk, b, i, j, lens, seed_ref, operands,
+                   dk_acc, dv_acc, dq_acc)
+
+    _walk_block(plan, True, i, j, block)
+
+    @pl.when(i == j)
+    def _dq_final():
+        dq_ref[0] = dq_acc[i].astype(dq_ref.dtype)
+
+    @pl.when(step == _steps(plan, True) - 1)
+    def _final():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _bwd_call(body, kernel, plan, args, *, grid, in_specs, out_specs, out_like, scratch,
+              **compiler_params):
     """One backward ``pallas_call`` of ``args`` (:func:`_fa_bwd_pallas`'s, from
     ``q`` on): ``body`` over the scalars, the six operands — ``in_specs``, whose
     last serves ``dlse`` too — the outputs shaped like ``out_like`` and float32
-    ``scratch``; books the plan's tiles under ``kernel``."""
+    ``scratch``; books the plan's tiles under ``kernel``. ``compiler_params``
+    replace Mosaic's defaults and the (parallel, parallel, arbitrary) grid."""
     *operands, dlse, lens, scale, interpret, rate, seed = args
     has_dlse = dlse is not None
     scalars = _scalar_operands(lens, seed, rate)
     _book_tiles(plan, _widths(operands[0], operands[2]), lens is not None, kernel)
+    compiler_params.setdefault("dimension_semantics", ("parallel", "parallel", "arbitrary"))
     return pl.pallas_call(
         functools.partial(body, plan, scale, lens is not None, has_dlse, rate),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -972,9 +1041,7 @@ def _bwd_call(body, kernel, plan, args, *, grid, in_specs, out_specs, out_like, 
             scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in scratch],
         ),
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in out_like],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
+        compiler_params=pltpu.CompilerParams(**compiler_params),
         interpret=interpret,
         name=_kernel_name(plan, kernel),
     )(*scalars, *operands, *((dlse,) if has_dlse else ()))
@@ -1017,26 +1084,94 @@ def _fa_bwd_two_calls(plan, *args):
     return dq, dk, dv
 
 
+# Float32 bytes of one head's dq (``Sq * Dk * 4``) that the fused backward of
+# several blocks may keep in VMEM for the head's whole walk. 8 MiB admits every
+# cell at S = 8192 (D = 256: 8 MiB; 192: 6; 128: 4; 64: 2 — in VMEM a row takes
+# whole lane tiles, so 192 holds 8 and 64 holds 4: ``_blocks_vmem_bytes``) and
+# S = 16,384 at D = 128, and with the dkv kernel's own ~16 MiB of blocks and
+# intermediates beside it stays under a third of a v5e's 128 MiB. A longer or
+# wider head keeps the two calls.
+_HEAD_DQ_BYTES = 8 * 2 ** 20
+
+
+def _blocks_vmem_bytes(plan, Dk, Dv, itemsize):
+    """VMEM the fused backward of several blocks asks Mosaic for (a ceiling,
+    not a reservation), from the plan's own bytes: every operand and result
+    block twice (the pipeline's two buffers), the three float32 accumulators —
+    the head's dq among them — and six (bq, bk) float32 intermediates (s, dp,
+    p, ds and the two cast for the MXU, transposed for dk and dv)."""
+    bq, bk = plan.bq, plan.bk
+    wide, narrow = (-(-d // 128) * 128 for d in (Dk, Dv))     # a row takes whole lane tiles
+    blocks = itemsize * (2 * (bq + bk) * wide + (2 * bq + 2 * bk) * narrow)   # q dq k dk, do o v dv
+    blocks += 2 * 4 * bq * 128                                                # lse, dlse
+    scratch = 4 * (plan.sq * wide + bk * (wide + narrow))
+    return 2 * blocks + scratch + 6 * 4 * bq * bk
+
+
+def _fa_bwd_blocks(plan, *args):
+    """(dq, dk, dv) of a causal plan of several blocks from ONE call on the dkv
+    kernel's grid (BH, nk, nq), a head's dq summed in VMEM
+    (:func:`_fa_dqkv_blocks_kernel`). The key axis carries that scratch, so it
+    is ``arbitrary`` too. The query side's maps are clamped onto the diagonal:
+    the steps above it name the block the diagonal step takes and copy nothing."""
+    q, k, v = args[:3]
+    BH, (Dk, Dv) = q.shape[0], _widths(q, v)
+    bq, bk = plan.bq, plan.bk
+    own, _, _ = _block_maps(plan)
+    queries = lambda b, j, i, *_: (b, jnp.maximum(i, j), 0)
+    spec = lambda rows, D, at: pl.BlockSpec((1, rows, D), at)
+    return _bwd_call(
+        _fa_dqkv_blocks_kernel, "dqkv_blocks", plan, args, grid=(BH, plan.nk, plan.nq),
+        in_specs=[spec(bq, Dk, queries), spec(bk, Dk, own), spec(bk, Dv, own),
+                  spec(bq, Dv, queries), spec(bq, Dv, queries), spec(bq, 128, queries)],
+        out_specs=[spec(bq, Dk, own), spec(bk, Dk, own), spec(bk, Dv, own)],
+        out_like=(q, k, v), scratch=[(plan.nq, bq, Dk), (bk, Dk), (bk, Dv)],
+        dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+        vmem_limit_bytes=_blocks_vmem_bytes(plan, Dk, Dv, q.dtype.itemsize))
+
+
+def _bwd_of(plan, Dk):
+    """Which backward a plan takes, from what the call can observe: by what
+    carries over between grid steps, and whether VMEM can hold it."""
+    if plan.one_pass:
+        return _fa_bwd_fused
+    if plan.causal and plan.window is None and plan.sq * Dk * 4 <= _HEAD_DQ_BYTES:
+        return _fa_bwd_blocks
+    return _fa_bwd_two_calls
+
+
 def _fa_bwd_pallas(q, k, v, do, o, lse, dlse, lens, causal, scale, interpret,
                    rate=0.0, seed=None, window=None):
     """The backward of a flash call, by what its plan says carries over between
-    grid steps. Where a head is ONE block (``plan.one_pass``: causal, S <= 1024
-    at D <= 128, S <= 512 above) nothing does, and one call
-    (:func:`_fa_bwd_fused`) recomputes the scores, ``do . v``, the ``exp``, the
-    diagonal's mask and ``ds`` once for dq, dk and dv: five products and one
-    vector pass over the live tiles, every operand read once. Everywhere else
-    dq sums over a query block's key blocks and dk / dv over a key block's
-    query blocks, which one grid order cannot both keep in VMEM, so two calls
-    (:func:`_fa_bwd_two_calls`) each walk the square their own way and each
-    recompute: seven products and two vector passes.
+    grid steps (:func:`_bwd_of`); every plan recomputes the scores, ``do . v``,
+    the ``exp``, the masks and ``ds`` from (q, k, lse).
+
+    Where a head is ONE block (``plan.one_pass``: causal, S <= 1024 at D <= 128,
+    S <= 512 above) nothing carries over, and one call (:func:`_fa_bwd_fused`)
+    recomputes once for dq, dk and dv: five products and one vector pass over
+    the live tiles, every operand read once.
+
+    Where a causal head is several blocks and has no window, dk / dv sum over a
+    key block's query blocks and dq over a query block's key blocks. One grid
+    order keeps only one of them in a block-sized accumulator, but the other
+    fits VMEM whole: one call (:func:`_fa_bwd_blocks`) walks the square by key
+    block, holds the head's float32 dq (``Sq * Dk * 4 <= _HEAD_DQ_BYTES``) and
+    again recomputes once — five products and one vector pass. The diagonal
+    makes it possible: query block ``j``'s dq is complete when key block ``j``
+    is done, so it leaves as a block, in the order of the walk.
+
+    Everything else — a non-causal call (dq is final only after the LAST key
+    block), a windowed plan (its grid is the band), a head too long for VMEM —
+    takes two calls (:func:`_fa_bwd_two_calls`), each walking the square its
+    own way and each recomputing: seven products and two vector passes.
 
     ``dlse=None`` (the plain-attention path) omits the operand entirely —
     an all-zero lane-replicated dlse would otherwise add an arena-sized HBM
     read to every backward kernel for nothing. ``lens=None``: no ``kv_lens``."""
     Dk, Dv = _widths(q, v)
     plan = _tile_plan(q.shape[1], k.shape[1], Dk, causal, window, Dv)
-    calls = _fa_bwd_fused if plan.one_pass else _fa_bwd_two_calls
-    return calls(plan, q, k, v, do, o, lse, dlse, lens, scale, interpret, rate, seed)
+    return _bwd_of(plan, Dk)(
+        plan, q, k, v, do, o, lse, dlse, lens, scale, interpret, rate, seed)
 
 
 # ---------------------------------------------------------------------------------

@@ -241,11 +241,11 @@ def check_flash_tiles(results: list) -> None:
         got = (r["live"], r["total"], r["masked"])
         check(f"counter/{r['kernel']}{r['key']}", got == want.get(r["key"]),
               f"{got[0]}/{got[1]} masked {got[2]} ({r['traces']} traces)")
-    # a head of one block books the fused backward, several blocks the two kernels
+    # a head of one block books the fused backward, a causal one of several blocks its own
     by_key = {}
     for r in rows:
         by_key.setdefault(r["key"], set()).add(r["kernel"])
-    one_block, blocks = {"fwd", "dqkv"}, {"fwd", "dq", "dkv"}
+    one_block, blocks = {"fwd", "dqkv"}, {"fwd", "dqkv_blocks"}
     check("counter/kernels_by_plan", by_key == {
         "(1024, 1024, 64, True, False)": one_block, "(1024, 1024, 64, True, True)": one_block,
         "(8192, 8192, 256, True, False)": blocks, "(1024, 1024, 64, False, True)": {"fwd"}},
@@ -549,6 +549,63 @@ def check_flash_mla(results: list, H: int = 32, S: int = 8192, Dk: int = 192, Dv
             A._block_size = ladder
             A._tile_plan.cache_clear()
     check("ms_a_layer_by_block", any(isinstance(v, dict) for v in ms.values()), json.dumps(ms))
+
+
+# (heads, S, Dk, Dv) of the four 8k cells' causal calls without a window
+_FUSED_SHAPES = ((32, 8192, 192, 128), (32, 8192, 64, 64), (32, 8192, 128, 128),
+                 (16, 8192, 256, 256))
+
+
+def check_flash_fused(results: list, parity=((32, 2048, 192, 128), (32, 2048, 64, 64)),
+                      timed=_FUSED_SHAPES) -> None:
+    """The fused backward of a causal head of several blocks
+    (``ops.attention._fa_bwd_blocks``: ONE call, the head's float32 dq in VMEM),
+    compiled: dq, dk and dv against the dq + dkv pair (``_fa_bwd_two_calls``) on the
+    same residuals at ``parity``'s shapes ``(heads, S, Dk, Dv)`` under the 3e-2 the
+    other compiled kernels are held to, with the elements that differ counted; then
+    ms a layer of the one call and of the two at the four 8k cells' calls, a fresh
+    function each. Interpret mode cannot see what Mosaic makes of 6-8 MiB of
+    scratch indexed by a grid id, of an output block written at the first live
+    step of its walk, or of the clamped index maps."""
+    from beforeholiday_tpu.ops import attention as A
+
+    def check(name, cond, info=""):
+        results.append((f"flash_fused/{name}", bool(cond), str(info)))
+
+    interpret = A._interpret_default()          # False on the chip
+
+    def residuals(H, S, Dk, Dv):
+        ks = jax.random.split(jax.random.PRNGKey(H + S + Dk), 4)
+        q, k, v, do = (jax.random.normal(kk, (H, S, D)).astype(jnp.bfloat16)
+                       for kk, D in zip(ks, (Dk, Dk, Dv, Dv)))
+        scale = Dk ** -0.5
+        plan = A._tile_plan(S, S, Dk, True, None, Dv)
+        o, lse = jax.jit(lambda q, k, v: A._fa_fwd_pallas(
+            q, k, v, None, True, scale, interpret))(q, k, v)
+        runs = {tag: functools.partial(
+                    jax.jit(lambda *a, fn=fn: fn(plan, *a, None, None, scale, interpret, 0.0, None)),
+                    q, k, v, do, o, lse)
+                for tag, fn in (("fused", A._fa_bwd_blocks), ("two_calls", A._fa_bwd_two_calls))}
+        return plan, runs
+
+    for H, S, Dk, Dv in parity:
+        plan, runs = residuals(H, S, Dk, Dv)
+        name = f"s{S}_d{Dk}" + (f"_{Dv}" if Dv != Dk else "")
+        check(f"{name}/plan", A._bwd_of(plan, Dk) is A._fa_bwd_blocks and plan.nq > 1,
+              f"{plan.nq} x {plan.nk} blocks of {plan.bq}")
+        one, two = runs["fused"](), runs["two_calls"]()
+        for gname, a, b in zip(("dq", "dk", "dv"), one, two):
+            a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+            gap, size = float(jnp.max(jnp.abs(a - b))), float(jnp.max(jnp.abs(b)))
+            ok = bool(jnp.all(jnp.isfinite(a))) and gap <= 3e-2 * size
+            check(f"{name}/{gname}", ok, f"max|d|={gap:.3e} of {size:.3e}, "
+                  f"{int(jnp.sum(a != b))} of {a.size} differ")
+    for H, S, Dk, Dv in timed:
+        _, runs = residuals(H, S, Dk, Dv)
+        ms = {tag: round(1e3 * _min_step_seconds(lambda _: fn(), None), 3)
+              for tag, fn in runs.items()}
+        check(f"ms_a_layer/{H}x{S}x{Dk}" + (f"_{Dv}" if Dv != Dk else ""),
+              ms["fused"] < ms["two_calls"], json.dumps(ms))
 
 
 # (tag, buffer rows, groups, K, N, rows in a group, the product's dtype)
@@ -1194,7 +1251,8 @@ def main() -> int:
     enable_compile_cache()
     results: list = []
     for group in (check_flash_dropout, check_flash_tiles, check_wy_prepare, check_deltanet,
-                  check_short_conv, check_flash_mla, check_grouped_matmul, check_moe_rows, check_ssd, check_aliased_mt_kernels,
+                  check_short_conv, check_flash_mla, check_flash_fused,
+                  check_grouped_matmul, check_moe_rows, check_ssd, check_aliased_mt_kernels,
                   check_compiled_kernel_parity):
         try:
             group(results)

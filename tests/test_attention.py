@@ -902,19 +902,67 @@ class TestFusedBackward:
            "slice": 8, "sub": 10, "swap": 14}
 
     @pytest.mark.parametrize("with_lse", [False, True], ids=["plain", "lse"])
-    def test_several_blocks_a_head_keep_their_two_calls_and_bodies(self, with_lse):
+    @pytest.mark.parametrize("case", ["non-causal", "dq-over-the-budget"])
+    def test_several_blocks_a_head_keep_their_two_calls_and_bodies(self, case, with_lse,
+                                                                   monkeypatch):
+        """What the fused call of several blocks does not take (PR 43) keeps the
+        dq + dkv pair and the bodies it had: a non-causal call (dq is final only
+        after the LAST key block; ``TestNonCausalBodyUnchanged``'s bodies), and a
+        causal head whose float32 dq is over the VMEM budget (the 2 x 2-block
+        bodies of 6707be1 above)."""
         import collections
 
         x = jax.ShapeDtypeStruct((1, 1024, 256), jnp.bfloat16)
-        assert A._tile_plan(1024, 1024, 256, True).nq == 2
-        calls = _pallas_eqns(jax.make_jaxpr(self._grad_fn(with_lse))(x, x, x).jaxpr)
+        causal = case == "dq-over-the-budget"
+        assert A._tile_plan(1024, 1024, 256, causal).nq == 2
+        if causal:
+            monkeypatch.setattr(A, "_HEAD_DQ_BYTES", 1024 * 256 * 4 - 1)
+            grad, want = self._grad_fn(with_lse), (self.FWD, self.DQ, self.DKV)
+            dlse = {"get": 5, "slice": 5} if with_lse else {}    # a read a strip
+        else:
+            lens, seed = jnp.full((1,), 1024.0), jnp.zeros((1,), jnp.int32)
+
+            def loss(q, k, v):
+                if with_lse:
+                    o, lse = A._flash3_lse(q, k, v, lens, False, 0.125)
+                    return jnp.sum(o.astype(jnp.float32)) + jnp.sum(lse)
+                return jnp.sum(A._flash3(q, k, v, lens, seed, False, 0.125, 0.0)
+                               .astype(jnp.float32))
+
+            grad = jax.grad(loss, argnums=(0, 1, 2))
+            body = TestNonCausalBodyUnchanged
+            want = (body.FWD, body.DQ, body.DKV)
+            dlse = {"get": 1, "slice": 1} if with_lse else {}
+        calls = _pallas_eqns(jax.make_jaxpr(grad)(x, x, x).jaxpr)
         assert [e.params["grid_mapping"].grid for e in calls] == [(1, 2, 2)] * 3
-        fwd, dq, dkv = (collections.Counter(p)
-                        for p in _kernel_primitives(self._grad_fn(with_lse), x, x, x))
-        dlse = collections.Counter({"get": 5, "slice": 5} if with_lse else {})   # a read a strip
-        assert fwd == self.FWD
-        assert dq == collections.Counter(self.DQ) + dlse
-        assert dkv == collections.Counter(self.DKV) + dlse
+        fwd, dq, dkv = (collections.Counter(p) for p in _kernel_primitives(grad, x, x, x))
+        assert fwd == want[0]
+        assert dq == collections.Counter(want[1]) + collections.Counter(dlse)
+        assert dkv == collections.Counter(want[2]) + collections.Counter(dlse)
+
+    @pytest.mark.parametrize("with_lse", [False, True], ids=["plain", "lse"])
+    def test_the_fused_body_of_several_blocks_is_smaller_than_the_two_it_replaces(self, with_lse):
+        """A causal 2 x 2-block head (S=1024 at D=256) is ONE backward call on the
+        dkv kernel's grid, and its body is the dkv body + one product a panel
+        (dq) + the head's dq zeroed and written out: 5 products a strip and one
+        ``exp`` a strip where the two bodies (``DQ`` + ``DKV`` above: 212 + 229
+        operations, 41 products, 10 ``exp``) had 7 and 2."""
+        x = jax.ShapeDtypeStruct((1, 1024, 256), jnp.bfloat16)
+        calls = _pallas_eqns(jax.make_jaxpr(self._grad_fn(with_lse))(x, x, x).jaxpr)
+        assert [e.params["grid_mapping"].grid for e in calls] == [(1, 2, 2)] * 2
+        assert [len(e.params["out_avals"]) for e in calls] == [2, 3]     # (o, lse); (dq, dk, dv)
+        assert all(e.params["name"] is None for e in calls)    # ``%flash_attention.N`` on the chip
+        params = calls[1].params["compiler_params"]["mosaic_tpu"]
+        assert tuple(params.dimension_semantics) == ("parallel", "arbitrary", "arbitrary")
+        assert params.vmem_limit_bytes == A._blocks_vmem_bytes(
+            A._tile_plan(1024, 1024, 256, True), 256, 256, 2)
+        fwd, body = _kernel_primitives(self._grad_fn(with_lse), x, x, x)
+        import collections
+        assert collections.Counter(fwd) == self.FWD
+        assert len(body) <= 265 + (10 if with_lse else 0), len(body)
+        # the DKV body's 23 products + dq's: one a strip of the diagonal's walk, one a block below
+        assert body.count("dot_general") == 23 + 4 + 1
+        assert body.count("exp") == 5
 
     def test_the_fused_body_is_smaller_than_the_two_it_replaces(self):
         """The body is traced at every start of a program (set-up time): at the
@@ -927,16 +975,18 @@ class TestFusedBackward:
         assert dqkv.count("exp") == 4 and "cond" not in dqkv
 
     @pytest.mark.parametrize("key,kernels,counts", [
-        ((1024, 1024, 64), ["dqkv", "fwd"], (10, 16, 4)),             # the GPT cells
-        ((8192, 8192, 256), ["dkv", "dq", "fwd"], (2080, 4096, 64)),  # the Qwen cell
-    ], ids=["gpt_cells", "qwen_cell"])
+        ((1024, 1024, 64), ["dqkv", "fwd"], (10, 16, 4)),                       # the GPT cells
+        ((8192, 8192, 256), ["dqkv_blocks", "fwd"], (2080, 4096, 64)),          # the Qwen cell
+        ((8192, 8192, (192, 128)), ["dqkv_blocks", "fwd"], (528, 1024, 32)),    # the Kanana cell
+    ], ids=["gpt_cells", "qwen_cell", "kanana_cell"])
     def test_tile_records_say_which_backward_was_traced(self, key, kernels, counts):
         from beforeholiday_tpu import monitor
         from beforeholiday_tpu.guard import dispatch
 
         dispatch.reset_dispatch_counters()
-        x = jax.ShapeDtypeStruct((1, key[0], key[2]), jnp.bfloat16)
-        jax.make_jaxpr(self._grad_fn(False))(x, x, x)
+        Dk, Dv = key[2] if isinstance(key[2], tuple) else (key[2], key[2])
+        x, v = (jax.ShapeDtypeStruct((1, key[0], D), jnp.bfloat16) for D in (Dk, Dv))
+        jax.make_jaxpr(self._grad_fn(False))(x, x, v)
         rows = {r["kernel"]: r for r in monitor.tile_records() if r["op"] == "flash_attention"}
         assert sorted(rows) == kernels
         for r in rows.values():
@@ -963,10 +1013,162 @@ class TestFusedBackward:
         assert names == ["flash_attention_window_fwd", "flash_attention_window_dqkv"]
 
 
+# -- one backward call where a causal head is several blocks (PR 43) ------------------
+
+# (Dk, Dv, S): 5 x 5 blocks of 256 (two strips of 128 on the diagonal) at D <= 128
+# and at the latent-attention widths
+BLOCKS_SHAPES = [(64, 64, 1280), (128, 128, 1280), (192, 128, 1280), (64, 128, 1280)]
+
+
+def _swaps_on(jaxpr, ref):
+    """``swap`` operations of a kernel body that store to ``ref`` (one of the
+    body's own variables), followed into the branches of its ``cond``s."""
+    n = 0
+    for e in jaxpr.eqns:
+        if e.primitive.name == "swap" and e.invars[0] is ref:
+            n += 1
+        elif e.primitive.name == "cond":
+            for at, v in enumerate(e.invars[1:]):
+                if v is ref:
+                    n += max(_swaps_on(br.jaxpr, br.jaxpr.invars[at]) for br in e.params["branches"])
+    return n
+
+
+class TestFusedBackwardOfSeveralBlocks:
+    """A causal, un-windowed head of several blocks whose float32 dq fits the
+    VMEM budget: dq, dk and dv from ONE call (``_fa_bwd_blocks``) against the
+    oracle and against the dq + dkv pair (``_fa_bwd_two_calls``) on the same
+    residuals. dk and dv are the dkv kernel's bit for bit (its body); dq sums the
+    same float32 terms, the block ON the diagonal by key strip and not by row
+    strip."""
+
+    BH = 2
+
+    def _inputs(self, Dk, Dv, S, seed=41):
+        return TestTwoWidths._inputs(self, Dk, Dv, S, seed)
+
+    @pytest.mark.parametrize("variant", ["plain", "kv_lens", "dlse", "dropout"])
+    @pytest.mark.parametrize("Dk,Dv,S", BLOCKS_SHAPES, ids=lambda x: str(x))
+    def test_matches_the_oracle_and_the_two_calls(self, Dk, Dv, S, variant, monkeypatch):
+        monkeypatch.setattr(A, "_keep_mask", _hashed_keep)
+        q, k, v, w, wl = self._inputs(Dk, Dv, S)
+        scale = Dk ** -0.5
+        plan = A._tile_plan(S, S, Dk, True, None, Dv)
+        assert (plan.nq, plan.bq) == (5, 256) and A._bwd_of(plan, Dk) is A._fa_bwd_blocks
+        lens = jnp.asarray((S - 70, S - 256), jnp.float32) if variant in ("kv_lens", "dlse") else None
+        rate = 0.25 if variant == "dropout" else 0.0
+        seed = jnp.asarray([4321], jnp.int32)
+        dlse = jnp.broadcast_to(wl[..., None], (self.BH, S, 128)) if variant == "dlse" else None
+        o, lse = A._fa_fwd_pallas(q, k, v, lens, True, scale, True, rate, seed)
+        args = (plan, q, k, v, w, o, lse, dlse, lens, scale, True, rate, seed)
+        one, two = A._fa_bwd_blocks(*args), A._fa_bwd_two_calls(*args)
+        assert [t.shape for t in one] == [q.shape, k.shape, v.shape]
+        for name, a, b in zip(("dq", "dk", "dv"), one, two):
+            assert not np.any(np.isnan(np.asarray(a))), name
+            if name == "dq":
+                np.testing.assert_allclose(a, b, atol=2e-6, rtol=2e-6, err_msg=name)
+            else:
+                np.testing.assert_array_equal(a, b, err_msg=name)
+        if rate == 0.0:       # the oracle draws another mask
+            full = jnp.full((self.BH,), float(S)) if lens is None else lens
+            want = _oracle_grads(q, k, v, full, True, scale, w, wl if variant == "dlse" else None)[1:]
+            for name, a, b in zip(("dq", "dk", "dv"), one, want):
+                np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4, err_msg=name)
+
+    @pytest.mark.parametrize("Dk,Dv,S", BLOCKS_SHAPES, ids=lambda x: str(x))
+    def test_bfloat16_results_are_the_two_calls_to_a_rounding(self, Dk, Dv, S):
+        q, k, v, w, _ = (t.astype(jnp.bfloat16) for t in self._inputs(Dk, Dv, S, 42))
+        plan = A._tile_plan(S, S, Dk, True, None, Dv)
+        o, lse = A._fa_fwd_pallas(q, k, v, None, True, Dk ** -0.5, True)
+        args = (plan, q, k, v, w, o, lse, None, None, Dk ** -0.5, True, 0.0, None)
+        one, two = A._fa_bwd_blocks(*args), A._fa_bwd_two_calls(*args)
+        np.testing.assert_array_equal(one[1], two[1])
+        np.testing.assert_array_equal(one[2], two[2])
+        a, b = (np.asarray(t, np.float32) for t in (one[0], two[0]))
+        assert one[0].dtype == jnp.bfloat16 and np.mean(a != b) < 1e-3
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=2 ** -6)    # a bfloat16 step or two
+
+    @pytest.mark.parametrize("with_lse", [False, True], ids=["plain", "lse"])
+    def test_every_query_blocks_dq_is_written_exactly_once(self, with_lse):
+        """The body stores to the dq output in ONE place, under the diagonal
+        step's predicate — so a head's grid (key block outer) writes query block
+        ``j`` at step ``(j, j)`` and at no other — and the query side's index
+        maps name, at the steps above the diagonal, the block the diagonal step
+        takes: nothing is copied for them, and dq's block leaves when ``j``
+        moves on with every key block before it summed in."""
+        x = jax.ShapeDtypeStruct((1, 1024, 256), jnp.bfloat16)
+        grad = TestFusedBackward._grad_fn(with_lse)
+        call = _pallas_eqns(jax.make_jaxpr(grad)(x, x, x).jaxpr)[1]
+        body, gm = call.params["jaxpr"], call.params["grid_mapping"]
+        n_in = 7 if with_lse else 6
+        dq_ref, dk_ref, dv_ref = body.invars[n_in:n_in + 3]
+        assert [_swaps_on(body, r) for r in (dq_ref, dk_ref, dv_ref)] == [1, 1, 1]
+        maps = [bm.index_map_jaxpr for bm in gm.block_mappings]
+        at = lambda m, j, i: tuple(int(t) for t in jax.core.eval_jaxpr(m.jaxpr, m.consts, 0, j, i))
+        for j in range(2):
+            for i in range(2):
+                blocks = [at(m, j, i)[1] for m in maps]
+                # q, k, v, do, o, lse [, dlse]; dq, dk, dv
+                want = [max(i, j), j, j] + [max(i, j)] * (n_in - 3) + [j, j, j]
+                assert blocks == want, (j, i, blocks)
+
+    @pytest.mark.parametrize("case", ["non-causal", "windowed", "dq-over-the-budget", "one-block"])
+    def test_what_it_does_not_take_keeps_the_calls_it_had(self, case, monkeypatch):
+        """The rule is a function of ``(Sq, Sk, Dk, Dv, causal, window)`` alone."""
+        S, D = 1280, 64
+        if case == "dq-over-the-budget":
+            assert A._HEAD_DQ_BYTES == 8 * 2 ** 20      # S = 8192 at D = 256; 16,384 at 128
+            for s, d, fits in ((8192, 256, True), (16384, 128, True), (16384, 192, False),
+                               (32768, 64, True), (32768, 128, False)):
+                plan = A._tile_plan(s, s, d, True)
+                assert (A._bwd_of(plan, d) is A._fa_bwd_blocks) == fits, (s, d)
+            monkeypatch.setattr(A, "_HEAD_DQ_BYTES", S * D * 4 - 1)
+        causal, window = case != "non-causal", 300 if case == "windowed" else None
+        S = 256 if case == "one-block" else S
+        plan = A._tile_plan(S, S, D, causal, window)
+        want = A._fa_bwd_fused if case == "one-block" else A._fa_bwd_two_calls
+        assert A._bwd_of(plan, D) is want
+        q, k, v, w, _ = self._inputs(D, D, S, 43)
+        lens = None if causal else jnp.full((self.BH,), float(S))
+        seed = jnp.zeros((1,), jnp.int32)
+        grad = jax.grad(lambda *a: jnp.sum(A._flash3(*a, lens, seed, causal, 0.125, 0.0, window) * w),
+                        argnums=(0, 1, 2))
+        calls = _pallas_eqns(jax.make_jaxpr(grad)(q, k, v).jaxpr)
+        assert len(calls) == (2 if case == "one-block" else 3)
+        for e in calls[1:]:
+            params = e.params["compiler_params"]["mosaic_tpu"]
+            assert tuple(params.dimension_semantics) == ("parallel", "parallel", "arbitrary")
+            assert params.vmem_limit_bytes is None
+
+    def test_through_the_public_call_and_ring_attentions_chunk(self):
+        """``flash_attention`` and ``flash_attention_with_lse`` (a causal chunk
+        of several blocks, ``dlse`` from the merge) reach the one call."""
+        from beforeholiday_tpu import monitor
+        from beforeholiday_tpu.guard import dispatch
+
+        dispatch.reset_dispatch_counters()
+        q, k, v, w, wl = self._inputs(64, 64, 640, 44)
+        f = lambda *a: jnp.sum(A.flash_attention(*(t[None] for t in a), causal=True,
+                                                 impl="pallas")[0] * w)
+
+        def g(q, k, v):
+            o, lse = A.flash_attention_with_lse(q, k, v, causal=True, scale=0.125)
+            return jnp.sum(o * w) + jnp.sum(lse * wl)
+
+        full = jnp.full((self.BH,), 640.0)
+        for fn, want in ((f, _oracle_grads(q, k, v, full, True, 0.125, w)[1:]),
+                         (g, _oracle_grads(q, k, v, full, True, 0.125, w, wl)[1:])):
+            for a, b in zip(jax.grad(fn, argnums=(0, 1, 2))(q, k, v), want):
+                np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+        kernels = {r["kernel"] for r in monitor.tile_records() if r["op"] == "flash_attention"}
+        assert kernels == {"fwd", "dqkv_blocks"}
+        dispatch.reset_dispatch_counters()
+
+
 # -- queries and keys of one width, values of another (latent attention; PR 42) ------
 
 # (Dk, Dv, S): a head that is one block (the fused backward) and one of several
-# (the dq + dkv pair: 5 x 5 blocks of 256) at each pair of widths
+# (the fused backward of several blocks: 5 x 5 blocks of 256) at each pair of widths
 TWO_WIDTH_SHAPES = [(192, 128, 256), (192, 128, 1280), (64, 128, 256), (64, 128, 1280)]
 
 
@@ -1053,8 +1255,8 @@ class TestTwoWidths:
         """Blocks of 1024 where the values are one lane tile wide and the keys at
         most two: 192 / 128 (measured on the chip, PR 42) as every one-width call
         at D <= 128; a one-width call at 192 or 256 keeps 512. A head of 192 /
-        128 at S = 8192 is 8 x 8 blocks and its backward the dq + dkv pair; both
-        widths are in the tiles' key."""
+        128 at S = 8192 is 8 x 8 blocks and its backward the fused call of several
+        blocks (PR 43); both widths are in the tiles' key."""
         from beforeholiday_tpu import monitor
         from beforeholiday_tpu.guard import dispatch
 
@@ -1066,13 +1268,13 @@ class TestTwoWidths:
         assert A._tile_plan(8192, 8192, 128, True, None, 192).bq == 512      # wide values
         assert A._tile_plan(8192, 8192, 128, True, None, 128) == A._tile_plan(8192, 8192, 128, True)
         key = (2048, 2048, (192, 128), True, False)
-        for kernel in ("fwd", "dq", "dkv"):
+        for kernel in ("fwd", "dqkv_blocks"):
             dispatch._TILES.pop(("flash_attention", kernel, key), None)
         q, k, v, w, _ = self._inputs(192, 128, 2048)
         jax.grad(lambda *a: jnp.sum(A._flash3(
             *a, None, jnp.zeros((1,), jnp.int32), True, 0.1, 0.0)))(q, k, v)
         rows = {r["kernel"]: r for r in monitor.tile_records() if r["key"] == repr(key)}
-        assert sorted(rows) == ["dkv", "dq", "fwd"]
+        assert sorted(rows) == ["dqkv_blocks", "fwd"]
         assert all((r["total"], r["live"], r["masked"]) == (64, 36, 8) for r in rows.values())
 
     def test_one_width_books_the_tiles_it_booked(self):
@@ -1086,7 +1288,7 @@ class TestTwoWidths:
             *a, None, jnp.zeros((1,), jnp.int32), True, 0.1, 0.0)))(q, k, v)
         new = set(dispatch.tile_counters()) - before
         assert new == {("flash_attention", kernel, (384, 384, 64, True, False))
-                       for kernel in ("fwd", "dq", "dkv")}
+                       for kernel in ("fwd", "dqkv_blocks")}
 
     def test_no_operand_is_padded_to_a_common_width(self):
         """The kernels' operands are the caller's arrays at their own widths:
@@ -1096,11 +1298,11 @@ class TestTwoWidths:
         flash = lambda *a: A.flash_attention(*(t[None] for t in a), causal=True, impl="pallas")
         jaxpr = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2)))(q, k, v)
         calls = _pallas_eqns(jaxpr.jaxpr)
-        assert len(calls) == 3          # fwd, dq, dkv
+        assert len(calls) == 2          # fwd, the fused backward of several blocks
         widths = lambda vs: sorted({x.aval.shape[-1] for x in vs} - {1})
         for eqn in calls:
             assert widths(eqn.invars) == [128, 192]
-        assert [widths(e.outvars) for e in calls[-2:]] == [[192], [128, 192]]
+        assert [x.aval.shape[-1] for x in calls[-1].outvars] == [192, 192, 128]
         names = {e.primitive.name for e in jaxpr.jaxpr.eqns}
         assert not names & {"pad", "concatenate"}
 

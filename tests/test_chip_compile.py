@@ -320,18 +320,22 @@ def test_the_fused_flash_backward_compiles_for_the_chip(one_chip, flash_compiled
 
 
 @pytest.mark.parametrize("BH,S,Dk,Dv,calls", (
-    (32, 8192, 192, 128, 3),                    # the Kanana cell: 8 x 8 blocks of 1,024 a head
+    (32, 8192, 192, 128, 2),                    # the Kanana cell: 8 x 8 blocks of 1,024 a head
     (8, 1024, 192, 128, 2),                     # a head that is one block: the fused backward
-    (8, 2048, 64, 128, 3),                      # narrower keys than values: blocks of 1,024
-), ids=("kanana_cell", "one_block", "narrow_keys"))
+    (8, 2048, 64, 128, 2),                      # narrower keys than values: blocks of 1,024
+    (8, 2048, 192, 128, 3),                     # the dq + dkv pair (a head's dq over the budget)
+), ids=("kanana_cell", "one_block", "narrow_keys", "two_calls"))
 @pytest.mark.parametrize("variant", ("plain", "lens_dlse"))
 def test_the_two_width_flash_kernels_compile_for_the_chip(one_chip, flash_compiled, BH, S, Dk, Dv,
-                                                          calls, variant):
+                                                          calls, variant, monkeypatch):
     """``q, k`` at ``Dk`` on ``v`` at ``Dv`` (latent attention: 192 on 128, one
-    and a half lane tiles of scores): the forward and both backward plans at the
-    cell's call, where 192-wide blocks and a 192-deep product are what Mosaic
-    could refuse; results come at their own widths, with no ``pad`` in the
-    program."""
+    and a half lane tiles of scores): the forward and all three backward plans
+    at the cell's call (the dq + dkv pair by taking the VMEM budget of the fused
+    call of several blocks away), where 192-wide blocks and a 192-deep product
+    are what Mosaic could refuse; results come at their own widths, with no
+    ``pad`` in the program."""
+    if calls == 3:
+        monkeypatch.setattr(flash_compiled, "_HEAD_DQ_BYTES", 0)
     A = flash_compiled
     bf = jnp.bfloat16
     shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
@@ -352,6 +356,42 @@ def test_the_two_width_flash_kernels_compile_for_the_chip(one_chip, flash_compil
     o, (dq, dk, dv) = jax.eval_shape(both, *args)
     assert [t.shape[-1] for t in (o, dq, dk, dv)] == [Dv, Dk, Dk, Dv]
     assert not re.search(r"= [a-z0-9]+\[[\d,]*\]\S* pad\(", text)
+
+
+@pytest.mark.parametrize("BH,S,Dk,Dv", (
+    (32, 8192, 192, 128),                       # the Kanana cell: 8 x 8 blocks, 6 MiB of dq a head
+    (32, 8192, 64, 64),                         # the LFM2 and Nemotron cells
+    (32, 8192, 128, 128),                       # the Mellum cell's full layer
+    (16, 8192, 256, 256),                       # the Qwen cell: 16 x 16 blocks of 512, 8 MiB of dq
+), ids=("kanana_cell", "lfm2_cell", "mellum_cell", "qwen_cell"))
+@pytest.mark.parametrize("variant", ("plain", "lens_dlse"))
+def test_the_fused_backward_of_several_blocks_compiles_for_the_chip(
+        one_chip, flash_compiled, BH, S, Dk, Dv, variant):
+    """A causal head of several blocks takes ONE backward call that keeps the
+    head's float32 dq in VMEM (``ops/attention.py:_fa_bwd_blocks``): at the four
+    8k cells' calls, plain and with ``kv_lens`` + the ``dlse`` operand, under
+    the ``vmem_limit_bytes`` the plan computes — more than Mosaic's default, and
+    what it could refuse."""
+    A = flash_compiled
+    bf = jnp.bfloat16
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    scale = Dk ** -0.5
+    plan = A._tile_plan(S, S, Dk, True, None, Dv)
+    assert A._bwd_of(plan, Dk) is A._fa_bwd_blocks
+    limit = A._blocks_vmem_bytes(plan, Dk, Dv, 2)
+    assert 16 * 2 ** 20 < limit < 64 * 2 ** 20
+
+    def bwd(q, k, v, do, o, lse, lens, dlse):
+        if variant == "plain":
+            lens = dlse = None
+        return A._fa_bwd_pallas(q, k, v, do, o, lse, dlse, lens, True, scale, False)
+
+    wide, narrow = shape((BH, S, Dk), bf), shape((BH, S, Dv), bf)
+    rows = shape((BH, S, 128), jnp.float32)
+    text = jax.jit(bwd).lower(wide, wide, narrow, narrow, narrow, rows,
+                              shape((BH,), jnp.float32), rows).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert f'"scoped_memory_configs":[{{"memory_space":"1","offset":"0","size":"{limit}"}}]' in text
 
 
 def test_same_step_reads_two_texts_as_one_program_when_only_source_lines_moved(

@@ -147,3 +147,28 @@ def test_the_flash_mla_check_runs_its_comparison():
     assert sorted(read) == ["128", "256"] and all(isinstance(v, dict) for v in read.values()), read
     assert A._block_size is ladder and A._tile_plan(8192, 8192, 192, True, None, 128).bq == 1024
     assert "check_flash_mla" in inspect.getsource(tpu_checks.main)
+
+
+def test_the_flash_fused_check_runs_its_comparison():
+    """The chip check of the fused backward of several blocks at toy shapes on
+    the interpreter (3 x 3 blocks of 128): the plan is the fused one, dq, dk and
+    dv are compared with the dq + dkv pair's on the same residuals, the four 8k
+    cells' calls are what it times by default (the timings are not judged
+    here), and ``main`` runs the group."""
+    import inspect
+    import json
+
+    results = []
+    tpu_checks.check_flash_fused(results, parity=((2, 384, 192, 128), (2, 384, 64, 64)),
+                                 timed=((1, 384, 64, 64),))
+    by_name = {name: (ok, info) for name, ok, info in results}
+    compared = {f"flash_fused/{shape}/{k}" for shape in ("s384_d192_128", "s384_d64")
+                for k in ("plan", "dq", "dk", "dv")}
+    assert set(by_name) == compared | {"flash_fused/ms_a_layer/1x384x64"}
+    for name in compared:
+        assert by_name[name][0], (name, by_name[name])
+    assert "3 x 3 blocks of 128" in by_name["flash_fused/s384_d64/plan"][1]
+    assert sorted(json.loads(by_name["flash_fused/ms_a_layer/1x384x64"][1])) == ["fused", "two_calls"]
+    assert tpu_checks._FUSED_SHAPES == ((32, 8192, 192, 128), (32, 8192, 64, 64),
+                                        (32, 8192, 128, 128), (16, 8192, 256, 256))
+    assert "check_flash_fused" in inspect.getsource(tpu_checks.main)
