@@ -63,7 +63,7 @@ _COUNTERS: Dict[Tuple, Dict[str, int]] = {}
 _WARNED_KEYS: Set[Tuple] = set()
 
 
-# (op, kernel, statics) -> {"traces", "total", "live", "masked"}: the tile plan
+# (op, kernel, statics) -> {"traces", "total", "live", "masked", ...}: the tile plan
 # a kernel was built with, booked when the kernel is traced (the plan is
 # static per call). Beside the dispatch counters: those say WHICH
 # implementation a key took, these how much of the score square that
@@ -93,16 +93,17 @@ def reset_dispatch_counters() -> None:
 
 
 def count_tiles(op_name: str, kernel: str, statics: Tuple, *, total: int,
-                live: int, masked: int) -> None:
+                live: int, masked: int, **grid: int) -> None:
     """Book one trace of ``op_name``'s ``kernel`` for the static key
     ``statics``: tiles in the score square, tiles the kernel computes, tiles
     it computes through a mask. ``live < total`` is a causal plan skipping the
     tiles above the diagonal; ``masked < live`` is tiles taking the mask-free
-    path. Telemetry only, like :func:`count_forced`."""
+    path. ``grid``: further counts of the kernel's grid (the flash forward's
+    ``copies`` and ``steps`` a head). Telemetry only, like :func:`count_forced`."""
     with _VERDICTS_LOCK:
         row = _TILES.setdefault((op_name, kernel, tuple(statics)), {"traces": 0})
         row["traces"] += 1
-        row.update(total=total, live=live, masked=masked)
+        row.update(total=total, live=live, masked=masked, **grid)
 
 
 def tile_counters() -> Dict[Tuple, Dict[str, int]]:

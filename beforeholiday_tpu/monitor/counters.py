@@ -94,7 +94,10 @@ def tile_records() -> List[Dict[str, object]]:
     tile plan the kernel was built with (``guard.dispatch.count_tiles``).
     ``live < total``: tiles above the causal diagonal are not computed;
     ``masked < live``: tiles wholly below it take the mask-free path. A call
-    whose ``live == total == masked`` did not engage either."""
+    whose ``live == total == masked`` did not engage either. A flash forward's
+    row also holds its grid a head: the ``steps`` it takes and the K + V blocks
+    it ``copies`` (``steps`` under the square's blocks: no step above the
+    causal diagonal; ``copies < steps``: steps that name a block again)."""
     from beforeholiday_tpu.guard import dispatch as _dispatch
 
     return sorted(

@@ -31,8 +31,16 @@ non-causal call is one tile a block. A causal call with a ``window`` (sliding-
 window attention: the last W keys) goes further: its GRID is the band of
 blocks the window reaches, through index maps offset from the outer block, so
 that a block outside the band costs no grid step and no copy, and the blocks
-the window's lower edge crosses are walked as the diagonal's are.
+the window's lower edge crosses are walked as the diagonal's are. A causal
+call of several blocks WITHOUT a window names live blocks only, too: the
+forward's last grid axis is the blocks on and below the diagonal, read from two
+int32 tables in SMEM (``TilePlan.live_axis``, :func:`_live_grid`), so every step
+computes and every copy is issued under a product; the dq and dkv kernels keep
+the square's grid, their carried sums being what they are, and clamp their
+index maps onto the diagonal (:func:`_block_maps`), where a repeated block is
+not copied again.
 ``guard.dispatch.count_tiles`` books what each traced kernel's plan computes
+and, for the forward, the grid steps and copies a head takes
 (``monitor.tile_records()``).
 
 The backward is one kernel or two by what the plan says carries over between
@@ -68,6 +76,7 @@ from typing import Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
 from jax.experimental import pallas as pl
@@ -202,6 +211,26 @@ class TilePlan(NamedTuple):
         (A non-causal call keeps the bodies it had.)"""
         return self.causal and self.nq == 1
 
+    @property
+    def live_axis(self) -> bool:
+        """A causal call of several blocks without a window: the forward's last
+        grid axis names the blocks on and below the diagonal and no other
+        (:meth:`fwd_steps`), so a block above it costs no grid step and no copy,
+        and every copy is issued under a step that computes."""
+        return self.causal and self.window is None and self.nq > 1
+
+    def fwd_steps(self):
+        """``[(i, j)]``: the query block and the key block the forward's index
+        maps name at each grid step of one head, in the grid's order. With
+        :attr:`live_axis` these ARE the grid (the two tables its steps read
+        their blocks from); a windowed plan walks its band, clamped into the
+        sequence; every other plan walks the whole row of key blocks."""
+        if self.window is not None:
+            back = self.band - 1
+            return [(i, max(i + s - back, 0)) for i in range(self.nq) for s in range(self.band)]
+        return [(i, j) for i in range(self.nq)
+                for j in range(i + 1 if self.live_axis else self.nk)]
+
     def walk(self, by_cols: bool, diag: bool):
         """Static walk of one block: ``[(fixed, [(moving, on_diag), ...])]``,
         spans as block-local slices. ``fixed`` runs along the kernel's own
@@ -278,11 +307,14 @@ class TilePlan(NamedTuple):
                 out.append((walk, [d]))
         return out
 
-    def counts(self, has_lens: bool) -> Dict[str, int]:
+    def counts(self, has_lens: bool, fwd: bool = False) -> Dict[str, int]:
         """Tiles of the score square: ``total``, ``live`` (computed) and
         ``masked`` (computed through a mask). The same for all three kernels.
         With ``kv_lens`` a key length can fall inside any tile, so every
-        computed tile takes the length test."""
+        computed tile takes the length test. ``fwd`` adds the forward's grid:
+        the ``steps`` it takes a head and the K + V blocks it ``copies`` (a step
+        of a query block's walk that names the key block the step before it
+        named copies nothing)."""
         total = (self.sq // self.tq) * (self.sk // self.tk)
         if self.window is not None:
             live = masked = 0
@@ -292,12 +324,18 @@ class TilePlan(NamedTuple):
                         n = (span.stop - span.start) // self.tk * (self.nq - d)
                         live += n
                         masked += n if edge or has_lens else 0
-            return {"total": total, "live": live, "masked": masked}
-        if not self.causal:
-            return {"total": total, "live": total, "masked": total}
-        n = self.sq // self.tq
-        live = n * (n + 1) // 2
-        return {"total": total, "live": live, "masked": live if has_lens else n}
+        elif not self.causal:
+            live = masked = total
+        else:
+            n = self.sq // self.tq
+            live = n * (n + 1) // 2
+            masked = live if has_lens else n
+        out = {"total": total, "live": live, "masked": masked}
+        if fwd:
+            steps = self.fwd_steps()
+            out.update(copies=sum(a != b for a, b in zip([None] + steps, steps)),
+                       steps=len(steps))
+        return out
 
 
 @functools.lru_cache(maxsize=None)
@@ -361,8 +399,9 @@ def is_flash_available(seq_len: int, head_dim: int, v_head_dim: Optional[int] = 
 
 
 # ---------------------------------------------------------------------------------
-# forward kernel: grid (BH, nq, nk); nk innermost so the VMEM accumulators
-# (acc, m, l) carry across key blocks of one query block
+# forward kernel: grid (BH, nq, nk), or (BH, live blocks) where a causal head is
+# several blocks; key blocks innermost so the VMEM accumulators (acc, m, l) carry
+# across the key blocks of one query block
 # ---------------------------------------------------------------------------------
 
 
@@ -582,14 +621,25 @@ def _kernel_scalars(refs, has_lens, rate):
 
 def _fa_fwd_kernel(plan, scale, has_lens, rate, *refs):
     lens_ref, seed_ref, refs = _kernel_scalars(refs, has_lens, rate)
+    if plan.live_axis:
+        # the grid is (BH, live blocks): a step reads its blocks from the two
+        # tables (TilePlan.fwd_steps), which go key block 0 to the diagonal
+        i_ref, j_ref, *refs = refs
+        b, step = pl.program_id(0), pl.program_id(1)
+        i, j = i_ref[step], j_ref[step]
+        first, last = (lambda: j == 0), (lambda: j == i)
+    else:
+        b, i, j, step = _grid_ids(plan, False)
+        # compared where the accumulators are set up and written out, not here:
+        # a one_pass plan does neither, and its body stays what it was
+        first, last = (lambda: step == 0), (lambda: step == _steps(plan) - 1)
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
-    b, i, j, step = _grid_ids(plan, False)
     lens = lens_ref[b] if has_lens else None
     one_pass = plan.one_pass
     fill = _fill(plan)
 
     if not one_pass:
-        @pl.when(step == 0)
+        @pl.when(first())
         def _init():
             m_ref[...] = jnp.full_like(m_ref, _NEG)
             l_ref[...] = jnp.zeros_like(l_ref)
@@ -653,7 +703,7 @@ def _fa_fwd_kernel(plan, scale, has_lens, rate, *refs):
     _walk_block(plan, False, i, j, block, step)
 
     if not one_pass:
-        @pl.when(step == _steps(plan) - 1)
+        @pl.when(last())
         def _final():
             l = l_ref[:, 0:1]
             nonempty = l > 0.0
@@ -681,7 +731,7 @@ def _book_tiles(plan, widths, has_lens, *kernels):
     if plan.window is not None:
         key += (plan.window,)
     for kernel in kernels:
-        _count_tiles("flash_attention", kernel, key, **plan.counts(has_lens))
+        _count_tiles("flash_attention", kernel, key, **plan.counts(has_lens, kernel == "fwd"))
 
 
 def _block_maps(plan):
@@ -689,9 +739,16 @@ def _block_maps(plan):
     ``own`` follows a kernel's outer block; ``keys`` (fwd, dq: query block
     outer) and ``queries`` (dkv: key block outer) follow the last grid axis.
     In a windowed plan that axis walks the band, offset from the outer block
-    and clamped into the sequence (:attr:`TilePlan.band`)."""
+    and clamped into the sequence (:attr:`TilePlan.band`). In a causal plan of
+    several blocks it is clamped onto the diagonal: a step above it names the
+    block the diagonal step named, and a repeated block is not copied again
+    (the forward of such a plan takes no such step: :func:`_live_grid`)."""
     own = lambda b, o, s, *_: (b, o, 0)
     if plan.window is None:
+        if plan.causal and plan.nq > 1:
+            keys = lambda b, i, s, *_: (b, lax.min(s, i), 0)
+            queries = lambda b, j, s, *_: (b, lax.max(s, j), 0)
+            return own, keys, queries
         other = lambda b, o, s, *_: (b, s, 0)
         return own, other, other
     back, last = plan.band - 1, plan.nq - 1
@@ -717,6 +774,17 @@ def _scalar_operands(lens, seed, rate):
     return scalars
 
 
+def _live_grid(plan, BH):
+    """``(grid, tables, own, keys)`` of the forward of a plan whose last axis
+    names live blocks only (:attr:`TilePlan.live_axis`): ``(BH, live blocks)``,
+    the query block and the key block of each step as two int32 tables that ride
+    SMEM after the other scalars, and the index maps that read them."""
+    i_tab, j_tab = np.asarray(plan.fwd_steps(), np.int32).T
+    own = lambda b, s, *scalars: (b, scalars[-2][s], 0)
+    keys = lambda b, s, *scalars: (b, scalars[-1][s], 0)
+    return (BH, len(i_tab)), [jnp.asarray(i_tab), jnp.asarray(j_tab)], own, keys
+
+
 def _fa_fwd_pallas(q, k, v, lens, causal, scale, interpret, rate=0.0, seed=None,
                    window=None):
     """``lens=None``: the call has no ``kv_lens`` — no length test anywhere."""
@@ -725,11 +793,16 @@ def _fa_fwd_pallas(q, k, v, lens, causal, scale, interpret, rate=0.0, seed=None,
     plan = _tile_plan(Sq, k.shape[1], Dk, causal, window, Dv)
     bq, bk = plan.bq, plan.bk
     _book_tiles(plan, (Dk, Dv), lens is not None, "fwd")
-    own, keys, _ = _block_maps(plan)
     scalars = _scalar_operands(lens, seed, rate)
+    if plan.live_axis:
+        grid, tables, own, keys = _live_grid(plan, BH)
+        scalars += tables
+    else:
+        grid = (BH, plan.nq, _steps(plan))
+        own, keys, _ = _block_maps(plan)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(BH, plan.nq, _steps(plan)),
+        grid=grid,
         in_specs=[pl.BlockSpec((1, bq, Dk), own), pl.BlockSpec((1, bk, Dk), keys),
                   pl.BlockSpec((1, bk, Dv), keys)],
         out_specs=[
@@ -746,7 +819,7 @@ def _fa_fwd_pallas(q, k, v, lens, causal, scale, interpret, rate=0.0, seed=None,
         functools.partial(_fa_fwd_kernel, plan, scale, lens is not None, rate),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+            dimension_semantics=("parallel",) * (len(grid) - 1) + ("arbitrary",)
         ),
         out_shape=[
             jax.ShapeDtypeStruct((BH, Sq, Dv), q.dtype),
@@ -1117,8 +1190,7 @@ def _fa_bwd_blocks(plan, *args):
     q, k, v = args[:3]
     BH, (Dk, Dv) = q.shape[0], _widths(q, v)
     bq, bk = plan.bq, plan.bk
-    own, _, _ = _block_maps(plan)
-    queries = lambda b, j, i, *_: (b, jnp.maximum(i, j), 0)
+    own, _, queries = _block_maps(plan)
     spec = lambda rows, D, at: pl.BlockSpec((1, rows, D), at)
     return _bwd_call(
         _fa_dqkv_blocks_kernel, "dqkv_blocks", plan, args, grid=(BH, plan.nk, plan.nq),

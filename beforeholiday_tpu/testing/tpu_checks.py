@@ -18,6 +18,7 @@ used (a directional FD on a sum of 1e5 fp32 terms drowns in cancellation).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import json
 
@@ -606,6 +607,97 @@ def check_flash_fused(results: list, parity=((32, 2048, 192, 128), (32, 2048, 64
               for tag, fn in runs.items()}
         check(f"ms_a_layer/{H}x{S}x{Dk}" + (f"_{Dv}" if Dv != Dk else ""),
               ms["fused"] < ms["two_calls"], json.dumps(ms))
+
+
+@contextlib.contextmanager
+def _flash_maps(A, live: bool, clamp: bool):
+    """``ops.attention`` with the causal forward's live axis taken away (``live``
+    False: the forward steps over the square under ``_block_maps``' clamp) and the
+    clamp too (``clamp`` False: the index maps of the commit before PR 44, the
+    plain ``(b, s, 0)`` on every plan without a window); put back on the way out."""
+    saved = A.TilePlan.live_axis, A._block_maps
+    own = lambda b, o, s, *_: (b, o, 0)
+    other = lambda b, o, s, *_: (b, s, 0)
+    try:
+        if not live:
+            A.TilePlan.live_axis = property(lambda self: False)
+        if not clamp:
+            A._block_maps = lambda plan: ((own, other, other) if plan.window is None
+                                          else saved[1](plan))
+        yield
+    finally:
+        A.TilePlan.live_axis, A._block_maps = saved
+
+
+def check_flash_fwd_live(results: list, timed=_FUSED_SHAPES, two_calls=_FUSED_SHAPES[0]) -> None:
+    """The causal forward of several blocks on its live blocks alone
+    (``ops.attention``: ``TilePlan.live_axis``, ``_live_grid``), compiled, at the
+    four 8k cells' calls ``(heads, S, Dk, Dv)``: ms for a layer's heads of the
+    forward alone under the parent's maps (the whole square stepped on, K + V
+    copied at every step), under the clamp alone (the square stepped on, nothing
+    copied above the diagonal) and on the live axis (what ships), a fresh function
+    each, ``o`` and ``lse`` of the three compared bit for bit; then the dq + dkv
+    pair at ``two_calls`` under the parent's maps and under the clamp, which a head
+    whose dq is over the VMEM budget takes, its three results compared the same
+    way. Interpret mode cannot see what the pipeline makes of a repeated block
+    index, nor of output blocks named through a table in SMEM."""
+    from beforeholiday_tpu.ops import attention as A
+
+    def check(name, cond, info=""):
+        results.append((f"flash_fwd_live/{name}", bool(cond), str(info)))
+
+    interpret = A._interpret_default()          # False on the chip
+    variants = (("parent_maps", False, False), ("clamp", False, True), ("live", True, True))
+
+    def operands(H, S, Dk, Dv):
+        ks = jax.random.split(jax.random.PRNGKey(H + S + Dk), 4)
+        return tuple(jax.random.normal(kk, (H, S, D)).astype(jnp.bfloat16)
+                     for kk, D in zip(ks, (Dk, Dk, Dv, Dv)))
+
+    def same(a, b):
+        return all(bool(jnp.all(x == y)) for x, y in zip(a, b))
+
+    def under(variants, make, *args):
+        """``({tag: outputs}, {tag: ms})`` of ``make()``, jitted afresh under each
+        variant's maps (a traced call keeps the maps it was traced with)."""
+        outs, ms = {}, {}
+        for tag, live, clamp in variants:
+            with _flash_maps(A, live, clamp):
+                fn = jax.jit(make())
+                outs[tag] = fn(*args)
+                ms[tag] = round(1e3 * _min_step_seconds(lambda _: fn(*args), None), 3)
+        return outs, ms
+
+    def name_of(H, S, Dk, Dv):
+        return f"{H}x{S}x{Dk}" + (f"_{Dv}" if Dv != Dk else "")
+
+    def fwd_of(scale):
+        return lambda q, k, v: A._fa_fwd_pallas(q, k, v, None, True, scale, interpret)
+
+    for H, S, Dk, Dv in timed:
+        q, k, v, _ = operands(H, S, Dk, Dv)
+        plan = A._tile_plan(S, S, Dk, True, None, Dv)
+        outs, ms = under(variants, lambda: fwd_of(Dk ** -0.5), q, k, v)
+        name = name_of(H, S, Dk, Dv)
+        check(f"{name}/bit_for_bit", plan.live_axis and same(outs["live"], outs["parent_maps"])
+              and same(outs["clamp"], outs["parent_maps"]),
+              f"{plan.nq} x {plan.nk} blocks of {plan.bq}: o and lse of the clamp and of the "
+              f"live axis against the parent's maps")
+        check(f"{name}/fwd_ms_a_layer", ms["live"] <= ms["parent_maps"], json.dumps(ms))
+
+    H, S, Dk, Dv = two_calls
+    q, k, v, do = operands(H, S, Dk, Dv)
+    scale = Dk ** -0.5
+    plan = A._tile_plan(S, S, Dk, True, None, Dv)
+    o, lse = jax.jit(fwd_of(scale))(q, k, v)
+    outs, ms = under(
+        variants[:2],
+        lambda: lambda *a: A._fa_bwd_two_calls(plan, *a, None, None, scale, interpret, 0.0, None),
+        q, k, v, do, o, lse)
+    name = name_of(H, S, Dk, Dv)
+    check(f"{name}/two_calls_bit_for_bit", same(outs["clamp"], outs["parent_maps"]),
+          "dq, dk and dv of the dq + dkv pair under the clamp against the parent's maps")
+    check(f"{name}/two_calls_ms_a_layer", ms["clamp"] <= ms["parent_maps"], json.dumps(ms))
 
 
 # (tag, buffer rows, groups, K, N, rows in a group, the product's dtype)
@@ -1251,7 +1343,7 @@ def main() -> int:
     enable_compile_cache()
     results: list = []
     for group in (check_flash_dropout, check_flash_tiles, check_wy_prepare, check_deltanet,
-                  check_short_conv, check_flash_mla, check_flash_fused,
+                  check_short_conv, check_flash_mla, check_flash_fused, check_flash_fwd_live,
                   check_grouped_matmul, check_moe_rows, check_ssd, check_aliased_mt_kernels,
                   check_compiled_kernel_parity):
         try:

@@ -892,6 +892,9 @@ class TestFusedBackward:
            "get": 40, "gt": 5, "iota": 8, "log": 1, "lt": 1, "max": 5, "mul": 26,
            "program_id": 3, "reduce_max": 5, "reduce_sum": 5, "select_n": 8, "slice": 10,
            "sub": 10, "swap": 20}
+    # since PR 44 that forward's grid is its three live blocks: the same body
+    # with two ids where three were, each step's blocks read from the two tables
+    FWD_LIVE = {**FWD, "program_id": 2, "get": 42}
     DQ = {"add": 24, "broadcast_in_dim": 10, "concatenate": 3, "cond": 4,
           "convert_element_type": 20, "dot_general": 18, "eq": 3, "exp": 5, "get": 47, "gt": 4,
           "iota": 8, "lt": 1, "mul": 31, "program_id": 3, "reduce_sum": 5, "select_n": 4,
@@ -917,7 +920,7 @@ class TestFusedBackward:
         assert A._tile_plan(1024, 1024, 256, causal).nq == 2
         if causal:
             monkeypatch.setattr(A, "_HEAD_DQ_BYTES", 1024 * 256 * 4 - 1)
-            grad, want = self._grad_fn(with_lse), (self.FWD, self.DQ, self.DKV)
+            grad, want = self._grad_fn(with_lse), (self.FWD_LIVE, self.DQ, self.DKV)
             dlse = {"get": 5, "slice": 5} if with_lse else {}    # a read a strip
         else:
             lens, seed = jnp.full((1,), 1024.0), jnp.zeros((1,), jnp.int32)
@@ -934,7 +937,8 @@ class TestFusedBackward:
             want = (body.FWD, body.DQ, body.DKV)
             dlse = {"get": 1, "slice": 1} if with_lse else {}
         calls = _pallas_eqns(jax.make_jaxpr(grad)(x, x, x).jaxpr)
-        assert [e.params["grid_mapping"].grid for e in calls] == [(1, 2, 2)] * 3
+        grids = [e.params["grid_mapping"].grid for e in calls]
+        assert grids == [(1, 3) if causal else (1, 2, 2)] + [(1, 2, 2)] * 2
         fwd, dq, dkv = (collections.Counter(p) for p in _kernel_primitives(grad, x, x, x))
         assert fwd == want[0]
         assert dq == collections.Counter(want[1]) + collections.Counter(dlse)
@@ -949,7 +953,7 @@ class TestFusedBackward:
         operations, 41 products, 10 ``exp``) had 7 and 2."""
         x = jax.ShapeDtypeStruct((1, 1024, 256), jnp.bfloat16)
         calls = _pallas_eqns(jax.make_jaxpr(self._grad_fn(with_lse))(x, x, x).jaxpr)
-        assert [e.params["grid_mapping"].grid for e in calls] == [(1, 2, 2)] * 2
+        assert [e.params["grid_mapping"].grid for e in calls] == [(1, 3), (1, 2, 2)]
         assert [len(e.params["out_avals"]) for e in calls] == [2, 3]     # (o, lse); (dq, dk, dv)
         assert all(e.params["name"] is None for e in calls)    # ``%flash_attention.N`` on the chip
         params = calls[1].params["compiler_params"]["mosaic_tpu"]
@@ -958,7 +962,7 @@ class TestFusedBackward:
             A._tile_plan(1024, 1024, 256, True), 256, 256, 2)
         fwd, body = _kernel_primitives(self._grad_fn(with_lse), x, x, x)
         import collections
-        assert collections.Counter(fwd) == self.FWD
+        assert collections.Counter(fwd) == self.FWD_LIVE
         assert len(body) <= 265 + (10 if with_lse else 0), len(body)
         # the DKV body's 23 products + dq's: one a strip of the diagonal's walk, one a block below
         assert body.count("dot_general") == 23 + 4 + 1
@@ -1299,7 +1303,8 @@ class TestTwoWidths:
         jaxpr = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2)))(q, k, v)
         calls = _pallas_eqns(jaxpr.jaxpr)
         assert len(calls) == 2          # fwd, the fused backward of several blocks
-        widths = lambda vs: sorted({x.aval.shape[-1] for x in vs} - {1})
+        # (the seed and the forward's two step tables are scalars: one dimension)
+        widths = lambda vs: sorted({x.aval.shape[-1] for x in vs if x.aval.ndim > 1})
         for eqn in calls:
             assert widths(eqn.invars) == [128, 192]
         assert [x.aval.shape[-1] for x in calls[-1].outvars] == [192, 192, 128]
@@ -1316,3 +1321,191 @@ class TestTwoWidths:
             A.flash_attention(q, k, v[..., :4], impl="pallas")
         assert A.is_flash_available(128, 192, 128) and not A.is_flash_available(128, 192, 4)
         assert A.is_flash_available(128, 64) and not A.is_flash_available(100, 64, 64)
+
+
+# -- the causal forward names live blocks only; dq and dkv clamp onto the diagonal (PR 44) --
+
+
+def _parents_maps(monkeypatch):
+    """``ops.attention`` with the index maps of the commit before: every plan
+    without a window steps over the whole square, the forward too, and names the
+    block of its step — the plain ``(b, s, 0)``."""
+    own = lambda b, o, s, *_: (b, o, 0)
+    other = lambda b, o, s, *_: (b, s, 0)
+    maps = A._block_maps
+    monkeypatch.setattr(A.TilePlan, "live_axis", property(lambda self: False))
+    monkeypatch.setattr(A, "_block_maps",
+                        lambda plan: (own, other, other) if plan.window is None else maps(plan))
+
+
+# (Sq, Sk, D, causal, window): blocks a side, block, and the forward's (copies, steps) a head
+GRIDS = {
+    "S2048-b512": ((2048, 2048, 256, True, None), 4, 512, (10, 10)),
+    "S8192-b1024": ((8192, 8192, 128, True, None), 8, 1024, (36, 36)),
+    "S8192-b512": ((8192, 8192, 256, True, None), 16, 512, (136, 136)),
+    "windowed": ((8192, 8192, 128, True, 1024), 8, 1024, (15, 16)),     # the Mellum cell's band of 2
+    "non-causal": ((2048, 2048, 64, False, None), 2, 1024, (4, 4)),
+    "one-block": ((1024, 1024, 64, True, None), 1, 1024, (1, 1)),       # the GPT cells
+}
+
+
+class TestLiveBlocks:
+    """A causal head of several blocks without a window: the forward's grid is
+    ``(BH, live blocks)`` — no step and no copy above the diagonal — and the dq
+    and dkv kernels, which keep the square's grid, name the diagonal's block at
+    the steps above it. No kernel body changes: every output is the parent's
+    maps' bit for bit."""
+
+    BH = 2
+
+    @staticmethod
+    def _walks(plan):
+        """``(fwd, dq, dkv)``: the (query block, key block) each kernel's index
+        maps name at every grid step of one head, in the grid's order."""
+        own, keys, queries = A._block_maps(plan)
+        at = lambda m, *ids: int(m(0, *ids)[1])
+        square = lambda steps: [(o, s) for o in range(plan.nq) for s in range(steps)]
+        dq = [(at(own, o, s), at(keys, o, s)) for o, s in square(A._steps(plan))]
+        dkv = [(at(queries, o, s), at(own, o, s)) for o, s in square(A._steps(plan, True))]
+        if not plan.live_axis:
+            return dq, dq, dkv
+        (BH, steps), tables, own, keys = A._live_grid(plan, 3)
+        tables = [np.asarray(t) for t in tables]
+        assert BH == 3 and all(t.shape == (steps,) and t.dtype == np.int32 for t in tables)
+        return [(at(own, s, *tables), at(keys, s, *tables)) for s in range(steps)], dq, dkv
+
+    @pytest.mark.parametrize("case", GRIDS)
+    def test_index_maps_name_the_live_blocks_and_no_other(self, case):
+        key, n, block, (copies, steps) = GRIDS[case]
+        plan = A._tile_plan(*key)
+        assert (plan.nq, plan.nk, plan.bq, plan.bk) == (n, n, block, block)
+        causal, window = key[3], key[4]
+        assert plan.live_axis == (causal and window is None and n > 1)
+        fwd, dq, dkv = self._walks(plan)
+        assert fwd == plan.fwd_steps() and len(fwd) == steps
+        named_anew = lambda walk: sum(a != b for a, b in zip([None] + walk, walk))
+        lower = [(i, j) for i in range(n) for j in range(i + 1)]
+        if plan.live_axis or plan.one_pass:
+            # the lower triangle and nothing else: the forward steps on each block
+            # once, row by row from key block 0 to the diagonal; dq and dkv step
+            # over the square and name, above the diagonal, the diagonal's block
+            assert fwd == lower
+            assert dq == [(i, min(j, i)) for i in range(n) for j in range(n)]
+            assert dkv == [(max(i, j), j) for j in range(n) for i in range(n)]
+            assert set(dq) == set(dkv) == set(lower)
+            assert [named_anew(w) for w in (fwd, dq, dkv)] == [n * (n + 1) // 2] * 3
+        elif window is not None:            # the parent's band, clamped into the sequence
+            back = plan.band - 1
+            assert fwd == dq == [(i, max(i + s - back, 0)) for i in range(n) for s in range(plan.band)]
+            assert dkv == [(min(j + s, n - 1), j) for j in range(n) for s in range(plan.band)]
+        else:                               # the parent's square, each block once
+            assert fwd == dq == [(i, j) for i in range(n) for j in range(n)]
+            assert dkv == [(i, j) for j in range(n) for i in range(n)]
+        assert named_anew(fwd) == copies
+
+    @pytest.mark.parametrize("case", GRIDS)
+    def test_counts_book_the_forwards_copies_and_steps(self, case):
+        key, n, _, (copies, steps) = GRIDS[case]
+        plan = A._tile_plan(*key)
+        tiles = plan.counts(False)
+        assert sorted(tiles) == ["live", "masked", "total"]         # the backward kernels' rows
+        assert plan.counts(False, fwd=True) == {**tiles, "copies": copies, "steps": steps}
+        assert plan.counts(True, fwd=True)["copies"] == copies
+        if plan.live_axis:      # what the parent's maps took: the whole square, each block copied
+            assert (copies, steps) == (n * (n + 1) // 2,) * 2 and n * n > steps
+
+    def test_tile_records_hold_them_on_the_forwards_row(self):
+        from beforeholiday_tpu import monitor
+        from beforeholiday_tpu.guard import dispatch
+
+        key = (384, 384, 64, True, False)
+        for kernel in ("fwd", "dqkv_blocks"):
+            dispatch._TILES.pop(("flash_attention", kernel, key), None)
+        q, k, v = (t[0] for t in _qkv(jax.random.PRNGKey(45), B=1, H=2, S=384, D=64))
+        jax.grad(lambda *a: jnp.sum(A._flash3(
+            *a, None, jnp.zeros((1,), jnp.int32), True, 0.1, 0.0)))(q, k, v)
+        rows = {r["kernel"]: r for r in monitor.tile_records() if r["key"] == repr(key)}
+        assert (rows["fwd"]["copies"], rows["fwd"]["steps"]) == (6, 6)      # 3 x 3 blocks of 128
+        assert "copies" not in rows["dqkv_blocks"] and "steps" not in rows["dqkv_blocks"]
+        assert all((r["total"], r["live"], r["masked"]) == (9, 6, 3) for r in rows.values())
+
+    def _inputs(self, Dk, Dv, S, seed=51):
+        return TestTwoWidths._inputs(self, Dk, Dv, S, seed)
+
+    def _all_five(self, Dk, Dv, S, variant, two_calls):
+        q, k, v, w, wl = self._inputs(Dk, Dv, S)
+        scale = Dk ** -0.5
+        plan = A._tile_plan(S, S, Dk, True, None, Dv)
+        lens = jnp.asarray((S - 70, S - 128), jnp.float32) if variant in ("kv_lens", "dlse") else None
+        rate = 0.25 if variant == "dropout" else 0.0
+        seed = jnp.asarray([4321], jnp.int32)
+        dlse = jnp.broadcast_to(wl[..., None], (self.BH, S, 128)) if variant == "dlse" else None
+        fwd = lambda q, k, v: A._fa_fwd_pallas(q, k, v, lens, True, scale, True, rate, seed)
+        (call,) = _pallas_eqns(jax.make_jaxpr(fwd)(q, k, v).jaxpr)
+        o, lse = fwd(q, k, v)
+        bwd = A._fa_bwd_two_calls if two_calls else A._fa_bwd_blocks
+        return call.params["grid_mapping"].grid, (o, lse) + tuple(
+            bwd(plan, q, k, v, w, o, lse, dlse, lens, scale, True, rate, seed))
+
+    @pytest.mark.parametrize("backward", ["one_call", "two_calls"])
+    @pytest.mark.parametrize("variant", ["plain", "kv_lens", "dropout", "dlse"])
+    @pytest.mark.parametrize("Dk,Dv", [(192, 128), (64, 64), (256, 256)], ids=lambda x: str(x))
+    def test_every_output_is_the_parents_maps_bit_for_bit(self, Dk, Dv, variant, backward,
+                                                          monkeypatch):
+        """3 x 3 blocks of 128 in the interpreter: ``o``, ``lse``, ``dq``, ``dk``
+        and ``dv`` under the live axis and the clamp against the same kernels
+        under the plain ``(b, s, 0)`` maps on the whole square — the one backward
+        call (its query side) and the dq + dkv pair (both sides)."""
+        monkeypatch.setattr(A, "_keep_mask", _hashed_keep)
+        S, two = 384, backward == "two_calls"
+        plan = A._tile_plan(S, S, Dk, True, None, Dv)
+        assert (plan.nq, plan.bq, plan.live_axis) == (3, 128, True)
+        grid, got = self._all_five(Dk, Dv, S, variant, two)
+        with monkeypatch.context() as parent:
+            _parents_maps(parent)
+            square, want = self._all_five(Dk, Dv, S, variant, two)
+        assert plan.live_axis and (grid, square) == ((self.BH, 6), (self.BH, 3, 3))
+        for name, a, b in zip(("o", "lse", "dq", "dk", "dv"), got, want):
+            assert a.shape == b.shape and not np.any(np.isnan(np.asarray(a))), name
+            np.testing.assert_array_equal(a, b, err_msg=name)
+
+    @pytest.mark.parametrize("variant", ["plain", "kv_lens", "dropout"])
+    def test_the_forwards_grid_is_the_live_blocks_and_the_parents_is_the_square(
+            self, variant, monkeypatch):
+        """The call's own grid and operands: ``(BH, 6)`` with the two tables after
+        the other scalars where the parent's maps give ``(BH, 3, 3)``; the
+        backward keeps the square and its operands."""
+        q, k, v, w, _ = self._inputs(64, 64, 384)
+        lens = jnp.asarray((300.0, 256.0)) if variant == "kv_lens" else None
+        rate = 0.25 if variant == "dropout" else 0.0
+        seed = jnp.zeros((1,), jnp.int32)
+
+        def calls():    # a fresh function a trace: ``_flash3`` keeps the forward it traced
+            def both(q, k, v):
+                o, lse = A._fa_fwd_pallas(q, k, v, lens, True, 0.125, True, rate, seed)
+                return A._fa_bwd_pallas(q, k, v, w, o, lse, None, lens, True, 0.125, True,
+                                        rate, seed)
+            return _pallas_eqns(jax.make_jaxpr(both)(q, k, v).jaxpr)
+
+        scalars = (lens is not None) + (rate > 0.0)
+        fwd, bwd = calls()
+        assert fwd.params["grid_mapping"].grid == (self.BH, 6)
+        assert bwd.params["grid_mapping"].grid == (self.BH, 3, 3)
+        assert fwd.params["grid_mapping"].num_index_operands == scalars + 2
+        assert bwd.params["grid_mapping"].num_index_operands == scalars
+        tables = [x.aval for x in fwd.invars[scalars:scalars + 2]]
+        assert [(t.shape, t.dtype) for t in tables] == [((6,), jnp.int32)] * 2
+        semantics = lambda e: tuple(e.params["compiler_params"]["mosaic_tpu"].dimension_semantics)
+        assert semantics(fwd) == ("parallel", "arbitrary")
+        with monkeypatch.context() as parent:
+            _parents_maps(parent)
+            fwd, bwd = calls()
+            assert fwd.params["grid_mapping"].grid == bwd.params["grid_mapping"].grid == (self.BH, 3, 3)
+            assert fwd.params["grid_mapping"].num_index_operands == scalars
+            assert semantics(fwd) == ("parallel", "parallel", "arbitrary")
+
+    def test_the_fused_backward_has_no_index_map_of_its_own(self):
+        import inspect
+
+        assert "lambda" not in inspect.getsource(A._fa_bwd_blocks).replace("spec = lambda", "")
+        assert "_block_maps(plan)" in inspect.getsource(A._fa_bwd_blocks)

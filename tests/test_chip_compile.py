@@ -358,12 +358,16 @@ def test_the_two_width_flash_kernels_compile_for_the_chip(one_chip, flash_compil
     assert not re.search(r"= [a-z0-9]+\[[\d,]*\]\S* pad\(", text)
 
 
-@pytest.mark.parametrize("BH,S,Dk,Dv", (
+# the four 8k cells' causal calls without a window: 36 live blocks of 64 a head, and 136 of 256
+at_the_8k_cells_calls = pytest.mark.parametrize("BH,S,Dk,Dv", (
     (32, 8192, 192, 128),                       # the Kanana cell: 8 x 8 blocks, 6 MiB of dq a head
     (32, 8192, 64, 64),                         # the LFM2 and Nemotron cells
     (32, 8192, 128, 128),                       # the Mellum cell's full layer
     (16, 8192, 256, 256),                       # the Qwen cell: 16 x 16 blocks of 512, 8 MiB of dq
 ), ids=("kanana_cell", "lfm2_cell", "mellum_cell", "qwen_cell"))
+
+
+@at_the_8k_cells_calls
 @pytest.mark.parametrize("variant", ("plain", "lens_dlse"))
 def test_the_fused_backward_of_several_blocks_compiles_for_the_chip(
         one_chip, flash_compiled, BH, S, Dk, Dv, variant):
@@ -392,6 +396,32 @@ def test_the_fused_backward_of_several_blocks_compiles_for_the_chip(
                               shape((BH,), jnp.float32), rows).compile().as_text()
     assert text.count("tpu_custom_call") == 1
     assert f'"scoped_memory_configs":[{{"memory_space":"1","offset":"0","size":"{limit}"}}]' in text
+
+
+@at_the_8k_cells_calls
+@pytest.mark.parametrize("variant", ("plain", "kv_lens"))
+def test_the_forward_on_its_live_blocks_compiles_for_the_chip(
+        one_chip, flash_compiled, BH, S, Dk, Dv, variant):
+    """A causal head of several blocks takes a forward whose grid is ``(BH, live
+    blocks)``, each step's blocks — the output's among them — named through two
+    int32 tables in SMEM (``ops/attention.py:_live_grid``): at the four 8k cells'
+    calls, plain and with ``kv_lens`` (a third scalar operand before the tables),
+    ONE kernel with no ``pad`` beside it."""
+    A = flash_compiled
+    bf = jnp.bfloat16
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    plan = A._tile_plan(S, S, Dk, True, None, Dv)
+    assert plan.live_axis and len(plan.fwd_steps()) == plan.nq * (plan.nq + 1) // 2
+
+    def fwd(q, k, v, lens):
+        return A._fa_fwd_pallas(q, k, v, lens if variant == "kv_lens" else None, True,
+                                Dk ** -0.5, False)
+
+    wide, narrow = shape((BH, S, Dk), bf), shape((BH, S, Dv), bf)
+    text = jax.jit(fwd).lower(wide, wide, narrow, shape((BH,), jnp.float32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert f"s32[{len(plan.fwd_steps())}]" in text            # the tables reach the kernel
+    assert not re.search(r"= [a-z0-9]+\[[\d,]*\]\S* pad\(", text)
 
 
 def test_same_step_reads_two_texts_as_one_program_when_only_source_lines_moved(

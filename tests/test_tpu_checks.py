@@ -172,3 +172,38 @@ def test_the_flash_fused_check_runs_its_comparison():
     assert tpu_checks._FUSED_SHAPES == ((32, 8192, 192, 128), (32, 8192, 64, 64),
                                         (32, 8192, 128, 128), (16, 8192, 256, 256))
     assert "check_flash_fused" in inspect.getsource(tpu_checks.main)
+
+
+def test_the_flash_fwd_live_check_runs_its_comparison():
+    """The chip check of the causal forward's live axis at toy shapes on the
+    interpreter (3 x 3 blocks of 128): ``o`` and ``lse`` under the parent's maps,
+    the clamp and the live axis agree bit for bit, as do dq, dk and dv of the
+    dq + dkv pair under the parent's maps and the clamp; the three forwards and
+    the two pairs are what it times (not judged here), the four 8k cells' calls
+    are its default, the module's maps are put back, and ``main`` runs the group."""
+    import inspect
+    import json
+
+    from beforeholiday_tpu.ops import attention as A
+
+    live_axis, maps, results = A.TilePlan.live_axis, A._block_maps, []
+    tpu_checks.check_flash_fwd_live(results, timed=((2, 384, 192, 128), (1, 384, 64, 64)),
+                                    two_calls=(1, 384, 64, 64))
+    by_name = {name: (ok, info) for name, ok, info in results}
+    assert set(by_name) == {f"flash_fwd_live/{k}" for k in (
+        "2x384x192_128/bit_for_bit", "2x384x192_128/fwd_ms_a_layer", "1x384x64/bit_for_bit",
+        "1x384x64/fwd_ms_a_layer", "1x384x64/two_calls_bit_for_bit",
+        "1x384x64/two_calls_ms_a_layer")}
+    for name, (ok, info) in by_name.items():
+        assert ok or name.endswith("ms_a_layer"), (name, info)
+    assert "3 x 3 blocks of 128" in by_name["flash_fwd_live/1x384x64/bit_for_bit"][1]
+    assert sorted(json.loads(by_name["flash_fwd_live/1x384x64/fwd_ms_a_layer"][1])) == [
+        "clamp", "live", "parent_maps"]
+    assert sorted(json.loads(by_name["flash_fwd_live/1x384x64/two_calls_ms_a_layer"][1])) == [
+        "clamp", "parent_maps"]
+    assert A.TilePlan.live_axis is live_axis and A._block_maps is maps
+    assert A._tile_plan(384, 384, 64, True).live_axis
+    defaults = inspect.signature(tpu_checks.check_flash_fwd_live).parameters
+    assert defaults["timed"].default == tpu_checks._FUSED_SHAPES
+    assert defaults["two_calls"].default == (32, 8192, 192, 128)
+    assert "check_flash_fwd_live" in inspect.getsource(tpu_checks.main)
