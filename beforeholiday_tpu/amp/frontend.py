@@ -255,9 +255,6 @@ def initialize(
     optimizer: Any = None,
     opt_level: Optional[str] = None,
     *,
-    tuned: bool = False,
-    tuning_key: Any = None,
-    tuning_manifest: Any = None,
     cast_model_outputs: Optional[Any] = jnp.float32,
     keep_batchnorm_fp32: Optional[bool] = None,
     master_weights: Optional[bool] = None,
@@ -290,14 +287,6 @@ def initialize(
     _initialize.py:229-233) — GAN-style multi-loss training scales each loss
     with its own dynamic state; all land in ``state_dict`` as loss_scaler{i}.
 
-    ``tuned=True`` resolves ``opt_level`` from the autotuning manifest
-    (:mod:`beforeholiday_tpu.tune`) under ``tuning_key`` — by default the
-    key is derived from the ``params`` pytree's abstract signature. An
-    explicitly passed ``opt_level`` always wins over the manifest; a
-    manifest miss falls back to the O5 default with one structured warning.
-    ``tuning_manifest`` accepts a ``TuningManifest`` or a path (None = the
-    default manifest location).
-
     ``arena_native=True`` (implies ``arena_masters``) stores the cast params
     as :class:`PackedParams` — per-dtype flat HBM arenas. ``AmpModel.apply``
     unpacks transparently (static slices XLA fuses into consumers), so
@@ -309,24 +298,7 @@ def initialize(
     (csrc/multi_tensor_apply.cuh never repacks either). Single-device /
     manual-shard_map fast path, like ``arena_masters``.
     """
-    if tuned:
-        from beforeholiday_tpu import tune as _tune
-
-        key = tuning_key
-        if key is None:
-            # the params pytree is the natural per-model signature here —
-            # same structure + leaf shapes/dtypes, same manifest entry
-            key = _tune.tuning_key(params)
-        resolved = _tune.resolve_trainer_knobs(
-            "amp.initialize",
-            {"opt_level": "O5"},
-            {"opt_level": _tune.UNSET if opt_level is None else opt_level},
-            tuned=True,
-            tuning_key=key,
-            manifest=tuning_manifest,
-        )
-        opt_level = resolved["opt_level"]
-    elif opt_level is None:
+    if opt_level is None:
         opt_level = "O5"
     if opt_level not in opt_levels:
         raise RuntimeError(

@@ -62,7 +62,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import math
 from typing import Optional, Tuple
 
 import jax
@@ -182,37 +181,27 @@ def param_shapes(cfg: DeepseekV3Config) -> dict:
     return shapes
 
 
-def _is_leaf_shape(x) -> bool:
-    return isinstance(x, tuple) and len(x) == 2 and isinstance(x[1], str)
-
-
 def init(key: jax.Array, cfg: DeepseekV3Config) -> dict:
     """Seeded float32 parameters: matmul weights, the embedding and the head
     N(0, ``initializer_range``), norm weights one, the selection bias zeros (as
     the published code registers it)."""
-    shapes, treedef = jax.tree.flatten(param_shapes(cfg), is_leaf=_is_leaf_shape)
-
-    def draw(i, shape, kind):
+    def draw(k, shape, kind):
         if kind == "one":
             return jnp.ones(shape, _F32)
         if kind == "zero":
             return jnp.zeros(shape, _F32)
-        return jax.random.normal(jax.random.fold_in(key, i), shape, _F32) * cfg.initializer_range
+        return jax.random.normal(k, shape, _F32) * cfg.initializer_range
 
-    return jax.tree.unflatten(treedef, [draw(i, *leaf) for i, leaf in enumerate(shapes)])
+    return _layers.draw_params(key, param_shapes(cfg), draw)
 
 
 def keep_fp32(path) -> bool:
     """``amp.initialize(keep_fp32_mask=...)``: the norm weights and the
     selection bias."""
-    names = [str(getattr(p, "key", getattr(p, "name", p))).lower() for p in path]
-    return any("norm" in n or n == "expert_bias" for n in names)
+    return _layers.keep_fp32(path, also=("expert_bias",))
 
 
-def rms_norm(x, w, eps):
-    from beforeholiday_tpu.ops import fused_rms_norm
-
-    return fused_rms_norm(x, w.astype(_F32), eps=eps)
+rms_norm = _layers.rms_norm
 
 
 def evens_then_odds(w):
@@ -239,8 +228,6 @@ def attention(cfg: DeepseekV3Config, u, p, table):
     zero-padded copy of it and a sum (9.6 ms a step of `add_any` in the first
     chip run of this cell), the transpose of a slice of a weight is 4 x
     smaller and off the token axis."""
-    from beforeholiday_tpu.ops import flash_attention
-
     B, S, _ = u.shape
     H, r = cfg.num_attention_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -259,31 +246,20 @@ def attention(cfg: DeepseekV3Config, u, p, table):
     q = jnp.concatenate([q_nope, _layers.apply_rotary(q_rot, *table)], axis=-1)
     k_rot = jnp.broadcast_to(_layers.apply_rotary(k_rot, *table), (B, S, H, dr))
     k = jnp.concatenate([k_nope, k_rot], axis=-1)
-    heads_first = lambda t: t.transpose(0, 2, 1, 3)
-    ctx = flash_attention(heads_first(q), heads_first(k), heads_first(v), causal=True,
-                          scale=cfg.qk_head_dim ** -0.5, impl=cfg.attention_impl)
-    return heads_first(ctx).reshape(B, S, H * dv) @ p["w_o"].astype(dt)
+    # a group of one; the scale is the queries' whole width: qk_head_dim^-1/2
+    ctx = _layers.grouped_query_attention(q, k, v, impl=cfg.attention_impl)
+    return ctx.reshape(B, S, H * dv) @ p["w_o"].astype(dt)
 
 
-@_annotate("dense_ffn")
-def dense_ffn(h, p):
-    from beforeholiday_tpu.moe.dropless import swiglu
-
-    return swiglu(h, p["w_gate"], p["w_up"], p["w_down"])
+dense_ffn = _annotate("dense_ffn")(_layers.swiglu_ffn)
 
 
 def sparse_ffn(cfg: DeepseekV3Config, h, p):
     """``(y, counters)`` of one mixture-of-experts part (``moe.dropless``'s spans)."""
-    from beforeholiday_tpu.moe.dropless import dropless_moe, route_sigmoid
-
-    B, S, D = h.shape
-    y, counters = dropless_moe(
-        h.reshape(B * S, D), p, top_k=cfg.num_experts_per_tok,
-        first_expert=cfg.first_expert, rows_bound=cfg.moe_rows_bound,
-        renormalize=cfg.norm_topk_prob,
-        route=functools.partial(route_sigmoid, bias=p["expert_bias"],
-                                scale=cfg.routed_scaling_factor))
-    return y.reshape(B, S, D), counters
+    return _layers.sigmoid_moe(
+        h, p, top_k=cfg.num_experts_per_tok, first_expert=cfg.first_expert,
+        rows_bound=cfg.moe_rows_bound, renormalize=cfg.norm_topk_prob,
+        bias=p["expert_bias"], scale=cfg.routed_scaling_factor)
 
 
 def _layer(cfg: DeepseekV3Config, ffn: str, x, p, table):
@@ -306,18 +282,12 @@ def forward(params: dict, tokens: jax.Array, cfg: DeepseekV3Config):
     layer = {ffn: _remat_apply(functools.partial(_layer, cfg, ffn), cfg.remat_policy)
              for ffn in sorted(set(held))}
     with _span("deepseek_v3_layers"):
-        seen = []
-        for ffn, p in zip(held, params["layers"], strict=True):
-            x, c = layer[ffn](x, p, table)
-            if c is not None:
-                seen.append(c)
-    counters = (_layers.reduce_counters(jax.tree.map(lambda *v: jnp.stack(v), *seen)) if seen
-                else {k: jnp.zeros((), _F32) for k in COUNTERS})
+        x, seen = _layers.unrolled_layers(layer, held, params["layers"], x, table)
+    counters = _layers.step_counters(seen)
     with _span("deepseek_v3_head"):
         x = rms_norm(x, params["norm"], cfg.rms_norm_eps)
-        head = params["embed" if cfg.tie_word_embeddings else "head"]
-        logits = jax.lax.dot_general(
-            x, head.astype(x.dtype), (((2,), (1,)), ((), ())), preferred_element_type=_F32)
+        logits = _layers.logits_of(
+            x, params["embed" if cfg.tie_word_embeddings else "head"])
     return logits, counters
 
 
@@ -329,13 +299,9 @@ def loss_fn(params: dict, tokens: jax.Array, targets: jax.Array,
     """``(mean next-token cross entropy over the vocabulary held, counters)``.
     ``forward_fn(params, tokens)`` overrides the plain forward (an amp-wrapped
     apply), as in ``testing/gpt.loss_fn``."""
-    if forward_fn is None:
-        logits, counters = forward(params, tokens, cfg)
-    else:
-        logits, counters = forward_fn(params, tokens)
-    return cross_entropy(logits, targets), counters
+    return _layers.loss_fn(forward_fn or functools.partial(forward, cfg=cfg), cross_entropy,
+                           params, tokens, targets)
 
 
 def param_count(cfg: DeepseekV3Config) -> int:
-    return sum(math.prod(shape) for shape, _ in
-               jax.tree.leaves(param_shapes(cfg), is_leaf=_is_leaf_shape))
+    return _layers.param_count(param_shapes(cfg))

@@ -1,16 +1,62 @@
-"""What the decoder models share: rotary tables, the causal depthwise
-convolution of the recurrent mixers, cross entropy, the layer stack's plumbing
-and the reduction of the MoE counters over layers."""
+"""What the decoder models share: the shapes tree a family's parameters are
+drawn and counted from, the RMS norm, rotary tables, grouped-query attention
+(the tail every family with grouped keys ends in, and the QK-normed body two of
+them are), the causal depthwise convolution of the recurrent mixers, the SwiGLU
+and sigmoid-routed feed-forward parts, the layer stack's plumbing (scanned
+periods, unrolled layers), the head, the loss and the reduction of the MoE
+counters over layers.
+
+A function here takes published hyper-parameters and arrays, never a family's
+name: a family opens its own span (``monitor.spans``) and calls the body inside
+it, so every op keeps the scope its layer metric reads."""
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import NamedTuple, Optional
+from typing import Callable, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 
 _F32 = jnp.float32
+
+
+# ---------------------------------------------------------------------------------
+# the shapes tree: ``param_shapes(cfg)`` of a family, leaves ``(shape, init kind)``
+# ---------------------------------------------------------------------------------
+
+
+def is_shape_leaf(x) -> bool:
+    return isinstance(x, tuple) and len(x) == 2 and isinstance(x[1], str)
+
+
+def param_count(shapes) -> int:
+    return sum(math.prod(shape) for shape, _ in jax.tree.leaves(shapes, is_leaf=is_shape_leaf))
+
+
+def draw_params(key: jax.Array, shapes, draw: Callable):
+    """The tree of ``shapes`` with leaf ``i`` (in the tree's flattened order:
+    keys sorted) drawn as ``draw(fold_in(key, i), shape, kind)``."""
+    leaves, treedef = jax.tree.flatten(shapes, is_leaf=is_shape_leaf)
+    return jax.tree.unflatten(
+        treedef, [draw(jax.random.fold_in(key, i), *leaf) for i, leaf in enumerate(leaves)])
+
+
+def keep_fp32(path, also: Sequence[str] = ()) -> bool:
+    """``amp.initialize(keep_fp32_mask=...)`` of a decoder family: the leaves
+    with "norm" in a name of their path, and those named in ``also`` (the
+    family's per-head scalars, its selection bias)."""
+    names = [str(getattr(p, "key", getattr(p, "name", p))).lower() for p in path]
+    return any("norm" in n or n in also for n in names)
+
+
+def rms_norm(x, w, eps):
+    """``w * x / sqrt(mean(x^2) + eps)`` over the last axis (``ops.fused_rms_norm``),
+    the weight in float32."""
+    from beforeholiday_tpu.ops import fused_rms_norm
+
+    return fused_rms_norm(x, w.astype(_F32), eps=eps)
 
 
 class Yarn(NamedTuple):
@@ -74,6 +120,42 @@ def apply_rotary(x, cos, sin):
     return jnp.concatenate([rotated.astype(x.dtype), x[..., rotary_dim:]], axis=-1)
 
 
+def grouped_query_attention(q, k, v, *, window: Optional[int] = None,
+                            impl: Optional[str] = None):
+    """Causal softmax attention of ``q (B, S, H, hd)`` over ``k (B, S, Hkv, hd)``,
+    ``v (B, S, Hkv, dv)`` at ``hd^-1/2``, each key head serving ``H / Hkv`` query
+    heads BY REPETITION (``ops.flash_attention`` takes equal head counts);
+    ``window``: the keys a query sees, None for all before it. ``(B, S, H, dv)``:
+    the heads back beside their positions, for the caller to gate or flatten."""
+    from beforeholiday_tpu.ops import flash_attention
+
+    H, Hkv, hd = q.shape[2], k.shape[2], q.shape[3]
+    if H != Hkv:
+        k, v = (jnp.repeat(t, H // Hkv, axis=2) for t in (k, v))
+    heads_first = lambda t: t.transpose(0, 2, 1, 3)
+    ctx = flash_attention(heads_first(q), heads_first(k), heads_first(v), causal=True,
+                          scale=hd ** -0.5, window=window, impl=impl)
+    return heads_first(ctx)
+
+
+def qk_norm_attention(u, p, table, *, heads: int, kv_heads: int, head_dim: int, eps: float,
+                      window: Optional[int] = None, impl: Optional[str] = None):
+    """One bias-free attention mixer on ``u (B, S, D)``: ``w_q`` / ``w_k`` /
+    ``w_v``, ``q`` and ``k`` through an RMS norm over the head (``q_norm`` /
+    ``k_norm``: one weight of ``head_dim``, shared by the heads) and the rotary
+    embedding of ``table = (cos, sin)`` on the whole head,
+    :func:`grouped_query_attention`, ``w_o``."""
+    B, S, _ = u.shape
+    dt = u.dtype
+    q = (u @ p["w_q"].astype(dt)).reshape(B, S, heads, head_dim)
+    k = (u @ p["w_k"].astype(dt)).reshape(B, S, kv_heads, head_dim)
+    v = (u @ p["w_v"].astype(dt)).reshape(B, S, kv_heads, head_dim)
+    q = apply_rotary(rms_norm(q, p["q_norm"], eps), *table)
+    k = apply_rotary(rms_norm(k, p["k_norm"], eps), *table)
+    ctx = grouped_query_attention(q, k, v, window=window, impl=impl)
+    return ctx.reshape(B, S, heads * head_dim) @ p["w_o"].astype(dt)
+
+
 def causal_depthwise_conv(x, w):
     """``y[t, c] = sum_j w[c, j] * x[t - (K-1) + j, c]``, zeros before the
     start. ``x``: ``(B, S, C)``, ``w``: ``(C, K)``."""
@@ -82,6 +164,33 @@ def causal_depthwise_conv(x, w):
     w = w.astype(_F32)
     y = sum(xp[:, j:j + S].astype(_F32) * w[:, j] for j in range(K))
     return y.astype(x.dtype)
+
+
+def swiglu_ffn(h, p):
+    """The dense SwiGLU of ``w_gate`` / ``w_up`` / ``w_down`` (``moe.dropless.swiglu``)."""
+    from beforeholiday_tpu.moe.dropless import swiglu
+
+    return swiglu(h, p["w_gate"], p["w_up"], p["w_down"])
+
+
+def sigmoid_moe(h, p, *, top_k: int, first_expert: int, rows_bound: Optional[int],
+                renormalize: bool, **route):
+    """``(y, counters)`` of one mixture-of-experts part on ``h (B, S, D)``:
+    ``moe.dropless.dropless_moe`` (its spans) under ``route_sigmoid(**route)``
+    (``bias``, ``scale``, ``eps``: the published router's)."""
+    from beforeholiday_tpu.moe.dropless import dropless_moe, route_sigmoid
+
+    B, S, D = h.shape
+    y, counters = dropless_moe(
+        h.reshape(B * S, D), p, top_k=top_k, first_expert=first_expert, rows_bound=rows_bound,
+        renormalize=renormalize, route=functools.partial(route_sigmoid, **route))
+    return y.reshape(B, S, D), counters
+
+
+def logits_of(x, head):
+    """``x (B, S, D)`` against the rows of ``head (V, D)``: float32 logits."""
+    return jax.lax.dot_general(
+        x, head.astype(x.dtype), (((2,), (1,)), ((), ())), preferred_element_type=_F32)
 
 
 def cross_entropy(logits, targets):
@@ -122,6 +231,26 @@ def unstack(tree, n: int):
             for i in range(n)]
 
 
+def unrolled_layers(layer: dict, kinds, leaves, x, *shared):
+    """``x`` through layers that repeat nothing: ``layer[kind](x, p, *shared)``
+    for each ``kind`` of ``kinds`` on its own leaves ``p`` (one dict a layer,
+    nothing stacked). ``(x, [the counters of the layers that count])``."""
+    seen = []
+    for kind, p in zip(kinds, leaves, strict=True):
+        x, c = layer[kind](x, p, *shared)
+        if c is not None:
+            seen.append(c)
+    return x, seen
+
+
+def loss_fn(forward: Callable, cross_entropy: Callable, params, tokens, targets):
+    """``(cross_entropy(logits, targets), counters)`` of ``forward(params, tokens)
+    -> (logits, counters)``: a family passes its plain forward or the caller's
+    amp-wrapped apply, and its own span-named cross entropy."""
+    logits, counters = forward(params, tokens)
+    return cross_entropy(logits, targets), counters
+
+
 # what ``moe.dropless`` counts in a layer, and a model returns for a step
 COUNTERS = ("expert_rows", "expert_load_max_over_mean", "dropped_rows")
 
@@ -135,3 +264,11 @@ def reduce_counters(seen):
         "expert_load_max_over_mean": jnp.max(seen["expert_load_max_over_mean"]),
         "dropped_rows": jnp.sum(seen["dropped_rows"]),
     }
+
+
+def step_counters(seen: list):
+    """:func:`reduce_counters` of a list of one dict a counting layer; a step
+    without such a layer counts zeros."""
+    if not seen:
+        return {k: jnp.zeros((), _F32) for k in COUNTERS}
+    return reduce_counters(jax.tree.map(lambda *v: jnp.stack(v), *seen))

@@ -25,8 +25,8 @@ Written from the published configuration
   ``head_dim`` each, shared by the heads); rotary embedding on the whole head
   (``rotate_half`` layout, ``rope_theta``, no scaling); causal softmax at
   ``head_dim^-1/2`` (``ops.flash_attention``); ``W_o``. This is
-  ``models.mellum.attention``'s mathematics without a window, **copied**: sharing
-  it would move lines of a file an accepted cell traces (ROADMAP C).
+  ``models.mellum``'s attention without a window: both call
+  ``models.layers.qk_norm_attention``.
 * mixture of experts (``moe.dropless``): ``s = sigmoid(u W_r)`` in float32 over
   all the router's outputs; the ``num_experts_per_tok`` largest of ``s + b``
   (``use_expert_bias``); the chosen ``s`` over their sum plus 1e-6
@@ -183,10 +183,6 @@ def param_shapes(cfg: Lfm2MoeConfig) -> dict:
     return shapes
 
 
-def _is_leaf_shape(x) -> bool:
-    return isinstance(x, tuple) and len(x) == 2 and isinstance(x[1], str)
-
-
 def init(key: jax.Array, cfg: Lfm2MoeConfig) -> dict:
     """Seeded float32 parameters: matmul weights and the (tied) embedding
     N(0, ``initializer_range``), norm weights one, the convolution uniform in
@@ -196,10 +192,7 @@ def init(key: jax.Array, cfg: Lfm2MoeConfig) -> dict:
     choice for some tokens in a hundred and the held experts' rows stay near
     their expected number, and **not zero**, so that the choice by ``score +
     bias`` and the weights by ``score`` differ in a step."""
-    shapes, treedef = jax.tree.flatten(param_shapes(cfg), is_leaf=_is_leaf_shape)
-
-    def draw(i, shape, kind):
-        k = jax.random.fold_in(key, i)
+    def draw(k, shape, kind):
         if kind == "one":
             return jnp.ones(shape, _F32)
         if kind == "conv":
@@ -208,20 +201,16 @@ def init(key: jax.Array, cfg: Lfm2MoeConfig) -> dict:
         std = cfg.expert_bias_init_std if kind == "bias" else cfg.initializer_range
         return jax.random.normal(k, shape, _F32) * std
 
-    return jax.tree.unflatten(treedef, [draw(i, *leaf) for i, leaf in enumerate(shapes)])
+    return _layers.draw_params(key, param_shapes(cfg), draw)
 
 
 def keep_fp32(path) -> bool:
     """``amp.initialize(keep_fp32_mask=...)``: the norm weights and the
     selection bias."""
-    names = [str(getattr(p, "key", getattr(p, "name", p))).lower() for p in path]
-    return any("norm" in n or n == "expert_bias" for n in names)
+    return _layers.keep_fp32(path, also=("expert_bias",))
 
 
-def rms_norm(x, w, eps):
-    from beforeholiday_tpu.ops import fused_rms_norm
-
-    return fused_rms_norm(x, w.astype(_F32), eps=eps)
+rms_norm = _layers.rms_norm
 
 
 @_annotate("conv_mixer")
@@ -236,42 +225,20 @@ def short_conv_mixer(cfg: Lfm2MoeConfig, u, p):
 @_annotate("attn_mixer")
 def attention(cfg: Lfm2MoeConfig, u, p, table):
     """One attention mixer; ``table``: ``(cos, sin)`` of the sequence."""
-    from beforeholiday_tpu.ops import flash_attention
-
-    B, S, _ = u.shape
-    H, Hkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-    dt = u.dtype
-    q = (u @ p["w_q"].astype(dt)).reshape(B, S, H, hd)
-    k = (u @ p["w_k"].astype(dt)).reshape(B, S, Hkv, hd)
-    v = (u @ p["w_v"].astype(dt)).reshape(B, S, Hkv, hd)
-    q = _layers.apply_rotary(rms_norm(q, p["q_norm"], cfg.norm_eps), *table)
-    k = _layers.apply_rotary(rms_norm(k, p["k_norm"], cfg.norm_eps), *table)
-    if H != Hkv:                       # GQA by repetition, as in ``models.mellum``
-        k, v = (jnp.repeat(t, H // Hkv, axis=2) for t in (k, v))
-    heads_first = lambda t: t.transpose(0, 2, 1, 3)
-    ctx = flash_attention(heads_first(q), heads_first(k), heads_first(v), causal=True,
-                          scale=hd ** -0.5, impl=cfg.attention_impl)
-    return heads_first(ctx).reshape(B, S, H * hd) @ p["w_o"].astype(dt)
+    return _layers.qk_norm_attention(
+        u, p, table, heads=cfg.num_attention_heads, kv_heads=cfg.num_key_value_heads,
+        head_dim=cfg.head_dim, eps=cfg.norm_eps, impl=cfg.attention_impl)
 
 
-@_annotate("dense_ffn")
-def dense_ffn(h, p):
-    from beforeholiday_tpu.moe.dropless import swiglu
-
-    return swiglu(h, p["w_gate"], p["w_up"], p["w_down"])
+dense_ffn = _annotate("dense_ffn")(_layers.swiglu_ffn)
 
 
 def sparse_ffn(cfg: Lfm2MoeConfig, h, p):
     """``(y, counters)`` of one mixture-of-experts part (``moe.dropless``'s spans)."""
-    from beforeholiday_tpu.moe.dropless import dropless_moe, route_sigmoid
-
-    B, S, D = h.shape
-    y, counters = dropless_moe(
-        h.reshape(B * S, D), p, top_k=cfg.num_experts_per_tok, first_expert=cfg.first_expert,
+    return _layers.sigmoid_moe(
+        h, p, top_k=cfg.num_experts_per_tok, first_expert=cfg.first_expert,
         rows_bound=cfg.moe_rows_bound, renormalize=cfg.norm_topk_prob,
-        route=functools.partial(route_sigmoid, bias=p.get("expert_bias"),
-                                scale=cfg.routed_scaling_factor, eps=_ROUTER_EPS))
-    return y.reshape(B, S, D), counters
+        bias=p.get("expert_bias"), scale=cfg.routed_scaling_factor, eps=_ROUTER_EPS)
 
 
 def _layer(cfg: Lfm2MoeConfig, mixer: str, ffn: str, x, p, table):
@@ -296,18 +263,12 @@ def forward(params: dict, tokens: jax.Array, cfg: Lfm2MoeConfig):
     layer = {kinds: _remat_apply(functools.partial(_layer, cfg, *kinds), cfg.remat_policy)
              for kinds in sorted(set(held))}
     with _span("lfm2_layers"):
-        seen = []
-        for kinds, p in zip(held, params["layers"], strict=True):
-            x, c = layer[kinds](x, p, table)
-            if c is not None:
-                seen.append(c)
-    counters = (_layers.reduce_counters(jax.tree.map(lambda *v: jnp.stack(v), *seen)) if seen
-                else {k: jnp.zeros((), _F32) for k in COUNTERS})
+        x, seen = _layers.unrolled_layers(layer, held, params["layers"], x, table)
+    counters = _layers.step_counters(seen)
     with _span("lfm2_head"):
         x = rms_norm(x, params["embedding_norm"], cfg.norm_eps)
-        head = params["embed" if cfg.tie_word_embeddings else "head"]
-        logits = jax.lax.dot_general(
-            x, head.astype(x.dtype), (((2,), (1,)), ((), ())), preferred_element_type=_F32)
+        logits = _layers.logits_of(
+            x, params["embed" if cfg.tie_word_embeddings else "head"])
     return logits, counters
 
 
@@ -319,13 +280,9 @@ def loss_fn(params: dict, tokens: jax.Array, targets: jax.Array,
     """``(mean next-token cross entropy over the vocabulary held, counters)``.
     ``forward_fn(params, tokens)`` overrides the plain forward (an amp-wrapped
     apply), as in ``testing/gpt.loss_fn``."""
-    if forward_fn is None:
-        logits, counters = forward(params, tokens, cfg)
-    else:
-        logits, counters = forward_fn(params, tokens)
-    return cross_entropy(logits, targets), counters
+    return _layers.loss_fn(forward_fn or functools.partial(forward, cfg=cfg), cross_entropy,
+                           params, tokens, targets)
 
 
 def param_count(cfg: Lfm2MoeConfig) -> int:
-    return sum(math.prod(shape) for shape, _ in
-               jax.tree.leaves(param_shapes(cfg), is_leaf=_is_leaf_shape))
+    return _layers.param_count(param_shapes(cfg))

@@ -26,7 +26,7 @@ Both kinds of layer have the same parameters, stacked under ``layers``
 ``(L, ...)`` (the held experts ``(L, E_held, ...)``); the layer stack is a
 ``lax.scan`` over periods of ``layer_types`` whose body unrolls one period.
 GQA goes through ``ops.flash_attention`` by repeating each KV head over its
-query heads, as in ``models.qwen3_next``.
+query heads (``models.layers.grouped_query_attention``).
 
 Not here: a multi-token-prediction head and an auxiliary balancing loss (the
 published ``config.json`` has a key for neither).
@@ -35,7 +35,7 @@ published ``config.json`` has a key for neither).
 from __future__ import annotations
 
 import dataclasses
-import math
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -142,32 +142,23 @@ def init(key: jax.Array, cfg: MellumConfig) -> dict:
     layer. A trained model's residual stream is token-specific and its routing
     balanced; unit-scale embeddings give a random one the same property (rows
     within 0.99 to 1.07 of expected, fullest expert 1.1 to 2.6 times the mean)."""
-    shapes = param_shapes(cfg)
-    out, i = {}, 0
-    for group in sorted(shapes):
-        dst = out if group == "top" else out.setdefault(group, {})
-        for name in sorted(shapes[group]):
-            shape, kind = shapes[group][name]
-            k = jax.random.fold_in(key, i)
-            i += 1
-            if kind == "one":
-                dst[name] = jnp.ones(shape, _F32)
-            else:
-                std = cfg.embedding_init_std if kind == "embed" else cfg.initializer_range
-                dst[name] = jax.random.normal(k, shape, _F32) * std
-    return out
+    def draw(k, shape, kind):
+        if kind == "one":
+            return jnp.ones(shape, _F32)
+        std = cfg.embedding_init_std if kind == "embed" else cfg.initializer_range
+        return jax.random.normal(k, shape, _F32) * std
+
+    tree = _layers.draw_params(key, param_shapes(cfg), draw)
+    top = tree.pop("top")              # its leaves sit beside the groups
+    return {**tree, **top}
 
 
 def keep_fp32(path) -> bool:
     """``amp.initialize(keep_fp32_mask=...)``: the norm weights."""
-    return any("norm" in str(getattr(p, "key", getattr(p, "name", p))).lower()
-               for p in path)
+    return _layers.keep_fp32(path)
 
 
-def rms_norm(x, w, eps):
-    from beforeholiday_tpu.ops import fused_rms_norm
-
-    return fused_rms_norm(x, w.astype(_F32), eps=eps)
+rms_norm = _layers.rms_norm
 
 
 def rotary_tables(cfg: MellumConfig, seq_len: int) -> dict:
@@ -181,25 +172,11 @@ def rotary_tables(cfg: MellumConfig, seq_len: int) -> dict:
 
 def attention(cfg: MellumConfig, x, p, kind: str, table):
     """One attention mixer of ``kind``; ``table``: the kind's ``(cos, sin)``."""
-    from beforeholiday_tpu.ops import flash_attention
-
     with _span("window_mixer" if kind == SLIDING else "full_mixer"):
-        B, S, _ = x.shape
-        H, Hkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-        dt = x.dtype
-        q = (x @ p["w_q"].astype(dt)).reshape(B, S, H, hd)
-        k = (x @ p["w_k"].astype(dt)).reshape(B, S, Hkv, hd)
-        v = (x @ p["w_v"].astype(dt)).reshape(B, S, Hkv, hd)
-        q = _layers.apply_rotary(rms_norm(q, p["q_norm"], cfg.rms_norm_eps), *table)
-        k = _layers.apply_rotary(rms_norm(k, p["k_norm"], cfg.rms_norm_eps), *table)
-        if H != Hkv:                       # GQA by repetition (module docstring)
-            k, v = (jnp.repeat(t, H // Hkv, axis=2) for t in (k, v))
-        heads_first = lambda t: t.transpose(0, 2, 1, 3)
-        ctx = flash_attention(
-            heads_first(q), heads_first(k), heads_first(v), causal=True,
-            scale=hd ** -0.5, window=cfg.sliding_window if kind == SLIDING else None,
-            impl=cfg.attention_impl)
-        return heads_first(ctx).reshape(B, S, H * hd) @ p["w_o"].astype(dt)
+        return _layers.qk_norm_attention(
+            x, p, table, heads=cfg.num_attention_heads, kv_heads=cfg.num_key_value_heads,
+            head_dim=cfg.head_dim, eps=cfg.rms_norm_eps,
+            window=cfg.sliding_window if kind == SLIDING else None, impl=cfg.attention_impl)
 
 
 def _layer(cfg: MellumConfig, x, lp, kind, table):
@@ -240,9 +217,7 @@ def forward(params: dict, tokens: jax.Array, cfg: MellumConfig):
     counters = _layers.reduce_counters(seen)
     with _span("mellum_head"):
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-        logits = jax.lax.dot_general(
-            x, params["head"].astype(x.dtype), (((2,), (1,)), ((), ())),
-            preferred_element_type=_F32)
+        logits = _layers.logits_of(x, params["head"])
     return logits, counters
 
 
@@ -254,13 +229,9 @@ def loss_fn(params: dict, tokens: jax.Array, targets: jax.Array,
     """``(mean next-token cross entropy over the vocabulary held, counters)``.
     ``forward_fn(params, tokens)`` overrides the plain forward (an amp-wrapped
     apply), as in ``testing/gpt.loss_fn``."""
-    if forward_fn is None:
-        logits, counters = forward(params, tokens, cfg)
-    else:
-        logits, counters = forward_fn(params, tokens)
-    return cross_entropy(logits, targets), counters
+    return _layers.loss_fn(forward_fn or functools.partial(forward, cfg=cfg), cross_entropy,
+                           params, tokens, targets)
 
 
 def param_count(cfg: MellumConfig) -> int:
-    return sum(math.prod(shape) for group in param_shapes(cfg).values()
-               for shape, _ in group.values())
+    return _layers.param_count(param_shapes(cfg))

@@ -188,46 +188,37 @@ def init(key: jax.Array, cfg: NemotronHConfig) -> dict:
     inverse softplus of a step log-uniform in ``time_step_min .. max``, floored at
     ``time_step_floor``; ``D`` and the norm weights one; the convolution and its
     bias uniform in +-1/sqrt(kernel) (torch's Conv1d default)."""
-    shapes = param_shapes(cfg)
     depth = cfg.rescale_layers or len(cfg.hybrid_override_pattern)
-    out, i = {}, 0
-    for group in sorted(shapes):
-        dst = out if group == "top" else out.setdefault(group, {})
-        for name in sorted(shapes[group]):
-            shape, kind = shapes[group][name]
-            k = jax.random.fold_in(key, i)
-            i += 1
-            if kind == "one":
-                w = jnp.ones(shape, _F32)
-            elif kind == "conv":
-                bound = 1.0 / math.sqrt(cfg.conv_kernel)
-                w = jax.random.uniform(k, shape, _F32, -bound, bound)
-            elif kind == "a_log":
-                w = jnp.log(jax.random.uniform(k, shape, _F32, 1.0, 16.0))
-            elif kind == "dt_bias":
-                lo, hi = math.log(cfg.time_step_min), math.log(cfg.time_step_max)
-                step = jnp.maximum(jnp.exp(jax.random.uniform(k, shape, _F32, lo, hi)),
-                                   cfg.time_step_floor)
-                w = step + jnp.log(-jnp.expm1(-step))
-            else:
-                std = cfg.initializer_range / (math.sqrt(depth) if kind == "out" else 1.0)
-                w = jax.random.normal(k, shape, _F32) * std
-            dst[name] = w
-    return out
+
+    def draw(k, shape, kind):
+        if kind == "one":
+            return jnp.ones(shape, _F32)
+        if kind == "conv":
+            bound = 1.0 / math.sqrt(cfg.conv_kernel)
+            return jax.random.uniform(k, shape, _F32, -bound, bound)
+        if kind == "a_log":
+            return jnp.log(jax.random.uniform(k, shape, _F32, 1.0, 16.0))
+        if kind == "dt_bias":
+            lo, hi = math.log(cfg.time_step_min), math.log(cfg.time_step_max)
+            step = jnp.maximum(jnp.exp(jax.random.uniform(k, shape, _F32, lo, hi)),
+                               cfg.time_step_floor)
+            return step + jnp.log(-jnp.expm1(-step))
+        std = cfg.initializer_range / (math.sqrt(depth) if kind == "out" else 1.0)
+        return jax.random.normal(k, shape, _F32) * std
+
+    tree = _layers.draw_params(key, param_shapes(cfg), draw)
+    top = tree.pop("top")              # its leaves sit beside the groups
+    return {**tree, **top}
 
 
 def keep_fp32(path) -> bool:
     """``amp.initialize(keep_fp32_mask=...)``: the norm weights and the three
     per-head scalars of the recurrence (``A_log`` enters through two
     exponentials)."""
-    names = [str(getattr(p, "key", getattr(p, "name", p))).lower() for p in path]
-    return any("norm" in n or n in ("a_log", "dt_bias", "d") for n in names)
+    return _layers.keep_fp32(path, also=("a_log", "dt_bias", "d"))
 
 
-def rms_norm(x, w, eps):
-    from beforeholiday_tpu.ops import fused_rms_norm
-
-    return fused_rms_norm(x, w.astype(_F32), eps=eps)
+rms_norm = _layers.rms_norm
 
 
 @_annotate("ssm_mixer")
@@ -259,33 +250,23 @@ def mamba2_mixer(cfg: NemotronHConfig, u, p):
 
 @_annotate("attn_mixer")
 def attention(cfg: NemotronHConfig, u, p):
-    from beforeholiday_tpu.ops import flash_attention
-
     B, S, _ = u.shape
     H, Hkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     dt_ = u.dtype
     q = (u @ p["w_q"].astype(dt_)).reshape(B, S, H, hd)
     k = (u @ p["w_k"].astype(dt_)).reshape(B, S, Hkv, hd)
     v = (u @ p["w_v"].astype(dt_)).reshape(B, S, Hkv, hd)
-    if H != Hkv:                       # GQA by repetition, as in ``models.mellum``
-        k, v = (jnp.repeat(t, H // Hkv, axis=2) for t in (k, v))
-    heads_first = lambda t: t.transpose(0, 2, 1, 3)
-    ctx = flash_attention(heads_first(q), heads_first(k), heads_first(v), causal=True,
-                          scale=hd ** -0.5, impl=cfg.attention_impl)
-    return heads_first(ctx).reshape(B, S, H * hd) @ p["w_o"].astype(dt_)
+    ctx = _layers.grouped_query_attention(q, k, v, impl=cfg.attention_impl)
+    return ctx.reshape(B, S, H * hd) @ p["w_o"].astype(dt_)
 
 
 def latent_moe(cfg: NemotronHConfig, u, p):
     """``(y, counters)`` of one LatentMoE part (``moe.dropless``'s spans)."""
-    from beforeholiday_tpu.moe.dropless import dropless_moe, route_sigmoid
-
-    B, S, D = u.shape
     # the selection bias is a constant of zeros here (module docstring): none is passed
-    y, counters = dropless_moe(
-        u.reshape(B * S, D), p, top_k=cfg.num_experts_per_tok, first_expert=cfg.first_expert,
+    return _layers.sigmoid_moe(
+        u, p, top_k=cfg.num_experts_per_tok, first_expert=cfg.first_expert,
         rows_bound=cfg.moe_rows_bound, renormalize=cfg.norm_topk_prob,
-        route=functools.partial(route_sigmoid, scale=cfg.routed_scaling_factor))
-    return y.reshape(B, S, D), counters
+        scale=cfg.routed_scaling_factor)
 
 
 def _block(cfg: NemotronHConfig, x, p, kind: str):
@@ -324,13 +305,10 @@ def forward(params: dict, tokens: jax.Array, cfg: NemotronHConfig):
     with _span("nemotron_h_layers"):
         x, seen = _layers.scan_periods(period, x, {
             g: _layers.by_period(params[g], periods, n) for g, n in per.items()})
-    counters = (_layers.reduce_counters(seen) if seen is not None
-                else {k: jnp.zeros((), _F32) for k in COUNTERS})
+    counters = _layers.reduce_counters(seen) if seen is not None else _layers.step_counters([])
     with _span("nemotron_h_head"):
         x = rms_norm(x, params["final_norm"], cfg.layer_norm_epsilon)
-        logits = jax.lax.dot_general(
-            x, params["head"].astype(x.dtype), (((2,), (1,)), ((), ())),
-            preferred_element_type=_F32)
+        logits = _layers.logits_of(x, params["head"])
     return logits, counters
 
 
@@ -342,13 +320,9 @@ def loss_fn(params: dict, tokens: jax.Array, targets: jax.Array,
     """``(mean next-token cross entropy over the vocabulary held, counters)``.
     ``forward_fn(params, tokens)`` overrides the plain forward (an amp-wrapped
     apply), as in ``testing/gpt.loss_fn``."""
-    if forward_fn is None:
-        logits, counters = forward(params, tokens, cfg)
-    else:
-        logits, counters = forward_fn(params, tokens)
-    return cross_entropy(logits, targets), counters
+    return _layers.loss_fn(forward_fn or functools.partial(forward, cfg=cfg), cross_entropy,
+                           params, tokens, targets)
 
 
 def param_count(cfg: NemotronHConfig) -> int:
-    return sum(math.prod(shape) for group in param_shapes(cfg).values()
-               for shape, _ in group.values())
+    return _layers.param_count(param_shapes(cfg))

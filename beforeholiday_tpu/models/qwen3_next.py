@@ -38,6 +38,7 @@ Not here: the multi-token-prediction module and an auxiliary balancing loss
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Optional
 
@@ -152,32 +153,25 @@ def init(key: jax.Array, cfg: Qwen3NextConfig) -> dict:
     uniform in +-1/sqrt(width) (torch's Conv1d default); ``A_log = log U(0, 16)``
     and ``dt_bias = 1`` (the published modelling code); norm weights at their
     identity."""
-    shapes = param_shapes(cfg)
-    out, i = {}, 0
-    for group in sorted(shapes):
-        dst = out if group == "top" else out.setdefault(group, {})
-        for name in sorted(shapes[group]):
-            shape, kind = shapes[group][name]
-            k = jax.random.fold_in(key, i)
-            i += 1
-            if kind == "std":
-                w = jax.random.normal(k, shape, _F32) * 0.02
-            elif kind == "conv":
-                bound = 1.0 / math.sqrt(shape[-1])
-                w = jax.random.uniform(k, shape, _F32, -bound, bound)
-            elif kind == "a_log":
-                w = jnp.log(jax.random.uniform(k, shape, _F32, 1e-3, 16.0))
-            else:
-                w = jnp.full(shape, 0.0 if kind == "zero" else 1.0, _F32)
-            dst[name] = w
-    return out
+    def draw(k, shape, kind):
+        if kind == "std":
+            return jax.random.normal(k, shape, _F32) * 0.02
+        if kind == "conv":
+            bound = 1.0 / math.sqrt(shape[-1])
+            return jax.random.uniform(k, shape, _F32, -bound, bound)
+        if kind == "a_log":
+            return jnp.log(jax.random.uniform(k, shape, _F32, 1e-3, 16.0))
+        return jnp.full(shape, 0.0 if kind == "zero" else 1.0, _F32)
+
+    tree = _layers.draw_params(key, param_shapes(cfg), draw)
+    top = tree.pop("top")              # its leaves sit beside the groups
+    return {**tree, **top}
 
 
 def keep_fp32(path) -> bool:
     """``amp.initialize(keep_fp32_mask=...)``: the norm weights, and the two
     per-head scalars of the decay (``A_log`` enters through two exponentials)."""
-    names = [str(getattr(p, "key", getattr(p, "name", p))).lower() for p in path]
-    return any("norm" in n or n in ("a_log", "dt_bias") for n in names)
+    return _layers.keep_fp32(path, also=("a_log", "dt_bias"))
 
 
 # ---------------------------------------------------------------------------------
@@ -231,8 +225,6 @@ def gated_delta_net(cfg: Qwen3NextConfig, x, p):
 
 @_annotate("attn_mixer")
 def gated_attention(cfg: Qwen3NextConfig, x, p):
-    from beforeholiday_tpu.ops import flash_attention
-
     B, S, _ = x.shape
     H, Hkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     dt = x.dtype
@@ -244,13 +236,8 @@ def gated_attention(cfg: Qwen3NextConfig, x, p):
     k = rms_norm0(k, p["k_norm"], cfg.rms_norm_eps)
     q = rope_partial(q, cfg.rotary_dim, cfg.rope_theta)
     k = rope_partial(k, cfg.rotary_dim, cfg.rope_theta)
-    if H != Hkv:                       # GQA by repetition (module docstring)
-        k, v = (jnp.repeat(t, H // Hkv, axis=2) for t in (k, v))
-    heads_first = lambda t: t.transpose(0, 2, 1, 3)
-    ctx = flash_attention(
-        heads_first(q), heads_first(k), heads_first(v), causal=True,
-        scale=hd ** -0.5, impl=cfg.attention_impl)
-    ctx = heads_first(ctx) * jax.nn.sigmoid(gate.astype(_F32)).astype(dt)
+    ctx = _layers.grouped_query_attention(q, k, v, impl=cfg.attention_impl)
+    ctx = ctx * jax.nn.sigmoid(gate.astype(_F32)).astype(dt)       # a gate a head's dim
     return ctx.reshape(B, S, H * hd) @ p["w_o"].astype(dt)
 
 
@@ -301,9 +288,7 @@ def forward(params: dict, tokens: jax.Array, cfg: Qwen3NextConfig):
     counters = _layers.reduce_counters(seen)
     with _span("qwen3n_head"):
         x = rms_norm0(x, params["final_norm"], cfg.rms_norm_eps)
-        logits = jax.lax.dot_general(
-            x, params["head"].astype(x.dtype), (((2,), (1,)), ((), ())),
-            preferred_element_type=_F32)
+        logits = _layers.logits_of(x, params["head"])
     return logits, counters
 
 
@@ -315,13 +300,9 @@ def loss_fn(params: dict, tokens: jax.Array, targets: jax.Array,
     """``(mean next-token cross entropy over the vocabulary held, counters)``.
     ``forward_fn(params, tokens)`` overrides the plain forward (an amp-wrapped
     apply), as in ``testing/gpt.loss_fn``."""
-    if forward_fn is None:
-        logits, counters = forward(params, tokens, cfg)
-    else:
-        logits, counters = forward_fn(params, tokens)
-    return cross_entropy(logits, targets), counters
+    return _layers.loss_fn(forward_fn or functools.partial(forward, cfg=cfg), cross_entropy,
+                           params, tokens, targets)
 
 
 def param_count(cfg: Qwen3NextConfig) -> int:
-    return sum(math.prod(shape) for group in param_shapes(cfg).values()
-               for shape, _ in group.values())
+    return _layers.param_count(param_shapes(cfg))

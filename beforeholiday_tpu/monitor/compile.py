@@ -215,8 +215,8 @@ def reset_compile_counts(entry: Optional[str] = None) -> None:
 
     With ``entry``, the reset is SCOPED: only that entry's signature set,
     call counter, and armed warning are cleared, every other entry keeps
-    counting. The autotuner resets its own ``tune.trial<N>`` scope between
-    trials this way — a global reset would silently zero the training
+    counting. A caller that re-traces one entry on purpose resets its own
+    scope this way — a global reset would silently zero the training
     step's recompile evidence and disarm warnings the user still wants."""
     with _LOCK:
         if entry is not None:

@@ -7,6 +7,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from beforeholiday_tpu.guard.dispatch import count_forced as _count_forced
+
 
 def interpret_default() -> bool:
     """Pallas compiles natively on TPU; elsewhere the interpreter runs."""
@@ -80,6 +82,24 @@ def resolve_impl(impl: Optional[str]) -> str:
     if impl not in ("pallas", "jnp"):
         raise ValueError(f"impl must be 'pallas' or 'jnp', got {impl!r}")
     return impl
+
+
+def dispatch(op: str, impl: Optional[str], available: bool, why: str, *arrays, statics):
+    """``(impl, forced)`` by the one policy of the kernels with a shape of their
+    own (``deltanet``, ``short_conv``, ``gated_delta``, ``ssd``,
+    ``grouped_matmul``): :func:`resolve_impl`, then ``jnp`` where the call is off
+    the kernels' shapes (``available`` false) — booked once under ``op`` with
+    ``arrays`` and ``statics`` as ``guard.dispatch`` keys a probe — unless
+    ``pallas`` was asked for by name, which raises with the op's own ``why``."""
+    forced = impl is not None
+    impl = resolve_impl(impl)
+    if impl == "pallas" and not available:
+        if forced:
+            raise ValueError(f"impl='pallas' forced but {why}; pass impl=None for the "
+                             "automatic fallback")
+        impl = "jnp"
+        _count_forced(op, impl, *arrays, statics=statics)
+    return impl, forced
 
 
 def resolve_impl_streaming(impl: Optional[str]) -> str:

@@ -48,14 +48,11 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from beforeholiday_tpu.guard.dispatch import (
-    checked_impl as _checked_impl,
-    count_forced as _count_forced,
-)
+from beforeholiday_tpu.guard.dispatch import checked_impl as _checked_impl
 from beforeholiday_tpu.monitor.spans import span as _span
 from beforeholiday_tpu.ops._pallas_util import (
+    dispatch as _dispatch,
     interpret_default as _interpret_default,
-    resolve_impl as _resolve_impl,
 )
 
 __all__ = ["by_key_head", "deltanet_gate", "deltanet_qkv", "is_kernel_available"]
@@ -434,21 +431,6 @@ def _probe_gate(o, z, w, group, tile, eps):
 # ---------------------------------------------------------------------------------
 # public API
 # ---------------------------------------------------------------------------------
-
-
-def _dispatch(op, impl, available, why, *arrays, statics):
-    """``(impl, forced)`` by the one policy of every kernel here: ``pallas``
-    where the traced program owns its device, ``jnp`` (counted) off the kernels'
-    shapes unless ``pallas`` was asked for by name."""
-    forced = impl is not None
-    impl = _resolve_impl(impl)
-    if impl == "pallas" and not available:
-        if forced:
-            raise ValueError(f"impl='pallas' forced but {why}; pass impl=None for the "
-                             "automatic fallback")
-        impl = "jnp"
-        _count_forced(op, impl, *arrays, statics=statics)
-    return impl, forced
 
 
 def deltanet_qkv(cols: jax.Array, filt: jax.Array, *, key_heads: int, value_heads: int,

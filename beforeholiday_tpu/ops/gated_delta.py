@@ -53,14 +53,11 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from beforeholiday_tpu.guard.dispatch import (
-    checked_impl as _checked_impl,
-    count_forced as _count_forced,
-)
+from beforeholiday_tpu.guard.dispatch import checked_impl as _checked_impl
 from beforeholiday_tpu.monitor.spans import span as _span
 from beforeholiday_tpu.ops._pallas_util import (
+    dispatch as _dispatch,
     interpret_default as _interpret_default,
-    resolve_impl as _resolve_impl,
 )
 
 __all__ = ["gated_delta_rule", "is_kernel_available", "unit_lower_inverse", "wy_prepare"]
@@ -624,16 +621,10 @@ def gated_delta_rule(
             f"v {v.shape} g {g.shape} beta {beta.shape}")
     if chunk < 1 or chunk & (chunk - 1):      # the blockwise inverse halves its way down
         raise ValueError(f"chunk must be a power of two, got {chunk}")
-    forced = impl is not None
-    impl = _resolve_impl(impl)
-    if impl == "pallas" and not is_kernel_available(chunk, dk, dv):
-        if forced:
-            raise ValueError(
-                f"impl='pallas' forced but chunk {chunk} is not a multiple of 64 or "
-                f"d_k {dk} / d_v {dv} not of {_LANES}; pass impl=None for the "
-                "automatic fallback")
-        impl = "jnp"
-        _count_forced("gated_delta_rule", impl, q, k, v, statics=(chunk,))
+    impl, forced = _dispatch(
+        "gated_delta_rule", impl, is_kernel_available(chunk, dk, dv),
+        f"chunk {chunk} is not a multiple of 64 or d_k {dk} / d_v {dv} not of {_LANES}",
+        q, k, v, statics=(chunk,))
     pad = -S % chunk
     N = (S + pad) // chunk
 

@@ -44,12 +44,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from beforeholiday_tpu.guard.dispatch import (
     checked_impl as _checked_impl,
-    count_forced as _count_forced,
     count_tiles as _count_tiles,
 )
 from beforeholiday_tpu.ops._pallas_util import (
+    dispatch as _dispatch,
     interpret_default as _interpret_default,
-    resolve_impl as _resolve_impl,
 )
 
 __all__ = ["Plan", "grouped_matmul", "is_kernel_available", "plan"]
@@ -367,17 +366,12 @@ def grouped_matmul(
     out_dtype = jnp.dtype(lhs.dtype if preferred_element_type is None
                           else preferred_element_type)
     R, (E, K, N) = lhs.shape[0], rhs.shape
-    forced = impl is not None
-    impl = _resolve_impl(impl)
-    if impl == "pallas" and not is_kernel_available(R, E, K, N, lhs.dtype, rhs.dtype, out_dtype):
-        if forced:
-            raise ValueError(
-                f"impl='pallas' forced but lhs {lhs.shape} {lhs.dtype} x rhs {rhs.shape} "
-                f"{rhs.dtype} is off the kernels' shapes (K and N multiples of {_LANES}, "
-                "both operands bfloat16 or both float32); pass impl=None for the "
-                "automatic fallback")
-        impl = "jnp"
-        _count_forced("grouped_matmul", impl, lhs, rhs, statics=(str(out_dtype),))
+    impl, forced = _dispatch(
+        "grouped_matmul", impl,
+        is_kernel_available(R, E, K, N, lhs.dtype, rhs.dtype, out_dtype),
+        f"lhs {lhs.shape} {lhs.dtype} x rhs {rhs.shape} {rhs.dtype} is off the kernels' "
+        f"shapes (K and N multiples of {_LANES}, both operands bfloat16 or both float32)",
+        lhs, rhs, statics=(str(out_dtype),))
     if impl == "pallas" and not forced:
         impl = _checked_impl("grouped_matmul", impl, _probe, lhs, rhs, group_sizes, out_dtype)
     if impl == "pallas":

@@ -43,8 +43,10 @@ from jax.experimental.pallas import tpu as pltpu
 from beforeholiday_tpu.guard.dispatch import checked_impl as _checked_impl
 from beforeholiday_tpu.monitor.counters import book_tiles as _book_tiles
 from beforeholiday_tpu.monitor.spans import span as _span
-from beforeholiday_tpu.ops._pallas_util import interpret_default as _interpret_default
-from beforeholiday_tpu.ops.deltanet import _dispatch
+from beforeholiday_tpu.ops._pallas_util import (
+    dispatch as _dispatch,
+    interpret_default as _interpret_default,
+)
 
 __all__ = ["gated_short_conv", "is_kernel_available"]
 

@@ -55,13 +55,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 from beforeholiday_tpu.guard.dispatch import (
     checked_impl as _checked_impl,
-    count_forced as _count_forced,
     count_tiles as _count_tiles,
 )
 from beforeholiday_tpu.monitor.spans import span as _span
 from beforeholiday_tpu.ops._pallas_util import (
+    dispatch as _dispatch,
     interpret_default as _interpret_default,
-    resolve_impl as _resolve_impl,
 )
 from beforeholiday_tpu.ops.gated_delta import _NN, _NT, _TN, _dot
 
@@ -466,16 +465,10 @@ def ssd(
     if chunk < 1:
         raise ValueError(f"chunk must be positive, got {chunk}")
     d = _Dims(G, H // G, P, N, chunk)
-    forced = impl is not None
-    impl = _resolve_impl(impl)
-    if impl == "pallas" and not is_kernel_available(chunk, P, N, d.Hg):
-        if forced:
-            raise ValueError(
-                f"impl='pallas' forced but chunk {chunk} / state {N} is not a multiple of "
-                f"{_LANES}, or {d.Hg} heads a group of {P} do not fill {_LANES}-lane units; "
-                "pass impl=None for the automatic fallback")
-        impl = "jnp"
-        _count_forced("ssd", impl, x, B, statics=(chunk,))
+    impl, forced = _dispatch(
+        "ssd", impl, is_kernel_available(chunk, P, N, d.Hg),
+        f"chunk {chunk} / state {N} is not a multiple of {_LANES}, or {d.Hg} heads a group "
+        f"of {P} do not fill {_LANES}-lane units", x, B, statics=(chunk,))
     pad = -S % chunk
 
     def padded(t):

@@ -45,7 +45,6 @@ from beforeholiday_tpu.parallel.parallel_state import (
     DATA_AXIS,
     hierarchical_axes,
 )
-from beforeholiday_tpu.tune import UNSET, resolve_trainer_knobs
 
 
 def _shard_len(total_padded: int, world: int) -> int:
@@ -72,43 +71,14 @@ class _DistributedFused:
         *,
         axis_name: Any = DATA_AXIS,
         grad_average: bool = True,
-        bucket_bytes: Any = UNSET,
-        compress: Any = UNSET,
+        bucket_bytes: Optional[int] = None,
+        compress: bool = False,
         wire_dtype: Any = jnp.bfloat16,
-        overlap_backward: Any = UNSET,
-        hierarchical: Any = UNSET,
+        overlap_backward: bool = False,
+        hierarchical: bool = False,
         compress_intra: Optional[bool] = None,
         compress_dcn: Optional[bool] = None,
-        tuned: bool = False,
-        tuning_key: Any = None,
-        tuning_manifest: Any = None,
     ):
-        # UNSET-defaulted knobs resolve through the autotuning manifest when
-        # tuned=True; explicit kwargs always win, a miss warns once and keeps
-        # the shipped defaults (see beforeholiday_tpu.tune).
-        knobs = resolve_trainer_knobs(
-            self._site_prefix,
-            {
-                "bucket_bytes": None,
-                "compress": False,
-                "overlap_backward": False,
-                "hierarchical": False,
-            },
-            {
-                "bucket_bytes": bucket_bytes,
-                "compress": compress,
-                "overlap_backward": overlap_backward,
-                "hierarchical": hierarchical,
-            },
-            tuned=tuned,
-            tuning_key=tuning_key,
-            manifest=tuning_manifest,
-            context={"two_level": hierarchical_axes(axis_name) is not None},
-        )
-        bucket_bytes = knobs["bucket_bytes"]
-        compress = knobs["compress"]
-        overlap_backward = knobs["overlap_backward"]
-        hierarchical = knobs["hierarchical"]
         if hierarchical and hierarchical_axes(axis_name) is None:
             raise ValueError(
                 "hierarchical=True needs a (slice, intra) axis spec; got "
@@ -320,17 +290,14 @@ class DistributedFusedAdam(_DistributedFused):
         bias_correction: bool = True,
         axis_name: Any = DATA_AXIS,
         grad_average: bool = True,
-        bucket_bytes: Any = UNSET,
-        compress: Any = UNSET,
+        bucket_bytes: Optional[int] = None,
+        compress: bool = False,
         wire_dtype: Any = jnp.bfloat16,
-        overlap_backward: Any = UNSET,
-        hierarchical: Any = UNSET,
+        overlap_backward: bool = False,
+        hierarchical: bool = False,
         compress_intra: Optional[bool] = None,
         compress_dcn: Optional[bool] = None,
         impl: Optional[str] = None,
-        tuned: bool = False,
-        tuning_key: Any = None,
-        tuning_manifest: Any = None,
     ):
         super().__init__(
             axis_name=axis_name, grad_average=grad_average,
@@ -338,8 +305,6 @@ class DistributedFusedAdam(_DistributedFused):
             wire_dtype=wire_dtype, overlap_backward=overlap_backward,
             hierarchical=hierarchical, compress_intra=compress_intra,
             compress_dcn=compress_dcn,
-            tuned=tuned, tuning_key=tuning_key,
-            tuning_manifest=tuning_manifest,
         )
         self.lr, self.betas, self.eps = lr, betas, eps
         self.adam_w_mode = adam_w_mode

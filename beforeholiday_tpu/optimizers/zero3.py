@@ -79,7 +79,6 @@ from beforeholiday_tpu.parallel.parallel_state import (
     hierarchical_axes,
 )
 from beforeholiday_tpu.remat.policies import ZERO3_GATHERED_TAG
-from beforeholiday_tpu.tune import UNSET, resolve_trainer_knobs
 
 __all__ = [
     "ZeRO3FusedAdam",
@@ -330,53 +329,24 @@ class ZeRO3FusedAdam(DistributedFusedAdam):
         bias_correction: bool = True,
         axis_name: Any = DATA_AXIS,
         grad_average: bool = True,
-        bucket_bytes: Any = UNSET,
-        compress: Any = UNSET,
+        bucket_bytes: Optional[int] = bucketing.DEFAULT_BUCKET_BYTES,
+        compress: bool = False,
         wire_dtype: Any = jnp.bfloat16,
-        overlap_backward: Any = UNSET,
-        hierarchical: Any = UNSET,
+        overlap_backward: bool = False,
+        hierarchical: bool = False,
         compress_intra: Optional[bool] = None,
         compress_dcn: Optional[bool] = None,
         impl: Optional[str] = None,
-        prefetch: Any = UNSET,
+        prefetch: int = 1,
         param_residency: str = "regather",
-        tuned: bool = False,
-        tuning_key: Any = None,
-        tuning_manifest: Any = None,
     ):
-        # ZeRO-3 owns prefetch and a different bucket_bytes default, so it
-        # resolves its manifest knobs HERE and hands the base class concrete
-        # values (tuned=False below — resolution must not run twice).
-        knobs = resolve_trainer_knobs(
-            self._site_prefix,
-            {
-                "bucket_bytes": bucketing.DEFAULT_BUCKET_BYTES,
-                "compress": False,
-                "overlap_backward": False,
-                "hierarchical": False,
-                "prefetch": 1,
-            },
-            {
-                "bucket_bytes": bucket_bytes,
-                "compress": compress,
-                "overlap_backward": overlap_backward,
-                "hierarchical": hierarchical,
-                "prefetch": prefetch,
-            },
-            tuned=tuned,
-            tuning_key=tuning_key,
-            manifest=tuning_manifest,
-            context={"two_level": hierarchical_axes(axis_name) is not None},
-        )
-        prefetch = knobs["prefetch"]
         super().__init__(
             lr, betas, eps, adam_w_mode=adam_w_mode,
             weight_decay=weight_decay, bias_correction=bias_correction,
             axis_name=axis_name, grad_average=grad_average,
-            bucket_bytes=knobs["bucket_bytes"], compress=knobs["compress"],
-            wire_dtype=wire_dtype,
-            overlap_backward=knobs["overlap_backward"],
-            hierarchical=knobs["hierarchical"], compress_intra=compress_intra,
+            bucket_bytes=bucket_bytes, compress=compress,
+            wire_dtype=wire_dtype, overlap_backward=overlap_backward,
+            hierarchical=hierarchical, compress_intra=compress_intra,
             compress_dcn=compress_dcn, impl=impl,
         )
         if prefetch < 0:

@@ -32,7 +32,6 @@ from beforeholiday_tpu.parallel.parallel_state import (
     DATA_AXIS,
     hierarchical_axes,
 )
-from beforeholiday_tpu.tune import UNSET, resolve_trainer_knobs
 
 
 def _axis_size(axis_name: Any):
@@ -330,44 +329,14 @@ class DistributedDataParallel:
         gradient_average: bool = True,
         gradient_predivide_factor: Optional[float] = None,
         allreduce_always_fp32: bool = False,
-        bucket_bytes: Any = UNSET,
-        compress: Any = UNSET,
+        bucket_bytes: Optional[int] = None,
+        compress: bool = False,
         wire_dtype: Any = jnp.bfloat16,
-        overlap_backward: Any = UNSET,
-        hierarchical: Any = UNSET,
+        overlap_backward: bool = False,
+        hierarchical: bool = False,
         compress_intra: Optional[bool] = None,
         compress_dcn: Optional[bool] = None,
-        tuned: bool = False,
-        tuning_key: Any = None,
-        tuning_manifest: Any = None,
     ):
-        # UNSET-defaulted knobs resolve through the autotuning manifest when
-        # tuned=True; explicitly passed kwargs always win (beforeholiday_tpu
-        # .tune.resolve_trainer_knobs), and a manifest miss warns once and
-        # keeps the shipped defaults below.
-        knobs = resolve_trainer_knobs(
-            "ddp",
-            {
-                "bucket_bytes": None,
-                "compress": False,
-                "overlap_backward": False,
-                "hierarchical": False,
-            },
-            {
-                "bucket_bytes": bucket_bytes,
-                "compress": compress,
-                "overlap_backward": overlap_backward,
-                "hierarchical": hierarchical,
-            },
-            tuned=tuned,
-            tuning_key=tuning_key,
-            manifest=tuning_manifest,
-            context={"two_level": hierarchical_axes(axis_name) is not None},
-        )
-        bucket_bytes = knobs["bucket_bytes"]
-        compress = knobs["compress"]
-        overlap_backward = knobs["overlap_backward"]
-        hierarchical = knobs["hierarchical"]
         if hierarchical and hierarchical_axes(axis_name) is None:
             raise ValueError(
                 "hierarchical=True needs a (slice, intra) axis spec; got "

@@ -1,5 +1,6 @@
-"""Buffer donation for step functions (ref: the ``donate_argnums`` contract
-``transformer/tensor_parallel/memory.py`` documents).
+"""Buffer donation for step functions: the in-place reuse the reference gets
+from its preallocated ``MemoryBuffer`` views (apex/transformer/tensor_parallel/
+memory.py:25-146) is, under XLA, ``jax.jit(donate_argnums=...)``.
 
 On TPU the params + optimizer state of a training step are the largest live
 buffers; without donation XLA must hold BOTH the input and output copies

@@ -24,10 +24,6 @@ from beforeholiday_tpu.transformer.tensor_parallel.mappings import (  # noqa: F4
     scatter_to_sequence_parallel_region,
     scatter_to_tensor_model_parallel_region,
 )
-from beforeholiday_tpu.transformer.tensor_parallel.memory import (  # noqa: F401
-    MemoryBuffer,
-    RingMemBuffer,
-)
 from beforeholiday_tpu.transformer.tensor_parallel.random import (  # noqa: F401
     checkpoint,
     checkpoint_apply,

@@ -426,8 +426,8 @@ def test_the_forward_on_its_live_blocks_compiles_for_the_chip(
 
 def test_same_step_reads_two_texts_as_one_program_when_only_source_lines_moved(
         one_chip, flash_compiled, tmp_path):
-    """``tools/same_step.py`` on a compiled flash forward + backward: a frame
-    table with other line numbers is the same program, a kernel's body prints
+    """``tools/same_step.py`` on a compiled flash forward + backward: source
+    tables with other line numbers are the same program, a kernel's body prints
     without its locations, and a changed operand is not the same program."""
     import sys
 
@@ -443,8 +443,8 @@ def test_same_step_reads_two_texts_as_one_program_when_only_source_lines_moved(
         return o, pull(do)
 
     text = jax.jit(both).lower(x, x, x, seed, x).compile().as_text()
-    frames = [l for l in text.splitlines() if same_step._FRAME.match(l)]
-    assert frames and text.count('"body":"') == 2
+    assert all(f"\n{table}\n" in text for table in same_step._TABLES)
+    assert text.count('"body":"') == 2 and "stack_frame_id=" in text
     moved = re.sub(r"(function_name_id=\d+ line=)(\d+)", lambda m: m[1] + str(int(m[2]) + 7), text)
     other = text.replace("bf16[2,256,64]", "bf16[2,256,65]", 1)
     assert moved != text and other != text
@@ -454,3 +454,35 @@ def test_same_step_reads_two_texts_as_one_program_when_only_source_lines_moved(
     assert len(same_step.differing(tmp_path / "a", tmp_path / "c")) == 1
     kernel = same_step._kernel_text(same_step._BODY.search(text).group(1))
     assert "func.func" in kernel and "loc(" not in kernel
+
+
+def test_same_step_reads_a_moved_function_as_the_same_program_and_a_changed_op_as_another(
+        one_chip, tmp_path):
+    """A body moved into a shared function compiles to the parent's ops under one
+    more call frame: every source table grows a row and the ops' frame ids
+    shift. ``same_step`` reads the two as one program; with one op changed
+    (``sin`` for ``cos``) beside the same move, it does not."""
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
+    import same_step
+
+    x = jax.ShapeDtypeStruct((256, 128), jnp.float32, sharding=one_chip)
+
+    def shared(op, a):
+        return op(a) * 2.0
+
+    inline = lambda a: jnp.sum(jnp.cos(a) * 2.0)
+    called = lambda a: jnp.sum(shared(jnp.cos, a))
+    changed = lambda a: jnp.sum(shared(jnp.sin, a))
+    for name, fn in (("inline", inline), ("called", called), ("changed", changed)):
+        (tmp_path / name).write_text(jax.jit(fn).lower(x).compile().as_text().replace(
+            "jit__lambda_", "jit_fn"))
+    def table_rows(name):
+        text = (tmp_path / name).read_text()
+        return len(text.splitlines()) - len(same_step.program_lines(text))
+
+    assert table_rows("called") > table_rows("inline") > 0         # the added frame's rows
+    assert same_step.differing(tmp_path / "inline", tmp_path / "called") == []
+    assert same_step.differing(tmp_path / "inline", tmp_path / "changed")
+    assert same_step.differing(tmp_path / "called", tmp_path / "changed")

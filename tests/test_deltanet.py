@@ -229,31 +229,6 @@ def test_dispatch_is_guarded_and_counted(monkeypatch):
         assert counted[op]["pallas"] == 1 and counted[op]["jnp"] == 0, op
 
 
-def test_a_shape_the_gate_refuses_takes_the_chain_and_is_counted(monkeypatch):
-    """Head dims of 16 (the small model of ``tests/test_qwen3_next.py``) and a
-    sequence of 40 rows are not the kernels': on a TPU too the chain runs, and
-    ``guard.dispatch`` says so."""
-    dispatch.reset_dispatch_counters()
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(dn, "_interpret_default", lambda: True)
-    heads = dict(key_heads=2, value_heads=4, d_k=16, d_v=16)
-    ks = jax.random.split(jax.random.PRNGKey(2), 4)
-    cols = jax.random.normal(ks[0], (2, 40, 128))
-    filt = jax.random.normal(ks[1], (128, _K))
-    q, k, v = dn.deltanet_qkv(cols, filt, **heads)
-    assert q.shape == (2, 4, 40, 16)
-    z = jax.random.normal(ks[2], (2, 40, 64))
-    y = dn.deltanet_gate(v, z, jnp.ones((16,)), eps=1e-6)
-    assert y.shape == (2, 40, 64)
-    counted = _counted()
-    for op in ("deltanet_qkv", "deltanet_gate"):
-        assert counted[op]["jnp"] == 1 and counted[op]["pallas"] == 0, op
-    with pytest.raises(ValueError, match="forced"):
-        dn.deltanet_qkv(cols, filt, impl="pallas", **heads)
-    with pytest.raises(ValueError, match="forced"):
-        dn.deltanet_gate(v, z, jnp.ones((16,)), eps=1e-6, impl="pallas")
-
-
 def test_mismatched_shapes_are_refused():
     cols, filt, _ = _qkv_inputs(1, 48, 1, 2)
     with pytest.raises(ValueError, match="shapes mismatch"):

@@ -290,18 +290,6 @@ def test_the_wy_backward_kernel_recomputes_the_inverse():
     assert len(res) == 4 and all(r is o for r, o in zip(res, (q, k, v, rows)))
 
 
-def test_a_shape_the_gate_refuses_falls_to_jnp_and_is_counted(monkeypatch):
-    from beforeholiday_tpu.guard import dispatch
-
-    dispatch.reset_dispatch_counters()
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # resolve_impl -> pallas
-    monkeypatch.setattr(gd, "_interpret_default", lambda: True)
-    args, _ = inputs(6, 1, 128, 1, 64, 128)
-    _close(gd.gated_delta_rule(*args), recurrence(*args), "o, d_k=64")
-    counted = {k[0]: v for k, v in dispatch.dispatch_counters().items()}
-    assert counted["gated_delta_rule"]["jnp"] == 1 and counted["gated_delta_rule"]["pallas"] == 0
-
-
 def test_the_wy_kernels_lie_under_the_scope_and_are_not_named_after_it():
     """``gated_delta_ms`` reads the scope path, ``gated_delta_roofline`` the
     kernels named ``gated_delta*``: the scan's two, not these."""

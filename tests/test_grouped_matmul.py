@@ -204,15 +204,6 @@ def test_dispatch_is_guarded_and_counted(monkeypatch):
     assert float(jnp.max(jnp.abs(jnp.where(valid, got - want, 0.0)))) <= 1e-4
 
 
-def test_a_shape_the_gate_refuses_falls_to_ragged_dot_and_is_counted(monkeypatch):
-    _as_on_the_chip(monkeypatch)
-    lhs, rhs = jnp.ones((256, 96)), jnp.ones((2, 96, 24))
-    sizes = jnp.asarray((100, 56), jnp.int32)
-    got = gm.grouped_matmul(lhs, rhs, sizes)
-    assert _counted()["jnp"] == 1 and _counted()["pallas"] == 0
-    assert bool(jnp.array_equal(got[:156], jax.lax.ragged_dot(lhs, rhs, sizes)[:156]))
-
-
 def test_a_failed_probe_degrades_to_ragged_dot(monkeypatch):
     from beforeholiday_tpu.testing import faults
 

@@ -532,10 +532,9 @@ def test_the_deltanet_kernels_lie_under_the_mixer_and_outside_the_delta_rule(mon
     of the others, the forward ones under ``amp_forward`` and the backward ones
     under ``amp_backward``; the delta rule's own four stay where they were."""
     from beforeholiday_tpu.models import qwen3_next
-    from beforeholiday_tpu.ops import deltanet, gated_delta
+    from beforeholiday_tpu.ops import _pallas_util
 
-    monkeypatch.setattr(deltanet, "_resolve_impl", lambda impl: "pallas")
-    monkeypatch.setattr(gated_delta, "_resolve_impl", lambda impl: "pallas")
+    monkeypatch.setattr(_pallas_util, "resolve_impl", lambda impl: "pallas")
     cfg = qwen3_next.Qwen3NextConfig(
         hidden_size=64, linear_num_key_heads=1, linear_num_value_heads=2,
         linear_key_head_dim=128, linear_value_head_dim=128, gated_delta_chunk=64,

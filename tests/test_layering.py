@@ -32,7 +32,7 @@ _ROOT_SCRIPTS = frozenset({
 ALLOWED = {
     "__init__": {"amp", "fp16_utils", "guard", "monitor", "ops", "optimizers",
                  "parallel", "remat", "rnn", "transformer", "utils"},
-    "amp": {"monitor", "ops", "optimizers", "tune", "utils"},
+    "amp": {"monitor", "ops", "optimizers", "utils"},
     "contrib": {"ops", "optimizers", "parallel"},
     "elastic": {"guard", "monitor", "optimizers", "parallel", "utils"},
     "fp16_utils": {"amp", "contrib", "ops"},
@@ -53,8 +53,8 @@ ALLOWED = {
         "monitor",
         "remat",    # debt: ops <-> remat
     },
-    "optimizers": {"monitor", "ops", "parallel", "remat", "tune"},
-    "parallel": {"monitor", "ops", "tune"},
+    "optimizers": {"monitor", "ops", "parallel", "remat"},
+    "parallel": {"monitor", "ops"},
     "remat": {
         "monitor",
         "ops",      # debt: ops <-> remat
@@ -64,7 +64,6 @@ ALLOWED = {
     "testing": {"amp", "contrib", "elastic", "guard", "moe", "monitor", "ops",
                 "optimizers", "parallel", "remat", "transformer", "utils"},
     "transformer": {"amp", "monitor", "ops", "parallel", "remat"},
-    "tune": {"guard", "monitor", "utils"},
     "utils": {
         "monitor",  # debt: utils <-> monitor (utils/__init__ re-exports spans)
         "parallel",  # debt: utils.logging -> parallel_state, lazily
@@ -126,6 +125,22 @@ def test_subpackage_imports_only_what_its_row_allows(sub):
         f"{sub} imports {sorted(imported - ALLOWED[sub])}: not in its row")
     if sub != "testing":
         assert "testing" not in ALLOWED[sub]
+
+
+def test_nothing_imports_the_tuner():
+    """``beforeholiday_tpu.tune`` went in PR 45: the entry points say their
+    defaults in their signatures, and no file of the repo asks for the module."""
+    assert not os.path.exists(os.path.join(_REPO, _PKG, "tune"))
+    asking = []
+    for top in (_PKG, "benchmark", "examples", "tools", "tests"):
+        for d, _, files in os.walk(os.path.join(_REPO, top)):
+            for f in files:
+                if f.endswith(".py"):
+                    path = os.path.join(d, f)
+                    asking += [os.path.relpath(path, _REPO)
+                               for name in _modules_imported(path)
+                               if name.startswith(f"{_PKG}.tune")]
+    assert not asking, f"still importing the tuner: {sorted(set(asking))}"
 
 
 # --------------------------------------------------------------- citations

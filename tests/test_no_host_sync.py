@@ -64,14 +64,6 @@ _SANCTIONED_BY_FILE = {
     # readback must fail the scan)
     "elastic/signals.py": frozenset({"_handler"}),
     "elastic/watchdog.py": frozenset({"_monitor_loop", "beat"}),
-    # the tuning manifest is a host-side JSON cache by contract: ``load``
-    # coerces the stored cost/trial fields (plain host floats/ints from
-    # json.load — never traced values) and ``save`` is the atomic write
-    # path; everything else in tune/ (the search loop, the signature
-    # hasher, the knob space) must stay sync-free — trial COSTS arrive as
-    # host floats from the caller's trial_fn, the search never reads one
-    # back itself
-    "tune/manifest.py": frozenset({"load", "save"}),
 }
 
 # file-scoped waivers for sync points that are part of a documented host-side
@@ -178,7 +170,7 @@ def test_monitor_package_is_scanned():
     assert set(_SANCTIONED_BY_FILE) == {
         "monitor/export.py", "monitor/trace.py", "monitor/flight.py",
         "infer/engine.py", "infer/batching.py", "elastic/checkpoint.py",
-        "elastic/signals.py", "elastic/watchdog.py", "tune/manifest.py",
+        "elastic/signals.py", "elastic/watchdog.py",
     }
     assert _SANCTIONED_BY_FILE["monitor/export.py"] == {"drain", "flush", "_fetch"}
     assert _SANCTIONED_BY_FILE["monitor/trace.py"] == {"export"}
@@ -411,35 +403,6 @@ def test_telemetry_surface_is_scanned():
         assert pathlib.Path(rel).parts[0] not in _SKIP_DIRS
         assert rel not in _SANCTIONED_BY_FILE
         assert not any(path == rel for path, _ in _WAIVED)
-
-
-def test_tune_surface_is_scanned():
-    """The autotuner promises that ONLY the manifest's read/write path
-    touches host values: ``load`` coerces the JSON-decoded cost/trial
-    fields and ``save`` is the atomic write — the search loop itself
-    receives trial costs as host floats from the caller's ``trial_fn`` and
-    never reads a traced value back, the signature hasher works on abstract
-    shapes (``jax.eval_shape``), and the knob space is pure metadata. Pin
-    that every tune/ file sits inside the scanner's reach, that the
-    sanction is EXACTLY ``{load, save}`` on manifest.py, and that nothing
-    else in tune/ carries a sanction or waiver — a future ``.item()`` in
-    the halving loop or a ``float()`` on a traced cost must fail this
-    suite, not ship a per-step stall into every tuned trial."""
-    tune_files = sorted(
-        p.relative_to(_PKG_ROOT).as_posix()
-        for p in (_PKG_ROOT / "tune").rglob("*.py")
-    )
-    assert "tune/space.py" in tune_files
-    assert "tune/signature.py" in tune_files
-    assert "tune/search.py" in tune_files
-    assert "tune/manifest.py" in tune_files
-    assert "tune" not in _SKIP_DIRS
-    assert _SANCTIONED_BY_FILE["tune/manifest.py"] == {"load", "save"}
-    assert not any(
-        path.startswith("tune/") and path != "tune/manifest.py"
-        for path in _SANCTIONED_BY_FILE
-    )
-    assert not any(path.startswith("tune/") for path, _ in _WAIVED)
 
 
 def test_moe_surface_is_scanned():

@@ -190,23 +190,6 @@ def test_dispatch_is_guarded_counted_and_booked(monkeypatch):
     assert booked["fwd"]["total"] == booked["bwd"]["total"] == 3       # the probe ran both
 
 
-def test_a_shape_the_gate_refuses_takes_the_chain_and_is_counted(monkeypatch):
-    """64 channels (the small model of ``tests/test_lfm2_moe.py``) and a
-    sequence of 40 rows are not the kernels': on a TPU too the chain runs, and
-    ``guard.dispatch`` says so."""
-    dispatch.reset_dispatch_counters()
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(sc, "_interpret_default", lambda: True)
-    for shape in ((2, 40, 128), (1, 48, 64)):
-        bcx, w, dy = _inputs(*shape)
-        got, loop = _run(None, bcx, w, dy), _loop64(bcx, w, dy)
-        for what in _PARTS:
-            _close(got[what], loop[what], what)
-        with pytest.raises(ValueError, match="forced"):
-            sc.gated_short_conv(bcx, w, impl="pallas")
-    assert _counted()["short_conv"]["jnp"] == 2 and _counted()["short_conv"]["pallas"] == 0
-
-
 def test_mismatched_shapes_are_refused():
     bcx, w, _ = _inputs(1, 48, 128)
     with pytest.raises(ValueError, match="shapes mismatch"):

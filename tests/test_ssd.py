@@ -139,21 +139,10 @@ def test_the_shape_gate(chunk, P, N, Hg, ok):
     assert is_kernel_available(chunk, P, N, Hg) is ok
 
 
-def test_a_forced_kernel_off_its_shapes_raises_and_the_default_falls_back(monkeypatch):
-    args = operands(6, 1, 64, 2, 8, 1, 16)
-    with pytest.raises(ValueError, match="impl='pallas' forced"):
-        ssd(*args, chunk=32, impl="pallas")
-    monkeypatch.setattr(ssd_mod, "_resolve_impl", lambda impl: impl or "pallas")
-    dispatch.reset_dispatch_counters()
-    got = ssd(*args, chunk=32)                       # the chip's default, off its shapes
-    np.testing.assert_allclose(got, ssd(*args, chunk=32, impl="jnp"), atol=1e-6)
-    counted = {k[0]: v for k, v in dispatch.dispatch_counters().items()}
-    assert counted["ssd"]["jnp"] == 1 and counted["ssd"]["pallas"] == 0
-
-
 def test_the_default_on_the_kernels_shapes_is_probed_counted_and_booked(monkeypatch):
     args = operands(7, 1, 256, 2, 64, 1, 128)
-    monkeypatch.setattr(ssd_mod, "_resolve_impl", lambda impl: impl or "pallas")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # resolve_impl -> pallas
+    monkeypatch.setattr(ssd_mod, "_interpret_default", lambda: True)
     dispatch.reset_dispatch_counters()
     dispatch.clear_probe_cache("ssd")
     jax.clear_caches()      # a kernel call is a jit function: booked when traced, not when hit

@@ -336,18 +336,6 @@ class TestRandomAndMemory:
             np.asarray(g0), np.asarray(g1), rtol=2e-5, atol=1e-7
         )
 
-    def test_memory_buffer_views(self):
-        buf = tp.MemoryBuffer(64)
-        v = buf.get((4, 8), 16)
-        assert v.shape == (4, 8)
-        with pytest.raises(ValueError, match="exceeds"):
-            buf.get((8, 8), 16)
-
-    def test_ring_buffer_cycles(self):
-        ring = tp.RingMemBuffer(2, 16)
-        a, b, c = (ring.get_next_buffer() for _ in range(3))
-        assert a is c and a is not b
-
     def test_broadcast_data_validates(self):
         data = {"x": jnp.ones((2,), jnp.int32)}
         out = tp.broadcast_data(["x"], data, jnp.int32)

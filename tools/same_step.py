@@ -1,17 +1,21 @@
 #!/usr/bin/env python
 """Say whether two dumps of ``tools/offline_step.py --hlo-dir`` are one program.
 
-An edit to a file a cell traces moves source lines, and the compiled step's text
-carries them twice: in the frame table (``N {file_name_id=… line=…}``) and inside
-each Pallas kernel's serialized MLIR (``"body":"<base64>"``). So the texts of a
-parent and a change that compile the same program still differ. This compares
-them with both taken out: every other line must be equal, and every kernel body
-equal once it is printed without its source locations::
+An edit to a file a cell traces moves source lines, and a body that moves into
+another function adds a call frame. The compiled step's text carries both: in
+its four tables (``FileNames``, ``FunctionNames``, ``FileLocations``,
+``StackFrames``, which change length with a frame), in every op's
+``stack_frame_id=N`` into them, and inside each Pallas kernel's serialized MLIR
+(``"body":"<base64>"``). So the texts of a parent and a change that compile the
+same program still differ. This compares them with all of that taken out: the
+tables dropped, the frame ids blanked, every other line equal, and every kernel
+body equal once it is printed without its source locations::
 
     JAX_PLATFORMS=cpu python tools/same_step.py /root/scratch/hlo_parent /root/scratch/hlo_change
 
 Both dumps have to come from ONE directory (unpack parent and change there in
-turn): the checkout's path is in the file table. Exit code 1 if any cell differs.
+turn): the checkout's path is inside the kernels' serialized MLIR. Exit code 1 if
+any cell differs.
 """
 
 import base64
@@ -19,8 +23,8 @@ import os
 import re
 import sys
 
-_FRAME = re.compile(r"^\d+ \{file_name_id=\d+ function_name_id=\d+ line=\d+ end_line=\d+ "
-                    r"column=\d+ end_column=\d+\}$")
+_TABLES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
+_FRAME_ID = re.compile(r"stack_frame_id=\d+")
 _BODY = re.compile(r'"body":"([^"]+)"')
 
 
@@ -34,14 +38,30 @@ def _kernel_text(body: str) -> str:
         return module.operation.get_asm(enable_debug_info=False)
 
 
+def program_lines(text: str) -> list:
+    """The lines of a compiled module's text that say what runs: the source
+    tables (a heading, its rows, up to the blank line after) dropped, and each
+    op's ``stack_frame_id`` blanked."""
+    out, in_table = [], False
+    for line in text.splitlines():
+        if in_table:
+            in_table = bool(line.strip())
+        elif line in _TABLES:
+            in_table = True
+        else:
+            out.append(_FRAME_ID.sub("stack_frame_id=", line))
+    return out
+
+
 def differing(parent: str, change: str) -> list:
-    """Lines (1-based) at which the two texts are not one program."""
-    a, b = open(parent).read().splitlines(), open(change).read().splitlines()
+    """Where the two texts are not one program: numbers into :func:`program_lines`
+    (1-based), or one sentence if the two have different numbers of lines."""
+    a, b = (program_lines(open(path).read()) for path in (parent, change))
     if len(a) != len(b):
         return [f"{len(a)} lines against {len(b)}"]
     out = []
     for n, (x, y) in enumerate(zip(a, b), 1):
-        if x == y or (_FRAME.match(x) and _FRAME.match(y)):
+        if x == y:
             continue
         bx, by = _BODY.search(x), _BODY.search(y)
         if not (bx and by and _BODY.sub("", x) == _BODY.sub("", y)
