@@ -106,6 +106,24 @@ def rotary_table(seq_len: int, rotary_dim: int, theta: float,
     return cos, sin
 
 
+def mrope_table(positions, rotary_dim: int, theta: float, sections: Sequence[int]):
+    """``(cos, sin)``, each ``(S, rotary_dim / 2)`` float32, of a table whose
+    frequency pairs take their positions from several rows (a published
+    ``mrope_section``): ``positions (len(sections), S)``, pair ``i`` of the
+    ``rotary_dim / 2`` reads row ``r`` where ``i`` falls in the ``r``-th run of
+    ``sections`` (``[16, 24, 24]``: pairs 0-15 the temporal row, 16-39 the
+    height, 40-63 the width). Equal rows give :func:`rotary_table`'s table of
+    those positions: on text, whose three positions are the token's index, the
+    sections change nothing."""
+    half = rotary_dim // 2
+    if sum(sections) != half or len(sections) != positions.shape[0]:
+        raise ValueError(f"sections {tuple(sections)} are not {positions.shape[0]} runs of the "
+                         f"{half} frequency pairs")
+    row = jnp.repeat(jnp.arange(len(sections)), jnp.asarray(sections), total_repeat_length=half)
+    angle = positions.astype(_F32).T[:, row] * rotary_frequencies(rotary_dim, theta)[None, :]
+    return jnp.cos(angle), jnp.sin(angle)
+
+
 def apply_rotary(x, cos, sin):
     """Rotary position embedding on the first ``2 * cos.shape[-1]`` dims of
     each head (``rotate_half`` layout: dim ``i`` pairs with ``i + half``), the
@@ -121,12 +139,14 @@ def apply_rotary(x, cos, sin):
 
 
 def grouped_query_attention(q, k, v, *, window: Optional[int] = None,
-                            impl: Optional[str] = None):
+                            impl: Optional[str] = None, selected=None):
     """Causal softmax attention of ``q (B, S, H, hd)`` over ``k (B, S, Hkv, hd)``,
     ``v (B, S, Hkv, dv)`` at ``hd^-1/2``, each key head serving ``H / Hkv`` query
     heads BY REPETITION (``ops.flash_attention`` takes equal head counts);
-    ``window``: the keys a query sees, None for all before it. ``(B, S, H, dv)``:
-    the heads back beside their positions, for the caller to gate or flatten."""
+    ``window``: the keys a query sees, None for all before it; ``selected``: the
+    keys each query keeps, ``(B, S, S)`` int8 (``ops.index_select``), the same for
+    every head. ``(B, S, H, dv)``: the heads back beside their positions, for the
+    caller to gate or flatten."""
     from beforeholiday_tpu.ops import flash_attention
 
     H, Hkv, hd = q.shape[2], k.shape[2], q.shape[3]
@@ -134,12 +154,13 @@ def grouped_query_attention(q, k, v, *, window: Optional[int] = None,
         k, v = (jnp.repeat(t, H // Hkv, axis=2) for t in (k, v))
     heads_first = lambda t: t.transpose(0, 2, 1, 3)
     ctx = flash_attention(heads_first(q), heads_first(k), heads_first(v), causal=True,
-                          scale=hd ** -0.5, window=window, impl=impl)
+                          scale=hd ** -0.5, window=window, impl=impl, selected=selected)
     return heads_first(ctx)
 
 
 def qk_norm_attention(u, p, table, *, heads: int, kv_heads: int, head_dim: int, eps: float,
-                      window: Optional[int] = None, impl: Optional[str] = None):
+                      window: Optional[int] = None, impl: Optional[str] = None,
+                      selected=None):
     """One bias-free attention mixer on ``u (B, S, D)``: ``w_q`` / ``w_k`` /
     ``w_v``, ``q`` and ``k`` through an RMS norm over the head (``q_norm`` /
     ``k_norm``: one weight of ``head_dim``, shared by the heads) and the rotary
@@ -152,7 +173,7 @@ def qk_norm_attention(u, p, table, *, heads: int, kv_heads: int, head_dim: int, 
     v = (u @ p["w_v"].astype(dt)).reshape(B, S, kv_heads, head_dim)
     q = apply_rotary(rms_norm(q, p["q_norm"], eps), *table)
     k = apply_rotary(rms_norm(k, p["k_norm"], eps), *table)
-    ctx = grouped_query_attention(q, k, v, window=window, impl=impl)
+    ctx = grouped_query_attention(q, k, v, window=window, impl=impl, selected=selected)
     return ctx.reshape(B, S, heads * head_dim) @ p["w_o"].astype(dt)
 
 
@@ -173,18 +194,34 @@ def swiglu_ffn(h, p):
     return swiglu(h, p["w_gate"], p["w_up"], p["w_down"])
 
 
+def _dropless(h, p, **kw):
+    """``moe.dropless.dropless_moe`` (its spans) on ``h (B, S, D)``: ``(y (B, S, D), counters)``."""
+    from beforeholiday_tpu.moe.dropless import dropless_moe
+
+    B, S, D = h.shape
+    y, counters = dropless_moe(h.reshape(B * S, D), p, **kw)
+    return y.reshape(B, S, D), counters
+
+
+def softmax_moe(h, p, *, top_k: int, first_expert: int, rows_bound: Optional[int],
+                renormalize: bool):
+    """``(y, counters)`` of one mixture-of-experts part on ``h (B, S, D)`` under
+    ``moe.dropless``'s own router (softmax over all the router's outputs, the
+    ``top_k`` largest, divided by their sum where ``renormalize``), no shared
+    expert: the held experts' part of the routed sum."""
+    return _dropless(h, p, top_k=top_k, first_expert=first_expert, rows_bound=rows_bound,
+                     renormalize=renormalize)
+
+
 def sigmoid_moe(h, p, *, top_k: int, first_expert: int, rows_bound: Optional[int],
                 renormalize: bool, **route):
     """``(y, counters)`` of one mixture-of-experts part on ``h (B, S, D)``:
     ``moe.dropless.dropless_moe`` (its spans) under ``route_sigmoid(**route)``
     (``bias``, ``scale``, ``eps``: the published router's)."""
-    from beforeholiday_tpu.moe.dropless import dropless_moe, route_sigmoid
+    from beforeholiday_tpu.moe.dropless import route_sigmoid
 
-    B, S, D = h.shape
-    y, counters = dropless_moe(
-        h.reshape(B * S, D), p, top_k=top_k, first_expert=first_expert, rows_bound=rows_bound,
-        renormalize=renormalize, route=functools.partial(route_sigmoid, **route))
-    return y.reshape(B, S, D), counters
+    return _dropless(h, p, top_k=top_k, first_expert=first_expert, rows_bound=rows_bound,
+                     renormalize=renormalize, route=functools.partial(route_sigmoid, **route))
 
 
 def logits_of(x, head):

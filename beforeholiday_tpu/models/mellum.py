@@ -181,16 +181,12 @@ def attention(cfg: MellumConfig, x, p, kind: str, table):
 
 def _layer(cfg: MellumConfig, x, lp, kind, table):
     """One decoder layer: ``(x, counters)``."""
-    from beforeholiday_tpu.moe.dropless import dropless_moe
-
-    B, S, D = x.shape
     x = x + attention(cfg, rms_norm(x, lp["input_norm"], cfg.rms_norm_eps), lp, kind, table)
     h = rms_norm(x, lp["post_norm"], cfg.rms_norm_eps)
-    y, counters = dropless_moe(
-        h.reshape(B * S, D), lp, top_k=cfg.num_experts_per_tok,
-        first_expert=cfg.first_expert, rows_bound=cfg.moe_rows_bound,
-        renormalize=cfg.norm_topk_prob)
-    return x + y.reshape(B, S, D), counters
+    y, counters = _layers.softmax_moe(
+        h, lp, top_k=cfg.num_experts_per_tok, first_expert=cfg.first_expert,
+        rows_bound=cfg.moe_rows_bound, renormalize=cfg.norm_topk_prob)
+    return x + y, counters
 
 
 def forward(params: dict, tokens: jax.Array, cfg: MellumConfig):

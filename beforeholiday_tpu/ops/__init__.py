@@ -73,6 +73,7 @@ from .attention import (  # noqa: F401
     is_flash_available,
     self_attention,
 )
+from .indexer import index_select  # noqa: F401
 from .gated_delta import gated_delta_rule  # noqa: F401
 from .deltanet import deltanet_gate, deltanet_qkv  # noqa: F401
 from .short_conv import gated_short_conv  # noqa: F401

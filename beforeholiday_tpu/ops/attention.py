@@ -39,6 +39,13 @@ computes and every copy is issued under a product; the dq and dkv kernels keep
 the square's grid, their carried sums being what they are, and clamp their
 index maps onto the diagonal (:func:`_block_maps`), where a repeated block is
 not copied again.
+A causal call may also take the keys each query keeps as an operand
+(``selected``: int8 ``(B, Sq, Sk)``, made at run time by ``ops.index_select``, the
+same for a batch row's heads): the plan is then the causal plan with
+``TilePlan.selected``, every kernel family takes the operand's block beside its
+own (:func:`_sel_spec`) and :func:`_mask` reads it where it would have compared
+positions. Every causal block is walked; what the selection leaves out is
+masked, not skipped.
 ``guard.dispatch.count_tiles`` books what each traced kernel's plan computes
 and, for the forward, the grid steps and copies a head takes
 (``monitor.tile_records()``).
@@ -192,6 +199,7 @@ class TilePlan(NamedTuple):
     tk: int
     causal: bool
     window: Optional[int] = None    # keys a query sees, itself among them
+    selected: bool = False          # a (B, Sq, Sk) int8 operand says which keys a query keeps
 
     @property
     def nq(self) -> int:
@@ -329,7 +337,7 @@ class TilePlan(NamedTuple):
         else:
             n = self.sq // self.tq
             live = n * (n + 1) // 2
-            masked = live if has_lens else n
+            masked = live if has_lens or self.selected else n
         out = {"total": total, "live": live, "masked": masked}
         if fwd:
             steps = self.fwd_steps()
@@ -340,7 +348,8 @@ class TilePlan(NamedTuple):
 
 @functools.lru_cache(maxsize=None)
 def _tile_plan(sq: int, sk: int, head_dim: int, causal: bool,
-               window: Optional[int] = None, v_head_dim: Optional[int] = None) -> TilePlan:
+               window: Optional[int] = None, v_head_dim: Optional[int] = None,
+               selected: bool = False) -> TilePlan:
     """The schedule of a call from what it can observe; no knob. ``head_dim``
     is the queries' and keys', ``v_head_dim`` the values' (the same where not
     given).
@@ -349,7 +358,9 @@ def _tile_plan(sq: int, sk: int, head_dim: int, causal: bool,
     (``sq == sk``) and the ones on the diagonal are walked in ``_DIAG_STRIPS``
     strips of square tiles, never smaller than the 128-lane minimum. With a
     ``window`` (fewer keys than the sequence has) the blocks are
-    :func:`_window_block`'s and the grid is the band (:attr:`TilePlan.band`)."""
+    :func:`_window_block`'s and the grid is the band (:attr:`TilePlan.band`).
+    ``selected`` (a causal call whose keys are chosen per query, by an operand):
+    the causal plan, every computed piece masked by the operand's block."""
     bq, bk = _block_size(sq, head_dim, v_head_dim), _block_size(sk, head_dim, v_head_dim)
     if not causal:
         return TilePlan(sq, sk, bq, bk, bq, bk, False)
@@ -358,7 +369,7 @@ def _tile_plan(sq: int, sk: int, head_dim: int, causal: bool,
     if window is not None:
         bq = bk = _window_block(sq, head_dim, window, v_head_dim)
     t = max(_MIN_BLOCK, bq // _DIAG_STRIPS)
-    return TilePlan(sq, sk, bq, bk, t, t, True, window)
+    return TilePlan(sq, sk, bq, bk, t, t, True, window, selected)
 
 
 # Above this many bytes of materialized (BH, S, Sk) fp32 scores the jnp
@@ -437,13 +448,18 @@ def _at(block, size, offset):
     return lax.add(at, offset) if offset else at
 
 
-def _mask(plan, on_diag, i, j, rows, cols, lens):
+def _mask(plan, on_diag, i, j, rows, cols, lens, sel_ref=None):
     """Mask predicate of the score piece ``rows`` x ``cols`` of block (i, j).
     True = masked out; None where nothing in the piece can be. ``lens`` is a
     scalar int32 (this sequence's key length), or None when the call has no
     ``kv_lens``: every key is then in range, statically. ``on_diag`` says
     which edges cross the piece (:meth:`TilePlan.band_walk`): 1 (or True) the
-    causal diagonal, 2 a window's lower edge, 3 both."""
+    causal diagonal, 2 a window's lower edge, 3 both. ``sel_ref`` (a selected
+    plan): the block of the keys each query keeps, which holds the diagonal too
+    (a kept key is never after its query), so the piece's mask is read and
+    nothing is computed from positions."""
+    if sel_ref is not None:
+        return lax.eq(sel_ref[0, rows, cols].astype(jnp.int32), 0)
     if lens is None and not on_diag:
         return None
     shape = (rows.stop - rows.start, cols.stop - cols.start)
@@ -521,7 +537,7 @@ def _join(parts, axis):
     return parts[0] if len(parts) == 1 else lax.concatenate(parts, axis)
 
 
-def _panels(plan, by_cols, walk, b, i, j, lens, seed_ref, rate):
+def _panels(plan, by_cols, walk, b, i, j, lens, seed_ref, rate, sel_ref=None):
     """The walk of block (i, j) as ``_Panel``s, every mask built."""
     out = []
     for fixed, moving in walk:
@@ -529,7 +545,7 @@ def _panels(plan, by_cols, walk, b, i, j, lens, seed_ref, rate):
         pieces = []
         for span, on_diag in moving:
             rows, cols = (span, fixed) if by_cols else (fixed, span)
-            pieces.append((rows, cols, _mask(plan, on_diag, i, j, rows, cols, lens)))
+            pieces.append((rows, cols, _mask(plan, on_diag, i, j, rows, cols, lens, sel_ref)))
         rows, cols = (run, fixed) if by_cols else (fixed, run)
         axis = 0 if by_cols else 1
         keep = None
@@ -559,8 +575,9 @@ def _fill(plan):
     can mask a whole row of a panel while the row's max still stands at its
     initial ``_NEG``, and ``exp(_NEG - _NEG)`` would count every masked key as
     one; ``exp(2 * _NEG - _NEG)`` is exactly 0. (On the diagonal alone every
-    row has its own key, which is why the plain causal plan needs none of it.)"""
-    return _NEG if plan.window is None else 2 * _NEG
+    row has its own key, which is why the plain causal plan needs none of it.)
+    A selected plan's too: a query may keep no key of a block, its own included."""
+    return _NEG if plan.window is None and not plan.selected else 2 * _NEG
 
 
 def _grid_ids(plan, by_cols):
@@ -633,7 +650,9 @@ def _fa_fwd_kernel(plan, scale, has_lens, rate, *refs):
         # compared where the accumulators are set up and written out, not here:
         # a one_pass plan does neither, and its body stays what it was
         first, last = (lambda: step == 0), (lambda: step == _steps(plan) - 1)
-    q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
+    q_ref, k_ref, v_ref, *refs = refs
+    sel_ref = refs.pop(0) if plan.selected else None
+    o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
     lens = lens_ref[b] if has_lens else None
     one_pass = plan.one_pass
     fill = _fill(plan)
@@ -664,7 +683,7 @@ def _fa_fwd_kernel(plan, scale, has_lens, rate, *refs):
     # overlaps one panel's matmul with another's vector work instead of
     # waiting out every strip's matmul -> max -> exp -> matmul chain
     def block(walk):
-        panels = _panels(plan, False, walk, b, i, j, lens, seed_ref, rate)
+        panels = _panels(plan, False, walk, b, i, j, lens, seed_ref, rate, sel_ref)
         # phase 1 — the scores of every panel
         scores = [_panel_scores(pn, q_ref, k_ref, scale, fill) for pn in panels]
         # phase 2 — the (running) max: one cross-lane reduction per strip
@@ -730,6 +749,8 @@ def _book_tiles(plan, widths, has_lens, *kernels):
     key = (plan.sq, plan.sk, dk if dk == dv else widths, plan.causal, has_lens)
     if plan.window is not None:
         key += (plan.window,)
+    if plan.selected:
+        key += ("selected",)
     for kernel in kernels:
         _count_tiles("flash_attention", kernel, key, **plan.counts(has_lens, kernel == "fwd"))
 
@@ -758,9 +779,25 @@ def _block_maps(plan):
 
 
 def _kernel_name(plan, kernel):
-    """A windowed plan's kernels carry their own names in the device trace,
-    under the op's prefix; the others keep the scope's (``%flash_attention.N``)."""
+    """A windowed plan's kernels, and a selected plan's, carry their own names
+    in the device trace, under the op's prefix; the others keep the scope's
+    (``%flash_attention.N``)."""
+    if plan.selected:
+        return f"flash_attention_sparse_{kernel}"
     return None if plan.window is None else f"flash_attention_window_{kernel}"
+
+
+def _sel_spec(plan, sel, BH, q_map, k_map):
+    """``[BlockSpec]`` of a selected plan's operand ``sel (B, Sq, Sk)`` (``[]``
+    where the call has none): the ``(1, bq, bk)`` block of the step's query block
+    and key block, as ``q_map`` and ``k_map`` (the index maps of the kernel's
+    query-side and key-side operands) name them, of the batch row that head ``b``
+    of the ``BH`` belongs to — the heads of a row share one selection."""
+    if sel is None:
+        return []
+    heads = BH // sel.shape[0]
+    return [pl.BlockSpec((1, plan.bq, plan.bk),
+                         lambda b, *at: (b // heads, q_map(b, *at)[1], k_map(b, *at)[1]))]
 
 
 def _scalar_operands(lens, seed, rate):
@@ -786,11 +823,12 @@ def _live_grid(plan, BH):
 
 
 def _fa_fwd_pallas(q, k, v, lens, causal, scale, interpret, rate=0.0, seed=None,
-                   window=None):
-    """``lens=None``: the call has no ``kv_lens`` — no length test anywhere."""
+                   window=None, sel=None):
+    """``lens=None``: the call has no ``kv_lens`` — no length test anywhere.
+    ``sel``: the kept keys of a selected call, int8 ``(B, Sq, Sk)``."""
     BH, Sq, _ = q.shape
     Dk, Dv = _widths(q, v)
-    plan = _tile_plan(Sq, k.shape[1], Dk, causal, window, Dv)
+    plan = _tile_plan(Sq, k.shape[1], Dk, causal, window, Dv, sel is not None)
     bq, bk = plan.bq, plan.bk
     _book_tiles(plan, (Dk, Dv), lens is not None, "fwd")
     scalars = _scalar_operands(lens, seed, rate)
@@ -804,7 +842,7 @@ def _fa_fwd_pallas(q, k, v, lens, causal, scale, interpret, rate=0.0, seed=None,
         num_scalar_prefetch=len(scalars),
         grid=grid,
         in_specs=[pl.BlockSpec((1, bq, Dk), own), pl.BlockSpec((1, bk, Dk), keys),
-                  pl.BlockSpec((1, bk, Dv), keys)],
+                  pl.BlockSpec((1, bk, Dv), keys)] + _sel_spec(plan, sel, BH, own, keys),
         out_specs=[
             pl.BlockSpec((1, bq, Dv), own),
             pl.BlockSpec((1, bq, 128), own),
@@ -827,7 +865,7 @@ def _fa_fwd_pallas(q, k, v, lens, causal, scale, interpret, rate=0.0, seed=None,
         ],
         interpret=interpret,
         name=_kernel_name(plan, "fwd"),
-    )(*scalars, q, k, v)
+    )(*scalars, q, k, v, *(() if sel is None else (sel,)))
     return o, lse
 
 
@@ -876,18 +914,20 @@ def _panel_p_ds(scale, s, dp, lse, delta, dlse, panel, rate):
     return z, lax.mul(lax.mul(p, inner), scale)
 
 
-def _bwd_refs(refs, has_dlse):
-    """Split a backward kernel's refs after the scalars: the six operands
-    and dlse (or None), then the outputs and accumulators."""
+def _bwd_refs(refs, has_dlse, selected=False):
+    """Split a backward kernel's refs after the scalars: the six operands,
+    dlse (or None) and a selected plan's block of kept keys (or None), then the
+    outputs and accumulators."""
     q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, *rest = refs
     dlse_ref = rest.pop(0) if has_dlse else None
-    return (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref), rest
+    sel_ref = rest.pop(0) if selected else None
+    return (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref, sel_ref), rest
 
 
 def _fa_dq_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
     lens_ref, seed_ref, refs = _kernel_scalars(refs, has_lens, rate)
-    (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref), rest = _bwd_refs(
-        refs, has_dlse)
+    (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref, sel_ref), rest = _bwd_refs(
+        refs, has_dlse, plan.selected)
     dq_ref, dq_acc = rest
     b, i, j, step = _grid_ids(plan, False)
     lens = lens_ref[b] if has_lens else None
@@ -898,7 +938,7 @@ def _fa_dq_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
     def block(walk):  # phase by phase, as the forward
-        panels = _panels(plan, False, walk, b, i, j, lens, seed_ref, rate)
+        panels = _panels(plan, False, walk, b, i, j, lens, seed_ref, rate, sel_ref)
         scores = []
         for pn in panels:
             do = do_ref[0, pn.rows, :]
@@ -927,8 +967,8 @@ def _dkv_block(plan, scale, rate, walk, b, i, j, lens, seed_ref, operands,
     sums dq too (``dq_acc``: the head's float32 dq, ``(nq, bq, Dk)``), query
     block ``i``'s share of dq from the same ``ds``. Phase by phase, as the
     forward."""
-    q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref = operands
-    panels = _panels(plan, True, walk, b, i, j, lens, seed_ref, rate)
+    q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref, sel_ref = operands
+    panels = _panels(plan, True, walk, b, i, j, lens, seed_ref, rate, sel_ref)
     # once for the block: a row's delta serves every strip that reaches it
     delta = _row_delta(do_ref[0], o_ref[0])
     scores = []
@@ -958,7 +998,7 @@ def _dkv_block(plan, scale, rate, walk, b, i, j, lens, seed_ref, operands,
 
 def _fa_dkv_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
     lens_ref, seed_ref, refs = _kernel_scalars(refs, has_lens, rate)
-    operands, rest = _bwd_refs(refs, has_dlse)
+    operands, rest = _bwd_refs(refs, has_dlse, plan.selected)
     dk_ref, dv_ref, dk_acc, dv_acc = rest
     # k block outer, q block inner
     b, i, j, step = _grid_ids(plan, True)
@@ -991,8 +1031,8 @@ def _fa_dqkv_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
     the result out — the walk is static, so which strip is which is known
     here, and nothing is zeroed first or copied out afterwards."""
     lens_ref, seed_ref, refs = _kernel_scalars(refs, has_lens, rate)
-    (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref), rest = _bwd_refs(
-        refs, has_dlse)
+    (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref, sel_ref), rest = _bwd_refs(
+        refs, has_dlse, plan.selected)
     dq_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
     b, i, j, _ = _grid_ids(plan, False)
     lens = lens_ref[b] if has_lens else None
@@ -1000,7 +1040,7 @@ def _fa_dqkv_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
     # the one block is on the diagonal, and at the window's lower edge if any
     walk = plan.walk(False, True) if plan.window is None else plan.band_walk(False, 0)
 
-    panels = _panels(plan, False, walk, b, i, j, lens, seed_ref, rate)
+    panels = _panels(plan, False, walk, b, i, j, lens, seed_ref, rate, sel_ref)
     delta = _row_delta(do_ref[0], o_ref[0])      # once for the block
     # the key tiles each strip reaches, and the first and last strip to reach each
     tiles = [range(pn.cols.start // plan.tk, pn.cols.stop // plan.tk) for pn in panels]
@@ -1062,7 +1102,7 @@ def _fa_dqkv_blocks_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
     when ``j`` moves on: dq never sits in HBM in float32, and is summed in the
     order the dq kernel sums it."""
     lens_ref, seed_ref, refs = _kernel_scalars(refs, has_lens, rate)
-    operands, rest = _bwd_refs(refs, has_dlse)
+    operands, rest = _bwd_refs(refs, has_dlse, plan.selected)
     dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = rest
     b, i, j, step = _grid_ids(plan, True)
     lens = lens_ref[b] if has_lens else None
@@ -1093,12 +1133,14 @@ def _fa_dqkv_blocks_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
 
 
 def _bwd_call(body, kernel, plan, args, *, grid, in_specs, out_specs, out_like, scratch,
-              **compiler_params):
+              sel=None, sel_maps=None, **compiler_params):
     """One backward ``pallas_call`` of ``args`` (:func:`_fa_bwd_pallas`'s, from
     ``q`` on): ``body`` over the scalars, the six operands — ``in_specs``, whose
-    last serves ``dlse`` too — the outputs shaped like ``out_like`` and float32
-    ``scratch``; books the plan's tiles under ``kernel``. ``compiler_params``
-    replace Mosaic's defaults and the (parallel, parallel, arbitrary) grid."""
+    last serves ``dlse`` too — a selected plan's kept keys ``sel`` under
+    ``sel_maps`` (:func:`_sel_spec`'s two maps), the outputs shaped like ``out_like`` and
+    float32 ``scratch``; books the plan's tiles under ``kernel``.
+    ``compiler_params`` replace Mosaic's defaults and the (parallel, parallel,
+    arbitrary) grid."""
     *operands, dlse, lens, scale, interpret, rate, seed = args
     has_dlse = dlse is not None
     scalars = _scalar_operands(lens, seed, rate)
@@ -1109,7 +1151,8 @@ def _bwd_call(body, kernel, plan, args, *, grid, in_specs, out_specs, out_like, 
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=grid,
-            in_specs=in_specs + ([in_specs[-1]] if has_dlse else []),
+            in_specs=in_specs + ([in_specs[-1]] if has_dlse else [])
+            + _sel_spec(plan, sel, operands[0].shape[0], *(sel_maps or ())),
             out_specs=out_specs,
             scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in scratch],
         ),
@@ -1117,10 +1160,10 @@ def _bwd_call(body, kernel, plan, args, *, grid, in_specs, out_specs, out_like, 
         compiler_params=pltpu.CompilerParams(**compiler_params),
         interpret=interpret,
         name=_kernel_name(plan, kernel),
-    )(*scalars, *operands, *((dlse,) if has_dlse else ()))
+    )(*scalars, *operands, *((dlse,) if has_dlse else ()), *(() if sel is None else (sel,)))
 
 
-def _fa_bwd_fused(plan, *args):
+def _fa_bwd_fused(plan, *args, sel=None):
     """(dq, dk, dv) of a ``one_pass`` plan from one call on a (BH, 1, 1) grid."""
     q, k, v = args[:3]
     BH, (Dk, Dv) = q.shape[0], _widths(q, v)
@@ -1130,10 +1173,10 @@ def _fa_bwd_fused(plan, *args):
         _fa_dqkv_kernel, "dqkv", plan, args, grid=(BH, 1, 1),
         in_specs=[at_k, at_k, at_v, at_v, at_v, pl.BlockSpec((1, plan.bq, 128), own)],
         out_specs=[at_k, at_k, at_v], out_like=(q, k, v),
-        scratch=[(plan.bk, Dk), (plan.bk, Dv)])
+        scratch=[(plan.bk, Dk), (plan.bk, Dv)], sel=sel, sel_maps=(own, own))
 
 
-def _fa_bwd_two_calls(plan, *args):
+def _fa_bwd_two_calls(plan, *args, sel=None):
     """(dq, dk, dv) from the dq kernel (grid BH, nq, nk: dq carries over key
     blocks) and the dkv kernel (grid BH, nk, nq: dk and dv over query blocks)."""
     q, k, v = args[:3]
@@ -1146,14 +1189,15 @@ def _fa_bwd_two_calls(plan, *args):
         _fa_dq_kernel, "dq", plan, args, grid=(BH, plan.nq, _steps(plan)),
         in_specs=[spec(bq, Dk, own), spec(bk, Dk, keys), spec(bk, Dv, keys),
                   spec(bq, Dv, own), spec(bq, Dv, own), spec(bq, 128, own)],
-        out_specs=[spec(bq, Dk, own)], out_like=(q,), scratch=[(bq, Dk)])
+        out_specs=[spec(bq, Dk, own)], out_like=(q,), scratch=[(bq, Dk)],
+        sel=sel, sel_maps=(own, keys))
     # dkv grid: (BH, k-block, q-block) — q-side operands indexed by the INNER id
     dk, dv = _bwd_call(
         _fa_dkv_kernel, "dkv", plan, args, grid=(BH, plan.nk, _steps(plan, True)),
         in_specs=[spec(bq, Dk, queries), spec(bk, Dk, own), spec(bk, Dv, own),
                   spec(bq, Dv, queries), spec(bq, Dv, queries), spec(bq, 128, queries)],
         out_specs=[spec(bk, Dk, own), spec(bk, Dv, own)], out_like=(k, v),
-        scratch=[(bk, Dk), (bk, Dv)])
+        scratch=[(bk, Dk), (bk, Dv)], sel=sel, sel_maps=(queries, own))
     return dq, dk, dv
 
 
@@ -1178,10 +1222,13 @@ def _blocks_vmem_bytes(plan, Dk, Dv, itemsize):
     blocks = itemsize * (2 * (bq + bk) * wide + (2 * bq + 2 * bk) * narrow)   # q dq k dk, do o v dv
     blocks += 2 * 4 * bq * 128                                                # lse, dlse
     scratch = 4 * (plan.sq * wide + bk * (wide + narrow))
+    if plan.selected:       # the kept keys' int8 block, and its int32 copy among the six
+        blocks += bq * bk
+        scratch += 4 * bq * bk
     return 2 * blocks + scratch + 6 * 4 * bq * bk
 
 
-def _fa_bwd_blocks(plan, *args):
+def _fa_bwd_blocks(plan, *args, sel=None):
     """(dq, dk, dv) of a causal plan of several blocks from ONE call on the dkv
     kernel's grid (BH, nk, nq), a head's dq summed in VMEM
     (:func:`_fa_dqkv_blocks_kernel`). The key axis carries that scratch, so it
@@ -1198,6 +1245,7 @@ def _fa_bwd_blocks(plan, *args):
                   spec(bq, Dv, queries), spec(bq, Dv, queries), spec(bq, 128, queries)],
         out_specs=[spec(bq, Dk, own), spec(bk, Dk, own), spec(bk, Dv, own)],
         out_like=(q, k, v), scratch=[(plan.nq, bq, Dk), (bk, Dk), (bk, Dv)],
+        sel=sel, sel_maps=(queries, own),
         dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         vmem_limit_bytes=_blocks_vmem_bytes(plan, Dk, Dv, q.dtype.itemsize))
 
@@ -1213,7 +1261,7 @@ def _bwd_of(plan, Dk):
 
 
 def _fa_bwd_pallas(q, k, v, do, o, lse, dlse, lens, causal, scale, interpret,
-                   rate=0.0, seed=None, window=None):
+                   rate=0.0, seed=None, window=None, sel=None):
     """The backward of a flash call, by what its plan says carries over between
     grid steps (:func:`_bwd_of`); every plan recomputes the scores, ``do . v``,
     the ``exp``, the masks and ``ds`` from (q, k, lse).
@@ -1239,11 +1287,13 @@ def _fa_bwd_pallas(q, k, v, do, o, lse, dlse, lens, causal, scale, interpret,
 
     ``dlse=None`` (the plain-attention path) omits the operand entirely —
     an all-zero lane-replicated dlse would otherwise add an arena-sized HBM
-    read to every backward kernel for nothing. ``lens=None``: no ``kv_lens``."""
+    read to every backward kernel for nothing. ``lens=None``: no ``kv_lens``.
+    ``sel``: a selected call's kept keys; its plan is the causal one, so the rule
+    above picks its backward too."""
     Dk, Dv = _widths(q, v)
-    plan = _tile_plan(q.shape[1], k.shape[1], Dk, causal, window, Dv)
+    plan = _tile_plan(q.shape[1], k.shape[1], Dk, causal, window, Dv, sel is not None)
     return _bwd_of(plan, Dk)(
-        plan, q, k, v, do, o, lse, dlse, lens, scale, interpret, rate, seed)
+        plan, q, k, v, do, o, lse, dlse, lens, scale, interpret, rate, seed, sel=sel)
 
 
 # ---------------------------------------------------------------------------------
@@ -1283,6 +1333,38 @@ def _flash3_bwd(causal, scale, rate, window, res, do):
 
 
 _flash3.defvjp(_flash3_fwd, _flash3_bwd)
+
+
+# --- the selected-keys call: causal, the kept keys an int8 operand ---------------
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _flash3_selected(q, k, v, sel, scale):
+    o, _ = _fa_fwd_pallas(q, k, v, None, True, scale, _interpret_default(), sel=sel)
+    return o
+
+
+def _flash3_selected_fwd(q, k, v, sel, scale):
+    o, lse = _fa_fwd_pallas(q, k, v, None, True, scale, _interpret_default(), sel=sel)
+    lse = _checkpoint_name(lse, _TAG_FLASH_LSE)
+    return o, (q, k, v, sel, o, lse)
+
+
+def _flash3_selected_bwd(scale, res, do):
+    q, k, v, sel, o, lse = res
+    dq, dk, dv = _fa_bwd_pallas(
+        q, k, v, do, o, lse, None, None, True, scale, _interpret_default(), sel=sel)
+    return dq, dk, dv, np.zeros(sel.shape, jax.dtypes.float0)
+
+
+_flash3_selected.defvjp(_flash3_selected_fwd, _flash3_selected_bwd)
+
+
+def _probe_flash_selected(q3, k3, v3, sel, *, scale):
+    """Guard probe of the selected-keys call: its forward and backward kernels."""
+    o, vjp = jax.vjp(lambda q, k, v: _flash3_selected(q, k, v, sel, scale), q3, k3, v3)
+    vjp(jnp.zeros_like(o))
+    return o
 
 
 # --- (o, lse) variant for chunk-merging callers (ring attention) ----------------
@@ -1360,7 +1442,7 @@ def flash_attention_with_lse(q3, k3, v3, *, causal, scale, kv_lens=None,
 
 
 def _attn_jnp(q, k, v, lens, causal, scale, dropout_rate=0.0, dropout_key=None,
-              window=None):
+              window=None, selected=None):
     BH, S, D = q.shape
     Sk = k.shape[1]
     s = jnp.einsum(
@@ -1372,6 +1454,8 @@ def _attn_jnp(q, k, v, lens, causal, scale, dropout_rate=0.0, dropout_key=None,
         masked |= kj[None, :] > jnp.arange(S)[:, None]
     if window is not None:
         masked |= kj[None, :] <= jnp.arange(S)[:, None] - window
+    if selected is not None:    # (B, S, Sk): a batch row's heads share one selection
+        masked |= jnp.repeat(selected == 0, BH // selected.shape[0], axis=0)
     s = jnp.where(masked, _NEG, s)
     m = jnp.max(s, axis=-1, keepdims=True)
     # zero masked slots explicitly: for a fully-masked row s == m == _NEG and
@@ -1420,6 +1504,7 @@ def flash_attention(
     dropout_key: Optional[jax.Array] = None,
     impl: Optional[str] = None,
     window: Optional[int] = None,
+    selected: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Fused scaled-dot-product attention.
 
@@ -1437,6 +1522,16 @@ def flash_attention(
     kernels' grid is then the band of blocks the window reaches and not the
     square (:attr:`TilePlan.band`); a window that holds the whole sequence is
     the plain causal call.
+
+    ``selected`` (with ``causal=True``, and no window, key lengths or dropout):
+    attention over a set of keys per query that arrives at run time — int8
+    ``(B, S, S)``, nonzero at ``[b, t, s]`` where query ``t`` keeps key ``s``, the
+    same for every head; a kept key is never after its query (``s <= t``:
+    ``ops.index_select`` makes such a mask), and every query keeps at least one.
+    The softmax runs over the kept keys only. The kernels walk every causal
+    block and mask each score by the operand's block (named
+    ``flash_attention_sparse_*`` in the device trace); a selection of all causal
+    keys gives the plain causal call's result, bit for bit. It passes no gradient.
 
     ``dropout_rate``/``dropout_key``: attention-probability dropout in
     torch's softmax->dropout->matmul order (ref:
@@ -1470,6 +1565,13 @@ def flash_attention(
         )
     scale = float(scale) if scale is not None else 1.0 / (D ** 0.5)
     window = _checked_window(window, causal, S)
+    if selected is not None:
+        if not causal or window is not None or kv_lens is not None or dropout_rate > 0.0:
+            raise ValueError("selected= needs causal=True and takes no window, kv_lens or "
+                             "dropout")
+        if selected.shape != (B, S, Sk):
+            raise ValueError(f"selected must be (B, S, Sk) = {(B, S, Sk)}, got {selected.shape}")
+        selected = selected.astype(jnp.int8)
     # a windowed call's probe key and kernels carry the window; a call without
     # one passes nothing, and its key and kernels are what they were
     windowed = {} if window is None else {"window": window}
@@ -1521,31 +1623,29 @@ def flash_attention(
             else:
                 seed = jnp.zeros((1,), jnp.int32)
             if not forced:
+                # default-on dispatch is guarded (a forced impl='pallas' keeps the
+                # honor-or-raise contract above); a selected call has its own key
+                if selected is not None:
+                    probe, args, statics = _probe_flash_selected, (q3, k3, v3, selected), {}
+                else:
+                    probe, args = _probe_flash_pallas, (q3, k3, v3, lens_pallas, seed)
+                    statics = dict(causal=causal, rate=float(dropout_rate), **windowed)
                 if 4 * B * H * S * Sk > _ORACLE_SCORE_BYTES_CAP:
                     # no viable oracle at this shape: the jnp fallback would
                     # materialize > budget of fp32 scores through autodiff.
                     # Flash is the only path — book it, skip probe/downgrade.
-                    _count_forced(
-                        "flash_attention", impl,
-                        q3, k3, v3, lens_pallas, seed,
-                        causal=causal, scale=scale, rate=float(dropout_rate),
-                        **windowed,
-                    )
+                    _count_forced("flash_attention", impl, *args, scale=scale, **statics)
                 else:
-                    # default-on dispatch is guarded; a forced impl='pallas'
-                    # keeps the honor-or-raise contract above
-                    impl = _checked_impl(
-                        "flash_attention", impl, _probe_flash_pallas,
-                        q3, k3, v3, lens_pallas, seed,
-                        causal=causal, scale=scale, rate=float(dropout_rate),
-                        **windowed,
-                    )
-        if impl == "pallas":
+                    impl = _checked_impl("flash_attention", impl, probe, *args, scale=scale,
+                                         **statics)
+        if impl == "pallas" and selected is not None:
+            o = _flash3_selected(q3, k3, v3, selected, scale)
+        elif impl == "pallas":
             o = _flash3(q3, k3, v3, lens_pallas, seed, causal, scale,
                         float(dropout_rate), window)
         else:
             o = _attn_jnp(q3, k3, v3, lens_bh, causal, scale,
-                          dropout_rate, dropout_key, window)
+                          dropout_rate, dropout_key, window, selected)
     # remat boundary tag: the attention context is a cheap (B, H, S, Dv)
     # save point vs the O(S^2) score/prob intermediates behind it
     return _checkpoint_name(o.reshape(B, H, S, Dv), _TAG_ATTN_OUT)

@@ -552,6 +552,119 @@ def check_flash_mla(results: list, H: int = 32, S: int = 8192, Dk: int = 192, Dv
     check("ms_a_layer_by_block", any(isinstance(v, dict) for v in ms.values()), json.dumps(ms))
 
 
+def _index_operands(S, heads=16, d=64, key=7):
+    """Seeded indexer operands ``(q (1, S, heads, d) bf16, k (1, S, d) bf16,
+    w (1, S, heads) float32)`` at unit scale."""
+    ks = jax.random.split(jax.random.PRNGKey(key), 3)
+    q = jax.random.normal(ks[0], (1, S, heads, d)).astype(jnp.bfloat16)
+    k = jax.random.normal(ks[1], (1, S, d)).astype(jnp.bfloat16)
+    return q, k, jax.random.normal(ks[2], (1, S, heads)) * (heads * d) ** -0.5
+
+
+def check_index_select(results: list, lengths=(2048, 8192), topk: int = 2048) -> None:
+    """The indexer's kernel (``ops.indexer``), compiled, at the Keye cell's call
+    (16 heads of 64 on one key, bfloat16 operands, ``topk`` 2,048): its selected
+    set against float32 ``lax.top_k`` on the same operands — the share of pairs
+    that agree, the count kept (exact: ``selected_pairs``), and the largest
+    ``|I - I_ref|`` over the scores' spread where the reference's ``I`` is float32
+    at ``highest`` — and ms a layer beside the jnp form's."""
+    from beforeholiday_tpu.monitor.roofline import _resolve_chip
+    from beforeholiday_tpu.ops import indexer as X
+
+    def check(name, cond, info=""):
+        results.append((f"index_select/{name}", bool(cond), str(info)))
+
+    peak = _resolve_chip(None).peak_tflops * 1e12
+    for S in lengths:
+        q, k, w = _index_operands(S)
+        k_of = min(topk, S)
+        got = jax.jit(lambda q, k, w: X.index_select(q, k, w, topk=k_of, impl="pallas"))(q, k, w)
+        want = jax.jit(lambda q, k, w: X.index_select(q, k, w, topk=k_of, impl="jnp"))(q, k, w)
+        kept, agree = int(jnp.sum(got, dtype=jnp.int32)), float(jnp.mean(got == want))
+        check(f"S{S}/pairs", kept == X.selected_pairs(S, k_of),
+              f"kept {kept} of {X.selected_pairs(S, k_of)}")
+        # the two forms sum a product's 64 terms in different orders: a key within a
+        # float32 rounding of a row's topk-th score may fall on either side
+        check(f"S{S}/agrees_with_top_k", agree >= 1.0 - 1e-5,
+              f"share of pairs agreeing {agree:.9f} ({int(jnp.sum(got != want))} differ)")
+        with jax.default_matmul_precision("highest"):
+            f32 = lambda t: t.astype(jnp.float32)
+            rows = slice(S - min(S, 512), S)
+            ref = jax.jit(lambda q, k, w: X.index_scores(f32(q), f32(k), w, rows))(q, k, w)
+        mine = jax.jit(lambda q, k, w: X.index_scores(q, k, w, rows))(q, k, w)
+        gap, spread = float(jnp.max(jnp.abs(mine - ref))), float(jnp.std(ref))
+        check(f"S{S}/scores_vs_float32", gap <= 1e-2 * spread,
+              f"max|I - I_ref| {gap:.3e} over a spread of {spread:.3e}")
+        ms = {}
+        for impl in ("pallas", "jnp"):
+            fn = jax.jit(lambda q, k, w, impl=impl: X.index_select(q, k, w, topk=k_of, impl=impl))
+            ms[impl] = round(1e3 * _min_step_seconds(lambda _: fn(q, k, w), None), 3)
+        flops = 2 * 16 * 64 * S * (S + 1) / 2
+        ms["pallas_pct_of_peak"] = round(100 * flops / peak / (1e-3 * ms["pallas"]), 2)
+        check(f"S{S}/ms_a_layer", True, json.dumps(ms))
+
+
+def check_flash_sparse(results: list, H: int = 32, D: int = 128, parity=(2048,),
+                       timed=(8192,), topk: int = 2048) -> None:
+    """The selected-keys form of the flash kernels (``flash_attention(selected=)``),
+    compiled, at the Keye cell's call ``(1, H, S, D)`` bfloat16 under a selection
+    the indexer's kernel makes from seeded operands: ``o, dq, dk, dv`` against the
+    jnp path at lengths whose scores the oracle can hold, then ms a layer of the
+    forward and of the fused backward at the cell's length beside the plain
+    causal call's, with the share of the bf16 peak the SELECTED pairs' operations
+    reach."""
+    from beforeholiday_tpu.monitor.roofline import _resolve_chip
+    from beforeholiday_tpu.ops import attention as A, indexer as X
+
+    def check(name, cond, info=""):
+        results.append((f"flash_sparse/{name}", bool(cond), str(info)))
+
+    bf, scale = jnp.bfloat16, D ** -0.5
+
+    def inputs(S):
+        ks = jax.random.split(jax.random.PRNGKey(42), 4)
+        qkv = tuple(jax.random.normal(k, (1, H, S, D)).astype(bf) for k in ks)
+        sel = X.index_select(*_index_operands(S), topk=min(topk, max(S // 4, 1)))
+        return qkv + (sel,)
+
+    def both(impl):
+        def run(q, k, v, do, sel):
+            o, pull = jax.vjp(lambda *a: A.flash_attention(
+                *a, causal=True, scale=scale, impl=impl, selected=sel), q, k, v)
+            return (o,) + pull(do)
+        return jax.jit(run)
+
+    for S in parity:
+        args = inputs(S)
+        got, want = both("pallas")(*args), both("jnp")(*args)
+        for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+            a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+            gap, size = float(jnp.max(jnp.abs(a - b))), float(jnp.max(jnp.abs(b)))
+            ok = bool(jnp.all(jnp.isfinite(a))) and gap <= 3e-2 * size
+            check(f"S{S}/parity/{name}", ok, f"shape {a.shape} max|d|={gap:.3e} of {size:.3e}")
+
+    peak = _resolve_chip(None).peak_tflops * 1e12
+    interpret = A._interpret_default()              # False on the chip
+    for S in timed:
+        q, k, v, do, sel = inputs(S)
+        q, k, v, do = (t[0] for t in (q, k, v, do))
+        flops = 2 * H * 2 * D * int(jnp.sum(sel, dtype=jnp.int32))     # forward; backward twice
+        ms = {}
+        for name, mask in (("sparse", sel), ("causal", None)):
+            fwd = jax.jit(lambda q, k, v, mask=mask: A._fa_fwd_pallas(
+                q, k, v, None, True, scale, interpret, sel=mask))
+            o, lse = fwd(q, k, v)
+            bwd = jax.jit(lambda q, k, v, do, o, lse, mask=mask: A._fa_bwd_pallas(
+                q, k, v, do, o, lse, None, None, True, scale, interpret, sel=mask))
+            t_f = _min_step_seconds(lambda _: fwd(q, k, v), None)
+            t_b = _min_step_seconds(lambda _: bwd(q, k, v, do, o, lse), None)
+            ms[name] = {"fwd_ms": round(1e3 * t_f, 3), "bwd_ms": round(1e3 * t_b, 3)}
+            if mask is not None:
+                ms[name].update(fwd_pct_of_peak=round(100 * flops / peak / t_f, 1),
+                                bwd_pct_of_peak=round(100 * 2 * flops / peak / t_b, 1))
+        check(f"S{S}/ms_a_layer", True, json.dumps(ms))
+
+
 # (heads, S, Dk, Dv) of the four 8k cells' causal calls without a window
 _FUSED_SHAPES = ((32, 8192, 192, 128), (32, 8192, 64, 64), (32, 8192, 128, 128),
                  (16, 8192, 256, 256))
@@ -1344,6 +1457,7 @@ def main() -> int:
     results: list = []
     for group in (check_flash_dropout, check_flash_tiles, check_wy_prepare, check_deltanet,
                   check_short_conv, check_flash_mla, check_flash_fused, check_flash_fwd_live,
+                  check_index_select, check_flash_sparse,
                   check_grouped_matmul, check_moe_rows, check_ssd, check_aliased_mt_kernels,
                   check_compiled_kernel_parity):
         try:

@@ -1509,3 +1509,166 @@ class TestLiveBlocks:
 
         assert "lambda" not in inspect.getsource(A._fa_bwd_blocks).replace("spec = lambda", "")
         assert "_block_maps(plan)" in inspect.getsource(A._fa_bwd_blocks)
+
+
+# ---------------------------------------------------------------------------------
+# the selected-keys form: a set of keys per query that arrives at run time (PR 46)
+# ---------------------------------------------------------------------------------
+
+def _selection(key, B, S, keep):
+    """A seeded selection ``(B, S, S)`` int8: query ``t`` keeps ``min(t + 1,
+    keep)`` keys ``s <= t``, scattered (the largest of a random score), so that
+    whole rows of a block can be empty and a query may not keep its own key."""
+    scores = jax.random.normal(key, (B, S, S))
+    causal = jnp.tril(jnp.ones((S, S), bool))
+    _, idx = jax.lax.top_k(jnp.where(causal, scores, -jnp.inf), min(keep, S))
+    sel = jnp.zeros((B, S, S), bool).at[
+        jnp.arange(B)[:, None, None], jnp.arange(S)[None, :, None], idx].set(True)
+    return (sel & causal).astype(jnp.int8)
+
+
+def _selected_oracle(q, k, v, sel, scale):
+    """Materialised scores in float32, the softmax over the kept keys only."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32)) * scale
+    p = jax.nn.softmax(jnp.where(sel[:, None] != 0, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)
+
+
+def _all_four(f, q, k, v, w):
+    o, pull = jax.vjp(f, q, k, v)
+    return (o,) + pull(w)
+
+
+class TestSelected:
+    """``flash_attention(selected=)``: forward, the fused backward of one block,
+    the fused backward of several blocks and the dq + dkv pair, in the interpreter,
+    against the jnp oracle and a materialised softmax over the kept keys."""
+
+    # (S, D, keep, which backward the plan takes)
+    SHAPES = ((256, 64, 40, "dqkv"), (2048, 64, 300, "dqkv_blocks"), (384, 128, 1, "dqkv_blocks"))
+
+    @pytest.mark.parametrize("S,D,keep,kernel", SHAPES,
+                             ids=[f"S{s}-D{d}-keep{n}" for s, d, n, _ in SHAPES])
+    def test_o_dq_dk_dv_against_the_oracles(self, S, D, keep, kernel):
+        from beforeholiday_tpu import monitor
+        from beforeholiday_tpu.guard import dispatch as gd
+
+        B, H = (1, 2) if S > 1024 else (2, 2)
+        q, k, v = _qkv(jax.random.PRNGKey(S), B=B, H=H, S=S, D=D)
+        w = jax.random.normal(jax.random.PRNGKey(1), q.shape)
+        sel = _selection(jax.random.PRNGKey(2), B, S, keep)
+        scale = D ** -0.5
+        gd.reset_dispatch_counters()
+        run = lambda impl: _all_four(lambda q, k, v: A.flash_attention(
+            q, k, v, causal=True, selected=sel, impl=impl), q, k, v, w)
+        got, want = run("pallas"), run("jnp")
+        plain = _all_four(lambda q, k, v: _selected_oracle(q, k, v, sel, scale), q, k, v, w)
+        for name, a, b, c in zip(("o", "dq", "dk", "dv"), got, want, plain):
+            # one kept key: dq and dk are zero in exact arithmetic; the inputs' scale is 1
+            size = max(float(jnp.max(jnp.abs(c))), 1.0)
+            assert float(jnp.max(jnp.abs(a - b))) <= 2e-5 * size, name
+            assert float(jnp.max(jnp.abs(a - c))) <= 2e-5 * size, name
+        booked = {r["kernel"] for r in monitor.tile_records() if "selected" in r["key"]}
+        assert booked == {"fwd", kernel}, booked
+        gd.reset_dispatch_counters()
+
+    def test_the_two_call_backward_takes_the_operand_too(self, monkeypatch):
+        """A head whose float32 dq does not fit the fused kernel's VMEM budget
+        takes dq + dkv, each under the block of kept keys its own maps name."""
+        S, D = 512, 64
+        monkeypatch.setattr(A, "_block_size", lambda s, *w: 128)
+        monkeypatch.setattr(A, "_HEAD_DQ_BYTES", S * D * 4 - 1)
+        A._tile_plan.cache_clear()
+        try:
+            q, k, v = _qkv(jax.random.PRNGKey(3), B=1, H=2, S=S, D=D)
+            w = jax.random.normal(jax.random.PRNGKey(1), q.shape)
+            sel = _selection(jax.random.PRNGKey(2), 1, S, 70)
+            f = lambda q, k, v: A.flash_attention(q, k, v, causal=True, selected=sel,
+                                                  impl="pallas")
+            names = [e.params["name"] for e in _pallas_eqns(
+                jax.make_jaxpr(lambda *a: _all_four(f, *a, w))(q, k, v).jaxpr)]
+            assert names == [f"flash_attention_sparse_{n}" for n in ("fwd", "dq", "dkv")]
+            got = _all_four(f, q, k, v, w)
+            want = _all_four(lambda q, k, v: _selected_oracle(q, k, v, sel, D ** -0.5), q, k, v, w)
+            for a, b in zip(got, want):
+                assert float(jnp.max(jnp.abs(a - b))) <= 2e-5 * float(jnp.max(jnp.abs(b)))
+        finally:
+            A._tile_plan.cache_clear()
+
+    @pytest.mark.parametrize("S", (256, 2048), ids=("one-block", "several-blocks"))
+    def test_a_selection_of_all_causal_keys_is_the_plain_causal_call_bit_for_bit(self, S):
+        B, H = 1, 2
+        q, k, v = _qkv(jax.random.PRNGKey(S + 1), B=B, H=H, S=S, D=64)
+        w = jax.random.normal(jax.random.PRNGKey(1), q.shape)
+        everything = jnp.broadcast_to(jnp.tril(jnp.ones((S, S), jnp.int8)), (B, S, S))
+        run = lambda sel: _all_four(lambda q, k, v: A.flash_attention(
+            q, k, v, causal=True, selected=sel, impl="pallas"), q, k, v, w)
+        for name, a, b in zip(("o", "dq", "dk", "dv"), run(everything), run(None)):
+            assert bool(jnp.array_equal(a, b)), name
+
+    def test_the_heads_of_a_batch_row_share_its_selection_and_rows_differ(self):
+        """``sel[b]`` serves every head of row ``b`` and no other row."""
+        B, H, S = 2, 3, 256
+        q, k, v = _qkv(jax.random.PRNGKey(9), B=B, H=H, S=S, D=64)
+        sel = _selection(jax.random.PRNGKey(2), B, S, 30)
+        assert not bool(jnp.array_equal(sel[0], sel[1]))
+        got = A.flash_attention(q, k, v, causal=True, selected=sel, impl="pallas")
+        for b in range(B):
+            one = A.flash_attention(q[b:b + 1], k[b:b + 1], v[b:b + 1], causal=True,
+                                    selected=sel[b:b + 1], impl="pallas")
+            assert bool(jnp.array_equal(got[b], one[0]))
+
+    def test_plan_names_fill_and_counts(self):
+        plain = A._tile_plan(8192, 8192, 128, True, None, 128)
+        plan = A._tile_plan(8192, 8192, 128, True, None, 128, True)
+        assert plan == plain._replace(selected=True) and plan.live_axis and not plain.selected
+        assert A._bwd_of(plan, 128) is A._fa_bwd_blocks
+        assert [A._kernel_name(plan, n) for n in ("fwd", "dqkv_blocks")] == [
+            "flash_attention_sparse_fwd", "flash_attention_sparse_dqkv_blocks"]
+        assert A._kernel_name(plain, "fwd") is None
+        assert A._fill(plan) == 2 * A._NEG and A._fill(plain) == A._NEG
+        # every causal tile is walked, and every one of them through the operand's mask
+        counts, base = plan.counts(False, True), plain.counts(False, True)
+        assert {k: counts[k] for k in ("total", "live", "steps", "copies")} == \
+            {k: base[k] for k in ("total", "live", "steps", "copies")}
+        assert counts["masked"] == counts["live"] == 528 and base["masked"] == 32
+        # the kept keys' block and its int32 copy are in what the fused backward asks for
+        extra = A._blocks_vmem_bytes(plan, 128, 128, 2) - A._blocks_vmem_bytes(plain, 128, 128, 2)
+        assert extra == 2 * 1024 * 1024 + 4 * 1024 * 1024
+
+    def test_a_call_without_a_selection_traces_what_it_traced(self, monkeypatch):
+        """``selected=None`` hands the kernels no operand: the same operands, grid
+        and (scope-given) names as a call that does not name the argument."""
+        monkeypatch.setattr(A, "_resolve_impl", lambda impl: "pallas")
+        q, k, v = _qkv(jax.random.PRNGKey(5), B=1, H=2, S=512, D=64)
+        f = lambda kw: jax.make_jaxpr(jax.grad(
+            lambda q: jnp.sum(A.flash_attention(q, k, v, causal=True, **kw))))(q)
+        a, b = f({}), f({"selected": None})
+        assert str(a) == str(b)
+        assert all(e.params["name"] is None for e in _pallas_eqns(a.jaxpr))
+
+    def test_what_a_selected_call_refuses(self):
+        q, k, v = _qkv(jax.random.PRNGKey(5), B=1, H=1, S=128, D=64)
+        sel = jnp.ones((1, 128, 128), jnp.int8)
+        for kw in ({"causal": False}, {"causal": True, "window": 64},
+                   {"causal": True, "kv_lens": jnp.asarray([100])},
+                   {"causal": True, "dropout_rate": 0.1, "dropout_key": jax.random.PRNGKey(0)}):
+            with pytest.raises(ValueError, match="selected="):
+                A.flash_attention(q, k, v, selected=sel, **kw)
+        with pytest.raises(ValueError, match=r"\(B, S, Sk\)"):
+            A.flash_attention(q, k, v, causal=True, selected=sel[:, :64])
+
+    def test_the_guard_probes_a_selected_call_under_its_own_key(self, monkeypatch):
+        from beforeholiday_tpu.guard import dispatch as gd
+
+        monkeypatch.setattr(A, "_resolve_impl", lambda impl: "pallas")
+        q, k, v = _qkv(jax.random.PRNGKey(5), B=1, H=2, S=256, D=64)
+        sel = _selection(jax.random.PRNGKey(2), 1, 256, 30)
+        gd.clear_probe_cache("flash_attention")
+        gd.reset_dispatch_counters()
+        A.flash_attention(q, k, v, causal=True, selected=sel)
+        (key,) = [key for key in gd.dispatch_counters() if key[0] == "flash_attention"]
+        sig = ((2, 256, 64), "float32")
+        assert key[2] == (sig, sig, sig, ((1, 256, 256), "int8")) and key[3] == (("scale", "0.125"),)
+        assert gd.dispatch_counters()[key]["pallas"] == 1
+        gd.reset_dispatch_counters()

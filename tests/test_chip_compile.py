@@ -424,6 +424,56 @@ def test_the_forward_on_its_live_blocks_compiles_for_the_chip(
     assert not re.search(r"= [a-z0-9]+\[[\d,]*\]\S* pad\(", text)
 
 
+@pytest.mark.parametrize("B,H,S,D,calls", (
+    (1, 32, 8192, 128, ("fwd", "dqkv_blocks")),     # the Keye cell: a head is 8 x 8 blocks
+    (2, 4, 1024, 128, ("fwd", "dqkv")),             # a head is one block; two batch rows
+    (1, 4, 16384, 256, ("fwd", "dq", "dkv")),       # a head too long for the fused kernel's VMEM
+), ids=("keye_cell", "one_block", "two_calls"))
+def test_the_selected_keys_flash_kernels_compile_for_the_chip(one_chip, flash_compiled,
+                                                              B, H, S, D, calls):
+    """``flash_attention(selected=)``: the forward and each backward the plan can
+    take, with the kept keys' int8 ``(1, bq, bk)`` block as one more operand
+    (``ops/attention.py:_sel_spec``) — what the interpreter cannot refuse: an int8
+    block Mosaic cannot tile, its widening, the VMEM of the fused backward."""
+    A = flash_compiled
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    x = shape((B, H, S, D), jnp.bfloat16)
+
+    def both(q, k, v, sel, do):
+        o, pull = jax.vjp(lambda *a: A.flash_attention(
+            *a, causal=True, selected=sel, impl="pallas"), q, k, v)
+        return o, pull(do)
+
+    text = jax.jit(both).lower(x, x, x, shape((B, S, S), jnp.int8), x).compile().as_text()
+    assert text.count("tpu_custom_call") == len(calls)
+    for kernel in calls:
+        assert f"flash_attention_sparse_{kernel}" in text
+
+
+@pytest.mark.parametrize("B,S,Hi,d,topk", (
+    (1, 8192, 16, 64, 2048),                        # the Keye cell
+    (2, 1000, 4, 32, 100),                          # a sequence that is padded
+), ids=("keye_cell", "ragged"))
+def test_the_index_select_kernel_compiles_for_the_chip(one_chip, monkeypatch, B, S, Hi, d, topk):
+    """``ops/indexer.py``: scores, the bisection and the int8 mask in one kernel,
+    a ``(256, S)`` int32 scratch in VMEM under its own limit."""
+    from beforeholiday_tpu.ops import indexer as X
+
+    monkeypatch.setattr(X, "_interpret_default", lambda: False)
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    try:
+        compiled = jax.jit(lambda q, k, w: X.index_select(q, k, w, topk=topk, impl="pallas")).lower(
+            shape((B, S, Hi, d), jnp.bfloat16), shape((B, S, d), jnp.bfloat16),
+            shape((B, S, Hi), jnp.float32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "index_select" in text
+    assert f"s8[{B},{S},{S}]" in text
+
+
 def test_same_step_reads_two_texts_as_one_program_when_only_source_lines_moved(
         one_chip, flash_compiled, tmp_path):
     """``tools/same_step.py`` on a compiled flash forward + backward: source

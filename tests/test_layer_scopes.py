@@ -850,3 +850,113 @@ def test_the_mixers_glue_is_what_its_metric_reads(dsv3_names):
     assert kinds & {"concatenate", "mul", "broadcast_in_dim", "reduce_sum", "slice", "transpose"}
     under = [n for n in dsv3_names if "mla_mixer" in n]
     assert any(n.endswith("dot_general") for n in under) and len(glue) < len(under)
+
+
+# ---------------------------------------------------------------------------
+# family keye_vl2 (PR 46): sparse_mixer with the indexer's two scopes and the
+# selected-keys flash kernels inside it, the MoE and the model's four scopes
+# ---------------------------------------------------------------------------
+
+_KEYE_MODEL = ("keye_vl2_embed", "keye_vl2_layers", "keye_vl2_head", "keye_vl2_loss")
+_KEYE_SCOPES = _KEYE_MODEL + (
+    "sparse_mixer", "indexer_proj", "indexer_select", "index_select", "amp_forward",
+    "amp_backward", "amp_unscale", "fused_adam_step_flat", "layer_norm", "flash_attention",
+    "moe_route", "moe_dispatch", "moe_experts", "moe_combine")
+
+
+@pytest.fixture(scope="module")
+def keye_names():
+    """The distinct ``op_name`` of every op of the compiled tiny-keye-vl2 step."""
+    from benchmark import run as bench_run
+
+    cell = bench_run.load("workloads", "tiny-keye-vl2.train")
+    run = bench_run.Cell(cell, bench_run.load("configs", cell["config"]), jax.devices()[:1])
+    run.start(7)
+    run.build()
+    compiled = run.program.step.jitted.lower(run.state, run.pool[0]).compile()
+    return sorted(set(re.findall(r'op_name="(jit\([^"]+)"', compiled.as_text())))
+
+
+@pytest.mark.parametrize("scope", _KEYE_SCOPES)
+def test_keye_vl2_scope_is_in_the_compiled_step(keye_names, scope):
+    assert any(scope in _scopes_of(n) or f"jvp({scope})" in n for n in keye_names), scope
+
+
+def test_keye_vl2_first_level_scopes_partition_the_step(keye_names):
+    first = ("amp_backward", "amp_unscale", "ddp_reduce_gradients",
+             "ddp_overlap_hook", "fused_adam_step_flat")
+    twice = [n for n in keye_names
+             if sum(s in n for s in first) + (_pass_of(n) == "amp_forward") > 1]
+    assert not twice
+    both = [n for n in keye_names if "amp_forward" in n and "amp_backward" in n]
+    assert all("amp_backward/transpose(amp_forward)" in n for n in both), both
+    for scope in _KEYE_MODEL:        # the model's scopes survive inside both passes
+        assert any(f"amp_forward/jvp({scope})" in n for n in keye_names), scope
+        assert any(_pass_of(n) == "amp_backward" and f"jvp({scope})" in n
+                   for n in keye_names), scope
+
+
+def test_keye_vl2_second_level_scopes_do_not_overlap(keye_names):
+    """An op is under one model scope at most, and under the mixer or the MoE at
+    most; ``indexer_proj``, ``indexer_select`` and ``flash_attention`` lie inside
+    ``sparse_mixer`` and not inside each other, all inside ``keye_vl2_layers``;
+    the indexer runs forward only: nothing of it is in the backward pass; no name
+    of the family holds another family's metric pattern."""
+    in_path = lambda s, n: any(s == part.strip("()").split("(")[-1] for part in _scopes_of(n))
+    for n in keye_names:
+        assert sum(f"({s})" in n or s in _scopes_of(n) for s in _KEYE_MODEL) <= 1, n
+        parts = [s for s in ("sparse_mixer", "moe") if in_path(s, n)]
+        assert len(parts) <= 1, n
+        if parts and _pass_of(n):
+            assert "keye_vl2_layers" in n, n
+        inner = [s for s in ("indexer_proj", "indexer_select", "flash_attention")
+                 if in_path(s, n)]
+        assert len(inner) <= 1, n
+        if inner:
+            assert parts == ["sparse_mixer"], n
+        if in_path("index_select", n):
+            assert inner == ["indexer_select"], n
+        if inner and inner[0].startswith("indexer"):
+            assert _pass_of(n) == "amp_forward", n
+        assert not re.search(r"gated_delta|ssd|window_mixer|full_mixer|ssm_mixer|attn_mixer|"
+                             r"conv_mixer|short_conv|moe_latent|moe_shared|linear_mixer|mla_", n), n
+    heavy = [n for n in keye_names if n.endswith("dot_general")]
+    assert heavy and not [n for n in heavy if _pass_of(n) is None]
+    # the indexer's three products and its LayerNorm are under indexer_proj
+    proj = [n for n in keye_names if in_path("indexer_proj", n)]
+    assert any(n.endswith("dot_general") for n in proj)
+    assert any(in_path("layer_norm", n) for n in proj)
+
+
+def test_the_sparse_kernels_carry_their_names_under_the_mixer_in_both_passes():
+    """``flash_sparse_ms`` / ``flash_sparse_roofline`` read the kernels' own
+    ``name=`` (the chip prints ``%flash_attention_sparse_fwd.N``, ..),
+    ``index_select_ms`` / ``index_select_roofline`` the indexer's
+    (``%index_select.N``): the forward kernel and the indexer's lie under
+    ``amp_forward``, the backward one under ``amp_backward``, all under
+    ``sparse_mixer``, the flash kernels under ``flash_attention`` too (so
+    ``flash_attn_ms`` reads them)."""
+    from beforeholiday_tpu.models import keye_vl2
+
+    cfg = keye_vl2.KeyeVL2Config(attention_impl="pallas", dtype=jnp.bfloat16,
+                                 sa_config=keye_vl2.SparseAttentionConfig(topk=40))
+    params = keye_vl2.init(jax.random.PRNGKey(0), cfg)
+    p = params["layers"][0]
+    x = jnp.zeros((1, 256, cfg.hidden_size), jnp.bfloat16)
+    tables = keye_vl2.rotary_tables(cfg, 256)
+    svag = amp.scaled_value_and_grad(
+        lambda p, x: jnp.sum(keye_vl2.attention(cfg, x, p, tables)[0].astype(jnp.float32)),
+        LossScaler(loss_scale=1.0))
+    text = jax.jit(svag).lower(p, LossScaler(loss_scale=1.0).init(), x).as_text(debug_info=True)
+    names = set(re.findall(r'loc\("([^"]+)"', text))
+    kernels = {k: [n for n in names if f"/{k}/pallas_call" in n]
+               for k in ("flash_attention_sparse_fwd", "flash_attention_sparse_dqkv",
+                         "index_select")}
+    assert all(kernels.values()), {k: len(v) for k, v in kernels.items()}
+    for k, found in kernels.items():
+        for n in found:
+            assert "sparse_mixer" in n, n
+            assert ("flash_attention/" in n) == k.startswith("flash"), n
+            assert ("indexer_select" in n) == (k == "index_select"), n
+            assert not re.search(r"grouped_matmul|/moe/|window", n), n
+    assert "flash_attention_window" not in text

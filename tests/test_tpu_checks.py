@@ -207,3 +207,42 @@ def test_the_flash_fwd_live_check_runs_its_comparison():
     assert defaults["timed"].default == tpu_checks._FUSED_SHAPES
     assert defaults["two_calls"].default == (32, 8192, 192, 128)
     assert "check_flash_fwd_live" in inspect.getsource(tpu_checks.main)
+
+
+def test_the_index_select_check_runs_its_comparison():
+    """The chip check of ``ops.indexer`` at toy lengths on the interpreter (one
+    of them padded): the kept pairs are counted, the selection is held to
+    ``lax.top_k``'s and the scores to float32's (the timings are not judged
+    here), and ``main`` runs the group."""
+    import inspect
+
+    results = []
+    tpu_checks.check_index_select(results, lengths=(200, 256), topk=48)
+    by_name = {name: (ok, info) for name, ok, info in results}
+    compared = ("pairs", "agrees_with_top_k", "scores_vs_float32")
+    assert set(by_name) == {f"index_select/S{S}/{k}" for S in (200, 256)
+                            for k in compared + ("ms_a_layer",)}
+    for name, (ok, info) in by_name.items():
+        assert ok, (name, info)
+    assert "kept 8472 of 8472" in by_name["index_select/S200/pairs"][1]
+    assert "check_index_select" in inspect.getsource(tpu_checks.main)
+
+
+def test_the_flash_sparse_check_runs_its_comparison():
+    """The chip check of the selected-keys flash kernels at a toy length on the
+    interpreter: the output and the three cotangents under a selection the
+    indexer's kernel made are compared with the jnp path's, the sparse call is
+    timed beside the plain causal one, and ``main`` runs the group."""
+    import inspect
+    import json
+
+    results = []
+    tpu_checks.check_flash_sparse(results, H=2, D=64, parity=(256,), timed=(256,), topk=48)
+    by_name = {name: (ok, info) for name, ok, info in results}
+    compared = tuple(f"S256/parity/{k}" for k in ("o", "dq", "dk", "dv"))
+    assert set(by_name) == {f"flash_sparse/{k}" for k in compared + ("S256/ms_a_layer",)}
+    for k in compared:
+        assert by_name[f"flash_sparse/{k}"][0], by_name
+    read = json.loads(by_name["flash_sparse/S256/ms_a_layer"][1])
+    assert set(read) == {"sparse", "causal"} and "fwd_pct_of_peak" in read["sparse"]
+    assert "check_flash_sparse" in inspect.getsource(tpu_checks.main)
