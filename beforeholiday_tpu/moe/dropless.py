@@ -31,10 +31,19 @@ buffer and leave it through :func:`gather_rows` and :func:`scatter_add_rows`,
 loops over row tiles whose trip count is the rows that landed (a device scalar),
 where XLA's static gather and scatter-add move every row of the buffer. What a
 tighter bound still saves is memory, in proportion, and the experts' elementwise
-work (``silu``, the products, the converts) over the empty rows. A buffer that
-is full (every expert held and no bound: every choice lands) walks every tile
-and pays the loop on top: 25 % more than the static ops at 16,384 x 2,048, 40 %
-at 8,192 x 1,024, nothing at 24,576 x 2,304 (:func:`_row_tile`; PERF.md, PR 34).
+work (``silu``, the products, the converts) over the empty rows. **On the TPU
+the two sums (the combine's, and the dispatch's transpose) are no scatter-add**
+(PR 47): :func:`token_order` lists the landed rows by tile of 256 tokens once
+a layer, each sum moves its rows into that list with the gather loop and
+``ops/segment_sum.py`` adds a tile's rows as a one-hot product on the MXU, in
+the loop's arithmetic but for the order of a token's float32 additions; XLA's
+scatter-add cost 96-145 ns a row and was more than half of the sort's two
+sides. Off the TPU, under GSPMD and off the kernel's shapes (``D`` not whole
+lane tiles, a dtype other than bfloat16 / float32) the scatter-add loop stays,
+and is the oracle. A buffer that is full (every expert held and no bound: every
+choice lands) walks every tile and paid the loops 25-40 % over the static ops
+at the smaller buffers (PR 34); with the sums by token a layer's four movements
+take 5.71 ms against the loops' 7.70 and the static ops' 8.38 at 24,576 x 2,304, 2.74 against 3.81 and 4.01 at 12,288 x 2,048 (:func:`_row_tile`; PERF.md, PRs 34 and 47).
 
 **Two expert forms, two routers.** The form is a property of the parameters:
 with a ``w_gate`` an expert is SwiGLU's three matrices, ``(silu(x W_g) * x W_u)
@@ -63,6 +72,12 @@ from jax import lax
 from beforeholiday_tpu.monitor.counters import book_tiles as _book_tiles
 from beforeholiday_tpu.monitor.spans import span as _span
 from beforeholiday_tpu.ops.grouped_matmul import grouped_matmul as _grouped_matmul
+from beforeholiday_tpu.ops.segment_sum import (
+    TokenOrder,
+    segment_sum as _segment_sum,
+    token_order,
+    unwritten_like as _unwritten_like,
+)
 
 __all__ = [
     "dropless_experts",
@@ -74,6 +89,7 @@ __all__ = [
     "scatter_add_rows",
     "shared_expert",
     "swiglu",
+    "token_order",
 ]
 
 _F32 = jnp.float32
@@ -161,7 +177,29 @@ def _row_tile(D: int) -> int:
     are within 4 % of each other everywhere; at 2048 rows a float32 tile of 2304
     columns no longer stays where the smaller ones do and a row costs 2.5 x. So:
     1024 rows up to these widths (a wider row would want fewer: hold ``tile x D``
-    near 2.4 M elements); the callers cap the tile at the buffer."""
+    near 2.4 M elements); the callers cap the tile at the buffer.
+
+    Since PR 47 the two sums are taken by token (``ops/segment_sum.py``) and the
+    loops left are gathers, at this tile. ``moe_rows/*`` then, ms a layer for
+    the four movements and the token order as ONE program (a call alone costs
+    the chip machine's host 0.2 ms), rows in = the cell's / ``R / 8`` / ``R``:
+
+    ====================  ========  =====  =============
+    ``R x D``, rows in    one-shot  loops  sums by token
+    ====================  ========  =====  =============
+    24576 x 2304, 16216   8.36      5.43   4.11
+    24576 x 2304,  3072   8.37      1.68   1.50
+    24576 x 2304, 24576   8.38      7.70   5.71
+    12288 x 2048,  8149   4.01      2.72   2.05
+    12288 x 2048,  1536   4.02      1.03   0.93
+    12288 x 2048, 12288   4.01      3.81   2.74
+    ====================  ========  =====  =============
+
+    (the 24,576-row readings the final tree's, the 12,288-row ones its first
+    tree's, which listed the rows in full token order: the two read alike). The two sums alone,
+    loops -> by token: 3.88 -> 2.61 ms (0.67 x) and 1.98 -> 1.22; of the 2.61, the
+    two gathers into the list 0.945 each (58 ns a row), the kernel 0.32 (one
+    pass) and 0.58 (three)."""
     return max(256, min(1024, 2_400_000 // D // 256 * 256))
 
 
@@ -173,15 +211,17 @@ def _trips(n_valid, tile: int):
 # once a shape and served to every layer and program after it from jit's cache
 # (untraced, 32 loop bodies a step cost the chip machine's host 3.5 s of set-up).
 
-@functools.partial(jax.jit, static_argnames=("tile", "out_dtype"))
-def _gather_loop(src, token, n_valid, scale, dot_with, tile: int, out_dtype):
+@functools.partial(jax.jit, static_argnames=("tile", "out_dtype", "fill"))
+def _gather_loop(src, token, n_valid, scale, dot_with, tile: int, out_dtype, fill: bool = True):
     """``(rows (R, D) out_dtype, dots (R,) float32)``: for the tiles that reach
     below ``n_valid``, ``rows[r] = src[token[r]] * scale[r]`` (the product in
     float32, rounded once) and ``dots[r] = sum(src[token[r]] * dot_with[r])``
     in float32, from the same fetch; rows at or past ``n_valid`` are zero in
     both. Either is ``None`` where it was not asked for (``out_dtype`` /
     ``dot_with`` ``None``). Where ``R`` is no multiple of the tile, the last
-    tile is moved back to end at ``R`` and writes some rows a second time."""
+    tile is moved back to end at ``R`` and writes some rows a second time.
+    Without ``fill`` the rows of the tiles the loop never reached are left
+    unwritten, for a reader that stops where the loop stopped."""
     R, D = token.shape[0], src.shape[1]
 
     def body(i, carry):
@@ -201,8 +241,9 @@ def _gather_loop(src, token, n_valid, scale, dot_with, tile: int, out_dtype):
             rows = lax.dynamic_update_slice(rows, got, (start, 0))
         return rows, dots
 
-    init = (None if out_dtype is None else jnp.zeros((R, D), out_dtype),
-            None if dot_with is None else jnp.zeros((R,), _F32))
+    rows = None if out_dtype is None else jnp.zeros((R, D), out_dtype) if fill \
+        else _unwritten_like(src, (R, D), out_dtype)
+    init = (rows, None if dot_with is None else jnp.zeros((R,), _F32))
     return lax.fori_loop(0, _trips(n_valid, tile), body, init)
 
 
@@ -228,45 +269,76 @@ def _scatter_add_loop(rows, token, n_valid, scale, tile: int, out_rows: int, out
     return lax.fori_loop(0, _trips(n_valid, tile), body, jnp.zeros((out_rows, D), out_dtype))
 
 
+def _sum_by_token(rows, n_valid, scale, order, tile: int, out_rows: int, out_dtype):
+    """``out[token[r]] += rows[r] * scale[r]`` over ``r < n_valid`` without a
+    scatter-add, or ``None`` where the caller's loop stays (no ``order``: off the
+    TPU, off the kernel's shapes). The rows that landed are moved into token
+    order by the gather loop — whole rows, the tail selected to zero on the way
+    — and summed a tile of tokens at a time on the MXU (``ops/segment_sum.py``):
+    the terms and their sum in float32 as the loop's, rounded once to
+    ``out_dtype``."""
+    if order is None:
+        return None
+    listed, chunk = order.perm.shape[0], order.token.shape[2]
+    if not 0 <= listed - rows.shape[0] < chunk:
+        raise ValueError(f"this order lists a buffer of {listed} rows (whole chunks of "
+                         f"{chunk}), not one of {rows.shape[0]}")
+    tile = min(tile, listed)
+    # tiles of whole chunks write every chunk that lists a row, and the kernel reads
+    # no other: no zero-fill of the rest
+    by_token = _gather_loop(rows, order.perm, n_valid, None, None, tile, rows.dtype,
+                            fill=tile % chunk != 0)[0]
+    if scale is not None:
+        scale = scale[order.perm] if order.scale is None else order.scale
+    return _segment_sum(by_token, order, out_rows=out_rows, out_dtype=out_dtype, scale=scale)
+
+
 # Each is the other's transpose, and a loop with a trip count the device knows
 # has no transpose of its own. ``static`` is the tile and what the backward
 # needs of an operand it does not keep (its rows, its dtype): no residual is
-# held for a shape.
+# held for a shape. ``order`` is integers, like ``token``: no cotangent.
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _gather(src, token, n_valid, scale, static):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _gather(src, token, n_valid, scale, order, static):
     tile, _, dtype = static
     return _gather_loop(src, token, n_valid, scale, None, tile, dtype)[0]
 
 
-def _gather_fwd(src, token, n_valid, scale, static):
-    return (_gather(src, token, n_valid, scale, static),
-            (None if scale is None else src, token, n_valid, scale))
+def _gather_fwd(src, token, n_valid, scale, order, static):
+    return (_gather(src, token, n_valid, scale, order, static),
+            (None if scale is None else src, token, n_valid, scale, order))
 
 
 def _gather_bwd(static, res, ct):
     tile, src_rows, dtype = static
-    src, token, n_valid, scale = res
-    # a token's rows lie in different tiles: the sum stays float32 across the
-    # trips and is rounded once, as XLA's one-shot scatter-add of bfloat16 is
-    d_src = _scatter_add_loop(ct, token, n_valid, scale, tile, src_rows, _F32).astype(dtype)
+    src, token, n_valid, scale, order = res
+    # the factors an order carries are its scaled sum's (the combine's), not these
+    d_src = _sum_by_token(ct, n_valid, scale, order and order._replace(scale=None), tile,
+                          src_rows, dtype)
+    if d_src is None:
+        # a token's rows lie in different tiles: the sum stays float32 across the
+        # trips and is rounded once, as XLA's one-shot scatter-add of bfloat16 is
+        d_src = _scatter_add_loop(ct, token, n_valid, scale, tile, src_rows, _F32).astype(dtype)
     d_scale = None
     if scale is not None:
         d_scale = _gather_loop(src, token, n_valid, None, ct, tile, None)[1].astype(scale.dtype)
-    return d_src, None, None, d_scale
+    return d_src, None, None, d_scale, None
 
 
 _gather.defvjp(_gather_fwd, _gather_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _scatter_add(rows, token, n_valid, scale, static):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _scatter_add(rows, token, n_valid, scale, order, static):
     tile, out_rows, out_dtype, _ = static
-    return _scatter_add_loop(rows, token, n_valid, scale, tile, out_rows, out_dtype)
+    out = _sum_by_token(rows, n_valid, scale, order, tile, out_rows, out_dtype)
+    if out is None:
+        out = _scatter_add_loop(rows, token, n_valid, scale, tile, out_rows, out_dtype)
+    return out
 
 
-def _scatter_add_fwd(rows, token, n_valid, scale, static):
-    return (_scatter_add(rows, token, n_valid, scale, static),
+def _scatter_add_fwd(rows, token, n_valid, scale, order, static):
+    return (_scatter_add(rows, token, n_valid, scale, order, static),
             (None if scale is None else rows, token, n_valid, scale))
 
 
@@ -274,7 +346,7 @@ def _scatter_add_bwd(static, res, ct):
     tile, _, _, rows_dtype = static
     rows, token, n_valid, scale = res
     d_rows, d_scale = _gather_loop(ct, token, n_valid, scale, rows, tile, rows_dtype)
-    return d_rows, None, None, None if scale is None else d_scale.astype(scale.dtype)
+    return d_rows, None, None, None if scale is None else d_scale.astype(scale.dtype), None
 
 
 _scatter_add.defvjp(_scatter_add_fwd, _scatter_add_bwd)
@@ -285,7 +357,8 @@ def _n_valid(n_valid, R: int):
 
 
 def gather_rows(src: jax.Array, token: jax.Array, n_valid, *,
-                scale: Optional[jax.Array] = None) -> jax.Array:
+                scale: Optional[jax.Array] = None,
+                order: Optional[TokenOrder] = None) -> jax.Array:
     """``(R, D)`` in ``src``'s dtype: row ``r`` is ``src[token[r]]`` (times
     ``scale[r]``, the product in float32 and rounded once) for ``r < n_valid``
     and zero from there on.
@@ -294,27 +367,37 @@ def gather_rows(src: jax.Array, token: jax.Array, n_valid, *,
     scalar. A loop walks the tiles (:func:`_row_tile` rows each) that reach
     below ``n_valid`` and no others: the tail of the buffer costs its zero-fill.
     Its transpose is :func:`scatter_add_rows`, with the sum taken in float32
-    and rounded once to ``src``'s dtype."""
+    and rounded once to ``src``'s dtype; ``order`` is that sum's."""
     R = token.shape[0]
     tile = min(R, _row_tile(src.shape[1]))
     _book("gather", R, src.shape[1], src.dtype, tile)
-    return _gather(src, token, _n_valid(n_valid, R), scale, (tile, src.shape[0], src.dtype))
+    return _gather(src, token, _n_valid(n_valid, R), scale, order,
+                   (tile, src.shape[0], src.dtype))
 
 
 def scatter_add_rows(rows: jax.Array, token: jax.Array, n_valid, *, out_rows: int,
-                     scale: Optional[jax.Array] = None, out_dtype=None) -> jax.Array:
+                     scale: Optional[jax.Array] = None, out_dtype=None,
+                     order: Optional[TokenOrder] = None) -> jax.Array:
     """``(out_rows, D)`` of ``out_dtype`` (``rows``' own by default):
     ``out[token[r]] += rows[r] * scale[r]`` over ``r < n_valid``, a token's rows
     in the order the buffer holds them, the product in float32.
 
     What ``rows`` holds at or past ``n_valid`` is never read into a sum (it may
     be NaN). The same loop as :func:`gather_rows`, whose transpose this is; with
-    a ``scale`` its backward takes the scale's cotangent from the same fetch."""
+    a ``scale`` its backward takes the scale's cotangent from the same fetch.
+
+    ``order``: :func:`token_order` of the same ``token``, ``n_valid`` and
+    ``out_rows`` (one for a layer's two sums), or ``None``. With one the sum is no
+    scatter-add: the landed rows go into token order through the gather loop and
+    are summed a tile of tokens at a time by a one-hot product on the MXU
+    (``ops/segment_sum.py``); a token's terms are then added in another order,
+    in float32 as here, and nothing else differs. An order made with a ``scale``
+    carries it: it must be this ``scale``."""
     R = token.shape[0]
     out_dtype = jnp.dtype(rows.dtype if out_dtype is None else out_dtype)
     tile = min(R, _row_tile(rows.shape[1]))
     _book("scatter_add", R, rows.shape[1], rows.dtype, tile)
-    return _scatter_add(rows, token, _n_valid(n_valid, R), scale,
+    return _scatter_add(rows, token, _n_valid(n_valid, R), scale, order,
                         (tile, out_rows, out_dtype, rows.dtype))
 
 
@@ -355,7 +438,8 @@ def dropless_experts(
     ``experts``: ``w_up`` ``(E_held, D, F)`` and ``w_down`` ``(E_held, F, D)``
     for expert ids ``first_expert .. first_expert + E_held``, and for SwiGLU
     experts ``w_gate`` like ``w_up`` (without it an expert is ``relu^2``).
-    ``impl`` is :func:`~beforeholiday_tpu.ops.grouped_matmul.grouped_matmul`'s.
+    ``impl`` is :func:`~beforeholiday_tpu.ops.grouped_matmul.grouped_matmul`'s
+    and :func:`token_order`'s.
     Returns ``(y (T, D) float32, counters)``."""
     T, D = x.shape
     k = idx.shape[1]
@@ -388,7 +472,11 @@ def dropless_experts(
         # in its outputs AND in the cotangent it hands back for ``xs``: both
         # loops and their transposes select them out (and never walk the tiles
         # past the last row that landed)
-        xs = _settled(gather_rows(x, token, n_valid), jnp.arange(R) < n_valid)
+        # both sums of the layer (the combine's, this gather's transpose) onto the
+        # same tokens: one token order, where the backend and the shapes take it
+        by_token = token_order(token, n_valid, out_rows=T, width=D, dtype=x.dtype,
+                               scale=w_sorted, impl=impl)
+        xs = _settled(gather_rows(x, token, n_valid, order=by_token), jnp.arange(R) < n_valid)
     with _span("moe_experts"):
         dt = x.dtype
         grouped = lambda a, w, out: _grouped_matmul(
@@ -401,7 +489,8 @@ def dropless_experts(
         y = grouped(h.astype(dt), experts["w_down"], dt)      # as a dense layer hands it on
     with _span("moe_combine"):
         # ``w * y`` a tile at a time in float32, summed onto the tokens in float32
-        out = scatter_add_rows(y, token, n_valid, scale=w_sorted, out_rows=T, out_dtype=_F32)
+        out = scatter_add_rows(y, token, n_valid, scale=w_sorted, out_rows=T, out_dtype=_F32,
+                               order=by_token)
     counters = {
         "expert_rows": rows.astype(_F32),
         "expert_load_max_over_mean": jnp.max(counts).astype(_F32) * held

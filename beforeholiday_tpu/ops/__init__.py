@@ -25,6 +25,9 @@
 * ``grouped_matmul`` — rows sorted by expert times each expert's weight panel,
   the panel held in VMEM while its row tiles go by (Pallas; ``jax.lax.ragged_dot``
   off the kernels' shapes): the experts of ``moe/dropless.py``.
+* ``segment_sum`` — rows summed onto their tokens without a scatter-add: the landed
+  rows listed by tile of tokens, then ``onehot @ chunk`` on the MXU a tile at a time
+  (Pallas): the two sums of ``moe/dropless.py`` on the TPU.
 * ``quantized`` — fp8-style quantized matmul with per-tensor delayed scaling
   (the O6 tier; no reference equivalent — Transformer-Engine-shaped departure).
 """

@@ -873,44 +873,61 @@ def check_grouped_matmul(results: list) -> None:
               json.dumps({n: round(t, 3) for n, t in ms.items()}))
 
 
-# (tag, tokens, buffer rows, width, rows that land a layer in the cell: ledger, PR 33)
+# (tag, tokens, buffer rows, width, rows that land a layer in the cell: ledger, PRs 33-46)
 _MOE_ROWS_SHAPES = (
     ("qwen", 8192, 16384, 2048, 5126),
     ("mellum", 8192, 24576, 2304, 16216),
     ("nemotron", 8192, 8192, 1024, 2607),
+    ("kanana", 8192, 9216, 2048, 6067),
+    ("keye", 8192, 12288, 2048, 8149),
+    ("lfm2", 8192, 12288, 2048, 8148),
 )
 _MOE_ROWS_TILES = (512, 1024, 2048)
+_MOE_ROWS_MOVEMENTS = ("dispatch_fwd", "dispatch_bwd", "combine_fwd", "combine_bwd")
+# (tokens a tile, rows a chunk) of the segment sum, beside the one ``plan`` picks
+_MOE_ROWS_PLANS = ((256, 256), (128, 256), (128, 128), (256, 512))
 
 
-def check_moe_rows(results: list) -> None:
-    """The sort's two sides (``moe.dropless.gather_rows`` / ``scatter_add_rows``:
-    loops that stop at the last row that landed), compiled, at the three 8k
-    cells' ``(T, R, D)``: the four row movements of a layer — dispatch forward
-    and backward in bfloat16, combine forward (``w * y`` summed in float32) and
-    backward (``dy`` and ``dw`` from one fetch) — against plain indexing over the
-    whole buffer, with the tail of ``y`` and of the cotangents NaN, at ``n_valid``
-    = the cell's, ``R / 8`` and ``R``; and the ms a layer's four movements take
-    as loops, at three tiles, beside the one-shot form (which walks ``R`` rows
-    whatever landed). Only the chip says what a trip of the loop costs."""
+def check_moe_rows(results: list, shapes=_MOE_ROWS_SHAPES, tiles=_MOE_ROWS_TILES,
+                   plans=_MOE_ROWS_PLANS) -> None:
+    """The sort's two sides (``moe.dropless.gather_rows`` / ``scatter_add_rows``),
+    compiled, at the six 8k cells' ``(T, R, D)``: the four row movements of a
+    layer — dispatch forward and backward in bfloat16, combine forward (``w * y``
+    summed in float32) and backward (``dy`` and ``dw`` from one fetch) — against
+    plain indexing over the whole buffer, with the tail of ``y`` and of the
+    cotangents NaN, at ``n_valid`` = the cell's, ``R / 8`` and ``R``, in both
+    forms: the loops that stop at the last row that landed, and the loops with
+    the two sums taken by token (``token_order``: the landed rows gathered into
+    token order and summed by ``ops.segment_sum``'s one-hot product). And the ms
+    a layer's four movements take (the token order's own sort beside them): the
+    loops at three tiles, the sums by token under a few plans, the one-shot form
+    (which walks ``R`` rows whatever landed). Only the chip says what a trip of
+    the loop, a scatter-added row and a visit of the kernel cost.
+    ``check_moe_rows(results, shapes=(("toy", 256, 512, 128, 200),), tiles=(64,),
+    plans=((128, 128),))`` is its CPU rehearsal (``token_order`` takes the kernel
+    on the TPU only: there the by-token form IS the loop)."""
     from beforeholiday_tpu.moe import dropless
+    from beforeholiday_tpu.ops import segment_sum as seg
 
     def check(name, cond, info=""):
         results.append((f"moe_rows/{name}", bool(cond), str(info)))
 
-    def at_tile(tile, fn):
-        """``fn`` traced with the loops' tile at ``tile`` (``_row_tile`` is read
-        when a movement is traced)."""
+    def patched(module, values, fn):
+        """``fn`` traced with ``module``'s attributes at ``values`` (the tile and
+        the plan are read when a movement is traced)."""
         def traced(*args):
-            kept = dropless._row_tile
-            dropless._row_tile = lambda D: tile
+            kept = {k: getattr(module, k) for k in values}
+            for k, v in values.items():
+                setattr(module, k, v)
             try:
                 return fn(*args)
             finally:
-                dropless._row_tile = kept
+                for k, v in kept.items():
+                    setattr(module, k, v)
         return traced
 
     bf, f32 = jnp.bfloat16, jnp.float32
-    for tag, T, R, D, landed in _MOE_ROWS_SHAPES:
+    for tag, T, R, D, landed in shapes:
         ks = jax.random.split(jax.random.PRNGKey(R + D), 6)
         # sixteen groups of ascending tokens, as a stable sort by expert leaves them
         token = jnp.sort(jax.random.randint(ks[0], (16, R // 16), 0, T), axis=1).reshape(-1)
@@ -920,65 +937,127 @@ def check_moe_rows(results: list) -> None:
         dxs = jax.random.normal(ks[4], (R, D), f32).astype(bf)
         dout = jax.random.normal(ks[5], (T, D), f32)
 
-        def movements(tile):
-            """The four as jitted functions of ``n_valid``; ``tile`` ``None`` is
-            the one-shot form. A fresh function each call: nothing is served
-            from another setting's cache."""
+        def movements(tile, plan=None):
+            """The four as jitted functions of ``n_valid``, the token order (or
+            ``None``) and the operands; ``tile`` ``None`` is the one-shot form, a
+            ``plan`` (``True``: the one the layer runs with) takes the two sums
+            by token, and ``order(n_valid, token)`` makes their order. A fresh
+            function each call: nothing is served from another setting's cache.
+            Tile and plan are read while a movement is traced, its backward too."""
+            def fresh(fn):
+                if tile is not None:
+                    fn = patched(dropless, {"_row_tile": lambda D: tile}, fn)
+                if plan not in (None, True):
+                    fn = patched(seg, {"_TOKEN_TILES": plan[:1], "_ROW_CHUNK": plan[1]}, fn)
+                return jax.jit(fn)
+
             if tile is None:
                 cut = lambda n, a: jnp.where((jnp.arange(R) < n)[:, None], a, 0)
-                gather = lambda n, x: cut(n, x[token])
-                combine = lambda n, y, w: jnp.zeros((T, D), f32).at[token].add(
+                gather = lambda n, o, token, x: cut(n, x[token])
+                combine = lambda n, o, token, y, w: jnp.zeros((T, D), f32).at[token].add(
                     cut(n, y).astype(f32) * w[:, None])
             else:
-                gather = at_tile(tile, lambda n, x: dropless.gather_rows(x, token, n))
-                combine = at_tile(tile, lambda n, y, w: dropless.scatter_add_rows(
-                    y, token, n, scale=w, out_rows=T, out_dtype=f32))
-            return {
-                "dispatch_fwd": jax.jit(lambda n: gather(n, x)),
-                "dispatch_bwd": jax.jit(lambda n, ct: jax.vjp(
-                    lambda x: gather(n, x), x)[1](ct)[0]),
-                "combine_fwd": jax.jit(lambda n, y: combine(n, y, w)),
-                "combine_bwd": jax.jit(lambda n, y: jax.vjp(
-                    lambda y, w: combine(n, y, w), y, w)[1](dout)),
+                gather = lambda n, o, token, x: dropless.gather_rows(x, token, n, order=o)
+                combine = lambda n, o, token, y, w: dropless.scatter_add_rows(
+                    y, token, n, scale=w, out_rows=T, out_dtype=f32, order=o)
+            fns = {
+                "dispatch_fwd": fresh(gather),
+                "dispatch_bwd": fresh(lambda n, o, token, x, ct: jax.vjp(
+                    lambda x: gather(n, o, token, x), x)[1](ct)[0]),
+                "combine_fwd": fresh(combine),
+                "combine_bwd": fresh(lambda n, o, token, y, w, ct: jax.vjp(
+                    lambda y, w: combine(n, o, token, y, w), y, w)[1](ct)),
             }
+            order = None if plan is None else fresh(lambda n, token, w: dropless.token_order(
+                token, n, out_rows=T, width=D, dtype=bf, scale=w))
+
+            def layer(n, token, x, y, w, dxs, dout):
+                """A layer's four movements (and its token order) as one program."""
+                o = None if order is None else dropless.token_order(
+                    token, n, out_rows=T, width=D, dtype=bf, scale=w)
+                xs, pull = jax.vjp(lambda x: gather(n, o, token, x), x)
+                out, back = jax.vjp(lambda y, w: combine(n, o, token, y, w), y, w)
+                return xs, pull(dxs), out, back(dout)
+
+            fns["layer"] = fresh(layer)
+            if plan is not None:        # the by-token sums' two parts, each alone
+                tile_ = min(R, dropless._row_tile(D) if tile is None else tile)
+                fns["into_token_order"] = fresh(lambda n, o, y: dropless._gather_loop(
+                    y, o.perm, n, None, None, tile_, bf, fill=False)[0])
+                fns["sum_kernel"] = fresh(lambda o, rows: seg.segment_sum(
+                    rows, o, out_rows=T, out_dtype=bf))
+                fns["sum_kernel_scaled"] = fresh(lambda o, rows: seg.segment_sum(
+                    rows, o, out_rows=T, out_dtype=f32, scale=o.scale))
+            return fns, order
 
         def poisoned(a, n):
             return jnp.where((jnp.arange(R) >= n)[:, None], jnp.nan, a)
 
-        def args(name, n):
-            return {"dispatch_fwd": (n,), "dispatch_bwd": (n, poisoned(dxs, n)),
-                    "combine_fwd": (n, poisoned(y, n)), "combine_bwd": (n, poisoned(y, n))}[name]
+        def args(name, n, o):
+            if name == "layer":
+                return n, token, x, poisoned(y, n), w, poisoned(dxs, n), dout
+            if name == "into_token_order":
+                return n, o, poisoned(y, n)
+            if name.startswith("sum_kernel"):
+                return o, jnp.where((jnp.arange(R) < n)[:, None], y, 0)
+            return (n, o, token) + {
+                "dispatch_fwd": (x,), "dispatch_bwd": (x, poisoned(dxs, n)),
+                "combine_fwd": (poisoned(y, n), w),
+                "combine_bwd": (poisoned(y, n), w, dout)}[name]
 
         def timed(fn, a):
             return 1e3 * _min_step_seconds(lambda _: fn(*a), None)
 
-        picked = dropless._row_tile(D)                 # what the layer runs with
-        loop, plain = movements(picked), movements(None)
-        for label, n in (("cell", landed), ("eighth", R // 8), ("full", R)):
-            n = jnp.int32(n)
-            for name in loop:
-                got = jax.tree.leaves(loop[name](*args(name, n)))
-                want = jax.tree.leaves(plain[name](*args(name, n)))
-                gap = max(float(jnp.max(jnp.abs(g.astype(f32) - v.astype(f32))))
-                          for g, v in zip(got, want))
-                scale = max(float(jnp.max(jnp.abs(v.astype(f32)))) for v in want)
-                finite = all(bool(jnp.all(jnp.isfinite(g.astype(f32)))) for g in got)
-                # gathers move bits; the bfloat16 transpose is a float32 sum rounded
-                # once here: one bfloat16 ulp of the largest value from a form
-                # that rounds as often as it adds (the one-shot one off the TPU)
-                limit = 0.0 if name == "dispatch_fwd" else \
-                    8e-3 if name == "dispatch_bwd" else 1e-5
-                check(f"{tag}/{label}/{name}", finite and gap <= limit * scale,
-                      f"max|d|={gap:.3e} of {scale:.3e}")
-        ms = {}
-        for tile in (None,) + _MOE_ROWS_TILES:
-            fns = movements(tile)
-            for label, n in (("cell", landed), ("full", R)):
+        def ms_of(forms, n):
+            """ms of each movement, ``token_order`` among them where the form has one."""
+            fns, order = forms
+            o = None if order is None else order(n, token, w)
+            out = {name: timed(fn, args(name, n, o)) for name, fn in fns.items()}
+            if order is not None:
+                out["token_order"] = timed(order, (n, token, w))
+            return out
+
+        picked = min(R, dropless._row_tile(D))         # what the layer runs with
+        plain = movements(None)[0]
+        for form, (fns, order) in (("loop", movements(picked)),
+                                   ("by_token", movements(picked, True))):
+            for label, n in (("cell", landed), ("eighth", R // 8), ("full", R)):
                 n = jnp.int32(n)
-                ms[f"{'one_shot' if tile is None else tile}_{label}"] = sum(
-                    timed(fns[name], args(name, n)) for name in fns)
-        check(f"{tag}/ms_a_layer", ms[f"{picked}_cell"] < ms["one_shot_cell"],
+                o = None if order is None else order(n, token, w)
+                for name in _MOE_ROWS_MOVEMENTS:
+                    got = jax.tree.leaves(fns[name](*args(name, n, o)))
+                    want = jax.tree.leaves(plain[name](*args(name, n, None)))
+                    gap = max(float(jnp.max(jnp.abs(g.astype(f32) - v.astype(f32))))
+                              for g, v in zip(got, want))
+                    scale = max(float(jnp.max(jnp.abs(v.astype(f32)))) for v in want)
+                    finite = all(bool(jnp.all(jnp.isfinite(g.astype(f32)))) for g in got)
+                    # gathers move bits; the bfloat16 transpose is a float32 sum rounded
+                    # once here: one bfloat16 ulp of the largest value from a form
+                    # that rounds as often as it adds (the one-shot one off the TPU)
+                    limit = 0.0 if name == "dispatch_fwd" else \
+                        8e-3 if name == "dispatch_bwd" else 1e-5
+                    check(f"{tag}/{form}/{label}/{name}", finite and gap <= limit * scale,
+                          f"max|d|={gap:.3e} of {scale:.3e}")
+        ms, sums = {}, {}
+        forms = [("one_shot", movements(None))] \
+            + [(str(t), movements(t)) for t in sorted({picked, *tiles})] \
+            + [("by_token", movements(picked, True))] \
+            + [(f"by_token_{a}x{b}", movements(picked, (a, b))) for a, b in plans]
+        for form, fns in forms:
+            for label, n in (("cell", landed), ("eighth", R // 8), ("full", R)):
+                if label == "eighth" and form not in ("one_shot", str(picked), "by_token"):
+                    continue
+                each = ms_of(fns, jnp.int32(n))
+                ms[f"{form}_{label}"] = each["layer"]
+                sums[f"{form}_{label}"] = each["combine_fwd"] + each["dispatch_bwd"]
+                if form in (str(picked), "by_token"):
+                    check(f"{tag}/{form}/{label}/ms", True,
+                          json.dumps({k: round(v, 3) for k, v in each.items()}))
+        check(f"{tag}/ms_a_layer", ms["by_token_cell"] < ms[f"{picked}_cell"],
               json.dumps({n: round(t, 3) for n, t in ms.items()}))
+        # the two sums alone: the gate of ISSUE 47 is 0.6 x the loops' at the Mellum buffer
+        check(f"{tag}/two_sums_ms", sums["by_token_cell"] <= 0.6 * sums[f"{picked}_cell"],
+              json.dumps({n: round(t, 3) for n, t in sums.items()}))
 
 
 # batch, tokens, heads, head dim, groups, state: one Mamba-2 block of the Nemotron cell

@@ -34,9 +34,10 @@ def one_chip(topo):
 
 @pytest.fixture
 def compiled_not_interpreted(monkeypatch):
-    from beforeholiday_tpu.ops import grouped_matmul as gm
+    from beforeholiday_tpu.ops import grouped_matmul as gm, segment_sum as seg
 
     monkeypatch.setattr(gm, "_interpret_default", lambda: False)
+    monkeypatch.setattr(seg, "_interpret_default", lambda: False)
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)   # unreadable without a chip
     yield gm
@@ -109,10 +110,10 @@ def test_the_ssd_kernels_compile_for_the_chip(one_chip, monkeypatch, batch, S, H
 def _one_shot_rows(monkeypatch, dropless):
     """The sort's two sides as the static ops they were before PR 34: one
     gather and one scatter-add over the whole buffer, cut by a ``where``."""
-    def gather(src, token, n_valid, *, scale=None):
+    def gather(src, token, n_valid, *, scale=None, order=None):
         return jnp.where((jnp.arange(token.shape[0]) < n_valid)[:, None], src[token], 0)
 
-    def scatter_add(rows, token, n_valid, *, out_rows, scale=None, out_dtype=None):
+    def scatter_add(rows, token, n_valid, *, out_rows, scale=None, out_dtype=None, order=None):
         valid = jnp.arange(token.shape[0]) < n_valid
         rows = jnp.where(valid[:, None], rows, 0).astype(jnp.float32) \
             * jnp.where(valid, scale, 0.0)[:, None]
@@ -127,18 +128,24 @@ def _one_shot_rows(monkeypatch, dropless):
     (8192, 24576, 2304, 8, 16, 896, True),      # the Mellum cell
     (8192, 8192, 1024, 22, 8, 2688, False),     # the Nemotron cell (the latent; k > held)
     (8192, 9216, 2048, 6, 16, 768, True),       # the Kanana cell
-), ids=("qwen", "mellum", "nemotron", "kanana"))
+    (8192, 12288, 2048, 8, 16, 768, True),      # the Keye cell
+    (8192, 12288, 2048, 4, 8, 1792, True),      # the LFM2 cell
+), ids=("qwen", "mellum", "nemotron", "kanana", "keye", "lfm2"))
 def test_the_dropless_layer_compiles_for_the_chip_with_its_rows_moved_in_loops(
         one_chip, compiled_not_interpreted, monkeypatch, T, R, D, k, held, F, gated):
-    """Forward + backward of ``dropless_experts`` at a cell's shapes: the four
-    row movements are ``while`` loops whose bodies update the buffers in place
-    (no ``copy`` of a buffer inside one), and the program's temporaries are not
-    above the one-shot form's (1 MiB of slack: the loops carry a few ``(R,)``
-    vectors; what they save is the ``R x D`` float32 product). At the Mellum
-    cell's shapes the masking pass over ``xs`` (``dropless._settled``) is what
-    keeps the compiler from holding the gather loop's result at twice its size:
-    without it the temporaries are ``R x D`` bfloat16 larger. If this compiler
-    stops doing that, the pass can go."""
+    """Forward + backward of ``dropless_experts`` at a cell's shapes: the rows
+    move in ``while`` loops whose bodies update the buffers in place (no ``copy``
+    of a buffer inside one) — the layer's two gathers and the two into token
+    order — the two sums are the ``segment_sum`` kernel (a ``tpu_custom_call``
+    each: with the weights forward, without backward) and no loop scatter-adds;
+    the program's temporaries are not above the one-shot form's plus the one
+    token-ordered copy of the buffer, ``R x D`` bfloat16 (the forward's and the
+    backward's never live together; 1 MiB of slack: the loops carry a few
+    ``(R,)`` vectors, the token order a few more). At the Mellum cell's shapes
+    the masking pass over ``xs`` (``dropless._settled``) is what keeps the
+    compiler from holding the gather loop's result at twice its size: without
+    it the temporaries are ``R x D`` bfloat16 larger. If this compiler stops
+    doing that, the pass can go."""
     from beforeholiday_tpu.moe import dropless
 
     bf, f32 = jnp.bfloat16, jnp.float32
@@ -158,9 +165,13 @@ def test_the_dropless_layer_compiles_for_the_chip_with_its_rows_moved_in_loops(
 
     loops = compiled()
     text = loops.as_text()
+    sums = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*op_name="[^"]*moe_(\w+)\)*'
+                      r'/jit\(_segment_sum\)', text)
+    assert sorted(sums) == ["combine", "dispatch"], sums
     bodies = [c for c in text.split("\n\n") if "/while/body/" in c
               and ("moe_dispatch" in c or "moe_combine" in c)]
-    movers = [b for b in bodies if "scatter-add" in b or "/while/body/gather" in b]
+    assert not [b for b in bodies if "scatter-add" in b]
+    movers = [b for b in bodies if "/while/body/gather" in b]
     assert len(movers) >= 4, len(movers)
     for body in movers:
         big = [l for l in body.splitlines() if re.search(r" copy\(", l)
@@ -174,7 +185,7 @@ def test_the_dropless_layer_compiles_for_the_chip_with_its_rows_moved_in_loops(
     one_shot = compiled()
     assert "moe_dispatch)/while/body" not in one_shot.as_text()
     got, was = (c.memory_analysis().temp_size_in_bytes for c in (loops, one_shot))
-    assert got <= was + 2 ** 20, (got / 2 ** 20, was / 2 ** 20)
+    assert got <= was + R * D * 2 + 2 ** 20, (got / 2 ** 20, was / 2 ** 20)
 
 
 def test_the_deltanet_kernels_compile_for_the_chip(one_chip, monkeypatch):

@@ -631,40 +631,82 @@ def qwen_names():
     return sorted(set(re.findall(r'op_name="(jit\([^"]+)"', compiled.as_text())))
 
 
-@pytest.mark.parametrize("names_of,suffix", (
-    ("qwen_names", ""), ("mellum_names", ".mellum"), ("nemotron_names", ".nemotron_h")))
-def test_every_op_of_the_row_loops_lies_under_its_span(request, names_of, suffix):
-    """``gather_rows`` / ``scatter_add_rows`` run as ``while`` loops inside the
-    spans ``moe/moe_dispatch`` and ``moe/moe_combine``, the backward loops (a
-    ``custom_vjp``'s) too: ``moe_ms*`` and ``moe_sort_ms*`` read them by those
-    names, so a loop op without one would fall to ``unattributed_ms``."""
+def _names_with_the_sums_by_token(workload):
+    """The distinct ``op_name`` of every op of a tiny step compiled with the
+    layer's two sums taken by token (``ops/segment_sum.py``), as the chip takes
+    them: forced here, where ``token_order`` would keep the scatter-add loop,
+    and at the tiny widths (the interpreter tiles nothing)."""
+    from beforeholiday_tpu.moe import dropless
+    from beforeholiday_tpu.ops import segment_sum as seg
     from benchmark import run as bench_run
 
-    names = request.getfixturevalue(names_of)
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(seg, "is_kernel_available", lambda *a: True)
+        m.setattr(dropless, "token_order", lambda *a, **kw: seg.token_order(
+            *a, **{**kw, "impl": "pallas"}))
+        cell = bench_run.load("workloads", workload)
+        run = bench_run.Cell(cell, bench_run.load("configs", cell["config"]), jax.devices()[:1])
+        run.start(7)
+        run.build()
+        compiled = run.program.step.jitted.lower(run.state, run.pool[0]).compile()
+    return sorted(set(re.findall(r'op_name="(jit\([^"]+)"', compiled.as_text())))
+
+
+@pytest.mark.parametrize("workload,names_of,suffix", (
+    ("tiny-qwen3-next.train", "qwen_names", ""),
+    ("tiny-mellum.train", "mellum_names", ".mellum"),
+    ("tiny-nemotron-h.train", "nemotron_names", ".nemotron_h")))
+def test_every_op_of_the_row_loops_lies_under_its_span(request, workload, names_of, suffix):
+    """The sort's two sides run inside the spans ``moe/moe_dispatch`` and
+    ``moe/moe_combine`` — the gather loops, the token order and the sums by
+    token (the kernel, and the gather that feeds it), the backward ones (a
+    ``custom_vjp``'s) too: ``moe_ms*`` and ``moe_sort_ms*`` read them by those
+    names, so an op without one would fall to ``unattributed_ms``. With the sums
+    by token no row-sized ``scatter-add`` is left; off the TPU (the fixture's
+    step) they are the scatter-add loops, under the same spans."""
+    from benchmark import run as bench_run
+
     patterns = [re.compile(bench_run.load("layer_metrics", m + suffix)["pattern"])
                 for m in ("moe_ms", "moe_sort_ms")]
-    moved = [n for n in names if re.search(r"/(gather|scatter-add|dynamic_update_slice)$", n)
-             and "/while/body" in n.split("moe_")[-1] and "/moe" in n]
+    spans = r"moe_(dispatch|combine)\)*/"
+
+    def held(names, inner):
+        """Every op inside ``inner`` of the two spans, whatever it is."""
+        found = [n for n in names if re.search(spans + inner, n)]
+        for n in found:
+            assert all(p.search(n) for p in patterns), n
+            assert _pass_of(n) is not None, n
+        return found
+
+    def passes(found, side):
+        return {_pass_of(n) for n in found if side in _scopes_of(n)}
+
+    names = _names_with_the_sums_by_token(workload)
+    # the token order once a layer, forward, beside the sort it completes
+    order = held(names, r"jit\(_order\)/")
+    assert order and passes(order, "moe_dispatch") == {"amp_forward"}
+    # the kernel: the combine's sum forward, the dispatch's transpose backward
+    sums = held(names, r"jit\(_segment_sum\)/segment_sum/")
+    assert [n for n in sums if n.endswith("dot_general")]
+    assert passes(sums, "moe_combine") == {"amp_forward"}
+    assert passes(sums, "moe_dispatch") == {"amp_backward"}
+    # four gather loops a side: the layer's own two and the two into token order
+    loops = held(names, r"jit\(_gather_loop\)/while/body/")
+    movers = [n for n in loops if n.endswith("/gather")]
     for side in ("moe_dispatch", "moe_combine"):
-        for mover in ("gather", "scatter-add"):
-            for want in ("amp_forward", "amp_backward"):
-                # dispatch gathers forward and scatter-adds backward; combine the reverse
-                forward = (side == "moe_dispatch") == (mover == "gather")
-                if forward != (want == "amp_forward"):
-                    continue
-                assert [n for n in moved if side in _scopes_of(n) and n.endswith(mover)
-                        and _pass_of(n) == want], (side, mover, want)
-    # every op inside a loop of the two spans, whatever it is
-    in_loops = [n for n in names
-                if re.search(r"moe_(dispatch|combine)\)*/jit\(_\w+_loop\)/while/body/", n)]
-    assert len(in_loops) >= 8
-    for n in in_loops:
-        assert all(p.search(n) for p in patterns), n
-        assert _pass_of(n) is not None, n
-    # and no row mover of the two spans outside a loop
-    loose = [n for n in names if re.search(r"moe_(dispatch|combine)", n)
-             and n.endswith("scatter-add") and "/while/body/" not in n.split("moe_")[-1]]
-    assert not [n for n in loose if "moe_combine" in n], loose
+        assert passes(movers, side) == {"amp_forward", "amp_backward"}, side
+    # and no scatter-add of rows: the one left is the router weights' (T, k)
+    left = [n for n in names if re.search(spans, n) and n.endswith("scatter-add")]
+    assert all("/while/body/" not in n and "moe_combine" not in n for n in left), left
+
+    # the step as it compiles here: the scatter-add loops, under the same spans
+    names = request.getfixturevalue(names_of)
+    loops = held(names, r"jit\(_\w+_loop\)/while/body/")
+    assert len(loops) >= 8
+    adds = [n for n in loops if n.endswith("scatter-add")]
+    assert passes(adds, "moe_combine") == {"amp_forward"}
+    assert passes(adds, "moe_dispatch") == {"amp_backward"}
+    assert not held(names, r"jit\(_segment_sum\)/")
 
 
 # ---------------------------------------------------------------------------

@@ -10,6 +10,8 @@ callable with no arguments, and on a CPU backend each returns a
 measures them with no further wiring.
 """
 
+import json
+
 import jax
 import pytest
 
@@ -86,20 +88,33 @@ def test_the_ssd_check_runs_its_comparison(monkeypatch):
 def test_the_moe_rows_check_runs_its_comparison(monkeypatch):
     """The chip check of the sort's two sides at a toy shape on the CPU: the four
     row movements of a layer against plain indexing at three fills, the tail
-    NaN (the timings are not judged here)."""
+    NaN, as loops and with the two sums by token — taken here through the
+    interpreter, where ``token_order`` would keep the loop (the timings are not
+    judged here)."""
     from beforeholiday_tpu.moe import dropless
+    from beforeholiday_tpu.ops import segment_sum as seg
 
-    monkeypatch.setattr(tpu_checks, "_MOE_ROWS_SHAPES", (("toy", 64, 256, 128, 100),))
-    monkeypatch.setattr(tpu_checks, "_MOE_ROWS_TILES", (32, 64))
-    monkeypatch.setattr(dropless, "_row_tile", lambda D: 64)
+    monkeypatch.setattr(dropless, "_row_tile", lambda D: 256)
+    monkeypatch.setattr(dropless, "token_order", lambda *a, **kw: seg.token_order(
+        *a, **{**kw, "impl": "pallas"}))
     results = []
-    tpu_checks.check_moe_rows(results)
+    tpu_checks.check_moe_rows(results, shapes=(("toy", 64, 512, 128, 300),), tiles=(64,),
+                              plans=((128, 128),))
     by_name = {name: (ok, info) for name, ok, info in results}
     moved = ("dispatch_fwd", "dispatch_bwd", "combine_fwd", "combine_bwd")
-    assert set(by_name) == {f"moe_rows/toy/{fill}/{m}" for fill in ("cell", "eighth", "full")
-                            for m in moved} | {"moe_rows/toy/ms_a_layer"}
+    fills = ("cell", "eighth", "full")
+    assert set(by_name) == {
+        f"moe_rows/toy/{form}/{fill}/{m}" for form in ("loop", "by_token") for fill in fills
+        for m in moved} | {f"moe_rows/toy/{form}/{fill}/ms" for form in ("256", "by_token")
+                           for fill in fills} | {"moe_rows/toy/ms_a_layer",
+                                                 "moe_rows/toy/two_sums_ms"}
     for name, (ok, info) in by_name.items():
-        assert ok or name.endswith("ms_a_layer"), (name, info)
+        assert ok or name.endswith(("ms_a_layer", "two_sums_ms")), (name, info)
+    timed = json.loads(by_name["moe_rows/toy/by_token/cell/ms"][1])
+    assert set(timed) == set(moved) | {"layer", "token_order", "into_token_order",
+                                       "sum_kernel", "sum_kernel_scaled"}
+    assert {"one_shot_cell", "64_full", "256_eighth", "by_token_full",
+            "by_token_128x128_cell"} <= set(json.loads(by_name["moe_rows/toy/ms_a_layer"][1]))
 
 
 @pytest.mark.parametrize("check", ("check_ssd", "check_moe_rows"))
