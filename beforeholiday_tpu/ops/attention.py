@@ -54,14 +54,14 @@ The backward is one kernel or two by what the plan says carries over between
 grid steps (:func:`_fa_bwd_pallas`). Where a head is one block
 (``TilePlan.one_pass``: causal, S <= 1024 at D <= 128, S <= 512 above) nothing
 does, and ONE call recomputes the scores once and returns dq, dk and dv
-(booked as ``dqkv``). Where a causal head without a window is several blocks
-(S = 8192: 8 x 8 or 16 x 16) dk / dv sum over query blocks in a block-sized
-accumulator and dq over key blocks in a float32 scratch that holds the whole
-head in VMEM (``Sq * Dk * 4 <= _HEAD_DQ_BYTES``), so again ONE call on the dkv
-kernel's grid recomputes once (booked as ``dqkv_blocks``). Everywhere else — a
-non-causal call, a windowed plan, a head too long for VMEM — two grid orders, so
-a dq call and a dkv call each recompute the scores (booked as ``dq`` and
-``dkv``).
+(booked as ``dqkv``). Where a causal head is several blocks (S = 8192: 8 x 8 or
+16 x 16, or a window's band of them) dk / dv sum over query blocks in a
+block-sized accumulator and dq over key blocks in a float32 scratch that holds
+the whole head in VMEM (``Sq * Dk * 4 <= _HEAD_DQ_BYTES``), so again ONE call on
+the dkv kernel's grid — the square's, or the band's — recomputes once (booked as
+``dqkv_blocks``). Everywhere else — a non-causal call, a head too long for VMEM —
+two grid orders, so a dq call and a dkv call each recompute the scores (booked
+as ``dq`` and ``dkv``).
 
 Variable-length batches are expressed as per-sequence key lengths
 (``kv_lens``) rather than the reference's packed cu_seqlens: on TPU the
@@ -269,10 +269,11 @@ class TilePlan(NamedTuple):
         to the diagonal. The last grid axis has this many steps, not ``nk``:
         step ``s`` of query block ``i`` is key block ``i - (band - 1) + s``
         (fwd, dq), step ``s`` of key block ``j`` is query block ``j + s``
-        (dkv), through index maps offset from the outer block. The
-        ``band - 1`` steps that fall before the sequence's start (or, for
-        dkv, past its end) are clamped onto the first (last) block, so they
-        copy nothing new, and compute nothing."""
+        (dkv, and the one-call backward that walks as dkv does:
+        :func:`_fa_bwd_blocks`), through index maps offset from the outer
+        block. The ``band - 1`` steps that fall before the sequence's start
+        (or, key block outer, past its end) are clamped onto the first (last)
+        block, so they copy nothing new, and compute nothing."""
         return min(self.nq, -(-(self.window - 1) // self.bk) + 1)
 
     def band_walk(self, by_cols: bool, d: int):
@@ -872,8 +873,9 @@ def _fa_fwd_pallas(q, k, v, lens, causal, scale, interpret, rate=0.0, seed=None,
 # ---------------------------------------------------------------------------------
 # backward: dq kernel (grid BH, nq, nk) + dkv kernel (grid BH, nk, nq), or the two
 # in one: where a head is one block (grid BH, 1, 1), and where a causal head of
-# several blocks keeps its dq in VMEM (grid BH, nk, nq); all recompute block
-# scores from (q, k, lse) — flash-attention rematerialization
+# several blocks keeps its dq in VMEM (grid BH, nk, nq; BH, nk, band with a
+# window); all recompute block scores from (q, k, lse) — flash-attention
+# rematerialization
 # ---------------------------------------------------------------------------------
 
 
@@ -1100,7 +1102,14 @@ def _fa_dqkv_blocks_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
     ``(j, j)`` — the first live step of ``j``'s walk — adds the last, so it is
     rounded and written to the ``(1, bq, Dk)`` output block there and leaves
     when ``j`` moves on: dq never sits in HBM in float32, and is summed in the
-    order the dq kernel sums it."""
+    order the dq kernel sums it.
+
+    A windowed plan is the same walk cut to its band (``TilePlan.band``: query
+    blocks ``j .. j + band - 1``, the diagonal still first, so the last key block
+    of query block ``j`` is still ``j``). Only where a query block's sum STARTS
+    moves: at the band's far end, its last step — or anywhere in key block 0's
+    walk, which is the first to reach the first ``band`` query blocks. The steps
+    clamped past the sequence's end compute nothing and touch no slot."""
     lens_ref, seed_ref, refs = _kernel_scalars(refs, has_lens, rate)
     operands, rest = _bwd_refs(refs, has_dlse, plan.selected)
     dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = rest
@@ -1112,7 +1121,13 @@ def _fa_dqkv_blocks_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(j == 0)            # every query block's first term is key block 0's
+    # query block i's first term: key block 0's without a window; with one, the
+    # band's far end (its last step) or, in key block 0's walk, every step
+    first = j == 0
+    if plan.window is not None:
+        first = lax.bitwise_and(lax.bitwise_or(first, step == plan.band - 1), i < plan.nq)
+
+    @pl.when(first)
     def _init_dq():
         dq_acc[i] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
 
@@ -1120,7 +1135,7 @@ def _fa_dqkv_blocks_kernel(plan, scale, has_lens, has_dlse, rate, *refs):
         _dkv_block(plan, scale, rate, walk, b, i, j, lens, seed_ref, operands,
                    dk_acc, dv_acc, dq_acc)
 
-    _walk_block(plan, True, i, j, block)
+    _walk_block(plan, True, i, j, block, step)
 
     @pl.when(i == j)
     def _dq_final():
@@ -1230,17 +1245,20 @@ def _blocks_vmem_bytes(plan, Dk, Dv, itemsize):
 
 def _fa_bwd_blocks(plan, *args, sel=None):
     """(dq, dk, dv) of a causal plan of several blocks from ONE call on the dkv
-    kernel's grid (BH, nk, nq), a head's dq summed in VMEM
-    (:func:`_fa_dqkv_blocks_kernel`). The key axis carries that scratch, so it
-    is ``arbitrary`` too. The query side's maps are clamped onto the diagonal:
-    the steps above it name the block the diagonal step takes and copy nothing."""
+    kernel's grid — (BH, nk, nq), or (BH, nk, band) with a window — a head's dq
+    summed in VMEM (:func:`_fa_dqkv_blocks_kernel`). The key axis carries that
+    scratch, so it is ``arbitrary`` too. The query side's maps are the dkv
+    kernel's (:func:`_block_maps`): clamped onto the diagonal, where the steps
+    above it name the block the diagonal step takes and copy nothing; with a
+    window the band's, clamped onto the last block."""
     q, k, v = args[:3]
     BH, (Dk, Dv) = q.shape[0], _widths(q, v)
     bq, bk = plan.bq, plan.bk
     own, _, queries = _block_maps(plan)
     spec = lambda rows, D, at: pl.BlockSpec((1, rows, D), at)
     return _bwd_call(
-        _fa_dqkv_blocks_kernel, "dqkv_blocks", plan, args, grid=(BH, plan.nk, plan.nq),
+        _fa_dqkv_blocks_kernel, "dqkv_blocks", plan, args,
+        grid=(BH, plan.nk, _steps(plan, True)),
         in_specs=[spec(bq, Dk, queries), spec(bk, Dk, own), spec(bk, Dv, own),
                   spec(bq, Dv, queries), spec(bq, Dv, queries), spec(bq, 128, queries)],
         out_specs=[spec(bq, Dk, own), spec(bk, Dk, own), spec(bk, Dv, own)],
@@ -1255,7 +1273,7 @@ def _bwd_of(plan, Dk):
     carries over between grid steps, and whether VMEM can hold it."""
     if plan.one_pass:
         return _fa_bwd_fused
-    if plan.causal and plan.window is None and plan.sq * Dk * 4 <= _HEAD_DQ_BYTES:
+    if plan.causal and plan.sq * Dk * 4 <= _HEAD_DQ_BYTES:
         return _fa_bwd_blocks
     return _fa_bwd_two_calls
 
@@ -1271,19 +1289,20 @@ def _fa_bwd_pallas(q, k, v, do, o, lse, dlse, lens, causal, scale, interpret,
     recomputes once for dq, dk and dv: five products and one vector pass over
     the live tiles, every operand read once.
 
-    Where a causal head is several blocks and has no window, dk / dv sum over a
-    key block's query blocks and dq over a query block's key blocks. One grid
+    Where a causal head is several blocks, with or without a window, dk / dv sum
+    over a key block's query blocks and dq over a query block's key blocks. One grid
     order keeps only one of them in a block-sized accumulator, but the other
     fits VMEM whole: one call (:func:`_fa_bwd_blocks`) walks the square by key
     block, holds the head's float32 dq (``Sq * Dk * 4 <= _HEAD_DQ_BYTES``) and
     again recomputes once — five products and one vector pass. The diagonal
     makes it possible: query block ``j``'s dq is complete when key block ``j``
-    is done, so it leaves as a block, in the order of the walk.
+    is done, so it leaves as a block, in the order of the walk. A window only
+    shortens the walk to its band (grid ``(BH, nk, band)``).
 
     Everything else — a non-causal call (dq is final only after the LAST key
-    block), a windowed plan (its grid is the band), a head too long for VMEM —
-    takes two calls (:func:`_fa_bwd_two_calls`), each walking the square its
-    own way and each recomputing: seven products and two vector passes.
+    block), a head too long for VMEM — takes two calls
+    (:func:`_fa_bwd_two_calls`), each walking the square its own way and each
+    recomputing: seven products and two vector passes.
 
     ``dlse=None`` (the plain-attention path) omits the operand entirely —
     an all-zero lane-replicated dlse would otherwise add an arena-sized HBM

@@ -668,19 +668,24 @@ def check_flash_sparse(results: list, H: int = 32, D: int = 128, parity=(2048,),
 # (heads, S, Dk, Dv) of the four 8k cells' causal calls without a window
 _FUSED_SHAPES = ((32, 8192, 192, 128), (32, 8192, 64, 64), (32, 8192, 128, 128),
                  (16, 8192, 256, 256))
+# (heads, S, Dk, Dv, window): the Mellum cell's three window layers (a band of 2 of
+# 8 blocks of 1,024), and a band of 4 of 5 blocks of 512 whose edge falls inside a tile
+_BAND_SHAPES = ((32, 8192, 128, 128, 1024), (8, 2560, 64, 64, 1152))
 
 
-def check_flash_fused(results: list, parity=((32, 2048, 192, 128), (32, 2048, 64, 64)),
-                      timed=_FUSED_SHAPES) -> None:
+def check_flash_fused(results: list,
+                      parity=((32, 2048, 192, 128), (32, 2048, 64, 64)) + _BAND_SHAPES,
+                      timed=_FUSED_SHAPES + _BAND_SHAPES[:1]) -> None:
     """The fused backward of a causal head of several blocks
     (``ops.attention._fa_bwd_blocks``: ONE call, the head's float32 dq in VMEM),
     compiled: dq, dk and dv against the dq + dkv pair (``_fa_bwd_two_calls``) on the
-    same residuals at ``parity``'s shapes ``(heads, S, Dk, Dv)`` under the 3e-2 the
-    other compiled kernels are held to, with the elements that differ counted; then
-    ms a layer of the one call and of the two at the four 8k cells' calls, a fresh
-    function each. Interpret mode cannot see what Mosaic makes of 6-8 MiB of
-    scratch indexed by a grid id, of an output block written at the first live
-    step of its walk, or of the clamped index maps."""
+    same residuals at ``parity``'s shapes ``(heads, S, Dk, Dv[, window])`` under the
+    3e-2 the other compiled kernels are held to, with the elements that differ
+    counted; then ms a layer of the one call and of the two at the four 8k cells'
+    calls and at the Mellum cell's windowed one (PR 48: the same call on the band's
+    grid), a fresh function each. Interpret mode cannot see what Mosaic makes of
+    6-8 MiB of scratch indexed by a grid id, of an output block written at the
+    first live step of its walk, or of the clamped index maps."""
     from beforeholiday_tpu.ops import attention as A
 
     def check(name, cond, info=""):
@@ -688,25 +693,30 @@ def check_flash_fused(results: list, parity=((32, 2048, 192, 128), (32, 2048, 64
 
     interpret = A._interpret_default()          # False on the chip
 
-    def residuals(H, S, Dk, Dv):
+    def residuals(H, S, Dk, Dv, window=None):
         ks = jax.random.split(jax.random.PRNGKey(H + S + Dk), 4)
         q, k, v, do = (jax.random.normal(kk, (H, S, D)).astype(jnp.bfloat16)
                        for kk, D in zip(ks, (Dk, Dk, Dv, Dv)))
         scale = Dk ** -0.5
-        plan = A._tile_plan(S, S, Dk, True, None, Dv)
+        plan = A._tile_plan(S, S, Dk, True, window, Dv)
         o, lse = jax.jit(lambda q, k, v: A._fa_fwd_pallas(
-            q, k, v, None, True, scale, interpret))(q, k, v)
+            q, k, v, None, True, scale, interpret, window=window))(q, k, v)
         runs = {tag: functools.partial(
                     jax.jit(lambda *a, fn=fn: fn(plan, *a, None, None, scale, interpret, 0.0, None)),
                     q, k, v, do, o, lse)
                 for tag, fn in (("fused", A._fa_bwd_blocks), ("two_calls", A._fa_bwd_two_calls))}
         return plan, runs
 
-    for H, S, Dk, Dv in parity:
-        plan, runs = residuals(H, S, Dk, Dv)
-        name = f"s{S}_d{Dk}" + (f"_{Dv}" if Dv != Dk else "")
+    def name_of(sep, Dk, Dv, window=None):
+        return (f"{sep}{Dk}" + (f"_{Dv}" if Dv != Dk else "")
+                + (f"_w{window}" if window is not None else ""))
+
+    for H, S, Dk, Dv, *window in parity:
+        plan, runs = residuals(H, S, Dk, Dv, *window)
+        name = f"s{S}" + name_of("_d", Dk, Dv, *window)
         check(f"{name}/plan", A._bwd_of(plan, Dk) is A._fa_bwd_blocks and plan.nq > 1,
-              f"{plan.nq} x {plan.nk} blocks of {plan.bq}")
+              f"{plan.nq} x {plan.nk} blocks of {plan.bq}"
+              + (f", a band of {plan.band}" if window else ""))
         one, two = runs["fused"](), runs["two_calls"]()
         for gname, a, b in zip(("dq", "dk", "dv"), one, two):
             a, b = a.astype(jnp.float32), b.astype(jnp.float32)
@@ -714,11 +724,11 @@ def check_flash_fused(results: list, parity=((32, 2048, 192, 128), (32, 2048, 64
             ok = bool(jnp.all(jnp.isfinite(a))) and gap <= 3e-2 * size
             check(f"{name}/{gname}", ok, f"max|d|={gap:.3e} of {size:.3e}, "
                   f"{int(jnp.sum(a != b))} of {a.size} differ")
-    for H, S, Dk, Dv in timed:
-        _, runs = residuals(H, S, Dk, Dv)
+    for H, S, Dk, Dv, *window in timed:
+        _, runs = residuals(H, S, Dk, Dv, *window)
         ms = {tag: round(1e3 * _min_step_seconds(lambda _: fn(), None), 3)
               for tag, fn in runs.items()}
-        check(f"ms_a_layer/{H}x{S}x{Dk}" + (f"_{Dv}" if Dv != Dk else ""),
+        check(f"ms_a_layer/{H}x{S}" + name_of("x", Dk, Dv, *window),
               ms["fused"] < ms["two_calls"], json.dumps(ms))
 
 

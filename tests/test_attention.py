@@ -690,14 +690,13 @@ class TestWindow:
         f = lambda q: jnp.sum(A.flash_attention(q, k, v, causal=True, window=200, impl="pallas"))
         jax.grad(f)(q)
         rows = {r["kernel"]: r for r in monitor.tile_records() if r["op"] == "flash_attention"}
-        assert sorted(rows) == ["dkv", "dq", "fwd"]
+        assert sorted(rows) == ["dqkv_blocks", "fwd"]      # the band's backward is one call (PR 48)
         for r in rows.values():
             assert r["key"] == repr((512, 512, 64, True, False, 200))
             assert (r["live"], r["total"], r["masked"]) == (9, 16, 9)
         dispatch.reset_dispatch_counters()
         names = [e.params["name"] for e in _pallas_eqns(jax.make_jaxpr(jax.grad(f))(q).jaxpr)]
-        assert sorted(names) == ["flash_attention_window_dkv", "flash_attention_window_dq",
-                                 "flash_attention_window_fwd"]
+        assert sorted(names) == ["flash_attention_window_dqkv_blocks", "flash_attention_window_fwd"]
 
     def test_errors(self):
         q, k, v = _qkv(jax.random.PRNGKey(1), B=1, H=1, S=128)
@@ -1116,21 +1115,26 @@ class TestFusedBackwardOfSeveralBlocks:
                 want = [max(i, j), j, j] + [max(i, j)] * (n_in - 3) + [j, j, j]
                 assert blocks == want, (j, i, blocks)
 
-    @pytest.mark.parametrize("case", ["non-causal", "windowed", "dq-over-the-budget", "one-block"])
+    @pytest.mark.parametrize("case", ["non-causal", "windowed", "dq-over-the-budget",
+                                      "windowed-dq-over-the-budget", "one-block"])
     def test_what_it_does_not_take_keeps_the_calls_it_had(self, case, monkeypatch):
-        """The rule is a function of ``(Sq, Sk, Dk, Dv, causal, window)`` alone."""
+        """The rule is a function of ``(Sq, Sk, Dk, Dv, causal, window)`` alone. A
+        windowed head of several blocks is on the fused side since PR 48, under the
+        same budget: over it, it keeps the pair as the un-windowed head does."""
         S, D = 1280, 64
-        if case == "dq-over-the-budget":
+        if case.endswith("dq-over-the-budget"):
             assert A._HEAD_DQ_BYTES == 8 * 2 ** 20      # S = 8192 at D = 256; 16,384 at 128
             for s, d, fits in ((8192, 256, True), (16384, 128, True), (16384, 192, False),
                                (32768, 64, True), (32768, 128, False)):
-                plan = A._tile_plan(s, s, d, True)
-                assert (A._bwd_of(plan, d) is A._fa_bwd_blocks) == fits, (s, d)
+                for window in (None, 1024):
+                    plan = A._tile_plan(s, s, d, True, window)
+                    assert (A._bwd_of(plan, d) is A._fa_bwd_blocks) == fits, (s, d, window)
             monkeypatch.setattr(A, "_HEAD_DQ_BYTES", S * D * 4 - 1)
-        causal, window = case != "non-causal", 300 if case == "windowed" else None
+        causal, window = case != "non-causal", 300 if case.startswith("windowed") else None
         S = 256 if case == "one-block" else S
         plan = A._tile_plan(S, S, D, causal, window)
-        want = A._fa_bwd_fused if case == "one-block" else A._fa_bwd_two_calls
+        want = {"one-block": A._fa_bwd_fused, "windowed": A._fa_bwd_blocks}.get(
+            case, A._fa_bwd_two_calls)
         assert A._bwd_of(plan, D) is want
         q, k, v, w, _ = self._inputs(D, D, S, 43)
         lens = None if causal else jnp.full((self.BH,), float(S))
@@ -1138,9 +1142,13 @@ class TestFusedBackwardOfSeveralBlocks:
         grad = jax.grad(lambda *a: jnp.sum(A._flash3(*a, lens, seed, causal, 0.125, 0.0, window) * w),
                         argnums=(0, 1, 2))
         calls = _pallas_eqns(jax.make_jaxpr(grad)(q, k, v).jaxpr)
-        assert len(calls) == (2 if case == "one-block" else 3)
+        assert len(calls) == (3 if want is A._fa_bwd_two_calls else 2)
         for e in calls[1:]:
             params = e.params["compiler_params"]["mosaic_tpu"]
+            if want is A._fa_bwd_blocks:
+                assert tuple(params.dimension_semantics) == ("parallel", "arbitrary", "arbitrary")
+                assert params.vmem_limit_bytes == A._blocks_vmem_bytes(plan, D, D, 4)
+                continue
             assert tuple(params.dimension_semantics) == ("parallel", "parallel", "arbitrary")
             assert params.vmem_limit_bytes is None
 
@@ -1166,6 +1174,176 @@ class TestFusedBackwardOfSeveralBlocks:
                 np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
         kernels = {r["kernel"] for r in monitor.tile_records() if r["op"] == "flash_attention"}
         assert kernels == {"fwd", "dqkv_blocks"}
+        dispatch.reset_dispatch_counters()
+
+
+# -- one backward call where a windowed head is several blocks: the band (PR 48) --------
+
+# the TestWindow shapes whose head is several blocks: the edge inside a block and
+# on a block boundary (band == nq == 2), a band of 2 of 4 blocks, of 4 of 5, and
+# the diagonal alone (band 1); + a band of 3 of 5 blocks, two strips a block
+BAND_SHAPES = {**{c: WINDOWED[c] for c in WINDOWED if c != "one-block-a-head"},
+               "band-of-3": (1280, 256 + 130, 64)}
+
+
+class TestFusedBackwardOfTheBand:
+    """A causal, WINDOWED head of several blocks whose float32 dq fits the VMEM
+    budget: dq, dk and dv from ONE call (``_fa_bwd_blocks`` on the band's dkv
+    grid ``(BH, nk, band)``) against ``_window_oracle`` and against the dq + dkv
+    pair (``_fa_bwd_two_calls``) on the same residuals. As without a window, dk
+    and dv are the dkv kernel's bit for bit (its body, its order) and dq sums the
+    same float32 terms key block by key block as the dq kernel does, the blocks
+    an edge crosses by key strip and not by row strip: equal to a float32
+    rounding, and bit for bit where a block is one tile."""
+
+    BH = 2
+
+    def _inputs(self, S, D, seed=61):
+        return TestFusedBackward._inputs(self, S, D, seed)
+
+    def test_the_shapes_are_what_the_cases_say(self):
+        got = {c: (p.nq, p.band, A._bwd_of(p, D) is A._fa_bwd_blocks)
+               for c, (S, W, D) in BAND_SHAPES.items()
+               for p in [A._tile_plan(S, S, D, True, W)]}
+        assert got == {
+            "edge-inside-a-block": (2, 2, True), "edge-inside-a-tile-D128": (2, 2, True),
+            "edge-on-a-block-boundary": (2, 2, True), "window-is-one-tile": (4, 2, True),
+            "across-several-blocks": (5, 4, True), "own-key-only": (2, 1, True),
+            "band-of-3": (5, 3, True)}
+
+    @pytest.mark.parametrize("variant", ["plain", "kv_lens", "dlse", "dropout"])
+    @pytest.mark.parametrize("case", BAND_SHAPES)
+    def test_matches_the_oracle_and_the_two_calls(self, case, variant, monkeypatch):
+        monkeypatch.setattr(A, "_keep_mask", _hashed_keep)
+        S, W, D = BAND_SHAPES[case]
+        q, k, v, w, wl = self._inputs(S, D)
+        scale = D ** -0.5
+        plan = A._tile_plan(S, S, D, True, W)
+        # one length inside the last block, one that empties it
+        lens = jnp.asarray((S - 70, S - plan.bq), jnp.float32) if variant in ("kv_lens", "dlse") else None
+        rate = 0.25 if variant == "dropout" else 0.0
+        seed = jnp.asarray([4321], jnp.int32)
+        dlse = jnp.broadcast_to(wl[..., None], (self.BH, S, 128)) if variant == "dlse" else None
+        o, lse = A._fa_fwd_pallas(q, k, v, lens, True, scale, True, rate, seed, W)
+        args = (plan, q, k, v, w, o, lse, dlse, lens, scale, True, rate, seed)
+        one, two = A._fa_bwd_blocks(*args), A._fa_bwd_two_calls(*args)
+        assert [t.shape for t in one] == [q.shape, k.shape, v.shape]
+        for name, a, b in zip(("dq", "dk", "dv"), one, two):
+            assert not np.any(np.isnan(np.asarray(a))), name
+            if name == "dq" and plan.bq > plan.tq:
+                np.testing.assert_allclose(a, b, atol=2e-6, rtol=2e-6, err_msg=name)
+            else:
+                np.testing.assert_array_equal(a, b, err_msg=name)
+        if rate == 0.0 and dlse is None:       # the oracle draws another mask, and has no lse
+            want = jax.grad(lambda q, k, v: jnp.sum(_window_oracle(q, k, v, W, scale, lens) * w),
+                            argnums=(0, 1, 2))(q, k, v)
+            for name, a, b in zip(("dq", "dk", "dv"), one, want):
+                np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4, err_msg=name)
+
+    @pytest.mark.parametrize("case", ["edge-inside-a-block", "across-several-blocks"])
+    def test_the_lse_variants_dlse_against_autodiff_of_the_mask(self, case):
+        """``flash_attention_with_lse(window=)``: the cotangent of the exposed lse
+        through the one call, against the materialised mask's own logsumexp."""
+        S, W, D = BAND_SHAPES[case]
+        q, k, v, w, wl = self._inputs(S, D, 62)
+        i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+        keep = (j <= i) & (j > i - W)
+
+        def flash(q, k, v):
+            o, lse = A.flash_attention_with_lse(q, k, v, causal=True, scale=0.125, window=W)
+            return jnp.sum(o * w) + jnp.sum(lse * wl)
+
+        def oracle(q, k, v):
+            s = jnp.where(keep, jnp.einsum("bqd,bkd->bqk", q, k) * 0.125, -jnp.inf)
+            return (jnp.sum(_window_oracle(q, k, v, W, 0.125) * w)
+                    + jnp.sum(jax.nn.logsumexp(s, -1) * wl))
+
+        for name, a, b in zip(("dq", "dk", "dv"), jax.grad(flash, (0, 1, 2))(q, k, v),
+                              jax.grad(oracle, (0, 1, 2))(q, k, v)):
+            np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4, err_msg=name)
+
+    @pytest.mark.parametrize("case", ["edge-inside-a-tile-D128", "across-several-blocks"])
+    def test_bfloat16_results_are_the_two_calls_to_a_rounding(self, case):
+        S, W, D = BAND_SHAPES[case]
+        q, k, v, w, _ = (t.astype(jnp.bfloat16) for t in self._inputs(S, D, 63))
+        plan = A._tile_plan(S, S, D, True, W)
+        o, lse = A._fa_fwd_pallas(q, k, v, None, True, D ** -0.5, True, window=W)
+        args = (plan, q, k, v, w, o, lse, None, None, D ** -0.5, True, 0.0, None)
+        one, two = A._fa_bwd_blocks(*args), A._fa_bwd_two_calls(*args)
+        np.testing.assert_array_equal(one[1], two[1])
+        np.testing.assert_array_equal(one[2], two[2])
+        a, b = (np.asarray(t, np.float32) for t in (one[0], two[0]))
+        assert one[0].dtype == jnp.bfloat16 and np.mean(a != b) < 1e-3
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=2 ** -6)    # a bfloat16 step or two
+
+    @pytest.mark.parametrize("with_lse", [False, True], ids=["plain", "lse"])
+    def test_one_call_on_the_bands_grid_under_its_own_name(self, with_lse):
+        """At the Mellum cell's call: forward and ONE backward ``pallas_call``,
+        ``flash_attention_window_dqkv_blocks`` (``^%flash_attention_window`` reads
+        it), grid ``(BH, nk, band)``, the query side under the band's dkv maps —
+        query block ``j + s`` clamped onto the last — and dq, dk, dv under the key
+        block's; the dq output is stored in one place, the diagonal step's."""
+        S, W, D = 8192, 1024, 128
+        x = jax.ShapeDtypeStruct((2, S, D), jnp.bfloat16)
+        seed = jnp.zeros((1,), jnp.int32)
+
+        def loss(q, k, v):
+            if with_lse:
+                o, lse = A._flash3_lse(q, k, v, None, True, 0.125, W)
+                return jnp.sum(o.astype(jnp.float32)) + jnp.sum(lse)
+            return jnp.sum(A._flash3(q, k, v, None, seed, True, 0.125, 0.0, W).astype(jnp.float32))
+
+        calls = _pallas_eqns(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x).jaxpr)
+        assert [e.params["name"] for e in calls] == [
+            "flash_attention_window_fwd", "flash_attention_window_dqkv_blocks"]
+        assert [e.params["grid_mapping"].grid for e in calls] == [(2, 8, 2)] * 2
+        assert len(calls[1].params["out_avals"]) == 3
+        plan = A._tile_plan(S, S, D, True, W)
+        params = calls[1].params["compiler_params"]["mosaic_tpu"]
+        assert tuple(params.dimension_semantics) == ("parallel", "arbitrary", "arbitrary")
+        assert params.vmem_limit_bytes == A._blocks_vmem_bytes(plan, D, D, 2) < 48 * 2 ** 20
+        body, gm = calls[1].params["jaxpr"], calls[1].params["grid_mapping"]
+        n_in = 7 if with_lse else 6
+        dq_ref, dk_ref, dv_ref = body.invars[n_in:n_in + 3]
+        assert [_swaps_on(body, r) for r in (dq_ref, dk_ref, dv_ref)] == [1, 1, 1]
+        maps = [bm.index_map_jaxpr for bm in gm.block_mappings]
+        at = lambda m, j, s: tuple(int(t) for t in jax.core.eval_jaxpr(m.jaxpr, m.consts, 0, j, s))
+        for j in range(plan.nk):
+            for s in range(plan.band):
+                i = min(j + s, plan.nq - 1)
+                # q, k, v, do, o, lse [, dlse]; dq, dk, dv
+                assert [at(m, j, s)[1] for m in maps] == [i, j, j] + [i] * (n_in - 3) + [j, j, j]
+
+    def test_the_body_is_smaller_than_the_two_it_replaces(self):
+        """Set-up time: the band's one body holds its two walks once (5 products
+        and one ``exp`` a strip) where the dq and dkv bodies held them twice."""
+        S, W, D = 8192, 1024, 128
+        x = jax.ShapeDtypeStruct((1, S, D), jnp.bfloat16)
+        plan = A._tile_plan(S, S, D, True, W)
+        args = (plan, x, x, x, x, x, jax.ShapeDtypeStruct((1, S, 128), jnp.float32),
+                None, None, 0.125, True, 0.0, None)
+        ops = lambda fn: _kernel_primitives(lambda *a: fn(plan, *a, *args[7:]), *args[1:7])
+        (one,), (dq, dkv) = ops(A._fa_bwd_blocks), ops(A._fa_bwd_two_calls)
+        assert len(one) < 0.62 * (len(dq) + len(dkv)), (len(one), len(dq), len(dkv))
+        assert one.count("exp") == dkv.count("exp") == dq.count("exp")
+        assert one.count("dot_general") == dkv.count("dot_general") + dkv.count("exp")
+
+    def test_tile_records_and_the_public_call(self):
+        """``flash_attention(window=)`` reaches the one call: ``fwd`` +
+        ``dqkv_blocks`` booked, no ``dq`` / ``dkv``, with the band's counts."""
+        from beforeholiday_tpu import monitor
+        from beforeholiday_tpu.guard import dispatch
+
+        dispatch.reset_dispatch_counters()
+        x = jax.ShapeDtypeStruct((1, 4, 8192, 128), jnp.bfloat16)
+        f = lambda q, k, v: jnp.sum(A.flash_attention(
+            q, k, v, causal=True, window=1024, impl="pallas").astype(jnp.float32))
+        jax.make_jaxpr(jax.grad(f, argnums=(0, 1, 2)))(x, x, x)
+        rows = {r["kernel"]: r for r in monitor.tile_records() if r["op"] == "flash_attention"}
+        assert sorted(rows) == ["dqkv_blocks", "fwd"]
+        for r in rows.values():
+            assert r["key"] == repr((8192, 8192, 128, True, False, 1024))
+            assert (r["live"], r["total"], r["masked"]) == (150, 1024, 60)
         dispatch.reset_dispatch_counters()
 
 

@@ -370,28 +370,35 @@ def test_the_two_width_flash_kernels_compile_for_the_chip(one_chip, flash_compil
 
 
 # the four 8k cells' causal calls without a window: 36 live blocks of 64 a head, and 136 of 256
-at_the_8k_cells_calls = pytest.mark.parametrize("BH,S,Dk,Dv", (
-    (32, 8192, 192, 128),                       # the Kanana cell: 8 x 8 blocks, 6 MiB of dq a head
-    (32, 8192, 64, 64),                         # the LFM2 and Nemotron cells
-    (32, 8192, 128, 128),                       # the Mellum cell's full layer
-    (16, 8192, 256, 256),                       # the Qwen cell: 16 x 16 blocks of 512, 8 MiB of dq
-), ids=("kanana_cell", "lfm2_cell", "mellum_cell", "qwen_cell"))
+CALLS_8K = {
+    "kanana_cell": (32, 8192, 192, 128),        # 8 x 8 blocks, 6 MiB of dq a head
+    "lfm2_cell": (32, 8192, 64, 64),            # the LFM2 and Nemotron cells
+    "mellum_cell": (32, 8192, 128, 128),        # the Mellum cell's full layer
+    "qwen_cell": (16, 8192, 256, 256),          # 16 x 16 blocks of 512, 8 MiB of dq
+}
+at_the_8k_cells_calls = pytest.mark.parametrize(
+    "BH,S,Dk,Dv", tuple(CALLS_8K.values()), ids=tuple(CALLS_8K))
 
 
-@at_the_8k_cells_calls
+# ... and the Mellum cell's three window layers: a band of 2 of 8 blocks of 1,024 (PR 48)
+@pytest.mark.parametrize(
+    "BH,S,Dk,Dv,W", tuple((*c, None) for c in CALLS_8K.values()) + ((32, 8192, 128, 128, 1024),),
+    ids=tuple(CALLS_8K) + ("mellum_band",))
 @pytest.mark.parametrize("variant", ("plain", "lens_dlse"))
 def test_the_fused_backward_of_several_blocks_compiles_for_the_chip(
-        one_chip, flash_compiled, BH, S, Dk, Dv, variant):
+        one_chip, flash_compiled, BH, S, Dk, Dv, W, variant):
     """A causal head of several blocks takes ONE backward call that keeps the
     head's float32 dq in VMEM (``ops/attention.py:_fa_bwd_blocks``): at the four
-    8k cells' calls, plain and with ``kv_lens`` + the ``dlse`` operand, under
+    8k cells' calls and, on the band's grid and under the windowed kernels' own
+    name (what ``^%flash_attention_window`` reads on the chip), at the Mellum
+    cell's window layers; plain and with ``kv_lens`` + the ``dlse`` operand, under
     the ``vmem_limit_bytes`` the plan computes — more than Mosaic's default, and
     what it could refuse."""
     A = flash_compiled
     bf = jnp.bfloat16
     shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
     scale = Dk ** -0.5
-    plan = A._tile_plan(S, S, Dk, True, None, Dv)
+    plan = A._tile_plan(S, S, Dk, True, W, Dv)
     assert A._bwd_of(plan, Dk) is A._fa_bwd_blocks
     limit = A._blocks_vmem_bytes(plan, Dk, Dv, 2)
     assert 16 * 2 ** 20 < limit < 64 * 2 ** 20
@@ -399,13 +406,14 @@ def test_the_fused_backward_of_several_blocks_compiles_for_the_chip(
     def bwd(q, k, v, do, o, lse, lens, dlse):
         if variant == "plain":
             lens = dlse = None
-        return A._fa_bwd_pallas(q, k, v, do, o, lse, dlse, lens, True, scale, False)
+        return A._fa_bwd_pallas(q, k, v, do, o, lse, dlse, lens, True, scale, False, window=W)
 
     wide, narrow = shape((BH, S, Dk), bf), shape((BH, S, Dv), bf)
     rows = shape((BH, S, 128), jnp.float32)
     text = jax.jit(bwd).lower(wide, wide, narrow, narrow, narrow, rows,
                               shape((BH,), jnp.float32), rows).compile().as_text()
     assert text.count("tpu_custom_call") == 1
+    assert ("flash_attention_window_dqkv_blocks" in text) == (W is not None)
     assert f'"scoped_memory_configs":[{{"memory_space":"1","offset":"0","size":"{limit}"}}]' in text
 
 

@@ -167,25 +167,33 @@ def test_the_flash_mla_check_runs_its_comparison():
 def test_the_flash_fused_check_runs_its_comparison():
     """The chip check of the fused backward of several blocks at toy shapes on
     the interpreter (3 x 3 blocks of 128): the plan is the fused one, dq, dk and
-    dv are compared with the dq + dkv pair's on the same residuals, the four 8k
-    cells' calls are what it times by default (the timings are not judged
-    here), and ``main`` runs the group."""
+    dv are compared with the dq + dkv pair's on the same residuals — a windowed
+    head's on the band's grid too (PR 48) — the four 8k cells' calls and the
+    Mellum band are what it times by default (the timings are not judged here),
+    and ``main`` runs the group."""
     import inspect
     import json
 
     results = []
-    tpu_checks.check_flash_fused(results, parity=((2, 384, 192, 128), (2, 384, 64, 64)),
-                                 timed=((1, 384, 64, 64),))
+    tpu_checks.check_flash_fused(
+        results, parity=((2, 384, 192, 128), (2, 384, 64, 64), (2, 640, 64, 64, 200)),
+        timed=((1, 384, 64, 64), (1, 640, 64, 64, 200)))
     by_name = {name: (ok, info) for name, ok, info in results}
-    compared = {f"flash_fused/{shape}/{k}" for shape in ("s384_d192_128", "s384_d64")
+    compared = {f"flash_fused/{shape}/{k}"
+                for shape in ("s384_d192_128", "s384_d64", "s640_d64_w200")
                 for k in ("plan", "dq", "dk", "dv")}
-    assert set(by_name) == compared | {"flash_fused/ms_a_layer/1x384x64"}
+    timed = {"flash_fused/ms_a_layer/1x384x64", "flash_fused/ms_a_layer/1x640x64_w200"}
+    assert set(by_name) == compared | timed
     for name in compared:
         assert by_name[name][0], (name, by_name[name])
     assert "3 x 3 blocks of 128" in by_name["flash_fused/s384_d64/plan"][1]
-    assert sorted(json.loads(by_name["flash_fused/ms_a_layer/1x384x64"][1])) == ["fused", "two_calls"]
+    # a windowed head (PR 48): the same one call on the band's grid
+    assert "5 x 5 blocks of 128, a band of 3" in by_name["flash_fused/s640_d64_w200/plan"][1]
+    for name in timed:
+        assert sorted(json.loads(by_name[name][1])) == ["fused", "two_calls"]
     assert tpu_checks._FUSED_SHAPES == ((32, 8192, 192, 128), (32, 8192, 64, 64),
                                         (32, 8192, 128, 128), (16, 8192, 256, 256))
+    assert tpu_checks._BAND_SHAPES[0] == (32, 8192, 128, 128, 1024)      # the Mellum cell's
     assert "check_flash_fused" in inspect.getsource(tpu_checks.main)
 
 
