@@ -3,7 +3,7 @@ in-repo testing harnesses (examples/imagenet/main_amp.py:135,
 apex/transformer/testing/standalone_gpt.py); here they are first-class."""
 
 from beforeholiday_tpu.models import (
-    deepseek_v3, keye_vl2, lfm2_moe, mellum, nemotron_h, qwen3_next, resnet)
+    deepseek_v3, keye_vl2, kimi_linear, lfm2_moe, mellum, nemotron_h, qwen3_next, resnet)
 from beforeholiday_tpu.models.resnet import (
     CONFIGS,
     ResNetConfig,
@@ -17,6 +17,7 @@ from beforeholiday_tpu.models.resnet import (
 __all__ = [
     "deepseek_v3",
     "keye_vl2",
+    "kimi_linear",
     "lfm2_moe",
     "mellum",
     "nemotron_h",

@@ -218,37 +218,13 @@ def evens_then_odds(w):
 
 @_annotate("mla_mixer")
 def attention(cfg: DeepseekV3Config, u, p, table):
-    """One latent-attention mixer; ``table``: ``(cos, sin)`` of the sequence at
-    ``qk_rope_head_dim``.
-
-    The three projections are taken apart BY COLUMNS OF THE WEIGHTS (``W_q`` into
-    every head's 128 plain and 64 rotary columns, ``W_kva`` into the latent's and
-    the rotary key's, ``W_kvb`` into every head's keys and values), so that no
-    activation is sliced: the transpose of a slice of ``(S, 6144)`` is a
-    zero-padded copy of it and a sum (9.6 ms a step of `add_any` in the first
-    chip run of this cell), the transpose of a slice of a weight is 4 x
-    smaller and off the token axis."""
-    B, S, _ = u.shape
-    H, r = cfg.num_attention_heads, cfg.kv_lora_rank
-    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    dt = u.dtype
-    relay = evens_then_odds if cfg.rope_interleave else (lambda w: w)
-    by_head = lambda w, d: w.astype(dt).reshape(w.shape[0], H, d)
-    # x (B, S, K) through some columns of every head, w (K, H, d): (B, S, H, d)
-    project = lambda x, w: (x @ w.reshape(w.shape[0], -1)).reshape(B, S, H, -1)
-    w_q = by_head(p["w_q"], dn + dr)
-    q_nope, q_rot = project(u, w_q[..., :dn]), project(u, relay(w_q[..., dn:]))
-    with _span("mla_latent"):
-        w_kva, w_kvb = p["w_kva"].astype(dt), by_head(p["w_kvb"], dn + dv)
-        k_rot = (u @ relay(w_kva[:, r:])).reshape(B, S, 1, dr)          # one head for all
-        c = rms_norm(u @ w_kva[:, :r], p["kv_a_layernorm"], cfg.rms_norm_eps)
-        k_nope, v = project(c, w_kvb[..., :dn]), project(c, w_kvb[..., dn:])
-    q = jnp.concatenate([q_nope, _layers.apply_rotary(q_rot, *table)], axis=-1)
-    k_rot = jnp.broadcast_to(_layers.apply_rotary(k_rot, *table), (B, S, H, dr))
-    k = jnp.concatenate([k_nope, k_rot], axis=-1)
-    # a group of one; the scale is the queries' whole width: qk_head_dim^-1/2
-    ctx = _layers.grouped_query_attention(q, k, v, impl=cfg.attention_impl)
-    return ctx.reshape(B, S, H * dv) @ p["w_o"].astype(dt)
+    """One latent-attention mixer (``models.layers.latent_attention``); ``table``:
+    ``(cos, sin)`` of the sequence at ``qk_rope_head_dim``."""
+    return _layers.latent_attention(
+        u, p, heads=cfg.num_attention_heads, kv_lora_rank=cfg.kv_lora_rank,
+        qk_nope_head_dim=cfg.qk_nope_head_dim, qk_rope_head_dim=cfg.qk_rope_head_dim,
+        v_head_dim=cfg.v_head_dim, eps=cfg.rms_norm_eps, table=table,
+        relay=evens_then_odds if cfg.rope_interleave else None, impl=cfg.attention_impl)
 
 
 dense_ffn = _annotate("dense_ffn")(_layers.swiglu_ffn)

@@ -1,7 +1,8 @@
 """What the decoder models share: the shapes tree a family's parameters are
 drawn and counted from, the RMS norm, rotary tables, grouped-query attention
 (the tail every family with grouped keys ends in, and the QK-normed body two of
-them are), the causal depthwise convolution of the recurrent mixers, the SwiGLU
+them are), the latent-attention mixer (with a rotary shared key or a plain one),
+the causal depthwise convolution of the recurrent mixers, the SwiGLU
 and sigmoid-routed feed-forward parts, the layer stack's plumbing (scanned
 periods, unrolled layers), the head, the loss and the reduction of the MoE
 counters over layers.
@@ -175,6 +176,54 @@ def qk_norm_attention(u, p, table, *, heads: int, kv_heads: int, head_dim: int, 
     k = apply_rotary(rms_norm(k, p["k_norm"], eps), *table)
     ctx = grouped_query_attention(q, k, v, window=window, impl=impl, selected=selected)
     return ctx.reshape(B, S, heads * head_dim) @ p["w_o"].astype(dt)
+
+
+def latent_attention(u, p, *, heads: int, kv_lora_rank: int, qk_nope_head_dim: int,
+                     qk_rope_head_dim: int, v_head_dim: int, eps: float, table=None,
+                     relay: Optional[Callable] = None, impl: Optional[str] = None):
+    """One multi-head latent-attention mixer without a query latent on ``u (B, S,
+    D)``: ``q = u w_q`` on ``heads`` of ``[plain | shared-key part]``; ``[c |
+    k_shared] = u w_kva`` (``k_shared`` is ONE head all share); ``[k_plain | v]``
+    of each head from ``rms(c, kv_a_layernorm) w_kvb``; causal softmax of ``[q]
+    . [k_plain | k_shared]`` at the queries' whole width over ``v``; ``w_o``.
+    ``table = (cos, sin)`` at ``qk_rope_head_dim`` turns the shared-key part of
+    queries and key by the rotary embedding; None leaves both as projected (a
+    published ``mla_use_nope``). ``relay`` re-lays that part's weight columns
+    first (a published ``rope_interleave``). The shared key is broadcast over the
+    heads and joined to ``k_plain`` here, in XLA, and its cotangent summed over the
+    heads by autodiff.
+
+    The three projections are taken apart BY COLUMNS OF THE WEIGHTS (``w_q`` into
+    every head's plain and shared-key columns, ``w_kva`` into the latent's and the
+    shared key's, ``w_kvb`` into every head's keys and values), so that no
+    activation is sliced: the transpose of a slice of ``(S, 6144)`` is a
+    zero-padded copy of it and a sum (9.6 ms a step of `add_any` in the first
+    chip run of the Kanana cell), the transpose of a slice of a weight is 4 x
+    smaller and off the token axis."""
+    from beforeholiday_tpu.monitor.spans import span
+
+    B, S, _ = u.shape
+    H, r = heads, kv_lora_rank
+    dn, dr, dv = qk_nope_head_dim, qk_rope_head_dim, v_head_dim
+    dt = u.dtype
+    relay = relay or (lambda w: w)
+    turn = (lambda x: x) if table is None else (lambda x: apply_rotary(x, *table))
+    by_head = lambda w, d: w.astype(dt).reshape(w.shape[0], H, d)
+    # x (B, S, K) through some columns of every head, w (K, H, d): (B, S, H, d)
+    project = lambda x, w: (x @ w.reshape(w.shape[0], -1)).reshape(B, S, H, -1)
+    w_q = by_head(p["w_q"], dn + dr)
+    q_nope, q_rot = project(u, w_q[..., :dn]), project(u, relay(w_q[..., dn:]))
+    with span("mla_latent"):
+        w_kva, w_kvb = p["w_kva"].astype(dt), by_head(p["w_kvb"], dn + dv)
+        k_rot = (u @ relay(w_kva[:, r:])).reshape(B, S, 1, dr)          # one head for all
+        c = rms_norm(u @ w_kva[:, :r], p["kv_a_layernorm"], eps)
+        k_nope, v = project(c, w_kvb[..., :dn]), project(c, w_kvb[..., dn:])
+    q = jnp.concatenate([q_nope, turn(q_rot)], axis=-1)
+    k_rot = jnp.broadcast_to(turn(k_rot), (B, S, H, dr))
+    k = jnp.concatenate([k_nope, k_rot], axis=-1)
+    # a group of one; the scale is the queries' whole width: (dn + dr)^-1/2
+    ctx = grouped_query_attention(q, k, v, impl=impl)
+    return ctx.reshape(B, S, H * dv) @ p["w_o"].astype(dt)
 
 
 def causal_depthwise_conv(x, w):

@@ -12,6 +12,11 @@
 * ``attention`` — Pallas flash attention (``fmhalib``, ``fast_multihead_attn``).
 * ``gated_delta`` — the gated delta rule of Gated DeltaNet linear attention,
   chunk-wise, with a Pallas chunk scan (no reference equivalent).
+* ``kda`` — the same rule under a decay a key CHANNEL (Kimi Delta Attention): the
+  in-chunk scores carry the decay inside their contraction, so the lower triangle
+  is cut by halves and each level is one product whose exponents are all <= 0;
+  four Pallas kernels (the chunk-local factors and the chunk scan, each forward
+  and backward).
 * ``deltanet`` — a gated-DeltaNet layer outside its recurrence as two fused
   Pallas passes, each with its backward kernel: convolution, SiLU, L2 norms,
   the key heads' repetition and the heads-first layout; the gated output norm.
@@ -78,6 +83,7 @@ from .attention import (  # noqa: F401
 )
 from .indexer import index_select  # noqa: F401
 from .gated_delta import gated_delta_rule  # noqa: F401
+from .kda import kda_rule  # noqa: F401
 from .deltanet import deltanet_gate, deltanet_qkv  # noqa: F401
 from .short_conv import gated_short_conv  # noqa: F401
 from .ssd import ssd as state_space_dual  # noqa: F401  (``ops.ssd`` stays the module)

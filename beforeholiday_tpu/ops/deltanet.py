@@ -140,12 +140,13 @@ def _qkv_jnp(cols, filt, p: _Plan):
     return tuple(jnp.moveaxis(t, 2, 1) for t in (q, k, v))
 
 
-def _gate_jnp(o, z, weight, eps):
+def _gate_jnp(o, z, weight, eps, activation):
     from beforeholiday_tpu.ops.normalization import fused_rms_norm
 
     B, H, S, dv = o.shape
     o = fused_rms_norm(jnp.moveaxis(o, 1, 2), weight, eps=eps)
-    return (o * jax.nn.silu(z.reshape(B, S, H, dv))).reshape(B, S, H * dv)
+    act = jax.nn.silu if activation == "silu" else jax.nn.sigmoid
+    return (o * act(z.reshape(B, S, H, dv))).reshape(B, S, H * dv)
 
 
 # ---------------------------------------------------------------------------------
@@ -329,16 +330,17 @@ def _probe_qkv(cols, filt8, p):
 # ---------------------------------------------------------------------------------
 
 
-def _gate_fwd_kernel(eps, o_ref, z_ref, w_ref, y_ref):
+def _gate_fwd_kernel(eps, silu, o_ref, z_ref, w_ref, y_ref):
     group, _, dv = o_ref.shape[1:]
     for j in range(group):
         lanes = slice(j * dv, (j + 1) * dv)
         o, z = _f32(o_ref[0, j]), _f32(z_ref[0, :, lanes])
         inv = lax.rsqrt(_rowsum(o * o) * (1.0 / dv) + eps)
-        y_ref[0, :, lanes] = (o * inv * w_ref[...] * (z * lax.logistic(z))).astype(y_ref.dtype)
+        y_ref[0, :, lanes] = (o * inv * w_ref[...] * (
+            z * lax.logistic(z) if silu else lax.logistic(z))).astype(y_ref.dtype)
 
 
-def _gate_bwd_kernel(eps, o_ref, z_ref, w_ref, dy_ref, do_ref, dz_ref, dw_ref):
+def _gate_bwd_kernel(eps, silu, o_ref, z_ref, w_ref, dy_ref, do_ref, dz_ref, dw_ref):
     group, _, dv = o_ref.shape[1:]
     w = w_ref[...]
 
@@ -353,8 +355,9 @@ def _gate_bwd_kernel(eps, o_ref, z_ref, w_ref, dy_ref, do_ref, dz_ref, dw_ref):
         sig = lax.logistic(z)
         inv = lax.rsqrt(_rowsum(o * o) * (1.0 / dv) + eps)
         xn = o * inv
-        da = dy * (z * sig)
-        dz_ref[0, :, lanes] = (dy * (xn * w) * (sig * (1.0 + z * (1.0 - sig)))).astype(dz_ref.dtype)
+        da = dy * (z * sig if silu else sig)
+        dz_ref[0, :, lanes] = (dy * (xn * w) * (
+            sig * (1.0 + z * (1.0 - sig)) if silu else sig * (1.0 - sig))).astype(dz_ref.dtype)
         dw_ref[...] += jnp.sum(da * xn, axis=0, keepdims=True)
         dn = da * w
         do_ref[0, j] = (inv * (dn - xn * (_rowsum(dn * xn) * (1.0 / dv)))).astype(do_ref.dtype)
@@ -368,16 +371,17 @@ def _gate_specs(group, tile, dv):
     return heads, cols, weight
 
 
-# ``group`` heads and ``tile`` rows a grid step: static, the key of the ``jax.jit``
-_GATE_STATICS = ("group", "tile", "eps")
+# ``group`` heads and ``tile`` rows a grid step, and whether the gate is SiLU (else
+# the sigmoid): static, the key of the ``jax.jit``
+_GATE_STATICS = ("group", "tile", "eps", "silu")
 
 
 @functools.partial(jax.jit, static_argnames=_GATE_STATICS)
-def _gate_fwd(o, z, w, group: int, tile: int, eps: float):
+def _gate_fwd(o, z, w, group: int, tile: int, eps: float, silu: bool = True):
     B, H, S, dv = o.shape
     heads, cols, weight = _gate_specs(group, tile, dv)
     return pl.pallas_call(
-        functools.partial(_gate_fwd_kernel, eps),
+        functools.partial(_gate_fwd_kernel, eps, silu),
         grid=(B, S // tile, H // group),
         in_specs=[heads, cols, weight],
         out_specs=cols,
@@ -389,11 +393,11 @@ def _gate_fwd(o, z, w, group: int, tile: int, eps: float):
 
 
 @functools.partial(jax.jit, static_argnames=_GATE_STATICS)
-def _gate_bwd(o, z, w, dy, group: int, tile: int, eps: float):
+def _gate_bwd(o, z, w, dy, group: int, tile: int, eps: float, silu: bool = True):
     B, H, S, dv = o.shape
     heads, cols, weight = _gate_specs(group, tile, dv)
     return pl.pallas_call(
-        functools.partial(_gate_bwd_kernel, eps),
+        functools.partial(_gate_bwd_kernel, eps, silu),
         grid=(B, S // tile, H // group),
         in_specs=[heads, cols, weight, cols],
         out_specs=[heads, cols, weight],
@@ -405,25 +409,25 @@ def _gate_bwd(o, z, w, dy, group: int, tile: int, eps: float):
     )(o, z, w, dy.astype(z.dtype))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _gate_pallas(o, z, w, group: int, tile: int, eps: float):
-    return _gate_fwd(o, z, w, group=group, tile=tile, eps=eps)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _gate_pallas(o, z, w, group: int, tile: int, eps: float, silu: bool):
+    return _gate_fwd(o, z, w, group=group, tile=tile, eps=eps, silu=silu)
 
 
-def _gate_pallas_fwd(o, z, w, group, tile, eps):
-    return _gate_fwd(o, z, w, group=group, tile=tile, eps=eps), (o, z, w)
+def _gate_pallas_fwd(o, z, w, group, tile, eps, silu=True):
+    return _gate_fwd(o, z, w, group=group, tile=tile, eps=eps, silu=silu), (o, z, w)
 
 
-def _gate_pallas_bwd(group, tile, eps, res, dy):
-    return tuple(_gate_bwd(*res, dy, group=group, tile=tile, eps=eps))
+def _gate_pallas_bwd(group, tile, eps, silu, res, dy):
+    return tuple(_gate_bwd(*res, dy, group=group, tile=tile, eps=eps, silu=silu))
 
 
 _gate_pallas.defvjp(_gate_pallas_fwd, _gate_pallas_bwd)
 
 
-def _probe_gate(o, z, w, group, tile, eps):
+def _probe_gate(o, z, w, group, tile, eps, silu):
     """Guard probe: both kernels must build."""
-    y, vjp = jax.vjp(lambda *a: _gate_pallas(*a, group, tile, eps), o, z, w)
+    y, vjp = jax.vjp(lambda *a: _gate_pallas(*a, group, tile, eps, silu), o, z, w)
     vjp(jnp.zeros_like(y))
     return y
 
@@ -466,8 +470,9 @@ def deltanet_qkv(cols: jax.Array, filt: jax.Array, *, key_heads: int, value_head
 
 
 def deltanet_gate(o: jax.Array, z: jax.Array, weight: jax.Array, *, eps: float,
-                  impl: Optional[str] = None) -> jax.Array:
-    """``rms_norm(o) * weight * silu(z)``, heads first to columns.
+                  activation: str = "silu", impl: Optional[str] = None) -> jax.Array:
+    """``rms_norm(o) * weight * act(z)``, heads first to columns; ``activation``:
+    ``"silu"`` (gated DeltaNet) or ``"sigmoid"`` (Kimi Delta Attention).
 
     ``o``: ``(B, H, S, d_v)`` (the delta rule's output as its scan leaves it),
     ``z``: ``(B, S, H d_v)``, ``weight``: ``(d_v,)``. Returns ``(B, S, H d_v)`` in
@@ -476,15 +481,17 @@ def deltanet_gate(o: jax.Array, z: jax.Array, weight: jax.Array, *, eps: float,
     if z.shape != (B, S, H * dv) or weight.shape != (dv,):
         raise ValueError(
             f"deltanet_gate shapes mismatch: o {o.shape} z {z.shape} weight {weight.shape}")
+    if activation not in ("silu", "sigmoid"):
+        raise ValueError(f"activation must be 'silu' or 'sigmoid', got {activation!r}")
     statics = (next(g for g in (4, 2, 1) if H % g == 0),    # heads a grid step: 512 lanes at 128
-               _row_tile(S), float(eps))
+               _row_tile(S), float(eps), activation == "silu")
     impl, forced = _dispatch(
         "deltanet_gate", impl, is_kernel_available(S, dv, dv),
         f"S {S} is not whole tiles of {_ROW_TILES[-1]} rows or d_v {dv} not a multiple "
-        f"of {_LANES}", o, z, statics=())
+        f"of {_LANES}", o, z, statics=(activation,))
     with _span("deltanet_gate"):
         if impl == "pallas":
             w = weight.astype(_F32).reshape(1, dv)
             if forced or _checked_impl("deltanet_gate", impl, _probe_gate, o, z, w, *statics) == impl:
                 return _gate_pallas(o, z, w, *statics)
-        return _gate_jnp(o, z, weight, eps)
+        return _gate_jnp(o, z, weight, eps, activation)

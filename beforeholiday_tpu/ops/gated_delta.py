@@ -223,6 +223,30 @@ def _mm3(a, b, dims=_NN):
     return lax.add(lax.add(_bdot(ah, bl, dims), _bdot(al, bh, dims)), _bdot(ah, bh, dims))
 
 
+def _tile_inverse(L, eye, block, zero):
+    """``(I + L)^-1`` of strictly lower-triangular tiles ``L (G, C, C)`` inside a
+    kernel, by the scheme of ``_inverse``; ``eye`` and ``block = i ^ j`` are the
+    caller's masks of that shape. I + power splits into (I + hi, lo), so
+    T (I + power) = T + T power and the identity is never split."""
+    cc = L.shape
+    C = cc[-1]
+    base = min(_BASE, C)
+    within = lambda size: lax.lt(block, lax.full(cc, size, jnp.int32))
+    power = lax.neg(lax.select(within(base), L, zero))
+    T = lax.add(lax.select(eye, lax.full(cc, 1.0, _F32), zero), power)
+    power = _split(power)
+    for _ in range(max(base.bit_length() - 2, 0)):
+        power = _split(_mm3(power, power))
+        T = lax.add(T, _mm3(_split(T), power))
+    size = base
+    while size < C:
+        corners = lax.select(lax.ne(within(2 * size), within(size)), L, zero)
+        Ts = _split(T)
+        T = lax.sub(T, _mm3(_split(_mm3(Ts, _split(corners))), Ts))
+        size *= 2
+    return T
+
+
 @jax.jit      # traced once a shape: each later trace of a kernel body binds one call
 def _wy_tiles(q, k, v, rows):
     """What both kernels compute of the ``G`` chunks of a grid step: ``q, k
@@ -252,23 +276,7 @@ def _wy_tiles(q, k, v, rows):
     kbf = _cast(kb, _F32)
     kk = _bdot(kb, k, _NT)
     L = lax.select(strict, lax.mul(kk, decay), zero)
-
-    # (I + L)^-1 by the scheme of ``_inverse``. I + power splits into (I + hi, lo),
-    # so T (I + power) = T + T power and the identity is never split
-    base = min(_BASE, C)
-    within = lambda size: lax.lt(block, lax.full(cc, size, jnp.int32))
-    power = lax.neg(lax.select(within(base), L, zero))
-    T = lax.add(lax.select(eye, lax.full(cc, 1.0, _F32), zero), power)
-    power = _split(power)
-    for _ in range(max(base.bit_length() - 2, 0)):
-        power = _split(_mm3(power, power))
-        T = lax.add(T, _mm3(_split(T), power))
-    size = base
-    while size < C:
-        corners = lax.select(lax.ne(within(2 * size), within(size)), L, zero)
-        Ts = _split(T)
-        T = lax.sub(T, _mm3(_split(_mm3(Ts, _split(corners))), Ts))
-        size *= 2
+    T = _tile_inverse(L, eye, block, zero)
     rhs = lax.concatenate([lax.mul(vf, _bcast(beta, vf.shape)),
                            lax.mul(kbf, _bcast(gamma, kf.shape))], 2)
     Ts = _split(T)

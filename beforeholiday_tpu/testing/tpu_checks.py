@@ -345,6 +345,89 @@ def check_wy_prepare(results: list) -> None:
           json.dumps({n: round(t, 3) for n, t in ms.items()}))
 
 
+def check_kda(results: list, H: int = 32, S: int = 8192, d: int = 128, parity_heads: int = 8,
+              chunks=(64, 128), groups=(1, 2, 4)) -> None:
+    """The delta rule under a decay a key channel (``ops.kda``), compiled, at the
+    Kimi-Linear cell's shape ``(1, 32, 8192, 128)``, bfloat16: ``o, dq, dk, dv, dg,
+    dbeta`` of the four kernels against the ``jnp`` form (on ``parity_heads`` heads:
+    the ``jnp`` form streams every level's float32 tiles through HBM) at the gate's
+    initial range AND at the strongest decay the parameters allow (``e^{A_log}``
+    16, ``softplus`` of +4: -64 a token a channel; nothing may overflow or be NaN),
+    and the time a layer forward + backward at each chunk size, with each kernel's
+    own (the two state-free ones at ``groups`` chunks a grid step). A smaller ``H``
+    / ``S`` is the CPU rehearsal."""
+    from beforeholiday_tpu.ops import kda
+
+    def check(name, cond, info=""):
+        results.append((f"kda/{name}", bool(cond), str(info)))
+
+    bf = jnp.bfloat16
+    ks = jax.random.split(jax.random.PRNGKey(11), 8)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = (unit(jax.random.normal(ks[0], (1, H, S, d))) * d ** -0.5).astype(bf)
+    k = unit(jax.random.normal(ks[1], (1, H, S, d))).astype(bf)
+    v = jax.random.normal(ks[2], (1, H, S, d)).astype(bf)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[3], (1, H, S)))
+    do = jax.random.normal(ks[4], (1, H, S, d)).astype(bf)
+    # the gate as the model draws it: -e^{A_log} softplus(a + dt_bias), A_log = log U(1, 16)
+    # a head, dt_bias the inverse softplus of a step log-uniform in 0.001 .. 0.1
+    rate = jax.random.uniform(ks[5], (1, H, 1, 1), jnp.float32, 1.0, 16.0)
+    step = jnp.exp(jax.random.uniform(ks[6], (1, H, 1, d), jnp.float32,
+                                      np.log(1e-3), np.log(1e-1)))
+    a = 0.22 * jax.random.normal(ks[7], (1, H, S, d))
+    gates = {"initial": -rate * jax.nn.softplus(a + jnp.log(jnp.expm1(step))),
+             "strongest": jnp.full((1, H, S, d), -16.0 * float(jax.nn.softplus(4.0)))}
+
+    def both(impl, chunk):
+        def run(q, k, v, g, beta, do):
+            o, pull = jax.vjp(lambda *a: kda.kda_rule(*a, chunk=chunk, impl=impl,
+                                                      heads_first=True), q, k, v, g, beta)
+            return (o,) + pull(do)
+        return jax.jit(run)
+
+    heads = lambda t: t[:, :parity_heads]
+    for gate, g in gates.items():
+        args = tuple(heads(t) for t in (q, k, v, g, beta, do))
+        for chunk in chunks:
+            got, want = both("pallas", chunk)(*args), both("jnp", chunk)(*args)
+            for name, x, y in zip(("o", "dq", "dk", "dv", "dg", "dbeta"), got, want):
+                x, y = x.astype(jnp.float32), y.astype(jnp.float32)
+                gap, scale = float(jnp.max(jnp.abs(x - y))), float(jnp.max(jnp.abs(y)))
+                ok = bool(jnp.all(jnp.isfinite(x))) and gap <= 2e-2 * max(scale, 1e-20)
+                check(f"parity/{gate}/c{chunk}/{name}", ok, f"max|d|={gap:.3e} of {scale:.3e}")
+
+    ms = {}
+    chunked = lambda t, C: t.reshape(H, S // C, C, *t.shape[3:])
+    for chunk in chunks:
+        g = gates["initial"]
+        fn = both("pallas", chunk)
+        ms[f"fwd_bwd@{chunk}"] = 1e3 * _min_step_seconds(lambda _: fn(q, k, v, g, beta, do), None)
+        res = jax.jit(kda._operands)(*(chunked(t, chunk) for t in (q, k, v, g, beta)))
+        facts = jax.jit(lambda *res: kda._factors(res, H))(*res)
+        s0 = jax.jit(kda._scan_fwd)(*facts)[1]
+        cts = tuple(jnp.ones_like(t).reshape(-1, *t.shape[2:]) for t in facts[:5]) \
+            + (jnp.ones((res[3].shape[0], d), jnp.float32),)
+        calls = {
+            "scan_fwd": (jax.jit(kda._scan_fwd), facts),
+            "scan_bwd": (jax.jit(kda._scan_bwd), facts + (s0, chunked(do, chunk))),
+        }
+        for group in groups:           # chunks a grid step of the two state-free kernels
+            kept, kda._GROUP = kda._GROUP, group     # read when a call is traced
+            for name, fn, args in (("prepare_fwd", kda._prepare_fwd, res),
+                                   ("prepare_bwd", kda._prepare_bwd, res + cts)):
+                call = jax.jit(lambda *a, fn=fn: fn(*a))    # a fresh function each
+                try:
+                    ms[f"{name}/{group}@{chunk}"] = 1e3 * _min_step_seconds(
+                        lambda _: call(*args), None)
+                except Exception as e:  # noqa: BLE001 — a plan Mosaic refuses is a reading too
+                    ms[f"{name}/{group}@{chunk}"] = f"{type(e).__name__}: {str(e)[:80]}"
+            kda._GROUP = kept
+        for name, (call, args) in calls.items():
+            ms[f"{name}@{chunk}"] = 1e3 * _min_step_seconds(lambda _: call(*args), None)
+    check("ms_a_layer", True, json.dumps(
+        {n: round(t, 3) if isinstance(t, float) else t for n, t in ms.items()}))
+
+
 def check_deltanet(results: list, S: int = 8192, Hk: int = 16) -> None:
     """The DeltaNet layer's two fused passes (``ops.deltanet``), compiled, at the
     Qwen cell's shape (8,192 rows, 16 key and 32 value heads of 128, a filter of
@@ -1544,7 +1627,7 @@ def main() -> int:
 
     enable_compile_cache()
     results: list = []
-    for group in (check_flash_dropout, check_flash_tiles, check_wy_prepare, check_deltanet,
+    for group in (check_flash_dropout, check_flash_tiles, check_wy_prepare, check_kda, check_deltanet,
                   check_short_conv, check_flash_mla, check_flash_fused, check_flash_fwd_live,
                   check_index_select, check_flash_sparse,
                   check_grouped_matmul, check_moe_rows, check_ssd, check_aliased_mt_kernels,

@@ -260,6 +260,77 @@ def test_the_qwen_step_compiled_for_the_chip_keeps_what_the_deltanet_kernels_gav
     assert compiled.memory_analysis().temp_size_in_bytes <= 6.25 * 2 ** 30
 
 
+@pytest.mark.parametrize("chunk", (64, 128))
+def test_the_kda_kernels_compile_for_the_chip(one_chip, monkeypatch, chunk):
+    """The four kernels of ``ops/kda.py`` at the Kimi-Linear cell's shape (32
+    heads of 128, 8,192 tokens, bfloat16 with a float32 gate), at both chunk
+    sizes: the state-free pair — whose backward body is ``jax.vjp`` of the
+    forward's function, traced inside the kernel — and the chunk scan's; the
+    backward pass runs the two forward kernels a second time."""
+    from beforeholiday_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "_interpret_default", lambda: False)
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    bf, f32 = jnp.bfloat16, jnp.float32
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    B, H, S, d = 1, 32, 8192, 128
+
+    def both(q, k, v, g, beta, do):
+        o, pull = jax.vjp(lambda *a: kda.kda_rule(*a, chunk=chunk, impl="pallas",
+                                                  heads_first=True), q, k, v, g, beta)
+        return o, pull(do)
+
+    heads_first = shape((B, H, S, d), bf)
+    try:
+        text = jax.jit(both).lower(
+            heads_first, heads_first, heads_first, shape((B, H, S, d), f32),
+            shape((B, H, S), f32), heads_first).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+    calls = {k: len(set(re.findall(rf"%{k}[.\d]* = ", text)))
+             for k in ("kda_prepare_fwd", "kda_prepare_bwd", "kda_scan_fwd", "kda_scan_bwd")}
+    assert calls == {"kda_prepare_fwd": 2, "kda_prepare_bwd": 1, "kda_scan_fwd": 2,
+                     "kda_scan_bwd": 1}, calls
+    assert text.count("tpu_custom_call") == 6
+
+
+def test_the_kimi_step_compiled_for_the_chip_runs_no_kernel_twice_over(topo, monkeypatch):
+    """The whole step of ``kimi-linear-48b-a3b.train-s8k`` compiled for a described
+    v5e (``tools/offline_step.py``; ~50 s, nothing runs). Its 9.64 GB of state
+    leave the sequence 6 GB, and the compiler rematerialises what does not fit
+    (the four ``x @ W_qkv`` products and one of the dense layer's: 5 arrays; with
+    the scan's five operands kept as residuals it was 19, PERF.md, PR 49). Held
+    to: no flash, ``kda`` or ``deltanet`` kernel beyond what the ``custom_vjp``
+    rules ask for (a rematerialised kernel would be a layer's time again), at
+    most 8 rematerialised arrays, and temporaries under 7.2 GiB."""
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "tools"))
+    import offline_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")     # resolve_impl -> pallas
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled, _ = offline_step.compile_cell("kimi-linear-48b-a3b.train-s8k", topo)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+    text = compiled.as_text()
+    calls = lambda kernel: len(set(re.findall(rf"%{kernel}[.\d]* = ", text)))
+    # four KDA layers: each forward kernel once forward and once more backward
+    for kernel, want in (("kda_prepare_fwd", 8), ("kda_prepare_bwd", 4), ("kda_scan_fwd", 8),
+                         ("kda_scan_bwd", 4), ("deltanet_qkv_fwd", 4), ("deltanet_qkv_bwd", 4),
+                         ("deltanet_gate_fwd", 4), ("deltanet_gate_bwd", 4)):
+        assert calls(kernel) == want, (kernel, calls(kernel))
+    assert calls("flash_attention") == 2            # the one latent layer: forward, fused backward
+    made = [l for l in text.splitlines() if re.match(r"\s*(ROOT )?%\S+ = ", l)]
+    remat = [l for l in made if re.match(r"\s*(ROOT )?%\S*\.remat\S* = ", l)]
+    assert len(remat) <= 8, len(remat)
+    assert not [l for l in remat if "f32[8192,20480]" in l or "f32[1,8192,20480]" in l]
+    assert compiled.memory_analysis().temp_size_in_bytes <= 7.2 * 2 ** 30
+
+
 @pytest.mark.parametrize("batch,S,D,K", ((1, 8192, 2048, 3), (2, 1024, 256, 4), (1, 48, 128, 8)),
                          ids=("lfm2_mixer", "two_sequences_four_taps", "tiles_of_16_rows"))
 def test_the_short_conv_kernels_compile_for_the_chip(one_chip, monkeypatch, batch, S, D, K):

@@ -286,6 +286,62 @@ def test_the_mixer_hands_flash_the_two_widths_unpadded():
     assert sliced and all(shape[-1] == 16 for shape in sliced if shape[:2] == (1, 128)), sliced
 
 
+def _mixer_as_it_stood(cfg, u, p, table, rotary=True):
+    """``models.deepseek_v3.attention`` as PR 42 wrote it, before its body moved
+    to ``models.layers.latent_attention`` (PR 49); ``rotary`` false leaves the
+    shared-key part as projected."""
+    layers = model._layers
+    B, S, _ = u.shape
+    H, r = cfg.num_attention_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    dt = u.dtype
+    relay = model.evens_then_odds if cfg.rope_interleave else (lambda w: w)
+    turn = (lambda x: layers.apply_rotary(x, *table)) if rotary else (lambda x: x)
+    by_head = lambda w, d: w.astype(dt).reshape(w.shape[0], H, d)
+    project = lambda x, w: (x @ w.reshape(w.shape[0], -1)).reshape(B, S, H, -1)
+    w_q = by_head(p["w_q"], dn + dr)
+    q_nope, q_rot = project(u, w_q[..., :dn]), project(u, relay(w_q[..., dn:]))
+    w_kva, w_kvb = p["w_kva"].astype(dt), by_head(p["w_kvb"], dn + dv)
+    k_rot = (u @ relay(w_kva[:, r:])).reshape(B, S, 1, dr)
+    c = model.rms_norm(u @ w_kva[:, :r], p["kv_a_layernorm"], cfg.rms_norm_eps)
+    k_nope, v = project(c, w_kvb[..., :dn]), project(c, w_kvb[..., dn:])
+    q = jnp.concatenate([q_nope, turn(q_rot)], axis=-1)
+    k_rot = jnp.broadcast_to(turn(k_rot), (B, S, H, dr))
+    k = jnp.concatenate([k_nope, k_rot], axis=-1)
+    ctx = layers.grouped_query_attention(q, k, v, impl=cfg.attention_impl)
+    return ctx.reshape(B, S, H * dv) @ p["w_o"].astype(dt)
+
+
+@pytest.mark.parametrize("rotary", (True, False), ids=("rotary", "no-rotary"))
+@pytest.mark.parametrize("dtype", (jnp.float32, jnp.bfloat16), ids=("f32", "bf16"))
+def test_the_moved_mixer_is_bit_for_bit_the_one_that_stood_here(rotary, dtype):
+    """PR 49 moved the mixer's body to ``models.layers.latent_attention`` with the
+    rotary table optional: with the table it is the former function bit for bit,
+    output and cotangents; without one (a published ``mla_use_nope``) it is that
+    function with the rotation taken out, and differs from the rotated one."""
+    cfg = dict(SHARE, seq_len=128)
+    p = reference._group(_weights(cfg, seed=5), "layers.1")
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, 128, 64)).astype(dtype)
+    mcfg = _mcfg(cfg, attention_impl="jnp", dtype=dtype)
+    table = model._layers.rotary_table(128, mcfg.qk_rope_head_dim, mcfg.rope_theta)
+    keys = ("w_q", "w_kva", "kv_a_layernorm", "w_kvb", "w_o")
+    mixer = {k: p[k] for k in keys}
+    if rotary:
+        moved = lambda x, p: model.attention(mcfg, x, p, table)
+    else:
+        moved = lambda x, p: model._layers.latent_attention(
+            x, p, heads=4, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+            v_head_dim=16, eps=mcfg.rms_norm_eps, relay=model.evens_then_odds, impl="jnp")
+    got, pull = jax.vjp(moved, x, mixer)
+    want, pull_want = jax.vjp(lambda x, p: _mixer_as_it_stood(mcfg, x, p, table, rotary), x, mixer)
+    np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+    for a, b in zip(jax.tree.leaves(pull(want)), jax.tree.leaves(pull_want(want))):
+        np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
+    other = _mixer_as_it_stood(mcfg, x, mixer, table, not rotary).astype(jnp.float32)
+    assert float(jnp.max(jnp.abs(other - want.astype(jnp.float32)))) \
+        > 1e-3 * float(jnp.max(jnp.abs(want.astype(jnp.float32))))
+
+
 # -- the expert layer ----------------------------------------------------------------
 
 def test_the_models_expert_layer_is_the_references():

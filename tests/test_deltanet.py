@@ -286,3 +286,35 @@ def test_the_kernels_are_named_for_the_trace_and_not_after_the_delta_rule():
     for span in ("deltanet_qkv", "deltanet_gate"):
         assert span in text
     assert "gated_delta" not in text and "layer_norm" not in text
+
+
+# -- the gate's activation (PR 49): the sigmoid of a Kimi Delta Attention layer --------
+
+@pytest.mark.parametrize("what", ("y", "do", "dz", "dw"))
+@pytest.mark.parametrize("impl", ("pallas", "jnp"))
+def test_the_sigmoid_gate_is_written_out(impl, what):
+    """``activation="sigmoid"``: ``rms_norm(o) * w * sigmoid(z)`` and its three
+    cotangents against the expression written out and differentiated by XLA, in
+    the kernels and in the chain; the default stays SiLU."""
+    o, z, w, dy = _gate_inputs(2, 96, 4, seed=11)
+
+    def written_out(o, z, w):
+        x = jnp.moveaxis(o, 1, 2)
+        x = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) * w
+        return (x * jax.nn.sigmoid(z.reshape(x.shape))).reshape(z.shape)
+
+    y, pull = jax.vjp(written_out, o, z, w)
+    want = dict(zip(("y", "do", "dz", "dw"), (y,) + pull(dy)))
+    y, pull = jax.vjp(lambda o, z, w: dn.deltanet_gate(o, z, w, eps=1e-6, activation="sigmoid",
+                                                       impl=impl), o, z, w)
+    got = dict(zip(("y", "do", "dz", "dw"), (y,) + pull(dy)))
+    _close(got[what], want[what], what)
+    silu = _gate(impl, o, z, w, dy)
+    assert np.max(np.abs(np.asarray(silu[what]) - np.asarray(want[what]))) \
+        > 1e-2 * np.max(np.abs(np.asarray(want[what]))), what
+
+
+def test_an_activation_that_is_not_built_raises():
+    o, z, w, _ = _gate_inputs(1, 32, 2)
+    with pytest.raises(ValueError, match="silu.*sigmoid"):
+        dn.deltanet_gate(o, z, w, eps=1e-6, activation="tanh")

@@ -1002,3 +1002,128 @@ def test_the_sparse_kernels_carry_their_names_under_the_mixer_in_both_passes():
             assert ("indexer_select" in n) == (k == "index_select"), n
             assert not re.search(r"grouped_matmul|/moe/|window", n), n
     assert "flash_attention_window" not in text
+
+
+# ---------------------------------------------------------------------------
+# family kimi_linear (PR 49): kda_mixer with its five inner scopes, mla_mixer
+# without a rotary table, dense_ffn, the MoE with its shared expert and the
+# model's four scopes
+# ---------------------------------------------------------------------------
+
+_KIMI_MODEL = ("kimi_linear_embed", "kimi_linear_layers", "kimi_linear_head", "kimi_linear_loss")
+_KIMI_INNER = ("kda_proj", "deltanet_qkv", "kda_gate_proj", "kda", "deltanet_gate")
+_KIMI_SCOPES = _KIMI_MODEL + _KIMI_INNER + (
+    "kda_mixer", "mla_mixer", "mla_latent", "dense_ffn", "amp_forward",
+    "amp_backward", "amp_unscale", "fused_adam_step_flat", "layer_norm", "flash_attention",
+    "moe_route", "moe_dispatch", "moe_experts", "moe_shared", "moe_combine")
+
+
+@pytest.fixture(scope="module")
+def kimi_names():
+    """The distinct ``op_name`` of every op of the compiled tiny-kimi-linear step."""
+    from benchmark import run as bench_run
+
+    cell = bench_run.load("workloads", "tiny-kimi-linear.train")
+    run = bench_run.Cell(cell, bench_run.load("configs", cell["config"]), jax.devices()[:1])
+    run.start(7)
+    run.build()
+    compiled = run.program.step.jitted.lower(run.state, run.pool[0]).compile()
+    return sorted(set(re.findall(r'op_name="(jit\([^"]+)"', compiled.as_text())))
+
+
+@pytest.mark.parametrize("scope", _KIMI_SCOPES)
+def test_kimi_linear_scope_is_in_the_compiled_step(kimi_names, scope):
+    assert any(scope in _scopes_of(n) or f"jvp({scope})" in n for n in kimi_names), scope
+
+
+def test_kimi_linear_first_level_scopes_partition_the_step(kimi_names):
+    first = ("amp_backward", "amp_unscale", "ddp_reduce_gradients",
+             "ddp_overlap_hook", "fused_adam_step_flat")
+    twice = [n for n in kimi_names
+             if sum(s in n for s in first) + (_pass_of(n) == "amp_forward") > 1]
+    assert not twice
+    # (off the TPU the rule's chunk-local algebra is a checkpointed function: what the
+    # backward pass recomputes of it carries its forward names behind the backward's)
+    both = [n for n in kimi_names if "amp_forward" in n and "amp_backward" in n
+            and not re.search(r"/kda/(checkpoint|remat2)", n)]
+    assert all("amp_backward/transpose(amp_forward)" in n for n in both), both
+    for scope in _KIMI_MODEL:        # the model's scopes survive inside both passes
+        assert any(f"amp_forward/jvp({scope})" in n for n in kimi_names), scope
+        assert any(_pass_of(n) == "amp_backward" and f"jvp({scope})" in n
+                   for n in kimi_names), scope
+
+
+def test_kimi_linear_second_level_scopes_do_not_overlap(kimi_names):
+    """An op is under one model scope at most, and under one of the two mixers,
+    the dense feed-forward part or the MoE at most; the five inner scopes of
+    ``kda_mixer`` lie inside it and not inside each other, ``mla_latent`` and ``flash_attention`` inside ``mla_mixer``,
+    ``moe_shared`` inside ``moe``, all inside ``kimi_linear_layers``; no name of
+    the family holds the scalar rule's metric pattern or another mixer's."""
+    in_path = lambda s, n: any(s == part.strip("()").split("(")[-1] for part in _scopes_of(n))
+    for n in kimi_names:
+        assert sum(f"({s})" in n or s in _scopes_of(n) for s in _KIMI_MODEL) <= 1, n
+        parts = [s for s in ("kda_mixer", "mla_mixer", "dense_ffn", "moe") if in_path(s, n)]
+        assert len(parts) <= 1, n
+        if parts and _pass_of(n):
+            assert "kimi_linear_layers" in n, n
+        inner = [s for s in _KIMI_INNER if in_path(s, n)]
+        assert len(inner) <= 1, n
+        if inner:
+            assert parts == ["kda_mixer"], n
+        if [s for s in ("mla_latent", "flash_attention") if in_path(s, n)]:
+            assert parts == ["mla_mixer"], n
+        if in_path("moe_shared", n):
+            assert parts == ["moe"], n
+        assert not re.search(r"gated_delta|ssd|window_mixer|full_mixer|ssm_mixer|attn_mixer|"
+                             r"conv_mixer|short_conv|moe_latent|linear_mixer|sparse_mixer", n), n
+    heavy = [n for n in kimi_names if n.endswith("dot_general")]
+    assert heavy and not [n for n in heavy if _pass_of(n) is None]
+    # every product of the mixer outside the rule is under one of the two projection scopes
+    mixer = [n for n in heavy if in_path("kda_mixer", n) and not in_path("kda", n)]
+    assert mixer and all(in_path("kda_proj", n) or in_path("kda_gate_proj", n) for n in mixer)
+    for scope in ("kda_proj", "kda_gate_proj", "kda"):
+        assert {_pass_of(n) for n in kimi_names if in_path(scope, n)} \
+            >= {"amp_forward", "amp_backward"}, scope
+
+
+def test_the_kda_kernels_are_named_by_the_op_and_lie_under_its_scopes():
+    """``kda_ms`` reads the ``kda`` scope, ``kda_roofline`` the kernels' own ``name=``
+    (the chip prints ``%kda_prepare_fwd.N``, ``%kda_scan_bwd.N``, ...): the four
+    kernels lie under ``kda_mixer/kda``, the forward ones under ``amp_forward``
+    and the backward ones (and the second run of the two forward ones, which
+    the backward pass makes its factors and chunk-start states with) under
+    ``amp_backward``; the layer's other two passes
+    are ``ops.deltanet``'s, under their own scopes."""
+    from beforeholiday_tpu.models import kimi_linear
+    from beforeholiday_tpu.ops import deltanet, kda
+
+    force = lambda fn: (lambda *a, **kw: fn(*a, **{**kw, "impl": "pallas"}))
+    cfg = kimi_linear.KimiLinearConfig(
+        hidden_size=128, dtype=jnp.bfloat16, kda_chunk=64,
+        linear_attn_config=dict(kda_layers=(1, 2, 3), full_attn_layers=(4,), num_heads=2,
+                                head_dim=128, short_conv_kernel_size=4))
+    p = kimi_linear.init(jax.random.PRNGKey(0), cfg)["layers"][0]
+    x = jnp.zeros((1, 128, cfg.hidden_size), jnp.bfloat16)
+    svag = amp.scaled_value_and_grad(
+        lambda p, x: jnp.sum(kimi_linear.kda_attention(cfg, x, p).astype(jnp.float32)),
+        LossScaler(loss_scale=1.0))
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(kda, "kda_rule", force(kda.kda_rule))
+        mp.setattr(deltanet, "deltanet_qkv", force(deltanet.deltanet_qkv))
+        mp.setattr(deltanet, "deltanet_gate", force(deltanet.deltanet_gate))
+        text = jax.jit(svag).lower(p, LossScaler(loss_scale=1.0).init(), x).as_text(debug_info=True)
+    names = set(re.findall(r'loc\("([^"]+)"', text))
+    kernels = {k: [n for n in names if f"/{k}/pallas_call" in n]
+               for k in ("kda_prepare_fwd", "kda_prepare_bwd", "kda_scan_fwd", "kda_scan_bwd")}
+    for scope in ("deltanet_qkv", "deltanet_gate"):     # their kernels sit in a jit of their own
+        assert any(re.search(rf"kda_mixer\)*/{scope}\)*/", n) for n in names), scope
+    assert all(kernels.values()), {k: len(v) for k, v in kernels.items()}
+    for k, found in kernels.items():
+        for n in found:
+            assert "kda_mixer" in n, n
+            assert re.search(r"kda_mixer\)*/kda\)*/", n), n
+            if k.endswith("_bwd"):
+                assert _pass_of(n) == "amp_backward", n
+            assert not re.search(r"gated_delta|grouped_matmul|/moe/|flash_attention", n), n
+    for k in ("kda_prepare_fwd", "kda_scan_fwd"):
+        assert {_pass_of(n) for n in kernels[k]} == {"amp_forward", "amp_backward"}, k
