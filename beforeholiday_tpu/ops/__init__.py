@@ -15,8 +15,8 @@
 * ``kda`` — the same rule under a decay a key CHANNEL (Kimi Delta Attention): the
   in-chunk scores carry the decay inside their contraction, so the lower triangle
   is cut by halves and each level is one product whose exponents are all <= 0;
-  four Pallas kernels (the chunk-local factors and the chunk scan, each forward
-  and backward).
+  two Pallas kernels, one a pass: each makes a grid step's chunk factors in VMEM
+  and walks the chunk scan over them.
 * ``deltanet`` — a gated-DeltaNet layer outside its recurrence as two fused
   Pallas passes, each with its backward kernel: convolution, SiLU, L2 norms,
   the key heads' repetition and the heads-first layout; the gated output norm.

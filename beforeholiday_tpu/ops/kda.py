@@ -38,18 +38,23 @@ Diag(e^{G_C}) S + (k e^{G_C - G})^T delta``.
   chunk of every head at once, differentiated by XLA and recomputed in the
   backward pass: the kernels' parity oracle, ``impl="jnp"`` and the off-TPU
   default (with :func:`_scan_jnp`, a ``lax.scan`` over chunks).
-* On the Pallas path ``kda_prepare_fwd`` / ``kda_prepare_bwd`` hold a chunk's
-  tiles in VMEM from the operands to the factors, the running sum ``G`` among
-  them (a product with the triangular ones on three exact parts of ``g``:
-  :func:`_rows_through`); the backward kernel recomputes them and transposes the SAME
-  function (``jax.vjp`` inside the kernel body: the three pieces that must not be
+* On the Pallas path ONE kernel a pass makes a grid step's chunk factors and
+  walks the chunk scan over them, so that ``w, u, qg, kd, p`` and their
+  cotangents live in VMEM alone (335 MB a layer each way at 32 heads x 8192
+  tokens, had they gone through HBM). Grid ``(heads, chunks / n)``, a head's
+  steps in order, ``n`` chunks a step: ``kda_fwd`` holds the ``n`` chunks' tiles
+  in VMEM from the operands to the factors, the running sum ``G`` among them (a
+  product with the triangular ones on three exact parts of ``g``:
+  :func:`_rows_through`), then takes the chunks in order on a state kept in VMEM
+  TRANSPOSED, ``(d_v, d_k)``, so that its decay is a row along the lanes.
+  ``kda_bwd`` makes the factors again — ONCE, as the primal half of ``jax.vjp``
+  of the same function inside the kernel body (the three pieces that must not be
   differentiated as written — the exact row read and running sum, the score
-  product, the triangular solve — carry their own transposes). The chunk scan
-  ``kda_scan_fwd`` / ``kda_scan_bwd`` keeps the state in VMEM TRANSPOSED, ``(d_v,
-  d_k)``, so that its decay is a row along the lanes. One ``custom_vjp`` spans
-  the four (:func:`_rule_pallas`): its residuals are the operands, and the
-  backward pass runs the two forward kernels once more for the factors and the
-  chunk-start states.
+  product, the triangular solve — carry their own transposes) — walks its chunks
+  forward from the step's start state, then back on the state's cotangent, and
+  pulls the factors' cotangents through that ``vjp``. One ``custom_vjp`` spans the
+  two (:func:`_rule_pallas`): its residuals are the operands and the state each
+  grid step starts from.
 
 Exponentials, running sums, the state and the inverse are float32; matrix
 operands keep the input dtype (the inverse and its solve in three bfloat16
@@ -74,7 +79,7 @@ from beforeholiday_tpu.ops._pallas_util import (
     interpret_default as _interpret_default,
 )
 from beforeholiday_tpu.ops.gated_delta import (
-    _NN, _NT, _TN, _ROWS, _bdot, _dot, _flat, _mm3, _split, _tile_inverse,
+    _NN, _NT, _TN, _ROWS, _bdot, _dot, _mm3, _split, _tile_inverse,
     is_kernel_available,
 )
 
@@ -274,97 +279,6 @@ def kda_prepare(q, k, v, g, beta):
 
 
 # ---------------------------------------------------------------------------------
-# the state-free part, Pallas: one chunk's tiles in VMEM from operands to factors
-# ---------------------------------------------------------------------------------
-
-
-def _column(rows):
-    """Row 0 of ``rows (G, 8, C)`` (``beta`` along the lanes) as ``(G, C, 1)``."""
-    n, _, C = rows.shape
-    ii, jj = _iotas(n, C)
-    across = lax.broadcast_in_dim(lax.slice_in_dim(rows, 0, 1, axis=1), ii.shape, (0, 1, 2))
-    return lax.expand_dims(lax.reduce_sum(
-        lax.select(lax.eq(ii, jj), across, lax.full(ii.shape, 0.0, _F32)), (2,)), (2,))
-
-
-def _factors_of_refs(q, k, v, g, rows):
-    return _chunk_factors(q, k, v, g, _column(rows))
-
-
-def _prepare_fwd_kernel(q_ref, k_ref, v_ref, g_ref, rows_ref,
-                        w_ref, u_ref, qg_ref, kd_ref, p_ref):
-    out = _factors_of_refs(q_ref[...], k_ref[...], v_ref[...], g_ref[...], rows_ref[...])
-    for ref, t in zip((w_ref, u_ref, qg_ref, kd_ref, p_ref), out):
-        ref[...] = t
-
-
-def _prepare_bwd_kernel(q_ref, k_ref, v_ref, g_ref, rows_ref, dw_ref, du_ref, dqg_ref,
-                        dkd_ref, dp_ref, dlast_ref, dq_ref, dk_ref, dv_ref, dg_ref, drows_ref):
-    """The factors recomputed and their function transposed where it stands: a
-    kept ``T`` would be 67 MB of float32 a layer, and a second spelling of seven
-    levels' chain rule one more thing to keep equal to the first. ``dlast`` (row 0
-    of its 8) is the cotangent of the chunk's whole log-decay ``G_C``, which the
-    scan's ``e^{G_C}`` hands back: it joins the function as ``sum(G_C * dlast)``."""
-    dlast = lax.slice_in_dim(dlast_ref[...], 0, 1, axis=1)              # (n, 1, d_k)
-
-    def with_last(q, k, v, g, rows):
-        return _factors_of_refs(q, k, v, g, rows), lax.reduce_sum(g, (1,))
-
-    _, vjp = jax.vjp(with_last, q_ref[...], k_ref[...], v_ref[...], g_ref[...], rows_ref[...])
-    cts = vjp((tuple(r[...] for r in (dw_ref, du_ref, dqg_ref, dkd_ref, dp_ref)),
-               lax.squeeze(dlast, (1,))))
-    for ref, t in zip((dq_ref, dk_ref, dv_ref, dg_ref, drows_ref), cts):
-        ref[...] = t.astype(ref.dtype)
-
-
-# chunks a grid step: each chunk is a chain of dependent products and one chunk's product
-# hides another's latency (a layer's forward kernel took 7.7 ms at one chunk of 128 a step,
-# 6.3 at two, 4.9 at four, the backward one 13.3 / 10.1 / 8.2: my chip run, PR 49)
-_GROUP = 4
-_VMEM_LIMIT = 64 * 1024 * 1024
-
-
-def _prepare_call(kernel, name, q, v, ins, outs):
-    """``ins`` / ``outs`` name each operand's and result's tile: ``k`` is ``(C,
-    d_k)``, ``v`` ``(C, d_v)``, ``p`` ``(C, C)``, ``g`` ``(C, d_k)`` float32, ``r``
-    the float32 rows ``(8, C)``, ``l`` float32 rows ``(8, d_k)``."""
-    M, C, dk = q.shape
-    n = next(n for n in (_GROUP, 2, 1) if M % n == 0)
-    tiles = {"k": (C, dk), "v": (C, v.shape[2]), "p": (C, C), "g": (C, dk), "r": (_ROWS, C),
-             "l": (_ROWS, dk)}
-    spec = lambda t: pl.BlockSpec((n,) + tiles[t], lambda i: (i, 0, 0))
-    shape = lambda t: jax.ShapeDtypeStruct((M,) + tiles[t], _F32 if t in "grl" else v.dtype)
-    return pl.pallas_call(
-        kernel,
-        grid=(M // n,),
-        in_specs=[spec(t) for t in ins],
-        out_specs=[spec(t) for t in outs],
-        out_shape=[shape(t) for t in outs],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",), vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=_interpret_default(),
-        name=name,
-    )
-
-
-def _prepare_fwd(q, k, v, g, rows):
-    """``q, k (M, C, d_k)``, ``v (M, C, d_v)``, ``g (M, C, d_k)``, ``rows (M, 8, C)``
-    (``beta`` in row 0): ``w, u, qg, kd, p`` of ``M`` chunks."""
-    return tuple(_prepare_call(_prepare_fwd_kernel, "kda_prepare_fwd", q, v, "kkvgr", "kvkkp")(
-        q, k, v, g, rows))
-
-
-def _prepare_bwd(q, k, v, g, rows, dw, du, dqg, dkd, dp, dlast):
-    """The cotangents ``dq, dk, dv, dg, drows`` of :func:`_prepare_fwd`'s operands
-    and of the chunk's whole log-decay (``dlast (M, d_k)``)."""
-    cts = tuple(t.astype(v.dtype) for t in (dw, du, dqg, dkd, dp))
-    dlast = jnp.pad(dlast.astype(_F32)[:, None, :], ((0, 0), (0, _ROWS - 1), (0, 0)))
-    return tuple(_prepare_call(
-        _prepare_bwd_kernel, "kda_prepare_bwd", q, v, "kkvgr" + "kvkkp" + "l", "kkvgr")(
-            q, k, v, g, rows, *cts, dlast))
-
-
-# ---------------------------------------------------------------------------------
 # the chunk scan, jnp oracle: (BH, N, C, .) operands, lax.scan over N
 # ---------------------------------------------------------------------------------
 
@@ -387,99 +301,146 @@ def _scan_jnp(w, u, qg, kd, p, gl):
 
 
 # ---------------------------------------------------------------------------------
-# the chunk scan, Pallas: grid (BH, N), N sequential, the state TRANSPOSED in VMEM
+# Pallas: a grid step's chunks from operands to factors in VMEM, and the scan over them
 # ---------------------------------------------------------------------------------
 
 
-def _scan_fwd_kernel(w_ref, u_ref, qg_ref, kd_ref, p_ref, gl_ref, o_ref, s0_ref, s_ref):
+def _column(rows):
+    """Row 0 of ``rows (G, 8, C)`` (``beta`` along the lanes) as ``(G, C, 1)``."""
+    n, _, C = rows.shape
+    ii, jj = _iotas(n, C)
+    across = lax.broadcast_in_dim(lax.slice_in_dim(rows, 0, 1, axis=1), ii.shape, (0, 1, 2))
+    return lax.expand_dims(lax.reduce_sum(
+        lax.select(lax.eq(ii, jj), across, lax.full(ii.shape, 0.0, _F32)), (2,)), (2,))
+
+
+def _factors_of_refs(q, k, v, g, rows):
+    return _chunk_factors(q, k, v, g, _column(rows))
+
+
+def _advance(St, w, u, kd, g):
+    """One chunk of the scan on the TRANSPOSED state ``St (d_v, d_k)`` (a
+    channel's decay is a lane's): ``(S^T and delta in the matmul dtype, e^{G_C}
+    (1, d_k), the next chunk's state)``."""
+    dt = w.dtype
+    Sb = St.astype(dt)
+    db = (u.astype(_F32) - _dot(w, Sb, _NT)).astype(dt)
+    gl = jnp.exp(jnp.sum(g, axis=0, keepdims=True))
+    return Sb, db, gl, gl * St + _dot(db, kd, _TN)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, rows_ref, o_ref, s0_ref, s_ref):
+    """The factors of this step's ``n`` chunks at once (``n`` independent chains
+    of products: one hides another's latency), then the chunks in order on the
+    state in ``s_ref``. ``s0_ref``: the state this step's FIRST chunk starts
+    from."""
     @pl.when(pl.program_id(1) == 0)
     def _init():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    St = s_ref[...]                       # (d_v, d_k): a channel's decay is a lane's
-    s0_ref[0, 0] = St                     # the state this chunk starts from
-    dt = w_ref.dtype
-    Sb = St.astype(dt)
-    delta = u_ref[0].astype(_F32) - _dot(w_ref[0], Sb, _NT)
-    db = delta.astype(dt)
-    o_ref[0] = (_dot(qg_ref[0], Sb, _NT) + _dot(p_ref[0], db, _NN)).astype(o_ref.dtype)
-    s_ref[...] = gl_ref[0, 0] * St + _dot(db, kd_ref[0], _TN)
+    g = g_ref[...]
+    w, u, qg, kd, p = _factors_of_refs(q_ref[...], k_ref[...], v_ref[...], g, rows_ref[...])
+    St = s0_ref[0] = s_ref[...]
+    for i in range(g.shape[0]):
+        Sb, db, _, St = _advance(St, w[i], u[i], kd[i], g[i])
+        o_ref[i] = (_dot(qg[i], Sb, _NT) + _dot(p[i], db, _NN)).astype(o_ref.dtype)
+    s_ref[...] = St
 
 
-def _scan_bwd_kernel(w_ref, u_ref, qg_ref, kd_ref, p_ref, gl_ref, s0_ref, do_ref,
-                     dw_ref, du_ref, dqg_ref, dkd_ref, dp_ref, dgl_ref, ds_ref):
-    @pl.when(pl.program_id(1) == 0)      # the LAST chunk: the grid runs reversed
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, rows_ref, s0_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, drows_ref, ds_ref):
+    """The factors recomputed ONCE, as the primal half of ``jax.vjp`` of their own
+    function where it stands (a kept ``T`` would be 67 MB of float32 a layer, and
+    a second spelling of seven levels' chain rule one more thing to keep equal to
+    the first); the step's chunks walked first to last from its start state for
+    the state each starts from, then last to first on the state's cotangent in
+    ``ds_ref`` (the grid runs reversed too), which gives the factors' cotangents;
+    then the transposed function. The whole log-decay ``G_C`` of a chunk, which
+    the scan's ``e^{G_C}`` hands a cotangent back to, is a sum over the chunk's
+    rows: its cotangent joins ``dg`` on every row."""
+    @pl.when(pl.program_id(1) == 0)      # the LAST chunks
     def _init():
         ds_ref[...] = jnp.zeros_like(ds_ref)
 
-    dt = w_ref.dtype
-    St, dSt = s0_ref[0, 0], ds_ref[...]
-    Sb, dSb = St.astype(dt), dSt.astype(dt)
-    w, qg, kd, do = w_ref[0], qg_ref[0], kd_ref[0], do_ref[0]
-    delta = u_ref[0].astype(_F32) - _dot(w, Sb, _NT)
-    db = delta.astype(dt)
-    ddelta = _dot(p_ref[0], do, _TN) + _dot(kd, dSb, _NT)
-    ddb = ddelta.astype(dt)
-    du_ref[0] = ddb
-    dw_ref[0] = (-_dot(ddb, Sb, _NN)).astype(dt)
-    dqg_ref[0] = _dot(do, Sb, _NN).astype(dt)
-    dkd_ref[0] = _dot(db, dSb, _NN).astype(dt)
-    dp_ref[0] = _dot(do, db, _NT).astype(dt)
-    dgl_ref[0, 0] = jnp.sum(St * dSt, axis=0, keepdims=True)
-    ds_ref[...] = gl_ref[0, 0] * dSt + _dot(do, qg, _TN) - _dot(ddb, w, _TN)
+    g = g_ref[...]
+    (w, u, qg, kd, p), vjp = jax.vjp(
+        _factors_of_refs, q_ref[...], k_ref[...], v_ref[...], g, rows_ref[...])
+    dt, n = w.dtype, g.shape[0]
+    walked, St = [], s0_ref[0]
+    for i in range(n):
+        Sb, db, gl, after = _advance(St, w[i], u[i], kd[i], g[i])
+        walked.append((St, Sb, db, gl))
+        St = after
+    dSt = ds_ref[...]
+    cts, dlast = [None] * n, [None] * n
+    for i in reversed(range(n)):
+        (St, Sb, db, gl), do = walked[i], do_ref[i]
+        dSb = dSt.astype(dt)
+        ddb = (_dot(p[i], do, _TN) + _dot(kd[i], dSb, _NT)).astype(dt)
+        cts[i] = ((-_dot(ddb, Sb, _NN)).astype(dt), ddb, _dot(do, Sb, _NN).astype(dt),
+                  _dot(db, dSb, _NN).astype(dt), _dot(do, db, _NT).astype(dt))
+        # gl = e^{G_C}: the cotangent of G_C is that of gl, times gl
+        dlast[i] = jnp.sum(St * dSt, axis=0, keepdims=True) * gl
+        dSt = gl * dSt + _dot(do, qg[i], _TN) - _dot(ddb, w[i], _TN)
+    ds_ref[...] = dSt
+    dq, dk, dv, dg, drows = vjp(tuple(jnp.stack(t) for t in zip(*cts)))
+    for ref, t in zip((dq_ref, dk_ref, dv_ref, drows_ref), (dq, dk, dv, drows)):
+        ref[...] = t.astype(ref.dtype)
+    for i in range(n):
+        dg_ref[i] = dg[i] + dlast[i]
 
 
-def _scan_specs(C, dk, dv, index):
-    """Block specs of one chunk of one head; ``index(n)`` gives the chunk."""
-    rows = lambda width: pl.BlockSpec((1, C, width), lambda b, n: (b, index(n), 0))
-    per_chunk = lambda *tail: pl.BlockSpec((1, 1) + tail, lambda b, n: (b, index(n), 0, 0))
-    return rows(dk), rows(dv), rows(C), per_chunk(1, dk), per_chunk(dv, dk)
+# chunks a grid step: each chunk is a chain of dependent products and one chunk's product
+# hides another's latency (a layer's kda_fwd + kda_bwd took 8.3 + 13.8 ms at one chunk of
+# 128 a step, 6.9 + 11.2 at two, 5.5 + 9.5 at four, 5.1 + 9.2 at eight; by the compiler's
+# bundle count sixteen gains nothing more: my chip run, PR 50)
+_GROUP = 8
+_VMEM_LIMIT = 64 * 1024 * 1024
 
 
-_SCAN_PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
-
-
-def _scan_fwd(w, u, qg, kd, p, gl):
-    """``(o (BH, N, C, dv), chunk-start states (BH, N, dv, dk) float32)``."""
-    BH, N, C, dk = w.shape
-    dv = u.shape[-1]
-    kspec, vspec, pspec, gspec, sspec = _scan_specs(C, dk, dv, lambda n: n)
-    o, s0 = pl.pallas_call(
-        _scan_fwd_kernel,
-        grid=(BH, N),
-        in_specs=[kspec, vspec, kspec, kspec, pspec, gspec],
-        out_specs=[vspec, sspec],
-        out_shape=[jax.ShapeDtypeStruct((BH, N * C, dv), u.dtype),
-                   jax.ShapeDtypeStruct((BH, N, dv, dk), _F32)],
+def _call(kernel, name, heads, n, interpret, q, v, ins, outs, reverse):
+    """One kernel over ``M = heads * N`` flat chunks, grid ``(heads, N / n)`` with
+    ``n`` chunks a step and a head's steps in order (``reverse``: last to first).
+    ``ins`` / ``outs`` name each operand's and result's tile a chunk: ``k`` is
+    ``(C, d_k)``, ``v`` ``(C, d_v)``, ``g`` ``(C, d_k)`` float32, ``r`` the float32
+    rows ``(8, C)``; ``s`` is one float32 state ``(d_v, d_k)`` a STEP."""
+    M, C, dk = q.shape
+    dv, steps = v.shape[2], M // (heads * n)
+    tiles = {"k": (C, dk), "v": (C, dv), "g": (C, dk), "r": (_ROWS, C), "s": (dv, dk)}
+    at = (lambda b, j: (b * steps + steps - 1 - j, 0, 0)) if reverse \
+        else (lambda b, j: (b * steps + j, 0, 0))
+    spec = lambda t: pl.BlockSpec((1 if t == "s" else n,) + tiles[t], at)
+    shape = lambda t: jax.ShapeDtypeStruct(
+        (M // n if t == "s" else M,) + tiles[t], _F32 if t in "grs" else v.dtype)
+    return pl.pallas_call(
+        kernel,
+        grid=(heads, steps),
+        in_specs=[spec(t) for t in ins],
+        out_specs=[spec(t) for t in outs],
+        out_shape=[shape(t) for t in outs],
         scratch_shapes=[pltpu.VMEM((dv, dk), _F32)],
-        compiler_params=_SCAN_PARAMS,
-        interpret=_interpret_default(),
-        name="kda_scan_fwd",
-    )(_flat(w), _flat(u), _flat(qg), _flat(kd), _flat(p), gl[:, :, None, :])
-    return o.reshape(BH, N, C, dv), s0
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name=name,
+    )
 
 
-def _scan_bwd(w, u, qg, kd, p, gl, s0, do):
-    BH, N, C, dk = w.shape
-    dv, dt = u.shape[-1], w.dtype
-    kspec, vspec, pspec, gspec, sspec = _scan_specs(C, dk, dv, lambda n: N - 1 - n)
-    rows = lambda width: jax.ShapeDtypeStruct((BH, N * C, width), dt)
-    dw, du, dqg, dkd, dp, dgl = pl.pallas_call(
-        _scan_bwd_kernel,
-        grid=(BH, N),
-        in_specs=[kspec, vspec, kspec, kspec, pspec, gspec, sspec, vspec],
-        out_specs=[kspec, vspec, kspec, kspec, pspec, gspec],
-        out_shape=[rows(dk), rows(dv), rows(dk), rows(dk), rows(C),
-                   jax.ShapeDtypeStruct((BH, N, 1, dk), _F32)],
-        scratch_shapes=[pltpu.VMEM((dv, dk), _F32)],
-        compiler_params=_SCAN_PARAMS,
-        interpret=_interpret_default(),
-        name="kda_scan_bwd",
-    )(_flat(w), _flat(u), _flat(qg), _flat(kd), _flat(p), gl[:, :, None, :], s0,
-      _flat(do.astype(dt)))
-    shape = lambda t, like: t.reshape(like.shape)
-    return (shape(dw, w), shape(du, u), shape(dqg, qg), shape(dkd, kd), shape(dp, p),
-            dgl[:, :, 0, :])
+# Each kernel call is a ``jax.jit`` function, as in ``ops/deltanet.py``: a model's KDA layers
+# and the guard's probe trace and lower each body once a shape, not once a layer.
+_STATICS = ("heads", "n", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_STATICS)
+def _fwd_call(q, k, v, g, rows, *, heads, n, interpret):
+    return _call(_fwd_kernel, "kda_fwd", heads, n, interpret, q, v, "kkvgr", "vs", False)(
+        q, k, v, g, rows)
+
+
+@functools.partial(jax.jit, static_argnames=_STATICS)
+def _bwd_call(q, k, v, g, rows, s0, do, *, heads, n, interpret):
+    return _call(_bwd_kernel, "kda_bwd", heads, n, interpret, q, v, "kkvgr" + "sv", "kkvgr", True)(
+        q, k, v, g, rows, s0, do)
 
 
 def _operands(q, k, v, g, beta):
@@ -490,41 +451,36 @@ def _operands(q, k, v, g, beta):
     return flat(q), flat(k), flat(v), flat(g.astype(_F32)), flat(rows)
 
 
-def _factors(res, BH):
-    """:func:`_prepare_fwd` of the kernels' operands, as the scan takes them:
-    ``(w, u, qg, kd, p, gl)``, ``(BH, N, C, .)`` and ``gl (BH, N, d_k)``."""
-    gl = _whole_decay(res[3]).reshape(BH, -1, res[3].shape[-1])
-    return tuple(t.reshape(BH, -1, *t.shape[1:]) for t in _prepare_fwd(*res)) + (gl,)
+def _fwd(res, heads):
+    """``(o (M, C, d_v), the states (M / n, d_v, d_k) float32 that each grid
+    step of n chunks starts from)`` of the kernels' operands."""
+    N = res[0].shape[0] // heads
+    n = next(n for n in (_GROUP, 4, 2, 1) if N % n == 0)
+    return _fwd_call(*res, heads=heads, n=n, interpret=_interpret_default())
 
 
 @jax.custom_vjp
 def _rule_pallas(q, k, v, g, beta):
-    """The four kernels on chunked operands ``(BH, N, C, .)``: ``o (BH, N, C, d_v)``.
-    ONE ``custom_vjp`` over the factors and the scan: its residuals are the
-    kernels' operands, and the backward pass makes the five factors again (335 MB
-    a layer at 32 heads x 8192 tokens, which as residuals of the scan put the
-    five-layer step 1.3 GB further over the chip) and the chunk-start states
-    (0.27 GB)."""
-    return _scan_fwd(*_factors(_operands(q, k, v, g, beta), q.shape[0]))[0]
+    """The two kernels on chunked operands ``(BH, N, C, .)``: ``o (BH, N, C, d_v)``.
+    The residuals are the kernels' operands and the state at each grid step's
+    start (17 MB a layer at 32 heads x 8192 tokens and 8 chunks a step); the five
+    factors (335 MB) and their cotangents never leave VMEM."""
+    return _rule_pallas_fwd(q, k, v, g, beta)[0]
 
 
 def _rule_pallas_fwd(q, k, v, g, beta):
     res = _operands(q, k, v, g, beta)
-    return _scan_fwd(*_factors(res, q.shape[0]))[0], (res, q.shape)
+    o, s0 = _fwd(res, q.shape[0])
+    return o.reshape(v.shape), (res, s0)
 
 
 def _rule_pallas_bwd(saved, do):
-    res, (BH, N, C, _) = saved
-    # behind a barrier with the cotangent, or XLA merges these calls with the
-    # forward pass's identical ones and keeps their results alive until here
-    res, do = lax.optimization_barrier((res, do))
-    factors = _factors(res, BH)
-    _, s0 = _scan_fwd(*factors)
-    *dfactors, dgl = _scan_bwd(*factors, s0, do)
-    flat = lambda t: t.reshape(-1, *t.shape[2:])
-    # gl = e^{G_C}: the cotangent of G_C is dgl * gl
-    dq, dk, dv, dg, drows = _prepare_bwd(*res, *(flat(t) for t in dfactors),
-                                         flat(dgl * factors[5]))
+    res, s0 = saved
+    BH, N = do.shape[:2]
+    v = res[2]
+    dq, dk, dv, dg, drows = _bwd_call(
+        *res, s0, do.astype(v.dtype).reshape(v.shape), heads=BH, n=v.shape[0] // s0.shape[0],
+        interpret=_interpret_default())
     chunked = lambda t: t.reshape(BH, N, *t.shape[1:])
     return chunked(dq), chunked(dk), chunked(dv), chunked(dg), chunked(drows[:, 0, :])
 
@@ -533,7 +489,7 @@ _rule_pallas.defvjp(_rule_pallas_fwd, _rule_pallas_bwd)
 
 
 def _probe_pallas(q, k, v, g, beta):
-    """Guard probe: the four kernels must build."""
+    """Guard probe: both kernels must build."""
     o, vjp = jax.vjp(_rule_pallas, q, k, v, g, beta)
     vjp(jnp.zeros_like(o))
     return o
@@ -600,8 +556,8 @@ def kda_rule(
         chunked = tuple(chunks(t) for t in (q, k, v, g, beta))
         if impl == "pallas" and not forced:
             impl = _checked_impl("kda_rule", impl, _probe_pallas, *chunked)
-        # either way only the operands live on to the backward pass, which
-        # recomputes the float32 intermediates and, in the kernels, the factors
+        # either way the operands live on to the backward pass (and, of the kernels,
+        # a state a grid step), which recomputes the float32 intermediates and the factors
         if impl == "pallas":
             o = _rule_pallas(*chunked)
         else:

@@ -346,16 +346,16 @@ def check_wy_prepare(results: list) -> None:
 
 
 def check_kda(results: list, H: int = 32, S: int = 8192, d: int = 128, parity_heads: int = 8,
-              chunks=(64, 128), groups=(1, 2, 4)) -> None:
+              chunks=(64, 128), groups=(1, 2, 4, 8)) -> None:
     """The delta rule under a decay a key channel (``ops.kda``), compiled, at the
     Kimi-Linear cell's shape ``(1, 32, 8192, 128)``, bfloat16: ``o, dq, dk, dv, dg,
-    dbeta`` of the four kernels against the ``jnp`` form (on ``parity_heads`` heads:
+    dbeta`` of the two kernels against the ``jnp`` form (on ``parity_heads`` heads:
     the ``jnp`` form streams every level's float32 tiles through HBM) at the gate's
     initial range AND at the strongest decay the parameters allow (``e^{A_log}``
     16, ``softplus`` of +4: -64 a token a channel; nothing may overflow or be NaN),
     and the time a layer forward + backward at each chunk size, with each kernel's
-    own (the two state-free ones at ``groups`` chunks a grid step). A smaller ``H``
-    / ``S`` is the CPU rehearsal."""
+    own at ``groups`` chunks a grid step. A smaller ``H`` / ``S`` is the CPU
+    rehearsal."""
     from beforeholiday_tpu.ops import kda
 
     def check(name, cond, info=""):
@@ -403,27 +403,20 @@ def check_kda(results: list, H: int = 32, S: int = 8192, d: int = 128, parity_he
         fn = both("pallas", chunk)
         ms[f"fwd_bwd@{chunk}"] = 1e3 * _min_step_seconds(lambda _: fn(q, k, v, g, beta, do), None)
         res = jax.jit(kda._operands)(*(chunked(t, chunk) for t in (q, k, v, g, beta)))
-        facts = jax.jit(lambda *res: kda._factors(res, H))(*res)
-        s0 = jax.jit(kda._scan_fwd)(*facts)[1]
-        cts = tuple(jnp.ones_like(t).reshape(-1, *t.shape[2:]) for t in facts[:5]) \
-            + (jnp.ones((res[3].shape[0], d), jnp.float32),)
-        calls = {
-            "scan_fwd": (jax.jit(kda._scan_fwd), facts),
-            "scan_bwd": (jax.jit(kda._scan_bwd), facts + (s0, chunked(do, chunk))),
-        }
-        for group in groups:           # chunks a grid step of the two state-free kernels
-            kept, kda._GROUP = kda._GROUP, group     # read when a call is traced
-            for name, fn, args in (("prepare_fwd", kda._prepare_fwd, res),
-                                   ("prepare_bwd", kda._prepare_bwd, res + cts)):
-                call = jax.jit(lambda *a, fn=fn: fn(*a))    # a fresh function each
-                try:
+        ct = chunked(do, chunk)
+        for group in groups:           # chunks a grid step
+            kept, kda._GROUP = kda._GROUP, group     # read when a call is traced: a static of
+            try:                                     # the kernel's jit, so a fresh trace each
+                fwd = jax.jit(lambda *res: kda._fwd(res, H))
+                s0 = fwd(*res)[1]
+                bwd = jax.jit(lambda s0, ct, *res: kda._rule_pallas_bwd((res, s0), ct))
+                for name, call, args in (("kda_fwd", fwd, res), ("kda_bwd", bwd, (s0, ct) + res)):
                     ms[f"{name}/{group}@{chunk}"] = 1e3 * _min_step_seconds(
                         lambda _: call(*args), None)
-                except Exception as e:  # noqa: BLE001 — a plan Mosaic refuses is a reading too
-                    ms[f"{name}/{group}@{chunk}"] = f"{type(e).__name__}: {str(e)[:80]}"
-            kda._GROUP = kept
-        for name, (call, args) in calls.items():
-            ms[f"{name}@{chunk}"] = 1e3 * _min_step_seconds(lambda _: call(*args), None)
+            except Exception as e:  # noqa: BLE001 — a plan Mosaic refuses is a reading too
+                ms[f"{group}@{chunk}"] = f"{type(e).__name__}: {str(e)[:80]}"
+            finally:
+                kda._GROUP = kept
     check("ms_a_layer", True, json.dumps(
         {n: round(t, 3) if isinstance(t, float) else t for n, t in ms.items()}))
 

@@ -262,11 +262,12 @@ def test_the_qwen_step_compiled_for_the_chip_keeps_what_the_deltanet_kernels_gav
 
 @pytest.mark.parametrize("chunk", (64, 128))
 def test_the_kda_kernels_compile_for_the_chip(one_chip, monkeypatch, chunk):
-    """The four kernels of ``ops/kda.py`` at the Kimi-Linear cell's shape (32
+    """The two kernels of ``ops/kda.py`` at the Kimi-Linear cell's shape (32
     heads of 128, 8,192 tokens, bfloat16 with a float32 gate), at both chunk
-    sizes: the state-free pair — whose backward body is ``jax.vjp`` of the
-    forward's function, traced inside the kernel — and the chunk scan's; the
-    backward pass runs the two forward kernels a second time."""
+    sizes: each makes a grid step's chunk factors and walks the chunk scan over
+    them — the backward body is ``jax.vjp`` of the forward's function, traced
+    inside the kernel, round the reversed walk — and the backward pass runs
+    no forward kernel again (the state each grid step starts from is a residual)."""
     from beforeholiday_tpu.ops import kda
 
     monkeypatch.setattr(kda, "_interpret_default", lambda: False)
@@ -288,22 +289,27 @@ def test_the_kda_kernels_compile_for_the_chip(one_chip, monkeypatch, chunk):
             shape((B, H, S), f32), heads_first).compile().as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", prev)
-    calls = {k: len(set(re.findall(rf"%{k}[.\d]* = ", text)))
-             for k in ("kda_prepare_fwd", "kda_prepare_bwd", "kda_scan_fwd", "kda_scan_bwd")}
-    assert calls == {"kda_prepare_fwd": 2, "kda_prepare_bwd": 1, "kda_scan_fwd": 2,
-                     "kda_scan_bwd": 1}, calls
-    assert text.count("tpu_custom_call") == 6
+    calls = {k: len(set(re.findall(rf"%{k}[.\d]* = ", text))) for k in ("kda_fwd", "kda_bwd")}
+    assert calls == {"kda_fwd": 1, "kda_bwd": 1}, calls
+    assert not re.search(r"%kda_(prepare|scan)", text)
+    assert text.count("tpu_custom_call") == 2
 
 
 def test_the_kimi_step_compiled_for_the_chip_runs_no_kernel_twice_over(topo, monkeypatch):
     """The whole step of ``kimi-linear-48b-a3b.train-s8k`` compiled for a described
-    v5e (``tools/offline_step.py``; ~50 s, nothing runs). Its 9.64 GB of state
-    leave the sequence 6 GB, and the compiler rematerialises what does not fit
-    (the four ``x @ W_qkv`` products and one of the dense layer's: 5 arrays; with
-    the scan's five operands kept as residuals it was 19, PERF.md, PR 49). Held
-    to: no flash, ``kda`` or ``deltanet`` kernel beyond what the ``custom_vjp``
-    rules ask for (a rematerialised kernel would be a layer's time again), at
-    most 8 rematerialised arrays, and temporaries under 7.2 GiB."""
+    v5e (``tools/offline_step.py``; ~60 s, nothing runs). Its 9.64 GB of state
+    leave the sequence 6 GB, and the compiler rematerialises what does not fit:
+    with the fused KDA kernels (PR 51: one forward and one backward kernel a
+    layer, the state at each grid step's start a residual of 17 MB a layer) two
+    ``x @ W_qkv`` products and three gate fusions of two arrays each, 8 arrays
+    (the parent's four kernels: four ``x @ W_qkv``; with every chunk's start
+    state kept, 134 MB a layer, 11 arrays; with the scan's five operands kept it
+    was 19: PERF.md, PRs 49 and 51). Held to: no flash, ``kda`` or ``deltanet``
+    kernel beyond what the ``custom_vjp`` rules ask for (a rematerialised kernel
+    would be a layer's time again), none of the parent's four KDA kernels left,
+    at most 8 rematerialised arrays (a fusion of several results is a tuple and
+    its elements: the elements are the arrays), and temporaries under what the
+    fused step reads (7.016 GiB) + 0.1."""
     import sys
 
     sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "tools"))
@@ -318,17 +324,25 @@ def test_the_kimi_step_compiled_for_the_chip_runs_no_kernel_twice_over(topo, mon
         jax.config.update("jax_enable_compilation_cache", prev)
     text = compiled.as_text()
     calls = lambda kernel: len(set(re.findall(rf"%{kernel}[.\d]* = ", text)))
-    # four KDA layers: each forward kernel once forward and once more backward
-    for kernel, want in (("kda_prepare_fwd", 8), ("kda_prepare_bwd", 4), ("kda_scan_fwd", 8),
-                         ("kda_scan_bwd", 4), ("deltanet_qkv_fwd", 4), ("deltanet_qkv_bwd", 4),
-                         ("deltanet_gate_fwd", 4), ("deltanet_gate_bwd", 4)):
+    # four KDA layers: one kernel a pass, the forward one in the forward pass alone
+    for kernel, want in (("kda_fwd", 4), ("kda_bwd", 4), ("deltanet_qkv_fwd", 4),
+                         ("deltanet_qkv_bwd", 4), ("deltanet_gate_fwd", 4),
+                         ("deltanet_gate_bwd", 4)):
         assert calls(kernel) == want, (kernel, calls(kernel))
+    assert not re.search(r"%kda_(prepare|scan)", text)
+    # what ``kda_ms`` reads (the op's scope path, ``kda_mixer/kda/``) and the pass of each
+    for kernel, in_pass, other in (("kda_fwd", "amp_forward", "amp_backward"),
+                                   ("kda_bwd", "amp_backward", None)):
+        for line in re.findall(rf"%{kernel}[.\d]* = .*", text):
+            op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+            assert "kda_mixer/kda/" in op_name and in_pass in op_name, op_name
+            assert other is None or other not in op_name, op_name
     assert calls("flash_attention") == 2            # the one latent layer: forward, fused backward
     made = [l for l in text.splitlines() if re.match(r"\s*(ROOT )?%\S+ = ", l)]
-    remat = [l for l in made if re.match(r"\s*(ROOT )?%\S*\.remat\S* = ", l)]
-    assert len(remat) <= 8, len(remat)
+    remat = [l for l in made if re.match(r"\s*(ROOT )?%\S*\.remat\S* = [^(]", l)]
+    assert len(remat) <= 8, [l.split(" = ")[0].strip() for l in remat]     # by name
     assert not [l for l in remat if "f32[8192,20480]" in l or "f32[1,8192,20480]" in l]
-    assert compiled.memory_analysis().temp_size_in_bytes <= 7.2 * 2 ** 30
+    assert compiled.memory_analysis().temp_size_in_bytes <= 7.12 * 2 ** 30
 
 
 @pytest.mark.parametrize("batch,S,D,K", ((1, 8192, 2048, 3), (2, 1024, 256, 4), (1, 48, 128, 8)),

@@ -80,8 +80,14 @@ def oracle(S, H, d, gate):
 
 # (impl, d, chunk, S): several chunks with a ragged tail, chunk sizes 64 and 128 (and 16
 # for the jnp form: every level of the triangle down to pairs of rows)
+# for the kernels also six chunks a head — grid steps of TWO chunks, since neither eight nor
+# four divides six —, twelve (three steps of four) and sixteen (two of eight): the state
+# crosses grid steps, and the backward kernel walks a step's chunks forward from its start
+# state before it walks them back; and six chunks of 128
 _CASES = (("jnp", 32, 16, 72), ("jnp", 32, 64, 160), ("jnp", 32, 128, 288),
-          ("pallas", 128, 64, 160), ("pallas", 128, 128, 288))
+          ("pallas", 128, 64, 160), ("pallas", 128, 128, 288),
+          ("pallas", 128, 64, 384), ("pallas", 128, 64, 768), ("pallas", 128, 64, 1024),
+          ("pallas", 128, 128, 768))
 
 
 @pytest.mark.parametrize("gate", ("drawn", "strongest"))
@@ -118,6 +124,35 @@ def test_a_gate_constant_over_the_channels_is_the_scalar_rule(impl, d):
     assert close(o, o_want)
     for name, got, want in zip(NAMES, grads, grads_want):
         assert close(got, want), name
+
+
+# (chunk, S, chunks a grid step): eight chunks a step where eight divides a head's chunks, else
+# four, two, one; (128, 1024) is ONE step of eight a head, the walk inside a step alone
+_STEPS = ((64, 384, 2), (64, 768, 4), (64, 1024, 8), (128, 384, 1), (128, 768, 2), (128, 1024, 8),
+          (128, 2048, 8))
+
+
+@pytest.mark.parametrize("chunk,S,n", _STEPS, ids=lambda c: str(c))
+def test_the_state_crosses_a_grid_steps_boundary(chunk, S, n):
+    """No decay (a gate of zeros) and ``beta`` = 1 on the first chunk only: every
+    later chunk leaves the state alone, so the LAST chunk's ``o`` is the first
+    chunk's state read by its queries — through every chunk of its own grid step
+    and every grid step's start state between them — and its gradient reaches
+    the first chunk's keys and nowhere else among the keys."""
+    q, k, v, _, _ = operands(S, 2, 128, 128, seed=7)
+    g = jnp.zeros(q.shape, jnp.float32)
+    beta = jnp.zeros(q.shape[:3], jnp.float32).at[:, :chunk].set(1.0)
+    last = lambda fn: (lambda k: jnp.sum(fn(q, k, v, g, beta)[:, -chunk:] ** 2))
+    rule = lambda *a: kda.kda_rule(*a, chunk=chunk, impl="pallas")
+    chunked = (jnp.moveaxis(t, 2, 1).reshape(2, S // chunk, chunk, *t.shape[3:])
+               for t in (q, k, v, g, beta))
+    _, starts = jax.eval_shape(lambda *a: kda._fwd(kda._operands(*a), 2), *chunked)
+    assert starts.shape[0] == 2 * (S // chunk) // n         # a start state a grid step, 2 heads
+    got, want = rule(q, k, v, g, beta)[:, -chunk:], token_scan(q, k, v, g, beta)[:, -chunk:]
+    assert float(jnp.max(jnp.abs(want))) > 1e-3 and close(got, want)
+    dk, dk_want = jax.grad(last(rule))(k), jax.grad(last(token_scan))(k)
+    assert float(jnp.max(jnp.abs(dk_want[:, :chunk]))) > 1e-4 and close(dk, dk_want)
+    assert float(jnp.max(jnp.abs(dk[:, chunk:-chunk]))) == 0.0
 
 
 def test_the_strongest_decay_forgets_everything_and_overflows_nothing():
@@ -170,12 +205,15 @@ def test_shapes_layouts_and_refusals():
         kda.kda_rule(q, k, v, g, beta, chunk=32, impl="pallas")
 
 
-def test_the_kernels_and_the_jnp_form_agree_in_bfloat16():
-    """The parity ``check_kda`` holds on the chip, at interpreter size."""
-    args = operands(256, 2, 128, 128, seed=5)
+@pytest.mark.parametrize("chunk,S", ((64, 256), (128, 768)))
+def test_the_kernels_and_the_jnp_form_agree_in_bfloat16(chunk, S):
+    """The parity ``check_kda`` holds on the chip, at interpreter size: the fused
+    pair against ``_scan_jnp`` of ``kda_prepare``, one grid step of four chunks
+    and three of two."""
+    args = operands(S, 2, 128, 128, seed=5)
     bf = lambda t: t.astype(jnp.bfloat16)
     args = (bf(args[0]), bf(args[1]), bf(args[2]), args[3], args[4])
-    run = lambda impl: both(lambda *a: kda.kda_rule(*a, chunk=64, impl=impl)
+    run = lambda impl: both(lambda *a: kda.kda_rule(*a, chunk=chunk, impl=impl)
                             .astype(jnp.float32), args)
     (o, grads), (o_want, grads_want) = run("pallas"), run("jnp")
     assert close(o, o_want, 2e-2)

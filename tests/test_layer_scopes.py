@@ -1088,12 +1088,14 @@ def test_kimi_linear_second_level_scopes_do_not_overlap(kimi_names):
 
 def test_the_kda_kernels_are_named_by_the_op_and_lie_under_its_scopes():
     """``kda_ms`` reads the ``kda`` scope, ``kda_roofline`` the kernels' own ``name=``
-    (the chip prints ``%kda_prepare_fwd.N``, ``%kda_scan_bwd.N``, ...): the four
-    kernels lie under ``kda_mixer/kda``, the forward ones under ``amp_forward``
-    and the backward ones (and the second run of the two forward ones, which
-    the backward pass makes its factors and chunk-start states with) under
-    ``amp_backward``; the layer's other two passes
-    are ``ops.deltanet``'s, under their own scopes."""
+    (the chip prints ``%kda_fwd.N``, ``%kda_bwd.N``): each kernel sits in a
+    ``jax.jit`` function of its own (``_fwd_call``, ``_bwd_call``: traced and
+    lowered once for a model's layers), called under ``kda_mixer/kda`` —
+    ``_fwd_call`` under ``amp_forward`` alone (the backward pass takes the grid
+    steps' start states as a residual and runs no forward kernel again) and
+    ``_bwd_call`` under ``amp_backward``; the layer's other two passes are
+    ``ops.deltanet``'s, under their own scopes. What the compiled step makes of
+    the names is ``tests/test_chip_compile.py``'s to hold."""
     from beforeholiday_tpu.models import kimi_linear
     from beforeholiday_tpu.ops import deltanet, kda
 
@@ -1113,17 +1115,15 @@ def test_the_kda_kernels_are_named_by_the_op_and_lie_under_its_scopes():
         mp.setattr(deltanet, "deltanet_gate", force(deltanet.deltanet_gate))
         text = jax.jit(svag).lower(p, LossScaler(loss_scale=1.0).init(), x).as_text(debug_info=True)
     names = set(re.findall(r'loc\("([^"]+)"', text))
-    kernels = {k: [n for n in names if f"/{k}/pallas_call" in n]
-               for k in ("kda_prepare_fwd", "kda_prepare_bwd", "kda_scan_fwd", "kda_scan_bwd")}
+    assert not [n for n in names if re.search(r"kda_(prepare|scan)_", n)]
     for scope in ("deltanet_qkv", "deltanet_gate"):     # their kernels sit in a jit of their own
         assert any(re.search(rf"kda_mixer\)*/{scope}\)*/", n) for n in names), scope
-    assert all(kernels.values()), {k: len(v) for k, v in kernels.items()}
-    for k, found in kernels.items():
-        for n in found:
-            assert "kda_mixer" in n, n
-            assert re.search(r"kda_mixer\)*/kda\)*/", n), n
-            if k.endswith("_bwd"):
-                assert _pass_of(n) == "amp_backward", n
+    for kernel, call, in_pass in (("kda_fwd", "_fwd_call", "amp_forward"),
+                                  ("kda_bwd", "_bwd_call", "amp_backward")):
+        assert f"{kernel}/pallas_call" in names, kernel      # inside its own jit function
+        sites = [n for n in names if n.endswith(f"/jit({call})")]
+        assert sites, call
+        for n in sites:
+            assert re.search(r"kda_mixer\)*/kda\)*/jit", n), n
+            assert _pass_of(n) == in_pass, n
             assert not re.search(r"gated_delta|grouped_matmul|/moe/|flash_attention", n), n
-    for k in ("kda_prepare_fwd", "kda_scan_fwd"):
-        assert {_pass_of(n) for n in kernels[k]} == {"amp_forward", "amp_backward"}, k
