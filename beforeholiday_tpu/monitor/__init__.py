@@ -30,9 +30,12 @@ Split by which side of the device boundary each piece lives on:
   and the ``jax.monitoring`` listeners that feed the ledger its seconds.
 * :mod:`beforeholiday_tpu.monitor.memory`   — per-jit memory ledger
   (``track_memory``: AOT ``memory_analysis()`` bytes per entry/signature).
-* :mod:`beforeholiday_tpu.monitor.roofline` — roofline/MFU ledger
-  (``track_costs``: AOT ``cost_analysis()`` FLOPs/bytes per entry joined
-  with measured wall time; ``perf_report`` is the one-call rollup).
+* :mod:`beforeholiday_tpu.monitor.program`  — the program ledger
+  (``program_ops``: every instruction of a ``donate_step`` entry's compiled
+  module with its scope, or for one the compiler made, the scopes of what
+  feeds it and what it feeds; nothing is lowered or parsed until asked).
+* :mod:`beforeholiday_tpu.monitor.roofline` — published chip peaks
+  (``ChipSpec`` and its registry, keyed by ``device_kind``).
 * :mod:`beforeholiday_tpu.monitor.flight`   — crash flight recorder
   (ring buffer of drained steps, dumped on StepGuard rollback / crash).
 """
@@ -95,20 +98,15 @@ from beforeholiday_tpu.monitor.memory import (  # noqa: F401
     reset_memory_ledger,
     track_memory,
 )
+from beforeholiday_tpu.monitor.program import (  # noqa: F401
+    program_ops,
+    reset_program_ledger,
+)
 from beforeholiday_tpu.monitor.roofline import (  # noqa: F401
     ChipSpec,
     chip_specs,
-    estimate_costs,
     get_chip_spec,
-    join_spans,
-    measure_costs,
-    perf_report,
-    record_wall_time,
     register_chip_spec,
-    reset_roofline_ledger,
-    roofline_records,
-    roofline_summary,
-    track_costs,
 )
 from beforeholiday_tpu.monitor.flight import (  # noqa: F401
     FlightRecorder,
@@ -143,20 +141,16 @@ __all__ = [
     "dispatch_counters",
     "dispatch_records",
     "dispatch_summary",
-    "estimate_costs",
     "get_chip_spec",
     "global_norm",
     "goodput_report",
     "host_records",
-    "join_spans",
     "ledger_scope",
-    "measure_costs",
     "measure_memory",
     "memory_records",
     "memory_summary",
     "nvtx_range",
-    "perf_report",
-    "record_wall_time",
+    "program_ops",
     "register_chip_spec",
     "reset_comms_ledger",
     "reset_compile_counts",
@@ -164,9 +158,7 @@ __all__ = [
     "reset_dispatch_counters",
     "reset_host_ledger",
     "reset_memory_ledger",
-    "reset_roofline_ledger",
-    "roofline_records",
-    "roofline_summary",
+    "reset_program_ledger",
     "span",
     "span_intervals",
     "start_trace",
@@ -175,6 +167,5 @@ __all__ = [
     "timeline",
     "trace",
     "track_compiles",
-    "track_costs",
     "track_memory",
 ]

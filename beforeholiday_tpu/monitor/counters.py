@@ -121,8 +121,7 @@ def book_tiles(op: str, kernel: str, statics: Tuple, *, total: int, live: int,
 def dispatch_summary() -> List[Dict[str, object]]:
     """Op-level rollup, one JSON-ready row per op name:
     ``{"op", "keys", "pallas", "jnp", "probes", "pallas_ratio",
-    "degraded_keys"}`` — the shape ``monitor.perf_report`` embeds
-    (``pallas_ratio`` = fraction of the op's dispatches that took the
+    "degraded_keys"}`` (``pallas_ratio`` = fraction of the op's dispatches that took the
     kernel; 1.0 is a fully-healthy op, 0.0 a fully-degraded one)."""
     from beforeholiday_tpu.guard import dispatch as _dispatch
 

@@ -51,6 +51,7 @@ __all__ = [
     "TraceRecorder",
     "active_recorder",
     "book",
+    "held",
     "host_records",
     "outermost",
     "reset_host_ledger",
@@ -318,6 +319,21 @@ _SETUP: collections.deque = collections.deque()
 _STEPS: collections.deque = collections.deque(maxlen=RING)
 # ("gc.short", "gc.gen0") / ("dropped", kind) -> [count, ns, first start, last end]
 _TALLY: Dict[Tuple[str, str], List[int]] = {}
+_HELD: Optional[int] = None    # the thread ``held`` keeps off the ledger
+
+
+@contextlib.contextmanager
+def held():
+    """Book nothing this thread does in the block, a collection's pause
+    excepted (it happened): for a ledger that asks JAX for a program the
+    process already has (``monitor.program_ops``), so that set-up's account
+    stays set-up's. One thread at a time."""
+    global _HELD
+    prev, _HELD = _HELD, threading.get_ident()
+    try:
+        yield
+    finally:
+        _HELD = prev
 
 
 def book(kind: str, name: str, start_ns: int, end_ns: int, **extra: Any) -> None:
@@ -325,7 +341,10 @@ def book(kind: str, name: str, start_ns: int, end_ns: int, **extra: Any) -> None
     timeline recorder when there is one (``span`` events excepted: the span
     itself is already on it as a live ``B``/``E`` pair)."""
     extra = extra or None
-    event = (kind, name, start_ns, end_ns, threading.get_ident(), extra)
+    tid = threading.get_ident()
+    if tid == _HELD and kind != "gc":
+        return
+    event = (kind, name, start_ns, end_ns, tid, extra)
     if kind in _PER_STEP:
         _STEPS.append(event)
     elif len(_SETUP) < SETUP_CAP:

@@ -34,6 +34,7 @@ from typing import Any, Callable, Sequence, Tuple, Union
 
 import jax
 
+from beforeholiday_tpu.monitor.program import note_entry
 from beforeholiday_tpu.monitor.spans import span
 from beforeholiday_tpu.utils.logging import warn_once
 
@@ -116,12 +117,15 @@ def donate_step(
     The wrapper checks (host-side, shapes-only — no device sync) every
     positional argument OUTSIDE ``donate_argnums`` for a ``PackedParams``
     arena and warns once per (entry, slot) when one is found. The underlying
-    jitted function is exposed as ``.jitted`` (for ``.lower()`` / AOT use)."""
+    jitted function is exposed as ``.jitted`` (for ``.lower()`` / AOT use).
+    The first call notes the entry for ``monitor.program_ops()`` (abstract
+    values only; nothing is lowered for it until that is asked)."""
     if isinstance(donate_argnums, int):
         donate_argnums = (donate_argnums,)
     donated = frozenset(donate_argnums)
     jitted = jax.jit(fn, donate_argnums=tuple(donate_argnums), **jit_kwargs)
     entry = getattr(fn, "__name__", type(fn).__name__)
+    noted = False
 
     def warn_undonated(args):
         for i, arg in enumerate(args):
@@ -138,6 +142,9 @@ def donate_step(
 
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):
+        nonlocal noted
+        if not noted:  # before the call: the donated arrays are gone after it
+            noted = note_entry(entry, jitted, args, kwargs)
         # two host spans, so that a device-idle gap under the caller's
         # dispatch is told apart: this wrapper's own Python, or the jitted call
         with span("donate_step.prepare"):

@@ -178,26 +178,29 @@ def test_monitor_package_is_scanned():
 
 
 def test_perf_attribution_files_are_scanned():
-    """The perf-attribution trio (roofline ledger, the host ledger's feeders
-    in compile.py and spans.py, flight recorder) promises host-side arithmetic
-    over already-drained data — the scanner must reach them all, and only the
-    flight recorder's ``dump`` (its one crash-dump write path) is sanctioned;
-    the roofline and the ledger's feeders get NO sanctions and NO waivers."""
+    """The perf-attribution files (the chip peaks, the program ledger, the
+    host ledger's feeders in compile.py and spans.py, flight recorder) promise
+    host-side arithmetic over already-drained data — the scanner must reach
+    them all, and only the flight recorder's ``dump`` (its one crash-dump write
+    path) is sanctioned; the ledgers and their feeders get NO sanctions and NO
+    waivers."""
     monitor_files = sorted(
         p.relative_to(_PKG_ROOT).as_posix()
         for p in (_PKG_ROOT / "monitor").rglob("*.py")
     )
     assert "monitor/roofline.py" in monitor_files
+    assert "monitor/program.py" in monitor_files
     assert "monitor/overlap.py" not in monitor_files     # went with PR 35
     assert "monitor/spans.py" in monitor_files
     assert "monitor/flight.py" in monitor_files
     assert "monitor/roofline.py" not in _SANCTIONED_BY_FILE
+    assert "monitor/program.py" not in _SANCTIONED_BY_FILE
     assert "monitor/compile.py" not in _SANCTIONED_BY_FILE
     assert "monitor/spans.py" not in _SANCTIONED_BY_FILE
     assert _SANCTIONED_BY_FILE["monitor/flight.py"] == {"dump"}
     assert not [k for k in _WAIVED if k[0] in (
-        "monitor/roofline.py", "monitor/compile.py", "monitor/spans.py",
-        "monitor/flight.py",
+        "monitor/roofline.py", "monitor/program.py", "monitor/compile.py",
+        "monitor/spans.py", "monitor/flight.py",
     )]
 
 

@@ -383,7 +383,7 @@ class TestPerfettoMetadata:
 
     def test_exported_cross_rank_trace_round_trips_to_analyzers(self, tmp_path):
         """The exported JSON is the analyzers' input format (``span_intervals``,
-        goodput, ``join_spans``): nesting stays valid per rank and the spans
+        goodput): nesting stays valid per rank and the spans
         reconstruct exactly."""
         rec = self._cross_rank_trace()
         path = tmp_path / "trace.json"

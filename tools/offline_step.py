@@ -17,6 +17,13 @@ A gradient arena that is materialised once reads as a few two- to eight-operand
 fusions; one that is recomputed inside its consumers reads as fusions that
 each take every leaf cotangent as an operand (PERF.md §6, PR 25). Estimated
 cycles are the compiler's own model, not a time: times come from a chip run.
+
+``--nameless`` prints the program ledger's records (``monitor.program_ops``) of
+the instructions that carry no ``op_name`` — the compiler's own copies, fills,
+prefetches and fusions, which the device trace shows with an empty ``tf_op`` —
+largest first: opcode, bytes in and out, ``producer`` -> ``consumer``, hops.
+Bytes are a count: bytes / 819 GB/s is a floor, never a measured time; the
+times are in ``tools/dump_tf_ops.py``'s ``nameless`` table, from the chip.
 """
 
 from __future__ import annotations
@@ -32,20 +39,12 @@ import time
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
 
+from beforeholiday_tpu.monitor.program import parse_instructions, program_ops  # noqa: E402
+
 _SHAPE = re.compile(r"\b[a-z]+\d*\[([\d,]*)\]")
 _CYCLES = re.compile(r'"estimated_cycles":"(\d+)"')
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CALLS = re.compile(r"calls=(%[\w.\-]+)")
-
-
-def _closing(text: str, start: int) -> int:
-    """Index of the parenthesis that closes the one at ``start``."""
-    depth = 0
-    for i in range(start, len(text)):
-        depth += (text[i] == "(") - (text[i] == ")")
-        if not depth:
-            return i
-    raise ValueError(f"unbalanced parentheses: {text[:80]}")
 
 
 def _is_arena(shape: str, arena_elements: set) -> bool:
@@ -59,38 +58,16 @@ def _is_arena(shape: str, arena_elements: set) -> bool:
     return False
 
 
-def parse_instructions(hlo: str) -> list:
-    """``[(computation, name, shape, opcode, operand names, rest of line)]`` of
-    an HLO text, in the text's order (a compiled module prints its schedule)."""
-    out, computation = [], None
-    for line in hlo.splitlines():
-        line = line.strip().removeprefix("ROOT ")
-        if line.endswith("{") and " -> " in line:  # "%fused_computation.3 (p: ...) -> ... {"
-            computation = line.removeprefix("ENTRY ").split(" ", 1)[0]
-        if not line.startswith("%") or " = " not in line:
-            continue
-        name, rest = line.split(" = ", 1)
-        end = _closing(rest, 0) + 1 if rest.startswith("(") else rest.index(" ")
-        shape, call = rest[:end], rest[end:].lstrip()
-        if "(" not in call:
-            continue
-        opcode, args = call.split("(", 1)
-        close = _closing("(" + args, 0) - 1
-        operands = re.findall(r"%[\w.\-]+", args[:close])
-        out.append((computation, name, shape, opcode, operands, args[close:]))
-    return out
-
-
 def arena_wide_fusions(hlo: str, arena_elements: set) -> list:
     """``[(name, operand count, estimated cycles, op_name)]`` of the fusions
     that produce, consume or build inside themselves an arena-shaped array."""
     instructions = parse_instructions(hlo)
-    shape_of = {name: shape for _, name, shape, *_ in instructions}
+    shape_of = {i.name: i.shape for i in instructions}
     shapes_in = {}
-    for computation, _, shape, *_ in instructions:
-        shapes_in.setdefault(computation, []).append(shape)
+    for i in instructions:
+        shapes_in.setdefault(i.computation, []).append(i.shape)
     found = []
-    for _, name, shape, opcode, operands, rest in instructions:
+    for _, name, shape, opcode, operands, rest, *_ in instructions:
         if opcode != "fusion":
             continue
         called = _CALLS.search(rest)
@@ -151,10 +128,27 @@ def compile_cell(cellname: str, topo):
     return lowered.compile(), arenas
 
 
+_NOT_OPS = ("parameter", "constant", "tuple", "get-tuple-element", "bitcast")
+
+
+def print_nameless(cellname: str, hlo: str) -> None:
+    """The ledger's records of the nameless instructions of ``hlo``, largest first."""
+    rows = [r for r in program_ops(cellname, program=hlo)
+            if not r["scope"] and r["opcode"] not in _NOT_OPS]
+    print(f"{cellname}: {len(rows)} instructions without an op_name "
+          f"({sum(not (r['producer'] or r['consumer']) for r in rows)} with no named neighbour)")
+    for r in sorted(rows, key=lambda r: -max(r["bytes_in"], r["bytes_out"])):
+        print(f"  {r['name']:44s} {r['opcode']:20s} in {r['bytes_in'] / 1e6:9.1f} MB  "
+              f"out {r['bytes_out'] / 1e6:9.1f} MB  hops {r['hops']:2d}  "
+              f"{r['producer'] or '-'} -> {r['consumer'] or '-'}")
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("cells", nargs="+", help="names of cells in benchmark/workloads/")
     ap.add_argument("--hlo-dir", help="also write each cell's optimized HLO text here")
+    ap.add_argument("--nameless", action="store_true",
+                    help="also print the ledger's record of every instruction without an op_name")
     args = ap.parse_args(argv)
 
     import jax
@@ -177,6 +171,8 @@ def main(argv=None) -> int:
         )
         for name, n_operands, cycles, op_name in arena_wide_fusions(hlo, arenas):
             print(f"  {name:48s} operands {n_operands:3d}  est. cycles {cycles!s:>11}  {op_name}")
+        if args.nameless:
+            print_nameless(cellname, hlo)
         if args.hlo_dir:
             with open(os.path.join(args.hlo_dir, f"{cellname}.hlo.txt"), "w") as f:
                 f.write(hlo)
