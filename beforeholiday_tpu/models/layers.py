@@ -279,11 +279,50 @@ def logits_of(x, head):
         x, head.astype(x.dtype), (((2,), (1,)), ((), ())), preferred_element_type=_F32)
 
 
+@jax.custom_vjp
 def cross_entropy(logits, targets):
-    """Mean next-token cross entropy, the log-sum-exp in float32."""
-    logz = jax.nn.logsumexp(logits.astype(_F32), axis=-1)
-    picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    return jnp.mean(logz - picked.astype(_F32))
+    """Mean next-token cross entropy, the log-sum-exp in float32.
+
+    A ``custom_vjp`` because of what autodiff makes of ``logsumexp`` +
+    ``take_along_axis``: the head's cotangent as the SUM of two ``(B, S, V)``
+    float32 arrays from different ops in different layouts (softmax x g, and
+    -g scattered into zeros), which the TPU compiler writes, re-tiles, adds
+    and converts one pass at a time: four nameless ops of 403-671 MB each
+    between ``*_loss`` and ``*_head``'s backward products, 4.0-6.3 ms a step
+    in each of the seven 8k cells (PERF.md §5, PR 52's chip runs). The
+    backward here is that cotangent as ONE elementwise expression of the
+    saved logits, their log-sum-exp and the targets, ``(softmax - onehot) *
+    g / N`` in float32, which the compiler fuses into the two products or
+    writes once. Residuals: the logits (autodiff saved them too), ``logz``
+    and the targets. No forward-mode rule. ``testing/gpt.py:_cross_entropy``
+    is deliberately not this function yet: the ledger shows no logits-sized
+    nameless op in the GPT cells."""
+    return _cross_entropy_fwd(logits, targets)[0]
+
+
+def _target_columns(x, targets):
+    """Where ``x (..., V)`` holds its row's target: a ``(..., V)`` mask."""
+    return jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1) == targets[..., None]
+
+
+def _cross_entropy_fwd(logits, targets):
+    x = logits.astype(_F32)
+    logz = jax.nn.logsumexp(x, axis=-1)
+    # the target's logit as a masked row sum (exact: one term a row is not 0),
+    # so that it rides the log-sum-exp's reduce pass and no gather is made
+    picked = jnp.sum(jnp.where(_target_columns(x, targets), x, 0.0), axis=-1)
+    return jnp.mean(logz - picked), (logits, logz, targets)
+
+
+def _cross_entropy_bwd(saved, g):
+    logits, logz, targets = saved
+    x = logits.astype(_F32)
+    softmax = jnp.exp(x - logz[..., None])
+    d = (softmax - _target_columns(x, targets).astype(_F32)) * (g / logz.size)
+    return d.astype(logits.dtype), None
+
+
+cross_entropy.defvjp(_cross_entropy_fwd, _cross_entropy_bwd)
 
 
 def by_period(tree, periods: int, n: int):

@@ -196,3 +196,65 @@ def test_init_draws_leaf_i_of_the_flattened_shapes_from_fold_in_i(name):
     want = jax.random.normal(jax.random.fold_in(key, i), flat[i][1][0]) * cfg.initializer_range
     np.testing.assert_array_equal(inside, want)
     assert "top" not in params and "embed" in params
+
+
+# ---------------------------------------------------------------------------
+# the loss: one expression for the head's cotangent (PR 53) against autodiff
+# ---------------------------------------------------------------------------
+
+
+def _autodiff_cross_entropy(logits, targets):
+    """The form ``layers.cross_entropy`` had before it took a ``custom_vjp``:
+    what autodiff differentiates into softmax x g plus a scatter of -g."""
+    logz = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
+    picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    return jnp.mean(logz - picked.astype(jnp.float32))
+
+
+_WRAPPED = {"plain": lambda f: f, "jit": jax.jit, "checkpoint": jax.checkpoint}
+
+
+@pytest.mark.parametrize("wrap", sorted(_WRAPPED))
+@pytest.mark.parametrize("dtype", (jnp.float32, jnp.bfloat16), ids=("float32", "bfloat16"))
+@pytest.mark.parametrize("shape", ((1, 8, 128), (2, 16, 384), (1, 5, 130)),
+                         ids=lambda s: "x".join(map(str, s)))
+def test_cross_entropy_is_the_autodiff_form_in_value_and_cotangent(shape, dtype, wrap):
+    """Value and the logits' cotangent under an upstream cotangent of 3, with
+    the first id, the last id and a repeated id among the targets."""
+    B, S, V = shape
+    logits = (4.0 * jax.random.normal(jax.random.PRNGKey(V), shape)).astype(dtype)
+    targets = jax.random.randint(jax.random.PRNGKey(S), (B, S), 0, V)
+    targets = targets.at[0, :4].set(jnp.asarray([0, V - 1, 7, 7]))
+    g = jnp.float32(3.0)
+
+    want, pull = jax.vjp(_autodiff_cross_entropy, logits, targets)
+    got, pull_got = jax.vjp(_WRAPPED[wrap](layers.cross_entropy), logits, targets)
+    (d_want, _), (d_got, d_targets) = pull(g), pull_got(g)
+
+    assert got.dtype == jnp.float32 and d_got.dtype == dtype and d_got.shape == shape
+    assert d_targets.dtype == jax.dtypes.float0
+    np.testing.assert_allclose(got, want, rtol=2e-6)
+    # float32: two orders of summation apart; bfloat16: autodiff rounds the two
+    # terms and then their sum, the expression rounds once
+    peak = float(jnp.max(jnp.abs(d_want.astype(jnp.float32))))
+    atol = peak * (2e-6 if dtype == jnp.float32 else 2.0 ** -7)
+    np.testing.assert_allclose(d_got.astype(jnp.float32), d_want.astype(jnp.float32),
+                               rtol=0, atol=atol)
+    # every row's cotangent sums to nothing, and only the target's column is negative
+    np.testing.assert_allclose(jnp.sum(d_got.astype(jnp.float32), axis=-1), 0.0,
+                               atol=V * atol)
+    onehot = jax.nn.one_hot(targets, V, dtype=bool)
+    assert bool(jnp.all(jnp.where(onehot, d_got <= 0, d_got >= 0)))
+
+
+def test_cross_entropy_differentiates_through_jax_grad_of_the_head():
+    """As the families call it: ``jax.grad`` through ``logits_of`` to the
+    activations and the head's rows, the autodiff form's to float32 rounding."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 8, 32))
+    head = 0.3 * jax.random.normal(jax.random.PRNGKey(1), (96, 32))
+    targets = jax.random.randint(jax.random.PRNGKey(2), (2, 8), 0, 96)
+    loss = lambda ce: lambda x, head: ce(layers.logits_of(x, head), targets)
+    want = jax.grad(loss(_autodiff_cross_entropy), argnums=(0, 1))(x, head)
+    got = jax.grad(loss(layers.cross_entropy), argnums=(0, 1))(x, head)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=0, atol=2e-6 * float(jnp.max(jnp.abs(b))))
